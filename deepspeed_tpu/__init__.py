@@ -12,10 +12,6 @@ Public surface mirrors the reference (``deepspeed/__init__.py``):
 
 from deepspeed_tpu.version import __version__, __capability_parity__
 
-# installs jax.shard_map / lax.axis_size shims on older jax runtimes so
-# every call site can use the modern spelling
-from deepspeed_tpu.utils import jax_compat as _jax_compat  # noqa: F401
-
 from deepspeed_tpu.utils.logging import logger, log_dist
 from deepspeed_tpu import comm
 

@@ -14,14 +14,10 @@ _accelerator: Optional[DeepSpeedAccelerator] = None
 
 
 def _detect() -> str:
-    try:
-        import jax
-        for d in jax.devices():
-            if d.platform == "tpu" or "TPU" in getattr(d, "device_kind", ""):
-                return "tpu"
-    except Exception:
-        pass
-    return "cpu"
+    """"tpu" when jax reports a TPU device, "cpu" when it reports none. A
+    backend that fails to initialise raises: it is not the CPU."""
+    import jax
+    return "tpu" if any(d.platform == "tpu" for d in jax.devices()) else "cpu"
 
 
 def get_accelerator() -> DeepSpeedAccelerator:

@@ -1,8 +1,8 @@
 """graft-lint core: the rule registry, findings, and waivers.
 
 PR 5 and PR 6 each hardened a program-level invariant by hand ("no
-``[*,S,E,C]`` tensor in the sorted-route jaxpr", "owned_device_put on the
-0.4.37 container", pinned matmul precision on the parity path) — one-off
+``[*,S,E,C]`` tensor in the sorted-route jaxpr", "owned_device_put for
+donated host trees", pinned matmul precision on the parity path) — one-off
 assertions that protect nothing outside their own test. This package
 turns those invariants into a *registry of named rules* checked
 mechanically against every traced program, the same role the reference's

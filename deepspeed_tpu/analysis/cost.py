@@ -85,8 +85,8 @@ class CostInfo:
 def _backend_stats(compiled) -> Dict[str, Any]:
     """Flops + per-device memory stats from the compiled executable —
     the on-backend numbers the static estimate is cross-checked against.
-    jax 0.4.37 returns ``cost_analysis()`` as a list of per-computation
-    dicts (the PR 5 autotuner handling)."""
+    ``cost_analysis()`` may come back as a list of per-computation dicts
+    (the PR 5 autotuner handling)."""
     out: Dict[str, Any] = {}
     try:
         ca = compiled.cost_analysis()

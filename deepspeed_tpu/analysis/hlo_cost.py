@@ -20,7 +20,7 @@ Three inventory layers, honest about what each can see:
   calls, not collectives, before partitioning).
 * ``compiled`` — the post-SPMD, post-optimization HLO of
   ``lowered.compile().as_text()``: the collectives that actually run.
-  **Backend caveat (measured on the pinned jax 0.4.37 CPU container):**
+  **Backend caveat (XLA:CPU):**
   XLA:CPU decomposes reduce-scatter into all-reduce + dynamic-slice, so
   kind-exact reduce-scatter expectations must be declared per-backend
   (R009 ``backends`` field) and are *inventoried as unchecked* elsewhere
@@ -54,11 +54,11 @@ from deepspeed_tpu.analysis.program import ProgramAnalyzer, aval_bytes
 KINDS = ("all_reduce", "all_gather", "reduce_scatter", "all_to_all",
          "collective_permute")
 
-#: jaxpr primitive -> canonical kind (psum2 is shard_map's rep-rewritten
-#: psum on jax 0.4.37; check_rep=False regions keep plain psum)
+#: jaxpr primitive -> canonical kind (psum_invariant is what a psum
+#: traces to inside a checked shard_map; unchecked regions keep plain psum)
 _PRIM_KIND = {
     "psum": "all_reduce",
-    "psum2": "all_reduce",
+    "psum_invariant": "all_reduce",
     "pmax": "all_reduce",
     "pmin": "all_reduce",
     "all_gather": "all_gather",

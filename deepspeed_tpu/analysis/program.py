@@ -24,12 +24,7 @@ import numpy as np
 
 import jax
 
-# the public aliases exist on 0.4.37 (jax.extend.core); fall back to the
-# private module defensively for other pins
-try:
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-except ImportError:  # pragma: no cover
-    from jax.core import ClosedJaxpr, Jaxpr
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 class EqnRecord(NamedTuple):

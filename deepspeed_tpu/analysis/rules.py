@@ -136,8 +136,7 @@ def r002_precision(program, analyzer):
 # ---------------------------------------------------------------------------
 _HOST_PRIMS = {
     "device_put": ERROR,  # host<->device copy inside the step: a sync + a
-    # transfer every dispatch, and on the 0.4.37 CPU container the
-    # zero-copy alias hazard (utils/device.py)
+    # transfer every dispatch
     "io_callback": ERROR,
     "pure_callback": ERROR,
     "outside_call": ERROR,

@@ -35,7 +35,7 @@ SCENARIOS: Dict[str, Callable[[], ProgramInfo]] = {}
 
 class ScenarioSkipped(Exception):
     """Raised by a builder when its program cannot trace on this runtime
-    (e.g. partial-manual shard_map on jax 0.4.37) — reported, not fatal.
+    (e.g. too few devices) — reported, not fatal.
     ``kind`` is a stable machine-readable gap class so reports carry a
     structured ``blocking_gap: {kind, detail}`` instead of a prose string
     (the ROADMAP-5 burn-down reads the kind, not the wording). ``probe``
@@ -72,20 +72,6 @@ def _probe_composition_16dev() -> Dict[str, str]:
     device_count skip, never to a crash."""
     global _COMPOSITION_PROBE_CACHE
     if _COMPOSITION_PROBE_CACHE is not None:
-        return _COMPOSITION_PROBE_CACHE
-    from deepspeed_tpu.utils.jax_compat import PARTIAL_MANUAL_OK
-    if not PARTIAL_MANUAL_OK:
-        # the gap behind device_count is decided by a VERSION constant the
-        # child would read identically: partial-manual shard_map support.
-        # No subprocess needed on the pinned container — the probe only
-        # forks on modern jax, where the next gap (moe_in_pipe or beyond)
-        # requires actually attempting the 16-device build.
-        _COMPOSITION_PROBE_CACHE = {
-            "kind": "partial_manual", "probe": "version",
-            "detail": "[16-device outcome version-determined] jax-0.4.37 "
-                      "partial-manual shard_map gap: the pipe axis is manual "
-                      "while expert/tensor/fsdp stay auto at size 2 "
-                      "(utils/jax_compat.py) — the composition traces on jax>=0.5"}
         return _COMPOSITION_PROBE_CACHE
     import json
     import os
@@ -366,9 +352,7 @@ def train_batch_telemetry() -> ProgramInfo:
 
 @scenario("pipe_scan_step")
 def pipe_scan_step() -> ProgramInfo:
-    """The pipeline engine's scan step on a pipe=2 mesh (auto axes size 1
-    fold to full-manual, so this traces even on the 0.4.37 container —
-    jax_compat docstring). Skips, not fails, where shard_map can't."""
+    """The pipeline engine's scan step on a pipe=2 mesh."""
     import deepspeed_tpu
     from deepspeed_tpu.models.gpt2 import gpt2_pipe_layers
     from deepspeed_tpu.models import get_gpt2_config
@@ -487,9 +471,7 @@ PIPE_1F1B_BUDGET_MB = 2.0
 
 
 def _pipe_engine_program(name: str, pipeline_cfg: dict) -> ProgramInfo:
-    """Shared pipe=2-only builder (every auto axis size 1 folds to
-    full-manual, so these trace on the 0.4.37 container where
-    ``pipe_scan_step``'s pipe x data x fsdp mesh cannot)."""
+    """Shared pipe=2-only builder."""
     import deepspeed_tpu
     from deepspeed_tpu.models import get_gpt2_config
     from deepspeed_tpu.models.gpt2 import gpt2_pipe_layers
@@ -982,15 +964,11 @@ def composition_3d_ep_zeropp() -> ProgramInfo:
     skipped-scenarios section instead of staying folklore. The old first
     link — 8 forced host devices — is burned down: a <16-device run
     probes the 16-device build in a subprocess and reports the gap
-    *behind* it, so on the pinned container the chain now starts at the
-    jax-0.4.37 partial-manual shard_map gap (pipe is manual,
-    expert/tensor/fsdp stay auto at size 2) -> MoE blocks unsupported
-    inside the pipelined GPT-2."""
+    *behind* it: MoE blocks unsupported inside the pipelined GPT-2."""
     import deepspeed_tpu
     from deepspeed_tpu.models import get_gpt2_config
     from deepspeed_tpu.models.gpt2 import gpt2_pipe_layers
     from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
-    from deepspeed_tpu.utils.jax_compat import PARTIAL_MANUAL_OK
     from deepspeed_tpu.runtime.pipe.module import PipelineModule
 
     if len(jax.devices()) < 16:
@@ -1003,17 +981,10 @@ def composition_3d_ep_zeropp() -> ProgramInfo:
                 f"{len(jax.devices())})", kind="device_count")
         # device_count burn-down: the host-device count cannot change after
         # backend init, but the blocking-gap INVENTORY must not stop here —
-        # probe the 16-device build out of process and report the real gap
-        # (partial_manual on the pinned container). In-process tracing still
-        # needs GRAFT_LINT_DEVICES=16.
+        # probe the 16-device build out of process and report the real gap.
+        # In-process tracing still needs GRAFT_LINT_DEVICES=16.
         gap = _probe_composition_16dev()
         raise ScenarioSkipped(gap["detail"], kind=gap["kind"], probe=gap.get("probe"))
-    if not PARTIAL_MANUAL_OK:
-        raise ScenarioSkipped(
-            "jax-0.4.37 partial-manual shard_map gap: the pipe axis is "
-            "manual while expert/tensor/fsdp stay auto at size 2 "
-            "(utils/jax_compat.py) — the composition traces on jax>=0.5",
-            kind="partial_manual")
     set_topology(None)
     try:
         cfg = get_gpt2_config("test", n_layer=4, moe_num_experts=2,

@@ -5,11 +5,11 @@ traced — host-side API misuse. Two bans, both from hard-won container
 folklore:
 
 * raw ``jax.device_put`` anywhere in the package (outside
-  ``utils/device.py`` itself): on the pinned 0.4.37 CPU runtime a
-  zero-copy ``device_put`` of host data aliases foreign memory, and
-  donating that array corrupts the heap (glibc "corrupted double-linked
-  list" several dispatches later). ``owned_device_put`` is the safe
-  spelling. Audited-safe sites (jax-owned sources, device->device
+  ``utils/device.py`` itself): placement of host data that a step will
+  donate goes through one audited seam, ``owned_device_put`` (a zero-copy
+  ``device_put`` aliases host memory on the CPU backend; the seam's
+  docstring records what was checked about donating it). Audited-safe
+  sites (jax-owned sources, device->device
   resharding) carry an inline waiver:
 
       jax.device_put(x, sharding)  # graft-lint: waive R008 jax-owned source
@@ -145,8 +145,8 @@ def r008_source(files: Iterable[Tuple[str, str, ast.Module]]) -> List[Finding]:
                 if not device_put_ok and (name == "jax.device_put" or name in dp_aliases):
                     emit(node.lineno,
                          "raw jax.device_put — use "
-                         "deepspeed_tpu.utils.device.owned_device_put (0.4.37 "
-                         "zero-copy donation hazard) or waive with an audit note")
+                         "deepspeed_tpu.utils.device.owned_device_put (the "
+                         "audited placement seam) or waive with an audit note")
                 if jit_stack and jit_stack[-1] and _frozen_host_call(name):
                     emit(node.lineno,
                          f"'{name}' inside a @jit-decorated body is evaluated "

@@ -2,7 +2,7 @@
 ``bin/ds_report``): versions, accelerator status, and the op-builder
 compatibility matrix, so users can see at a glance what this install can do.
 
-The accelerator probe runs in a subprocess under a timeout: a wedged TPU
+The accelerator probe runs in a subprocess under a timeout: a hung TPU
 plugin must degrade the report, not hang it (the reference equivalent is
 ``real_accelerator`` probing with try/except, ``real_accelerator.py:90``).
 """

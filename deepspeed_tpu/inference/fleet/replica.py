@@ -168,7 +168,7 @@ class SubprocessReplica:
     ``env`` overlays the parent environment (FLEET_*/SERVE_* knobs); the
     replica's heartbeat file and stderr log land under ``workdir``.
     Liveness = process exit code OR heartbeat staleness — a replica
-    wedged inside a dispatch never exits, so the router also compares
+    hung inside a dispatch never exits, so the router also compares
     ``heartbeat_age()`` against its timeout (the PR-13 lesson)."""
 
     def __init__(self, name: str, workdir: str,

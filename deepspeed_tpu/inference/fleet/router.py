@@ -93,7 +93,7 @@ class FleetRouter:
         if age_fn is not None:
             age = age_fn()
             if age is not None and age > self.heartbeat_timeout:
-                return False  # wedged inside a dispatch: exit never fires
+                return False  # hung inside a dispatch: exit never fires
         return True
 
     # -- submission ----------------------------------------------------

@@ -219,8 +219,8 @@ def lookup_table_view(table):
     partitions ``take`` by psum-ing partial gathers and leaves the output
     embed-sharded; the residual-stream constraint then needs a transition
     the partitioner cannot produce — it replicates the whole activation
-    ("Involuntary full rematerialization", ``spmd_partitioner.cc:652``;
-    MULTICHIP_r03 tail). Pinning the TABLE un-sharded for the lookup moves
+    ("Involuntary full rematerialization", ``spmd_partitioner.cc:652``).
+    Pinning the TABLE un-sharded for the lookup moves
     the reshard onto the parameter (an ordinary all-gather — exactly the
     ZeRO-3 gather-on-use) so the gather emits (batch, length, embed)
     directly. Skipped on tensor=sequence=1 meshes, where the default

@@ -17,7 +17,7 @@ axis is the innermost grid dimension, one block streams into VMEM per grid
 step (Mosaic double-buffers the next block's DMA behind the current
 matmul), and the online-softmax state rides VMEM scratch across steps.
 VMEM held per step is a few blocks, independent of sequence length, so the
-single-chip ceiling is HBM, not VMEM (VERDICT r2 weak #5: the previous
+single-chip ceiling is HBM, not VMEM (the previous
 design staged full-length K/V per cell, capping L at ~24k). Causally dead
 K blocks skip their FLOPs via ``pl.when``. Longer-than-HBM contexts remain
 the job of sequence parallelism (``deepspeed_tpu.parallel.ring_attention``).
@@ -42,12 +42,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.ops.pallas import backend
 from deepspeed_tpu.ops.pallas.attention_geometry import (AttentionGeometry,
                                                          parse_spec,
                                                          pick_block,
                                                          resolve_geometry)
 from deepspeed_tpu.ops.transformer.attention import register_backend
+from deepspeed_tpu.parallel.topology import BATCH_AXES, TENSOR_AXIS, get_topology
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -97,10 +100,6 @@ def _n_live_blocks(kv_len, blk_k):
     return jnp.maximum((kv_len + blk_k - 1) // blk_k, 1)
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 _warned_fallback = set()
 
 
@@ -109,6 +108,39 @@ def _warn_fallback(reason: str):
         _warned_fallback.add(reason)
         from deepspeed_tpu.utils.logging import logger
         logger.warning(f"flash attention falling back to the XLA backend: {reason}")
+
+
+def per_shard(local, q, k, v, lengths):
+    """Run ``local(q, k, v, lengths)`` on each device's shard of BLHD
+    operands. GSPMD cannot partition a compiled Mosaic kernel ("wrap the
+    call in a shard_map"), so on a multi-device mesh the call goes manual
+    over every mesh axis an enclosing shard_map has not already taken:
+    batch splits over the batch axes and heads over the tensor axis where
+    they divide, and stay replicated where they do not (the batch-1 trace
+    of parameter init). Attention is independent per (batch, head), so the
+    shards need no collective."""
+    topo = get_topology()
+    if topo is None or topo.mesh.size == 1:
+        return local(q, k, v, lengths)
+    mesh = topo.mesh
+    taken = set(jax.sharding.get_abstract_mesh().manual_axes)
+    free = [a for a in mesh.axis_names if a not in taken]
+    if all(mesh.shape[a] == 1 for a in free):
+        return local(q, k, v, lengths)
+
+    def dividing(axes, extent):
+        axes = tuple(a for a in axes if a in free and mesh.shape[a] > 1)
+        n = 1
+        for a in axes:
+            n *= mesh.shape[a]
+        return axes if axes and extent % n == 0 else None
+
+    batch = dividing(BATCH_AXES, q.shape[0])
+    heads = dividing((TENSOR_AXIS,), q.shape[2])
+    blhd = P(batch, None, heads, None)
+    in_specs = (blhd, blhd, blhd, None if lengths is None else P(batch))
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=blhd,
+                         axis_names=set(free), check_vma=False)(q, k, v, lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -617,7 +649,7 @@ def flash_decode(q: jax.Array,
     if scale is None:
         scale = d**-0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = backend.interpret_default()
     blk_k = block_k or pick_block(lk)
     if lk % blk_k:
         raise ValueError(f"KV cache length {lk} not divisible by block {blk_k}")
@@ -738,7 +770,7 @@ def flash_attention(q: jax.Array,
     if scale is None:
         scale = d**-0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = backend.interpret_default()
     # explicit block kwargs keep the historical contract: a size that does
     # not tile the call warns and falls back to XLA (lower-precedence
     # layers are instead clamped to divisors inside resolve_geometry)
@@ -757,12 +789,19 @@ def flash_attention(q: jax.Array,
         overrides = overrides.merged_over(parse_spec(geometry_spec))
     geom, _ = resolve_geometry(lq, lk, d, h, b, bool(causal), q.dtype,
                                overrides=overrides)
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    o = _flash_attention_bhld(qt, kt, vt, kv_lengths, float(scale), bool(causal),
-                              geom.block_q, geom.block_k,
-                              geom.block_q_bwd, geom.block_k_bwd,
-                              geom.bwd_skip, geom.policy, interpret,
-                              int(window) if window is not None else None)
-    return o.transpose(0, 2, 1, 3)
+
+    def local(q, k, v, kv_lengths):
+        qt = q.transpose(0, 2, 1, 3)
+        kt = k.transpose(0, 2, 1, 3)
+        vt = v.transpose(0, 2, 1, 3)
+        o = _flash_attention_bhld(qt, kt, vt, kv_lengths, float(scale), bool(causal),
+                                  geom.block_q, geom.block_k,
+                                  geom.block_q_bwd, geom.block_k_bwd,
+                                  geom.bwd_skip, geom.policy, interpret,
+                                  int(window) if window is not None else None)
+        return o.transpose(0, 2, 1, 3)
+
+    if interpret:
+        # interpreted kernels lower to plain XLA ops, which GSPMD partitions
+        return local(q, k, v, kv_lengths)
+    return per_shard(local, q, k, v, kv_lengths)

@@ -40,22 +40,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas import backend
+
 IMPL_CHOICES = ("xla", "pallas")
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def resolve_impl(kernel: str) -> str:
     """Map a routing-engine kernel choice ("auto"|"xla"|"pallas") to a
     concrete impl for the current backend."""
-    if kernel == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    if kernel not in IMPL_CHOICES:
-        raise ValueError(f"moe kernel impl must be one of {IMPL_CHOICES} "
-                         f"(or 'auto'), got {kernel!r}")
-    return kernel
+    return backend.resolve_impl(kernel, IMPL_CHOICES, "moe kernel")
 
 
 # ---------------------------------------------------------------------------
@@ -71,39 +64,91 @@ def _xla_permute(x: jax.Array, idx: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: one output row per grid step, index-map-driven source DMA
+# Pallas kernel: a block of output rows per grid step, one row DMA each
 # ---------------------------------------------------------------------------
-def _permute_kernel(idx_ref, row_ref, out_ref, *, n_rows):
-    g, r = pl.program_id(0), pl.program_id(1)
-    live = idx_ref[g, r] < n_rows
-    out_ref[...] = jnp.where(live, row_ref[...],
-                             jnp.zeros_like(row_ref)).astype(out_ref.dtype)
+#: output rows gathered per grid step (one DMA in flight per row)
+ROW_BLOCK = 128
+
+
+def _permute_kernel(idx_ref, x_hbm, zero_hbm, out_ref, sem, *, n_rows, rb):
+    g, base = pl.program_id(0), pl.program_id(1) * rb
+
+    def row_copy(src_ref, i):
+        return pltpu.make_async_copy(src_ref, out_ref.at[i], sem)
+
+    def start(i, carry):
+        src = idx_ref[g, base + i]
+
+        @pl.when(src < n_rows)
+        def _live():
+            row_copy(x_hbm.at[g, src], i).start()
+
+        @pl.when(src >= n_rows)
+        def _dead():
+            # dropped slots pull the zero row: same bytes, same semaphore,
+            # so the waits below need not know which rows were live
+            row_copy(zero_hbm, i).start()
+
+        return carry
+
+    def wait(i, carry):
+        row_copy(zero_hbm, i).wait()
+        return carry
+
+    jax.lax.fori_loop(0, rb, start, 0)
+    jax.lax.fori_loop(0, rb, wait, 0)
+
+
+def _as_words(x: jax.Array) -> jax.Array:
+    """View rows of a sub-32-bit table as uint32 words ``[G, N, M/pack]``.
+    The TPU stores 16-bit rows two to a sublane, so a single such row is
+    not a legal DMA slice; a row of 32-bit words is."""
+    pack = 4 // x.dtype.itemsize
+    if pack == 1:
+        return x
+    if x.shape[-1] % pack:
+        raise ValueError(f"pallas moe permute needs a model dim divisible by "
+                         f"{pack} for {x.dtype}, got {x.shape[-1]}")
+    return jax.lax.bitcast_convert_type(
+        x.reshape(*x.shape[:-1], x.shape[-1] // pack, pack), jnp.uint32)
+
+
+def _from_words(w: jax.Array, dtype) -> jax.Array:
+    if w.dtype == dtype:
+        return w
+    out = jax.lax.bitcast_convert_type(w, dtype)
+    return out.reshape(*w.shape[:-1], -1)
 
 
 def _pallas_permute(x: jax.Array, idx: jax.Array,
                     interpret: Optional[bool] = None) -> jax.Array:
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = backend.interpret_default()
+    dtype = x.dtype
+    x = _as_words(x)
     groups, n, m = x.shape
     r = idx.shape[1]
+    # rows are an untiled major dimension of the output block: any divisor
+    rb = backend.largest_block(r, ROW_BLOCK)
 
-    def src_map(g, i, idx_ref):
-        # dead rows (idx >= n) clamp to a valid row: the fetch is elided
-        # when already resident, and the kernel writes zeros regardless
-        return (g, jnp.minimum(idx_ref[g, i], n - 1), 0)
-
-    return pl.pallas_call(
-        functools.partial(_permute_kernel, n_rows=n),
+    out = pl.pallas_call(
+        functools.partial(_permute_kernel, n_rows=n, rb=rb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(groups, r),
-            in_specs=[pl.BlockSpec((None, 1, m), src_map)],
-            out_specs=pl.BlockSpec((None, 1, m),
-                                   lambda g, i, idx_ref: (g, i, 0)),
+            grid=(groups, r // rb),
+            # the table and the zero row stay in HBM, one row per (1, m)
+            # tile so a row is a whole-tile slice; each output row is one
+            # row-sized DMA straight into the pipelined output block
+            in_specs=[pl.BlockSpec(memory_space=pltpu.HBM),
+                      pl.BlockSpec(memory_space=pltpu.HBM)],
+            out_specs=pl.BlockSpec((None, rb, 1, m),
+                                   lambda g, i, idx_ref: (g, i, 0, 0)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
         ),
-        out_shape=jax.ShapeDtypeStruct((groups, r, m), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((groups, r, 1, m), x.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x)
+    )(idx.astype(jnp.int32), x[:, :, None, :], jnp.zeros((1, m), x.dtype))
+    return _from_words(out[:, :, 0, :], dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -10,12 +10,13 @@ whole kernel:
 
 * ``impl="xla"`` (default off-TPU): unpack + broadcast-scale + dot. XLA
   fuses the dequant into the matmul prologue; runs everywhere.
-* ``impl="pallas"``: grid ``(N-blocks, K-groups)``; each step DMAs one
-  ``[K/G, bn]`` code block and its ``[1, bn]`` scale row, dequantizes in
-  VMEM, and accumulates the partial product into the output block in
-  fp32 (``@pl.when`` k==0 init, the standard accumulation idiom). Block
-  boundaries align with scale groups by construction — one scale row per
-  accumulation step. Interpret mode makes it CPU-testable.
+* ``impl="pallas"``: grid ``(M-blocks, N-blocks, K-steps)``; each step
+  DMAs the code block of a few whole scale groups and their scale rows,
+  dequantizes in VMEM, and accumulates the partial product into the
+  output block in fp32 (``@pl.when`` k==0 init, the standard accumulation
+  idiom). A step spans whole groups, as many as make its blocks tile the
+  TPU's (8, 128) / int8 (32, 128) layouts. Interpret mode makes it
+  CPU-testable.
 
 Forward-only on purpose: serving programs never differentiate.
 """
@@ -28,59 +29,52 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas import backend
 from deepspeed_tpu.ops.quantizer.weights import unpack_rows
 
 IMPL_CHOICES = ("xla", "pallas")
 
 #: output-column block cap (fp32 accumulator block stays a few hundred KB)
 MAX_BN = 512
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+#: activation-row block cap
+MAX_BM = 256
+#: contraction rows dequantized per grid step (whole scale groups)
+MAX_BK = 512
 
 
 def resolve_impl(kernel: str) -> str:
     """Map an impl choice ("auto"|"xla"|"pallas") to a concrete impl for
     the current backend (the ``moe_dispatch.resolve_impl`` convention)."""
-    if kernel == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    if kernel not in IMPL_CHOICES:
-        raise ValueError(f"quant_matmul impl must be one of {IMPL_CHOICES} "
-                         f"(or 'auto'), got {kernel!r}")
-    return kernel
+    return backend.resolve_impl(kernel, IMPL_CHOICES, "quant_matmul")
 
 
-def _col_block(n: int) -> int:
-    """Largest divisor of N at most MAX_BN, so output blocks tile N
-    exactly and no step straddles a scale row."""
-    bn = min(n, MAX_BN)
-    while n % bn != 0:
-        bn -= 1
-    return bn
+def _groups_per_step(g: int, rows: int) -> int:
+    """Scale groups dequantized per grid step. ``rows`` is the stored code
+    rows per group (halved for int4). A step's blocks must tile: the scale
+    block's group count by 8 sublanes, the activation block's lanes by 128,
+    the int8 code block's rows by 32 — or span the whole axis."""
+    for gs in range(8, g, 8):
+        if g % gs == 0 and (gs * rows) % 128 == 0 and gs * rows <= MAX_BK:
+            return gs
+    return g
 
 
-def _unpack_block(q: jax.Array) -> jax.Array:
-    """In-kernel row unpack: packed ``[bk/2, bn]`` → int8 codes
-    ``[bk, bn]`` (low nibble = even row, high nibble = odd row;
-    arithmetic shift then mask, sign-extend > 7)."""
-    lo = q & 0xF
-    hi = (q >> 4) & 0xF
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
-    return jnp.stack([lo, hi], axis=1).reshape(2 * q.shape[0], q.shape[1])
+def _dequant(q, s_ref, gs, dtype):
+    """int32 codes ``[gs*rows, bn]`` times their group's scale row, as the
+    activation dtype so bf16 serving feeds bf16 operands (fp32 accum)."""
+    rows = q.shape[0] // gs
+    parts = [q[i * rows:(i + 1) * rows].astype(jnp.float32) * s_ref[i:i + 1, :]
+             for i in range(gs)]
+    return jnp.concatenate(parts, axis=0).astype(dtype)
 
 
-def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, *, bits):
-    gi = pl.program_id(1)
-    q = w_ref[...]
-    if bits == 4:
-        q = _unpack_block(q)
-    # dequant on the way into the MXU: fp32 scale multiply, then the
-    # activation dtype so bf16 serving feeds bf16 operands (fp32 accum)
-    w = (q.astype(jnp.float32) * s_ref[...]).astype(x_ref.dtype)
-    part = jax.lax.dot_general(x_ref[...], w, (((1,), (0,)), ((), ())),
+def _dot(x, w):
+    return jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
+
+
+def _accumulate(o_ref, part):
+    gi = pl.program_id(2)
 
     @pl.when(gi == 0)
     def _init():
@@ -91,30 +85,55 @@ def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, *, bits):
         o_ref[...] += part
 
 
+def _qmm8_kernel(x_ref, w_ref, s_ref, o_ref, *, gs):
+    # dequant on the way into the MXU
+    w = _dequant(w_ref[...].astype(jnp.int32), s_ref, gs, x_ref.dtype)
+    _accumulate(o_ref, _dot(x_ref[...], w))
+
+
+def _qmm4_kernel(xe_ref, xo_ref, w_ref, s_ref, o_ref, *, gs):
+    # packed row i holds code rows 2i (low nibble) and 2i+1 (high nibble).
+    # Interleaving them back would shuffle sublanes; instead the caller
+    # splits x into its even and odd columns, so each nibble plane is a
+    # plain GEMM operand. Shifts on sign-extended int32 keep the sign.
+    q = w_ref[...].astype(jnp.int32)
+    lo = (q << 28) >> 28
+    hi = q >> 4
+    part = _dot(xe_ref[...], _dequant(lo, s_ref, gs, xe_ref.dtype))
+    part += _dot(xo_ref[...], _dequant(hi, s_ref, gs, xo_ref.dtype))
+    _accumulate(o_ref, part)
+
+
 def _pallas_quant_matmul(x: jax.Array, qw: jax.Array, scale: jax.Array,
                          bits: int, interpret: Optional[bool]) -> jax.Array:
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = backend.interpret_default()
     m, k = x.shape
     g, n = scale.shape
-    bk = k // g
-    bkw = bk // 2 if bits == 4 else bk
-    bn = _col_block(n)
+    rows = qw.shape[0] // g          # stored code rows per scale group
+    gs = _groups_per_step(g, rows)
+    bk = gs * rows
+    bm = backend.largest_block(m, MAX_BM, 8 * (4 // x.dtype.itemsize))
+    bn = backend.largest_block(n, MAX_BN, 128)
 
+    x_spec = pl.BlockSpec((bm, bk), lambda i, j, gi: (i, gi))
+    if bits == 4:
+        kernel, xs = _qmm4_kernel, (x[:, 0::2], x[:, 1::2])
+    else:
+        kernel, xs = _qmm8_kernel, (x,)
     out = pl.pallas_call(
-        functools.partial(_qmm_kernel, bits=bits),
-        grid=(n // bn, g),
-        in_specs=[
-            pl.BlockSpec((m, bk), lambda j, gi: (0, gi)),
-            pl.BlockSpec((bkw, bn), lambda j, gi: (gi, j)),
-            pl.BlockSpec((1, bn), lambda j, gi: (gi, j)),
+        functools.partial(kernel, gs=gs),
+        grid=(m // bm, n // bn, g // gs),
+        in_specs=[x_spec] * len(xs) + [
+            pl.BlockSpec((bk, bn), lambda i, j, gi: (gi, j)),
+            pl.BlockSpec((gs, bn), lambda i, j, gi: (gi, j)),
         ],
-        out_specs=pl.BlockSpec((m, bn), lambda j, gi: (0, j)),
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, gi: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, qw, scale)
+    )(*xs, qw, scale)
     return out.astype(x.dtype)
 
 

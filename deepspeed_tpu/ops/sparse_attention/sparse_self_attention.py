@@ -18,9 +18,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.flash_attention import (NEG_INF, _apply_causal_mask,
-                                                      _interpret_default)
+from deepspeed_tpu.ops.pallas import backend
+from deepspeed_tpu.ops.pallas.flash_attention import NEG_INF, _apply_causal_mask
 from deepspeed_tpu.ops.sparse_attention.sparsity_config import SparsityConfig
 
 
@@ -51,9 +52,15 @@ def layout_index_lists(layout: np.ndarray):
 # ---------------------------------------------------------------------------
 # kernels (BHLD, block == layout block)
 # ---------------------------------------------------------------------------
+def _row(n_rows):
+    """This grid step's (head, block-row) position in the flat index tables."""
+    return pl.program_id(1) * n_rows + pl.program_id(2)
+
+
 def _sp_fwd_kernel(kidx_ref, kcnt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                   scale, causal, blk):
+                   scale, causal, blk, n_rows, max_n):
     qi = pl.program_id(2)
+    row = _row(n_rows)
     q = q_ref[...].astype(jnp.float32) * scale
     m0 = jnp.full((blk,), NEG_INF, jnp.float32)
     l0 = jnp.zeros((blk,), jnp.float32)
@@ -61,7 +68,7 @@ def _sp_fwd_kernel(kidx_ref, kcnt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
     def body(t, carry):
         m, l, acc = carry
-        j = kidx_ref[t]
+        j = kidx_ref[row * max_n + t]
         k = k_ref[pl.ds(j * blk, blk), :].astype(jnp.float32)
         v = v_ref[pl.ds(j * blk, blk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -77,22 +84,23 @@ def _sp_fwd_kernel(kidx_ref, kcnt_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         return m_new, l_new, acc_new
 
     # traced upper bound: dead blocks are never visited
-    m, l, acc = jax.lax.fori_loop(0, kcnt_ref[0], body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(0, kcnt_ref[row], body, (m0, l0, acc0))
     l_safe = jnp.maximum(l, 1e-37)
     o_ref[...] = (acc / l_safe[:, None]).astype(o_ref.dtype)
     lse_ref[...] = jnp.where(l > 0, m + jnp.log(l_safe), NEG_INF)[:, None]
 
 
 def _sp_bwd_dq_kernel(kidx_ref, kcnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                      delta_ref, dq_ref, *, scale, causal, blk):
+                      delta_ref, dq_ref, *, scale, causal, blk, n_rows, max_n):
     qi = pl.program_id(2)
+    row = _row(n_rows)
     q = q_ref[...].astype(jnp.float32) * scale
     do = do_ref[...].astype(jnp.float32)
     lse = lse_ref[...][:, 0]
     delta = delta_ref[...][:, 0]
 
     def body(t, dq):
-        j = kidx_ref[t]
+        j = kidx_ref[row * max_n + t]
         k = k_ref[pl.ds(j * blk, blk), :].astype(jnp.float32)
         v = v_ref[pl.ds(j * blk, blk), :].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
@@ -103,19 +111,20 @@ def _sp_bwd_dq_kernel(kidx_ref, kcnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         ds = p * (dp - delta[:, None])
         return dq + jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    dq = jax.lax.fori_loop(0, kcnt_ref[0], body, jnp.zeros(q.shape, jnp.float32))
+    dq = jax.lax.fori_loop(0, kcnt_ref[row], body, jnp.zeros(q.shape, jnp.float32))
     dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _sp_bwd_dkv_kernel(qidx_ref, qcnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                       delta_ref, dk_ref, dv_ref, *, scale, causal, blk):
+                       delta_ref, dk_ref, dv_ref, *, scale, causal, blk, n_rows, max_n):
     ki = pl.program_id(2)
+    row = _row(n_rows)
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
 
     def body(t, carry):
         dk, dv = carry
-        i = qidx_ref[t]
+        i = qidx_ref[row * max_n + t]
         q = q_ref[pl.ds(i * blk, blk), :].astype(jnp.float32) * scale
         do = do_ref[pl.ds(i * blk, blk), :].astype(jnp.float32)
         lse = lse_ref[pl.ds(i * blk, blk), 0]
@@ -132,39 +141,51 @@ def _sp_bwd_dkv_kernel(qidx_ref, qcnt_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
     dk0 = jnp.zeros(k.shape, jnp.float32)
     dv0 = jnp.zeros(v.shape, jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, qcnt_ref[0], body, (dk0, dv0))
+    dk, dv = jax.lax.fori_loop(0, qcnt_ref[row], body, (dk0, dv0))
     dk_ref[...] = dk.astype(dk_ref.dtype)
     dv_ref[...] = dv.astype(dv_ref.dtype)
 
 
-def _idx_specs(max_n):
-    return [
-        pl.BlockSpec((None, None, max_n), lambda bi, hi, qi: (hi, qi, 0)),
-        pl.BlockSpec((None, None, 1), lambda bi, hi, qi: (hi, qi, 0)),
-    ]
+def _sp_call(kernel, idx, cnt, operands, in_specs, out_specs, out_shape,
+             grid, interpret, **static):
+    """One pallas_call whose per-(head, block-row) active-block table is
+    scalar-prefetched into SMEM, flat: the kernels read ``idx[row * max_n
+    + t]`` and ``cnt[row]`` as scalars to bound and steer their loops."""
+    n_rows, max_n = idx.shape[1], idx.shape[2]
+    return pl.pallas_call(
+        functools.partial(kernel, n_rows=n_rows, max_n=max_n, **static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=in_specs, out_specs=out_specs),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(idx.reshape(-1), cnt.reshape(-1), *operands)
+
+
+def _blk_spec(blk, last):
+    """This grid step's own [blk, last] block of a [B, H, L, last] array."""
+    return pl.BlockSpec((None, None, blk, last),
+                        lambda bi, hi, i, *_: (bi, hi, i, 0))
+
+
+def _full_spec(l, last):
+    """The whole length of a [B, H, L, last] array for this (b, h)."""
+    return pl.BlockSpec((None, None, l, last),
+                        lambda bi, hi, i, *_: (bi, hi, 0, 0))
 
 
 def _sp_fwd(q, k, v, kidx, kcnt, scale, causal, blk, interpret):
     b, h, l, d = q.shape
-    grid = (b, h, l // blk)
-    o, lse = pl.pallas_call(
-        functools.partial(_sp_fwd_kernel, scale=scale, causal=causal, blk=blk),
-        grid=grid,
-        in_specs=_idx_specs(kidx.shape[-1]) + [
-            pl.BlockSpec((None, None, blk, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, l, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, l, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, blk, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, blk, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        ],
+    o, lse = _sp_call(
+        _sp_fwd_kernel, kidx, kcnt, (q, k, v),
+        in_specs=[_blk_spec(blk, d), _full_spec(l, d), _full_spec(l, d)],
+        out_specs=[_blk_spec(blk, d), _blk_spec(blk, 1)],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, l, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, l, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(kidx, kcnt, q, k, v)
+        grid=(b, h, l // blk), interpret=interpret,
+        scale=scale, causal=causal, blk=blk)
     return o, lse
 
 
@@ -173,44 +194,25 @@ def _sp_bwd(res, g, scale, causal, blk, interpret):
     b, h, l, d = q.shape
     do = g
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(axis=-1, keepdims=True)
+    static = dict(grid=(b, h, l // blk), interpret=interpret,
+                  scale=scale, causal=causal, blk=blk)
 
-    dq = pl.pallas_call(
-        functools.partial(_sp_bwd_dq_kernel, scale=scale, causal=causal, blk=blk),
-        grid=(b, h, l // blk),
-        in_specs=_idx_specs(kidx.shape[-1]) + [
-            pl.BlockSpec((None, None, blk, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, l, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, l, d), lambda bi, hi, qi: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, blk, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, blk, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
-            pl.BlockSpec((None, None, blk, 1), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, blk, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(kidx, kcnt, q, k, v, do, lse, delta)
+    dq = _sp_call(
+        _sp_bwd_dq_kernel, kidx, kcnt, (q, k, v, do, lse, delta),
+        in_specs=[_blk_spec(blk, d), _full_spec(l, d), _full_spec(l, d),
+                  _blk_spec(blk, d), _blk_spec(blk, 1), _blk_spec(blk, 1)],
+        out_specs=_blk_spec(blk, d),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype), **static)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_sp_bwd_dkv_kernel, scale=scale, causal=causal, blk=blk),
-        grid=(b, h, l // blk),
-        in_specs=_idx_specs(qidx.shape[-1]) + [
-            pl.BlockSpec((None, None, l, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, blk, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((None, None, blk, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((None, None, l, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, l, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((None, None, l, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, None, blk, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((None, None, blk, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-        ],
+    dk, dv = _sp_call(
+        _sp_bwd_dkv_kernel, qidx, qcnt, (q, k, v, do, lse, delta),
+        in_specs=[_full_spec(l, d), _blk_spec(blk, d), _blk_spec(blk, d),
+                  _full_spec(l, d), _full_spec(l, 1), _full_spec(l, 1)],
+        out_specs=[_blk_spec(blk, d), _blk_spec(blk, d)],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        interpret=interpret,
-    )(qidx, qcnt, q, k, v, do, lse, delta)
+        ], **static)
     return dq, dk, dv, None, None, None, None
 
 
@@ -245,7 +247,7 @@ def sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = backend.interpret_default()
     kidx, kcnt, qidx, qcnt = layout_index_lists(layout)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     o = _sparse_attention_bhld(qt, kt, vt, jnp.asarray(kidx), jnp.asarray(kcnt),

@@ -30,10 +30,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.ops.transformer.attention import register_backend
 from deepspeed_tpu.parallel.topology import BATCH_AXES, SEQUENCE_AXIS, TENSOR_AXIS, get_topology

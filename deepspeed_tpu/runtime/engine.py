@@ -273,7 +273,7 @@ class DeepSpeedEngine:
         if self.monitor.enabled:
             self.telemetry.subscribe(self.monitor.write_events)
         # DS_TRACE_STEPS=<start>[:<count>]: cadenced XLA device-trace
-        # capture into the telemetry run dir (jax_compat.profiler_start_trace
+        # capture into the telemetry run dir (jax.profiler.start_trace
         # via _maybe_trace_window) — the env wins over any trace_profiler
         # config block, the A/B lever for one-off captures
         _trace_spec = parse_trace_steps(os.environ.get("DS_TRACE_STEPS"))
@@ -397,8 +397,7 @@ class DeepSpeedEngine:
         params/opt state (stage 0) on a pure-DP multi-device mesh without
         MoE/offload.
 
-        A model-parallel mesh RAISES instead of degrading (VERDICT r3 weak
-        #8): the reference's cupy backends have the same pure-DP scope, and
+        A model-parallel mesh RAISES instead of degrading: the reference's cupy backends have the same pure-DP scope, and
         a user asking for 1-bit wire compression on a TP/pipe mesh would
         otherwise silently train with dense collectives — paying full wire
         bytes while believing they bought the 32x compression."""
@@ -1734,14 +1733,11 @@ class DeepSpeedEngine:
         # tensor axes compose: the qcomm shard_map is manual over (data,
         # fsdp) only and GSPMD keeps owning the TP collectives inside
         # (qcomm.py axis_names); pipe/expert/sequence still fall back
+        # (TP composes through qcomm's partial-manual shard_map: tensor stays
+        # an automatic axis)
         dp_compat = all(self.mesh.shape[a] == 1 for a in ("pipe", "sequence", "expert"))
-        # TP composes through qcomm's partial-manual shard_map (tensor stays
-        # an automatic axis) — only when the jax runtime supports live auto
-        # axes inside manual regions (jax_compat shims can't emulate it)
-        from deepspeed_tpu.utils import jax_compat
-        tp_compat = self.mesh.shape["tensor"] == 1 or jax_compat.PARTIAL_MANUAL_OK
         dp_world = self.mesh.shape["data"] * self.mesh.shape["fsdp"]
-        self._use_qcomm = (want_qcomm and dp_compat and tp_compat and dp_world > 1
+        self._use_qcomm = (want_qcomm and dp_compat and dp_world > 1
                            and not has_moe
                            and not getattr(self, "_offload_enabled", False)
                            and not getattr(self, "_param_offload_enabled", False))
@@ -2445,8 +2441,11 @@ class DeepSpeedEngine:
         if (not getattr(self, "_trace_active", False)
                 and step < tc.start_step + tc.num_steps
                 and step + n_steps > tc.start_step):
-            from deepspeed_tpu.utils.jax_compat import profiler_start_trace
-            profiler_start_trace(tc.output_dir, tc.host_tracer_level, tc.python_tracer)
+            import jax.profiler
+            opts = jax.profiler.ProfileOptions()
+            opts.host_tracer_level = tc.host_tracer_level
+            opts.python_tracer_level = 1 if tc.python_tracer else 0
+            jax.profiler.start_trace(tc.output_dir, profiler_options=opts)
             self._trace_active = True
             self.telemetry.emit("xla_trace", phase="start", step=step,
                                 output_dir=tc.output_dir)
@@ -2463,7 +2462,7 @@ class DeepSpeedEngine:
             log_dist(f"XLA trace capture stopped after step {step - 1}")
 
     def _post_step(self, metrics):
-        # metric semantics note (VERDICT r2 weak #4): during a 1-bit/0-1 Adam
+        # metric semantics note: during a 1-bit/0-1 Adam
         # compression phase there IS no globally-reduced gradient, so
         # "grad_norm" carries the compressed-update norm instead (the step
         # functions also emit it under the explicit key) — reference 1-bit
@@ -2715,16 +2714,9 @@ class DeepSpeedEngine:
             # ranks save now), or ranks enter the collective save at
             # different steps and deadlock. Armed multi-host runs pay one
             # small host allgather per boundary for this.
-            try:
-                from jax.experimental import multihost_utils
-                requested = bool(np.any(multihost_utils.process_allgather(
-                    np.asarray(requested))))
-            except Exception as e:  # noqa: BLE001 — no host collectives (old CPU jaxlib)
-                if not getattr(self, "_warned_preempt_sync", False):
-                    self._warned_preempt_sync = True
-                    logger.warning(f"preemption flag cannot be synchronized across "
-                                   f"processes ({e}); falling back to local signals — "
-                                   f"deliver the signal to every host")
+            from jax.experimental import multihost_utils
+            requested = bool(np.any(multihost_utils.process_allgather(
+                np.asarray(requested))))
         if not requested:
             return
         sig = g.consume() or "peer-rank signal"

@@ -1,7 +1,7 @@
 """Fault-tolerant training subsystem.
 
 Production TPU fleets live with preemption, host crashes, and flaky
-tunnels as the steady state; this package is the layer that lets a fleet
+networks as the steady state; this package is the layer that lets a fleet
 lose a host and keep training:
 
 * :mod:`manifest` — per-leaf checksum + shape/dtype manifests and file
@@ -12,10 +12,10 @@ lose a host and keep training:
   lost run.
 * :mod:`retry` — shared exponential-backoff-with-jitter policy with a
   per-attempt evidence log, wrapped around the flaky pieces of the
-  tooling (remote compile helper, chip probes).
+  tooling (backend start-up, chip probes).
 * :mod:`faults` — deterministic fault injection by class (SIGKILL at a
   step boundary, torn saves, truncated/bit-flipped checkpoint files,
-  persistent-overflow gradients, transient compile-helper 500s) so the
+  persistent-overflow gradients, a transiently unavailable backend) so the
   documented recovery behavior is *tested*, not assumed
   (``tools/fault_bench.py`` runs the full matrix).
 """

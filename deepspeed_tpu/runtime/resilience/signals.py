@@ -42,7 +42,7 @@ class PreemptionGuard:
     A SECOND SIGINT while a request is already pending escalates: the
     previous handlers are restored and ``KeyboardInterrupt`` is raised
     immediately — pressing Ctrl-C twice always gets you out of a process
-    stuck off the step boundary (wedged compile, hung collective).
+    stuck off the step boundary (stuck compile, hung collective).
     """
 
     def __init__(self, signals: Sequence[str] = ("SIGTERM", "SIGINT")):
@@ -58,7 +58,7 @@ class PreemptionGuard:
         # a process that was mid-dispatch when the signal landed
         if self._requested is not None and signum == signal.SIGINT:
             # escalation escape hatch: a SECOND Ctrl-C while a request is
-            # already pending means the step boundary never came (wedged
+            # already pending means the step boundary never came (stuck
             # compile, hung collective) — restore the previous handlers and
             # interrupt NOW rather than swallowing Ctrl-C forever
             self.uninstall()

@@ -16,7 +16,7 @@ Three pillars (ISSUE 13):
    ``tools/trace_report.py`` turns the timeline into Chrome trace-event
    JSON. ``DS_TRACE_STEPS=<start>:<count>`` additionally opens a cadenced
    ``jax.profiler`` device-trace window into the same run directory
-   (wired by the engine through ``jax_compat.profiler_start_trace``).
+   (wired by the engine through ``jax.profiler.start_trace``).
 3. **Drift** — each window closes with a ``drift`` event: achieved
    TFLOPS (predicted ``flops_proxy`` ÷ measured median step time) and
    predicted-vs-measured memory ratios (device ``memory_stats`` peaks

@@ -1,42 +1,25 @@
 """Device-placement helpers.
 
-``owned_device_put`` exists because of a CPU-backend hazard in older
-jaxlib (observed on 0.4.37): ``jax.device_put`` of host data (numpy
-arrays, orbax-restored tensorstore views) can be ZERO-COPY — the
-resulting jax.Array aliases memory jax does not own. Donating such an
-array into a jitted step makes XLA free foreign memory: glibc
-"corrupted double-linked list" / segfaults several dispatches later, or
-silent garbage in small scalars. Any host-originated tree that will be
-DONATED (TrainState after checkpoint restore, externally built params)
-must come through here: the non-donating jitted copy forces XLA to
-materialize fresh, runtime-owned buffers.
+``owned_device_put`` is the one seam through which host-originated trees
+that will be DONATED (TrainState after checkpoint restore, externally
+built params) reach the device; graft-lint R008 keeps raw
+``jax.device_put`` out of the rest of the package.
+
+On the CPU backend ``jax.device_put`` of 64-byte-aligned host data is
+zero-copy: the jax.Array aliases memory jax does not own. Such a buffer
+is not donatable — a jitted step that donates it writes its outputs to
+fresh, runtime-owned buffers and leaves the host memory alone (checked on
+jaxlib 0.9.0: aliased arrays of every size donated through 30 steps, host
+copies dropped, no write-through and no heap damage) — so no defensive
+copy is made here.
 """
 
 import jax
-import jax.numpy as jnp
 
 
 def owned_device_put(tree, shardings=None):
-    """``device_put`` whose results are guaranteed runtime-owned buffers.
+    """``device_put`` of a host tree that a jitted step will donate.
 
     ``shardings``: optional pytree of shardings (same treedef), forwarded
-    to ``device_put`` and pinned on the jitted copy's outputs so the
-    placement survives the copy."""
-    placed = jax.device_put(tree, shardings) if shardings is not None else jax.device_put(tree)
-    if jax.default_backend() != "cpu":
-        # the zero-copy alias only exists when device memory IS host
-        # memory; TPU/GPU device_put crosses PCIe into runtime-owned HBM,
-        # and the extra jitted copy would double peak memory (a full
-        # TrainState restore can't afford a second resident copy)
-        return placed
-
-    def copy(t):
-        # add-zero instead of bare identity: jit of a no-op identity can
-        # short-circuit to the input buffer; arithmetic forces a write
-        return jax.tree.map(
-            lambda x: x + jnp.zeros((), x.dtype) if jnp.issubdtype(x.dtype, jnp.number)
-            else jnp.logical_or(x, False) if x.dtype == bool else x,
-            t)
-
-    fn = jax.jit(copy) if shardings is None else jax.jit(copy, out_shardings=shardings)
-    return fn(placed)
+    to ``device_put``."""
+    return jax.device_put(tree, shardings)

@@ -176,7 +176,9 @@ class TestR011:
     def test_clean_on_carry_dependent_collective_in_scan(self):
         def f(x):
             def body(c, _):
-                return jax.lax.psum(c, "x") * 0.5, None  # carry-derived
+                # carry-derived; the reduced value re-enters the carry as a
+                # varying one (jax 0.9 types carries by their manual axes)
+                return jax.lax.pcast(jax.lax.psum(c, "x"), "x", to="varying") * 0.5, None
             out, _ = jax.lax.scan(body, x, None, length=4)
             return out
 

@@ -71,7 +71,7 @@ class TestR001:
 # ---------------------------------------------------------------------------
 class TestR002:
     def test_fires_on_float64(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64():
             jx = _jaxpr(lambda x: x.astype(jnp.float64).sum(), jnp.ones(4, jnp.float32))
         fs = check_program(jx, rules=["R002"])
         assert any(f.severity == ERROR and "float64" in f.message for f in fs)

@@ -44,10 +44,8 @@ def test_matrix_builds_expected_scenarios(matrix):
                 "pipe_chunked_step", "pipe_1f1b_step", "serve_decode_step",
                 "rlhf_rollout_step"}
     assert expected <= set(programs) | set(skipped)
-    # the pipe pipe*data*fsdp scenario is allowed to skip on the 0.4.37
-    # container (the known partial-manual shard_map gap) and the
-    # 16-device composition on an 8-device runtime — never to silently
-    # vanish: the skip reasons inventory the gaps
+    # the 16-device composition is allowed to skip on an 8-device runtime
+    # — never to silently vanish: the skip reasons inventory the gaps
     for gap in ("pipe_scan_step", "composition_3d_ep_zeropp"):
         assert gap in set(programs) | set(skipped)
 
@@ -140,14 +138,11 @@ def test_composition_blocking_gap_ratchet():
     device-count -> partial-manual -> moe-in-pipe -> none. The
     device-count link is burned down (a <16-device run probes the
     16-virtual-device build in a subprocess and reports the gap behind
-    it), so the floor is now partial-manual on the pinned container and
-    moe-in-pipe on modern jax — TIGHTER than the PR-12 floor, on every
-    runtime, regardless of the ambient device count."""
+    it), so the floor is now moe-in-pipe — TIGHTER than the PR-12 floor,
+    regardless of the ambient device count."""
     from deepspeed_tpu.analysis.scenarios import (COMPOSITION_GAP_ORDER,
                                                   composition_blocking_gap,
                                                   composition_gap_rank)
-    from deepspeed_tpu.utils.jax_compat import PARTIAL_MANUAL_OK
-
     import pytest
 
     gap = composition_blocking_gap()
@@ -157,10 +152,10 @@ def test_composition_blocking_gap_ratchet():
         # the probe itself cannot run (resource-starved, fork-limited) is
         # an environment problem, not a burn-down regression
         pytest.skip(f"16-device composition probe failed on this rig: {gap}")
-    floor = "partial_manual" if not PARTIAL_MANUAL_OK else "moe_in_pipe"
+    floor = "moe_in_pipe"
     assert composition_gap_rank(gap["kind"]) >= composition_gap_rank(floor), (
-        f"composition gap regressed backward: {gap} (floor on this "
-        f"runtime: {floor})")
+        f"composition gap regressed backward: {gap} (floor: "
+        f"{floor})")
 
 
 def test_dense_env_route_fires_r001_through_scenarios(monkeypatch):

@@ -201,7 +201,7 @@ def test_model_factory_overrides(tmp_path):
 
 
 def test_mesh_axis_search_picks_tensor_when_pure_dp_ooms(tmp_path):
-    """VERDICT r3 #9: with a memory budget pure-DP cannot meet at any
+    """With a memory budget pure-DP cannot meet at any
     micro-batch, the tuner must explore the tensor axis and pick a
     non-trivial (stage, mbs, tensor) candidate that fits."""
     cfg = get_gpt2_config("test", n_layer=2, n_embd=128, n_head=4)

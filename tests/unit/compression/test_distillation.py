@@ -72,14 +72,6 @@ def test_distillation_end_to_end_loss_decreases_and_gates_observed():
     seeds the student, the KD terms activate at schedule_offset (observed:
     pre-offset steps match a no-teacher run bitwise; post-offset steps
     diverge), and the distillation loss decreases."""
-    from deepspeed_tpu.utils.jax_compat import PARTIAL_MANUAL_OK
-    if not PARTIAL_MANUAL_OK:
-        # env-bound: on jax 0.4.37 the XLA:CPU runtime intermittently
-        # corrupts the heap dispatching the KD train step (two models +
-        # capture_intermediates + donated state) — pass/hang/segfault vary
-        # run to run and a segfault kills the whole tier-1 process. The KD
-        # numerics themselves are covered by the non-dispatching tests.
-        pytest.skip("KD train-step dispatch is unstable on this jax/XLA (CPU)")
     t_module, t_params, _ = _teacher()
     kd_block = {"compression_training": {
         **LR_BLOCK,

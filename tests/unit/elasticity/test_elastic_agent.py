@@ -19,7 +19,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.a
 # fixed global batch (any ladder size divides it), per-step deterministic
 # data, checkpoint + heartbeat every step. Failure injection:
 #   CRASH_AT_STEP  — os._exit(1) before that step completes (first launch only)
-#   HANG_AT_STEP   — stop heartbeating and sleep (wedge simulation)
+#   HANG_AT_STEP   — stop heartbeating and sleep (hang simulation)
 CHILD = textwrap.dedent("""
     import json, os, sys, time
     world = int(os.environ["DS_ELASTIC_WORLD_SIZE"])
@@ -27,7 +27,7 @@ CHILD = textwrap.dedent("""
     sys.path.insert(0, __REPO__)
     import jax
     jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_compilation_cache_dir", os.path.join(__REPO__, ".jax_cache"))
+    from envutil import use_compile_cache; use_compile_cache()
     import numpy as np, jax.numpy as jnp
     import deepspeed_tpu
     from deepspeed_tpu.elasticity.elastic_agent import touch_heartbeat
@@ -52,7 +52,7 @@ CHILD = textwrap.dedent("""
     while eng.global_steps < total_steps:
         step = eng.global_steps
         if step == hang_at:
-            time.sleep(600)  # wedged backend: heartbeat goes silent
+            time.sleep(600)  # hung backend: heartbeat goes silent
         rng = np.random.RandomState(1000 + step)
         batch = {"input_ids": rng.randint(0, cfg.vocab_size, (8, 16)).astype(np.int32)}
         loss = float(jnp.asarray(eng.train_batch(batch)))
@@ -119,7 +119,7 @@ def test_crash_recovery_resumes_at_new_world_size(tmp_path):
 
 
 def test_hang_detection_kills_and_restarts(tmp_path):
-    """Heartbeat silence (the wedge signature) is a failure: the hung child
+    """Heartbeat silence (the hang signature) is a failure: the hung child
     is killed and the job restarts at the next world size and completes."""
     rc, agent, rows = _run_agent(tmp_path, {"HANG_AT_STEP": "1"}, [4, 2],
                                  heartbeat_timeout=30.0)

@@ -197,13 +197,13 @@ def test_router_death_readmits_orphans_on_peer():
 
 def test_router_heartbeat_staleness_counts_as_death():
     """A replica that still has a live process but a stale heartbeat is
-    wedged (stuck dispatch) — the router must treat it as dead."""
+    hung (stuck dispatch) — the router must treat it as dead."""
     router = FleetRouter(heartbeat_timeout=5.0)
-    wedged = StubReplica()
-    wedged.heartbeat_age = lambda: 60.0  # way past the timeout
+    hung = StubReplica()
+    hung.heartbeat_age = lambda: 60.0  # way past the timeout
     fresh = StubReplica(load=2.0)
     fresh.heartbeat_age = lambda: 0.1
-    router.add_replica("wedged", wedged)
+    router.add_replica("hung", hung)
     router.add_replica("fresh", fresh)
     assert list(router.alive_replicas()) == ["fresh"]
     rid = router.submit(np.arange(3, dtype=np.int32), 4)

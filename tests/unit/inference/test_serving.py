@@ -1,5 +1,4 @@
-"""Serving-path tests: bucketed compile reuse (VERDICT r2 'decode path'
-item) and the paged KV cache (reference ``inference_context.h`` workspace)."""
+"""Serving-path tests: bucketed compile reuse and the paged KV cache (reference ``inference_context.h`` workspace)."""
 
 import numpy as np
 import pytest

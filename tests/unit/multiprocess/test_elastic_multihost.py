@@ -20,8 +20,7 @@ import textwrap
 import numpy as np
 import pytest
 
-from tests.unit.multiprocess.common import (REPO, WORKER, free_port,
-                                            require_multiprocess_backend)
+from tests.unit.multiprocess.common import REPO, WORKER, free_port
 
 GANG_RUNNER = textwrap.dedent("""
     import json, os, socket, subprocess, sys
@@ -66,7 +65,6 @@ def _read_losses(path):
 
 @pytest.mark.parametrize("crash_at", [2])
 def test_two_process_gang_death_resumes_single_process(tmp_path, crash_at):
-    require_multiprocess_backend()
     from deepspeed_tpu.elasticity.elastic_agent import DSElasticAgent
 
     runner = tmp_path / "gang_runner.py"

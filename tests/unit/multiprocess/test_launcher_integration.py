@@ -15,8 +15,7 @@ import os
 import subprocess
 import sys
 
-from tests.unit.multiprocess.common import (REPO, _last_json_line, free_port,
-                                            require_multiprocess_backend)
+from tests.unit.multiprocess.common import REPO, _last_json_line, free_port
 
 LAUNCH = os.path.join(REPO, "deepspeed_tpu", "launcher", "launch.py")
 
@@ -42,7 +41,6 @@ print(json.dumps({{"rank": rank, "world": world, "ndev": jax.device_count(),
 
 
 def test_launcher_bootstraps_two_node_local_job(tmp_path):
-    require_multiprocess_backend()
     script = tmp_path / "user_script.py"
     script.write_text(USER_SCRIPT.format(repo=REPO))
     sys.path.insert(0, REPO)

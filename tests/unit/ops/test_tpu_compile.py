@@ -1,0 +1,227 @@
+"""AOT compiles for a described TPU v5e — the only file that does this.
+
+The TPU's compiler is installed next to the CPU backend and compiles for
+a chip that is described, not attached. Interpret mode cannot see what
+Mosaic refuses (block shapes against the (8, 128) tiling, single-row
+slices, scoped VMEM) or what GSPMD refuses (an unpartitionable kernel on
+a mesh), so the kernels of the main paths compile here at GPT-2 350M
+widths, plus one serving tick and one four-device ZeRO-3 step at reduced
+depth. Nothing runs: a compile that passes is not a chip run.
+
+Only one process may load the TPU's library, and it keeps it until it
+exits: the topology is described inside a module-scoped fixture (never at
+import or collection), every compile happens in the test's own process,
+and no other test file may do the same.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+B, L, H, D, E = 8, 1024, 16, 64, 1024  # micro-batch, positions, heads, head dim, width
+SLOTS, CHUNK = 8, 16
+bf16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the package steered to what it does on a
+    TPU (compiled kernels, "auto" = pallas) and the persistent compile
+    cache off: an executable for a described device is written to the
+    cache but cannot be read back without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from deepspeed_tpu.ops.pallas import backend
+    from deepspeed_tpu.parallel.topology import get_topology, set_topology
+
+    on_tpu, topology = backend.on_tpu, get_topology()
+    backend.on_tpu = lambda: True
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    set_topology(None)
+    yield SingleDeviceSharding(topo.devices[0])
+    backend.on_tpu = on_tpu
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+    set_topology(topology)
+
+
+def _compile(fn, sharding, *shapes, **jit_kwargs):
+    """Compile ``fn`` for the described chip; returns the compiled HLO."""
+    args = [jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding), a)
+            for a in shapes]
+    return jax.jit(fn, **jit_kwargs).lower(*args).compile()
+
+
+def _shape(*dims, dtype=bf16):
+    return jax.ShapeDtypeStruct(dims, dtype)
+
+
+def _kernel_text(compiled):
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled program"
+    return text
+
+
+def _sq_grads(fn):
+    return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum(), argnums=(0, 1, 2))
+
+
+# ---------------------------------------------------------------------------
+# kernels at 350M widths
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_flash_attention_compiles(one_chip, backward):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    qkv = _shape(B, L, H, D)
+    _kernel_text(_compile(_sq_grads(fn) if backward else fn, one_chip, qkv, qkv, qkv))
+
+
+@pytest.mark.parametrize("lq", [1, CHUNK], ids=["decode_tick", "prefill_chunk"])
+def test_flash_decode_compiles(one_chip, lq):
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_decode
+    cache = _shape(SLOTS, L, H, D)
+    _kernel_text(_compile(flash_decode, one_chip, _shape(SLOTS, lq, H, D), cache, cache,
+                          _shape(SLOTS, dtype=jnp.int32)))
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("dtype", [bf16, jnp.float32], ids=["bf16", "fp32"])
+def test_moe_permute_compiles(one_chip, backward, dtype):
+    from deepspeed_tpu.ops.pallas.moe_dispatch import permute_rows
+    tokens, slots = B * L, B * L * 5 // 4
+
+    def fn(x, fwd_idx, bwd_idx):
+        return permute_rows(x, fwd_idx, bwd_idx, impl="pallas")
+
+    if backward:
+        fn = jax.grad(lambda x, f, b, fn=fn: fn(x, f, b).astype(jnp.float32).sum())
+    _kernel_text(_compile(fn, one_chip, _shape(1, tokens, E, dtype=dtype),
+                          _shape(1, slots, dtype=jnp.int32),
+                          _shape(1, tokens, dtype=jnp.int32)))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("m,k,n", [(SLOTS, E, 3 * E), (SLOTS * CHUNK, E, 4 * E), (SLOTS, 4 * E, E)],
+                         ids=["qkv_decode", "mlp_in_prefill", "mlp_out_decode"])
+def test_quant_matmul_compiles(one_chip, bits, m, k, n):
+    from deepspeed_tpu.ops.pallas.quant_matmul import quant_matmul, resolve_impl
+    assert resolve_impl("auto") == "pallas"
+
+    def fn(x, codes, scale):
+        return quant_matmul(x, codes, scale, bits=bits)
+
+    _kernel_text(_compile(fn, one_chip, _shape(m, k),
+                          _shape(k * bits // 8, n, dtype=jnp.int8),
+                          _shape(k // 64, n, dtype=jnp.float32)))
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_block_sparse_attention_compiles(one_chip, backward):
+    from deepspeed_tpu.ops.sparse_attention.sparse_self_attention import sparse_attention
+    from deepspeed_tpu.ops.sparse_attention.sparsity_config import FixedSparsityConfig
+    block = 64
+    layout = FixedSparsityConfig(num_heads=H, block=block, num_local_blocks=4,
+                                 num_global_blocks=1,
+                                 attention="unidirectional").make_layout(L)
+
+    def fn(q, k, v):
+        return sparse_attention(q, k, v, layout, block, causal=True)
+
+    qkv = _shape(2, L, H, D)
+    _kernel_text(_compile(_sq_grads(fn) if backward else fn, one_chip, qkv, qkv, qkv))
+
+
+# ---------------------------------------------------------------------------
+# whole programs at 350M width, reduced depth
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+@pytest.mark.parametrize("attention", ["xla", "flash"])
+def test_serving_program_compiles(one_chip, program, attention):
+    """The scheduler's chunked prefill and decode tick at 8 slots over an
+    int8 KV cache, as ``serve_programs`` jits them."""
+    import flax.linen as nn
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step,
+                                                          make_apply_fn, make_slot_cache)
+    from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+
+    module = GPT2LMHeadModel(get_gpt2_config("350m", n_layer=2, dtype=None,
+                                             attention_backend=attention))
+    params = jax.eval_shape(
+        lambda key: nn.meta.unbox(module.init(key, jnp.zeros((1, 8), jnp.int32))["params"]),
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, SLOTS, kv_quant=True))
+    apply_fn = make_apply_fn(module)
+    if program == "prefill":
+        step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(SLOTS, CHUNK, dtype=jnp.int32), _shape(SLOTS, dtype=jnp.int32))
+    else:
+        step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(SLOTS, dtype=jnp.int32),)
+    compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
+    assert ("tpu_custom_call" in compiled.as_text()) == (attention == "flash")
+
+
+def _train_engine(devices, zero_stage, fsdp):
+    import deepspeed_tpu
+    from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+    from deepspeed_tpu.parallel.topology import MeshTopology
+
+    cfg = get_gpt2_config("350m", n_layer=2, n_positions=L, remat=True,
+                          attention_backend="flash", dtype=bf16, vocab_size=50304,
+                          embed_onehot_grad=True, fused_head_loss_chunk=1024)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=GPT2LMHeadModel(cfg),
+        topology=MeshTopology(fsdp=fsdp, data=1, devices=devices),
+        config={"train_batch_size": B,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-4, "weight_decay": 0.01}},
+                "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+                "zero_optimization": {"stage": zero_stage}, "steps_per_print": 10**9})
+    return engine, {"input_ids": np.zeros((B, L), np.int32)}
+
+
+def test_train_step_compiles_on_one_chip(one_chip, topo):
+    from deepspeed_tpu.parallel.topology import set_topology
+    try:
+        engine, batch = _train_engine(topo.devices[:1], zero_stage=0, fsdp=1)
+        lowered = engine.lower_train_step(batch)
+        assert "tpu_custom_call" in lowered.as_text()  # flash, not its XLA fallback
+        compiled = lowered.compile()
+    finally:
+        set_topology(None)
+    _kernel_text(compiled)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_zero3_step_compiles_over_four_chips(one_chip, topo):
+    """The flash kernel inside a GSPMD-partitioned step: Mosaic kernels
+    cannot be partitioned automatically, so this compiles only because
+    ``flash_attention`` goes manual per shard on a mesh."""
+    from deepspeed_tpu.parallel.topology import set_topology
+    try:
+        engine, batch = _train_engine(topo.devices, zero_stage=3, fsdp=4)
+        compiled = engine.lower_train_step(batch).compile()
+    finally:
+        set_topology(None)
+    text = _kernel_text(compiled)
+    assert "all-gather" in text and "reduce-scatter" in text

@@ -1,6 +1,6 @@
 """Flops profiler tests (reference ``profiling/flops_profiler/profiler.py:27``):
 enabling the config must produce a real report — no more silently-ignored
-``flops_profiler`` block (VERDICT r1 weak #12)."""
+``flops_profiler`` block."""
 
 import os
 

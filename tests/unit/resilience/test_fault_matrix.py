@@ -155,16 +155,16 @@ def test_http500_retry_matrix(tmp_path):
 
 
 def test_ladder_emits_structured_blocked_row(tmp_path, monkeypatch, capsys):
-    """A rung whose compile-helper 500 survives all retries must emit a
-    machine-readable ``blocked: compile_helper_500`` row with its retry
+    """A rung whose backend failure survives all retries must emit a
+    machine-readable ``blocked: backend_unavailable`` row with its retry
     history — never a bare error string (PERF.md §PR9 contract)."""
     import json
 
     import perf_ladder
-    from deepspeed_tpu.runtime.resilience.faults import make_compile_helper_500
+    from deepspeed_tpu.runtime.resilience.faults import make_backend_unavailable
 
     def always_500(tag, retry_evidence=None, **kw):
-        raise make_compile_helper_500()
+        raise make_backend_unavailable()
 
     monkeypatch.setattr(perf_ladder, "run_rung", always_500)
     monkeypatch.setitem(perf_ladder.RUNGS, "fake", dict(model_name="test", mb=2))
@@ -176,10 +176,10 @@ def test_ladder_emits_structured_blocked_row(tmp_path, monkeypatch, capsys):
             if l.startswith("{")]
     assert len(rows) == 1, rows
     row = rows[0]
-    assert row["blocked"] == "compile_helper_500"
+    assert row["blocked"] == "backend_unavailable"
     assert row["retries"] == 2
     assert len(row["retry_history"]) == 2
-    assert "tpu_compile_helper" in row["retry_history"][0]["error"]
+    assert "libtpu" in row["retry_history"][0]["error"]
 
 
 def test_ladder_success_after_retry_carries_evidence(tmp_path, monkeypatch, capsys):
@@ -188,14 +188,14 @@ def test_ladder_success_after_retry_carries_evidence(tmp_path, monkeypatch, caps
     import json
 
     import perf_ladder
-    from deepspeed_tpu.runtime.resilience.faults import make_compile_helper_500
+    from deepspeed_tpu.runtime.resilience.faults import make_backend_unavailable
 
     calls = {"n": 0}
 
     def flaky_rung(tag, retry_evidence=None, **kw):
         calls["n"] += 1
         if calls["n"] == 1:
-            raise make_compile_helper_500()
+            raise make_backend_unavailable()
         print(json.dumps({"tag": tag, "tflops": 1.0, **(retry_evidence or {})}), flush=True)
 
     monkeypatch.setattr(perf_ladder, "run_rung", flaky_rung)
@@ -208,7 +208,7 @@ def test_ladder_success_after_retry_carries_evidence(tmp_path, monkeypatch, caps
             if l.startswith("{")]
     assert len(rows) == 1 and rows[0]["tag"] == "fake"
     assert rows[0]["retries"] == 1
-    assert rows[0]["retry_history"][0]["error_class"] == "compile_helper_500"
+    assert rows[0]["retry_history"][0]["error_class"] == "backend_unavailable"
 
 
 # ---------------------------------------------------------------------------
@@ -258,7 +258,7 @@ def test_preempt_exit_code_distinguishes_from_success(tmp_path, _restore_signals
 
 def test_second_sigint_escalates_to_keyboard_interrupt(_restore_signals):
     """Ctrl-C twice always gets you out: with a request already pending
-    (the boundary never came — wedged compile), the second SIGINT restores
+    (the boundary never came — stuck compile), the second SIGINT restores
     the previous handlers and raises KeyboardInterrupt immediately."""
     import time
 
@@ -339,7 +339,7 @@ def test_heartbeat_throttle(tmp_path):
 
 def test_engine_step_touches_heartbeat(tmp_path, monkeypatch):
     """The train loop feeds the elastic agent's liveness signal (cadenced
-    via resilience.heartbeat_interval) — the wedge detector has a pulse."""
+    via resilience.heartbeat_interval) — the hang detector has a pulse."""
     hb = str(tmp_path / "hb")
     monkeypatch.setenv("DS_ELASTIC_HEARTBEAT_FILE", hb)
     engine, batch = fault_bench._tiny_engine(

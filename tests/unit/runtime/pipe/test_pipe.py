@@ -13,18 +13,6 @@ from deepspeed_tpu.models.gpt2 import cross_entropy_loss, gpt2_pipe_layers
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 from deepspeed_tpu.runtime.pipe import schedule as sched
 from deepspeed_tpu.runtime.pipe.module import LayerSpec, PipelineModule
-from deepspeed_tpu.utils.jax_compat import PARTIAL_MANUAL_OK
-
-# the pipe engine is manual over {pipe} only; meshes with a live
-# data/fsdp axis need partial-manual shard_map, which jax 0.4.37 lacks
-# (utils/jax_compat.py docstring). These are KNOWN-environment skips, not
-# failures — test_partial_manual_gap_is_the_documented_one below is the
-# sentinel asserting the gate still fires for the documented reason, so
-# a runtime upgrade (or a full-manual pipe refactor) un-skips loudly.
-needs_partial_manual = pytest.mark.skipif(
-    not PARTIAL_MANUAL_OK,
-    reason="jax-0.4.37 partial-manual shard_map gap (pipe mesh with live "
-           "auto axes) — see jax_compat docstring + the sentinel test")
 
 
 @pytest.fixture(autouse=True)
@@ -32,28 +20,6 @@ def _clear_topology():
     set_topology(None)
     yield
     set_topology(None)
-
-
-def test_partial_manual_gap_is_the_documented_one():
-    """Sentinel for the skip gate: on runtimes without partial-manual
-    shard_map, building the pipe step on a pipe x fsdp mesh must raise
-    the jax_compat NotImplementedError (naming the gate), not abort the
-    process or fail some other way. When PARTIAL_MANUAL_OK turns True,
-    the skipped tests above run instead and this sentinel inverts."""
-    cfg = get_gpt2_config("test", n_layer=2)
-    topo = MeshTopology(pipe=2, data=1, fsdp=4)
-    pipe = PipelineModule(layers=gpt2_pipe_layers(cfg), topology=topo)
-    engine, _, _, _ = deepspeed_tpu.initialize(
-        model=pipe, config={"train_batch_size": 8,
-                            "optimizer": {"type": "Adam", "params": {"lr": 1e-3}}},
-        topology=topo)
-    batch = {"input_ids": np.zeros((8, 32), np.int32)}
-    if PARTIAL_MANUAL_OK:
-        engine.initialize_state(batch)  # modern jax: the mesh just works
-        assert np.isfinite(float(engine.eval_batch(batch)))
-    else:
-        with pytest.raises(NotImplementedError, match="partial-manual"):
-            engine.initialize_state(batch)
 
 
 # ---------------------------------------------------------------------------
@@ -129,7 +95,6 @@ def _dense_params_from_pipe(pipe_params, n_layer):
     return dense
 
 
-@needs_partial_manual
 def test_pipeline_matches_dense_loss():
     cfg = get_gpt2_config("test", n_layer=4)
     topo = MeshTopology(pipe=2, data=2, fsdp=2)
@@ -154,7 +119,6 @@ def test_pipeline_matches_dense_loss():
     np.testing.assert_allclose(pipe_loss, dense_loss, rtol=2e-5)
 
 
-@needs_partial_manual
 def test_pipeline_trains():
     cfg = get_gpt2_config("test", n_layer=2)
     topo = MeshTopology(pipe=2, data=1, fsdp=4)
@@ -185,7 +149,6 @@ def test_pipeline_trains():
 # ---------------------------------------------------------------------------
 # tied weights + checkpointing (reference tied-layer grads, pipe ckpt tests)
 # ---------------------------------------------------------------------------
-@needs_partial_manual
 def test_tied_embedding_receives_both_gradient_paths():
     """The tied wte is used by the prologue (lookup) AND the epilogue (LM
     head). Its gradient must include both uses — zeroing the head
@@ -226,7 +189,6 @@ def test_tied_embedding_receives_both_gradient_paths():
                                np.asarray(g_dense, np.float32), atol=2e-5)
 
 
-@needs_partial_manual
 def test_pipeline_checkpoint_roundtrip(tmp_path):
     cfg = get_gpt2_config("test", n_layer=2)
     topo = MeshTopology(pipe=2, data=1, fsdp=4)
@@ -255,9 +217,8 @@ def test_pipeline_checkpoint_roundtrip(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# 4-stage pipeline (VERDICT r4 #10: nothing validated >2 stages before)
+# 4-stage pipeline (nothing validated >2 stages before)
 # ---------------------------------------------------------------------------
-@needs_partial_manual
 def test_pipeline_matches_dense_loss_4stage():
     """4 pipeline stages x fsdp, tied embeddings: eval loss must equal the
     dense model's on the same (re-assembled) weights."""
@@ -283,7 +244,6 @@ def test_pipeline_matches_dense_loss_4stage():
     np.testing.assert_allclose(pipe_loss, dense_loss, rtol=2e-5)
 
 
-@needs_partial_manual
 def test_pipeline_trains_4stage_tied_grads():
     """4-stage training decreases the loss, and the tied wte gradient (used
     by stage 0's lookup and stage 3's head — 3 stages apart) matches the

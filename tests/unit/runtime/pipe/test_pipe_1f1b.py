@@ -1,9 +1,8 @@
 """1F1B schedule tests (ROADMAP-2 / PR 11 acceptance).
 
 Everything here runs on pipe-ONLY meshes (pipe=2 or pipe=4 with every
-other axis size 1), which fold to full-manual shard_map and therefore
-execute on the pinned jax-0.4.37 container — unlike the pipe x data x
-fsdp composition tests, which are version-gated (test_pipe.py).
+other axis size 1); the pipe x data x fsdp compositions are in
+test_pipe.py.
 
 Three claims are pinned:
 

@@ -18,9 +18,8 @@ Measured on this 8-device CPU mesh (S=4, seq=128, embd=128):
 M=4 full 4.69 MB | M=16 full 10.75 MB | M=32 full 20.23 MB |
 M=16 chunk4 5.68 MB | M=32 chunk4 5.68 MB.
 
-The pipe x fsdp meshes need partial-manual shard_map (version-gated on
-the 0.4.37 container — test_pipe.py sentinel); the 1F1B law is asserted
-on a pipe-only mesh too, which folds to full-manual and runs everywhere.
+The pipe x fsdp meshes run partial-manual shard_map; the 1F1B law is
+asserted on a pipe-only mesh too.
 """
 import numpy as np
 import pytest
@@ -32,13 +31,6 @@ from deepspeed_tpu.models import get_gpt2_config
 from deepspeed_tpu.models.gpt2 import gpt2_pipe_layers
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 from deepspeed_tpu.runtime.pipe.module import PipelineModule
-from deepspeed_tpu.utils.jax_compat import PARTIAL_MANUAL_OK
-
-needs_partial_manual = pytest.mark.skipif(
-    not PARTIAL_MANUAL_OK,
-    reason="jax-0.4.37 partial-manual shard_map gap (pipe x fsdp mesh) — "
-           "see jax_compat docstring + the test_pipe.py sentinel")
-
 N_STAGES = 4
 SEQ = 128
 EMBD = 128
@@ -83,7 +75,6 @@ def _temp_bytes(engine, batch):
     return comp.memory_analysis().temp_size_in_bytes
 
 
-@needs_partial_manual
 def test_gpipe_scan_liveness_grows_with_microbatches():
     """Honest statement of the gpipe schedule's gap (now opt-in, no
     longer the default): without chunking, autodiff residuals hold one
@@ -93,7 +84,6 @@ def test_gpipe_scan_liveness_grows_with_microbatches():
     assert t32 > 2.5 * t4, (t4, t32)
 
 
-@needs_partial_manual
 def test_chunked_schedule_bounds_liveness_constant_in_m():
     """chunk_microbatches=S holds temp memory CONSTANT in M, within a fixed
     small factor of the one-wave (M=S) program — the wave-bounded
@@ -111,7 +101,6 @@ def test_chunked_schedule_bounds_liveness_constant_in_m():
     assert t16 < 0.7 * t16_full, (t16, t16_full)
 
 
-@needs_partial_manual
 def test_chunked_matches_unchunked_numerics():
     """Wave-wise accumulation is the same math: same loss (reduction-order
     tolerance) and the engine trains on."""
@@ -133,9 +122,7 @@ def test_1f1b_liveness_constant_in_m_and_below_chunked():
     """The tentpole claim, on XLA's own numbers: the 1F1B stash bound is
     CONSTANT in M (the carry is 2(S-1) slots however many microbatches
     stream through) and sits below the chunked schedule's footprint at
-    the same M. Runs on a pipe-only mesh (full-manual fold), so this
-    executes on the pinned 0.4.37 container — the law is enforced here,
-    not just on future runtimes."""
+    the same M. Runs on a pipe-only mesh."""
     t8 = _temp_bytes(*_engine(micro=8, pipe_only=True))
     t32 = _temp_bytes(*_engine(micro=32, pipe_only=True))
     # constant in M (allow compiler scheduling noise)

@@ -13,6 +13,8 @@ import time
 
 import numpy as np
 
+import jax
+
 import deepspeed_tpu
 from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
 
@@ -80,11 +82,14 @@ def test_telemetry_overhead_within_2pct(tmp_path):
     for _ in range(3):
         on_t, off_t = [], []
         for _ in range(n):
+            # both arms end in block_until_ready: dispatch is asynchronous,
+            # and a step timed without it measures only the enqueue (the
+            # telemetry-on arm syncs on the loss by design)
             t0 = time.perf_counter()
-            off_engine.train_batch(batch)
+            jax.block_until_ready(off_engine.train_batch(batch))
             off_t.append(time.perf_counter() - t0)
             t0 = time.perf_counter()
-            on_engine.train_batch(batch)
+            jax.block_until_ready(on_engine.train_batch(batch))
             on_t.append(time.perf_counter() - t0)
         med_on, med_off = float(np.median(on_t)), float(np.median(off_t))
         rounds.append((med_on, med_off, med_on / med_off - 1.0))

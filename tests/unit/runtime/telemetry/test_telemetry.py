@@ -199,7 +199,7 @@ def test_trace_report_round_trip_and_drift(tmp_path, capsys):
 
 def test_ds_trace_steps_env_knob(tmp_path, monkeypatch):
     """DS_TRACE_STEPS=<start>:<count> drops an XLA device trace into the
-    telemetry run dir (jax_compat.profiler_start_trace cadence)."""
+    telemetry run dir (jax.profiler.start_trace cadence)."""
     import glob
 
     monkeypatch.setenv("DS_TRACE_STEPS", "2:1")

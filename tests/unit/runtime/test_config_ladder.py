@@ -47,12 +47,6 @@ def test_llama_1b_lowers_with_zeropp_and_tp():
     tensor parallelism (fsdp=4 x tensor=2)."""
     import jax.numpy as jnp
 
-    import pytest
-    from deepspeed_tpu.utils.jax_compat import PARTIAL_MANUAL_OK
-    if not PARTIAL_MANUAL_OK:
-        # qcomm + live TP axis needs partial-manual shard_map (engine
-        # falls back to QDQ numerics on this jax — see jax_compat)
-        pytest.skip("partial-manual shard_map unsupported on this jax")
     cfg = get_llama_config("1b", max_position_embeddings=128, dtype=jnp.bfloat16, remat=True)
     ds = {"train_batch_size": 8,
           "optimizer": {"type": "AdamW", "params": {"lr": 1e-4}},

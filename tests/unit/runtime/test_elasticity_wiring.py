@@ -1,5 +1,5 @@
 """Elasticity must be WIRED into config resolution, not parsed-and-dropped
-(VERDICT r1 weak #11; reference ``elasticity/elasticity.py:233`` invoked
+(reference ``elasticity/elasticity.py:233`` invoked
 from ``runtime/config.py``)."""
 
 import numpy as np

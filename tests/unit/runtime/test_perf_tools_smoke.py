@@ -1,7 +1,6 @@
-"""Perf-harness smoke tests: the chip-window stages (tools/perf_ladder,
+"""Perf-harness smoke tests: the perf tools (tools/perf_ladder,
 tools/serve_bench) must run end-to-end on the CPU backend with tiny
-models — a harness bug discovered during a live chip window costs the
-window (r3 wedge #3 started exactly that way)."""
+models — a harness bug discovered during a chip run costs chip time."""
 import json
 import os
 import subprocess

@@ -42,13 +42,8 @@ def curve(steps: int = STEPS, seed: int = SEED):
     import jax
 
     jax.config.update("jax_default_matmul_precision", "highest")
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.environ.get("JAX_CACHE_DIR", os.path.join(
-                              os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                              ".jax_cache")))
-    except Exception:
-        pass
+    from envutil import use_compile_cache
+    use_compile_cache()
 
     import numpy as np
     import jax.numpy as jnp

@@ -75,7 +75,8 @@ Env: SERVE_MODEL=test|125m|350m...   model family config
                                     summary rides the continuous row)
      SERVE_TELEMETRY_DIR=/tmp/ds_tpu_serve_telemetry
      SERVE_SEED=0
-NEVER wrap in `timeout` — clean-exit only (PERF.md wedge lessons).
+One process holds the chip; fleet mode starts one worker process per
+replica, which needs one chip each (ROADMAP launcher audit).
 """
 import json
 import os
