@@ -24,6 +24,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.utils import trace
+
 #: cache leaves that hold write positions (scalar in ``generate``'s
 #: lockstep cache; [slots] vectors in the serving cache)
 INDEX_LEAVES = ("cache_index", "position_index")
@@ -243,6 +245,7 @@ def serve_programs(engine, slots_bucket: int, *, prefill_chunk: int,
            weight_dtype)
     if key in engine._serve_cache:
         return engine._serve_cache[key]
+    trace.recorder().count("serve_program_builds")
     apply_fn = make_apply_fn(mod,
                              mparams if mparams is not None else engine._mparams)
     fns: Dict[str, Any] = {

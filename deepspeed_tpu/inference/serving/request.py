@@ -39,6 +39,7 @@ class Request:
     cached_prefix_tokens: int = 0
 
     # latency accounting (clock units of the scheduler's injected clock)
+    admit_time: Optional[float] = None    # a slot and its KV blocks were taken
     first_token_time: Optional[float] = None
     finish_time: Optional[float] = None
     token_times: List[float] = dataclasses.field(default_factory=list)

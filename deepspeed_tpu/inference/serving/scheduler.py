@@ -20,8 +20,9 @@ prefix.
 Integration seams (the five the last PRs built):
 * resilience — :meth:`serve` wires a ``PreemptionGuard``; SIGTERM drains
   in-flight requests (finish), refuses the queue, and returns exit 143.
-* telemetry — per-tick spans + per-request latency/acceptance events
-  ride a ``RuntimeTelemetry`` bus when one is attached.
+* tracing — every tick and its host phases are spans of the process's
+  recorder (``utils/trace.py``), always; per-request latency/acceptance
+  events ride a ``RuntimeTelemetry`` sink when one is attached.
 * graft-audit — the decode program is the ``serve_decode_step`` scenario
   (same ``make_apply_fn``), budgeted and signature-pinned by R009/R010/R013.
 * compression — the drafter is the KD student
@@ -51,6 +52,7 @@ from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
 from deepspeed_tpu.runtime.telemetry.metrics import Histogram
+from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -134,6 +136,13 @@ class ContinuousBatchingScheduler:
         self.module = engine.module
         self.clock = clock or time.monotonic
         self.telemetry = telemetry
+        # spans and counters go to the process's recorder whether or not a
+        # sink is attached; with one, under its source, so its window flush
+        # reads this scheduler's records
+        self._rec = trace.recorder()
+        self._source = (telemetry.source if telemetry is not None
+                        else trace.new_source("sched"))
+        self._tick_no = 0
 
         # graft-quant-serve: resolve the served weight dtype (env outranks
         # config — the DS_SERVE_WQ drift seam, same layering as kv_write)
@@ -309,11 +318,9 @@ class ContinuousBatchingScheduler:
                 total += leaf.size * leaf.dtype.itemsize
         return total / float(self.slots * self.capacity)
 
-    def _span(self, name: str):
-        if self.telemetry is not None:
-            return self.telemetry.span(name)
-        import contextlib
-        return contextlib.nullcontext()
+    def _phase(self, name: str):
+        """A host phase of the tick in progress: a child span of ``tick``."""
+        return self._rec.span(name, self._tick_no, self._source)
 
     # ------------------------------------------------------------------
     def warmup(self) -> None:
@@ -370,8 +377,12 @@ class ContinuousBatchingScheduler:
     def _admit(self) -> int:
         free = self._free_slots()
         admitted = self.queue.admit(len(free))
+        now = self.clock() if admitted else None
         for slot, req in zip(free, admitted):
             self._slot_req[slot] = req
+            req.admit_time = now
+            self._rec.record("queue_wait", req.arrival_time, now,
+                             req.request_id, self._source)
             # graft-prefix-cache: the reservation may have matched an
             # indexed prefix — restore its KV rows into the slot and
             # start prefill AFTER them, so the tick only pays for the
@@ -446,37 +457,38 @@ class ContinuousBatchingScheduler:
         hashed, so shared prefixes publish their KV rows exactly once."""
         if self.prefix_cache != "on":
             return
-        committed = int(self._lengths[slot])
-        if committed < self.pool.block_size:
-            return
-        tokens = np.concatenate([
-            np.asarray(req.prompt, np.int64),
-            np.asarray(req.output, np.int64)])[:committed]
+        with self._phase("publish"):
+            committed = int(self._lengths[slot])
+            if committed < self.pool.block_size:
+                return
+            tokens = np.concatenate([
+                np.asarray(req.prompt, np.int64),
+                np.asarray(req.output, np.int64)])[:committed]
 
-        # ONE device_get per leaf per publish, host-sliced per block:
-        # per-block device slices would compile a fresh XLA program per
-        # (start, stop) offset and dominate the tick under load. Lazy and
-        # tail-only — the pool walks blocks in order and calls fetch only
-        # for blocks not yet indexed, so the first call's ``start`` is the
-        # first new row: a finish-time publish whose prompt blocks are
-        # already shared transfers just the output tail (or, when every
-        # full block is already indexed, nothing at all)
-        full: dict = {}
+            # ONE device_get per leaf per publish, host-sliced per block:
+            # per-block device slices would compile a fresh XLA program per
+            # (start, stop) offset and dominate the tick under load. Lazy and
+            # tail-only — the pool walks blocks in order and calls fetch only
+            # for blocks not yet indexed, so the first call's ``start`` is the
+            # first new row: a finish-time publish whose prompt blocks are
+            # already shared transfers just the output tail (or, when every
+            # full block is already indexed, nothing at all)
+            full: dict = {}
 
-        def fetch(start: int, stop: int) -> dict:
-            if not full:
-                full["base"] = start
-                full["target"] = self._kv_rows(self._cache, slot,
-                                               start, committed)
-                if self._drafter is not None:
-                    full["drafter"] = self._kv_rows(self._drafter_cache,
-                                                    slot, start, committed)
-            base = full["base"]
-            return {role: {k: arr[start - base:stop - base]
-                           for k, arr in rows.items()}
-                    for role, rows in full.items() if role != "base"}
+            def fetch(start: int, stop: int) -> dict:
+                if not full:
+                    full["base"] = start
+                    full["target"] = self._kv_rows(self._cache, slot,
+                                                   start, committed)
+                    if self._drafter is not None:
+                        full["drafter"] = self._kv_rows(self._drafter_cache,
+                                                        slot, start, committed)
+                base = full["base"]
+                return {role: {k: arr[start - base:stop - base]
+                               for k, arr in rows.items()}
+                        for role, rows in full.items() if role != "base"}
 
-        self.pool.publish(req.request_id, tokens, fetch=fetch)
+            self.pool.publish(req.request_id, tokens, fetch=fetch)
 
     # ------------------------------------------------------------------
     # tick
@@ -491,32 +503,35 @@ class ContinuousBatchingScheduler:
         set_default_weight_dtype(self.config.weight_dtype)
         if self.telemetry is not None:
             self.telemetry.begin_step(step_no)
-        with self._span("serve_admit"):
-            if admit:
-                self._admit()
-        prefilling = [i for i, r in enumerate(self._slot_req)
-                      if r is not None and r.state == PREFILL]
-        active = [i for i, r in enumerate(self._slot_req)
-                  if r is not None and r.state == ACTIVE]
-        if prefilling and (not active or self._decode_ticks_since_prefill
-                           >= self.config.prefill_interleave):
-            kind = "prefill"
-            with self._span("serve_prefill"):
+        self._tick_no = step_no
+        with self._phase("tick") as tick:
+            with self._phase("admit"):
+                if admit:
+                    self._admit()
+            prefilling = [i for i, r in enumerate(self._slot_req)
+                          if r is not None and r.state == PREFILL]
+            active = [i for i, r in enumerate(self._slot_req)
+                      if r is not None and r.state == ACTIVE]
+            if prefilling and (not active or self._decode_ticks_since_prefill
+                               >= self.config.prefill_interleave):
+                kind = "prefill"
                 self._prefill_tick(prefilling)
-            self._decode_ticks_since_prefill = 0
-        elif active:
-            kind = "spec" if self.spec_k else "decode"
-            with self._span(f"serve_{kind}"):
+                self._decode_ticks_since_prefill = 0
+            elif active:
+                kind = "spec" if self.spec_k else "decode"
                 if self.spec_k:
                     self._spec_tick(active)
                 else:
                     self._decode_tick(active)
-            self._decode_ticks_since_prefill += 1
-        else:
-            kind = "idle"
-        if kind != "idle" and self._serve_t0 is None:
-            self._serve_t0 = self.clock()
-        self.ticks[kind] += 1
+                self._decode_ticks_since_prefill += 1
+            else:
+                kind = "idle"
+            tick.kind = kind
+            if kind != "idle" and self._serve_t0 is None:
+                self._serve_t0 = self.clock()
+            self.ticks[kind] += 1
+            with self._phase("heartbeat"):
+                self._touch_serving_heartbeat(step_no)
         if self.telemetry is not None:
             self.telemetry.end_step(step_no)
             every = self.config.tick_telemetry_every
@@ -526,7 +541,6 @@ class ContinuousBatchingScheduler:
                 # buffered — the window flush syncs, not every tick
                 self.telemetry.emit("serve_tick", flush=False,
                                     tick=step_no, kind=kind, **self.signals())
-        self._touch_serving_heartbeat(step_no)
         return kind
 
     # ------------------------------------------------------------------
@@ -708,78 +722,96 @@ class ContinuousBatchingScheduler:
     # -- prefill -------------------------------------------------------
     def _prefill_tick(self, slots: List[int]) -> None:
         C = self.config.prefill_chunk
-        ids = np.zeros((self.slots, C), np.int32)
-        last_idx = np.full(self.slots, C - 1, np.int32)
-        write_pos = np.full(self.slots, self.capacity, np.int64)
-        rems: Dict[int, int] = {}
-        for i in slots:
-            req = self._slot_req[i]
-            chunk = req.prompt[req.prefill_pos:req.prefill_pos + C]
-            rems[i] = rem = len(chunk)
-            ids[i, :rem] = chunk
-            last_idx[i] = rem - 1
-            write_pos[i] = self._lengths[i]
-        cache = stamp_lengths(self._cache, write_pos)
-        args = (self._serve_params, cache, jax.numpy.asarray(ids),
-                jax.numpy.asarray(last_idx))
-        if self.config.do_sample:
-            self._rng, key = jax.random.split(self._rng)
-            self._cache, tok = self.fns["prefill"](*args, key)
-        else:
-            self._cache, tok = self.fns["prefill"](*args)
-        if self._drafter is not None:
-            d_module, d_params = self._drafter
-            d_cache = stamp_lengths(self._drafter_cache, write_pos)
-            d_args = (d_params, d_cache, jax.numpy.asarray(ids),
-                      jax.numpy.asarray(last_idx))
+        with self._phase("build_inputs"):
+            ids = np.zeros((self.slots, C), np.int32)
+            last_idx = np.full(self.slots, C - 1, np.int32)
+            write_pos = np.full(self.slots, self.capacity, np.int64)
+            rems: Dict[int, int] = {}
+            for i in slots:
+                req = self._slot_req[i]
+                chunk = req.prompt[req.prefill_pos:req.prefill_pos + C]
+                rems[i] = rem = len(chunk)
+                ids[i, :rem] = chunk
+                last_idx[i] = rem - 1
+                write_pos[i] = self._lengths[i]
+        # the fixed-shape program computes slots x chunk positions whatever
+        # it is fed: the ratio is the prefill program's fill
+        self._rec.count("prefill_positions_fed", sum(rems.values()))
+        self._rec.count("prefill_positions_computed", self.slots * C)
+        with self._phase("stamp"):
+            cache = stamp_lengths(self._cache, write_pos)
+            args = (self._serve_params, cache, jax.numpy.asarray(ids),
+                    jax.numpy.asarray(last_idx))
+            if self._drafter is not None:
+                d_module, d_params = self._drafter
+                d_cache = stamp_lengths(self._drafter_cache, write_pos)
+                d_args = (d_params, d_cache, jax.numpy.asarray(ids),
+                          jax.numpy.asarray(last_idx))
+        with self._phase("dispatch"):
             if self.config.do_sample:
-                self._rng, dkey = jax.random.split(self._rng)
-                self._drafter_cache, _ = self.dfns["prefill"](*d_args, dkey)
+                self._rng, key = jax.random.split(self._rng)
+                self._cache, tok = self.fns["prefill"](*args, key)
             else:
-                self._drafter_cache, _ = self.dfns["prefill"](*d_args)
-        with self._span("serve_device_wait"):
+                self._cache, tok = self.fns["prefill"](*args)
+            if self._drafter is not None:
+                if self.config.do_sample:
+                    self._rng, dkey = jax.random.split(self._rng)
+                    self._drafter_cache, _ = self.dfns["prefill"](*d_args, dkey)
+                else:
+                    self._drafter_cache, _ = self.dfns["prefill"](*d_args)
+        with self._phase("device_wait"):
             tok = np.asarray(tok)
-        now = self.clock()
-        for i in slots:
-            req, rem = self._slot_req[i], rems[i]
-            req.prefill_pos += rem
-            self._lengths[i] += rem
-            self.pool.advance(req.request_id, rem)
-            if req.prefill_pos >= req.prompt_len:
-                # prompt complete: the chunk's last-position logits sampled
-                # the FIRST new token — TTFT stops here. The committed
-                # prompt's full blocks enter the hash index now, so the
-                # next same-prefix request skips their prefill entirely
-                self._publish_prefix(i, req)
-                req.state = ACTIVE
-                req.record_token(int(tok[i]), now)
-                self._next_token[i] = tok[i]
-                self._maybe_finish(i, now)
+        with self._phase("commit"):
+            now = self.clock()
+            for i in slots:
+                req, rem = self._slot_req[i], rems[i]
+                req.prefill_pos += rem
+                self._lengths[i] += rem
+                self.pool.advance(req.request_id, rem)
+                if req.prefill_pos >= req.prompt_len:
+                    # prompt complete: the chunk's last-position logits sampled
+                    # the FIRST new token — TTFT stops here. The committed
+                    # prompt's full blocks enter the hash index now, so the
+                    # next same-prefix request skips their prefill entirely
+                    self._publish_prefix(i, req)
+                    req.state = ACTIVE
+                    req.record_token(int(tok[i]), now)
+                    if req.admit_time is not None:   # None: migrated in
+                        self._rec.record("prefill_wait", req.admit_time, now,
+                                         req.request_id, self._source)
+                    self._next_token[i] = tok[i]
+                    self._maybe_finish(i, now)
 
     # -- plain decode --------------------------------------------------
     def _decode_tick(self, slots: List[int]) -> None:
-        write_pos = np.full(self.slots, self.capacity, np.int64)
-        tokens = np.zeros(self.slots, np.int32)
-        for i in slots:
-            write_pos[i] = self._lengths[i]
-            tokens[i] = self._next_token[i]
-        cache = stamp_lengths(self._cache, write_pos)
-        args = (self._serve_params, cache, jax.numpy.asarray(tokens))
-        if self.config.do_sample:
-            self._rng, key = jax.random.split(self._rng)
-            self._cache, tok = self.fns["decode"](*args, key)
-        else:
-            self._cache, tok = self.fns["decode"](*args)
-        with self._span("serve_device_wait"):
+        with self._phase("build_inputs"):
+            write_pos = np.full(self.slots, self.capacity, np.int64)
+            tokens = np.zeros(self.slots, np.int32)
+            for i in slots:
+                write_pos[i] = self._lengths[i]
+                tokens[i] = self._next_token[i]
+        self._rec.count("decode_slots_fed", len(slots))
+        self._rec.count("decode_slots_computed", self.slots)
+        with self._phase("stamp"):
+            cache = stamp_lengths(self._cache, write_pos)
+            args = (self._serve_params, cache, jax.numpy.asarray(tokens))
+        with self._phase("dispatch"):
+            if self.config.do_sample:
+                self._rng, key = jax.random.split(self._rng)
+                self._cache, tok = self.fns["decode"](*args, key)
+            else:
+                self._cache, tok = self.fns["decode"](*args)
+        with self._phase("device_wait"):
             tok = np.asarray(tok)
-        now = self.clock()
-        for i in slots:
-            req = self._slot_req[i]
-            self._lengths[i] += 1  # the fed token's KV is now committed
-            self.pool.advance(req.request_id, 1)
-            req.record_token(int(tok[i]), now)
-            self._next_token[i] = tok[i]
-            self._maybe_finish(i, now)
+        with self._phase("commit"):
+            now = self.clock()
+            for i in slots:
+                req = self._slot_req[i]
+                self._lengths[i] += 1  # the fed token's KV is now committed
+                self.pool.advance(req.request_id, 1)
+                req.record_token(int(tok[i]), now)
+                self._next_token[i] = tok[i]
+                self._maybe_finish(i, now)
 
     # -- speculative decode --------------------------------------------
     def _spec_tick(self, slots: List[int]) -> None:
@@ -789,64 +821,74 @@ class ContinuousBatchingScheduler:
         every draft (its own pass never wrote the kth draft's KV)."""
         k = self.spec_k
         d_module, d_params = self._drafter
-        write_pos = np.full(self.slots, self.capacity, np.int64)
-        for i in slots:
-            write_pos[i] = self._lengths[i]
+        with self._phase("build_inputs"):
+            write_pos = np.full(self.slots, self.capacity, np.int64)
+            for i in slots:
+                write_pos[i] = self._lengths[i]
+            first = np.asarray([self._next_token[i] if self._slot_req[i] is not None
+                                and self._slot_req[i].state == ACTIVE else 0
+                                for i in range(self.slots)], np.int32)
+        self._rec.count("decode_slots_fed", len(slots))
+        self._rec.count("decode_slots_computed", self.slots)
         # committed to the mesh placement so iteration 1's input sharding
         # matches iterations 2..k (which feed the previous jit output back);
         # an uncommitted first feed would cost a second decode compile
-        cur = jax.device_put(  # graft-lint: waive R008 host token mirror to mesh placement, never donated
-            np.asarray([self._next_token[i] if self._slot_req[i] is not None
-                        and self._slot_req[i].state == ACTIVE else 0
-                        for i in range(self.slots)], np.int32), self._placement)
+        with self._phase("stamp"):
+            cur = jax.device_put(first, self._placement)  # graft-lint: waive R008 host token mirror to mesh placement, never donated
         drafts = []
-        with self._span("serve_spec_draft"):
-            for j in range(k):
+        for j in range(k):
+            with self._phase("stamp"):
                 d_cache = stamp_lengths(self._drafter_cache, write_pos + j)
+            with self._phase("dispatch"):
                 self._drafter_cache, cur = self.dfns["decode"](d_params, d_cache, cur)
-                drafts.append(cur)
+            drafts.append(cur)
+        with self._phase("device_wait"):
             drafts = np.stack([np.asarray(d) for d in drafts], axis=1)  # [S, k]
-        block = np.zeros((self.slots, k + 1), np.int32)
-        for i in slots:
-            block[i, 0] = self._next_token[i]
-            block[i, 1:] = drafts[i]
-        with self._span("serve_spec_verify"):
+        with self._phase("build_inputs"):
+            block = np.zeros((self.slots, k + 1), np.int32)
+            for i in slots:
+                block[i, 0] = self._next_token[i]
+                block[i, 1:] = drafts[i]
+        with self._phase("stamp"):
             cache = stamp_lengths(self._cache, write_pos)
-            self._cache, greedy = self.fns["verify"](
-                self._serve_params, cache, jax.numpy.asarray(block))
+            block_dev = jax.numpy.asarray(block)
+        with self._phase("dispatch"):
+            self._cache, greedy = self.fns["verify"](self._serve_params, cache, block_dev)
+        with self._phase("device_wait"):
             greedy = np.asarray(greedy)  # [S, k+1] target argmax per position
         refeed = False
-        now = self.clock()
-        for i in slots:
-            req = self._slot_req[i]
-            # longest prefix of drafts the target reproduces
-            a = 0
-            while a < k and drafts[i, a] == greedy[i, a]:
-                a += 1
-            emitted = list(drafts[i, :a]) + [greedy[i, a]]
-            req.drafted_tokens += k
-            req.accepted_tokens += a
-            self.drafted_total += k
-            self.accepted_total += a
-            if a == k:
-                refeed = True  # drafter never wrote d_k's KV — resync below
-            # budget/eos truncation
-            room = req.max_new_tokens - len(req.output)
-            emitted = emitted[:room]
-            if req.eos_token_id is not None and req.eos_token_id in emitted:
-                emitted = emitted[:emitted.index(req.eos_token_id) + 1]
-            for t in emitted:
-                req.record_token(int(t), now)
-            # committed KV: the fed block prefix [last, d_1..d_{m-1}]
-            self._lengths[i] += len(emitted)
-            self.pool.advance(req.request_id, len(emitted))
-            self._next_token[i] = emitted[-1]
-            self._maybe_finish(i, now)
+        with self._phase("commit"):
+            now = self.clock()
+            for i in slots:
+                req = self._slot_req[i]
+                # longest prefix of drafts the target reproduces
+                a = 0
+                while a < k and drafts[i, a] == greedy[i, a]:
+                    a += 1
+                emitted = list(drafts[i, :a]) + [greedy[i, a]]
+                req.drafted_tokens += k
+                req.accepted_tokens += a
+                self.drafted_total += k
+                self.accepted_total += a
+                if a == k:
+                    refeed = True  # drafter never wrote d_k's KV — resync below
+                # budget/eos truncation
+                room = req.max_new_tokens - len(req.output)
+                emitted = emitted[:room]
+                if req.eos_token_id is not None and req.eos_token_id in emitted:
+                    emitted = emitted[:emitted.index(req.eos_token_id) + 1]
+                for t in emitted:
+                    req.record_token(int(t), now)
+                # committed KV: the fed block prefix [last, d_1..d_{m-1}]
+                self._lengths[i] += len(emitted)
+                self.pool.advance(req.request_id, len(emitted))
+                self._next_token[i] = emitted[-1]
+                self._maybe_finish(i, now)
         if refeed and any(self._slot_req[i] is not None for i in slots):
-            with self._span("serve_spec_refeed"):
+            with self._phase("stamp"):
                 d_cache = stamp_lengths(self._drafter_cache, write_pos)
-                self._drafter_cache, _ = self.dfns["verify"](
-                    d_params, d_cache, jax.numpy.asarray(block))
+            with self._phase("dispatch"):
+                self._drafter_cache, _ = self.dfns["verify"](d_params, d_cache, block_dev)
 
     # ------------------------------------------------------------------
     # live KV migration (graft-fleet)

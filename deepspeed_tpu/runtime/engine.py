@@ -258,10 +258,12 @@ class DeepSpeedEngine:
         from deepspeed_tpu.monitor.monitor import MonitorMaster
         self.monitor = MonitorMaster(config.monitor_config)
 
-        # -- telemetry (runtime/telemetry, graft-trace): host-side step
-        # spans + JSONL event log + drift. The monitor is ONE subscriber of
-        # the event bus — TB/W&B/CSV keep working unchanged, and every
-        # published batch also lands durably in the JSONL when enabled.
+        # -- telemetry (runtime/telemetry, graft-trace): JSONL event log +
+        # drift over the host-side step spans, which go to the process's
+        # recorder (utils/trace.py) whether or not the block is enabled.
+        # The monitor is ONE subscriber of the event bus — TB/W&B/CSV keep
+        # working unchanged, and every published batch also lands durably
+        # in the JSONL when enabled.
         # Instrumentation is host-only by construction: the traced step
         # program must stay eqn-identical with telemetry on (rule R015,
         # scenario train_batch_telemetry) and within 2% step time (tier-1).
@@ -269,7 +271,8 @@ class DeepSpeedEngine:
         self.telemetry = RuntimeTelemetry(config.telemetry_config,
                                           flush_every=config.steps_per_print,
                                           rank=dist.get_rank(),
-                                          run_info_fn=self._telemetry_run_info)
+                                          run_info_fn=self._telemetry_run_info,
+                                          label="engine")
         if self.monitor.enabled:
             self.telemetry.subscribe(self.monitor.write_events)
         # DS_TRACE_STEPS=<start>[:<count>]: cadenced XLA device-trace
@@ -2132,48 +2135,50 @@ class DeepSpeedEngine:
         self._maybe_write_telemetry_header(example)
         self._maybe_trace_window(n_steps)
         tel = self.telemetry
-        tel.begin_step(self.global_steps + 1)
-        self.tput_timer.start()
-        self.timers(TRAIN_BATCH_TIMER).start()
-        with tel.span("batch_stage"):
-            device_batch = self._shard_batch_steps(batch_stack)
-        rng = jax.random.fold_in(self._base_rng, self.global_steps)
-        with tel.span("dispatch"):
-            self.state, metrics = self._train_steps_fn(self.state, device_batch, rng)
-        self.global_steps += n_steps
-        self.global_samples += n_steps * self.config.train_batch_size
-        self.micro_steps += n_steps * self.config.gradient_accumulation_steps
-        if tel.enabled:
-            with tel.span("device_wait"):
-                jax.block_until_ready(metrics["loss"])
-        self.timers(TRAIN_BATCH_TIMER).stop()
-        self.tput_timer.stop(global_step=True)
-        # every step in the stack counts toward overflow accounting, not just
-        # the last one (_post_step sees a scalar; the stack's total lands here)
-        ov_steps = np.asarray(jax.device_get(metrics["overflow"]))
-        ls_steps = np.asarray(jax.device_get(metrics["loss_scale"]))
-        n_over = int(np.sum(ov_steps))
-        last = jax.tree.map(lambda m: m[-1], metrics)
-        if n_over:
-            self.skipped_steps += n_over
-            log_dist(f"{n_over}/{n_steps} steps in the fused stack overflowed; "
-                     f"updates skipped, loss scale -> {float(last['loss_scale'])}")
-        # per-step flags (already host-synced above) feed the overflow
-        # watcher so streaks inside a fused stack trip the same guard the
-        # per-dispatch path does. Drain first: earlier per-dispatch steps
-        # may still sit in _pending_overflow, and the watcher must see
-        # flags in step order or a stale streak replays after clean steps
-        self._drain_overflows()
-        first = self.global_steps - n_steps
-        for i in range(n_steps):
-            self._record_overflow(first + i + 1, bool(ov_steps[i]), float(ls_steps[i]))
-        # drop the key entirely (not overflow=False): a synthetic clean flag
-        # for the final step would reach the watcher at the next drain and
-        # zero a streak the real per-step flags above just built — the
-        # abort-after-K guard must see fused stacks exactly as per-dispatch
-        last = {k: v for k, v in last.items() if k != "overflow"}  # counted above
-        with tel.span("post_step"):
-            self._post_step(last)
+        step_no = self.global_steps + 1
+        tel.begin_step(step_no)
+        with tel.span("train_batch", step_no):
+            with tel.span("timer_sync", step_no):
+                self.tput_timer.start()
+            self.timers(TRAIN_BATCH_TIMER).start()
+            with tel.span("batch_stage", step_no):
+                device_batch = self._shard_batch_steps(batch_stack)
+            rng = jax.random.fold_in(self._base_rng, self.global_steps)
+            with tel.span("dispatch", step_no):
+                self.state, metrics = self._train_steps_fn(self.state, device_batch, rng)
+            self.global_steps += n_steps
+            self.global_samples += n_steps * self.config.train_batch_size
+            self.micro_steps += n_steps * self.config.gradient_accumulation_steps
+            # the step's one wait for the device: the throughput timer's sync
+            with tel.span("device_wait", step_no):
+                self.timers(TRAIN_BATCH_TIMER).stop()
+                self.tput_timer.stop(global_step=True)
+            # every step in the stack counts toward overflow accounting, not just
+            # the last one (_post_step sees a scalar; the stack's total lands here)
+            ov_steps = np.asarray(jax.device_get(metrics["overflow"]))
+            ls_steps = np.asarray(jax.device_get(metrics["loss_scale"]))
+            n_over = int(np.sum(ov_steps))
+            last = jax.tree.map(lambda m: m[-1], metrics)
+            if n_over:
+                self.skipped_steps += n_over
+                log_dist(f"{n_over}/{n_steps} steps in the fused stack overflowed; "
+                         f"updates skipped, loss scale -> {float(last['loss_scale'])}")
+            # per-step flags (already host-synced above) feed the overflow
+            # watcher so streaks inside a fused stack trip the same guard the
+            # per-dispatch path does. Drain first: earlier per-dispatch steps
+            # may still sit in _pending_overflow, and the watcher must see
+            # flags in step order or a stale streak replays after clean steps
+            self._drain_overflows()
+            first = self.global_steps - n_steps
+            for i in range(n_steps):
+                self._record_overflow(first + i + 1, bool(ov_steps[i]), float(ls_steps[i]))
+            # drop the key entirely (not overflow=False): a synthetic clean flag
+            # for the final step would reach the watcher at the next drain and
+            # zero a streak the real per-step flags above just built — the
+            # abort-after-K guard must see fused stacks exactly as per-dispatch
+            last = {k: v for k, v in last.items() if k != "overflow"}  # counted above
+            with tel.span("post_step", step_no):
+                self._post_step(last)
         tel.end_step(self.global_steps, n_steps=n_steps)
         self._maybe_trace_window()
         return metrics["loss"]
@@ -2212,60 +2217,60 @@ class DeepSpeedEngine:
         self._maybe_write_telemetry_header(batch)
         self._maybe_trace_window()
         tel = self.telemetry
-        tel.begin_step(self.global_steps + 1)
-        self.tput_timer.start()
-        self.timers(TRAIN_BATCH_TIMER).start()
-        with tel.span("batch_stage"):
-            device_batch = self._shard_batch(batch, with_gas_dim=True)
-        rng = jax.random.fold_in(self._base_rng, self.global_steps)
-        fp_cfg = self.config.flops_profiler_config
-        profiling_now = fp_cfg.enabled and self.global_steps + 1 == fp_cfg.profile_step
-        if profiling_now:
-            t_profile = time.time()
-        with tel.span("dispatch"):
-            if getattr(self, "_host_opt", None) is not None:
-                _, metrics = self._offload_train_batch(device_batch, rng)
-            elif self._zeroone_runner is not None:
-                # 0/1 Adam owns the whole schedule (dense/1-bit/local/sync)
-                metrics = self._zeroone_runner.step(device_batch, rng)
-            elif (self._onebit_cfg is not None
-                  and self.global_steps >= self._onebit_cfg["freeze_step"]):
-                # compression phase: momentum rides the 1-bit collective
-                if self._onebit_step_fn is None:
-                    self._build_onebit_step_fn(device_batch)
-                self.state, self._onebit_errors, metrics = self._onebit_step_fn(
-                    self.state, self._onebit_errors, device_batch, rng)
-            elif getattr(self, "_param_offload_enabled", False):
-                metrics = self._param_offload_train_batch(device_batch, rng)
-            else:
-                self.state, metrics = self._train_step_fn(self.state, device_batch, rng)
-        self.global_steps += 1
-        self.global_samples += self.config.train_batch_size
-        self.micro_steps += self.config.gradient_accumulation_steps
-        if profiling_now:
-            jax.block_until_ready(metrics["loss"])
-            step_latency = time.time() - t_profile
-        if tel.enabled:
-            # the ONE deliberate device sync telemetry adds: splits "host
-            # dispatched" from "device finished" so the window aggregates
-            # show where the step's wall time actually went. The timer
-            # stops below sync too, so recorded step time is unchanged.
-            with tel.span("device_wait"):
+        step_no = self.global_steps + 1
+        tel.begin_step(step_no)
+        with tel.span("train_batch", step_no):
+            with tel.span("timer_sync", step_no):
+                self.tput_timer.start()
+            self.timers(TRAIN_BATCH_TIMER).start()
+            with tel.span("batch_stage", step_no):
+                device_batch = self._shard_batch(batch, with_gas_dim=True)
+            rng = jax.random.fold_in(self._base_rng, self.global_steps)
+            fp_cfg = self.config.flops_profiler_config
+            profiling_now = fp_cfg.enabled and step_no == fp_cfg.profile_step
+            if profiling_now:
+                t_profile = time.time()
+            with tel.span("dispatch", step_no):
+                if getattr(self, "_host_opt", None) is not None:
+                    _, metrics = self._offload_train_batch(device_batch, rng)
+                elif self._zeroone_runner is not None:
+                    # 0/1 Adam owns the whole schedule (dense/1-bit/local/sync)
+                    metrics = self._zeroone_runner.step(device_batch, rng)
+                elif (self._onebit_cfg is not None
+                      and self.global_steps >= self._onebit_cfg["freeze_step"]):
+                    # compression phase: momentum rides the 1-bit collective
+                    if self._onebit_step_fn is None:
+                        self._build_onebit_step_fn(device_batch)
+                    self.state, self._onebit_errors, metrics = self._onebit_step_fn(
+                        self.state, self._onebit_errors, device_batch, rng)
+                elif getattr(self, "_param_offload_enabled", False):
+                    metrics = self._param_offload_train_batch(device_batch, rng)
+                else:
+                    self.state, metrics = self._train_step_fn(self.state, device_batch, rng)
+            self.global_steps += 1
+            self.global_samples += self.config.train_batch_size
+            self.micro_steps += self.config.gradient_accumulation_steps
+            if profiling_now:
                 jax.block_until_ready(metrics["loss"])
-        self.timers(TRAIN_BATCH_TIMER).stop()
-        self.tput_timer.stop(global_step=True)
-        if profiling_now:
-            # reference hooks the profiler at flops_profiler_profile_step
-            # (engine.py:1721,2121); here the compiled step IS the profile.
-            # Runs after the timers close so profiler-induced (re)compiles
-            # don't pollute the step's recorded throughput.
-            from deepspeed_tpu.profiling.flops_profiler.profiler import profile_engine_step
-            profile_engine_step(self, device_batch, rng,
-                                step_latency_s=step_latency,
-                                output_file=fp_cfg.output_file)
-        self._last_batch_for_stats = batch  # MoE gate observability (_post_step)
-        with tel.span("post_step"):
-            self._post_step(metrics)
+                step_latency = time.time() - t_profile
+            # the step's one wait for the device is the throughput timer's sync:
+            # the span splits "host dispatched" from "device finished" and adds
+            # no sync of its own, whether or not the JSONL sink is on
+            with tel.span("device_wait", step_no):
+                self.timers(TRAIN_BATCH_TIMER).stop()
+                self.tput_timer.stop(global_step=True)
+            if profiling_now:
+                # reference hooks the profiler at flops_profiler_profile_step
+                # (engine.py:1721,2121); here the compiled step IS the profile.
+                # Runs after the timers close so profiler-induced (re)compiles
+                # don't pollute the step's recorded throughput.
+                from deepspeed_tpu.profiling.flops_profiler.profiler import profile_engine_step
+                profile_engine_step(self, device_batch, rng,
+                                    step_latency_s=step_latency,
+                                    output_file=fp_cfg.output_file)
+            self._last_batch_for_stats = batch  # MoE gate observability (_post_step)
+            with tel.span("post_step", step_no):
+                self._post_step(metrics)
         tel.end_step(self.global_steps)
         self._maybe_trace_window()  # close the window right after its last step
         return metrics["loss"]

@@ -1,10 +1,12 @@
 """Unified runtime telemetry (graft-trace, ISSUE 13).
 
-* :mod:`.metrics` — counters/gauges/mergeable fixed-bucket histograms;
-* :mod:`.spans` — nested host-side step-phase spans;
+* :mod:`.metrics` — the mergeable fixed-bucket histogram;
 * :mod:`.sink` — schema-versioned rank-0 JSONL event log;
 * :mod:`.core` — :class:`RuntimeTelemetry`, the engine-facing facade
   (event bus + run header + window flush + drift).
+
+The spans and counters themselves live in the process's one recorder,
+``deepspeed_tpu.utils.trace``; this package consumes it.
 
 Reader/report side: ``tools/trace_report.py``.
 """
@@ -12,17 +14,13 @@ Reader/report side: ``tools/trace_report.py``.
 from deepspeed_tpu.runtime.telemetry.core import (RuntimeTelemetry, config_signature,
                                                   drift_ratios, measured_memory,
                                                   parse_trace_steps, TELEMETRY_FILE)
-from deepspeed_tpu.runtime.telemetry.metrics import (Counter, Gauge, Histogram,
-                                                     MetricsRegistry,
-                                                     DEFAULT_LATENCY_BOUNDS)
+from deepspeed_tpu.runtime.telemetry.metrics import DEFAULT_LATENCY_BOUNDS, Histogram
 from deepspeed_tpu.runtime.telemetry.sink import (TELEMETRY_SCHEMA_VERSION, JsonlSink,
                                                   iter_events, read_events)
-from deepspeed_tpu.runtime.telemetry.spans import NULL_SPAN, SpanRecorder
 
 __all__ = [
     "RuntimeTelemetry", "config_signature", "drift_ratios", "measured_memory",
     "parse_trace_steps", "TELEMETRY_FILE",
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "DEFAULT_LATENCY_BOUNDS",
+    "Histogram", "DEFAULT_LATENCY_BOUNDS",
     "TELEMETRY_SCHEMA_VERSION", "JsonlSink", "iter_events", "read_events",
-    "NULL_SPAN", "SpanRecorder",
 ]

@@ -1,4 +1,4 @@
-"""RuntimeTelemetry: the engine-facing facade over spans + metrics + sink.
+"""RuntimeTelemetry: the engine-facing consumer of the recorder, plus the sink.
 
 Three pillars (ISSUE 13):
 
@@ -10,9 +10,11 @@ Three pillars (ISSUE 13):
    ride a tiny bus: ``MonitorMaster`` is just one subscriber, so
    TB/W&B/CSV behavior is unchanged while every published event also
    lands durably in the JSONL.
-2. **Step-span timeline** — ``SpanRecorder`` buffers host-phase spans;
-   every ``flush_every`` steps one ``spans`` event (raw timeline) and
-   one ``step_window`` event (per-phase p50/p99 aggregates) are written.
+2. **Step-span timeline** — the process's recorder
+   (``deepspeed_tpu.utils.trace``) holds the host-phase spans, always;
+   every ``flush_every`` steps this consumer reads the records that carry
+   its ``source`` and writes one ``spans`` event (raw timeline) and one
+   ``step_window`` event (per-phase p50/p99 aggregates).
    ``tools/trace_report.py`` turns the timeline into Chrome trace-event
    JSON. ``DS_TRACE_STEPS=<start>:<count>`` additionally opens a cadenced
    ``jax.profiler`` device-trace window into the same run directory
@@ -37,9 +39,9 @@ import os
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from deepspeed_tpu.runtime.telemetry.metrics import Histogram, MetricsRegistry
+from deepspeed_tpu.runtime.telemetry.metrics import Histogram
 from deepspeed_tpu.runtime.telemetry.sink import (TELEMETRY_SCHEMA_VERSION, JsonlSink)
-from deepspeed_tpu.runtime.telemetry.spans import SpanRecorder
+from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import logger
 
 __all__ = ["RuntimeTelemetry", "config_signature", "parse_trace_steps",
@@ -130,22 +132,26 @@ def drift_ratios(price: Optional[Dict], median_step_s: Optional[float],
 
 
 class RuntimeTelemetry:
-    """Facade the engine owns. Disabled (`cfg.enabled=False`) it is a
-    pure event bus: ``publish_events`` still fans out to subscribers
-    (MonitorMaster), spans/sink are no-ops."""
+    """Facade the engine owns. Its spans always go to the process's
+    recorder, under this object's ``source``. Disabled
+    (`cfg.enabled=False`) it is otherwise a pure event bus:
+    ``publish_events`` still fans out to subscribers (MonitorMaster), the
+    window flush and the sink are no-ops."""
 
     def __init__(self, cfg=None, flush_every: int = 10, rank: int = 0,
-                 run_info_fn: Optional[Callable[[], Dict]] = None):
+                 run_info_fn: Optional[Callable[[], Dict]] = None, label: str = "run"):
         self.cfg = cfg
         self.enabled = bool(cfg is not None and getattr(cfg, "enabled", False))
         self.rank = int(rank)
         self.flush_every = max(int(getattr(cfg, "flush_interval_steps", 0) or 0)
                                or int(flush_every), 1)
         self._run_info_fn = run_info_fn
-        self.metrics = MetricsRegistry()
-        self.spans = SpanRecorder(
-            enabled=self.enabled,
-            max_buffered=int(getattr(cfg, "max_buffered_spans", 4096) or 4096))
+        self.recorder = trace.recorder()
+        self.source = trace.new_source(label)
+        self._cursor = self.recorder.last_seq    # the window flush reads on from here
+        self._epoch = time.time() - time.perf_counter()  # span starts -> JSONL ``ts``
+        self.max_buffered = int(getattr(cfg, "max_buffered_spans", 4096) or 4096)
+        self._window_step = Histogram()
         self.run_dir: Optional[str] = None
         self.sink = JsonlSink(None)
         if self.enabled:
@@ -214,12 +220,12 @@ class RuntimeTelemetry:
                          "static_price": self.static_price}, flush=True)
 
     # -- spans / steps -------------------------------------------------
-    def span(self, name: str):
-        return self.spans.span(name)
+    def span(self, name: str, uid: Optional[int] = None):
+        return self.recorder.span(name, uid, self.source)
 
     @property
     def last_span(self) -> Optional[str]:
-        return self.spans.last_span
+        return self.recorder.last_span
 
     def begin_step(self, step: int) -> None:
         if not self.enabled:
@@ -236,19 +242,39 @@ class RuntimeTelemetry:
         wall = time.perf_counter() - self._step_t0
         self._step_t0 = None
         per_step = wall / max(n_steps, 1)
-        h = self.spans._window_hist.setdefault("step", Histogram())
         for _ in range(n_steps):  # fused stacks: n per-step samples at stack/n each
-            h.record(per_step)
+            self._window_step.record(per_step)
             self._step_hist_total.record(per_step)
         self._window_steps += n_steps
         self._last_step = step
         if step % self.flush_every == 0 or self._window_steps >= self.flush_every:
             self.flush_window(step)
 
+    def _drain(self) -> Tuple[List[Dict], Dict[str, Histogram], int]:
+        """This source's records since the last flush as (span events for
+        the JSONL, per-phase window histograms, records lost): at most
+        ``max_buffered`` events a window, the histograms from every record
+        the ring still held."""
+        records, dropped = self.recorder.since(self._cursor, self.source)
+        self._cursor = self.recorder.last_seq
+        events: List[Dict] = []
+        hists: Dict[str, Histogram] = {}
+        for r in records:
+            if len(events) < self.max_buffered:
+                events.append({"name": r.name, "path": "/".join(r.path),
+                               "ts": r.start + self._epoch, "dur_s": r.dur,
+                               "depth": len(r.path), "uid": r.uid})
+            else:
+                dropped += 1
+            hists.setdefault(r.name, Histogram()).record(r.dur)
+        if self._window_step.count:
+            hists["step"], self._window_step = self._window_step, Histogram()
+        return events, hists, dropped
+
     def flush_window(self, step: int) -> None:
         if not self.enabled:
             return
-        events, hists, dropped = self.spans.drain()
+        events, hists, dropped = self._drain()
         self._window_steps = 0
         for name, hist in hists.items():
             total = self._phase_totals.get(name)
@@ -264,9 +290,8 @@ class RuntimeTelemetry:
         if hists:  # an empty window (explicit flush, no steps) emits nothing
             window = {"event": "step_window", "step": step,
                       "phases": {name: h.snapshot() for name, h in hists.items()}}
-            snap = self.metrics.snapshot()
-            if snap:
-                window["metrics"] = snap
+            if self.recorder.counters:   # process totals, not this source's alone
+                window["metrics"] = {"counters": dict(self.recorder.counters)}
             self.sink.write(window)
             step_hist = hists.get("step")
             med = step_hist.percentile(50) if step_hist else None

@@ -1,4 +1,4 @@
-"""Host-side metric primitives: counters, gauges, fixed-bucket histograms.
+"""The host-side latency type: a fixed-bucket, mergeable histogram.
 
 The reference's monitor layer only knows scalar ``(tag, value, step)``
 tuples; serving latency (ROADMAP item 1) and per-phase step spans need
@@ -7,16 +7,15 @@ bucket boundaries chosen at construction, so two histograms from
 different processes / windows merge by adding counts — the property a
 p50/p99 under load (``tools/serve_bench.py``) or a fleet-level rollup
 needs. Everything is plain Python floats and lists: recording must cost
-nanoseconds-to-microseconds, never a device sync (the step itself stays
-async; see ``spans.py`` for where the one deliberate sync lives).
+nanoseconds-to-microseconds, never a device sync. Counts are plain
+integers on the process's recorder (``deepspeed_tpu.utils.trace``).
 """
 
 import bisect
 import math
 from typing import Dict, List, Optional, Sequence
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "DEFAULT_LATENCY_BOUNDS"]
+__all__ = ["Histogram", "DEFAULT_LATENCY_BOUNDS"]
 
 
 def exponential_bounds(start: float, factor: float, count: int) -> List[float]:
@@ -29,30 +28,6 @@ def exponential_bounds(start: float, factor: float, count: int) -> List[float]:
 #: 32 buckets incl. the two open ends). Wide enough for a single decode
 #: tick AND a cold 760m compile; coarse enough that a snapshot stays small.
 DEFAULT_LATENCY_BOUNDS = tuple(exponential_bounds(1e-6, 2.0, 31))
-
-
-class Counter:
-    """Monotonic count (events, bytes, retries)."""
-
-    __slots__ = ("value",)
-
-    def __init__(self):
-        self.value = 0
-
-    def inc(self, n: int = 1) -> None:
-        self.value += n
-
-
-class Gauge:
-    """Last-write-wins instantaneous value."""
-
-    __slots__ = ("value",)
-
-    def __init__(self):
-        self.value = None
-
-    def set(self, v: float) -> None:
-        self.value = v
 
 
 class Histogram:
@@ -131,35 +106,3 @@ class Histogram:
                 "p90": self.percentile(90),
                 "p99": self.percentile(99),
                 "buckets": {str(i): c for i, c in enumerate(self.counts) if c}}
-
-
-class MetricsRegistry:
-    """Named counters/gauges/histograms with one JSON-able snapshot."""
-
-    def __init__(self):
-        self._counters: Dict[str, Counter] = {}
-        self._gauges: Dict[str, Gauge] = {}
-        self._histograms: Dict[str, Histogram] = {}
-
-    def counter(self, name: str) -> Counter:
-        return self._counters.setdefault(name, Counter())
-
-    def gauge(self, name: str) -> Gauge:
-        return self._gauges.setdefault(name, Gauge())
-
-    def histogram(self, name: str, bounds: Optional[Sequence[float]] = None) -> Histogram:
-        h = self._histograms.get(name)
-        if h is None:
-            h = self._histograms[name] = Histogram(bounds)
-        return h
-
-    def snapshot(self) -> Dict:
-        out: Dict = {}
-        if self._counters:
-            out["counters"] = {k: c.value for k, c in self._counters.items()}
-        if self._gauges:
-            out["gauges"] = {k: g.value for k, g in self._gauges.items()
-                             if g.value is not None}
-        if self._histograms:
-            out["histograms"] = {k: h.snapshot() for k, h in self._histograms.items()}
-        return out

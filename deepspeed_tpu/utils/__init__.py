@@ -1,6 +1,5 @@
 from deepspeed_tpu.utils.logging import logger, log_dist, LoggerFactory
 from deepspeed_tpu.utils.memory import OnDevice, see_memory_usage
-from deepspeed_tpu.utils.nvtx import instrument_w_nvtx
 from deepspeed_tpu.utils.tensor_fragment import (safe_get_full_fp32_param, safe_get_full_grad,
                                                  safe_get_full_optimizer_state,
                                                  safe_set_full_fp32_param)

@@ -10,9 +10,10 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.runtime.telemetry import (DEFAULT_LATENCY_BOUNDS, Histogram,
-                                             JsonlSink, MetricsRegistry,
-                                             SpanRecorder, TELEMETRY_SCHEMA_VERSION,
+                                             JsonlSink, RuntimeTelemetry,
+                                             TELEMETRY_SCHEMA_VERSION,
                                              parse_trace_steps, read_events)
+from deepspeed_tpu.utils import trace
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..", "..", ".."))
 
@@ -50,18 +51,6 @@ def test_histogram_empty_and_out_of_range():
     assert len(h.counts) == len(DEFAULT_LATENCY_BOUNDS) + 1
 
 
-def test_registry_snapshot():
-    reg = MetricsRegistry()
-    reg.counter("steps").inc(3)
-    reg.gauge("loss_scale").set(1024.0)
-    reg.histogram("step_s").record(0.01)
-    snap = reg.snapshot()
-    assert snap["counters"]["steps"] == 3
-    assert snap["gauges"]["loss_scale"] == 1024.0
-    assert snap["histograms"]["step_s"]["count"] == 1
-    assert reg.counter("steps") is reg.counter("steps")  # stable identity
-
-
 # ---------------------------------------------------------------------------
 # sink
 # ---------------------------------------------------------------------------
@@ -94,27 +83,36 @@ def test_parse_trace_steps():
 # ---------------------------------------------------------------------------
 # spans
 # ---------------------------------------------------------------------------
-def test_span_nesting_and_drain():
-    rec = SpanRecorder(enabled=True, max_buffered=3)
-    with rec.span("outer"):
-        with rec.span("inner"):
+def test_span_nesting_and_drain(monkeypatch):
+    """The telemetry's window drain over the process's recorder: its own
+    source's records only, at most ``max_buffered`` events a window (the
+    rest counted, the histograms never dropping), then an empty window."""
+    monkeypatch.setattr(trace, "_RECORDER", trace.Recorder())
+
+    class Cfg:
+        enabled = False
+        max_buffered_spans = 3
+
+    tel, other = RuntimeTelemetry(Cfg()), RuntimeTelemetry(Cfg())
+    assert tel.source != other.source
+    with tel.span("outer", 7):
+        with tel.span("inner", 7):
             pass
-    assert rec.last_span in ("inner", "outer")
-    with rec.span("third"):
+        with other.span("not_mine"):
+            pass
+    assert tel.last_span in ("inner", "outer", "not_mine")
+    with tel.span("third"):
         pass
-    with rec.span("dropped"):  # over the buffer cap: counted, not stored
+    with tel.span("dropped"):  # over the window's cap: counted, not written
         pass
-    events, hists, dropped = rec.drain()
+    events, hists, dropped = tel._drain()
     assert [e["name"] for e in events] == ["inner", "outer", "third"]
-    assert events[0]["path"] == "outer" and events[0]["depth"] == 1
+    assert events[0]["path"] == "outer" and events[0]["depth"] == 1 and events[0]["uid"] == 7
+    assert events[0]["ts"] > 1e9 and events[0]["dur_s"] >= 0     # epoch seconds, for trace_report
     assert dropped == 1
     assert set(hists) == {"outer", "inner", "third", "dropped"}  # hist never drops
-    # disabled recorder: the shared no-op span, nothing recorded
-    off = SpanRecorder(enabled=False)
-    assert off.span("a") is off.span("b")
-    with off.span("a"):
-        pass
-    assert off.drain() == ([], {}, 0)
+    assert tel._drain() == ([], {}, 0)
+    assert [e["name"] for e in other._drain()[0]] == ["not_mine"]
 
 
 # ---------------------------------------------------------------------------
@@ -167,6 +165,63 @@ def test_engine_event_stream_and_run_header(tmp_path):
     # monitor events rode the bus into the JSONL (no csv/tb sink configured)
     mon = [e for e in events if e["event"] == "monitor"][-1]
     assert any(t == "Train/loss" for t, _, _ in mon["events"])
+
+
+def test_train_batch_children_with_the_sink_off():
+    """The recorder is on whenever the engine runs: with no ``telemetry``
+    block every ``train_batch`` is a span with its five host phases as
+    children, under the step's number and the engine's source."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+
+    cfg = get_gpt2_config("test")
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=GPT2LMHeadModel(cfg),
+        config={"train_batch_size": 8, "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+    assert not engine.telemetry.enabled
+    batch = {"input_ids": np.arange(8 * 32, dtype=np.int32).reshape(8, 32) % cfg.vocab_size}
+    for _ in range(3):
+        engine.train_batch(batch)
+    stack = {"input_ids": np.tile(batch["input_ids"][None], (2, 1, 1))}
+    engine.train_batches(stack)
+    records = trace.recorder().records(engine.telemetry.source)
+    steps = [r for r in records if r.name == "train_batch"]
+    assert [r.uid for r in steps] == [1, 2, 3, 4]     # the fused stack is one span, from step 4
+    for step in steps:
+        children = [r for r in records if r.uid == step.uid and r.path == ("train_batch",)]
+        assert [r.name for r in children] == ["timer_sync", "batch_stage", "dispatch",
+                                              "device_wait", "post_step"]
+        assert all(r.parent == "train_batch" for r in children)
+        assert all(step.start <= r.start <= r.end <= step.end for r in children)
+        assert sum(r.dur for r in children) <= step.dur
+
+
+def test_the_sink_adds_no_device_sync(tmp_path, monkeypatch):
+    """With the sink on, a step waits for the device where it does with it
+    off: in the throughput timer's two syncs, and nowhere else."""
+    import jax
+
+    from deepspeed_tpu.utils import timer
+
+    engine, _ = _train_run(tmp_path, n_steps=3)     # past the timer's first two, unsynced, steps
+    calls = {"block_until_ready": 0, "timer": 0}
+    real_block, real_sync = jax.block_until_ready, timer._device_sync
+
+    def block(x):
+        calls["block_until_ready"] += 1
+        return real_block(x)
+
+    def sync():
+        calls["timer"] += 1
+        return real_sync()
+
+    monkeypatch.setattr(jax, "block_until_ready", block)
+    monkeypatch.setattr(timer, "_device_sync", sync)
+    cfg_vocab = engine.module.config.vocab_size
+    batch = {"input_ids": np.arange(8 * 32, dtype=np.int32).reshape(8, 32) % cfg_vocab}
+    for _ in range(2):
+        engine.train_batch(batch)
+    assert calls == {"block_until_ready": 0, "timer": 4}
 
 
 def test_trace_report_round_trip_and_drift(tmp_path, capsys):
