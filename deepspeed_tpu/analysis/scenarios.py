@@ -574,11 +574,11 @@ def serve_decode_step() -> ProgramInfo:
         decode = build_decode_step(make_apply_fn(engine.module, engine._mparams),
                                    do_sample=False, temperature=1.0, top_k=0,
                                    top_p=1.0)
-        tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(engine.params, cache, tokens)
+        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(engine.params, cache, write_pos, tokens)
         return ProgramInfo(
             name="serve_decode_step", jaxpr=jaxpr, kind="serve_decode",
-            lower=lambda: jax.jit(decode).lower(engine.params, cache, tokens),
+            lower=lambda: jax.jit(decode).lower(engine.params, cache, write_pos, tokens),
             metadata={
                 "serve_slots": slots,
                 # the committed intent, env layer skipped — a forced env
@@ -663,11 +663,11 @@ def serve_quant_decode_step() -> ProgramInfo:
         decode = build_decode_step(make_apply_fn(module, engine._mparams),
                                    do_sample=False, temperature=1.0, top_k=0,
                                    top_p=1.0)
-        tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(params, cache, tokens)
+        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(params, cache, write_pos, tokens)
         return ProgramInfo(
             name="serve_quant_decode_step", jaxpr=jaxpr, kind="serve_decode",
-            lower=lambda: jax.jit(decode).lower(params, cache, tokens),
+            lower=lambda: jax.jit(decode).lower(params, cache, write_pos, tokens),
             metadata={
                 "serve_slots": slots,
                 # committed intent, env layer skipped — the drift anchor
@@ -729,11 +729,11 @@ def serve_prefix_decode_step() -> ProgramInfo:
         decode = build_decode_step(make_apply_fn(engine.module, engine._mparams),
                                    do_sample=False, temperature=1.0, top_k=0,
                                    top_p=1.0)
-        tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(engine.params, cache, tokens)
+        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(engine.params, cache, write_pos, tokens)
         return ProgramInfo(
             name="serve_prefix_decode_step", jaxpr=jaxpr, kind="serve_decode",
-            lower=lambda: jax.jit(decode).lower(engine.params, cache, tokens),
+            lower=lambda: jax.jit(decode).lower(engine.params, cache, write_pos, tokens),
             metadata={
                 "serve_slots": slots,
                 # committed intent, env layer skipped — the drift anchors
@@ -817,11 +817,11 @@ def rlhf_rollout_step() -> ProgramInfo:
         decode = build_decode_step(make_apply_fn(infer.module, infer._mparams),
                                    do_sample=False, temperature=1.0, top_k=0,
                                    top_p=1.0)
-        tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(infer.params, cache, tokens)
+        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(infer.params, cache, write_pos, tokens)
         return ProgramInfo(
             name="rlhf_rollout_step", jaxpr=jaxpr, kind="serve_decode",
-            lower=lambda: jax.jit(decode).lower(infer.params, cache, tokens),
+            lower=lambda: jax.jit(decode).lower(infer.params, cache, write_pos, tokens),
             metadata={
                 "serve_slots": slots,
                 "rlhf_weight_sync_plan": sync_plan,

@@ -22,8 +22,7 @@ from deepspeed_tpu.inference.serving.config import (ENV_KV_WRITE,
                                                     set_default_weight_dtype)
 from deepspeed_tpu.inference.serving.programs import (make_slot_cache,
                                                       serve_programs,
-                                                      slot_capacity,
-                                                      stamp_lengths)
+                                                      slot_capacity)
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      QUEUED, REFUSED, Request)
@@ -45,5 +44,5 @@ __all__ = [
     "serve_programs",
     "set_default_kv_write", "set_default_prefix_cache",
     "set_default_weight_dtype", "slot_capacity",
-    "stamp_lengths", "validate_event",
+    "validate_event",
 ]

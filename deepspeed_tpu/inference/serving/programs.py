@@ -3,13 +3,15 @@
 The compiled surface of graft-serve is THREE programs per slot bucket —
 a chunked prefill, a one-token decode step, and (with speculation) a
 k+1-token verify step — whose shapes never change while requests join
-and leave. Join/leave is positional, not structural: the cache's index
-leaves are [slots] WRITE-POSITION vectors the scheduler stamps from its
-host-side length mirror before every tick; a parked slot carries the
-sentinel position ``n_positions`` so its KV writes drop out of bounds
-and its (garbage, finite) logits are discarded on the host. Rollback
-after a rejected speculation is therefore free — the next tick's stamp
-simply doesn't advance past the accepted prefix.
+and leave. Join/leave is positional, not structural: every program takes
+the scheduler's host-side length mirror as ONE operand, ``write_pos
+[slots] int32``, and fills the cache's index leaves from it inside the
+trace (:func:`with_write_positions`) — the host never touches the cache
+between ticks, and what the carried index leaves hold is ignored. A
+parked slot carries the sentinel position ``n_positions`` so its KV
+writes drop out of bounds and its (garbage, finite) logits are discarded
+on the host. Rollback after a rejected speculation is therefore free —
+the next tick's operand simply doesn't advance past the accepted prefix.
 
 Programs are cached on the target :class:`InferenceEngine` keyed by the
 pow2 slot bucket (``engine._pow2_bucket`` — the same bucketing discipline
@@ -18,8 +20,6 @@ compilations instead of churning them.
 """
 
 from typing import Any, Callable, Dict, Optional
-
-import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -101,22 +101,22 @@ def slot_capacity(cache) -> int:
     raise ValueError("cache has no cached_key leaves — not a decode cache")
 
 
-def stamp_lengths(cache, write_pos: np.ndarray):
-    """Host-side stamp of the scheduler's authoritative per-slot write
-    positions into every index leaf (tiny [slots] arrays — the big KV
-    leaves pass through untouched, so donation chains tick to tick).
-    Each leaf gets its OWN device buffer: the cache is donated, and
-    donating one buffer through several leaves is an XLA error."""
-    pos = np.asarray(write_pos, np.int32)
+def with_write_positions(cache, write_pos):
+    """Traced: ``cache`` with every index leaf set to the tick's
+    ``write_pos [slots] int32`` operand — one value used by each block's
+    ``cache_index`` and the model's ``position_index``, no transfer. The
+    carried leaves' own values are dead (each comes back a fresh output
+    buffer, so the donated cache still chains tick to tick)."""
 
     def sub(path, leaf):
-        return jnp.array(pos) if _is_index_leaf(path) else leaf
+        return write_pos if _is_index_leaf(path) else leaf
 
     return jax.tree_util.tree_map_with_path(sub, cache)
 
 
 # ---------------------------------------------------------------------------
-# step builders: apply_fn(params, cache, ids) -> (logits [S, L, V], cache')
+# step builders: apply_fn(params, cache, ids) -> (logits [S, L, V], cache').
+# Every built step takes ``write_pos [slots] int32`` right after the cache.
 # ---------------------------------------------------------------------------
 def make_apply_fn(module, mparams: Optional[Callable] = None) -> Callable:
     """The one decode apply shared by every serving program (and by the
@@ -146,9 +146,10 @@ def make_apply_fn(module, mparams: Optional[Callable] = None) -> Callable:
 def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
                        top_k: int, top_p: float) -> Callable:
     """One chunked-prefill tick: consume ``ids [S, C]`` at each slot's own
-    write position. ``last_idx [S]`` names each slot's final REAL token in
-    the chunk (a short final chunk is right-padded; pad positions write
-    beyond the committed length, are re-written by later tokens, and —
+    write position (``write_pos [S]``). ``last_idx [S]`` names each slot's
+    final REAL token in the chunk (a short final chunk is right-padded; pad
+    positions write beyond the committed length, are re-written by later
+    tokens, and —
     because the per-slot causal mask bounds every query by its own
     position — are never attended by real queries). The chunk that
     completes a prompt samples the request's FIRST token from its
@@ -161,14 +162,14 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
         return jnp.take_along_axis(logits, last_idx[:, None, None], axis=1)[:, 0]
 
     if do_sample:
-        def prefill(params, cache, ids, last_idx, rng):
-            logits, cache = apply_fn(params, cache, ids)
+        def prefill(params, cache, write_pos, ids, last_idx, rng):
+            logits, cache = apply_fn(params, with_write_positions(cache, write_pos), ids)
             tok = sample_logits(last_logits(logits, last_idx), rng, True,
                                 temperature, top_k, top_p).astype(jnp.int32)
             return cache, tok
     else:
-        def prefill(params, cache, ids, last_idx):
-            logits, cache = apply_fn(params, cache, ids)
+        def prefill(params, cache, write_pos, ids, last_idx):
+            logits, cache = apply_fn(params, with_write_positions(cache, write_pos), ids)
             return cache, jnp.argmax(last_logits(logits, last_idx),
                                      axis=-1).astype(jnp.int32)
 
@@ -177,20 +178,22 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
 
 def build_decode_step(apply_fn, do_sample: bool, temperature: float,
                       top_k: int, top_p: float) -> Callable:
-    """One decode tick: feed each slot's token, sample the next. Greedy
-    builds a no-rng program (``decode(params, cache, tokens)``); sampling
-    adds an rng operand."""
+    """One decode tick: feed each slot's token at its write position,
+    sample the next. Greedy builds a no-rng program (``decode(params,
+    cache, write_pos, tokens)``); sampling adds an rng operand."""
     from deepspeed_tpu.inference.engine import sample_logits
 
     if do_sample:
-        def decode(params, cache, tokens, rng):
-            logits, cache = apply_fn(params, cache, tokens[:, None])
+        def decode(params, cache, write_pos, tokens, rng):
+            logits, cache = apply_fn(params, with_write_positions(cache, write_pos),
+                                     tokens[:, None])
             tok = sample_logits(logits[:, -1], rng, True, temperature,
                                 top_k, top_p).astype(jnp.int32)
             return cache, tok
     else:
-        def decode(params, cache, tokens):
-            logits, cache = apply_fn(params, cache, tokens[:, None])
+        def decode(params, cache, write_pos, tokens):
+            logits, cache = apply_fn(params, with_write_positions(cache, write_pos),
+                                     tokens[:, None])
             return cache, jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
 
     return decode
@@ -198,13 +201,13 @@ def build_decode_step(apply_fn, do_sample: bool, temperature: float,
 
 def build_verify_step(apply_fn) -> Callable:
     """Batched target verification for speculative decoding: feed the
-    k+1-token block ``[last_accepted, d_1..d_k]`` and return the target's
-    greedy token at EVERY position — the host accepts the longest draft
+    k+1-token block ``[last_accepted, d_1..d_k]`` at ``write_pos`` and
+    return the target's greedy token at EVERY position — the host accepts the longest draft
     prefix the target reproduces and emits the target's own token at the
     first divergence (lossless under greedy decoding by construction)."""
 
-    def verify(params, cache, tokens):
-        logits, cache = apply_fn(params, cache, tokens)
+    def verify(params, cache, write_pos, tokens):
+        logits, cache = apply_fn(params, with_write_positions(cache, write_pos), tokens)
         return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, K+1]
 
     return verify

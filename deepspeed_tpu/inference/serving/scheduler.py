@@ -11,11 +11,13 @@ a request is admitted only when its worst-case KV footprint is
 reservable, so nothing dies mid-flight and nothing leaks.
 
 Host protocol (the part that makes rollback and join/leave free): the
-scheduler's numpy ``lengths`` mirror is authoritative — every tick
-stamps it into the cache's index leaves. A parked slot carries the
-sentinel position (= slot capacity) so its writes drop out of bounds; a
-rejected speculation simply never advances the mirror past the accepted
-prefix.
+scheduler's numpy ``lengths`` mirror is authoritative — every tick hands
+it to its program as the ``write_pos`` operand, beside the tick's other
+small inputs, as host arrays the jitted call's own dispatch carries: the
+host never writes into the cache between ticks. A parked slot carries
+the sentinel position (= slot capacity) so its writes drop out of
+bounds; a rejected speculation simply never advances the mirror past the
+accepted prefix.
 
 Integration seams (the five the last PRs built):
 * resilience — :meth:`serve` wires a ``PreemptionGuard``; SIGTERM drains
@@ -47,7 +49,7 @@ from deepspeed_tpu.inference.serving.config import (ServingConfig,
                                                     set_default_weight_dtype)
 from deepspeed_tpu.inference.serving.programs import (KV_LEAVES, _leaf_name,
                                                       make_slot_cache, serve_programs,
-                                                      slot_capacity, stamp_lengths)
+                                                      slot_capacity)
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
@@ -143,6 +145,10 @@ class ContinuousBatchingScheduler:
         self._source = (telemetry.source if telemetry is not None
                         else trace.new_source("sched"))
         self._tick_no = 0
+        # explicit host-to-device transfers issued inside ticks; a tick's
+        # inputs ride its program's own dispatch, so it reads 0 without
+        # speculation (shown as 0, not left out)
+        self._rec.count("tick_input_puts", 0)
 
         # graft-quant-serve: resolve the served weight dtype (env outranks
         # config — the DS_SERVE_WQ drift seam, same layering as kv_write)
@@ -334,31 +340,32 @@ class ContinuousBatchingScheduler:
         no histograms, and not the sampling rng stream."""
         set_default_kv_write(self.config.kv_write)
         set_default_weight_dtype(self.config.weight_dtype)
-        parked = np.full(self.slots, self.capacity, np.int64)
+        parked = np.full(self.slots, self.capacity, np.int32)
         rng = ((jax.random.PRNGKey(0),) if self.config.do_sample else ())
-        C = self.config.prefill_chunk
-        ids = jax.numpy.zeros((self.slots, C), jax.numpy.int32)
-        last_idx = jax.numpy.zeros((self.slots,), jax.numpy.int32)
-        tok = jax.numpy.zeros((self.slots,), jax.numpy.int32)
-        block = jax.numpy.zeros((self.slots, self.spec_k + 1), jax.numpy.int32)
+        # host arrays, as every tick hands them over: what a jitted call is
+        # given is part of the key it caches its program under
+        ids = np.zeros((self.slots, self.config.prefill_chunk), np.int32)
+        last_idx = np.zeros(self.slots, np.int32)
+        tok = np.zeros(self.slots, np.int32)
+        block = np.zeros((self.slots, self.spec_k + 1), np.int32)
         # a spec-mode scheduler never runs the target's plain decode
         # (step() always spec-ticks) — don't pay its compile
-        target_calls = ([("prefill", (ids, last_idx) + rng)]
-                        + ([("verify", (block,))] if self.spec_k
-                           else [("decode", (tok,) + rng)]))
+        target_calls = ([("prefill", (parked, ids, last_idx) + rng)]
+                        + ([("verify", (parked, block))] if self.spec_k
+                           else [("decode", (parked, tok) + rng)]))
         per_role = [(self.fns, "_cache", self._serve_params, target_calls)]
         if self._drafter is not None:
             # the draft loop feeds decode a mesh-committed token (see
-            # _spec_tick); every other tick input arrives uncommitted
+            # _spec_tick); every other tick input arrives as a host array
             dtok = jax.device_put(tok, self._placement)  # graft-lint: waive R008 warmup operand placement parity w/ the draft loop, never donated
             per_role.append((self.dfns, "_drafter_cache", self._drafter[1],
-                             [("prefill", (ids, last_idx) + rng),
-                              ("decode", (dtok,) + rng), ("verify", (block,))]))
+                             [("prefill", (parked, ids, last_idx) + rng),
+                              ("decode", (parked, dtok) + rng),
+                              ("verify", (parked, block))]))
         for fns, cache_attr, params, calls in per_role:
             for name, args in calls:
                 if name in fns:
-                    cache = stamp_lengths(getattr(self, cache_attr), parked)
-                    cache, _ = fns[name](params, cache, *args)
+                    cache, _ = fns[name](params, getattr(self, cache_attr), *args)
                     setattr(self, cache_attr, cache)
 
     # ------------------------------------------------------------------
@@ -602,11 +609,12 @@ class ContinuousBatchingScheduler:
         try:
             from deepspeed_tpu.analysis.cost import static_price_from_jaxpr
             name = "verify" if self.spec_k else "decode"
+            write_pos = jax.numpy.zeros((self.slots,), jax.numpy.int32)
             if self.spec_k:
-                args = (jax.numpy.zeros((self.slots, self.spec_k + 1),
-                                        jax.numpy.int32),)
+                args = (write_pos, jax.numpy.zeros((self.slots, self.spec_k + 1),
+                                                   jax.numpy.int32))
             else:
-                args = (jax.numpy.zeros((self.slots,), jax.numpy.int32),)
+                args = (write_pos, write_pos)
                 if self.config.do_sample:
                     args += (jax.random.PRNGKey(0),)
             closed = jax.make_jaxpr(self.fns[name])(
@@ -739,26 +747,16 @@ class ContinuousBatchingScheduler:
         self._rec.count("prefill_positions_fed", sum(rems.values()))
         self._rec.count("prefill_positions_computed", self.slots * C)
         with self._phase("stamp"):
-            cache = stamp_lengths(self._cache, write_pos)
-            args = (self._serve_params, cache, jax.numpy.asarray(ids),
-                    jax.numpy.asarray(last_idx))
-            if self._drafter is not None:
-                d_module, d_params = self._drafter
-                d_cache = stamp_lengths(self._drafter_cache, write_pos)
-                d_args = (d_params, d_cache, jax.numpy.asarray(ids),
-                          jax.numpy.asarray(last_idx))
+            inputs = (write_pos.astype(np.int32), ids, last_idx)
         with self._phase("dispatch"):
             if self.config.do_sample:
                 self._rng, key = jax.random.split(self._rng)
-                self._cache, tok = self.fns["prefill"](*args, key)
-            else:
-                self._cache, tok = self.fns["prefill"](*args)
-            if self._drafter is not None:
-                if self.config.do_sample:
-                    self._rng, dkey = jax.random.split(self._rng)
-                    self._drafter_cache, _ = self.dfns["prefill"](*d_args, dkey)
-                else:
-                    self._drafter_cache, _ = self.dfns["prefill"](*d_args)
+                inputs += (key,)
+            self._cache, tok = self.fns["prefill"](self._serve_params, self._cache,
+                                                   *inputs)
+            if self._drafter is not None:  # speculation is greedy: no rng operand
+                self._drafter_cache, _ = self.dfns["prefill"](
+                    self._drafter[1], self._drafter_cache, *inputs)
         with self._phase("device_wait"):
             tok = np.asarray(tok)
         with self._phase("commit"):
@@ -793,14 +791,13 @@ class ContinuousBatchingScheduler:
         self._rec.count("decode_slots_fed", len(slots))
         self._rec.count("decode_slots_computed", self.slots)
         with self._phase("stamp"):
-            cache = stamp_lengths(self._cache, write_pos)
-            args = (self._serve_params, cache, jax.numpy.asarray(tokens))
+            inputs = (write_pos.astype(np.int32), tokens)
         with self._phase("dispatch"):
             if self.config.do_sample:
                 self._rng, key = jax.random.split(self._rng)
-                self._cache, tok = self.fns["decode"](*args, key)
-            else:
-                self._cache, tok = self.fns["decode"](*args)
+                inputs += (key,)
+            self._cache, tok = self.fns["decode"](self._serve_params, self._cache,
+                                                  *inputs)
         with self._phase("device_wait"):
             tok = np.asarray(tok)
         with self._phase("commit"):
@@ -820,7 +817,7 @@ class ContinuousBatchingScheduler:
         The drafter re-feeds the verify block only when some slot accepted
         every draft (its own pass never wrote the kth draft's KV)."""
         k = self.spec_k
-        d_module, d_params = self._drafter
+        d_params = self._drafter[1]
         with self._phase("build_inputs"):
             write_pos = np.full(self.slots, self.capacity, np.int64)
             for i in slots:
@@ -834,13 +831,14 @@ class ContinuousBatchingScheduler:
         # matches iterations 2..k (which feed the previous jit output back);
         # an uncommitted first feed would cost a second decode compile
         with self._phase("stamp"):
+            write_pos = write_pos.astype(np.int32)
+            self._rec.count("tick_input_puts")
             cur = jax.device_put(first, self._placement)  # graft-lint: waive R008 host token mirror to mesh placement, never donated
         drafts = []
         for j in range(k):
-            with self._phase("stamp"):
-                d_cache = stamp_lengths(self._drafter_cache, write_pos + j)
             with self._phase("dispatch"):
-                self._drafter_cache, cur = self.dfns["decode"](d_params, d_cache, cur)
+                self._drafter_cache, cur = self.dfns["decode"](
+                    d_params, self._drafter_cache, write_pos + j, cur)
             drafts.append(cur)
         with self._phase("device_wait"):
             drafts = np.stack([np.asarray(d) for d in drafts], axis=1)  # [S, k]
@@ -849,11 +847,9 @@ class ContinuousBatchingScheduler:
             for i in slots:
                 block[i, 0] = self._next_token[i]
                 block[i, 1:] = drafts[i]
-        with self._phase("stamp"):
-            cache = stamp_lengths(self._cache, write_pos)
-            block_dev = jax.numpy.asarray(block)
         with self._phase("dispatch"):
-            self._cache, greedy = self.fns["verify"](self._serve_params, cache, block_dev)
+            self._cache, greedy = self.fns["verify"](self._serve_params, self._cache,
+                                                     write_pos, block)
         with self._phase("device_wait"):
             greedy = np.asarray(greedy)  # [S, k+1] target argmax per position
         refeed = False
@@ -885,10 +881,9 @@ class ContinuousBatchingScheduler:
                 self._next_token[i] = emitted[-1]
                 self._maybe_finish(i, now)
         if refeed and any(self._slot_req[i] is not None for i in slots):
-            with self._phase("stamp"):
-                d_cache = stamp_lengths(self._drafter_cache, write_pos)
             with self._phase("dispatch"):
-                self._drafter_cache, _ = self.dfns["verify"](d_params, d_cache, block_dev)
+                self._drafter_cache, _ = self.dfns["verify"](
+                    d_params, self._drafter_cache, write_pos, block)
 
     # ------------------------------------------------------------------
     # live KV migration (graft-fleet)

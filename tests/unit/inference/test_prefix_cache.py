@@ -648,7 +648,7 @@ def test_decode_program_identical_cache_on_vs_off(engine_cfg):
             cache = make_slot_cache(engine.module, 4)
             decode = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
             toks = jnp.zeros((4,), jnp.int32)
-            return str(jax.make_jaxpr(decode)(engine.params, cache, toks))
+            return str(jax.make_jaxpr(decode)(engine.params, cache, toks, toks))
         finally:
             set_default_prefix_cache(None)
 

@@ -173,10 +173,11 @@ def test_serving_program_compiles(one_chip, program, attention):
     apply_fn = make_apply_fn(module)
     if program == "prefill":
         step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
-        operands = (_shape(SLOTS, CHUNK, dtype=jnp.int32), _shape(SLOTS, dtype=jnp.int32))
+        operands = (_shape(SLOTS, dtype=jnp.int32), _shape(SLOTS, CHUNK, dtype=jnp.int32),
+                    _shape(SLOTS, dtype=jnp.int32))
     else:
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
-        operands = (_shape(SLOTS, dtype=jnp.int32),)
+        operands = (_shape(SLOTS, dtype=jnp.int32), _shape(SLOTS, dtype=jnp.int32))
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     assert ("tpu_custom_call" in compiled.as_text()) == (attention == "flash")
 

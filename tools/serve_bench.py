@@ -221,8 +221,8 @@ def serve_evidence(engine, slots, wq="fp", kv_quant=False):
         cache = make_slot_cache(module, slots, kv_quant=kv_quant)
         decode = build_decode_step(make_apply_fn(module, engine._mparams),
                                    False, 1.0, 0, 1.0)
-        tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(params, cache, tokens)
+        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(params, cache, write_pos, tokens)
         info = ProgramInfo(name="serve_decode", jaxpr=jaxpr, kind="serve_decode")
         findings, _ = analysis.run_program_rules(info)
         mem = estimate_memory(info)
