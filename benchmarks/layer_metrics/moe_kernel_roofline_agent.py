@@ -1,0 +1,22 @@
+"""The expert layer's Mosaic kernels' share of their roofline: the least
+time the chip could take for the routed expert matmuls and the row
+permutations of every tick in the traced slice (each tick at its kind's
+mean shape; ``lib/opcounts_olmoe.py``: routed FLOPs, the touched experts'
+weights once, each routed row in and out) over those kernels' device time
+(``pallas:moe:*``, as the family's ``op_label`` names them)."""
+
+from benchmarks.lib import harness, olmoe_ticks, program_spans, reducers
+
+
+def read(ctx):
+    kernel_s = reducers.op_seconds(ctx, "^pallas:moe")
+    if not kernel_s or ctx["peaks"] is None:
+        return None
+    ticks = olmoe_ticks.traced_ticks(ctx["trace"]["window_s"])
+    if not ticks:
+        return None
+    least_s = olmoe_ticks.moe_kernels_least_s(ctx["cell"].config, program_spans.ring()[1],
+                                              ctx["counters"], ctx["peaks"], ticks)
+    harness.log(moe_kernel_roofline={"traced_ticks": ticks, "kernel_s": kernel_s,
+                                     "least_s": least_s})
+    return 100.0 * least_s / kernel_s if least_s else None

@@ -24,11 +24,11 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+# the cache's leaf names are the models' (``models/common.py`` DecodeCache):
+# INDEX_LEAVES hold write positions (scalar in ``generate``'s lockstep
+# cache; [slots] vectors in the serving cache), KV_LEAVES are the pools
+from deepspeed_tpu.models.common import INDEX_LEAVES, KV_LEAVES
 from deepspeed_tpu.utils import trace
-
-#: cache leaves that hold write positions (scalar in ``generate``'s
-#: lockstep cache; [slots] vectors in the serving cache)
-INDEX_LEAVES = ("cache_index", "position_index")
 
 
 def _leaf_name(path) -> str:
@@ -38,10 +38,6 @@ def _leaf_name(path) -> str:
 
 def _is_index_leaf(path) -> bool:
     return _leaf_name(path) in INDEX_LEAVES
-
-
-#: KV pool leaves (``models/gpt2.py`` SelfAttention decode cache)
-KV_LEAVES = ("cached_key", "cached_value")
 
 
 def make_slot_cache(module, slots: int, kv_quant: bool = False):
@@ -96,7 +92,7 @@ def slot_capacity(cache) -> int:
     """Token capacity per slot = the KV pool's position extent (also the
     parked-slot sentinel: a write at this position drops out of bounds)."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        if _leaf_name(path) in ("cached_key", "cached_value"):
+        if _leaf_name(path) in KV_LEAVES:
             return int(leaf.shape[1])
     raise ValueError("cache has no cached_key leaves — not a decode cache")
 

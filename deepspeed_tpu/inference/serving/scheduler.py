@@ -149,6 +149,9 @@ class ContinuousBatchingScheduler:
         # inputs ride its program's own dispatch, so it reads 0 without
         # speculation (shown as 0, not left out)
         self._rec.count("tick_input_puts", 0)
+        # an expert model says what its expert matmuls owe and are given
+        # (``moe_rows``); a dense model has no such counters
+        self._moe_rows = getattr(engine.module, "moe_rows", None)
 
         # graft-quant-serve: resolve the served weight dtype (env outranks
         # config — the DS_SERVE_WQ drift seam, same layering as kv_write)
@@ -295,7 +298,8 @@ class ContinuousBatchingScheduler:
     def _probe_slot_decode(self) -> None:
         """Fail at construction — with the model family named — when the
         module's decode path cannot take a per-slot index vector (only
-        families with ragged-decode support, e.g. GPT-2, can serve)."""
+        families whose attention appends through ``models/common.py``'s
+        ``DecodeCache``, GPT-2 and the llama family, can serve)."""
         try:
             import jax.numpy as jnp
 
@@ -323,6 +327,17 @@ class ContinuousBatchingScheduler:
             if name in KV_LEAVES or name.endswith("_scale"):
                 total += leaf.size * leaf.dtype.itemsize
         return total / float(self.slots * self.capacity)
+
+    def _count_moe_rows(self, fed: int, computed: int) -> None:
+        """One target forward pass over ``computed`` positions, ``fed`` of
+        them real: rows routed to experts against rows the expert matmuls
+        run over (parked slots, chunk padding and the buffer's own)."""
+        if self._moe_rows is None:
+            return
+        per_position, rows = self._moe_rows(computed)
+        if rows:
+            self._rec.count("moe_rows_routed", fed * per_position)
+            self._rec.count("moe_rows_computed", rows)
 
     def _phase(self, name: str):
         """A host phase of the tick in progress: a child span of ``tick``."""
@@ -744,8 +759,10 @@ class ContinuousBatchingScheduler:
                 write_pos[i] = self._lengths[i]
         # the fixed-shape program computes slots x chunk positions whatever
         # it is fed: the ratio is the prefill program's fill
-        self._rec.count("prefill_positions_fed", sum(rems.values()))
+        fed = sum(rems.values())
+        self._rec.count("prefill_positions_fed", fed)
         self._rec.count("prefill_positions_computed", self.slots * C)
+        self._count_moe_rows(fed, self.slots * C)
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32), ids, last_idx)
         with self._phase("dispatch"):
@@ -790,6 +807,7 @@ class ContinuousBatchingScheduler:
                 tokens[i] = self._next_token[i]
         self._rec.count("decode_slots_fed", len(slots))
         self._rec.count("decode_slots_computed", self.slots)
+        self._count_moe_rows(len(slots), self.slots)
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32), tokens)
         with self._phase("dispatch"):
@@ -827,6 +845,7 @@ class ContinuousBatchingScheduler:
                                 for i in range(self.slots)], np.int32)
         self._rec.count("decode_slots_fed", len(slots))
         self._rec.count("decode_slots_computed", self.slots)
+        self._count_moe_rows(len(slots) * (k + 1), self.slots * (k + 1))  # the verify pass
         # committed to the mesh placement so iteration 1's input sharding
         # matches iterations 2..k (which feed the previous jit output back);
         # an uncommitted first feed would cost a second decode compile

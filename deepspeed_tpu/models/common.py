@@ -1,7 +1,7 @@
 """Shared model-zoo helpers."""
 
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,137 @@ def init_cache(model: nn.Module, batch_size: int, rng=None):
     kwargs = {"decoder_input_ids": ids} if is_seq2seq_module(model) else {}
     shapes = jax.eval_shape(lambda: model.init(rng, ids, decode=True, **kwargs))
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes["cache"])
+
+
+#: names of the decode cache's leaves, for whoever walks a cache without the
+#: model (``inference/serving``): the pools of :class:`DecodeCache` (an int8
+#: pool has a ``<name>_scale`` beside it), and the leaves that hold write
+#: positions: each layer's ``cache_index``, and the ``position_index`` a
+#: model with a learned position table (GPT-2) keeps at its top level
+KV_LEAVES = ("cached_key", "cached_value")
+INDEX_LEAVES = ("cache_index", "position_index")
+
+
+class DecodeCache:
+    """One attention layer's decode cache in the flax ``cache`` collection
+    (the reference's inference workspace, ``inference_context.h``): static
+    pools ``cached_key`` / ``cached_value`` [batch, positions, kv heads,
+    head dim] and the write index ``cache_index``. The one implementation
+    every decoder-only family's attention calls, so a change to the serving
+    cache is made once.
+
+    What the *provided* cache looks like decides, statically, which branch
+    traces:
+
+    * ``cache_index`` a scalar: lockstep decode (``generate``): every
+      sequence appends at the same position, one ``dynamic_update_slice``.
+    * ``cache_index`` a ``[batch]`` vector (``serving.make_slot_cache``):
+      each slot of an in-flight batch appends at its own length. Join and
+      leave are positional: a parked slot's sentinel position (>= the
+      pool's extent) makes its scatter writes drop out of bounds, no
+      ``jnp.where`` over the pool.
+    * int8 pools (``make_slot_cache(kv_quant=True)``, the serving
+      default): codes plus per-(slot, position, head) ``_scale`` leaves,
+      quantized on write and dequantized on read.
+    """
+
+    def __init__(self, module: nn.Module, batch: int, positions: int, kv_heads: int,
+                 head_dim: int, dtype):
+        shape = (batch, positions, kv_heads, head_dim)
+        self.key = module.variable("cache", "cached_key", jnp.zeros, shape, dtype)
+        self.value = module.variable("cache", "cached_value", jnp.zeros, shape, dtype)
+        self.quantized = self.key.value.dtype == jnp.int8
+        if self.quantized:
+            self.key_scale = module.variable("cache", "cached_key_scale", jnp.zeros,
+                                             shape[:-1] + (1,), dtype)
+            self.value_scale = module.variable("cache", "cached_value_scale", jnp.zeros,
+                                               shape[:-1] + (1,), dtype)
+        self.index = module.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
+
+    @property
+    def per_slot(self) -> bool:
+        return self.index.value.ndim > 0
+
+    def positions(self, length: int):
+        """[batch, length] positions of the ``length`` tokens about to be
+        appended (what RoPE rotates by)."""
+        idx = self.index.value
+        start = idx[:, None] if self.per_slot else idx
+        return jnp.broadcast_to(start + jnp.arange(length)[None, :],
+                                (self.key.value.shape[0], length))
+
+    def append(self, k, v, read_dtype, kv_write: Optional[str] = None):
+        """Write ``k`` / ``v`` [batch, l, kv heads, head dim] at the index,
+        advance it, and return ``(keys, values, decode_lengths)``: the whole
+        pools as attention reads them (``read_dtype`` values, HBM holds the
+        codes) and each sequence's live length."""
+        b, l = k.shape[0], k.shape[1]
+        idx = self.index.value
+        if self.per_slot:
+            self._append_per_slot(k, v, kv_write)
+            decode_lengths = idx + l
+        else:
+            if self.quantized:
+                raise NotImplementedError(
+                    "int8 KV pools are a per-slot serving cache "
+                    "(make_slot_cache(kv_quant=True)); lockstep decode uses fp KV")
+            self.key.value = jax.lax.dynamic_update_slice(self.key.value, k, (0, idx, 0, 0))
+            self.value.value = jax.lax.dynamic_update_slice(self.value.value, v, (0, idx, 0, 0))
+            # per-sequence live lengths: the flash backend's decode kernel
+            # skips dead KV blocks, the XLA backend masks by them
+            decode_lengths = jnp.broadcast_to(idx + l, (b,))
+        self.index.value = idx + l
+        if self.quantized:
+            # gather-dequant: attention reads fp values, HBM holds codes
+            return (self.key.value.astype(read_dtype) * self.key_scale.value,
+                    self.value.value.astype(read_dtype) * self.value_scale.value,
+                    decode_lengths)
+        return self.key.value, self.value.value, decode_lengths
+
+    def _append_per_slot(self, k, v, kv_write):
+        from deepspeed_tpu.inference.serving.config import resolve_kv_write
+        mode, _ = resolve_kv_write(kv_write)
+        b, l = k.shape[0], k.shape[1]
+        extent = self.key.value.shape[1]
+        pos = self.positions(l)  # [b, l]
+        pools = [(self.key, k), (self.value, v)]
+        if self.quantized:
+            (k, k_s), (v, v_s) = _kv_quantize(k), _kv_quantize(v)
+            pools = [(self.key, k), (self.value, v),
+                     (self.key_scale, k_s), (self.value_scale, v_s)]
+        if mode == "dense":
+            # masked full-pool rebuild: one [b, l, P] one-hot and a
+            # [b, P, h, d] temporary PER LAYER per tick — kept as the
+            # DS_SERVE_KV_WRITE seeded regression for the R010 gate
+            # (semantically identical: out-of-bounds one-hot rows are
+            # zero, so parked slots still drop their writes)
+            onehot = jax.nn.one_hot(pos, extent, dtype=jnp.float32)
+            written = (onehot.sum(1) > 0)[..., None, None]  # [b, P, 1, 1]
+            for pool, vals in pools:
+                upd = jnp.einsum("blp,blhd->bphd", onehot, vals.astype(jnp.float32))
+                if vals.dtype == jnp.int8:
+                    # int8 codes survive the fp32 einsum exactly
+                    # (±127 ≪ 2^24); rint guards the cast back
+                    upd = jnp.rint(upd)
+                pool.value = jnp.where(written, upd.astype(pool.value.dtype), pool.value)
+        else:
+            bidx = jnp.arange(b)[:, None]
+            # default scatter mode drops out-of-bounds updates — exactly
+            # the parked-slot contract
+            for pool, vals in pools:
+                pool.value = pool.value.at[bidx, pos].set(vals)
+
+
+def _kv_quantize(vals):
+    """Per-(slot, token, head) symmetric int8 KV quantization through the
+    one grouped quantizer in the repo (``ops/quantizer/core``). The
+    last-axis form keeps the reduce on the (unsharded) head_dim axis, so
+    a head-sharded KV write on a tensor mesh quantizes in place instead
+    of all-gathering the pool. Returns (codes [b, l, h, d] int8,
+    scales [b, l, h, 1] in KV dtype)."""
+    from deepspeed_tpu.ops.quantizer.core import quantize_lastaxis
+    codes, scale = quantize_lastaxis(vals, num_bits=8)
+    return codes, scale.astype(vals.dtype)
 
 
 def dense_init(scale: float = 0.02):

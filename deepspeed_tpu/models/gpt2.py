@@ -143,18 +143,6 @@ def _serve_quant_mode(module, cfg) -> str:
     return mode
 
 
-def _kv_quantize(vals):
-    """Per-(slot, token, head) symmetric int8 KV quantization through the
-    one grouped quantizer in the repo (``ops/quantizer/core``). The
-    last-axis form keeps the reduce on the (unsharded) head_dim axis, so
-    a head-sharded KV write on a tensor mesh quantizes in place instead
-    of all-gathering the pool. Returns (codes [b, l, h, d] int8,
-    scales [b, l, h, 1] in KV dtype)."""
-    from deepspeed_tpu.ops.quantizer.core import quantize_lastaxis
-    codes, scale = quantize_lastaxis(vals, num_bits=8)
-    return codes, scale.astype(vals.dtype)
-
-
 class QKVProj(nn.Module):
     """QKV projection over ONE fused ``[E, 3, H, D]`` parameter (the exact
     layout/init ``nn.DenseGeneral(features=(3, H, D))`` declared here
@@ -260,96 +248,14 @@ class SelfAttention(nn.Module):
             dropout_rng = self.make_rng("dropout")
         causal, decode_lengths = True, None
         if self.decode:
-            # incremental decoding against a static-shape KV cache (the
-            # reference's inference workspace, inference_context.h)
-            b, l = x.shape[0], x.shape[1]
-            cached_k = self.variable("cache", "cached_key", jnp.zeros,
-                                     (b, cfg.n_positions, cfg.n_head, cfg.head_dim), k.dtype)
-            cached_v = self.variable("cache", "cached_value", jnp.zeros,
-                                     (b, cfg.n_positions, cfg.n_head, cfg.head_dim), v.dtype)
-            # int8 KV pools (graft-quant-serve, the serving default): codes
-            # plus per-(slot, position, head) scales, quantize-on-write /
-            # dequantize-on-read — PagedKVCache(quantize=True) applied to
-            # the per-slot cache. Only serving.make_slot_cache(kv_quant=
-            # True) builds these pools, so which path traces is decided by
-            # the provided cache dtype, statically.
-            kv_q = cached_k.value.dtype == jnp.int8
-            if kv_q:
-                k_scale = self.variable("cache", "cached_key_scale", jnp.zeros,
-                                        (b, cfg.n_positions, cfg.n_head, 1), k.dtype)
-                v_scale = self.variable("cache", "cached_value_scale", jnp.zeros,
-                                        (b, cfg.n_positions, cfg.n_head, 1), v.dtype)
-            cache_index = self.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
-            idx = cache_index.value
-            if idx.ndim:
-                # graft-serve per-slot ragged cache: ``cache_index`` arrives
-                # as a [B] write-position vector (serving.make_slot_cache),
-                # so every slot of an in-flight batch appends at its OWN
-                # length — the join/leave masking is positional: a parked
-                # slot's sentinel position (>= n_positions) makes its
-                # scatter writes drop out of bounds, no jnp.where over the
-                # pool. decode_lengths becomes genuinely per-slot, which
-                # the attention backends already mask per sequence.
-                from deepspeed_tpu.inference.serving.config import resolve_kv_write
-                mode, _ = resolve_kv_write(getattr(cfg, "serve_kv_write", None))
-                pos = idx[:, None] + jnp.arange(l)[None, :]  # [b, l]
-                if kv_q:
-                    k_w, k_s = _kv_quantize(k)
-                    v_w, v_s = _kv_quantize(v)
-                else:
-                    k_w, v_w = k, v
-                if mode == "dense":
-                    # masked full-pool rebuild: one [b, l, P] one-hot and a
-                    # [b, P, h, d] temporary PER LAYER per tick — kept as the
-                    # DS_SERVE_KV_WRITE seeded regression for the R010 gate
-                    # (semantically identical: out-of-bounds one-hot rows are
-                    # zero, so parked slots still drop their writes)
-                    onehot = jax.nn.one_hot(pos, cfg.n_positions, dtype=jnp.float32)
-                    written = (onehot.sum(1) > 0)[..., None, None]  # [b, P, 1, 1]
-
-                    def _dense_put(pool, vals, round_int=False):
-                        upd = jnp.einsum("blp,blhd->bphd", onehot,
-                                         vals.astype(jnp.float32))
-                        if round_int:
-                            # int8 codes survive the fp32 einsum exactly
-                            # (±127 ≪ 2^24); rint guards the cast back
-                            upd = jnp.rint(upd)
-                        return jnp.where(written, upd.astype(pool.dtype), pool)
-
-                    cached_k.value = _dense_put(cached_k.value, k_w, round_int=kv_q)
-                    cached_v.value = _dense_put(cached_v.value, v_w, round_int=kv_q)
-                    if kv_q:
-                        k_scale.value = _dense_put(k_scale.value, k_s)
-                        v_scale.value = _dense_put(v_scale.value, v_s)
-                else:
-                    bidx = jnp.arange(b)[:, None]
-                    # default scatter mode drops out-of-bounds updates —
-                    # exactly the parked-slot contract
-                    cached_k.value = cached_k.value.at[bidx, pos].set(k_w)
-                    cached_v.value = cached_v.value.at[bidx, pos].set(v_w)
-                    if kv_q:
-                        k_scale.value = k_scale.value.at[bidx, pos].set(k_s)
-                        v_scale.value = v_scale.value.at[bidx, pos].set(v_s)
-                decode_lengths = idx + l
-            else:
-                if kv_q:
-                    raise NotImplementedError(
-                        "int8 KV pools are a per-slot serving cache "
-                        "(make_slot_cache(kv_quant=True)); lockstep decode "
-                        "uses fp KV")
-                cached_k.value = jax.lax.dynamic_update_slice(cached_k.value, k, (0, idx, 0, 0))
-                cached_v.value = jax.lax.dynamic_update_slice(cached_v.value, v, (0, idx, 0, 0))
-                # per-sequence live-length vector — the flash backend's decode
-                # kernel skips dead KV blocks; the XLA backend derives the
-                # validity mask from it
-                decode_lengths = jnp.broadcast_to(idx + l, (b,))
-            cache_index.value = idx + l
-            if kv_q:
-                # gather-dequant: attention reads fp values, HBM holds codes
-                k = cached_k.value.astype(q.dtype) * k_scale.value
-                v = cached_v.value.astype(q.dtype) * v_scale.value
-            else:
-                k, v = cached_k.value, cached_v.value
+            # incremental decoding against the static-shape KV cache every
+            # family shares (models/common.py DecodeCache: lockstep or
+            # per-slot writes, fp or int8 pools, decided by the cache handed in)
+            from deepspeed_tpu.models.common import DecodeCache
+            cache = DecodeCache(self, x.shape[0], cfg.n_positions, cfg.n_head, cfg.head_dim,
+                                k.dtype)
+            k, v, decode_lengths = cache.append(k, v, q.dtype,
+                                                getattr(cfg, "serve_kv_write", None))
             causal = False
         from deepspeed_tpu.models.common import attention_geometry_kwargs
         attn_out = dot_product_attention(q,

@@ -1,4 +1,5 @@
-"""LLaMA family — RMSNorm + RoPE + SwiGLU + GQA decoder
+"""LLaMA family — RMSNorm + RoPE + SwiGLU + GQA decoder; Mixtral, Qwen2
+and OLMoE are configurations of it
 (judged config ladder includes LLaMA-7B ZeRO-3 + ZeRO++, BASELINE.md; the
 reference supports LLaMA through kernel injection,
 ``module_inject/containers/llama.py``).
@@ -9,9 +10,10 @@ TPU-first notes, same conventions as ``models/gpt2.py``:
 * attention goes through the pluggable backend seam (xla/flash/ring);
 * a flax ``cache`` collection implements incremental decoding (the role of
   the reference's KV-cache workspace,
-  ``csrc/transformer/inference/includes/inference_context.h``) — static
-  cache shape ``[batch, max_len, kv_heads, head_dim]`` with a scalar write
-  index, jit-friendly.
+  ``csrc/transformer/inference/includes/inference_context.h``): the decode
+  cache every family shares (``models/common.py`` ``DecodeCache``), so the
+  server's per-slot int8 cache serves this family as it serves GPT-2; RoPE
+  rotates by each slot's own write position.
 """
 
 import dataclasses
@@ -21,7 +23,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.common import (config_from, dense_init as _init,
+from deepspeed_tpu.models.common import (DecodeCache, config_from, dense_init as _init,
                                          normalize_padding_mask, rms_norm)
 from deepspeed_tpu.ops.transformer.attention import dot_product_attention
 
@@ -50,6 +52,14 @@ class LlamaConfig:
     # env/config/autotune layers
     attention_blocks: Optional[str] = None
     attention_bias: bool = False  # Qwen2-style biased q/k/v projections
+    # OLMoE-style QK-norm: an RMSNorm over the whole projected q and the
+    # whole projected k (all heads at once), before the split into heads
+    # and RoPE
+    qk_norm: bool = False
+    # positions the decode cache holds per sequence; None = the context,
+    # ``max_position_embeddings`` (RoPE needs no table, so a server may
+    # reserve less than the context for each slot)
+    decode_cache_len: Optional[int] = None
     # Mistral-style sliding-window attention: each token attends the last
     # ``sliding_window`` positions. Training/prefill only — the flash
     # kernel skips out-of-window blocks (O(L*window)); decode attends the
@@ -64,7 +74,14 @@ class LlamaConfig:
     # moe_layer_freq-th layer replaces the SwiGLU MLP with experts)
     moe_num_experts: int = 0  # 0 = dense
     moe_layer_freq: int = 1   # Mixtral: every layer
-    moe_k: int = 2            # Mixtral: top-2
+    moe_k: int = 2            # Mixtral: top-2; any k <= experts (OLMoE: 8 of 64)
+    # renormalise the k chosen experts' weights (Mixtral) or keep their
+    # softmax values (OLMoE: ``norm_topk_prob`` false)
+    moe_norm_topk_prob: bool = True
+    # False: no token is ever dropped (OLMoE). The experts are then one
+    # bank and a tick's token copies are grouped by expert with no padding
+    # (moe/sharded_moe.py); the capacity factors are not used
+    moe_drop_tokens: bool = True
     moe_capacity_factor: float = 1.25
     moe_eval_capacity_factor: float = 2.0  # serving must not under-provision vs training
     moe_min_capacity: int = 4
@@ -102,6 +119,19 @@ LLAMA_CONFIGS = {
     "mixtral-test": dict(vocab_size=256, hidden_size=64, intermediate_size=128,
                          num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
                          max_position_embeddings=128, moe_num_experts=4, moe_k=2),
+    # OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct): every FFN is 64 SwiGLU
+    # experts of width 1024, top-8 with the softmax values as weights, no
+    # token dropped; QK-norm; 1.3 B of 6.9 B parameters active per token
+    "olmoe-1b-7b": dict(vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+                        num_hidden_layers=16, num_attention_heads=16, num_key_value_heads=16,
+                        max_position_embeddings=4096, rms_norm_eps=1e-5, rope_theta=10000.0,
+                        qk_norm=True, moe_num_experts=64, moe_k=8, moe_norm_topk_prob=False,
+                        moe_drop_tokens=False),
+    "olmoe-test": dict(vocab_size=256, hidden_size=64, intermediate_size=32,
+                       num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+                       max_position_embeddings=128, rms_norm_eps=1e-5, qk_norm=True,
+                       moe_num_experts=8, moe_k=2, moe_norm_topk_prob=False,
+                       moe_drop_tokens=False),
     # Qwen2 family: llama architecture + biased q/k/v projections
     "qwen2-7b": dict(vocab_size=152064, hidden_size=3584, intermediate_size=18944,
                      num_hidden_layers=28, num_attention_heads=28, num_key_value_heads=4,
@@ -166,6 +196,12 @@ class LlamaAttention(nn.Module):
         q = proj(cfg.num_attention_heads, "q_proj")(x)
         k = proj(cfg.num_key_value_heads, "k_proj")(x)
         v = proj(cfg.num_key_value_heads, "v_proj")(x)
+        if cfg.qk_norm:
+            def whole(t, name):
+                # over every head's features at once, as published
+                flat = RMSNorm(cfg, name=name)(t.reshape(b, l, -1))
+                return flat.reshape(t.shape)
+            q, k = whole(q, "q_norm"), whole(k, "k_norm")
 
         causal = True
         decode_lengths = None
@@ -173,31 +209,23 @@ class LlamaAttention(nn.Module):
         # mask). In decode mode L must span the cache (max_position_embeddings).
         mask = normalize_padding_mask(attention_mask)
         if decode:
-            # static-shape KV cache (flax convention: cache collection)
-            cached_k = self.variable("cache", "cached_key",
-                                     jnp.zeros, (b, cfg.max_position_embeddings,
-                                                 cfg.num_key_value_heads, cfg.head_dim), k.dtype)
-            cached_v = self.variable("cache", "cached_value",
-                                     jnp.zeros, (b, cfg.max_position_embeddings,
-                                                 cfg.num_key_value_heads, cfg.head_dim), v.dtype)
-            cache_index = self.variable("cache", "cache_index",
-                                        lambda: jnp.zeros([], jnp.int32))
-            idx = cache_index.value
-            if positions is None:
-                positions = idx + jnp.broadcast_to(jnp.arange(l)[None, :], (b, l))
+            # static-shape KV cache, lockstep or per serving slot, fp or int8
+            # (models/common.py DecodeCache; the cache handed in decides)
+            cache = DecodeCache(self, b, cfg.decode_cache_len or cfg.max_position_embeddings,
+                                cfg.num_key_value_heads, cfg.head_dim, k.dtype)
+            given = positions is not None
+            if not given:
+                positions = cache.positions(l)
             q = rotary_embedding(q, positions, cfg.rope_theta)
             k = rotary_embedding(k, positions, cfg.rope_theta)
-            cached_k.value = jax.lax.dynamic_update_slice(cached_k.value, k, (0, idx, 0, 0))
-            cached_v.value = jax.lax.dynamic_update_slice(cached_v.value, v, (0, idx, 0, 0))
-            cache_index.value = idx + l
-            k = cached_k.value
-            v = cached_v.value
-            # per-sequence live lengths (positions may differ per batch row);
-            # the backend derives causal validity over cache slots from them —
-            # flash's decode kernel additionally skips dead KV blocks' DMA.
-            # Any caller padding mask rides alongside (flash falls back to
-            # XLA when both are present).
-            decode_lengths = positions[:, -1] + 1
+            k, v, decode_lengths = cache.append(k, v, q.dtype)
+            if given:
+                # per-sequence live lengths (positions may differ per batch
+                # row); the backend derives causal validity over cache slots
+                # from them — flash's decode kernel additionally skips dead
+                # KV blocks' DMA. Any caller padding mask rides alongside
+                # (flash falls back to XLA when both are present).
+                decode_lengths = positions[:, -1] + 1
             causal = False
         else:
             if positions is None:
@@ -226,21 +254,60 @@ class LlamaAttention(nn.Module):
 
 class LlamaMLP(nn.Module):
     """SwiGLU MLP (reference fused GEGLU/gated-mlp inference kernels,
-    ``csrc/transformer/inference/csrc/gelu.cu`` fused_gemm_gelu family)."""
+    ``csrc/transformer/inference/csrc/gelu.cu`` fused_gemm_gelu family).
+
+    ``num_experts`` > 0 makes it a bank: every kernel carries a leading
+    expert axis, under the paths and shapes ``moe.Experts``' vmap gives a
+    single MLP's. A bank takes the capacity layout ``[..., E, C, M]`` or,
+    with ``group_sizes`` [E], rows ``[R, M]`` sorted by expert with no
+    padding between the groups (the drop-free sorted route)."""
 
     config: LlamaConfig
+    num_experts: int = 0
 
     @nn.compact
-    def __call__(self, x, deterministic: bool = True):
+    def __call__(self, x, deterministic: bool = True, group_sizes=None, impl: str = "xla"):
         cfg = self.config
+        if not self.num_experts:
+            def dense(feat, names, name):
+                return nn.Dense(features=feat, use_bias=False, dtype=cfg.dtype,
+                                param_dtype=cfg.param_dtype,
+                                kernel_init=nn.with_logical_partitioning(_init(), names),
+                                name=name)
 
-        def dense(feat, names, name):
-            return nn.Dense(features=feat, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                            kernel_init=nn.with_logical_partitioning(_init(), names), name=name)
+            gate = dense(cfg.intermediate_size, ("embed", "mlp"), "gate_proj")(x)
+            up = dense(cfg.intermediate_size, ("embed", "mlp"), "up_proj")(x)
+            return dense(cfg.hidden_size, ("mlp", "embed"), "down_proj")(jax.nn.silu(gate) * up)
 
-        gate = dense(cfg.intermediate_size, ("embed", "mlp"), "gate_proj")(x)
-        up = dense(cfg.intermediate_size, ("embed", "mlp"), "up_proj")(x)
-        return dense(cfg.hidden_size, ("mlp", "embed"), "down_proj")(jax.nn.silu(gate) * up)
+        def kernel(shape, names, name):
+            return ExpertKernel((self.num_experts,) + shape, ("expert",) + names,
+                                cfg.param_dtype, name=name)().astype(cfg.dtype)
+
+        w_gate = kernel((cfg.hidden_size, cfg.intermediate_size), ("embed", "mlp"), "gate_proj")
+        w_up = kernel((cfg.hidden_size, cfg.intermediate_size), ("embed", "mlp"), "up_proj")
+        w_down = kernel((cfg.intermediate_size, cfg.hidden_size), ("mlp", "embed"), "down_proj")
+        x = x.astype(cfg.dtype)
+        if group_sizes is None:
+            dot = lambda t, w: jnp.einsum("...eci,eio->...eco", t, w)  # noqa: E731
+        else:
+            from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+            dot = lambda t, w: grouped_matmul(t, w, group_sizes, impl=impl)  # noqa: E731
+        return dot(jax.nn.silu(dot(x, w_gate)) * dot(x, w_up), w_down)
+
+
+class ExpertKernel(nn.Module):
+    """A projection kernel of every expert, ``[E, in, out]``, at the param
+    path ``<name>/kernel`` where a vmapped ``nn.Dense`` keeps it."""
+
+    shape: tuple
+    names: tuple
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self):
+        w = self.param("kernel", nn.with_logical_partitioning(_init(), self.names),
+                       self.shape, self.param_dtype)
+        return w.value if isinstance(w, nn.meta.AxisMetadata) else w
 
 
 class LlamaDecoderLayer(nn.Module):
@@ -257,13 +324,17 @@ class LlamaDecoderLayer(nn.Module):
         h = RMSNorm(cfg, name="post_attention_layernorm")(x)
         if self.use_moe:
             from deepspeed_tpu.moe import MoE
+            # drop-free routing groups rows by expert, which takes a bank
+            bank = 0 if cfg.moe_drop_tokens else cfg.moe_num_experts
             moe_out, l_aux, _ = MoE(hidden_size=cfg.hidden_size,
-                                    expert=LlamaMLP(cfg),
+                                    expert=LlamaMLP(cfg, num_experts=bank),
                                     num_experts=cfg.moe_num_experts,
                                     k=cfg.moe_k,
                                     capacity_factor=cfg.moe_capacity_factor,
                                     eval_capacity_factor=cfg.moe_eval_capacity_factor,
                                     min_capacity=cfg.moe_min_capacity,
+                                    drop_tokens=cfg.moe_drop_tokens,
+                                    norm_topk_prob=cfg.moe_norm_topk_prob,
                                     route=cfg.moe_route,
                                     name="moe")(h, deterministic=deterministic)
             return x + moe_out, l_aux
@@ -288,6 +359,32 @@ class LlamaForCausalLM(nn.Module):
 
     config: LlamaConfig
 
+    def moe_layers(self):
+        """Indices of the layers whose FFN is the expert layer."""
+        cfg = self.config
+        every = max(cfg.moe_layer_freq, 1)
+        return [i for i in range(cfg.num_hidden_layers)
+                if cfg.moe_num_experts > 0 and i % every == every - 1]
+
+    def moe_rows(self, positions: int):
+        """``(routed, computed)``: expert-matmul rows one position owes over
+        a forward pass (``k`` a layer), and rows the expert matmuls of one
+        pass over ``positions`` positions are given, padding included: the
+        grouped buffer's ``positions * k`` a layer when drop-free, else
+        ``experts * capacity``. The server counts its ticks with this."""
+        cfg = self.config
+        layers = len(self.moe_layers())
+        if not layers:
+            return 0, 0
+        if not cfg.moe_drop_tokens:
+            per_layer = positions * cfg.moe_k
+        else:
+            from deepspeed_tpu.moe.sharded_moe import _gate_capacity
+            per_layer = cfg.moe_num_experts * _gate_capacity(
+                positions, cfg.moe_num_experts, cfg.moe_eval_capacity_factor,
+                cfg.moe_min_capacity, True, cfg.moe_k)
+        return cfg.moe_k * layers, per_layer * layers
+
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True, decode: bool = False,
                  positions=None, attention_mask=None, labels=None):
@@ -304,9 +401,9 @@ class LlamaForCausalLM(nn.Module):
         # see constrain_activation (the ZeRO-3 weak-scaling invariant)
         x = constrain_activation(x, "batch", "length", "embed")
         aux_total = jnp.zeros([], jnp.float32)
+        moe_layers = self.moe_layers()
         for i in range(cfg.num_hidden_layers):
-            use_moe = (cfg.moe_num_experts > 0
-                       and i % max(cfg.moe_layer_freq, 1) == max(cfg.moe_layer_freq, 1) - 1)
+            use_moe = i in moe_layers
             block_cls = maybe_remat(LlamaDecoderLayer, cfg, i, static_argnums=(3, 5),
                                     enabled=cfg.remat and not decode)
             x, l_aux = block_cls(cfg, use_moe, name=f"layers_{i}")(

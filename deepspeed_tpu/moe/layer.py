@@ -46,12 +46,16 @@ class MoE(nn.Module):
     # engine's "moe" config block, then the default (moe/routing.py)
     route: Optional[str] = None
     route_kernel: Optional[str] = None
+    # k >= 2: renormalise the k chosen experts' weights (Mixtral, the
+    # reference top-2) or keep the softmax values (OLMoE)
+    norm_topk_prob: bool = True
 
     def setup(self):
         if self.noisy_gate_policy not in (None, 'None', 'Jitter', 'RSample'):
             raise ValueError(f"Unsupported noisy_gate_policy: {self.noisy_gate_policy}")
-        if self.k not in (1, 2):
-            raise ValueError(f"Only top-1 and top-2 gatings are supported (got k={self.k})")
+        if not 1 <= self.k <= self.num_experts:
+            raise ValueError(f"top-k gating needs 1 <= k <= num_experts "
+                             f"(got k={self.k}, num_experts={self.num_experts})")
         if self.num_experts % self.ep_size != 0:
             raise ValueError(f"num_experts ({self.num_experts}) must be divisible by "
                              f"ep_size ({self.ep_size})")
@@ -72,6 +76,7 @@ class MoE(nn.Module):
             use_rts=self.use_rts,
             route=self.route,
             route_kernel=self.route_kernel,
+            norm_topk_prob=self.norm_topk_prob,
         )
         if self.use_residual:
             # PR-MoE (reference layer.py:70-77): dense MLP alongside the MoE

@@ -1,4 +1,4 @@
-"""Sharded MoE: top-1/top-2 gating + the expert-parallel MoE layer.
+"""Sharded MoE: top-k gating + the expert-parallel MoE layer.
 
 TPU-native redesign of reference ``deepspeed/moe/sharded_moe.py``
 (``top1gating`` :179, ``top2gating`` :277, ``MOELayer`` :420).
@@ -74,11 +74,10 @@ def _gate_capacity(num_tokens: int, num_experts: int, capacity_factor: float,
     """THE capacity derivation — single source for the gating cores (which
     assign slots against it) and ``TopKGate.capacity`` (which the sorted
     route sizes its permutation buffers with). The two must agree or
-    ``expert*C + slot`` mis-addresses the buffer; top-2 shares one buffer
-    between both choices, hence the doubled factor (reference
-    ``top2gating`` ``sharded_moe.py:285``)."""
-    cf = 2 * capacity_factor if k == 2 else capacity_factor
-    return _capacity(num_tokens, num_experts, cf, min_capacity, drop_tokens)
+    ``expert*C + slot`` mis-addresses the buffer; the k choices share one
+    buffer, hence the factor times k (reference ``top2gating``
+    ``sharded_moe.py:285`` doubles it)."""
+    return _capacity(num_tokens, num_experts, k * capacity_factor, min_capacity, drop_tokens)
 
 
 def sec_signature(num_tokens: int, num_experts: int, capacity_factor: float,
@@ -319,6 +318,71 @@ def top2routing(logits: jax.Array,
 
 
 
+def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, normalize,
+                    used_token=None):
+    """The decision core for any ``k`` <= experts (OLMoE: 8 of 64): softmax
+    over the experts in fp32, the ``k`` largest probabilities
+    (``jax.lax.top_k``; ties go to the lower index), and as combine weights
+    the softmax values themselves, or, with ``normalize``, those values
+    divided by their sum. Deterministic: no sampled choice, no RTS.
+
+    Slots are assigned choice-major, as the top-2 core does: every first
+    choice queues in its expert's buffer before any second choice, so what
+    a bounded capacity drops first is the lowest-ranked choices. With
+    ``drop_tokens=False`` the capacity is the token count, which no expert
+    can exceed (a token's ``k`` experts are distinct), and nothing drops."""
+    logits = logits.astype(jnp.float32)
+    num_tokens, num_experts = logits.shape
+    gates = jax.nn.softmax(logits, axis=1)
+    capacity = _gate_capacity(num_tokens, num_experts, capacity_factor, min_capacity,
+                              drop_tokens, k)
+    weights, experts = jax.lax.top_k(gates, k)                      # [S, k]
+    if normalize:
+        weights = weights / jnp.maximum(weights.sum(axis=1, keepdims=True),
+                                        jnp.finfo(gates.dtype).eps)
+    masks = jax.nn.one_hot(experts, num_experts, dtype=jnp.int32)   # [S, k, E]
+    if used_token is not None:
+        masks = masks * used_token[:, None, None].astype(masks.dtype)
+    counts = masks.sum(axis=0)                                      # [k, E]
+    queued_before = jnp.cumsum(counts, axis=0) - counts             # by earlier choices
+    locations = jnp.cumsum(masks, axis=0) - 1 + queued_before[None]
+    slot = jnp.sum(locations * masks, axis=2)                       # [S, k]
+    keep = masks.sum(axis=2) * (slot < capacity).astype(jnp.int32)
+    exp_counts = counts[0]
+    # load balancing as the OLMoE / Mixtral publications compute it: the
+    # mean router probability of an expert times the share of tokens that
+    # chose it, summed over the k choices
+    l_aux = jnp.sum(jnp.mean(gates, axis=0)
+                    * jnp.mean(masks.sum(axis=1).astype(jnp.float32), axis=0)) * num_experts
+    routing = SortedRouting(expert=experts.astype(jnp.int32), slot=slot.astype(jnp.int32),
+                            weight=weights * keep, keep=keep)
+    return l_aux, routing, exp_counts, capacity
+
+
+def topkrouting(logits: jax.Array, k: int, capacity_factor: float, min_capacity: int,
+                drop_tokens: bool = True, normalize: bool = False,
+                used_token: Optional[jax.Array] = None) -> Tuple[jax.Array, SortedRouting, jax.Array]:
+    """Top-``k`` gating, compact form for the sorted route. Returns
+    ``(l_aux, SortedRouting [S,k] fields, exp_counts [E])``."""
+    l_aux, routing, exp_counts, _ = _topk_decisions(
+        logits, k, capacity_factor, min_capacity, drop_tokens, normalize, used_token)
+    return l_aux, routing, exp_counts
+
+
+def topkgating(logits: jax.Array, k: int, capacity_factor: float, min_capacity: int,
+               drop_tokens: bool = True, normalize: bool = False,
+               used_token: Optional[jax.Array] = None):
+    """Top-``k`` gating in the dense route's form: the same decisions as
+    :func:`topkrouting`, spread into ``[S,E,C]`` combine weights."""
+    l_aux, routing, exp_counts, capacity = _topk_decisions(
+        logits, k, capacity_factor, min_capacity, drop_tokens, normalize, used_token)
+    expert_se = jax.nn.one_hot(routing.expert, logits.shape[1], dtype=jnp.float32)  # [S,k,E]
+    slot_sc = jax.nn.one_hot(routing.slot, capacity, dtype=jnp.float32)             # [S,k,C]
+    combine_weights = jnp.einsum("sk,ske,skc->sec", routing.weight, expert_se, slot_sc)
+    return l_aux, combine_weights, combine_weights > 0, exp_counts
+
+
+
 def _constrain_groups(x, spec, n_groups: int):
     """Apply a sharding constraint when the group dim really maps onto the
     DP shards (one guard for the gate/dispatch/combine sites; tiny
@@ -350,9 +414,16 @@ class TopKGate(nn.Module):
     drop_tokens: bool = True
     use_rts: bool = True
     route: str = "dense"
+    # k >= 2: combine weights renormalised over the k chosen experts (the
+    # reference top-2, Mixtral) or the softmax values as they are (OLMoE's
+    # ``norm_topk_prob`` false)
+    norm_topk_prob: bool = True
 
     @nn.compact
     def __call__(self, tokens, used_token=None, deterministic: bool = True):
+        if not 1 <= self.k <= self.num_experts:
+            raise ValueError(f"top-k gating needs 1 <= k <= experts "
+                             f"(got k={self.k}, experts={self.num_experts})")
         # the gate runs in fp32 regardless of compute dtype (reference keeps
         # wg in fp32, sharded_moe.py:373,394)
         wg = self.param("wg", nn.with_logical_partitioning(nn.initializers.normal(0.02), ("embed", None)),
@@ -386,10 +457,12 @@ class TopKGate(nn.Module):
             gate_fn = lambda lg, r, ut: top1_fn(lg, cf, self.min_capacity, ut,
                                                 self.noisy_gate_policy if not deterministic else None,
                                                 self.drop_tokens, self.use_rts, r)
-        elif self.k == 2:
+        elif self.k == 2 and self.norm_topk_prob:
             gate_fn = lambda lg, r, ut: top2_fn(lg, cf, self.min_capacity, self.drop_tokens, r)
         else:
-            raise ValueError(f"Only top-1 and top-2 gatings are supported (got k={self.k})")
+            topk_fn = topkrouting if self.route == "sorted" else topkgating
+            gate_fn = lambda lg, r, ut: topk_fn(lg, self.k, cf, self.min_capacity,
+                                                self.drop_tokens, self.norm_topk_prob, ut)
 
         if used_token is None:
             out = jax.vmap(lambda lg, r: gate_fn(lg, r, None))(logits, rngs) if rngs is not None \
@@ -433,11 +506,19 @@ class Experts(nn.Module):
     num_experts: int
 
     @nn.compact
-    def __call__(self, x, deterministic: bool = True):
+    def __call__(self, x, deterministic: bool = True, group_sizes=None, impl: str = "xla"):
         # x: [groups, experts, capacity, model] → vmap over the expert dim.
         # An unbound copy keeps params under this scope with a stable name
         # (reference state-dict path "…experts.deepspeed_experts.N").
         expert = self.expert.copy(name="deepspeed_experts")
+        if getattr(expert, "num_experts", 0):
+            # a bank: one module that holds every expert's weights under the
+            # same paths and shapes the vmap below gives them, and also
+            # takes rows sorted by expert (``group_sizes``, drop-free route)
+            return expert(x, deterministic=deterministic, group_sizes=group_sizes, impl=impl)
+        if group_sizes is not None:
+            raise ValueError(f"{type(expert).__name__} is not an expert bank: rows grouped "
+                             f"by expert need a module that takes group_sizes")
         xt = jnp.moveaxis(x, 1, 0)  # [E, G, C, M]
         vmapped = nn.vmap(
             lambda mdl, xi: mdl(xi, deterministic=deterministic),
@@ -504,6 +585,7 @@ class MOELayer(nn.Module):
     use_rts: bool = True
     route: Optional[str] = None
     route_kernel: Optional[str] = None
+    norm_topk_prob: bool = True
 
     @nn.compact
     def __call__(self, hidden_states, used_token=None, deterministic: bool = True):
@@ -523,7 +605,8 @@ class MOELayer(nn.Module):
 
         gate = TopKGate(self.model_dim, self.num_experts, self.k, self.capacity_factor,
                         self.eval_capacity_factor, self.min_capacity, self.noisy_gate_policy,
-                        self.drop_tokens, self.use_rts, route=route, name="gate")
+                        self.drop_tokens, self.use_rts, route=route,
+                        norm_topk_prob=self.norm_topk_prob, name="gate")
 
         if route == "sorted":
             out, l_aux, exp_counts, kept_counts, routed_counts, capacity = self._sorted_route(
@@ -592,59 +675,96 @@ class MOELayer(nn.Module):
                       constrain, orig_dtype, groups):
         from deepspeed_tpu.ops.pallas.moe_dispatch import (inverse_index, permute_rows,
                                                            resolve_impl)
-        l_aux, routing, exp_counts = gate(tokens, used_token, deterministic)
         num_tokens = tokens.shape[1]
         d_model = tokens.shape[2]
-        capacity = gate.capacity(num_tokens, deterministic)
-        E, C, k = self.num_experts, capacity, routing.expert.shape[-1]
+        E = self.num_experts
 
         impl = resolve_impl(kernel)
         topo = get_topology()
-        if impl == "pallas" and topo is not None and topo.mesh.size > 1:
+        on_mesh = topo is not None and topo.mesh.size > 1
+        if impl == "pallas" and on_mesh:
             # pallas_call has no SPMD partitioning rule on a live mesh; the
             # XLA permutation lowers to the same per-shard gathers
             _warn_sorted_fallback("pallas MoE dispatch on a multi-device mesh")
             impl = "xla"
+        # drop-free routing over an expert bank on one device: the copies
+        # are grouped by expert with no padding between the groups (below);
+        # everywhere else each expert owns ``capacity`` rows of the buffer
+        ragged = (not self.drop_tokens and not on_mesh
+                  and getattr(self.expert, "num_experts", 0) > 0)
+        # there the kernel choice is the grouped matmul's; the rows move by
+        # XLA's gather. The row-DMA kernel views 16-bit rows as 32-bit words
+        # through a [.., M/2, 2] reshape, which the TPU tiles 64 times over:
+        # at a prefill tick's 16,384 rows of 2,048 it made the tick 208 ms
+        # (PERF.md, PR 26)
+        permute_impl = "xla" if ragged else impl
 
-        # each kept copy owns a unique flat slot expert*C + position (the
-        # cumsum position assignment is a stable counting sort by expert);
-        # dropped copies park on the E*C sentinel → zero rows / no reads
-        flat_slot = jnp.where(routing.keep > 0,
-                              routing.expert * C + routing.slot,
-                              E * C).astype(jnp.int32).reshape(groups, num_tokens * k)
-        flat_slot = constrain(flat_slot, (BATCH_AXES, None))
-        src = inverse_index(flat_slot, E * C)  # [G, E*C] — slot -> token copy
-        src = constrain(src, (BATCH_AXES, None))
+        with jax.named_scope("moe_route"):
+            l_aux, routing, exp_counts = gate(tokens, used_token, deterministic)
+            capacity = gate.capacity(num_tokens, deterministic)
+            k = routing.expert.shape[-1]
+            # which experts each token took, [G, S, k], best first (read
+            # with mutable=["intermediates"]; costs nothing otherwise)
+            self.sow("intermediates", "expert_choice", routing.expert)
+            kept_counts = jnp.zeros((E,), jnp.int32).at[routing.expert.reshape(-1)].add(
+                routing.keep.reshape(-1).astype(jnp.int32))
+            if ragged:
+                # one device, so one group (``_num_groups``): ``kept_counts``
+                # are its group sizes, and group e starts where the groups
+                # before it end. The buffer holds S*k rows, every one a copy
+                starts = jnp.cumsum(kept_counts) - kept_counts
+                base = starts[routing.expert].reshape(groups, -1)
+                rows = num_tokens * k
+                capacity = -(-rows // E)   # evidence only: mean rows an expert
+            else:
+                base = (routing.expert * capacity).reshape(groups, -1)
+                rows = E * capacity
+            # each kept copy owns a unique row base + position (the cumsum
+            # position assignment is a stable counting sort by expert);
+            # dropped copies park on the sentinel → zero rows / no reads
+            flat_slot = jnp.where(routing.keep.reshape(groups, -1) > 0,
+                                  base + routing.slot.reshape(groups, -1),
+                                  rows).astype(jnp.int32)
+            flat_slot = constrain(flat_slot, (BATCH_AXES, None))
+            src = inverse_index(flat_slot, rows)  # [G, rows] — row -> token copy
+            src = constrain(src, (BATCH_AXES, None))
 
-        # [G, S, M] -> [G, S*k, M], copy j of token s at row s*k + j (the
-        # reshape order of the [S, k] routing fields)
-        tok_rep = jnp.repeat(tokens, k, axis=1) if k > 1 else tokens
+            # [G, S, M] -> [G, S*k, M], copy j of token s at row s*k + j (the
+            # reshape order of the [S, k] routing fields)
+            tok_rep = jnp.repeat(tokens, k, axis=1) if k > 1 else tokens
+            # dispatch = pure row permutation
+            dispatched = permute_rows(tok_rep, src, flat_slot, impl=permute_impl)
 
-        # dispatch = pure row permutation; same constraint pair as the dense
-        # route so the expert all-to-all still moves only the capacity-
-        # bounded [G,E,C,M] buffer
-        dispatched = permute_rows(tok_rep, src, flat_slot, impl=impl)
-        dispatched = dispatched.reshape(groups, E, C, d_model)
-        dispatched = constrain(dispatched, (BATCH_AXES, None, None, None))
-        dispatched = constrain(dispatched, ((DATA_AXIS, FSDP_AXIS), EXPERT_AXIS, None, None))
+        with jax.named_scope("moe_experts"):
+            experts = Experts(self.expert, self.num_experts, name="experts")
+            if ragged:
+                expert_out = experts(dispatched[0], deterministic,
+                                     group_sizes=kept_counts, impl=impl)[None]
+            else:
+                # same constraint pair as the dense route so the expert
+                # all-to-all still moves only the capacity-bounded
+                # [G,E,C,M] buffer
+                dispatched = dispatched.reshape(groups, E, capacity, d_model)
+                dispatched = constrain(dispatched, (BATCH_AXES, None, None, None))
+                dispatched = constrain(dispatched,
+                                       ((DATA_AXIS, FSDP_AXIS), EXPERT_AXIS, None, None))
+                expert_out = experts(dispatched, deterministic)
+                expert_out = constrain(expert_out,
+                                       ((DATA_AXIS, FSDP_AXIS), EXPERT_AXIS, None, None))
+                expert_out = constrain(expert_out, (BATCH_AXES, None, None, None))
+                expert_out = expert_out.reshape(groups, rows, d_model)
 
-        expert_out = Experts(self.expert, self.num_experts, name="experts")(dispatched, deterministic)
-        expert_out = constrain(expert_out, ((DATA_AXIS, FSDP_AXIS), EXPERT_AXIS, None, None))
-        expert_out = constrain(expert_out, (BATCH_AXES, None, None, None))
+        with jax.named_scope("moe_combine"):
+            # combine: gather each copy's expert output back and weight it —
+            # k fused multiply-adds per token instead of the [G,S,E,C] einsum
+            gathered = permute_rows(expert_out, flat_slot, src, impl=permute_impl)
+            weights = routing.weight.astype(orig_dtype).reshape(groups, num_tokens * k, 1)
+            combined = (weights * gathered).reshape(groups, num_tokens, k, d_model).sum(axis=2)
+            combined = constrain(combined, (BATCH_AXES, None, None))
 
-        # combine: gather each copy's expert output back and weight it —
-        # k fused multiply-adds per token instead of the [G,S,E,C] einsum
-        gathered = permute_rows(expert_out.reshape(groups, E * C, d_model),
-                                flat_slot, src, impl=impl)
-        weights = routing.weight.astype(orig_dtype).reshape(groups, num_tokens * k, 1)
-        combined = (weights * gathered).reshape(groups, num_tokens, k, d_model).sum(axis=2)
-        combined = constrain(combined, (BATCH_AXES, None, None))
-
-        kept_counts = jnp.zeros((E,), jnp.int32).at[routing.expert.reshape(-1)].add(
-            routing.keep.reshape(-1).astype(jnp.int32))
         # all k copies pre-capacity: the compact routing names every copy's
-        # expert, so the kept denominator is exact for both k (k=1: equals
-        # exp_counts; k=2: adds the second choices the dense return hides)
+        # expert, so the kept denominator is exact for every k (k=1: equals
+        # exp_counts; k>=2: adds the later choices the dense return hides)
         routed_counts = exp_counts if k == 1 else (
-            exp_counts + jnp.zeros((E,), jnp.int32).at[routing.expert[..., 1].reshape(-1)].add(1))
+            exp_counts + jnp.zeros((E,), jnp.int32).at[routing.expert[..., 1:].reshape(-1)].add(1))
         return combined, l_aux, exp_counts, kept_counts, routed_counts, capacity

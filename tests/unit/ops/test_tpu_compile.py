@@ -134,6 +134,21 @@ def test_quant_matmul_compiles(one_chip, bits, m, k, n):
                           _shape(k // 64, n, dtype=jnp.float32)))
 
 
+@pytest.mark.parametrize("rows", [256, 16384], ids=["decode_tick", "prefill_tick"])
+def test_grouped_matmul_compiles(one_chip, rows):
+    """The drop-free route's expert projection at OLMoE's published widths
+    (64 experts of 2048 x 1024) and its two tick shapes (32 x 1 and 32 x 64
+    positions, 8 experts each), tiled as ``grouped_matmul.tiling`` says."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul, resolve_impl
+    assert resolve_impl("auto") == "pallas"
+
+    def fn(x, w, sizes):
+        return grouped_matmul(x, w, sizes, impl="pallas")
+
+    _kernel_text(_compile(fn, one_chip, _shape(rows, 2048), _shape(64, 2048, 1024),
+                          _shape(64, dtype=jnp.int32)))
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
 def test_block_sparse_attention_compiles(one_chip, backward):
     from deepspeed_tpu.ops.sparse_attention.sparse_self_attention import sparse_attention
@@ -180,6 +195,39 @@ def test_serving_program_compiles(one_chip, program, attention):
         operands = (_shape(SLOTS, dtype=jnp.int32), _shape(SLOTS, dtype=jnp.int32))
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     assert ("tpu_custom_call" in compiled.as_text()) == (attention == "flash")
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_olmoe_serving_program_compiles(one_chip, program):
+    """One OLMoE layer at its published widths through the serving programs
+    of the benchmark's cell: 32 slots of 2,048 positions over the int8
+    cache, 64-token chunks, the grouped expert matmuls as Mosaic kernels
+    and the row permutations as XLA gathers."""
+    import flax.linen as nn
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step,
+                                                          make_apply_fn, make_slot_cache)
+    from deepspeed_tpu.models.llama import LlamaForCausalLM, get_llama_config
+
+    slots, chunk = 32, 64
+    module = LlamaForCausalLM(get_llama_config("olmoe-1b-7b", num_hidden_layers=1, dtype=bf16,
+                                               decode_cache_len=2048))
+    params = jax.eval_shape(
+        lambda key: jax.tree.map(lambda p: p.astype(bf16), nn.meta.unbox(
+            module.init(key, jnp.zeros((1, 8), jnp.int32))["params"])), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots, kv_quant=True))
+    apply_fn = make_apply_fn(module)
+    if program == "prefill":
+        step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, chunk, dtype=jnp.int32),
+                    _shape(slots, dtype=jnp.int32))
+    else:
+        step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
+    compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
+    assert compiled.as_text().count("tpu_custom_call") == 3       # gate, up, down
+    # a prefill tick's temporaries stay under a gigabyte: no [E, C, M] buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
 
 
 def _train_engine(devices, zero_stage, fsdp):
