@@ -31,6 +31,12 @@ Two headline numbers per program:
   declared activation budget, and the number the 1F1B refactor must
   drive down; donation does not move it.
 
+One value is no buffer: a ``transpose`` whose every consumer is a
+``dot_general``. A dot's dimension numbers name its operand's axes in any
+order, so XLA folds such a transpose into the dot (on the TPU, into the
+operand's layout); the estimate counts it as a view that keeps its
+operand alive. Any other transpose is a copy and counts as one.
+
 Accuracy contract: this is a *scheduling* estimate, not a simulator —
 XLA fuses, rematerializes and buffer-shares below this level. The
 cross-check against ``compiled.memory_analysis()`` (where the backend
@@ -74,6 +80,28 @@ def _is_tracked(v) -> bool:
     return hasattr(v, "aval") and type(v).__name__ not in ("Literal", "DropVar")
 
 
+def _dot_operand_views(jaxpr) -> Dict[Any, Any]:
+    """``{view: viewed}`` for every ``transpose`` result of ``jaxpr`` that
+    only ``dot_general`` eqns consume (and the program does not return): a
+    reordering of axes that the dot's dimension numbers absorb. A view of a
+    view resolves to the buffer underneath."""
+    consumers: Dict[Any, set] = {}
+    for eqn in jaxpr.eqns:
+        for v in eqn.invars:
+            if _is_tracked(v):
+                consumers.setdefault(v, set()).add(eqn.primitive.name)
+    returned = {v for v in jaxpr.outvars if _is_tracked(v)}
+    views: Dict[Any, Any] = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name != "transpose":
+            continue
+        (viewed,), (out,) = eqn.invars, eqn.outvars
+        if (_is_tracked(out) and _is_tracked(viewed) and out not in returned
+                and consumers.get(out) == {"dot_general"}):
+            views[out] = views.get(viewed, viewed)
+    return views
+
+
 class _Liveness:
     """One liveness walk over one (sub-)jaxpr.
 
@@ -101,13 +129,17 @@ class _Liveness:
             if _is_tracked(v):
                 self.vars[v] = [0, 0, aval_bytes(v.aval), "<inputs>", True]
         from deepspeed_tpu.analysis.program import _iter_sub_jaxprs
+        views = _dot_operand_views(self.jaxpr)  # view var -> the var it views
         for i, eqn in enumerate(self.jaxpr.eqns):
             slot = i + 1
             for v in eqn.invars:
-                if _is_tracked(v) and v in self.vars:
+                if not _is_tracked(v):
+                    continue
+                v = views.get(v, v)  # reading a view reads what it views
+                if v in self.vars:
                     self.vars[v][1] = max(self.vars[v][1], slot)
             for v in eqn.outvars:
-                if _is_tracked(v):
+                if _is_tracked(v) and v not in views:
                     self.vars[v] = [slot, slot, aval_bytes(v.aval), scope, False]
             # sub-jaxprs run *inside* this slot; alternatives (cond
             # branches) and single bodies both take the max internal

@@ -530,12 +530,17 @@ def pipe_1f1b_step() -> ProgramInfo:
 
 #: the committed activation budget (MiB) for the graft-serve decode tick
 #: below (16 slots x 512 positions, tp=2, tiny GPT-2). Measured static
-#: transient on the pinned container: 8.41 MiB with the committed
-#: ``scatter`` KV write (4 per-slot scatters, O(slots) bytes each);
-#: committed at 9.0 MiB (~7% headroom). The ``dense`` masked-rebuild
-#: write measures 10.5 MiB — so ``DS_SERVE_KV_WRITE=dense`` fails R010
-#: under this budget, the DS_MOE_ROUTE-pattern seeded regression for a
-#: forced/leaked serving knob.
+#: transient on the pinned container: 8.41 MiB (the per-slot KV write is
+#: O(slots) bytes; what is live is the tick's new pools); committed at 9.0
+#: MiB (~7% headroom). A write that rebuilds a pool fails R010 under it:
+#: the ``dense`` masked write that ISSUE 27 removed measured 10.5 MiB.
+#: That attention reads the stored, positions-minor pool through a
+#: ``transpose`` costs nothing here: the estimator counts a transpose that
+#: only dots consume as a view (``analysis/memory.py``), which is what the
+#: TPU's compiler makes of it (``tests/unit/ops/test_tpu_compile.py``). Off
+#: the TPU this program traces the scatter, not the in-place write the chip
+#: runs (``models/common.py`` ``slot_pool_append``): that one's temporaries
+#: are held by the compile test alone.
 SERVE_DECODE_BUDGET_MB = 9.0
 
 
@@ -548,13 +553,11 @@ def serve_decode_step() -> ProgramInfo:
     ``build_decode_step`` the scheduler jits — so R009 pins the tp
     collective signature, R010 gates the per-tick transient against
     :data:`SERVE_DECODE_BUDGET_MB`, and R013 ratchets both against the
-    committed cost baseline. The KV write strategy resolves through
-    env/config exactly like a serve run (``resolve_kv_write``), which is
-    what gives ``DS_SERVE_KV_WRITE=dense`` its teeth."""
+    committed cost baseline."""
     import deepspeed_tpu
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
     from deepspeed_tpu.inference.engine import InferenceEngine
-    from deepspeed_tpu.inference.serving import make_slot_cache, resolve_intended_kv_write
+    from deepspeed_tpu.inference.serving import make_slot_cache
     from deepspeed_tpu.inference.serving.programs import (build_decode_step,
                                                           make_apply_fn)
     from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
@@ -581,9 +584,6 @@ def serve_decode_step() -> ProgramInfo:
             lower=lambda: jax.jit(decode).lower(engine.params, cache, write_pos, tokens),
             metadata={
                 "serve_slots": slots,
-                # the committed intent, env layer skipped — a forced env
-                # override drifts the program but never this declaration
-                "serve_kv_write": resolve_intended_kv_write(),
                 "activation_budget_bytes": int(SERVE_DECODE_BUDGET_MB * 2**20),
                 "collective_signature": [
                     # tp=2 row-parallel projections: attention out-proj +
@@ -706,7 +706,6 @@ def serve_prefix_decode_step() -> ProgramInfo:
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
     from deepspeed_tpu.inference.engine import InferenceEngine
     from deepspeed_tpu.inference.serving import (make_slot_cache,
-                                                 resolve_intended_kv_write,
                                                  resolve_intended_prefix_cache,
                                                  set_default_prefix_cache)
     from deepspeed_tpu.inference.serving.programs import (build_decode_step,
@@ -737,7 +736,6 @@ def serve_prefix_decode_step() -> ProgramInfo:
             metadata={
                 "serve_slots": slots,
                 # committed intent, env layer skipped — the drift anchors
-                "serve_kv_write": resolve_intended_kv_write(),
                 "serve_prefix_cache": resolve_intended_prefix_cache(None),
                 # same budget as serve_decode_step ON PURPOSE: prefix
                 # caching must not move the decode tick's transient a byte

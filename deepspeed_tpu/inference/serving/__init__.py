@@ -6,18 +6,14 @@ from deepspeed_tpu.inference.serving.events import (SERVE_EVENT_SCHEMAS,
                                                     iter_serve_events,
                                                     last_tick_signals,
                                                     validate_event)
-from deepspeed_tpu.inference.serving.config import (ENV_KV_WRITE,
-                                                    ENV_PREFIX_CACHE,
+from deepspeed_tpu.inference.serving.config import (ENV_PREFIX_CACHE,
                                                     ENV_WEIGHT_DTYPE,
                                                     ServingConfig,
                                                     SpeculationConfig,
-                                                    resolve_intended_kv_write,
                                                     resolve_intended_prefix_cache,
                                                     resolve_intended_weight_dtype,
-                                                    resolve_kv_write,
                                                     resolve_prefix_cache,
                                                     resolve_weight_dtype,
-                                                    set_default_kv_write,
                                                     set_default_prefix_cache,
                                                     set_default_weight_dtype)
 from deepspeed_tpu.inference.serving.programs import (make_slot_cache,
@@ -32,17 +28,17 @@ from deepspeed_tpu.inference.serving.scheduler import (MIGRATABLE_STATES,
 
 __all__ = [
     "ACTIVE", "FINISHED", "PREFILL", "QUEUED", "REFUSED",
-    "BlockPool", "ContinuousBatchingScheduler", "ENV_KV_WRITE",
+    "BlockPool", "ContinuousBatchingScheduler",
     "ENV_PREFIX_CACHE", "ENV_WEIGHT_DTYPE", "MIGRATABLE_STATES",
     "MigrationError", "Request",
     "RequestQueue", "SERVE_EVENT_SCHEMAS", "ServingConfig",
     "SpeculationConfig", "iter_serve_events", "last_tick_signals",
     "make_slot_cache",
-    "resolve_intended_kv_write", "resolve_intended_prefix_cache",
+    "resolve_intended_prefix_cache",
     "resolve_intended_weight_dtype",
-    "resolve_kv_write", "resolve_prefix_cache", "resolve_weight_dtype",
+    "resolve_prefix_cache", "resolve_weight_dtype",
     "serve_programs",
-    "set_default_kv_write", "set_default_prefix_cache",
+    "set_default_prefix_cache",
     "set_default_weight_dtype", "slot_capacity",
     "validate_event",
 ]

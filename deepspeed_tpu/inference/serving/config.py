@@ -3,8 +3,8 @@
 Continuous in-flight batching (ISSUE 14 / ROADMAP item 1) is driven by a
 small set of knobs with the same layered resolution discipline as the MoE
 route and the attention geometry: explicit > env > config > default, with
-the env layer (``DS_SERVE_KV_WRITE``) existing so the graft-audit
-``serve_decode_step`` scenario can catch a forced/leaked serving knob the
+the env layer (``DS_SERVE_WQ``, ``DS_SERVE_PREFIX_CACHE``) existing so the
+graft-audit serving scenarios can catch a forced/leaked serving knob the
 exact way ``DS_MOE_ROUTE=dense`` is caught — the traced program drifts,
 the committed budget/signature does not, lint exits 1.
 """
@@ -16,13 +16,6 @@ from typing import Optional, Tuple
 from pydantic import Field, model_validator
 
 from deepspeed_tpu.runtime.config_utils import DeepSpeedConfigModel
-
-#: env override for the per-slot KV write strategy (the DS_MOE_ROUTE
-#: pattern: drifts the traced program, never the committed intent)
-ENV_KV_WRITE = "DS_SERVE_KV_WRITE"
-
-KV_WRITE_CHOICES = ("scatter", "dense")
-DEFAULT_KV_WRITE = "scatter"
 
 #: env override for the served weight dtype (graft-quant-serve); same
 #: drift seam: a forced/leaked value changes the traced decode program,
@@ -42,7 +35,6 @@ PREFIX_CACHE_CHOICES = ("on", "off")
 DEFAULT_PREFIX_CACHE = "on"
 
 _lock = threading.Lock()
-_config_kv_write: Optional[str] = None
 _config_weight_dtype: Optional[str] = None
 _config_prefix_cache: Optional[str] = None
 
@@ -51,44 +43,6 @@ def _check(value: Optional[str], choices, what: str) -> Optional[str]:
     if value is not None and value not in choices:
         raise ValueError(f"unknown {what} {value!r}; choices: {list(choices)}")
     return value
-
-
-def set_default_kv_write(mode: Optional[str]) -> None:
-    """Install the scheduler-level default KV write mode (None clears)."""
-    global _config_kv_write
-    with _lock:
-        _config_kv_write = _check(mode, KV_WRITE_CHOICES, "kv_write")
-
-
-def resolve_kv_write(mode: Optional[str] = None) -> Tuple[str, str]:
-    """Resolve ``(mode, source)`` for the per-slot KV cache write.
-
-    ``scatter`` (default) appends each slot's new tokens with an O(slots x
-    tokens) scatter whose out-of-bounds (parked-slot) updates drop;
-    ``dense`` rebuilds the pool through a masked one-hot einsum — a
-    per-layer O(slots x n_positions) transient kept as the seeded R010
-    regression. ``source`` names the deciding layer, perf-ladder evidence
-    convention (``explicit`` > ``env`` > ``config`` > ``default``)."""
-    src, m = "default", DEFAULT_KV_WRITE
-    if _config_kv_write is not None:
-        m, src = _config_kv_write, "config"
-    env = os.environ.get(ENV_KV_WRITE, "").strip() or None
-    if env is not None:
-        m, src = _check(env, KV_WRITE_CHOICES, f"kv_write (from {ENV_KV_WRITE})"), "env"
-    if mode is not None:
-        m, src = _check(mode, KV_WRITE_CHOICES, "kv_write"), "explicit"
-    return m, src
-
-
-def resolve_intended_kv_write(mode: Optional[str] = None) -> str:
-    """The write mode the *committed configuration* intends, skipping the
-    env layer — what the ``serve_decode_step`` scenario's budget is priced
-    for (mirror of ``moe.routing.resolve_intended_route``)."""
-    if mode is not None:
-        return _check(mode, KV_WRITE_CHOICES, "kv_write")
-    if _config_kv_write is not None:
-        return _config_kv_write
-    return DEFAULT_KV_WRITE
 
 
 def set_default_weight_dtype(mode: Optional[str]) -> None:
@@ -105,7 +59,7 @@ def resolve_weight_dtype(mode: Optional[str] = None) -> Tuple[str, str]:
     serve per-group quantized codes with dequant fused into the GEMM
     (``ops/pallas/quant_matmul.py``). ``source`` names the deciding layer
     (``explicit`` > ``env`` > ``config`` > ``default``), the same evidence
-    convention as :func:`resolve_kv_write`."""
+    convention as the MoE route's."""
     src, m = "default", DEFAULT_WEIGHT_DTYPE
     if _config_weight_dtype is not None:
         m, src = _config_weight_dtype, "config"
@@ -122,7 +76,7 @@ def resolve_intended_weight_dtype(mode: Optional[str] = None) -> str:
     """The weight dtype the *committed configuration* intends, skipping
     the env layer — what ``serve_quant_decode_step`` prices its budget
     and collective signature for (mirror of
-    :func:`resolve_intended_kv_write`)."""
+    ``moe.routing.resolve_intended_route``)."""
     if mode is not None:
         return _check(mode, WEIGHT_DTYPE_CHOICES, "weight_dtype")
     if _config_weight_dtype is not None:
@@ -146,7 +100,7 @@ def resolve_prefix_cache(mode: Optional[str] = None) -> Tuple[str, str]:
     their uncached tail. ``off`` restores the private-blocks pool (parity
     debugging / the A/B control arm). ``source`` names the deciding layer
     (``explicit`` > ``env`` > ``config`` > ``default``), the same
-    evidence convention as :func:`resolve_kv_write`."""
+    evidence convention as :func:`resolve_weight_dtype`."""
     src, m = "default", DEFAULT_PREFIX_CACHE
     if _config_prefix_cache is not None:
         m, src = _config_prefix_cache, "config"
@@ -209,8 +163,6 @@ class ServingConfig(DeepSpeedConfigModel):
     prefill_interleave: int = Field(1, ge=0)
     #: queued requests beyond this are refused on submit
     max_queue: int = Field(1024, ge=1)
-    #: per-slot KV append strategy; resolution via :func:`resolve_kv_write`
-    kv_write: Optional[str] = None
     #: served weight dtype (graft-quant-serve); resolution via
     #: :func:`resolve_weight_dtype`. ``int8``/``int4`` quantize the served
     #: param tree per group (weights only; embeddings/norms stay fp) and
@@ -245,7 +197,6 @@ class ServingConfig(DeepSpeedConfigModel):
 
     @model_validator(mode="after")
     def _validate(self):
-        _check(self.kv_write, KV_WRITE_CHOICES, "kv_write")
         _check(self.weight_dtype, WEIGHT_DTYPE_CHOICES, "weight_dtype")
         _check(self.prefix_cache, PREFIX_CACHE_CHOICES, "prefix_cache")
         if self.speculation.enabled and self.do_sample:

@@ -23,11 +23,13 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 # the cache's leaf names are the models' (``models/common.py`` DecodeCache):
 # INDEX_LEAVES hold write positions (scalar in ``generate``'s lockstep
 # cache; [slots] vectors in the serving cache), KV_LEAVES are the pools
-from deepspeed_tpu.models.common import INDEX_LEAVES, KV_LEAVES
+from deepspeed_tpu.models.common import (INDEX_LEAVES, KV_LEAVES, slot_pool,
+                                         slot_pool_positions, slot_pool_scale)
 from deepspeed_tpu.utils import trace
 
 
@@ -43,32 +45,40 @@ def _is_index_leaf(path) -> bool:
 def make_slot_cache(module, slots: int, kv_quant: bool = False):
     """A per-slot serving cache: the model's decode cache with every index
     leaf widened from a scalar to a [slots] vector (which is what flips
-    the model's decode branch to per-slot scatter writes + per-slot
-    ``decode_lengths``). Slots start PARKED (sentinel position).
+    the model's decode branch to per-slot writes + per-slot
+    ``decode_lengths``) and every KV pool in the stored form of
+    ``models/common.py`` ``slot_pool`` (positions minor-most, which is what
+    lets a tick write a token in place). Slots start PARKED (sentinel
+    position).
 
     ``kv_quant=True`` (the ``ServingConfig.kv_quant`` serving default)
     converts the KV pools to int8 codes and adds a
-    ``<leaf>_scale [slots, P, H, 1]`` companion per pool — the provided
+    ``<leaf>_scale [slots, H, P]`` companion per pool — the provided
     cache dtype is what statically flips the model's decode branch to
     quantize-on-write / dequantize-on-read."""
     from deepspeed_tpu.models.common import init_cache
-    cache = init_cache(module, slots)
-    parked = slot_capacity(cache)
 
-    def widen(path, leaf):
-        if _is_index_leaf(path):
-            return jnp.full((slots,), parked, jnp.int32)
-        return leaf
+    def stored(cache):
+        def leaf_of(path, leaf):
+            if _is_index_leaf(path):
+                return jnp.zeros((slots,), jnp.int32)
+            return slot_pool(leaf) if _leaf_name(path) in KV_LEAVES else leaf
 
-    cache = jax.tree_util.tree_map_with_path(widen, cache)
-    if kv_quant:
-        cache = quantize_slot_cache(cache)
-    return cache
+        cache = jax.tree_util.tree_map_with_path(leaf_of, cache)
+        return quantize_slot_cache(cache) if kv_quant else cache
+
+    # shapes first, then each stored leaf once: set-up never holds the
+    # lockstep pools, nor fp pools beside their int8 form
+    shapes = jax.eval_shape(lambda: stored(init_cache(module, slots)))
+    parked = slot_capacity(shapes)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, leaf: jnp.full(leaf.shape, parked if _is_index_leaf(path) else 0,
+                                    leaf.dtype), shapes)
 
 
 def quantize_slot_cache(cache):
     """int8-KV view of a (fresh) slot cache: each KV pool becomes int8
-    codes and gains a per-(slot, position, head) scale leaf in the pool's
+    codes and gains a per-(slot, head, position) scale leaf in the pool's
     original dtype. Zero scales on parked/unwritten rows dequantize to the
     zeros the fp cache would hold."""
 
@@ -79,8 +89,7 @@ def quantize_slot_cache(cache):
                 out[name] = walk(leaf)
             elif name in KV_LEAVES:
                 out[name] = jnp.zeros(leaf.shape, jnp.int8)
-                out[name + "_scale"] = jnp.zeros(leaf.shape[:-1] + (1,),
-                                                 leaf.dtype)
+                out[name + "_scale"] = slot_pool_scale(leaf)
             else:
                 out[name] = leaf
         return out
@@ -89,11 +98,12 @@ def quantize_slot_cache(cache):
 
 
 def slot_capacity(cache) -> int:
-    """Token capacity per slot = the KV pool's position extent (also the
-    parked-slot sentinel: a write at this position drops out of bounds)."""
+    """Token capacity per slot of a serving cache = its KV pools' position
+    extent (also the parked-slot sentinel: a write at this position drops
+    out of bounds)."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         if _leaf_name(path) in KV_LEAVES:
-            return int(leaf.shape[1])
+            return slot_pool_positions(leaf)
     raise ValueError("cache has no cached_key leaves — not a decode cache")
 
 
@@ -216,7 +226,6 @@ def serve_programs(engine, slots_bucket: int, *, prefill_chunk: int,
                    do_sample: bool, temperature: float, top_k: int, top_p: float,
                    spec_k: int = 0, role: str = "target",
                    module=None, mparams=None,
-                   kv_write: Optional[str] = None,
                    weight_dtype: Optional[str] = None) -> Dict[str, Any]:
     """The serving program dict for one pow2 slot bucket, cached on the
     ENGINE (``engine._serve_cache``) so every scheduler over the same
@@ -225,10 +234,10 @@ def serve_programs(engine, slots_bucket: int, *, prefill_chunk: int,
     satellite counts exactly one program set per bucket).
 
     ``role``/``module`` let the speculation drafter park its own programs
-    in the same cache under a distinct key; ``kv_write`` and
-    ``weight_dtype`` are the RESOLVED per-slot write mode / served weight
-    dtype the caller will trace under — part of the key, so schedulers
-    with different modes on one engine never share a program.
+    in the same cache under a distinct key; ``weight_dtype`` is the
+    RESOLVED served weight dtype the caller will trace under — part of the
+    key, so schedulers with different dtypes on one engine never share a
+    program.
 
     The key carries the module's identity (the cached closures keep the
     module alive, so ``id`` cannot be recycled): two drafters with
@@ -240,20 +249,31 @@ def serve_programs(engine, slots_bucket: int, *, prefill_chunk: int,
         engine._serve_cache = {}
     mod = module if module is not None else engine.module
     key = (role, id(mod), int(slots_bucket), int(prefill_chunk), bool(do_sample),
-           float(temperature), int(top_k), float(top_p), int(spec_k), kv_write,
-           weight_dtype)
+           float(temperature), int(top_k), float(top_p), int(spec_k), weight_dtype)
     if key in engine._serve_cache:
         return engine._serve_cache[key]
     trace.recorder().count("serve_program_builds")
     apply_fn = make_apply_fn(mod,
                              mparams if mparams is not None else engine._mparams)
+    jit_kwargs: Dict[str, Any] = {"donate_argnums": (1,)}
+    if engine.mesh.size == 1:
+        # On one device every placement is the same one, and jit then names an
+        # output's after the first operand of its rank: an int8 scale leaf
+        # [slots, heads, positions] comes back worded as some rank-3 weight's
+        # ``P(None, 'tensor', None)``, not the ``P()`` the scheduler placed
+        # the fresh cache with, and the first tick on that evolved cache is a
+        # second compile (``test_ticks_put_at_most_once_and_never_retrace``).
+        # So the cache is said to come back as it went in. Not on a mesh:
+        # there the placement of the pools is GSPMD's to choose (heads over
+        # ``tensor``), which a replicated ``P()`` here would undo.
+        jit_kwargs["out_shardings"] = (NamedSharding(engine.mesh, PartitionSpec()), None)
     fns: Dict[str, Any] = {
         "prefill": jax.jit(build_prefill_step(apply_fn, do_sample, temperature,
-                                              top_k, top_p), donate_argnums=(1,)),
+                                              top_k, top_p), **jit_kwargs),
         "decode": jax.jit(build_decode_step(apply_fn, do_sample, temperature,
-                                            top_k, top_p), donate_argnums=(1,)),
+                                            top_k, top_p), **jit_kwargs),
     }
     if spec_k > 0:
-        fns["verify"] = jax.jit(build_verify_step(apply_fn), donate_argnums=(1,))
+        fns["verify"] = jax.jit(build_verify_step(apply_fn), **jit_kwargs)
     engine._serve_cache[key] = fns
     return fns

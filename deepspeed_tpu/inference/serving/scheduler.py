@@ -41,10 +41,8 @@ import jax
 
 from deepspeed_tpu.inference.serving.blocks import BlockPool
 from deepspeed_tpu.inference.serving.config import (ServingConfig,
-                                                    resolve_kv_write,
                                                     resolve_prefix_cache,
                                                     resolve_weight_dtype,
-                                                    set_default_kv_write,
                                                     set_default_prefix_cache,
                                                     set_default_weight_dtype)
 from deepspeed_tpu.inference.serving.programs import (KV_LEAVES, _leaf_name,
@@ -53,6 +51,9 @@ from deepspeed_tpu.inference.serving.programs import (KV_LEAVES, _leaf_name,
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
+from deepspeed_tpu.models.common import (slot_pool_positions_touched,
+                                         slot_pool_row_shape, slot_pool_rows,
+                                         slot_pool_set_rows)
 from deepspeed_tpu.runtime.telemetry.metrics import Histogram
 from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import log_dist
@@ -100,7 +101,7 @@ def _quant_view(module, params, weight_dtype: str, group_size: int):
 def _restore_rows_jit_impl(flat_cache, rows, slot, kv_idx):
     out = list(flat_cache)
     for j, i in enumerate(kv_idx):
-        out[i] = out[i].at[slot, :rows[j].shape[0]].set(rows[j])
+        out[i] = slot_pool_set_rows(out[i], slot, rows[j])
     return out
 
 
@@ -154,7 +155,7 @@ class ContinuousBatchingScheduler:
         self._moe_rows = getattr(engine.module, "moe_rows", None)
 
         # graft-quant-serve: resolve the served weight dtype (env outranks
-        # config — the DS_SERVE_WQ drift seam, same layering as kv_write)
+        # config — the DS_SERVE_WQ drift seam)
         # and, when quantized, swap in the quant module + code/scale bundle
         # every program below closes over. The engine's own params stay fp.
         set_default_weight_dtype(config.weight_dtype)
@@ -213,15 +214,6 @@ class ContinuousBatchingScheduler:
         self.queue = RequestQueue(self.pool, max_queue=config.max_queue,
                                   max_total_tokens=self.capacity, clock=self.clock)
 
-        # the config's kv_write must reach the TRACED program, not just the
-        # evidence row: install it as the process default (the engine
-        # attention-block install/clear pattern — None clears), resolve the
-        # mode the program will actually trace under (env still outranks
-        # config, which is the DS_SERVE_KV_WRITE drift seam), and re-install
-        # at every tick so a program traced lazily after another scheduler's
-        # construction still binds THIS scheduler's mode.
-        set_default_kv_write(config.kv_write)
-        self.kv_write, self.kv_write_source = resolve_kv_write(None)
         if self.spec_k and drafter is None:
             raise ValueError("speculation.enabled needs a drafter: pass "
                              "drafter=(module, params) — e.g. the KD student from "
@@ -233,7 +225,7 @@ class ContinuousBatchingScheduler:
                                   module=self.module if quantized else None,
                                   mparams=(lambda p: p) if quantized else None,
                                   prefill_chunk=config.prefill_chunk,
-                                  spec_k=self.spec_k, kv_write=self.kv_write,
+                                  spec_k=self.spec_k,
                                   weight_dtype=self.weight_dtype if quantized else None,
                                   **sampling)
         self._drafter = None
@@ -257,7 +249,7 @@ class ContinuousBatchingScheduler:
             self.dfns = serve_programs(engine, self.slots, role="drafter",
                                        module=d_module, mparams=lambda p: p,
                                        prefill_chunk=config.prefill_chunk,
-                                       spec_k=self.spec_k, kv_write=self.kv_write,
+                                       spec_k=self.spec_k,
                                        weight_dtype=d_weight_dtype,
                                        **sampling)
 
@@ -288,8 +280,7 @@ class ContinuousBatchingScheduler:
         self.last_weight_sync: Optional[dict] = None
         log_dist(f"graft-serve: slots={self.slots} capacity={self.capacity} "
                  f"pool={self.pool.num_blocks}x{self.pool.block_size} "
-                 f"chunk={config.prefill_chunk} kv_write={self.kv_write}"
-                 f"({self.kv_write_source}) wq={self.weight_dtype}"
+                 f"chunk={config.prefill_chunk} wq={self.weight_dtype}"
                  f"({self.weight_dtype_source}) kv_quant={self.kv_quant} "
                  f"spec_k={self.spec_k} prefix_cache={self.prefix_cache}"
                  f"({self.prefix_cache_source})")
@@ -339,6 +330,18 @@ class ContinuousBatchingScheduler:
             self._rec.count("moe_rows_routed", fed * per_position)
             self._rec.count("moe_rows_computed", rows)
 
+    def _count_kv_write(self, write_pos: np.ndarray, length: int) -> None:
+        """One target pass writes ``length`` tokens at each live slot's
+        ``write_pos``: positions one pool leaf is handed against positions
+        the write rewrites there (whole windows, ``slot_pool_append``).
+        The ratio is what writing in place costs; a write that relaid the
+        pool would touch slots x capacity every tick."""
+        live = write_pos[write_pos < self.capacity]
+        self._rec.count("kv_positions_written",
+                        int(np.minimum(length, self.capacity - live).sum()))
+        self._rec.count("kv_window_positions_touched",
+                        slot_pool_positions_touched(live, length, self.capacity))
+
     def _phase(self, name: str):
         """A host phase of the tick in progress: a child span of ``tick``."""
         return self._rec.span(name, self._tick_no, self._source)
@@ -353,7 +356,6 @@ class ContinuousBatchingScheduler:
         rare-path programs (the drafter's refeed verify only runs when
         some slot accepts all k drafts). Touches no request accounting,
         no histograms, and not the sampling rng stream."""
-        set_default_kv_write(self.config.kv_write)
         set_default_weight_dtype(self.config.weight_dtype)
         parked = np.full(self.slots, self.capacity, np.int32)
         rng = ((jax.random.PRNGKey(0),) if self.config.do_sample else ())
@@ -428,16 +430,14 @@ class ContinuousBatchingScheduler:
         (zero-copy on the CPU backend — the migration exporter's lesson)
         and copies ONLY the requested rows: an eager device-side slice
         would compile a fresh XLA program per (start, stop) offset, one
-        per publishing request. ``np.array(copy=True)`` because a view
-        would alias the device buffer the next donated decode step
-        frees."""
+        per publishing request. A copy, because a view would alias the
+        device buffer the next donated decode step frees."""
         out: Dict[str, np.ndarray] = {}
         for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
             name = _leaf_name(path)
             if name in KV_LEAVES or name.endswith("_scale"):
                 host = np.asarray(jax.device_get(leaf))
-                out[jax.tree_util.keystr(path)] = np.array(
-                    host[slot, start:stop], copy=True)
+                out[jax.tree_util.keystr(path)] = slot_pool_rows(host, slot, start, stop)
         return out
 
     def _restore_prefix(self, slot: int, match) -> None:
@@ -446,7 +446,7 @@ class ContinuousBatchingScheduler:
         payloads concatenate, the partial block contributes its first
         ``partial_tokens`` rows (the COW copy — the shared source block's
         payload is read, never written). Payload rows restore through the
-        migration writer (``.at[slot, :n].set``) so the buffers stay
+        migration writer (``slot_pool_set_rows``) so the buffers stay
         XLA-owned on the existing placement."""
         roles = [("target", "_cache")]
         if self._drafter is not None:
@@ -519,9 +519,8 @@ class ContinuousBatchingScheduler:
         """One scheduler tick; returns the tick kind it ran
         (``prefill`` | ``decode`` | ``spec`` | ``idle``)."""
         step_no = sum(self.ticks.values()) + 1
-        # lazily-traced programs must bind THIS scheduler's write mode even
+        # lazily-traced programs must bind THIS scheduler's weight dtype even
         # if another scheduler re-installed the default since construction
-        set_default_kv_write(self.config.kv_write)
         set_default_weight_dtype(self.config.weight_dtype)
         if self.telemetry is not None:
             self.telemetry.begin_step(step_no)
@@ -763,6 +762,7 @@ class ContinuousBatchingScheduler:
         self._rec.count("prefill_positions_fed", fed)
         self._rec.count("prefill_positions_computed", self.slots * C)
         self._count_moe_rows(fed, self.slots * C)
+        self._count_kv_write(write_pos, C)
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32), ids, last_idx)
         with self._phase("dispatch"):
@@ -808,6 +808,7 @@ class ContinuousBatchingScheduler:
         self._rec.count("decode_slots_fed", len(slots))
         self._rec.count("decode_slots_computed", self.slots)
         self._count_moe_rows(len(slots), self.slots)
+        self._count_kv_write(write_pos, 1)
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32), tokens)
         with self._phase("dispatch"):
@@ -846,6 +847,7 @@ class ContinuousBatchingScheduler:
         self._rec.count("decode_slots_fed", len(slots))
         self._rec.count("decode_slots_computed", self.slots)
         self._count_moe_rows(len(slots) * (k + 1), self.slots * (k + 1))  # the verify pass
+        self._count_kv_write(write_pos, k + 1)
         # committed to the mesh placement so iteration 1's input sharding
         # matches iterations 2..k (which feed the previous jit output back);
         # an uncommitted first feed would cost a second decode compile
@@ -913,23 +915,12 @@ class ContinuousBatchingScheduler:
         the leaf's ``keystr`` path so target and drafter caches (same leaf
         names, different depths) stay unambiguous. Only ``[:length]`` rows
         travel: everything past the committed prefix is scratch."""
-        out: Dict[str, np.ndarray] = {}
-        for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-            name = _leaf_name(path)
-            if name in KV_LEAVES or name.endswith("_scale"):
-                host = np.asarray(jax.device_get(leaf))
-                # np.array copy=True, NOT ascontiguousarray: a row-prefix
-                # slice is already contiguous, so ascontiguousarray would
-                # return a zero-copy VIEW into the device buffer — which
-                # the next donated decode step frees under the payload
-                out[jax.tree_util.keystr(path)] = np.array(
-                    host[slot, :length], copy=True)
-        return out
+        return self._kv_rows(cache, slot, 0, length)
 
     def _restore_slot_kv(self, cache, slot: int, leaves: Dict[str, np.ndarray],
                          length: int):
         """Write migrated KV rows back into one slot of ``cache`` on
-        device (``.at[slot, :length].set``). Refuses — ``MigrationError``
+        device (``slot_pool_set_rows``). Refuses — ``MigrationError``
         — on a missing/mis-shaped/mis-typed leaf rather than serving a
         half-restored cache.
 
@@ -971,7 +962,7 @@ class ContinuousBatchingScheduler:
             if src is None:
                 raise MigrationError(f"migration bundle missing KV leaf {key}")
             src = np.asarray(src)
-            want_shape = (length,) + tuple(leaf.shape[2:])
+            want_shape = (length,) + slot_pool_row_shape(leaf)
             want_dtype = np.dtype(leaf.dtype)
             if src.shape != want_shape or src.dtype != want_dtype:
                 raise MigrationError(
@@ -1244,8 +1235,6 @@ class ContinuousBatchingScheduler:
             "generated_tokens": sum(len(r.output) for r in done),
             "ticks": dict(self.ticks),
             "pool": pool,
-            "kv_write": self.kv_write,
-            "kv_write_source": self.kv_write_source,
             "weight_dtype": self.weight_dtype,
             "weight_dtype_source": self.weight_dtype_source,
             "kv_quant": self.kv_quant,

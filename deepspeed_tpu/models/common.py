@@ -1,7 +1,9 @@
 """Shared model-zoo helpers."""
 
 import functools
-from typing import Any, Optional
+from typing import Any
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -47,24 +49,29 @@ INDEX_LEAVES = ("cache_index", "position_index")
 class DecodeCache:
     """One attention layer's decode cache in the flax ``cache`` collection
     (the reference's inference workspace, ``inference_context.h``): static
-    pools ``cached_key`` / ``cached_value`` [batch, positions, kv heads,
-    head dim] and the write index ``cache_index``. The one implementation
-    every decoder-only family's attention calls, so a change to the serving
-    cache is made once.
+    pools ``cached_key`` / ``cached_value`` and the write index
+    ``cache_index``. The one implementation every decoder-only family's
+    attention calls, so a change to the serving cache is made once.
 
     What the *provided* cache looks like decides, statically, which branch
     traces:
 
-    * ``cache_index`` a scalar: lockstep decode (``generate``): every
-      sequence appends at the same position, one ``dynamic_update_slice``.
+    * ``cache_index`` a scalar: lockstep decode (``generate``): pools
+      [batch, positions, kv heads, head dim], every sequence appends at
+      the same position, one ``dynamic_update_slice``.
     * ``cache_index`` a ``[batch]`` vector (``serving.make_slot_cache``):
-      each slot of an in-flight batch appends at its own length. Join and
-      leave are positional: a parked slot's sentinel position (>= the
-      pool's extent) makes its scatter writes drop out of bounds, no
-      ``jnp.where`` over the pool.
+      each slot of an in-flight batch appends at its own length, and the
+      pools are stored positions-minor (:func:`slot_pool`): [slots, kv
+      heads, head dim, positions], the layout the TPU holds a pool in and
+      attention reads it in, so the write (:func:`slot_pool_append`)
+      rewrites one or two 128-position windows per slot in place instead
+      of relaying the whole pool around a scatter. Join and leave are
+      positional: a parked slot's sentinel position (>= the pool's
+      extent) writes nothing.
     * int8 pools (``make_slot_cache(kv_quant=True)``, the serving
-      default): codes plus per-(slot, position, head) ``_scale`` leaves,
-      quantized on write and dequantized on read.
+      default): codes plus per-(slot, head, position) ``_scale`` leaves
+      [slots, kv heads, positions], quantized on write and dequantized on
+      read.
     """
 
     def __init__(self, module: nn.Module, batch: int, positions: int, kv_heads: int,
@@ -75,9 +82,9 @@ class DecodeCache:
         self.quantized = self.key.value.dtype == jnp.int8
         if self.quantized:
             self.key_scale = module.variable("cache", "cached_key_scale", jnp.zeros,
-                                             shape[:-1] + (1,), dtype)
+                                             (batch, kv_heads, positions), dtype)
             self.value_scale = module.variable("cache", "cached_value_scale", jnp.zeros,
-                                               shape[:-1] + (1,), dtype)
+                                               (batch, kv_heads, positions), dtype)
         self.index = module.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
 
     @property
@@ -92,66 +99,190 @@ class DecodeCache:
         return jnp.broadcast_to(start + jnp.arange(length)[None, :],
                                 (self.key.value.shape[0], length))
 
-    def append(self, k, v, read_dtype, kv_write: Optional[str] = None):
+    def append(self, k, v, read_dtype):
         """Write ``k`` / ``v`` [batch, l, kv heads, head dim] at the index,
         advance it, and return ``(keys, values, decode_lengths)``: the whole
-        pools as attention reads them (``read_dtype`` values, HBM holds the
-        codes) and each sequence's live length."""
+        pools [batch, positions, kv heads, head dim] as attention reads them
+        (``read_dtype`` values, HBM holds the codes) and each sequence's
+        live length."""
         b, l = k.shape[0], k.shape[1]
         idx = self.index.value
         if self.per_slot:
-            self._append_per_slot(k, v, kv_write)
-            decode_lengths = idx + l
-        else:
-            if self.quantized:
-                raise NotImplementedError(
-                    "int8 KV pools are a per-slot serving cache "
-                    "(make_slot_cache(kv_quant=True)); lockstep decode uses fp KV")
-            self.key.value = jax.lax.dynamic_update_slice(self.key.value, k, (0, idx, 0, 0))
-            self.value.value = jax.lax.dynamic_update_slice(self.value.value, v, (0, idx, 0, 0))
-            # per-sequence live lengths: the flash backend's decode kernel
-            # skips dead KV blocks, the XLA backend masks by them
-            decode_lengths = jnp.broadcast_to(idx + l, (b,))
-        self.index.value = idx + l
+            self._append_per_slot(k, v)
+            self.index.value = idx + l
+            scales = (self.key_scale, self.value_scale) if self.quantized else (None, None)
+            keys, values = (slot_pool_read(pool.value, scale and scale.value, read_dtype)
+                            for pool, scale in zip((self.key, self.value), scales))
+            return keys, values, idx + l
         if self.quantized:
-            # gather-dequant: attention reads fp values, HBM holds codes
-            return (self.key.value.astype(read_dtype) * self.key_scale.value,
-                    self.value.value.astype(read_dtype) * self.value_scale.value,
-                    decode_lengths)
-        return self.key.value, self.value.value, decode_lengths
+            raise NotImplementedError(
+                "int8 KV pools are a per-slot serving cache "
+                "(make_slot_cache(kv_quant=True)); lockstep decode uses fp KV")
+        self.key.value = jax.lax.dynamic_update_slice(self.key.value, k, (0, idx, 0, 0))
+        self.value.value = jax.lax.dynamic_update_slice(self.value.value, v, (0, idx, 0, 0))
+        self.index.value = idx + l
+        # per-sequence live lengths: the flash backend's decode kernel
+        # skips dead KV blocks, the XLA backend masks by them
+        return self.key.value, self.value.value, jnp.broadcast_to(idx + l, (b,))
 
-    def _append_per_slot(self, k, v, kv_write):
-        from deepspeed_tpu.inference.serving.config import resolve_kv_write
-        mode, _ = resolve_kv_write(kv_write)
-        b, l = k.shape[0], k.shape[1]
-        extent = self.key.value.shape[1]
-        pos = self.positions(l)  # [b, l]
-        pools = [(self.key, k), (self.value, v)]
+    def _append_per_slot(self, k, v):
+        pools, vals = [self.key, self.value], [k, v]
         if self.quantized:
             (k, k_s), (v, v_s) = _kv_quantize(k), _kv_quantize(v)
-            pools = [(self.key, k), (self.value, v),
-                     (self.key_scale, k_s), (self.value_scale, v_s)]
-        if mode == "dense":
-            # masked full-pool rebuild: one [b, l, P] one-hot and a
-            # [b, P, h, d] temporary PER LAYER per tick — kept as the
-            # DS_SERVE_KV_WRITE seeded regression for the R010 gate
-            # (semantically identical: out-of-bounds one-hot rows are
-            # zero, so parked slots still drop their writes)
-            onehot = jax.nn.one_hot(pos, extent, dtype=jnp.float32)
-            written = (onehot.sum(1) > 0)[..., None, None]  # [b, P, 1, 1]
-            for pool, vals in pools:
-                upd = jnp.einsum("blp,blhd->bphd", onehot, vals.astype(jnp.float32))
-                if vals.dtype == jnp.int8:
-                    # int8 codes survive the fp32 einsum exactly
-                    # (±127 ≪ 2^24); rint guards the cast back
-                    upd = jnp.rint(upd)
-                pool.value = jnp.where(written, upd.astype(pool.value.dtype), pool.value)
-        else:
-            bidx = jnp.arange(b)[:, None]
-            # default scatter mode drops out-of-bounds updates — exactly
-            # the parked-slot contract
-            for pool, vals in pools:
-                pool.value = pool.value.at[bidx, pos].set(vals)
+            pools += [self.key_scale, self.value_scale]
+            vals = [k, v, k_s[..., 0], v_s[..., 0]]
+        for pool, new in zip(pools, slot_pool_append([p.value for p in pools], vals,
+                                                     self.index.value)):
+            pool.value = new
+
+
+def slot_pool(leaf):
+    """A zeroed lockstep pool leaf [slots, positions, kv heads, head dim] in
+    the serving cache's stored form, positions minor-most: [slots, kv heads,
+    head dim, positions]. Whoever walks a serving cache's pool leaves
+    outside :class:`DecodeCache` (``inference/serving``) reads them through
+    the ``slot_pool_*`` functions below, never by a dimension's number."""
+    s, p, h, d = leaf.shape
+    return jnp.zeros((s, h, d, p), leaf.dtype)
+
+
+def slot_pool_read(pool, scale, read_dtype):
+    """A stored pool as attention's [slots, positions, kv heads, head dim]
+    operand, an int8 pool dequantised by its ``scale`` (attention reads fp
+    values, HBM holds the codes): a change of logical order only, which the
+    compiler folds into the consumer's layout."""
+    if scale is not None:
+        pool = pool.astype(read_dtype) * scale[:, :, None, :]
+    return jnp.transpose(pool, (0, 3, 1, 2))
+
+
+def slot_pool_scale(leaf):
+    """The zeroed int8 scale leaf of a stored pool leaf: one scale per
+    (slot, kv head, position), in the pool's own dtype."""
+    return jnp.zeros(leaf.shape[:2] + leaf.shape[-1:], leaf.dtype)
+
+
+def slot_pool_positions(leaf) -> int:
+    """Token capacity per slot of a stored pool (or scale) leaf."""
+    return int(leaf.shape[-1])
+
+
+def slot_pool_rows(leaf, slot: int, start: int, stop: int):
+    """Positions ``[start:stop)`` of one slot of a stored leaf (a host
+    array), position-major: codes or values [n, kv heads, head dim],
+    scales [n, kv heads] — what a prefix block or a migration carries.
+    Always a copy."""
+    return np.array(np.moveaxis(leaf[slot, ..., start:stop], -1, 0), copy=True, order="C")
+
+
+def slot_pool_row_shape(leaf) -> tuple:
+    """The shape of one position's row of a stored leaf."""
+    return tuple(leaf.shape[1:-1])
+
+
+def slot_pool_set_rows(leaf, slot, rows):
+    """Traced: ``rows`` (position-major, as :func:`slot_pool_rows` gives
+    them) written to positions ``[0:n)`` of ``slot`` of a stored leaf."""
+    return leaf.at[slot, ..., :rows.shape[0]].set(jnp.moveaxis(rows, 0, -1))
+
+
+#: positions of a written window: one lane row of the TPU's tiling
+_WINDOW = 128
+
+
+def _append_span(length: int, positions: int) -> int:
+    """Positions of a slot that :func:`_append_in_place` rewrites for a
+    piece of ``length`` tokens: the aligned window that holds one token;
+    two windows for more, so that the piece may straddle a boundary; a pool
+    whose extent is no multiple of a window is one window."""
+    w = _WINDOW if positions % _WINDOW == 0 else positions
+    return w if length == 1 else min(2 * w, positions)
+
+
+def slot_pool_append(leaves, updates, pos):
+    """Write ``updates[i]`` [slots, l, ...] (token-major, as the projections
+    produce them) into the stored leaves ``leaves[i]`` [slots, ..., positions]
+    at positions ``pos[s] .. pos[s] + l - 1`` of each slot ``s``; returns the
+    new leaves. A position at or past the extent writes nothing (a parked
+    slot); tokens past the extent are dropped.
+
+    On a TPU, :func:`_append_in_place`. Elsewhere one scatter on the minor
+    dimension: the same write, and what the in-place one is tested against;
+    the TPU would relay the whole pool around it (``PERF.md``, PR 27)."""
+    from deepspeed_tpu.ops.pallas import backend
+    pos = pos.astype(jnp.int32)
+    if backend.on_tpu():
+        return _append_in_place(leaves, updates, pos)
+    slots, length = updates[0].shape[:2]
+    at = pos[:, None] + jnp.arange(length)[None, :]
+    # advanced indices on the first and last axes: the indexed result is
+    # [slots, l, ...], the updates' own shape; out of bounds drops
+    return [leaf.at[jnp.arange(slots)[:, None], ..., at].set(upd.astype(leaf.dtype))
+            for leaf, upd in zip(leaves, updates)]
+
+
+def _append_in_place(leaves, updates, pos):
+    """The write as a read-modify-write of the aligned span that holds the
+    tokens, one slot at a time: a ``fori_loop`` of scalar-indexed
+    ``dynamic_slice`` / select / ``dynamic_update_slice``, which XLA updates
+    in place under donation."""
+    leaves, updates = list(leaves), list(updates)
+    length, piece = updates[0].shape[1], _append_span(1, leaves[0].shape[-1])
+    # a piece of at most one window's tokens touches at most two windows
+    for start in range(0, length, piece):
+        leaves = _append_piece(leaves, [u[:, start:start + piece] for u in updates],
+                               pos + start)
+    return leaves
+
+
+# jitted, so that a model's layers share one trace of the write
+@jax.jit
+def _append_piece(leaves, updates, pos):
+    positions = leaves[0].shape[-1]
+    slots, length = updates[0].shape[:2]
+    span = _append_span(length, positions)
+    # the aligned span that holds [pos, pos + length), pulled inside the
+    # pool: a parked slot's is the last one, where its mask is empty
+    start = jnp.clip(pos // _WINDOW * _WINDOW, 0, positions - span)
+
+    def window(leaf, upd):
+        """``upd`` as the leaf lays it out, token j of slot s on lane
+        ``pos[s] - start[s] + j`` of the span."""
+        upd = jnp.moveaxis(upd.astype(leaf.dtype), 1, -1)
+        upd = jnp.pad(upd, [(0, 0)] * (upd.ndim - 1) + [(0, span - length)])
+        return jax.vmap(lambda x, by: jnp.roll(x, by, axis=-1))(upd, pos - start)
+
+    # built outside the loop, which then only selects and stores
+    wins = [window(leaf, upd) for leaf, upd in zip(leaves, updates)]
+    lane = jnp.arange(span)
+
+    def body(s, leaves):
+        j = start[s] + lane - pos[s]
+        written = (j >= 0) & (j < length)
+        out = []
+        for leaf, win in zip(leaves, wins):
+            at = (s,) + (0,) * (leaf.ndim - 2) + (start[s],)
+            old = jax.lax.dynamic_slice(leaf, at, (1,) + leaf.shape[1:-1] + (span,))
+            new = jnp.where(written, jax.lax.dynamic_index_in_dim(win, s, 0), old)
+            out.append(jax.lax.dynamic_update_slice(leaf, new, at))
+        return out
+
+    return jax.lax.fori_loop(0, slots, body, leaves)
+
+
+def slot_pool_positions_touched(pos, length: int, positions: int) -> int:
+    """Positions :func:`slot_pool_append` rewrites in one stored leaf for
+    ``length`` tokens at each of ``pos`` (a host array of write positions),
+    none for a parked one: on a TPU a span for every live slot and piece,
+    elsewhere the positions written. What the write costs, beside the
+    ``length`` a slot it is handed."""
+    from deepspeed_tpu.ops.pallas import backend
+    live = np.asarray(pos)[np.asarray(pos) < positions]
+    if not backend.on_tpu():
+        return int(np.minimum(length, positions - live).sum())
+    piece = _append_span(1, positions)
+    return len(live) * sum(_append_span(min(piece, length - start), positions)
+                           for start in range(0, length, piece))
 
 
 def _kv_quantize(vals):

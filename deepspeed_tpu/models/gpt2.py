@@ -254,8 +254,7 @@ class SelfAttention(nn.Module):
             from deepspeed_tpu.models.common import DecodeCache
             cache = DecodeCache(self, x.shape[0], cfg.n_positions, cfg.n_head, cfg.head_dim,
                                 k.dtype)
-            k, v, decode_lengths = cache.append(k, v, q.dtype,
-                                                getattr(cfg, "serve_kv_write", None))
+            k, v, decode_lengths = cache.append(k, v, q.dtype)
             causal = False
         from deepspeed_tpu.models.common import attention_geometry_kwargs
         attn_out = dot_product_attention(q,

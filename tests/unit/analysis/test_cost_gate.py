@@ -29,7 +29,7 @@ def graft_lint():
 
 
 _ENVS = (routing.ENV_ROUTE, "DS_PIPE_ACT_BUDGET_MB", "DS_PIPE_SCHEDULE",
-         "DS_SERVE_KV_WRITE", "DS_SERVE_WQ")
+         "DS_SERVE_WQ")
 
 
 @pytest.fixture(autouse=True)
@@ -173,26 +173,6 @@ def test_pipe_schedule_env_drift_exits_1(graft_lint, tmp_path, monkeypatch):
     report = _report(tmp_path)
     hits = report["programs"]["pipe_1f1b_step"]["summary"]["rule_hits"]
     assert hits.get("R009") and hits.get("R010")
-
-
-def test_serve_kv_write_env_drift_exits_1(graft_lint, tmp_path, monkeypatch):
-    """DS_SERVE_KV_WRITE=dense against the committed-scatter serving
-    scenario (the DS_MOE_ROUTE pattern on a serving knob): the masked
-    full-pool KV rebuild fattens the per-tick transient past the
-    committed budget — R010 fires and the R013 ratchet reports the
-    regression vs the banked scatter price."""
-    monkeypatch.setenv("DS_SERVE_KV_WRITE", "dense")
-    rc = graft_lint.run(["--cost", "--scenarios", "serve_decode_step",
-                         "--no-ast", "--out", str(tmp_path), "-q"])
-    assert rc == 1
-    report = _report(tmp_path)
-    hits = report["programs"]["serve_decode_step"]["summary"]["rule_hits"]
-    assert hits.get("R010") or hits.get("R013"), hits
-    # the scenario's declared intent stays the committed one — the drift
-    # is visible precisely because the env layer cannot rewrite it
-    from deepspeed_tpu.analysis.scenarios import SERVE_DECODE_BUDGET_MB
-    assert (report["cost"]["serve_decode_step"]
-            ["memory"]["peak_transient_bytes"] > SERVE_DECODE_BUDGET_MB * 2**20)
 
 
 def test_serve_wq_env_drift_exits_1(graft_lint, tmp_path, monkeypatch):

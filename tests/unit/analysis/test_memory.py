@@ -48,6 +48,35 @@ class TestLiveness:
         est = _est(f, x)
         assert est.peak_transient_bytes <= est.peak_bytes - 4 * KB
 
+    @pytest.mark.parametrize("consumer,is_view", [("dot", True), ("add", False),
+                                                  ("dot_and_add", False)])
+    def test_a_transpose_that_only_dots_read_is_a_view(self, consumer, is_view):
+        """A dot names its operand's axes in any order, so a transpose it
+        alone reads is no buffer (and keeps what it views alive); read by
+        anything else, or returned, it is a copy."""
+        x = jnp.ones((64, 16), jnp.float32)  # 4 KiB
+        w = jnp.ones((64, 4), jnp.float32)   # 1 KiB
+
+        def f(x, w):
+            y = jnp.tanh(x)                  # 4 KiB, a transient
+            t = y.T                          # 4 KiB, unless a view
+            out = t @ w                      # [16, 4]: 256 B
+            if consumer == "add":
+                out = (t + 1.0)[:, :4]
+            elif consumer == "dot_and_add":
+                out = out + (t + 1.0)[:, :4]
+            return out
+
+        est = _est(f, x, w)
+        if is_view:
+            # y lives through the dot that reads its view, and nothing
+            # else of its size does
+            assert 4 * KB <= est.peak_transient_bytes < 5 * KB
+        else:
+            assert est.peak_transient_bytes >= 8 * KB
+        returned = _est(lambda x, w: (jnp.tanh(x).T, jnp.tanh(x).T @ w), x, w)
+        assert returned.output_bytes >= 4 * KB
+
     def test_fanout_holds_all_branches(self):
         """Three branches off one input, combined at the end: all three
         branch buffers + the input are live at the join."""

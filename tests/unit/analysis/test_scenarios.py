@@ -82,11 +82,9 @@ def test_cost_signature_metadata_armed(matrix):
         assert {"dense_dispatch", "resharding"} <= kinds
     if "serve_decode_step" in programs:
         # the graft-serve decode tick (PR 14): budget armed for R010, the
-        # tp=2 serving collective signature pinned for R009, and the
-        # committed KV-write intent declared (env drift has no way in)
+        # tp=2 serving collective signature pinned for R009
         meta = programs["serve_decode_step"].metadata
         assert meta.get("activation_budget_bytes", 0) > 0
-        assert meta["serve_kv_write"] == "scatter"
         assert any(e["kind"] == "all_reduce" and e["count"] == 5
                    for e in meta["collective_signature"])
 

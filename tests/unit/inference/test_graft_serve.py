@@ -224,33 +224,6 @@ def test_two_slot_buckets_compile_two_program_sets():
     assert outs[3] == outs[4] and outs[6] == outs[8]
 
 
-def test_config_kv_write_reaches_the_traced_program():
-    """ServingConfig.kv_write must not be a dead reporting knob: an
-    explicit 'dense' scheduler installs the mode the program traces
-    under, gets its OWN cached program set (keyed by mode), and —
-    because dense is semantically identical — the same tokens."""
-    engine, cfg = _fresh_engine()
-    prompt = np.arange(7, dtype=np.int32) % cfg.vocab_size
-
-    def run(mode):
-        sched = ContinuousBatchingScheduler(
-            engine, ServingConfig(slots=2, kv_write=mode))
-        assert sched.kv_write == (mode or "scatter")
-        assert sched.kv_write_source == ("config" if mode else "default")
-        r = Request(prompt=prompt.copy(), max_new_tokens=5)
-        sched.submit(r)
-        sched.run_until_drained(max_ticks=50)
-        return r.output
-
-    assert run("dense") == run(None)  # semantically identical writes
-    # two modes on one engine = two program sets, never a shared trace
-    # (key layout: ..., kv_write, weight_dtype — kv_write is second-to-last)
-    assert {k[-2] for k in engine._serve_cache} == {"dense", "scatter"}
-
-
-# ---------------------------------------------------------------------------
-# speculation: lossless under greedy decoding, acceptance accounted
-# ---------------------------------------------------------------------------
 def _kd_drafter(engine, cfg, n_layer=1):
     """The in-tree drafter the ISSUE names: a layer-reduced KD student
     seeded from the target's own layers (compression/compress.py)."""

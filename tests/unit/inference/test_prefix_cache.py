@@ -449,7 +449,7 @@ def test_env_knob_and_default_resolution(engine_cfg, monkeypatch):
         assert not sched.pool.prefix_cache
         # env is the experiment-override layer: it beats even a committed
         # ServingConfig value (a forced env hits both A/B arms the same
-        # way — the kv_write/weight_dtype convention)
+        # way — the weight_dtype convention)
         sched = _mk_sched(engine, clock=SimClock(), prefix_cache="on")
         assert (sched.prefix_cache, sched.prefix_cache_source) == ("off",
                                                                    "env")

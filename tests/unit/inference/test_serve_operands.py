@@ -20,6 +20,7 @@ from deepspeed_tpu.inference.serving.programs import (INDEX_LEAVES, KV_LEAVES, _
                                                       build_decode_step,
                                                       build_prefill_step, make_apply_fn)
 from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+from deepspeed_tpu.models.common import slot_pool_rows
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 from deepspeed_tpu.utils import trace
 
@@ -106,6 +107,10 @@ def test_ticks_put_at_most_once_and_never_retrace(engine_cfg, spec_k):
         for name, fn in fns.items():
             dead = bool(spec_k) and fns is sched.fns and name == "decode"
             assert fn._cache_size() == (0 if dead else 1), (name, fn._cache_size())
+    # which takes a cache that comes back placed as the fresh one was, the
+    # int8 scale leaves (rank 3, as some weights are) included
+    for path, leaf in jax.tree_util.tree_flatten_with_path(sched._cache)[0]:
+        assert leaf.sharding == sched._placement, (jax.tree_util.keystr(path), leaf.sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -139,16 +144,17 @@ def test_rows_land_at_write_pos_and_parked_slots_write_nothing(engine_cfg, progr
     after = {k: np.asarray(v) for k, v in _kv_leaves(new_cache).items()}
     assert after.keys() == before.keys() and len(after) == (8 if kv_quant else 4)
     for key, old in before.items():
-        new = after[key]
         for slot, pos in enumerate(write_pos):
+            # position-major rows, whatever the stored form
+            new, was = (slot_pool_rows(leaf, slot, 0, parked) for leaf in (after[key], old))
             if pos == parked:
-                np.testing.assert_array_equal(new[slot], old[slot], err_msg=f"{key} slot {slot}")
+                np.testing.assert_array_equal(new, was, err_msg=f"{key} slot {slot}")
                 continue
-            written = np.zeros(new.shape[1], bool)
+            written = np.zeros(parked, bool)
             written[pos:pos + rows] = True
-            np.testing.assert_array_equal(new[slot][~written], old[slot][~written],
+            np.testing.assert_array_equal(new[~written], was[~written],
                                           err_msg=f"{key} slot {slot}")
-            changed = (new[slot][written] != old[slot][written]).reshape(rows, -1).any(axis=1)
+            changed = (new[written] != was[written]).reshape(rows, -1).any(axis=1)
             assert changed.all(), (key, slot, changed)
     # what the carried index leaves held never mattered: each comes back as
     # the operand advanced by the rows fed

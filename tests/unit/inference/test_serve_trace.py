@@ -141,6 +141,17 @@ def test_decode_counters_are_slots_fed_against_slots_computed(served):
     assert counters["decode_slots_computed"] == sched.ticks["decode"] * SLOTS
 
 
+def test_kv_write_counters_are_positions_handed_against_positions_rewritten(served):
+    sched, reqs, _, counters = served
+    # a prefill tick hands every fed slot a whole chunk (a short one is
+    # padded), a decode tick one token; off the TPU the write is a scatter,
+    # which rewrites just those (the in-place write's whole windows:
+    # test_kv_pool_write.py)
+    chunks = sum(-(-n // CHUNK) for n in LENGTHS)
+    assert counters["kv_positions_written"] == chunks * CHUNK + counters["decode_slots_fed"]
+    assert counters["kv_window_positions_touched"] == counters["kv_positions_written"]
+
+
 def test_a_new_program_set_is_counted_once(engine):
     rec = trace.recorder()
     start = rec.counters.get("serve_program_builds", 0)

@@ -77,6 +77,35 @@ def _kernel_text(compiled):
     return text
 
 
+def _relayouts(compiled, elements):
+    """Instructions that exist only to move or retype an array of at least
+    ``elements`` elements: ``copy`` / ``convert`` / ``transpose`` (and a
+    fusion XLA named after a copy) outside fusion bodies, where each is a
+    pass over HBM of its own."""
+    import re
+    found, fused = [], False
+    for line in compiled.as_text().splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(")[0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+        if fused or not m:
+            continue
+        name, dims, op = m.groups()
+        if (op in ("copy", "convert", "transpose") or (op == "fusion" and name.startswith("copy"))) \
+                and np.prod([int(d) for d in dims.split(",") if d] or [1]) >= elements:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _assert_pool_written_in_place(compiled, leaf_bytes, layers, logits_bytes):
+    """No pass over a whole pool leaf beside the aliased write and the read
+    attention makes, and temporaries under one pool leaf a layer (beside the
+    head's logits, which no layer owns)."""
+    assert not _relayouts(compiled, leaf_bytes)      # int8: a leaf's bytes are its elements
+    assert compiled.memory_analysis().temp_size_in_bytes < layers * leaf_bytes + logits_bytes
+
+
 def _sq_grads(fn):
     return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum(), argnums=(0, 1, 2))
 
@@ -132,6 +161,30 @@ def test_quant_matmul_compiles(one_chip, bits, m, k, n):
     _kernel_text(_compile(fn, one_chip, _shape(m, k),
                           _shape(k * bits // 8, n, dtype=jnp.int8),
                           _shape(k // 64, n, dtype=jnp.float32)))
+
+
+@pytest.mark.parametrize("length", [1, CHUNK, 5], ids=["decode_tick", "prefill_chunk",
+                                                       "verify_block"])
+@pytest.mark.parametrize("heads,head_dim,positions", [(H, D, L), (16, 128, 2048), (25, D, L)],
+                         ids=["gpt2_medium", "olmoe", "gpt2_xl"])
+def test_kv_append_compiles(one_chip, heads, head_dim, positions, length):
+    """The serving cache's write alone: 32 slots of int8 codes and bf16
+    scales, K and V in one loop, every pool donated: no pass over a pool
+    leaf and next to no temporary."""
+    from deepspeed_tpu.models.common import _append_in_place
+    slots = 32
+    pool, scale = _shape(slots, heads, head_dim, positions, dtype=jnp.int8), _shape(slots, heads, positions)
+    new, new_scale = _shape(slots, length, heads, head_dim, dtype=jnp.int8), _shape(slots, length, heads)
+
+    def fn(k, v, ks, vs, nk, nv, nks, nvs, pos):
+        return _append_in_place([k, v, ks, vs], [nk, nv, nks, nvs], pos)
+
+    compiled = _compile(fn, one_chip, pool, pool, scale, scale, new, new, new_scale, new_scale,
+                        _shape(slots, dtype=jnp.int32), donate_argnums=(0, 1, 2, 3))
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert not _relayouts(compiled, slots * heads * head_dim * positions)
+    # under one pool's 128-position windows (a leaf is `positions / 128` of them)
+    assert compiled.memory_analysis().temp_size_in_bytes < slots * heads * head_dim * 128
 
 
 @pytest.mark.parametrize("rows", [256, 16384], ids=["decode_tick", "prefill_tick"])
@@ -198,6 +251,39 @@ def test_serving_program_compiles(one_chip, program, attention):
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_gpt2_serving_program_writes_the_pool_in_place(one_chip, program):
+    """The chat cell's programs (32 slots x 1,024 positions, 16 heads of
+    64, int8 KV, bf16 weights, 16-token chunks) at two layers: a pool whose
+    head size is half a lane row is held positions-minor by the chip, and
+    a write that does not follow it costs four relayout copies of the
+    pool a layer (ISSUE 27)."""
+    import flax.linen as nn
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step,
+                                                          make_apply_fn, make_slot_cache)
+    from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+
+    slots, layers = 32, 2
+    module = GPT2LMHeadModel(get_gpt2_config("350m", n_layer=layers, dtype=bf16))
+    params = jax.eval_shape(
+        lambda key: jax.tree.map(lambda p: p.astype(bf16), nn.meta.unbox(
+            module.init(key, jnp.zeros((1, 8), jnp.int32))["params"])), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots, kv_quant=True))
+    apply_fn = make_apply_fn(module)
+    if program == "prefill":
+        step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, CHUNK, dtype=jnp.int32),
+                    _shape(slots, dtype=jnp.int32))
+        logits = slots * CHUNK * module.config.vocab_size * 2
+    else:
+        step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
+        logits = slots * module.config.vocab_size * 2
+    compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
+    _assert_pool_written_in_place(compiled, slots * L * H * D, layers, logits)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
 def test_olmoe_serving_program_compiles(one_chip, program):
     """One OLMoE layer at its published widths through the serving programs
     of the benchmark's cell: 32 slots of 2,048 positions over the int8
@@ -228,6 +314,8 @@ def test_olmoe_serving_program_compiles(one_chip, program):
     assert compiled.as_text().count("tpu_custom_call") == 3       # gate, up, down
     # a prefill tick's temporaries stay under a gigabyte: no [E, C, M] buffer
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+    logits = slots * (chunk if program == "prefill" else 1) * module.config.vocab_size * 2
+    _assert_pool_written_in_place(compiled, slots * 2048 * 16 * 128, 1, logits)
 
 
 def _train_engine(devices, zero_stage, fsdp):

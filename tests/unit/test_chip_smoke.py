@@ -42,6 +42,7 @@ def test_kernels_phase_rehearsal():
     obs = chip_smoke.kernels_phase(batch=2, seq=128, heads=4, head_dim=32, width=256)
     assert obs["compiled"] is False  # interpret mode off the chip
     assert obs["worst_bf16_roundings"]["moe_permute_fwd"] == 0.0
+    assert [obs["worst_bf16_roundings"][f"kv_append_{n}"] for n in (1, 16, 5)] == [0.0] * 3
     assert {"flash_bwd", "flash_decode", "quant_matmul_int4", "sparse_bwd"} <= set(
         obs["worst_bf16_roundings"])
 

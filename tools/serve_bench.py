@@ -209,7 +209,7 @@ def serve_evidence(engine, slots, wq="fp", kv_quant=False):
         from deepspeed_tpu import analysis
         from deepspeed_tpu.analysis.memory import estimate_memory
         from deepspeed_tpu.analysis.program import ProgramInfo
-        from deepspeed_tpu.inference.serving import make_slot_cache, resolve_kv_write
+        from deepspeed_tpu.inference.serving import make_slot_cache
         from deepspeed_tpu.inference.serving.programs import (build_decode_step,
                                                               make_apply_fn)
 
@@ -226,11 +226,9 @@ def serve_evidence(engine, slots, wq="fp", kv_quant=False):
         info = ProgramInfo(name="serve_decode", jaxpr=jaxpr, kind="serve_decode")
         findings, _ = analysis.run_program_rules(info)
         mem = estimate_memory(info)
-        mode, src = resolve_kv_write(None)
         return {"serve_lint": analysis.summarize(findings),
                 "serve_cost_peak_bytes": mem.peak_bytes,
                 "serve_cost_transient_bytes": mem.peak_transient_bytes,
-                "serve_kv_write": mode, "serve_kv_write_source": src,
                 "serve_weight_dtype": wq, "serve_kv_quant": kv_quant}
     except Exception as e:  # evidence must never kill a run
         return {"serve_evidence_error": f"{type(e).__name__}: {str(e)[:120]}"}
