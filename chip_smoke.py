@@ -204,7 +204,7 @@ def serve_phase(preset="350m", prompt=128, new=64, n_requests=8, slots=8, chunk=
                                           max_out_tokens=n_positions,
                                           topology=_topology(devices))
     sched = ContinuousBatchingScheduler(engine, ServingConfig(
-        slots=slots, page_size=16, kv_quant=True, weight_dtype=None,
+        slots=slots, page_size=16, kv_quant=True,
         prefill_chunk=chunk))
     with _compiles() as warm:
         t0 = time.time()

@@ -15,10 +15,11 @@ scenarios declare their banned ``(S, E, C)`` signature via
 ``train_batch`` declares ``parity``/``expect_donation``; multi-device
 scenarios declare ``multi_device``.
 
-Route/kernel resolution inside the MoE scenarios goes through
-``moe.routing.resolve_route`` (no explicit kwarg), so a forced
-``DS_MOE_ROUTE=dense`` env — the seeded-regression acceptance check —
-flows into the traced program exactly as it would into a bench run.
+What a scenario traces is decided by the model and serving configuration
+it builds, and by nothing else. :data:`SCENARIO_CONFIG` holds the values
+the declared signatures, budgets and committed baselines are written for; a
+test seeds a regression (the dense MoE route, an fp tick where int8 is
+banked) by patching an entry, and the declared side stays as committed.
 """
 
 from typing import Callable, Dict, List, Optional
@@ -31,6 +32,10 @@ import jax.numpy as jnp
 from deepspeed_tpu.analysis.program import ProgramInfo
 
 SCENARIOS: Dict[str, Callable[[], ProgramInfo]] = {}
+
+#: the route the MoE scenarios build their layers and models with, and the
+#: weight dtype ``serve_quant_decode_step`` serves
+SCENARIO_CONFIG = {"moe_route": "sorted", "serve_weight_dtype": "int8"}
 
 
 class ScenarioSkipped(Exception):
@@ -226,11 +231,9 @@ def _moe_program(name: str, k: int) -> ProgramInfo:
 
     B, L, M, E, cf, min_cap = 2, 16, 8, 4, 1.0, 1
     S = B * L  # one group without a topology
-    # no explicit route kwarg: resolution flows through env/config exactly
-    # like a bench run, so DS_MOE_ROUTE=dense seeds the R001 regression
     layer = MOELayer(expert=_Expert(), model_dim=M, num_experts=E, k=k,
                      capacity_factor=cf, eval_capacity_factor=cf,
-                     min_capacity=min_cap)
+                     min_capacity=min_cap, route=SCENARIO_CONFIG["moe_route"])
     x = jnp.zeros((B, L, M), jnp.float32)
     variables = layer.init(jax.random.PRNGKey(0), x)
 
@@ -244,10 +247,8 @@ def _moe_program(name: str, k: int) -> ProgramInfo:
         name=name, jaxpr=jaxpr, kind="fwd_bwd",
         lower=lambda: jax.jit(grad).lower(variables, x),
         metadata={"moe_sec": [sec_signature(S, E, cf, min_cap, k=k)],
-                  # the committed intent is the sorted route: zero dense
-                  # [S,E,C] einsums feeding the dispatch/combine endpoints.
-                  # DS_MOE_ROUTE=dense drifts the traced program but not
-                  # this signature — the R009 seeded regression.
+                  # committed for the sorted route: zero dense [S,E,C]
+                  # einsums feeding the dispatch/combine endpoints
                   "collective_signature": [
                       {"layer": "jaxpr", "kind": "dense_dispatch", "count": 0,
                        "note": "sorted MoE dispatch is a permutation, "
@@ -433,7 +434,8 @@ def moe_ep_step() -> ProgramInfo:
         raise ScenarioSkipped("moe_ep_step expects >=8 host devices")
     set_topology(None)
     try:
-        cfg = get_gpt2_config("test", moe_num_experts=4, moe_layer_freq=2, moe_k=1)
+        cfg = get_gpt2_config("test", moe_num_experts=4, moe_layer_freq=2, moe_k=1,
+                              moe_route=SCENARIO_CONFIG["moe_route"])
         engine, _, _, _ = deepspeed_tpu.initialize(
             model=GPT2LMHeadModel(cfg),
             topology=MeshTopology(expert=4, data=2, devices=jax.devices()[:8]),
@@ -443,6 +445,11 @@ def moe_ep_step() -> ProgramInfo:
         batch = {"input_ids": np.zeros((8, 32), np.int32)}
         return _engine_program("moe_ep_step", engine, batch, {
             "collective_signature": [
+                # committed for the sorted route, whatever route the engine
+                # was handed (the engine declares the same of a sorted model)
+                {"layer": "jaxpr", "kind": "dense_dispatch", "count": 0,
+                 "note": "sorted MoE dispatch is a permutation, never an "
+                         "[S,E,C] einsum"},
                 {"layer": "jaxpr", "kind": "resharding", "min_count": 4,
                  "note": "2 capacity-bounded a2a reshards per MoE layer "
                          "per direction (dispatch + combine, fwd + bwd)"},
@@ -605,9 +612,8 @@ def serve_decode_step() -> ProgramInfo:
 #: int8-weight program's transient is dominated by the int8 KV pools +
 #: bf16 dequant/attention temporaries; measured static transient on the
 #: pinned container: 2.63 MiB, committed at 2.9 MiB (~10% headroom).
-#: ``DS_SERVE_WQ=fp`` swings the program back to full-width fp kernels —
-#: peak bytes jump ~40% past the R013 tolerance, the seeded regression
-#: for a forced/leaked served weight dtype.
+#: Served fp, the program is back to full-width kernels: peak bytes jump
+#: ~40% past the R013 tolerance, the seeded regression.
 SERVE_QUANT_DECODE_BUDGET_MB = 2.9
 
 
@@ -622,18 +628,13 @@ def serve_quant_decode_step() -> ProgramInfo:
     path the dominant term, so the A/B against ``serve_decode_step``
     prices exactly what quantization buys per tick.
 
-    The served dtype resolves at the BUILDER (``resolve_weight_dtype``
-    over the scenario's installed config default), never inside the
-    module — so ``DS_SERVE_WQ`` drifts the traced program while
-    ``serve_weight_dtype`` metadata stays the committed intent
-    (``resolve_intended_weight_dtype``), and R013 fails the drift."""
+    The served dtype is :data:`SCENARIO_CONFIG`'s, handed to the builder
+    as the scheduler hands its ``ServingConfig.weight_dtype``; the banked
+    cost is int8's, so anything else fails R013."""
     import deepspeed_tpu
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
     from deepspeed_tpu.inference.engine import InferenceEngine
-    from deepspeed_tpu.inference.serving import (make_slot_cache,
-                                                 resolve_intended_weight_dtype,
-                                                 resolve_weight_dtype,
-                                                 set_default_weight_dtype)
+    from deepspeed_tpu.inference.serving import make_slot_cache
     from deepspeed_tpu.inference.serving.programs import (build_decode_step,
                                                           make_apply_fn)
     from deepspeed_tpu.inference.serving.scheduler import _quant_view
@@ -644,7 +645,6 @@ def serve_quant_decode_step() -> ProgramInfo:
         raise ScenarioSkipped("serve_quant_decode_step needs >=2 devices for "
                               "the tensor=2 serving mesh")
     set_topology(None)
-    set_default_weight_dtype("int8")  # the committed serving config
     try:
         slots = 8
         cfg = get_gpt2_config("test", n_layer=2, n_embd=128, n_head=8,
@@ -652,10 +652,7 @@ def serve_quant_decode_step() -> ProgramInfo:
         topo = MeshTopology(tensor=2, data=1, fsdp=1, devices=jax.devices()[:2])
         engine = InferenceEngine(GPT2LMHeadModel(cfg),
                                  DeepSpeedInferenceConfig(), topology=topo)
-        # builder-level resolution, exactly the scheduler's seam: env
-        # outranks the installed config default, so a forced DS_SERVE_WQ
-        # changes WHAT GETS BUILT here while the metadata below does not
-        wd, _src = resolve_weight_dtype(None)
+        wd = SCENARIO_CONFIG["serve_weight_dtype"]
         module, params = engine.module, engine.params
         if wd != "fp":
             module, params = _quant_view(module, params, wd, 64)
@@ -670,8 +667,7 @@ def serve_quant_decode_step() -> ProgramInfo:
             lower=lambda: jax.jit(decode).lower(params, cache, write_pos, tokens),
             metadata={
                 "serve_slots": slots,
-                # committed intent, env layer skipped — the drift anchor
-                "serve_weight_dtype": resolve_intended_weight_dtype(None),
+                "serve_weight_dtype": "int8",  # what the baseline banks
                 "serve_kv_quant": True,
                 "activation_budget_bytes": int(SERVE_QUANT_DECODE_BUDGET_MB * 2**20),
                 "collective_signature": [
@@ -687,15 +683,14 @@ def serve_quant_decode_step() -> ProgramInfo:
                              "more would mean GSPMD re-gathers the int8 "
                              "codes or the KV pool per tick"}]})
     finally:
-        set_default_weight_dtype(None)
         set_topology(None)
 
 
 @scenario("serve_prefix_decode_step")
 def serve_prefix_decode_step() -> ProgramInfo:
     """graft-prefix-cache's decode tick: the SAME program as
-    :func:`serve_decode_step` built with the prefix cache installed as
-    the committed serving default. The cache is a HOST-SIDE allocator
+    :func:`serve_decode_step`, as a scheduler with ``prefix_cache="on"``
+    builds it. The cache is a HOST-SIDE allocator
     change — ref-counted content-addressed blocks, restore/publish
     through host row copies — so the compiled decode program must be
     BYTE-IDENTICAL to the uncached one: same budget, same tp=2
@@ -705,9 +700,7 @@ def serve_prefix_decode_step() -> ProgramInfo:
     import deepspeed_tpu
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
     from deepspeed_tpu.inference.engine import InferenceEngine
-    from deepspeed_tpu.inference.serving import (make_slot_cache,
-                                                 resolve_intended_prefix_cache,
-                                                 set_default_prefix_cache)
+    from deepspeed_tpu.inference.serving import make_slot_cache
     from deepspeed_tpu.inference.serving.programs import (build_decode_step,
                                                           make_apply_fn)
     from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
@@ -717,7 +710,6 @@ def serve_prefix_decode_step() -> ProgramInfo:
         raise ScenarioSkipped("serve_prefix_decode_step needs >=2 devices "
                               "for the tensor=2 serving mesh")
     set_topology(None)
-    set_default_prefix_cache("on")  # the committed serving config
     try:
         slots = 16
         cfg = get_gpt2_config("test", n_layer=2, n_positions=512)
@@ -735,8 +727,7 @@ def serve_prefix_decode_step() -> ProgramInfo:
             lower=lambda: jax.jit(decode).lower(engine.params, cache, write_pos, tokens),
             metadata={
                 "serve_slots": slots,
-                # committed intent, env layer skipped — the drift anchors
-                "serve_prefix_cache": resolve_intended_prefix_cache(None),
+                "serve_prefix_cache": "on",
                 # same budget as serve_decode_step ON PURPOSE: prefix
                 # caching must not move the decode tick's transient a byte
                 "activation_budget_bytes": int(SERVE_DECODE_BUDGET_MB * 2**20),
@@ -750,7 +741,6 @@ def serve_prefix_decode_step() -> ProgramInfo:
                              "more would mean prefix caching leaked into "
                              "the compiled program"}]})
     finally:
-        set_default_prefix_cache(None)
         set_topology(None)
 
 

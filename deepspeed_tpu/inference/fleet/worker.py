@@ -21,8 +21,7 @@ Env (set by :class:`SubprocessReplica` / the fleet bench):
   FLEET_POSITIONS=128     context length
   FLEET_KV_QUANT=1        int8 KV pools
   FLEET_PREFIX_CACHE=     on|off: content-hashed KV prefix caching
-                          (unset = the DS_SERVE_PREFIX_CACHE/config
-                          resolution, default on)
+                          (unset = on)
   FLEET_POOL_TOKENS=0     KV pool token budget (0 = slots x context);
                           the serve_prefix_fleet_* rungs size this
                           ABOVE slots x context so the pool has spare
@@ -72,7 +71,7 @@ def build_scheduler():
         prefill_chunk=int(os.environ.get("FLEET_CHUNK", "16")),
         kv_quant=os.environ.get("FLEET_KV_QUANT", "1") == "1",
         kv_pool_tokens=int(os.environ.get("FLEET_POOL_TOKENS", "0")) or None,
-        prefix_cache=os.environ.get("FLEET_PREFIX_CACHE") or None)
+        prefix_cache=os.environ.get("FLEET_PREFIX_CACHE") or "on")
     sched = ContinuousBatchingScheduler(engine, scfg, telemetry=telemetry)
     if telemetry is not None:
         # the run header carries the serving program's static price +

@@ -6,16 +6,8 @@ from deepspeed_tpu.inference.serving.events import (SERVE_EVENT_SCHEMAS,
                                                     iter_serve_events,
                                                     last_tick_signals,
                                                     validate_event)
-from deepspeed_tpu.inference.serving.config import (ENV_PREFIX_CACHE,
-                                                    ENV_WEIGHT_DTYPE,
-                                                    ServingConfig,
-                                                    SpeculationConfig,
-                                                    resolve_intended_prefix_cache,
-                                                    resolve_intended_weight_dtype,
-                                                    resolve_prefix_cache,
-                                                    resolve_weight_dtype,
-                                                    set_default_prefix_cache,
-                                                    set_default_weight_dtype)
+from deepspeed_tpu.inference.serving.config import (ServingConfig,
+                                                    SpeculationConfig)
 from deepspeed_tpu.inference.serving.programs import (make_slot_cache,
                                                       serve_programs,
                                                       slot_capacity)
@@ -29,16 +21,10 @@ from deepspeed_tpu.inference.serving.scheduler import (MIGRATABLE_STATES,
 __all__ = [
     "ACTIVE", "FINISHED", "PREFILL", "QUEUED", "REFUSED",
     "BlockPool", "ContinuousBatchingScheduler",
-    "ENV_PREFIX_CACHE", "ENV_WEIGHT_DTYPE", "MIGRATABLE_STATES",
+    "MIGRATABLE_STATES",
     "MigrationError", "Request",
     "RequestQueue", "SERVE_EVENT_SCHEMAS", "ServingConfig",
     "SpeculationConfig", "iter_serve_events", "last_tick_signals",
-    "make_slot_cache",
-    "resolve_intended_prefix_cache",
-    "resolve_intended_weight_dtype",
-    "resolve_prefix_cache", "resolve_weight_dtype",
-    "serve_programs",
-    "set_default_prefix_cache",
-    "set_default_weight_dtype", "slot_capacity",
+    "make_slot_cache", "serve_programs", "slot_capacity",
     "validate_event",
 ]

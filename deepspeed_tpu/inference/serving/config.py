@@ -1,128 +1,17 @@
 """graft-serve configuration: the ``"serving"`` config block.
 
 Continuous in-flight batching (ISSUE 14 / ROADMAP item 1) is driven by a
-small set of knobs with the same layered resolution discipline as the MoE
-route and the attention geometry: explicit > env > config > default, with
-the env layer (``DS_SERVE_WQ``, ``DS_SERVE_PREFIX_CACHE``) existing so the
-graft-audit serving scenarios can catch a forced/leaked serving knob the
-exact way ``DS_MOE_ROUTE=dense`` is caught — the traced program drifts,
-the committed budget/signature does not, lint exits 1.
+small set of knobs. The scheduler that holds a ``ServingConfig`` reads it
+and hands what shapes a program down as module configuration
+(``serve_weight_dtype``) or constructor arguments: nothing outside the
+configuration decides what a scheduler serves.
 """
 
-import os
-import threading
-from typing import Optional, Tuple
+from typing import Literal, Optional
 
 from pydantic import Field, model_validator
 
 from deepspeed_tpu.runtime.config_utils import DeepSpeedConfigModel
-
-#: env override for the served weight dtype (graft-quant-serve); same
-#: drift seam: a forced/leaked value changes the traced decode program,
-#: the serve_quant_decode_step budget stays priced for the intent
-ENV_WEIGHT_DTYPE = "DS_SERVE_WQ"
-
-WEIGHT_DTYPE_CHOICES = ("fp", "int8", "int4")
-DEFAULT_WEIGHT_DTYPE = "fp"
-
-#: env override for content-hashed KV prefix caching (graft-prefix-cache);
-#: the same drift seam — forcing it off under the env changes admission
-#: depth and prefill skip behaviour while the committed intent (and the
-#: serve_prefix_decode_step budget priced for it) stays put
-ENV_PREFIX_CACHE = "DS_SERVE_PREFIX_CACHE"
-
-PREFIX_CACHE_CHOICES = ("on", "off")
-DEFAULT_PREFIX_CACHE = "on"
-
-_lock = threading.Lock()
-_config_weight_dtype: Optional[str] = None
-_config_prefix_cache: Optional[str] = None
-
-
-def _check(value: Optional[str], choices, what: str) -> Optional[str]:
-    if value is not None and value not in choices:
-        raise ValueError(f"unknown {what} {value!r}; choices: {list(choices)}")
-    return value
-
-
-def set_default_weight_dtype(mode: Optional[str]) -> None:
-    """Install the scheduler-level served weight dtype (None clears)."""
-    global _config_weight_dtype
-    with _lock:
-        _config_weight_dtype = _check(mode, WEIGHT_DTYPE_CHOICES, "weight_dtype")
-
-
-def resolve_weight_dtype(mode: Optional[str] = None) -> Tuple[str, str]:
-    """Resolve ``(mode, source)`` for the served weight dtype.
-
-    ``fp`` (default) serves the param tree as stored; ``int8``/``int4``
-    serve per-group quantized codes with dequant fused into the GEMM
-    (``ops/pallas/quant_matmul.py``). ``source`` names the deciding layer
-    (``explicit`` > ``env`` > ``config`` > ``default``), the same evidence
-    convention as the MoE route's."""
-    src, m = "default", DEFAULT_WEIGHT_DTYPE
-    if _config_weight_dtype is not None:
-        m, src = _config_weight_dtype, "config"
-    env = os.environ.get(ENV_WEIGHT_DTYPE, "").strip() or None
-    if env is not None:
-        m, src = _check(env, WEIGHT_DTYPE_CHOICES,
-                        f"weight_dtype (from {ENV_WEIGHT_DTYPE})"), "env"
-    if mode is not None:
-        m, src = _check(mode, WEIGHT_DTYPE_CHOICES, "weight_dtype"), "explicit"
-    return m, src
-
-
-def resolve_intended_weight_dtype(mode: Optional[str] = None) -> str:
-    """The weight dtype the *committed configuration* intends, skipping
-    the env layer — what ``serve_quant_decode_step`` prices its budget
-    and collective signature for (mirror of
-    ``moe.routing.resolve_intended_route``)."""
-    if mode is not None:
-        return _check(mode, WEIGHT_DTYPE_CHOICES, "weight_dtype")
-    if _config_weight_dtype is not None:
-        return _config_weight_dtype
-    return DEFAULT_WEIGHT_DTYPE
-
-
-def set_default_prefix_cache(mode: Optional[str]) -> None:
-    """Install the scheduler-level prefix-cache default (None clears)."""
-    global _config_prefix_cache
-    with _lock:
-        _config_prefix_cache = _check(mode, PREFIX_CACHE_CHOICES, "prefix_cache")
-
-
-def resolve_prefix_cache(mode: Optional[str] = None) -> Tuple[str, str]:
-    """Resolve ``(mode, source)`` for content-hashed KV prefix caching.
-
-    ``on`` (default) ref-counts and content-addresses the BlockPool:
-    committed full blocks index under a rolling hash, freed blocks with a
-    live hash park on a cached-free LRU, and new prompts prefill only
-    their uncached tail. ``off`` restores the private-blocks pool (parity
-    debugging / the A/B control arm). ``source`` names the deciding layer
-    (``explicit`` > ``env`` > ``config`` > ``default``), the same
-    evidence convention as :func:`resolve_weight_dtype`."""
-    src, m = "default", DEFAULT_PREFIX_CACHE
-    if _config_prefix_cache is not None:
-        m, src = _config_prefix_cache, "config"
-    env = os.environ.get(ENV_PREFIX_CACHE, "").strip() or None
-    if env is not None:
-        m, src = _check(env, PREFIX_CACHE_CHOICES,
-                        f"prefix_cache (from {ENV_PREFIX_CACHE})"), "env"
-    if mode is not None:
-        m, src = _check(mode, PREFIX_CACHE_CHOICES, "prefix_cache"), "explicit"
-    return m, src
-
-
-def resolve_intended_prefix_cache(mode: Optional[str] = None) -> str:
-    """The prefix-cache mode the *committed configuration* intends,
-    skipping the env layer — what ``serve_prefix_decode_step`` stamps in
-    its metadata so a forced/leaked ``DS_SERVE_PREFIX_CACHE`` drifts the
-    traced evidence away from the committed intent (R013 catches it)."""
-    if mode is not None:
-        return _check(mode, PREFIX_CACHE_CHOICES, "prefix_cache")
-    if _config_prefix_cache is not None:
-        return _config_prefix_cache
-    return DEFAULT_PREFIX_CACHE
 
 
 class SpeculationConfig(DeepSpeedConfigModel):
@@ -163,17 +52,20 @@ class ServingConfig(DeepSpeedConfigModel):
     prefill_interleave: int = Field(1, ge=0)
     #: queued requests beyond this are refused on submit
     max_queue: int = Field(1024, ge=1)
-    #: served weight dtype (graft-quant-serve); resolution via
-    #: :func:`resolve_weight_dtype`. ``int8``/``int4`` quantize the served
-    #: param tree per group (weights only; embeddings/norms stay fp) and
-    #: fuse dequant into the GEMM
-    weight_dtype: Optional[str] = None
+    #: served weight dtype (graft-quant-serve): ``fp`` serves the param
+    #: tree as stored; ``int8``/``int4`` quantize it per group (weights
+    #: only; embeddings/norms stay fp) and fuse dequant into the GEMM
+    #: (the choices of ``ops/quantizer/weights.py``)
+    weight_dtype: Literal["fp", "int8", "int4"] = "fp"
     #: target rows per quantization group along the contraction axis
     weight_group_size: int = Field(64, ge=1)
-    #: content-hashed KV prefix caching (graft-prefix-cache); resolution
-    #: via :func:`resolve_prefix_cache` (default ``on``). ``off`` is the
-    #: A/B control arm: private blocks, no hash index, full prefill
-    prefix_cache: Optional[str] = None
+    #: content-hashed KV prefix caching (graft-prefix-cache). ``on``
+    #: ref-counts and content-addresses the BlockPool: committed full
+    #: blocks index under a rolling hash, freed blocks with a live hash
+    #: park on a cached-free LRU, and new prompts prefill only their
+    #: uncached tail. ``off`` is the A/B control arm: private blocks, no
+    #: hash index, full prefill
+    prefix_cache: Literal["on", "off"] = "on"
     #: int8 KV pools for the per-slot serving cache (the serving default:
     #: codes + per-(slot, position, head) scales, quantize-on-write /
     #: dequantize-on-read). False keeps fp KV for parity debugging
@@ -197,8 +89,6 @@ class ServingConfig(DeepSpeedConfigModel):
 
     @model_validator(mode="after")
     def _validate(self):
-        _check(self.weight_dtype, WEIGHT_DTYPE_CHOICES, "weight_dtype")
-        _check(self.prefix_cache, PREFIX_CACHE_CHOICES, "prefix_cache")
         if self.speculation.enabled and self.do_sample:
             raise ValueError("speculative decoding is only lossless under greedy "
                              "decoding; set do_sample=False or disable speculation")

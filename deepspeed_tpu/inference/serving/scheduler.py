@@ -40,11 +40,7 @@ import numpy as np
 import jax
 
 from deepspeed_tpu.inference.serving.blocks import BlockPool
-from deepspeed_tpu.inference.serving.config import (ServingConfig,
-                                                    resolve_prefix_cache,
-                                                    resolve_weight_dtype,
-                                                    set_default_prefix_cache,
-                                                    set_default_weight_dtype)
+from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.programs import (KV_LEAVES, _leaf_name,
                                                       make_slot_cache, serve_programs,
                                                       slot_capacity)
@@ -77,11 +73,10 @@ MIGRATABLE_STATES = (PREFILL, ACTIVE)
 def _quant_view(module, params, weight_dtype: str, group_size: int):
     """graft-quant-serve: the (quant module, params bundle) pair a
     quantized serving path closes over. The module is rebuilt with
-    ``serve_weight_dtype`` set EXPLICITLY — projections must statically
-    declare the code layout the param tree actually carries (int4 halves
-    the contraction axis), so env resolution never reaches the module;
-    the ``DS_SERVE_WQ`` seam acts here, at the builder. Refuses model
-    families without the seam rather than silently serving fp."""
+    ``serve_weight_dtype`` set — projections must statically declare the
+    code layout the param tree actually carries (int4 halves the
+    contraction axis). Refuses model families without the seam rather
+    than silently serving fp."""
     import dataclasses
 
     from deepspeed_tpu.ops.quantizer.weights import quantize_params
@@ -154,12 +149,10 @@ class ContinuousBatchingScheduler:
         # (``moe_rows``); a dense model has no such counters
         self._moe_rows = getattr(engine.module, "moe_rows", None)
 
-        # graft-quant-serve: resolve the served weight dtype (env outranks
-        # config — the DS_SERVE_WQ drift seam)
-        # and, when quantized, swap in the quant module + code/scale bundle
-        # every program below closes over. The engine's own params stay fp.
-        set_default_weight_dtype(config.weight_dtype)
-        self.weight_dtype, self.weight_dtype_source = resolve_weight_dtype(None)
+        # graft-quant-serve: when the configuration serves quantized, swap
+        # in the quant module + code/scale bundle every program below
+        # closes over. The engine's own params stay fp.
+        self.weight_dtype = config.weight_dtype
         self.kv_quant = bool(config.kv_quant)
         self._serve_params = engine.params
         if self.weight_dtype != "fp":
@@ -196,14 +189,12 @@ class ContinuousBatchingScheduler:
             pool_tokens = max(config.page_size,
                               int(config.kv_pool_bytes /
                                   max(1.0, self._kv_bytes_per_token())))
-        # graft-prefix-cache: content-address the pool (resolve-intent
-        # layering, DS_SERVE_PREFIX_CACHE drift seam). The hash envelope
+        # graft-prefix-cache: content-address the pool. The hash envelope
         # folds in every knob that makes cached KV bytes non-reusable —
         # kv_quant changes the stored codes/scales, the served weight
         # dtype changes the values prefill computes, speculation adds a
         # drafter cache role the payload must also carry.
-        set_default_prefix_cache(config.prefix_cache)
-        self.prefix_cache, self.prefix_cache_source = resolve_prefix_cache(None)
+        self.prefix_cache = config.prefix_cache
         self.spec_k = int(config.speculation.k) if config.speculation.enabled else 0
         envelope = (f"kvq:{int(self.kv_quant)}/wq:{self.weight_dtype}"
                     f"/spec:{self.spec_k}")
@@ -280,10 +271,9 @@ class ContinuousBatchingScheduler:
         self.last_weight_sync: Optional[dict] = None
         log_dist(f"graft-serve: slots={self.slots} capacity={self.capacity} "
                  f"pool={self.pool.num_blocks}x{self.pool.block_size} "
-                 f"chunk={config.prefill_chunk} wq={self.weight_dtype}"
-                 f"({self.weight_dtype_source}) kv_quant={self.kv_quant} "
-                 f"spec_k={self.spec_k} prefix_cache={self.prefix_cache}"
-                 f"({self.prefix_cache_source})")
+                 f"chunk={config.prefill_chunk} wq={self.weight_dtype} "
+                 f"kv_quant={self.kv_quant} "
+                 f"spec_k={self.spec_k} prefix_cache={self.prefix_cache}")
 
     # ------------------------------------------------------------------
     def _probe_slot_decode(self) -> None:
@@ -356,7 +346,6 @@ class ContinuousBatchingScheduler:
         rare-path programs (the drafter's refeed verify only runs when
         some slot accepts all k drafts). Touches no request accounting,
         no histograms, and not the sampling rng stream."""
-        set_default_weight_dtype(self.config.weight_dtype)
         parked = np.full(self.slots, self.capacity, np.int32)
         rng = ((jax.random.PRNGKey(0),) if self.config.do_sample else ())
         # host arrays, as every tick hands them over: what a jitted call is
@@ -519,9 +508,6 @@ class ContinuousBatchingScheduler:
         """One scheduler tick; returns the tick kind it ran
         (``prefill`` | ``decode`` | ``spec`` | ``idle``)."""
         step_no = sum(self.ticks.values()) + 1
-        # lazily-traced programs must bind THIS scheduler's weight dtype even
-        # if another scheduler re-installed the default since construction
-        set_default_weight_dtype(self.config.weight_dtype)
         if self.telemetry is not None:
             self.telemetry.begin_step(step_no)
         self._tick_no = step_no
@@ -1236,10 +1222,8 @@ class ContinuousBatchingScheduler:
             "ticks": dict(self.ticks),
             "pool": pool,
             "weight_dtype": self.weight_dtype,
-            "weight_dtype_source": self.weight_dtype_source,
             "kv_quant": self.kv_quant,
             "prefix_cache": self.prefix_cache,
-            "prefix_cache_source": self.prefix_cache_source,
             "cached_prefix_tokens": sum(r.cached_prefix_tokens for r in done),
             "ttft": self.ttft_hist.snapshot() if self.ttft_hist.count else None,
             "per_token": self.tok_hist.snapshot() if self.tok_hist.count else None,

@@ -87,17 +87,16 @@ class GPT2Config:
     moe_use_residual: bool = False
     moe_drop_tokens: bool = True
     moe_use_rts: bool = True
-    # dispatch/combine route pin ("dense"|"sorted"); None resolves through
-    # DS_MOE_ROUTE env > engine "moe" config block > default (moe/routing.py)
-    moe_route: Optional[str] = None
+    # dispatch/combine route ("dense"|"sorted") and the sorted route's
+    # permutation kernel ("auto"|"xla"|"pallas"); the engine's "moe" config
+    # block lands here
+    moe_route: str = "sorted"
+    moe_route_kernel: str = "auto"
     # graft-quant-serve: served weight dtype this module instance was BUILT
     # for ("int8"|"int4"). None (training, lockstep generate) keeps the fp
-    # projections. Set explicitly by the serving scheduler / scenarios —
-    # never resolved from env here, because the param tree's code layout
-    # must match what the projections statically declare (int4 halves the
-    # contraction axis); the DS_SERVE_WQ env seam lives at the builder
-    # (serving/scheduler.py, analysis/scenarios.py), where drift changes
-    # which program gets traced and the cost gate catches it
+    # projections. Set by the serving scheduler from its ServingConfig: the
+    # param tree's code layout must match what the projections statically
+    # declare (int4 halves the contraction axis)
     serve_weight_dtype: Optional[str] = None
 
     @property
@@ -134,13 +133,13 @@ def _serve_quant_mode(module, cfg) -> str:
     scales ride along in the ``"quant"`` collection — leaves the skip list
     (``ops/quantizer/weights.py``) keeps fp stay fp automatically."""
     swd = getattr(cfg, "serve_weight_dtype", None)
-    if swd is None:
+    if swd in (None, "fp"):
         return "fp"
-    from deepspeed_tpu.inference.serving.config import resolve_weight_dtype
-    mode, _ = resolve_weight_dtype(swd)  # explicit layer; validates choice
-    if mode == "fp" or not module.has_variable("quant", "kernel_scale"):
+    from deepspeed_tpu.ops.quantizer.weights import quant_bits
+    quant_bits(swd)  # validates the choice
+    if not module.has_variable("quant", "kernel_scale"):
         return "fp"
-    return mode
+    return swd
 
 
 class QKVProj(nn.Module):
@@ -376,6 +375,7 @@ class Block(nn.Module):
                                     drop_tokens=cfg.moe_drop_tokens,
                                     use_rts=cfg.moe_use_rts,
                                     route=cfg.moe_route,
+                                    route_kernel=cfg.moe_route_kernel,
                                     name="moe")(h, deterministic=deterministic)
             gated_moe, b = self._pld_gate(moe_out, keep)
             x = x + gated_moe

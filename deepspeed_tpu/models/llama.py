@@ -86,9 +86,11 @@ class LlamaConfig:
     moe_eval_capacity_factor: float = 2.0  # serving must not under-provision vs training
     moe_min_capacity: int = 4
     moe_aux_loss_coef: float = 0.01
-    # dispatch/combine route pin ("dense"|"sorted"); None resolves through
-    # DS_MOE_ROUTE env > engine "moe" config block > default (moe/routing.py)
-    moe_route: Optional[str] = None
+    # dispatch/combine route ("dense"|"sorted") and the sorted route's
+    # permutation kernel ("auto"|"xla"|"pallas"); the engine's "moe" config
+    # block lands here
+    moe_route: str = "sorted"
+    moe_route_kernel: str = "auto"
 
     @property
     def head_dim(self):
@@ -336,6 +338,7 @@ class LlamaDecoderLayer(nn.Module):
                                     drop_tokens=cfg.moe_drop_tokens,
                                     norm_topk_prob=cfg.moe_norm_topk_prob,
                                     route=cfg.moe_route,
+                                    route_kernel=cfg.moe_route_kernel,
                                     name="moe")(h, deterministic=deterministic)
             return x + moe_out, l_aux
         return x + LlamaMLP(cfg, name="mlp")(h), jnp.zeros([], jnp.float32)

@@ -1,7 +1,6 @@
 """Mixture-of-experts / expert parallelism (reference ``deepspeed/moe/``)."""
 
 from deepspeed_tpu.moe.layer import MoE
-from deepspeed_tpu.moe.routing import resolve_route, set_default_route
 from deepspeed_tpu.moe.sharded_moe import (Experts, MOELayer, SortedRouting, TopKGate,
                                            top1gating, top1routing, top2gating, top2routing,
                                            topkgating, topkrouting)
@@ -11,6 +10,6 @@ from deepspeed_tpu.moe.utils import (has_moe_layers, is_moe_param, split_params_
 __all__ = [
     "MoE", "MOELayer", "TopKGate", "Experts", "SortedRouting",
     "top1gating", "top2gating", "top1routing", "top2routing", "topkgating", "topkrouting",
-    "resolve_route", "set_default_route", "drop_tokens", "gather_tokens",
+    "drop_tokens", "gather_tokens",
     "has_moe_layers", "is_moe_param", "split_params_into_different_moe_groups_for_optimizer"
 ]

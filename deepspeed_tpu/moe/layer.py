@@ -41,11 +41,10 @@ class MoE(nn.Module):
     noisy_gate_policy: Optional[str] = None
     drop_tokens: bool = True
     use_rts: bool = True
-    # dispatch/combine route pin ("dense"|"sorted") + permutation kernel
-    # ("auto"|"xla"|"pallas"); None resolves through DS_MOE_ROUTE env, the
-    # engine's "moe" config block, then the default (moe/routing.py)
-    route: Optional[str] = None
-    route_kernel: Optional[str] = None
+    # dispatch/combine route ("dense"|"sorted") + the sorted route's
+    # permutation kernel ("auto"|"xla"|"pallas"), as MOELayer takes them
+    route: str = "sorted"
+    route_kernel: str = "auto"
     # k >= 2: renormalise the k chosen experts' weights (Mixtral, the
     # reference top-2) or keep the softmax values (OLMoE)
     norm_topk_prob: bool = True

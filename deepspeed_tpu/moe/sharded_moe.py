@@ -32,9 +32,9 @@ Key departures from the reference, all forced by XLA's compilation model
   ``[E*C, M]`` dispatch buffer by row permutation, and combines by gather
   + k-way weighted sum. Both routes share the gating DECISION core
   (:func:`_top1_decisions` / :func:`_top2_decisions`), so routing choices,
-  RTS drops, and rng streams are identical bit-for-bit; route selection
-  is layered (``moe/routing.py``: kwargs > ``DS_MOE_ROUTE`` > ``"moe"``
-  config block > default).
+  RTS drops, and rng streams are identical bit-for-bit. Which route a
+  layer traces is its own ``route`` field (the model configuration's
+  ``moe_route``, where the engine's ``"moe"`` block lands).
 """
 
 import math
@@ -45,7 +45,6 @@ import jax.numpy as jnp
 
 import flax.linen as nn
 
-from deepspeed_tpu.moe.routing import resolve_route
 from deepspeed_tpu.parallel.topology import (BATCH_AXES, DATA_AXIS, EXPERT_AXIS, FSDP_AXIS,
                                              get_topology)
 
@@ -532,6 +531,8 @@ class Experts(nn.Module):
         return jnp.moveaxis(out, 0, 1)
 
 
+ROUTE_CHOICES = ("dense", "sorted")
+
 _warned_sorted = set()
 
 
@@ -568,9 +569,9 @@ class MOELayer(nn.Module):
     (O(S*E*C*M) FLOPs/bytes fwd+bwd); ``sorted``: row permutation of the
     <= k*S dispatched tokens (O(k*S*M) moved, zero mask FLOPs).
 
-    ``route``/``route_kernel`` are explicit overrides; ``None`` resolves
-    through ``DS_MOE_ROUTE``/``DS_MOE_KERNEL`` env, the engine's ``"moe"``
-    config block, then the ``"sorted"`` default (``moe/routing.py``).
+    ``route_kernel`` is the sorted route's permutation: ``"xla"`` (gather,
+    runs everywhere), ``"pallas"`` (``ops/pallas/moe_dispatch.py``) or
+    ``"auto"`` (pallas on a TPU, xla elsewhere).
     """
 
     expert: nn.Module
@@ -583,8 +584,8 @@ class MOELayer(nn.Module):
     noisy_gate_policy: Optional[str] = None
     drop_tokens: bool = True
     use_rts: bool = True
-    route: Optional[str] = None
-    route_kernel: Optional[str] = None
+    route: str = "sorted"
+    route_kernel: str = "auto"
     norm_topk_prob: bool = True
 
     @nn.compact
@@ -593,7 +594,9 @@ class MOELayer(nn.Module):
         orig_dtype = hidden_states.dtype
         d_model = orig_shape[-1]
         batch = orig_shape[0]
-        route, kernel, _ = resolve_route(self.route, self.route_kernel)
+        route, kernel = self.route, self.route_kernel
+        if route not in ROUTE_CHOICES:
+            raise ValueError(f"moe route must be one of {ROUTE_CHOICES}, got {route!r}")
 
         groups = _num_groups(batch)
         tokens = hidden_states.reshape(groups, -1, d_model)  # [G, S, M]

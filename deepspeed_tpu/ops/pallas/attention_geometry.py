@@ -8,20 +8,18 @@ log-sum-exp or reads it from a stashed residual. Which combination is
 fastest depends on the call shape (FlashAttention-2: the partitioning, not
 the algorithm, is where the last 1.5-2x lives), so resolution is layered:
 
-1. explicit per-call kwargs (``block_q=...`` etc.) — tests, power users;
-2. ``DS_ATTN_BLOCKS`` env override — force a geometry for a bench run
-   without touching config (same spec grammar as the config field);
-3. the engine's ``"attention"`` JSON config block
-   (:func:`set_default_geometry`, applied by ``runtime/engine.py``);
-4. a shape-keyed winners cache written by the kernel autotuner
+1. explicit per-call kwargs (``block_q=...`` etc.) and the model
+   configuration's ``attention_blocks`` spec, which is where the engine's
+   ``"attention"`` JSON config block lands (``runtime/engine.py``);
+2. a shape-keyed winners cache written by the kernel autotuner
    (``autotuning/attention_tuner.py``; default
    ``autotuning_results/attention_blocks.json``);
-5. shape-keyed static defaults for TPU v5e (:func:`default_geometry`).
+3. shape-keyed static defaults for TPU v5e (:func:`default_geometry`).
 
 This module is import-light on purpose (no jax/pallas): the engine and the
 bench tools consult it without paying for a Pallas import.
 
-Spec grammar (env var, config strings, cache entries all share it):
+Spec grammar (config strings and cache entries share it):
 ``"block_q=512,block_k=1024,block_q_bwd=256,block_k_bwd=512,``
 ``bwd_skip=block,policy=lse"`` — any subset of fields; a bare pair of ints
 ``"512,1024"`` means forward ``block_q,block_k``.
@@ -32,9 +30,6 @@ import json
 import os
 import threading
 from typing import Any, Dict, Optional, Tuple
-
-ENV_BLOCKS = "DS_ATTN_BLOCKS"
-ENV_CACHE = "DS_ATTN_CACHE"
 
 #: causal work-skipping granularity in the backward pass: "block" gates
 #: each grid step's FLOPs/DMA behind a liveness predicate (skips the dead
@@ -182,13 +177,12 @@ _DEFAULT_CACHE = os.path.join("autotuning_results", CACHE_BASENAME)
 _lock = threading.Lock()
 _cache_path_override: Optional[str] = None
 _cache_memo: Optional[Tuple[str, float, Dict[str, Any]]] = None  # (path, mtime, data)
-_config_default: Optional[AttentionGeometry] = None
 
 
 def cache_path() -> str:
     if _cache_path_override is not None:
         return _cache_path_override
-    return os.environ.get(ENV_CACHE) or _DEFAULT_CACHE
+    return _DEFAULT_CACHE
 
 
 def set_cache_path(path: Optional[str]) -> None:
@@ -258,36 +252,6 @@ def lookup_cached(sig: str, path: Optional[str] = None) -> Optional[AttentionGeo
 
 
 # ---------------------------------------------------------------------------
-# process-wide config default (set by runtime/engine.py from the JSON config)
-# ---------------------------------------------------------------------------
-def set_default_geometry(geom) -> None:
-    """Install the engine-level default geometry. Accepts an
-    AttentionGeometry, a spec string, a dict, or None (clear)."""
-    global _config_default
-    if geom is None:
-        _config_default = None
-    elif isinstance(geom, AttentionGeometry):
-        _config_default = geom.validate()
-    elif isinstance(geom, str):
-        _config_default = parse_spec(geom)
-    elif isinstance(geom, dict):
-        _config_default = from_dict(geom)
-    else:
-        raise TypeError(f"set_default_geometry: unsupported type {type(geom)!r}")
-
-
-def get_default_geometry() -> Optional[AttentionGeometry]:
-    return _config_default
-
-
-def _env_override() -> AttentionGeometry:
-    try:
-        return parse_spec(os.environ.get(ENV_BLOCKS, ""))
-    except ValueError as e:
-        raise ValueError(f"bad {ENV_BLOCKS}: {e}") from e
-
-
-# ---------------------------------------------------------------------------
 # resolution
 # ---------------------------------------------------------------------------
 def resolve_geometry(lq: int, lk: int, head_dim: int, heads: int, batch: int,
@@ -298,7 +262,7 @@ def resolve_geometry(lq: int, lk: int, head_dim: int, heads: int, batch: int,
 
     Returns ``(geometry, source)`` where ``source`` names the
     highest-precedence layer that contributed any field — evidence for the
-    perf ladder ("explicit" > "env" > "config" > "cache" > "default").
+    perf ladder ("explicit" > "cache" > "default").
     Block sizes from every layer are clamped to divisors of the sequence
     lengths (a cache winner tuned at seq 8k must not break a seq 1000
     call); fields no layer sets come from the shape-keyed defaults.
@@ -307,12 +271,6 @@ def resolve_geometry(lq: int, lk: int, head_dim: int, heads: int, batch: int,
     cached = lookup_cached(signature(lq, lk, head_dim, heads, batch, causal, dtype))
     if cached is not None:
         layers.append(("cache", cached))
-    cfg = get_default_geometry()
-    if cfg is not None:
-        layers.append(("config", cfg))
-    env = _env_override()
-    if env != AttentionGeometry():
-        layers.append(("env", env))
     if overrides is not None and overrides != AttentionGeometry():
         layers.append(("explicit", overrides.validate()))
 

@@ -729,14 +729,13 @@ def flash_attention(q: jax.Array,
     Block geometry + backward policy (``block_q``/``block_k`` forward,
     ``block_q_bwd``/``block_k_bwd`` backward, ``bwd_skip`` in
     {"block", "none"}, ``policy`` in {"lse", "recompute"}): any knob left
-    None resolves through the layered geometry engine — ``DS_ATTN_BLOCKS``
-    env override, the engine config's ``"attention"`` block, the
-    autotuner's shape-keyed winners cache, then v5e shape defaults
-    (``attention_geometry.resolve_geometry``).
+    None resolves through the autotuner's shape-keyed winners cache, then
+    v5e shape defaults (``attention_geometry.resolve_geometry``).
 
     Direct block kwargs that don't tile the call warn and fall back to
     XLA (the historical contract). ``geometry_spec`` — a spec string, the
-    vehicle for per-model ``attention_blocks`` config pins — instead joins
+    vehicle for per-model ``attention_blocks`` config pins and the engine's
+    ``"attention"`` block — instead joins
     the resolution as a highest-precedence layer whose blocks are CLAMPED
     to divisors like every other layer, so a pin tuned at one shape can
     never knock another shape off the kernel."""

@@ -10,7 +10,7 @@ for the ``E`` groups of ``group_sizes``. Rows past ``sum(group_sizes)``
 hold no defined result.
 
 Implementations, chosen by the same kernel choice as the row permutation
-(``moe/routing.py``: kwarg > ``DS_MOE_KERNEL`` > config block > ``auto``):
+(``MOELayer.route_kernel``):
 
 * ``impl="xla"``: ``jax.lax.ragged_dot``. XLA lowers it to its own
   ragged-dot kernel on a TPU and to a masked dense form elsewhere.

@@ -1,6 +1,6 @@
 """Fused row-permutation kernel for the sorted MoE dispatch/combine route.
 
-The sorted route (``moe/routing.py``, ``moe/sharded_moe.py``) reduces both
+The sorted route (``moe/sharded_moe.py``) reduces both
 MoE data movements to one primitive: **permute rows of a table by a
 precomputed index vector**, where an out-of-range index yields a zero row:
 

@@ -8,7 +8,7 @@ TPU-native ``mesh`` block replacing the implicit world-size/mpu plumbing.
 
 import json
 import os
-from typing import Optional
+from typing import Literal, Optional
 
 from pydantic import Field
 
@@ -71,12 +71,12 @@ class AttentionConfig(DeepSpeedConfigModel):
     """Flash-attention work-partitioning block (TPU-native; no reference
     analog — the reference's CUDA kernels hard-code their tiling).
 
-    Every field is optional: unset knobs resolve through the geometry
-    engine's remaining layers (``DS_ATTN_BLOCKS`` env override, the
-    autotuner's shape-keyed winners cache, v5e shape defaults) — see
-    ``ops/pallas/attention_geometry.py``. ``cache_file`` repoints the
-    winners cache (default ``autotuning_results/attention_blocks.json``,
-    also via ``DS_ATTN_CACHE``)."""
+    Every field is optional. Set fields are applied by the engine onto
+    the model config's ``attention_blocks`` spec (``dataclasses.replace`` +
+    ``module.clone``, as the "program" block); unset ones resolve through
+    the autotuner's shape-keyed winners cache, then v5e shape defaults —
+    see ``ops/pallas/attention_geometry.py``. ``cache_file`` repoints the
+    winners cache (default ``autotuning_results/attention_blocks.json``)."""
     block_q: Optional[int] = Field(None, ge=8)
     block_k: Optional[int] = Field(None, ge=8)
     block_q_bwd: Optional[int] = Field(None, ge=8)
@@ -98,19 +98,17 @@ class MoEConfig(DeepSpeedConfigModel):
 
     ``route``: "dense" (the GShard/Tutel ``[G,S,E,C]`` einsum route) or
     "sorted" (token-permutation dispatch/combine). ``kernel``: permutation
-    implementation for the sorted route — "auto" | "xla" | "pallas". Unset
-    knobs resolve through the routing engine's remaining layers
-    (``DS_MOE_ROUTE``/``DS_MOE_KERNEL`` env, then the "sorted"/"auto"
-    defaults) — see ``moe/routing.py``."""
-    route: Optional[str] = None      # "dense" | "sorted"
-    kernel: Optional[str] = None     # "auto" | "xla" | "pallas"
+    implementation for the sorted route — "auto" | "xla" | "pallas". Set
+    knobs are applied by the engine onto the model config's ``moe_route`` /
+    ``moe_route_kernel`` (as the "program" block); unset ones leave the
+    model config's own ("sorted" / "auto" unless it says otherwise)."""
+    route: Optional[Literal["dense", "sorted"]] = None
+    kernel: Optional[Literal["auto", "xla", "pallas"]] = None
 
+    def model_updates(self) -> dict:
+        fields = {"moe_route": self.route, "moe_route_kernel": self.kernel}
+        return {f: v for f, v in fields.items() if v is not None}
 
-#: env overrides for the program block (the ``DS_MOE_ROUTE`` idiom: an A/B
-#: lever that drifts the traced program without editing configs — and whose
-#: drift is CAUGHT, here by the committed search frontier, rule R014)
-ENV_REMAT_POLICY = "DS_REMAT_POLICY"
-ENV_LMHEAD_CHUNK = "DS_LMHEAD_CHUNK"
 
 #: program-block field -> model-config field it lands on (``lm_head_chunk``
 #: maps onto the zoo's ``fused_head_loss_chunk``; the rest share names)
@@ -157,22 +155,6 @@ class ProgramConfig(DeepSpeedConfigModel):
                 value = None
             out[model_field] = value
         return out
-
-
-def program_env_updates() -> dict:
-    """The env layer of the program knobs ({model_field: value}): ambient
-    A/B levers that drift every engine built in the process. The drift is
-    caught — candidate prices move, and the committed search frontier
-    (R014) fails — exactly like ``DS_MOE_ROUTE``."""
-    out = {}
-    policy = os.environ.get(ENV_REMAT_POLICY)
-    if policy is not None:
-        out["remat_policy"] = None if policy in ("", "none") else policy
-        out["remat"] = True
-    chunk = os.environ.get(ENV_LMHEAD_CHUNK)
-    if chunk is not None:
-        out["fused_head_loss_chunk"] = int(chunk)
-    return out
 
 
 class MeshConfig(DeepSpeedConfigModel):

@@ -117,10 +117,10 @@ MESH_FSDP = "fsdp"
 ZERO_OPTIMIZATION = "zero_optimization"
 ACTIVATION_CHECKPOINTING = "activation_checkpointing"
 # flash-attention block geometry / backward policy (TPU-native; see
-# ops/pallas/attention_geometry.py for the resolution layering)
+# runtime/config.py AttentionConfig, ops/pallas/attention_geometry.py)
 ATTENTION = "attention"
 # MoE dispatch/combine route + permutation kernel (TPU-native; see
-# moe/routing.py for the resolution layering)
+# runtime/config.py MoEConfig)
 MOE = "moe"
 # traced-program shape knobs — remat policy, LM-head chunking, projection
 # fusion — applied onto the module's model config by the engine; the

@@ -111,42 +111,35 @@ def _global_norm(tree):
     return global_norm_l2(tree)
 
 
-def _apply_program_knobs(module, program_config):
-    """Rebuild ``module`` around a model config carrying the "program"
-    block's knobs (remat policy / LM-head chunk / projection fusion) plus
-    the ``DS_REMAT_POLICY``/``DS_LMHEAD_CHUNK`` env layer — the engine
-    plumbing that makes program shape an *engine* dimension graft-search
-    can enumerate (analysis/search.py). A config-block knob the model
-    family doesn't declare raises (a silently dropped knob would price one
-    program and run another); the ambient env layer only warns, since it
-    may legitimately reach engines whose family lacks the field."""
+def _apply_program_knobs(module, config):
+    """Rebuild ``module`` around a model config carrying the engine-level
+    blocks that shape the traced program: "program" (remat policy / LM-head
+    chunk / projection fusion), "moe" (dispatch route + permutation kernel)
+    and "attention" (flash block geometry, merged over the model's own
+    ``attention_blocks`` spec). The one way an engine block reaches a
+    module: per engine, never process-wide. A set knob the model family
+    doesn't declare raises (a silently dropped knob would price one
+    program and run another)."""
     import dataclasses
 
-    from deepspeed_tpu.runtime.config import program_env_updates
-
-    cfg_updates = program_config.model_updates()
-    env_updates = program_env_updates()
-    if not cfg_updates and not env_updates:
+    updates = {**config.program_config.model_updates(), **config.moe_config.model_updates()}
+    blocks = config.attention_config.geometry_fields()
+    if not updates and not blocks:
         return module
     mcfg = getattr(module, "config", None)
     if mcfg is None or not dataclasses.is_dataclass(mcfg):
-        if cfg_updates:
-            raise ValueError(
-                f"'program' config block set but {type(module).__name__} carries no "
-                f"dataclass model config to apply it to")
-        logger.warning("program env override (%s) ignored: %s has no model config",
-                       sorted(env_updates), type(module).__name__)
-        return module
-    missing = sorted(f for f in cfg_updates if not hasattr(mcfg, f))
+        raise ValueError(
+            f"'program'/'moe'/'attention' config block set but {type(module).__name__} "
+            f"carries no dataclass model config to apply it to")
+    if blocks:
+        from deepspeed_tpu.ops.pallas.attention_geometry import from_dict, parse_spec
+        updates["attention_blocks"] = from_dict(blocks).merged_over(
+            parse_spec(getattr(mcfg, "attention_blocks", None))).spec()
+    missing = sorted(f for f in updates if not hasattr(mcfg, f))
     if missing:
         raise ValueError(
-            f"'program' config block sets {missing} but {type(mcfg).__name__} does not "
+            f"engine config blocks set {missing} but {type(mcfg).__name__} does not "
             f"declare those fields — the knob would silently not apply")
-    for f in sorted(set(env_updates) - set(mcfg.__dataclass_fields__)):
-        logger.warning("program env override %s ignored: %s does not declare it",
-                       f, type(mcfg).__name__)
-        env_updates.pop(f)
-    updates = {**cfg_updates, **env_updates}  # env wins: the A/B lever
     changed = {f: v for f, v in updates.items() if getattr(mcfg, f) != v}
     if not changed:
         return module
@@ -199,35 +192,20 @@ class DeepSpeedEngine:
         from deepspeed_tpu.parallel.topology import set_topology
         set_topology(topology)  # sequence-parallel attention finds the mesh here
 
-        # -- attention block geometry ("attention" config block): install the
-        # engine-level default + winners-cache path in the geometry resolver
-        # so every flash_attention call site (model zoo, ops) picks it up.
-        # Process-wide on purpose — the geometry is a property of the chip +
-        # workload, not of one engine; per-model `attention_blocks` config
-        # fields and per-call kwargs still override. Unset fields clear any
-        # previous engine's install (an engine without an "attention" block
-        # must not inherit one from an earlier init in the same process).
-        _attn = config.attention_config
+        # -- attention winners cache ("attention.cache_file"): the path the
+        # geometry resolver reads the autotuner's winners from, still set
+        # process-wide (ROADMAP S2 decides whether geometry becomes constants)
         from deepspeed_tpu.ops.pallas import attention_geometry as _ag
-        _ag.set_cache_path(_attn.cache_file or None)
-        _ag.set_default_geometry(_attn.geometry_fields() or None)
+        _ag.set_cache_path(config.attention_config.cache_file or None)
 
-        # -- MoE dispatch route ("moe" config block): same install/clear
-        # contract as the attention geometry — process-wide default, per-model
-        # `moe_route` config fields and per-layer kwargs still override, and
-        # an engine without a "moe" block clears any previous engine's install
-        from deepspeed_tpu.moe import routing as _moe_routing
-        _moe_routing.set_default_route(config.moe_config.route,
-                                       config.moe_config.kernel)
-
-        # -- traced-program shape knobs ("program" config block +
-        # DS_REMAT_POLICY/DS_LMHEAD_CHUNK env): rebuild the module around a
-        # replaced model config so remat policy, LM-head chunking and
-        # projection fusion are ENGINE dimensions — what graft-search
+        # -- traced-program shape ("program", "moe" and "attention" config
+        # blocks): rebuild the module around a replaced model config, so
+        # remat policy, LM-head chunking, projection fusion, MoE route and
+        # attention block geometry are ENGINE dimensions — what graft-search
         # enumerates and prices statically (analysis/search.py). Per-engine
         # (module.clone), never process-wide: two engines in one process can
         # trace two different program variants.
-        self.module = _apply_program_knobs(self.module, config.program_config)
+        self.module = _apply_program_knobs(self.module, config)
 
         # -- precision (reference engine.py:1056-1069 half()/bfloat16())
         if config.bfloat16_enabled:
@@ -759,9 +737,9 @@ class DeepSpeedEngine:
         metadata.update(self.config.zero_config.cost_metadata(
             fsdp_size=int(self.mesh.shape.get("fsdp", 1))))
         cfg_model = getattr(self.module, "config", None)
-        # the program knobs THIS trace actually carried (post config-block
-        # + env resolution) — graft-search's candidate evidence, and the
-        # audit trail that a banked rung ran the variant it claims
+        # the program knobs THIS trace carried — graft-search's candidate
+        # evidence, and the audit trail that a banked rung ran the variant
+        # it claims
         from deepspeed_tpu.runtime.config import PROGRAM_MODEL_FIELDS
         knobs = {field: getattr(cfg_model, mf)
                  for field, mf in PROGRAM_MODEL_FIELDS.items()
@@ -773,7 +751,6 @@ class DeepSpeedEngine:
             metadata["program_knobs"] = knobs
         moe_experts = getattr(cfg_model, "moe_num_experts", 0) if cfg_model is not None else 0
         if moe_experts:
-            from deepspeed_tpu.moe.routing import resolve_intended_route
             from deepspeed_tpu.moe.sharded_moe import _num_groups, sec_signature
             batch_leaf = np.asarray(jax.tree.leaves(example_batch)[0])
             micro = batch_leaf.shape[0] // self.config.gradient_accumulation_steps
@@ -784,11 +761,7 @@ class DeepSpeedEngine:
                 getattr(cfg_model, "moe_capacity_factor", 1.0),
                 getattr(cfg_model, "moe_min_capacity", 8),
                 k=getattr(cfg_model, "moe_k", 1))]
-            # the collective signature pins the *committed* route intent
-            # (config layers only — resolve_intended_route skips the env),
-            # so a DS_MOE_ROUTE=dense override drifts the program but not
-            # the signature and R009 catches it
-            if resolve_intended_route(getattr(cfg_model, "moe_route", None)) == "sorted":
+            if getattr(cfg_model, "moe_route", "sorted") == "sorted":
                 sig = metadata.setdefault("collective_signature", [])
                 sig.append({"layer": "jaxpr", "kind": "dense_dispatch", "count": 0,
                             "note": "sorted MoE route: the a2a endpoints are fed "

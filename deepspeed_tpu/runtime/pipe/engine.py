@@ -7,7 +7,7 @@ instruction stream per process — NCCL p2p sends with a meta handshake
 (``engine.py:795``), explicit buffer pools, separate fwd/bwd executors.
 Here a schedule is a jitted ``lax.scan`` over ticks with ``ppermute``
 neighbor exchange; three schedules are selectable via
-``pipeline.schedule`` (or the ``DS_PIPE_SCHEDULE`` env A/B override):
+``pipeline.schedule``:
 
 * ``1f1b`` (default) — the real thing. Per-tick forward/backward
   interleave with an explicitly managed activation stash: warmup ticks
@@ -54,7 +54,7 @@ from deepspeed_tpu.runtime.pipe.module import PipelineModule
 from deepspeed_tpu.runtime.pipe.schedule import TrainSchedule
 from deepspeed_tpu.utils.logging import log_dist, logger
 
-#: selectable tick schedules (``pipeline.schedule`` / ``DS_PIPE_SCHEDULE``)
+#: selectable tick schedules (``pipeline.schedule``)
 PIPE_SCHEDULES = ("1f1b", "chunked", "gpipe")
 
 
@@ -91,20 +91,12 @@ class PipelineEngine(DeepSpeedEngine):
                     f"gradient_accumulation_steps={self.micro_batches}")
             if chunk == self.micro_batches:
                 chunk = 0  # one wave == the plain schedule
-        # schedule resolution: env A/B override > explicit config >
-        # chunked-compat default (a config that asked for waves keeps
-        # them) > 1f1b
-        sched = os.environ.get("DS_PIPE_SCHEDULE") or pipe_cfg.get("schedule")
-        if sched is not None and sched not in PIPE_SCHEDULES:
+        # pipeline.schedule, else chunked where the config asked for waves
+        # (it keeps them), else 1f1b
+        sched = pipe_cfg.get("schedule") or ("chunked" if chunk else "1f1b")
+        if sched not in PIPE_SCHEDULES:
             raise ValueError(f"pipeline.schedule must be one of {PIPE_SCHEDULES}, "
                              f"got {sched!r}")
-        # the committed intent skips the env layer (the DS_MOE_ROUTE
-        # pattern): a DS_PIPE_SCHEDULE override drifts the traced program
-        # but not the stamped collective signature, so R009 catches it
-        self.pipe_schedule_intent = (pipe_cfg.get("schedule")
-                                     or ("chunked" if chunk else "1f1b"))
-        if sched is None:
-            sched = "chunked" if chunk else "1f1b"
         if sched != "chunked" and chunk:
             logger.warning(f"pipeline.chunk_microbatches={chunk} only applies to the "
                            f"chunked schedule; ignored under schedule={sched!r}")
@@ -546,8 +538,8 @@ class PipelineEngine(DeepSpeedEngine):
         contract (graft-audit, analysis/cost.py):
 
         * ``activation_budget_bytes`` — from ``pipeline.activation_budget_mb``
-          (or the ``DS_PIPE_ACT_BUDGET_MB`` env override, the seeded-
-          regression path mirroring ``DS_MOE_ROUTE``). When declared,
+          (or the ``DS_PIPE_ACT_BUDGET_MB`` env override, lint's seeded-
+          regression path: a budget, not a program choice). When declared,
           R010 gates the statically estimated transient peak against it:
           the pre-wired CPU gate for the ROADMAP-2 1F1B refactor's
           ``<=1F1B`` bound. No budget declared = inventoried, not gated.
@@ -576,11 +568,8 @@ class PipelineEngine(DeepSpeedEngine):
         }
         if self.pipe_schedule == "1f1b":
             metadata["pipe_schedule"]["stash_slots"] = self.stash_slots
-        # the signature pins the config-committed schedule INTENT (env
-        # overrides drift the program, not the signature — R009's seeded
-        # regression, mirroring the MoE route intent)
         sig = metadata.setdefault("collective_signature", [])
-        if self.pipe_schedule_intent == "1f1b":
+        if self.pipe_schedule == "1f1b":
             sig.append({"layer": "jaxpr", "kind": "collective_permute", "count": 4,
                         "note": "2 boundary hops per tick boundary (act fwd + "
                                 "grad bwd) over 3 phase bodies: warmup holds "

@@ -1,7 +1,7 @@
 """The tier-1 cost gate: tools/graft_lint.py --cost run in-process against
 the COMMITTED cost baseline (analysis_results/cost_baseline.json) on a
 CPU-fast scenario subset, including the deliberate-regression exit-1
-cases — the forced dense MoE route (R009 route-signature drift + the
+cases — the dense MoE route patched into the scenario (R009 route-signature drift + the
 einsum route delta inventoried in the cost report) and an activation
 budget below the chunked pipe schedule's static estimate (R010, the
 pre-wired ROADMAP-2 1F1B gate). Plus the stale-waiver WARN units."""
@@ -13,7 +13,7 @@ import os
 import pytest
 
 from deepspeed_tpu.analysis.core import Finding, Waiver, stale_config_waivers
-from deepspeed_tpu.moe import routing
+from deepspeed_tpu.analysis.scenarios import SCENARIO_CONFIG
 from deepspeed_tpu.parallel.topology import set_topology
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
@@ -28,21 +28,13 @@ def graft_lint():
     return mod
 
 
-_ENVS = (routing.ENV_ROUTE, "DS_PIPE_ACT_BUDGET_MB", "DS_PIPE_SCHEDULE",
-         "DS_SERVE_WQ")
-
-
 @pytest.fixture(autouse=True)
 def _clean():
-    for env in _ENVS:
-        os.environ.pop(env, None)
+    os.environ.pop("DS_PIPE_ACT_BUDGET_MB", None)
     set_topology(None)
-    routing.set_default_route(None, None)
     yield
-    for env in _ENVS:
-        os.environ.pop(env, None)
+    os.environ.pop("DS_PIPE_ACT_BUDGET_MB", None)
     set_topology(None)
-    routing.set_default_route(None, None)
 
 
 def _report(tmp_path):
@@ -128,11 +120,11 @@ def test_cost_gate_passes_clean_subset(graft_lint, tmp_path):
 
 def test_dense_route_regression_exits_1_with_cost_delta(graft_lint, tmp_path,
                                                         monkeypatch):
-    """DS_MOE_ROUTE=dense through the EP scenario: R009 fires on the
-    route-signature drift (and R001 on the [S,E,C] shape), and the cost
+    """The EP scenario's model built with the dense route: R009 fires on
+    the route-signature drift (and R001 on the [S,E,C] shape), and the cost
     report carries the dense-dispatch delta — the a2a endpoints fed by an
     einsum instead of a permutation."""
-    monkeypatch.setenv(routing.ENV_ROUTE, "dense")
+    monkeypatch.setitem(SCENARIO_CONFIG, "moe_route", "dense")
     rc = graft_lint.run(["--cost", "--scenarios", "moe_ep_step",
                          "--no-ast", "--out", str(tmp_path), "-q"])
     assert rc == 1
@@ -161,28 +153,12 @@ def test_chunked_schedule_fails_under_the_1f1b_budget(graft_lint, tmp_path,
     assert budget_msgs and "budget" in budget_msgs[0]["message"]
 
 
-def test_pipe_schedule_env_drift_exits_1(graft_lint, tmp_path, monkeypatch):
-    """DS_PIPE_SCHEDULE=chunked against the committed-1f1b scenario: the
-    traced program drifts but the stamped signature pins the config
-    intent (the DS_MOE_ROUTE pattern), so R009 fires on the permute
-    count — and the chunked program also busts the 1F1B budget (R010)."""
-    monkeypatch.setenv("DS_PIPE_SCHEDULE", "chunked")
-    rc = graft_lint.run(["--cost", "--scenarios", "pipe_1f1b_step",
-                         "--no-ast", "--out", str(tmp_path), "-q"])
-    assert rc == 1
-    report = _report(tmp_path)
-    hits = report["programs"]["pipe_1f1b_step"]["summary"]["rule_hits"]
-    assert hits.get("R009") and hits.get("R010")
-
-
 def test_serve_wq_env_drift_exits_1(graft_lint, tmp_path, monkeypatch):
-    """DS_SERVE_WQ=fp against the committed-int8 quantized serving
-    scenario: the builder resolves the env layer, so the traced program
-    swings back to full-width fp kernels — peak bytes jump past the R013
-    ratchet tolerance while the scenario's ``serve_weight_dtype`` metadata
-    stays the committed intent (``resolve_intended_weight_dtype`` skips
-    env). The graft-quant-serve seeded regression."""
-    monkeypatch.setenv("DS_SERVE_WQ", "fp")
+    """The quantized serving scenario built fp where int8 is banked: the
+    traced program swings back to full-width fp kernels — peak bytes jump
+    past the R013 ratchet tolerance. The graft-quant-serve seeded
+    regression."""
+    monkeypatch.setitem(SCENARIO_CONFIG, "serve_weight_dtype", "fp")
     rc = graft_lint.run(["--cost", "--scenarios", "serve_quant_decode_step",
                          "--no-ast", "--out", str(tmp_path), "-q"])
     assert rc == 1
@@ -226,7 +202,7 @@ def test_cost_update_baseline_roundtrip(graft_lint, tmp_path, monkeypatch):
     """--cost --update-baseline banks the (regressed) costs into the cost
     baseline; the immediately following gate run passes — ratchet
     semantics, merge-preserving entries from other scenarios."""
-    monkeypatch.setenv(routing.ENV_ROUTE, "dense")
+    monkeypatch.setitem(SCENARIO_CONFIG, "moe_route", "dense")
     baseline = tmp_path / "baseline.json"
     cost_baseline = tmp_path / "cost_baseline.json"
     # seed the cost baseline with a foreign entry that must survive the merge

@@ -10,7 +10,7 @@ import sys
 
 import pytest
 
-from deepspeed_tpu.moe import routing
+from deepspeed_tpu.analysis.scenarios import SCENARIO_CONFIG
 from deepspeed_tpu.parallel.topology import set_topology
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
@@ -28,12 +28,8 @@ def graft_lint():
 @pytest.fixture(autouse=True)
 def _clean():
     set_topology(None)
-    routing.set_default_route(None, None)
-    os.environ.pop(routing.ENV_ROUTE, None)
     yield
     set_topology(None)
-    routing.set_default_route(None, None)
-    os.environ.pop(routing.ENV_ROUTE, None)
 
 
 def test_committed_baseline_exists_and_is_clean():
@@ -58,9 +54,9 @@ def test_gate_passes_on_clean_scenarios(graft_lint, tmp_path):
 
 
 def test_gate_fails_on_seeded_dense_regression(graft_lint, tmp_path, monkeypatch):
-    """The ISSUE 7 acceptance check: DS_MOE_ROUTE=dense analyzed against
-    the clean committed baseline exits non-zero."""
-    monkeypatch.setenv(routing.ENV_ROUTE, "dense")
+    """The ISSUE 7 acceptance check: the MoE scenario built with the dense
+    route, analyzed against the clean committed baseline, exits non-zero."""
+    monkeypatch.setitem(SCENARIO_CONFIG, "moe_route", "dense")
     rc = graft_lint.run(["--scenarios", "moe_top1_route",
                          "--out", str(tmp_path), "-q"])
     assert rc == 1
@@ -83,7 +79,7 @@ def test_ast_pass_is_clean_against_waivers(graft_lint, tmp_path):
 
 
 def test_report_findings_carry_fingerprints(graft_lint, tmp_path, monkeypatch):
-    monkeypatch.setenv(routing.ENV_ROUTE, "dense")
+    monkeypatch.setitem(SCENARIO_CONFIG, "moe_route", "dense")
     graft_lint.run(["--scenarios", "moe_top1_route", "--out", str(tmp_path), "-q"])
     report = json.loads(next(tmp_path.glob("lint_*.json")).read_text())
     for f in report["findings"]:
@@ -94,7 +90,7 @@ def test_update_baseline_roundtrip(graft_lint, tmp_path, monkeypatch):
     """--update-baseline acknowledges current ERRORs; an immediately
     following gate run against that baseline passes even with the
     regression still in place (the ratchet semantics)."""
-    monkeypatch.setenv(routing.ENV_ROUTE, "dense")
+    monkeypatch.setitem(SCENARIO_CONFIG, "moe_route", "dense")
     baseline = tmp_path / "baseline.json"
     rc = graft_lint.run(["--scenarios", "moe_top1_route", "--no-ast",
                          "--baseline", str(baseline), "--out", str(tmp_path),

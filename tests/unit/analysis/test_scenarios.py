@@ -1,29 +1,20 @@
 """Clean-program matrix: every tier-1 scenario program must produce ZERO
 unwaived findings (the false-positive budget is zero), and the seeded
-dense-route regression must light R001 up through the same path a bench
-run would take (env-resolved route)."""
-
-import os
+dense-route regression (the scenario's model configuration patched) must
+light R001 up."""
 
 import pytest
 
 from deepspeed_tpu.analysis import run_program_rules, summarize
 from deepspeed_tpu.analysis import scenarios as scen
-from deepspeed_tpu.moe import routing
 from deepspeed_tpu.parallel.topology import set_topology
 
 
 @pytest.fixture(autouse=True)
 def _clean():
     set_topology(None)
-    routing.set_default_route(None, None)
-    os.environ.pop(routing.ENV_ROUTE, None)
-    os.environ.pop(routing.ENV_KERNEL, None)
     yield
     set_topology(None)
-    routing.set_default_route(None, None)
-    os.environ.pop(routing.ENV_ROUTE, None)
-    os.environ.pop(routing.ENV_KERNEL, None)
 
 
 @pytest.fixture(scope="module")
@@ -157,10 +148,10 @@ def test_composition_blocking_gap_ratchet():
 
 
 def test_dense_env_route_fires_r001_through_scenarios(monkeypatch):
-    """DS_MOE_ROUTE=dense — the seeded regression — must reach the traced
-    scenario program through the same resolution layers as a bench run
-    and produce ERROR-severity R001 findings."""
-    monkeypatch.setenv(routing.ENV_ROUTE, "dense")
+    """The dense route — the seeded regression — patched into the
+    scenarios' configuration must reach the traced program and produce
+    ERROR-severity R001 findings."""
+    monkeypatch.setitem(scen.SCENARIO_CONFIG, "moe_route", "dense")
     programs, _ = scen.build(["moe_top1_route", "moe_top2_route"])
     assert len(programs) == 2
     for info in programs:

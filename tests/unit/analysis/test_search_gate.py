@@ -25,12 +25,8 @@ ARTIFACT = os.path.join(REPO, "analysis_results", "search_pareto.json")
 
 @pytest.fixture(autouse=True)
 def _clean():
-    for env in ("DS_REMAT_POLICY", "DS_LMHEAD_CHUNK"):
-        os.environ.pop(env, None)
     set_topology(None)
     yield
-    for env in ("DS_REMAT_POLICY", "DS_LMHEAD_CHUNK"):
-        os.environ.pop(env, None)
     set_topology(None)
 
 

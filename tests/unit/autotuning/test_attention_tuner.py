@@ -18,13 +18,9 @@ from deepspeed_tpu.ops.pallas.attention_geometry import (AttentionGeometry,
 
 
 @pytest.fixture(autouse=True)
-def _clean_geometry_state(monkeypatch):
-    monkeypatch.delenv(ag.ENV_BLOCKS, raising=False)
-    monkeypatch.delenv(ag.ENV_CACHE, raising=False)
-    ag.set_default_geometry(None)
+def _clean_geometry_state():
     yield
     ag.set_cache_path(None)
-    ag.set_default_geometry(None)
 
 
 def test_sweep_persists_winner_and_kernel_reloads_it(tmp_path):

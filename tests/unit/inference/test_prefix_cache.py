@@ -14,19 +14,16 @@ import numpy as np
 import pytest
 
 import jax
-import jax.numpy as jnp
 
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.fleet import FleetRouter, load_bundle, save_bundle
 from deepspeed_tpu.inference.serving import (BlockPool,
                                              ContinuousBatchingScheduler,
-                                             ENV_PREFIX_CACHE, FINISHED,
+                                             FINISHED,
                                              MigrationError, Request,
                                              ServingConfig,
                                              iter_serve_events,
-                                             resolve_prefix_cache,
-                                             set_default_prefix_cache,
                                              validate_event)
 from deepspeed_tpu.inference.serving.blocks import _ROOT, chain_hash, prefix_key
 from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
@@ -434,35 +431,20 @@ def test_cache_on_greedy_parity_and_prefill_skip(engine_cfg):
     assert stats["pool"]["prefix_evictions"] == 0  # pool never under pressure
 
 
-def test_env_knob_and_default_resolution(engine_cfg, monkeypatch):
+def test_env_knob_and_default_resolution(engine_cfg):
+    """``ServingConfig.prefix_cache`` alone decides: on by default, off where
+    the configuration says so, anything else refused at the configuration."""
     engine, cfg = engine_cfg
-    try:
-        monkeypatch.delenv(ENV_PREFIX_CACHE, raising=False)
-        set_default_prefix_cache(None)
-        assert resolve_prefix_cache(None) == ("on", "default")
-        sched = _mk_sched(engine, clock=SimClock())
-        assert sched.prefix_cache == "on" and sched.pool.prefix_cache
-        monkeypatch.setenv(ENV_PREFIX_CACHE, "off")
-        sched = _mk_sched(engine, clock=SimClock())
-        assert sched.prefix_cache == "off"
-        assert sched.prefix_cache_source == "env"
-        assert not sched.pool.prefix_cache
-        # env is the experiment-override layer: it beats even a committed
-        # ServingConfig value (a forced env hits both A/B arms the same
-        # way — the weight_dtype convention)
-        sched = _mk_sched(engine, clock=SimClock(), prefix_cache="on")
-        assert (sched.prefix_cache, sched.prefix_cache_source) == ("off",
-                                                                   "env")
-        monkeypatch.delenv(ENV_PREFIX_CACHE)
-        sched = _mk_sched(engine, clock=SimClock(), prefix_cache="off")
-        assert (sched.prefix_cache, sched.prefix_cache_source) == ("off",
-                                                                   "config")
-        # an unparseable env value refuses loudly, naming the variable
-        monkeypatch.setenv(ENV_PREFIX_CACHE, "sideways")
+    assert ServingConfig().prefix_cache == "on"
+    sched = _mk_sched(engine, clock=SimClock())
+    assert sched.prefix_cache == "on" and sched.pool.prefix_cache
+    assert sched.stats()["prefix_cache"] == "on"
+    sched = _mk_sched(engine, clock=SimClock(), prefix_cache="off")
+    assert sched.prefix_cache == "off" and not sched.pool.prefix_cache
+    assert sched.stats()["prefix_cache"] == "off"
+    for bad in ("sideways", None):
         with pytest.raises(ValueError, match="prefix_cache"):
-            _mk_sched(engine, clock=SimClock())
-    finally:
-        set_default_prefix_cache(None)
+            ServingConfig(prefix_cache=bad)
 
 
 # ---------------------------------------------------------------------------
@@ -636,21 +618,13 @@ def test_router_recent_dispatch_memory_colocates_bursts():
 # ---------------------------------------------------------------------------
 
 def test_decode_program_identical_cache_on_vs_off(engine_cfg):
-    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
-                                                          make_apply_fn,
-                                                          make_slot_cache)
     engine, cfg = engine_cfg
-    apply_fn = make_apply_fn(engine.module, engine._mparams)
 
-    def jaxpr_str(mode):
-        set_default_prefix_cache(mode)
-        try:
-            cache = make_slot_cache(engine.module, 4)
-            decode = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
-            toks = jnp.zeros((4,), jnp.int32)
-            return str(jax.make_jaxpr(decode)(engine.params, cache, toks, toks))
-        finally:
-            set_default_prefix_cache(None)
+    def lowered(mode):
+        sched = _mk_sched(engine, prefix_cache=mode)
+        toks = np.zeros(sched.slots, np.int32)
+        return sched.fns["decode"].lower(sched._serve_params, sched._cache,
+                                         toks, toks).as_text()
 
-    on, off = jaxpr_str("on"), jaxpr_str("off")
+    on, off = lowered("on"), lowered("off")
     assert on == off  # byte-identical: zero device-side cost when idle

@@ -1,12 +1,11 @@
 """graft-quant-serve tier-1 gates: the quantized serving path end to end —
 scheduler greedy parity (int8 weights + int8 KV vs fp) under the committed
 logit envelope (``QUANT_PARITY_MAX_ABS``), the int8-KV-only parity +
-identical pool counters, the DS_SERVE_WQ layered resolution (explicit >
-env > config > default) and its refusal edges, and the byte-budget pool
-sizing that turns int8 KV into deeper admission."""
+identical pool counters, ``ServingConfig.weight_dtype``'s default, choices
+and refusal edges, and the byte-budget pool sizing that turns int8 KV into
+deeper admission."""
 
 import dataclasses
-import os
 
 import numpy as np
 import pytest
@@ -18,10 +17,7 @@ from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference.serving import (FINISHED,
                                              ContinuousBatchingScheduler,
-                                             Request, ServingConfig,
-                                             resolve_intended_weight_dtype,
-                                             resolve_weight_dtype,
-                                             set_default_weight_dtype)
+                                             Request, ServingConfig)
 from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
 from deepspeed_tpu.ops.quantizer.weights import QUANT_PARITY_MAX_ABS, quantize_params
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
@@ -29,12 +25,8 @@ from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 
 @pytest.fixture(autouse=True)
 def _clean():
-    os.environ.pop("DS_SERVE_WQ", None)
-    set_default_weight_dtype(None)
     set_topology(None)
     yield
-    os.environ.pop("DS_SERVE_WQ", None)
-    set_default_weight_dtype(None)
     set_topology(None)
 
 
@@ -75,44 +67,32 @@ def _serve(engine, cfg, scfg, lengths=(5, 12, 9), max_new=6, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# layered resolution (explicit > env > config > default) + drift anchor
+# the configuration alone decides the served weight dtype
 # ---------------------------------------------------------------------------
-def test_weight_dtype_layered_resolution():
-    assert resolve_weight_dtype(None) == ("fp", "default")
-    set_default_weight_dtype("int8")
-    assert resolve_weight_dtype(None) == ("int8", "config")
-    os.environ["DS_SERVE_WQ"] = "int4"
-    assert resolve_weight_dtype(None) == ("int4", "env")
-    assert resolve_weight_dtype("int8") == ("int8", "explicit")
-    # the committed intent never reads the env layer — the R013 drift seam
-    assert resolve_intended_weight_dtype(None) == "int8"
-    assert resolve_intended_weight_dtype("int4") == "int4"
+def test_weight_dtype_layered_resolution(engine_cfg):
+    """``ServingConfig.weight_dtype`` defaults to fp and takes the quantizer's
+    choices; the scheduler serves what its configuration says, and a module
+    built for a dtype the quantizer does not know refuses to trace."""
+    engine, cfg = engine_cfg
+    assert ServingConfig().weight_dtype == "fp"
+    for wd in ("fp", "int8", "int4"):
+        assert ServingConfig(weight_dtype=wd).weight_dtype == wd
+    for bad in ("fp16", None):
+        with pytest.raises(ValueError, match="weight_dtype"):
+            ServingConfig(weight_dtype=bad)
+    sched = ContinuousBatchingScheduler(engine, ServingConfig(slots=4))
+    assert sched.stats()["weight_dtype"] == "fp" and sched.module is engine.module
+    module = GPT2LMHeadModel(dataclasses.replace(cfg, serve_weight_dtype="int2"))
     with pytest.raises(ValueError, match="weight_dtype"):
-        resolve_weight_dtype("fp16")
-    os.environ["DS_SERVE_WQ"] = "bogus"
-    with pytest.raises(ValueError, match="DS_SERVE_WQ"):
-        resolve_weight_dtype(None)
+        jax.eval_shape(module.init, jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
 
 
 def test_serving_config_validates_weight_dtype():
     with pytest.raises(ValueError):
         ServingConfig(weight_dtype="int2")
     scfg = ServingConfig()
-    assert scfg.weight_dtype is None and scfg.kv_quant is True
+    assert scfg.weight_dtype == "fp" and scfg.kv_quant is True
     assert scfg.weight_group_size == 64
-
-
-def test_env_reaches_scheduler_build(engine_cfg):
-    """DS_SERVE_WQ flips what the scheduler BUILDS (the drift seam is the
-    builder, never the module): an env int8 over a default-fp config
-    serves quantized, and stats() reports the env source."""
-    engine, cfg = engine_cfg
-    os.environ["DS_SERVE_WQ"] = "int8"
-    sched, outs = _serve(engine, cfg, ServingConfig(slots=4))
-    st = sched.stats()
-    assert st["weight_dtype"] == "int8"
-    assert st["weight_dtype_source"] == "env"
-    assert all(len(o) == 6 for o in outs)
 
 
 # ---------------------------------------------------------------------------

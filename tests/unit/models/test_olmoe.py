@@ -178,13 +178,8 @@ def test_the_bank_takes_both_kernels_and_both_layouts():
     ids = ids_of(5)
     base = np.asarray(model.apply({"params": params}, ids)[0])
     for kernel in ("xla", "pallas"):
-        cfg = dataclasses.replace(model.config)
-        from deepspeed_tpu.moe import routing
-        routing.set_default_route(None, kernel)
-        try:
-            out = np.asarray(LlamaForCausalLM(cfg).apply({"params": params}, ids)[0])
-        finally:
-            routing.set_default_route(None, None)
+        cfg = dataclasses.replace(model.config, moe_route_kernel=kernel)
+        out = np.asarray(LlamaForCausalLM(cfg).apply({"params": params}, ids)[0])
         np.testing.assert_allclose(out, base, atol=2e-5)
     dense = LlamaForCausalLM(dataclasses.replace(model.config, moe_route="dense"))
     np.testing.assert_allclose(np.asarray(dense.apply({"params": params}, ids)[0]), base, atol=2e-5)

@@ -1,9 +1,7 @@
-"""Sorted-route MoE tests: layered route resolution (kwargs > env > config
-block > default), dense-vs-sorted parity (fwd outputs + grads) across the
+"""Sorted-route MoE tests: the route as a field of the layer and the model
+configuration, dense-vs-sorted parity (fwd outputs + grads) across the
 top1/top2 × drop/no-drop × deterministic/RTS matrix, the no-[G,S,E,C]
 jaxpr guarantee, and a sharded EP>=2 dryrun with ``route=sorted``."""
-
-import os
 
 import numpy as np
 import pytest
@@ -14,7 +12,6 @@ import jax.numpy as jnp
 import flax.linen as nn
 
 import deepspeed_tpu
-from deepspeed_tpu.moe import routing
 from deepspeed_tpu.moe.sharded_moe import MOELayer, _capacity, top1gating, top1routing, top2gating, top2routing
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 
@@ -22,39 +19,53 @@ from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 @pytest.fixture(autouse=True)
 def _clean():
     set_topology(None)
-    routing.set_default_route(None, None)
-    os.environ.pop(routing.ENV_ROUTE, None)
-    os.environ.pop(routing.ENV_KERNEL, None)
     yield
     set_topology(None)
-    routing.set_default_route(None, None)
-    os.environ.pop(routing.ENV_ROUTE, None)
-    os.environ.pop(routing.ENV_KERNEL, None)
 
 
 # ---------------------------------------------------------------------------
-# resolution layering
+# the route is a field: of the layer, of the model config, of the "moe" block
 # ---------------------------------------------------------------------------
+def _moe_block_config(**moe):
+    from deepspeed_tpu.runtime.config import DeepSpeedConfig
+    return DeepSpeedConfig({"train_batch_size": 8, **({"moe": moe} if moe else {})},
+                           dp_world_size=1)
+
+
 def test_route_resolution_layers():
-    assert routing.resolve_route() == ("sorted", "auto", "default")
-    routing.set_default_route("dense", "xla")
-    assert routing.resolve_route() == ("dense", "xla", "config")
-    os.environ[routing.ENV_ROUTE] = "sorted"
-    os.environ[routing.ENV_KERNEL] = "pallas"
-    assert routing.resolve_route() == ("sorted", "pallas", "env")
-    assert routing.resolve_route(route="dense", kernel="xla") == ("dense", "xla", "explicit")
-    routing.set_default_route(None, None)
-    del os.environ[routing.ENV_ROUTE], os.environ[routing.ENV_KERNEL]
-    assert routing.resolve_route() == ("sorted", "auto", "default")
+    """Default sorted/auto on the layer and on the model configuration; the
+    engine's "moe" block lands on a copy of the model configuration and on
+    nothing else."""
+    from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+    from deepspeed_tpu.runtime.engine import _apply_program_knobs
+
+    layer = MOELayer(expert=_TinyExpert(), model_dim=8, num_experts=4)
+    assert (layer.route, layer.route_kernel) == ("sorted", "auto")
+    cfg = get_gpt2_config("test", moe_num_experts=4)
+    assert (cfg.moe_route, cfg.moe_route_kernel) == ("sorted", "auto")
+    assert get_gpt2_config("test", moe_route="dense").moe_route == "dense"
+
+    model = GPT2LMHeadModel(cfg)
+    assert _apply_program_knobs(model, _moe_block_config()) is model
+    built = _apply_program_knobs(model, _moe_block_config(route="dense", kernel="xla"))
+    assert (built.config.moe_route, built.config.moe_route_kernel) == ("dense", "xla")
+    assert (model.config.moe_route, model.config.moe_route_kernel) == ("sorted", "auto")
+    assert _apply_program_knobs(model, _moe_block_config(kernel="pallas")).config.moe_route == "sorted"
 
 
 def test_route_resolution_validates():
+    x = jnp.zeros((2, 8, 8), jnp.float32)
+
+    def init(**kw):
+        MOELayer(expert=_TinyExpert(), model_dim=8, num_experts=4, **kw).init(
+            jax.random.PRNGKey(0), x)
+
     with pytest.raises(ValueError, match="route"):
-        routing.resolve_route(route="einsum")
+        init(route="einsum")
     with pytest.raises(ValueError, match="kernel"):
-        routing.resolve_route(kernel="cuda")
+        init(route_kernel="cuda")
     with pytest.raises(ValueError, match="route"):
-        routing.set_default_route("blocksparse")
+        _moe_block_config(route="blocksparse")
 
 
 # ---------------------------------------------------------------------------
@@ -109,7 +120,7 @@ class _TinyExpert(nn.Module):
                         kernel_init=nn.initializers.normal(1.0))(x)
 
 
-def _run_layer(route, k, cf, deterministic, use_rts, kernel=None, x=None):
+def _run_layer(route, k, cf, deterministic, use_rts, kernel="auto", x=None):
     M, E = 8, 4
     layer = MOELayer(expert=_TinyExpert(), model_dim=M, num_experts=E, k=k,
                      capacity_factor=cf, eval_capacity_factor=cf, min_capacity=1,
@@ -291,7 +302,7 @@ def test_moe_gpt2_trains_sorted_on_expert_mesh():
         "moe": {"route": "sorted", "kernel": "xla"},
     }
     engine, _, _, _ = deepspeed_tpu.initialize(model=model, config=ds_config, topology=topo)
-    assert routing.resolve_route() == ("sorted", "xla", "config")
+    assert engine.module.config.moe_route_kernel == "xla"
     rng = np.random.default_rng(0)
     batch = {"input_ids": rng.integers(0, cfg.vocab_size, (8, 32)).astype(np.int32)}
     losses = [float(engine.train_batch(batch)) for _ in range(8)]

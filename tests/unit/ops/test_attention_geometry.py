@@ -23,16 +23,12 @@ from deepspeed_tpu.ops.transformer.attention import dot_product_attention
 
 
 @pytest.fixture(autouse=True)
-def _clean_geometry_state(monkeypatch, tmp_path):
-    """Every test sees an empty env/config/cache resolution stack; the
-    winners cache points into tmp so repo artifacts can't leak in."""
-    monkeypatch.delenv(ag.ENV_BLOCKS, raising=False)
-    monkeypatch.delenv(ag.ENV_CACHE, raising=False)
+def _clean_geometry_state(tmp_path):
+    """Every test sees an empty winners cache: it points into tmp so repo
+    artifacts can't leak in."""
     ag.set_cache_path(str(tmp_path / "attention_blocks.json"))
-    ag.set_default_geometry(None)
     yield
     ag.set_cache_path(None)
-    ag.set_default_geometry(None)
 
 
 def _rand_qkv(seed, b, l, h, d, dtype=jnp.float32):
@@ -152,30 +148,24 @@ def test_default_geometry_shape_keyed():
     assert wide.block_k == 512  # wide heads keep the smaller tile
 
 
-def test_resolution_precedence_env_config_cache_default(monkeypatch):
+def test_resolution_precedence_env_config_cache_default():
+    """explicit > winners file > shape default, field by field."""
     shape = dict(lq=256, lk=256, head_dim=32, heads=2, batch=1, causal=True)
     sig = signature(256, 256, 32, 2, 1, True)
 
     g, src = resolve_geometry(**shape)
     assert src == "default"
+    default = g
 
     store_winner(sig, AttentionGeometry(block_q=64, block_k=128))
     g, src = resolve_geometry(**shape)
     assert (src, g.block_q, g.block_k) == ("cache", 64, 128)
-
-    ag.set_default_geometry("block_q=32")
-    g, src = resolve_geometry(**shape)
-    assert (src, g.block_q) == ("config", 32)
-    assert g.block_k == 128  # unset config fields fall through to the cache
-
-    monkeypatch.setenv(ag.ENV_BLOCKS, "block_q=128,policy=recompute")
-    g, src = resolve_geometry(**shape)
-    assert (src, g.block_q, g.policy) == ("env", 128, "recompute")
+    assert g.policy == default.policy  # unset cache fields fall through to the default
 
     g, src = resolve_geometry(**shape,
-                              overrides=AttentionGeometry(block_q=16))
-    assert (src, g.block_q) == ("explicit", 16)
-    assert g.policy == "recompute"  # env still supplies unset fields
+                              overrides=AttentionGeometry(block_q=16, policy="recompute"))
+    assert (src, g.block_q, g.policy) == ("explicit", 16, "recompute")
+    assert g.block_k == 128  # the winners file still supplies unset fields
 
 
 def test_cache_winner_clamped_to_divisors():
@@ -190,8 +180,8 @@ def test_cache_winner_clamped_to_divisors():
 def test_forward_only_override_keeps_shape_default_bwd():
     # overriding just the forward tiling must not disturb the backward's
     # shape-keyed defaults (the two passes prefer different partitionings)
-    ag.set_default_geometry("block_q=64,block_k=32")
-    g, _ = resolve_geometry(256, 256, 32, 2, 1, True)
+    g, _ = resolve_geometry(256, 256, 32, 2, 1, True,
+                            overrides=parse_spec("block_q=64,block_k=32"))
     assert (g.block_q, g.block_k) == (64, 32)
     base = ag.default_geometry(256, 256, 32, True)
     assert (g.block_q_bwd, g.block_k_bwd) == (base.block_q_bwd, base.block_k_bwd)
@@ -216,31 +206,32 @@ def test_store_and_reload_winner_roundtrip(tmp_path):
     assert ag.lookup_cached(sig, path=path) is None
 
 
-def test_env_cache_path_override(monkeypatch, tmp_path):
-    ag.set_cache_path(None)
-    p = tmp_path / "elsewhere.json"
-    monkeypatch.setenv(ag.ENV_CACHE, str(p))
-    assert ag.cache_path() == str(p)
-    sig = signature(64, 64, 16, 1, 1, True)
-    store_winner(sig, AttentionGeometry(block_q=32))
-    assert p.exists()
-    g, src = resolve_geometry(64, 64, 16, 1, 1, True)
-    assert (src, g.block_q) == ("cache", 32)
-
-
-def test_bad_env_spec_raises(monkeypatch):
-    monkeypatch.setenv(ag.ENV_BLOCKS, "block_q=nope")
-    with pytest.raises(ValueError, match=ag.ENV_BLOCKS):
-        resolve_geometry(128, 128, 32, 2, 1, True)
-
-
 def test_attention_config_block_installs_engine_default():
-    from deepspeed_tpu.runtime.config import AttentionConfig
+    """The engine's "attention" block reaches the kernel through the model
+    configuration: merged over the model's own ``attention_blocks`` spec on
+    a copy of the module, which hands it down as ``geometry_spec``."""
+    from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+    from deepspeed_tpu.models.common import attention_geometry_kwargs
+    from deepspeed_tpu.runtime.config import AttentionConfig, DeepSpeedConfig
+    from deepspeed_tpu.runtime.engine import _apply_program_knobs
+
     cfg = AttentionConfig(block_q=256, policy="recompute")
     assert cfg.geometry_fields() == {"block_q": 256, "policy": "recompute"}
-    ag.set_default_geometry(cfg.geometry_fields())
-    g, src = resolve_geometry(512, 512, 64, 4, 1, True)
-    assert (src, g.block_q, g.policy) == ("config", 256, "recompute")
+
+    model = GPT2LMHeadModel(get_gpt2_config(
+        "test", attention_backend="flash", attention_blocks="block_q=64,block_k=128"))
+    ds = DeepSpeedConfig({"train_batch_size": 8,
+                          "attention": {"block_q": 256, "policy": "recompute"}},
+                         dp_world_size=1)
+    built = _apply_program_knobs(model, ds)
+    assert model.config.attention_blocks == "block_q=64,block_k=128"
+    spec = attention_geometry_kwargs(built.config)["geometry_spec"]
+    assert parse_spec(spec) == AttentionGeometry(block_q=256, block_k=128,
+                                                 policy="recompute")
+    g, src = resolve_geometry(512, 512, 64, 4, 1, True, overrides=parse_spec(spec))
+    assert (src, g.block_q, g.block_k, g.policy) == ("explicit", 256, 128, "recompute")
+    # and it leaves nothing behind for a call that names no geometry
+    assert resolve_geometry(512, 512, 64, 4, 1, True)[1] == "default"
 
 
 def test_model_config_spec_overrides_resolution():

@@ -38,12 +38,10 @@ from deepspeed_tpu.runtime.pipe.module import PipelineModule
 
 @pytest.fixture(autouse=True)
 def _clear():
-    for env in ("DS_PIPE_SCHEDULE", "DS_PIPE_ACT_BUDGET_MB"):
-        os.environ.pop(env, None)
+    os.environ.pop("DS_PIPE_ACT_BUDGET_MB", None)
     set_topology(None)
     yield
-    for env in ("DS_PIPE_SCHEDULE", "DS_PIPE_ACT_BUDGET_MB"):
-        os.environ.pop(env, None)
+    os.environ.pop("DS_PIPE_ACT_BUDGET_MB", None)
     set_topology(None)
 
 
@@ -152,11 +150,6 @@ def test_schedule_knob_resolution():
     # liveness) when S does not divide M
     with pytest.raises(ValueError, match="chunk_microbatches"):
         _pipe_engine(schedule="chunked", gas=3, bs=6)
-    # env override drifts the resolved schedule but not the intent
-    os.environ["DS_PIPE_SCHEDULE"] = "chunked"
-    e, _, _ = _pipe_engine()
-    assert e.pipe_schedule == "chunked" and e.pipe_schedule_intent == "1f1b"
-    del os.environ["DS_PIPE_SCHEDULE"]
     with pytest.raises(ValueError, match="pipeline.schedule"):
         _pipe_engine(schedule="interleaved")
     # chunk under a non-chunked schedule is ignored with a warning

@@ -59,3 +59,77 @@ def test_round4_surfaces_resolve():
                 DSElasticAgent, touch_heartbeat, PartitionedParamSwapper,
                 stream_in, NVMeAdam):
         assert obj is not None
+
+
+# ---------------------------------------------------------------------------
+# the traced program is a function of configuration (docs/API.md): the
+# packages that build programs read nothing from the environment
+# ---------------------------------------------------------------------------
+PROGRAM_PACKAGES = ("models", "moe", "ops", "inference/serving", "runtime/pipe")
+
+#: (file, what it reads): paths and a lint budget, not program choices
+ENVIRONMENT_READS_ALLOWED = {
+    ("ops/op_builder/builder.py", "'DS_BUILD_CACHE'"),      # where host ops are built
+    ("ops/op_builder/builder.py", "'CXX'"),                 # the compiler that builds them
+    ("inference/serving/scheduler.py", "HEARTBEAT_ENV"),    # a supervisor's liveness file
+    ("runtime/pipe/engine.py", "'DS_PIPE_ACT_BUDGET_MB'"),  # graft-lint's budget (R010)
+}
+
+
+def _package_sources(package):
+    import os
+    root = os.path.join(os.path.dirname(ds.__file__), *package.split("/"))
+    for folder, _, files in os.walk(root):
+        for name in files:
+            if name.endswith(".py"):
+                path = os.path.join(folder, name)
+                rel = os.path.relpath(path, os.path.dirname(ds.__file__)).replace(os.sep, "/")
+                with open(path) as f:
+                    yield rel, f.read()
+
+
+def _environment_reads(source):
+    """What a module reads of ``os.environ``: the key's source text for a
+    ``.get(key)`` / ``[key]`` / ``os.getenv(key)``, ``<environ>`` for any
+    other use of it."""
+    import ast
+    tree = ast.parse(source)
+    keyed, reads = set(), []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and isinstance(node.func, ast.Attribute):
+            f = node.func
+            environ_get = (f.attr in ("get", "pop", "setdefault")
+                           and ast.unparse(f.value) == "os.environ")
+            if (environ_get or ast.unparse(f) == "os.getenv") and node.args:
+                reads.append(ast.unparse(node.args[0]))
+                keyed.add(id(f.value) if environ_get else id(f))
+        elif isinstance(node, ast.Subscript) and ast.unparse(node.value) == "os.environ":
+            reads.append(ast.unparse(node.slice))
+            keyed.add(id(node.value))
+    for node in ast.walk(tree):
+        if id(node) in keyed:
+            continue
+        if isinstance(node, ast.Attribute) and ast.unparse(node) in ("os.environ", "os.getenv"):
+            reads.append("<environ>")
+        if isinstance(node, ast.ImportFrom) and node.module == "os" and any(
+                a.name in ("environ", "getenv") for a in node.names):
+            reads.append("<environ>")
+    return reads
+
+
+@pytest.mark.parametrize("package", PROGRAM_PACKAGES)
+def test_program_packages_do_not_read_the_environment(package):
+    found = {(rel, key) for rel, source in _package_sources(package)
+             for key in _environment_reads(source)}
+    allowed = {(rel, key) for rel, key in ENVIRONMENT_READS_ALLOWED
+               if rel.startswith(package + "/")}
+    assert found == allowed
+
+
+def test_models_do_not_import_inference():
+    import ast
+    for rel, source in _package_sources("models"):
+        for node in ast.walk(ast.parse(source)):
+            names = ([a.name for a in node.names] if isinstance(node, ast.Import)
+                     else [node.module or ""] if isinstance(node, ast.ImportFrom) else [])
+            assert not [n for n in names if n.startswith("deepspeed_tpu.inference")], rel

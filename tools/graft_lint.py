@@ -5,9 +5,10 @@ scenarios.py) on CPU — no compilation, <2 min — runs every registered
 rule (R001..R008, deepspeed_tpu/analysis/rules.py + source_rules.py),
 writes ``analysis_results/lint_<sig>.json``, and exits non-zero when a
 NEW unwaived ERROR appears relative to the committed baseline
-(``analysis_results/baseline.json``). A seeded regression — e.g. forcing
-the dense MoE dispatch with ``DS_MOE_ROUTE=dense`` — must fail this
-gate; that is the acceptance check.
+(``analysis_results/baseline.json``). A seeded regression — e.g. the MoE
+scenarios built with the dense dispatch (a test patches
+``analysis.scenarios.SCENARIO_CONFIG``) — must fail this gate; that is
+the acceptance check.
 
 ``--cost`` adds the graft-audit pass (deepspeed_tpu/analysis/cost.py):
 per program, a jaxpr-liveness static memory estimate + the three-layer
@@ -24,23 +25,18 @@ against the committed ``analysis_results/search_pareto.json`` (rule
 R014): a drifted candidate set, a committed Pareto winner whose static
 price moves >5%, or a winner that is now dominated fails the gate.
 ``--search`` forces the pass on scenario subsets; ``--no-search`` skips
-it; seeded regression: ``DS_LMHEAD_CHUNK=16 python tools/graft_lint.py
---cost`` (the env layer drifts every candidate's traced program, so the
-committed winners' prices move and R014 exits 1 — the DS_MOE_ROUTE
-pattern). Bank frontier changes with ``tools/graft_search.py --update``,
+it. Bank frontier changes with ``tools/graft_search.py --update``,
 never here. The same full-matrix runs judge the committed measured-mode
 calibration with rule R016 (deepspeed_tpu/analysis/calibrate.py):
 perturbed coefficients, a stale jax signature, or a stale
 ``predicted_seconds`` frontier re-rank vs
 ``analysis_results/cost_calibration.json`` fail the gate; bank with
 ``tools/graft_calibrate.py fit --update``.
-Seeded cost regressions: ``DS_MOE_ROUTE=dense`` (R009 route-signature
-drift + the dense-einsum memory delta), ``DS_PIPE_ACT_BUDGET_MB=2``
-on ``pipe_chunked_step`` (R010: the chunked schedule cannot fit the
-1F1B activation budget the ``pipe_1f1b_step`` scenario passes), and
-``DS_PIPE_SCHEDULE=chunked`` on ``pipe_1f1b_step`` (R009: the program
-drifts but the stamped collective signature pins the config-committed
-schedule intent — 4 ``collective_permute`` sites vs the drifted 2).
+Seeded cost regressions: the dense MoE route patched into the scenarios
+(R009 route-signature drift + the dense-einsum memory delta) and
+``DS_PIPE_ACT_BUDGET_MB=2`` on ``pipe_chunked_step`` (R010: the chunked
+schedule cannot fit the 1F1B activation budget the ``pipe_1f1b_step``
+scenario passes).
 
 Usage:
   python tools/graft_lint.py                         # full matrix + AST, gate vs baseline

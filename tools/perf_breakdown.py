@@ -86,7 +86,7 @@ def moe_sections():
     S = MB * SEQ                                          # tokens per group (G=1)
     F = 4 * M
     C = _capacity(S, E, (2 * CF) if K == 2 else CF, 4)
-    impl = resolve_impl(os.environ.get("DS_MOE_KERNEL", "auto"))
+    impl = resolve_impl("auto")
     routes = os.environ.get("BENCH_MOE_ROUTES", "dense,sorted").split(",")
     dt = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
     print(f"# moe breakdown M={M} E={E} k={K} cf={CF} S={S} C={C} "

@@ -118,7 +118,7 @@ def _traced_programs_evidence(engine, batch):
 
 def attn_geometry_evidence(cfg, mb, seq):
     """Which flash-attention geometry this rung ran, and which resolution
-    layer picked it (explicit/env/config/cache/default) — rows regenerate
+    layer picked it (explicit/cache/default) — rows regenerate
     the PERF.md long-context table, so the chosen partitioning must ride
     next to the TFLOPS it produced."""
     if getattr(cfg, "attention_backend", None) != "flash":
@@ -143,20 +143,14 @@ def attn_geometry_evidence(cfg, mb, seq):
 
 
 def moe_route_evidence(cfg):
-    """Which MoE dispatch/combine route this rung ran and which resolution
-    layer picked it (explicit/env/config/default) — the dense-vs-sorted A/B
-    rows regenerate PERF.md's MoE table, so the route must ride next to the
-    TFLOPS it produced (same contract as attn_geometry_source)."""
+    """Which MoE dispatch/combine route this rung's model configuration
+    names — the dense-vs-sorted A/B rows regenerate PERF.md's MoE table, so
+    the route must ride next to the TFLOPS it produced."""
     if not getattr(cfg, "moe_num_experts", 0):
         return {}
-    try:
-        from deepspeed_tpu.moe.routing import resolve_route
-        route, kernel, src = resolve_route(getattr(cfg, "moe_route", None))
-        return {"moe_route": route, "moe_route_source": src,
-                "moe_kernel": kernel if route == "sorted" else None}
-    except Exception as e:  # evidence must never kill a rung
-        return {"moe_route": f"error: {type(e).__name__}: {str(e)[:120]}",
-                "moe_route_source": "error"}
+    route = cfg.moe_route
+    return {"moe_route": route,
+            "moe_kernel": cfg.moe_route_kernel if route == "sorted" else None}
 
 
 def telemetry_evidence(engine):
@@ -273,7 +267,7 @@ RUNGS = {
                           cfg_overrides=dict(moe_num_experts=8,
                                              moe_layer_freq=2, moe_k=1)),
     # dispatch-route A/B at the same operating point: 125m_moe8_mb8 runs
-    # the resolved default (sorted unless overridden); this rung pins the
+    # the default (sorted); this rung pins the
     # dense einsum route so the sorted-route gain is measured in one window
     # (ROADMAP 3c: >=58 active-TFLOPS target, from 48.8 dense)
     "125m_moe8_mb8_dense": dict(model_name="125m", mb=8, fused_xent=True,
@@ -301,7 +295,7 @@ RUNGS = {
     # VMEM no longer caps sequence length; fused xent keeps the logits
     # buffers off the OOM line at long L. Rows report the chosen attention
     # block geometry + its source — run tools/attn_tune.py first to bank
-    # shape-keyed winners, or force one via DS_ATTN_BLOCKS.
+    # shape-keyed winners, or pin one in the rung's attention_blocks.
     "350m_seq2k": dict(model_name="350m", mb=4, seq=2048, fused_xent=True),
     "350m_seq4k": dict(model_name="350m", mb=2, seq=4096, fused_xent=True),
     "350m_seq8k": dict(model_name="350m", mb=1, seq=8192, fused_xent=True),
@@ -666,7 +660,8 @@ def main():
                 # rung must be attributable to the route that failed it)
                 class _C:  # minimal cfg shim for the evidence helper
                     moe_num_experts = cfg_ov["moe_num_experts"]
-                    moe_route = cfg_ov.get("moe_route")
+                    moe_route = cfg_ov.get("moe_route", "sorted")
+                    moe_route_kernel = cfg_ov.get("moe_route_kernel", "auto")
                 row.update(moe_route_evidence(_C))
             print(json.dumps(row), flush=True)
             traceback.print_exc(file=sys.stderr)

@@ -236,7 +236,7 @@ def serve_evidence(engine, slots, wq="fp", kv_quant=False):
 
 def run_continuous(engine, cfg, trace, drafter=None, telemetry=None,
                    wq=None, kv_quant=None, pool_bytes=None, label="continuous",
-                   collect_outputs=False, prefix_cache=None):
+                   collect_outputs=False, prefix_cache="on"):
     from deepspeed_tpu.inference.serving import (ContinuousBatchingScheduler,
                                                  Request, ServingConfig)
 
@@ -245,14 +245,10 @@ def run_continuous(engine, cfg, trace, drafter=None, telemetry=None,
         slots=SLOTS, page_size=16,
         kv_pool_tokens=POOL_TOKENS or None,
         kv_pool_bytes=(POOL_BYTES or None) if pool_bytes is None else pool_bytes,
-        # explicit wq (the quant_ab arms) is passed verbatim as the config
-        # layer; env-driven runs map SERVE_WQ=fp to None. DS_SERVE_WQ
-        # still outranks either — the drift seam is deliberate, and lint
-        # (not the bench) is what catches a leaked env
-        weight_dtype=(None if WQ == "fp" else WQ) if wq is None else wq,
+        # the quant_ab arms pass wq; every other run serves SERVE_WQ
+        weight_dtype=WQ if wq is None else wq,
         kv_quant=KV_QUANT if kv_quant is None else kv_quant,
-        # None = the DS_SERVE_PREFIX_CACHE/config resolution (default on);
-        # the prefix_ab arms pin "on"/"off" explicitly
+        # the prefix_ab arms pass "on"/"off"
         prefix_cache=prefix_cache,
         prefill_chunk=CHUNK if CHUNK > 0 else n_positions,
         speculation={"enabled": drafter is not None, "k": SPEC_K})
@@ -289,10 +285,8 @@ def run_continuous(engine, cfg, trace, drafter=None, telemetry=None,
         "ttft": _lat_row(stats["ttft"]), "per_token": _lat_row(stats["per_token"]),
         "ticks": stats["ticks"], "pool": stats["pool"],
         "weight_dtype": stats["weight_dtype"],
-        "weight_dtype_source": stats["weight_dtype_source"],
         "kv_quant": stats["kv_quant"],
         "prefix_cache": stats["prefix_cache"],
-        "prefix_cache_source": stats["prefix_cache_source"],
         "cached_prefix_tokens": stats["cached_prefix_tokens"],
         "prefix_hit_rate": stats["pool"].get("prefix_hit_rate"),
         "chunked_prefill": CHUNK > 0, "prefill_chunk": CHUNK or n_positions,
@@ -631,13 +625,12 @@ def main():
             output_path=os.environ.get("SERVE_TELEMETRY_DIR",
                                        "/tmp/ds_tpu_serve_telemetry"),
             job_name=f"serve_{MODEL}_qps{QPS}"))
-        from deepspeed_tpu.inference.serving import resolve_prefix_cache
         # graft-calibrate separation markers (same contract as the fleet
         # worker's header): the field's presence keys collect_samples'
         # mixed-run refusal for serve-scope samples
         telemetry.write_run_header({"bench": "serve_bench", "model": MODEL,
                                     "qps": QPS, "slots": SLOTS,
-                                    "prefix_cache": resolve_prefix_cache(None)[0],
+                                    "prefix_cache": "on",
                                     "cached_prefix_tokens": 0})
 
     rows = {}
