@@ -2,8 +2,8 @@
 attention engine.
 
 The kernel in ``flash_attention.py`` is parameterized over its work
-partitioning — forward and backward (q, kv) block sizes, the backward's
-causal work-skipping granularity, and whether the backward recomputes the
+partitioning — forward and backward (q, kv) BLOCK sizes (what one grid step
+stages in VMEM), the compute TILE a block is walked in, and whether the backward recomputes the
 log-sum-exp or reads it from a stashed residual. Which combination is
 fastest depends on the call shape (FlashAttention-2: the partitioning, not
 the algorithm, is where the last 1.5-2x lives), so resolution is layered:
@@ -11,17 +11,19 @@ the algorithm, is where the last 1.5-2x lives), so resolution is layered:
 1. explicit per-call kwargs (``block_q=...`` etc.) and the model
    configuration's ``attention_blocks`` spec, which is where the engine's
    ``"attention"`` JSON config block lands (``runtime/engine.py``);
-2. a shape-keyed winners cache written by the kernel autotuner
-   (``autotuning/attention_tuner.py``; default
-   ``autotuning_results/attention_blocks.json``);
-3. shape-keyed static defaults for TPU v5e (:func:`default_geometry`).
+2. a shape-keyed winners file written by the kernel autotuner
+   (``autotuning/attention_tuner.py``), where one exists at
+   ``autotuning_results/attention_blocks.json`` or :func:`set_cache_path`
+   points at one — none is committed: the winners measured on a v5e are the
+   defaults below;
+3. shape-keyed defaults, the v5e's measured winners (:func:`default_geometry`).
 
 This module is import-light on purpose (no jax/pallas): the engine and the
 bench tools consult it without paying for a Pallas import.
 
 Spec grammar (config strings and cache entries share it):
-``"block_q=512,block_k=1024,block_q_bwd=256,block_k_bwd=512,``
-``bwd_skip=block,policy=lse"`` — any subset of fields; a bare pair of ints
+``"block_q=1024,block_k=1024,block_q_bwd=1024,block_k_bwd=1024,tile=512,``
+``policy=lse"`` — any subset of fields; a bare pair of ints
 ``"512,1024"`` means forward ``block_q,block_k``.
 """
 
@@ -31,11 +33,6 @@ import os
 import threading
 from typing import Any, Dict, Optional, Tuple
 
-#: causal work-skipping granularity in the backward pass: "block" gates
-#: each grid step's FLOPs/DMA behind a liveness predicate (skips the dead
-#: triangle), "none" runs every step and relies on masking alone — cheaper
-#: scalar path, sometimes wins at short sequence lengths.
-BWD_SKIP_CHOICES = ("block", "none")
 #: backward recompute policy: "lse" stashes the [B,H,L] log-sum-exp residual
 #: in forward and reads it back; "recompute" stashes nothing extra and
 #: re-runs the forward kernel inside the backward to regenerate it —
@@ -43,7 +40,7 @@ BWD_SKIP_CHOICES = ("block", "none")
 #: footprint (matters under remat at long L).
 POLICY_CHOICES = ("lse", "recompute")
 
-_FIELDS = ("block_q", "block_k", "block_q_bwd", "block_k_bwd", "bwd_skip", "policy")
+_FIELDS = ("block_q", "block_k", "block_q_bwd", "block_k_bwd", "tile", "policy")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,7 +52,7 @@ class AttentionGeometry:
     block_k: Optional[int] = None
     block_q_bwd: Optional[int] = None
     block_k_bwd: Optional[int] = None
-    bwd_skip: Optional[str] = None
+    tile: Optional[int] = None
     policy: Optional[str] = None
 
     def merged_over(self, base: "AttentionGeometry") -> "AttentionGeometry":
@@ -76,13 +73,10 @@ class AttentionGeometry:
         return ",".join(f"{k}={v}" for k, v in self.as_dict().items())
 
     def validate(self) -> "AttentionGeometry":
-        for f in ("block_q", "block_k", "block_q_bwd", "block_k_bwd"):
+        for f in ("block_q", "block_k", "block_q_bwd", "block_k_bwd", "tile"):
             v = getattr(self, f)
             if v is not None and (not isinstance(v, int) or v <= 0):
                 raise ValueError(f"attention geometry: {f} must be a positive int, got {v!r}")
-        if self.bwd_skip is not None and self.bwd_skip not in BWD_SKIP_CHOICES:
-            raise ValueError(f"attention geometry: bwd_skip must be one of "
-                             f"{BWD_SKIP_CHOICES}, got {self.bwd_skip!r}")
         if self.policy is not None and self.policy not in POLICY_CHOICES:
             raise ValueError(f"attention geometry: policy must be one of "
                              f"{POLICY_CHOICES}, got {self.policy!r}")
@@ -116,7 +110,7 @@ def parse_spec(spec: str) -> AttentionGeometry:
         if "=" not in p:
             raise ValueError(f"attention geometry spec {spec!r}: mixed bare/keyed fields")
         k, v = (s.strip() for s in p.split("=", 1))
-        d[k] = v if k in ("bwd_skip", "policy") else int(v)
+        d[k] = v if k == "policy" else int(v)
     return from_dict(d)
 
 
@@ -136,34 +130,31 @@ def signature(lq: int, lk: int, head_dim: int, heads: int, batch: int,
 
 def pick_block(length: int, preferred: int = 512) -> int:
     """Largest block from the standard chain that tiles ``length``."""
-    for blk in sorted({preferred, 1024, 512, 256, 128, 64, 32, 16, 8}, reverse=True):
+    for blk in sorted({preferred, 2048, 1024, 512, 256, 128, 64, 32, 16, 8}, reverse=True):
         if blk <= preferred and blk <= length and length % blk == 0:
             return blk
     return length
 
 
 def default_geometry(lq: int, lk: int, head_dim: int, causal: bool) -> AttentionGeometry:
-    """Shape-keyed static defaults for TPU v5e.
+    """Shape-keyed defaults: the winners of the tuner's sweeps on a TPU v5e
+    (bf16, causal, head 64; ``PERF.md`` section 6, PR 29).
 
-    Under 2k the historical symmetric 512/512 tiling (fwd == bwd) is kept
-    bit-for-bit — it is the judged-config operating point. At 4k+ the
-    forward doubles the kv tile when head_dim <= 64 (halves grid steps and
-    per-step scalar overhead; scores tile 512x1024 fp32 = 2 MiB, well
-    inside VMEM) and the backward goes asymmetric (smaller q tiles for the
-    dkv pass, FlashAttention-2's partitioning) — heuristics the autotuner's
-    measured winners override per shape.
+    A block is the whole sequence where that fits — up to 2,048 rows in the
+    forward at heads of 64, 1,024 otherwise and in the backward — and is
+    walked in compute tiles of 512: at 1,024 positions (8 x 16 heads and
+    4 x 25) one grid step a head with whole-sequence blocks beat every
+    split of it in all three kernels, at 2,048 and 4,096 the forward won at
+    2,048 x 2,048 and the backward at 1,024 x 1,024, and the tile of 512
+    won at every block size. Longer sequences stream blocks of those sizes.
     """
-    if lk >= 4096:
-        want_q, want_k = 512, (1024 if head_dim <= 64 else 512)
-        want_qb, want_kb = 256, 512
-    else:
-        want_q = want_k = want_qb = want_kb = 512
+    want = 2048 if head_dim <= 64 else 1024
     return AttentionGeometry(
-        block_q=pick_block(lq, want_q),
-        block_k=pick_block(lk, want_k),
-        block_q_bwd=pick_block(lq, want_qb),
-        block_k_bwd=pick_block(lk, want_kb),
-        bwd_skip="block",
+        block_q=pick_block(lq, want),
+        block_k=pick_block(lk, want),
+        block_q_bwd=pick_block(lq, 1024),
+        block_k_bwd=pick_block(lk, 1024),
+        tile=512,
         policy="lse",
     )
 
@@ -289,7 +280,7 @@ def resolve_geometry(lq: int, lk: int, head_dim: int, heads: int, batch: int,
         block_k=pick_block(lk, geom.block_k),
         block_q_bwd=pick_block(lq, geom.block_q_bwd),
         block_k_bwd=pick_block(lk, geom.block_k_bwd),
-        bwd_skip=geom.bwd_skip,
+        tile=geom.tile,
         policy=geom.policy,
     )
     return geom.validate(), source

@@ -73,15 +73,14 @@ class AttentionConfig(DeepSpeedConfigModel):
 
     Every field is optional. Set fields are applied by the engine onto
     the model config's ``attention_blocks`` spec (``dataclasses.replace`` +
-    ``module.clone``, as the "program" block); unset ones resolve through
-    the autotuner's shape-keyed winners cache, then v5e shape defaults —
-    see ``ops/pallas/attention_geometry.py``. ``cache_file`` repoints the
-    winners cache (default ``autotuning_results/attention_blocks.json``)."""
+    ``module.clone``, as the "program" block); unset ones take the shape's
+    defaults, measured on a v5e — see ``ops/pallas/attention_geometry.py``.
+    ``cache_file`` points at a tuner's winners file (none is committed;
+    ``autotuning_results/attention_blocks.json`` where one exists)."""
     block_q: Optional[int] = Field(None, ge=8)
     block_k: Optional[int] = Field(None, ge=8)
     block_q_bwd: Optional[int] = Field(None, ge=8)
     block_k_bwd: Optional[int] = Field(None, ge=8)
-    bwd_skip: Optional[str] = None      # "block" | "none"
     policy: Optional[str] = None        # "lse" | "recompute"
     cache_file: Optional[str] = None
 
@@ -89,7 +88,7 @@ class AttentionConfig(DeepSpeedConfigModel):
         return {k: v for k, v in dict(
             block_q=self.block_q, block_k=self.block_k,
             block_q_bwd=self.block_q_bwd, block_k_bwd=self.block_k_bwd,
-            bwd_skip=self.bwd_skip, policy=self.policy).items() if v is not None}
+            policy=self.policy).items() if v is not None}
 
 
 class MoEConfig(DeepSpeedConfigModel):

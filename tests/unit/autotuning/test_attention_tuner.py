@@ -28,9 +28,9 @@ def test_sweep_persists_winner_and_kernel_reloads_it(tmp_path):
     exps = tmp_path / "exps"
     cands = [
         AttentionGeometry(block_q=32, block_k=32, block_q_bwd=32,
-                          block_k_bwd=32, bwd_skip="block", policy="lse"),
+                          block_k_bwd=32, tile=16, policy="lse"),
         AttentionGeometry(block_q=64, block_k=64, block_q_bwd=64,
-                          block_k_bwd=64, bwd_skip="none", policy="recompute"),
+                          block_k_bwd=64, tile=32, policy="recompute"),
     ]
     tuner = AttentionBlockTuner(results_dir=str(results), exps_dir=str(exps),
                                 repeats=1, candidates=cands, interpret=True)
@@ -57,7 +57,7 @@ def test_sweep_persists_winner_and_kernel_reloads_it(tmp_path):
     geom, src = resolve_geometry(64, 64, 8, 1, 1, True, jnp.dtype(jnp.float32))
     assert src == "cache"
     assert all(getattr(geom, f) == getattr(best, f)
-               for f in ("block_q", "block_k", "bwd_skip", "policy"))
+               for f in ("block_q", "block_k", "tile", "policy"))
 
 
 def test_failed_candidates_prune_cleanly(tmp_path):
@@ -89,12 +89,13 @@ def test_default_sweep_is_staged(tmp_path):
     assert best is not None
     stages = [r["stage"] for r in records]
     assert set(stages) == {"fwd", "train"}
-    from deepspeed_tpu.autotuning.attention_tuner import candidate_axes
-    fwd_pairs, bwd_pairs, skips = candidate_axes(64, 64, 8, True, itemsize=4)
-    assert stages.count("fwd") == len(fwd_pairs)
-    assert stages.count("train") == len(bwd_pairs) * len(skips) * 2
+    from deepspeed_tpu.autotuning.attention_tuner import candidate_axes, tile_axis
+    fwd_pairs, bwd_pairs = candidate_axes(64, 64, 8, True, itemsize=4)
+    tiles = tile_axis(64, 64)
+    assert stages.count("fwd") == len(fwd_pairs) * len(tiles)
+    assert stages.count("train") == len(bwd_pairs) * len(tiles) * 2
     # the banked winner carries stage-2 (fwd+bwd) timing and full geometry
-    assert (best.block_q_bwd, best.bwd_skip) != (None, None)
+    assert (best.block_q_bwd, best.tile) != (None, None)
     # forward-only tune stops after stage 1
     tuner2 = AttentionBlockTuner(results_dir=str(tmp_path / "r2"),
                                  exps_dir=str(tmp_path / "e2"),
@@ -109,10 +110,10 @@ def test_default_candidates_respect_divisibility_and_budget():
     assert len(cands) > 4
     for c in cands:
         assert 2048 % c.block_q == 0 and 2048 % c.block_k == 0
-        assert c.bwd_skip in ("block", "none") and c.policy in ("lse", "recompute")
-    # non-causal shapes skip the causal-skip axis
-    nc = default_candidates(2048, 2048, 64, causal=False)
-    assert all(c.bwd_skip == "block" for c in nc)
+        assert c.tile in (128, 256, 512) and c.policy in ("lse", "recompute")
+    # the tile axis never exceeds the sequence
+    assert all(c.tile == 256 for c in default_candidates(256, 256, 64, causal=False)
+               if c.tile > 128)
     # tiny shapes degrade to the full-length block, never zero candidates
     tiny = default_candidates(64, 64, 8, causal=True)
     assert tiny and all(c.block_q == 64 for c in tiny)

@@ -39,22 +39,22 @@ def _rand_qkv(seed, b, l, h, d, dtype=jnp.float32):
     return q, k, v
 
 
-# geometry x policy grid: asymmetric fwd/bwd blocks, both causal-skip
-# granularities, both recompute policies (>= 6 combos per the acceptance
+# geometry x policy grid: asymmetric fwd/bwd blocks, compute tiles of a
+# block's size and below it, both recompute policies (>= 6 combos per the acceptance
 # criteria; every one must match the XLA reference in fwd AND grads)
 GEOMETRIES = [
     dict(block_q=64, block_k=64, block_q_bwd=64, block_k_bwd=64,
-         bwd_skip="block", policy="lse"),
+         tile=64, policy="lse"),
     dict(block_q=64, block_k=128, block_q_bwd=32, block_k_bwd=64,
-         bwd_skip="block", policy="lse"),
+         tile=32, policy="lse"),
     dict(block_q=128, block_k=64, block_q_bwd=64, block_k_bwd=32,
-         bwd_skip="none", policy="lse"),
+         tile=16, policy="lse"),
     dict(block_q=64, block_k=64, block_q_bwd=64, block_k_bwd=64,
-         bwd_skip="block", policy="recompute"),
+         tile=32, policy="recompute"),
     dict(block_q=128, block_k=128, block_q_bwd=32, block_k_bwd=32,
-         bwd_skip="none", policy="recompute"),
+         tile=64, policy="recompute"),
     dict(block_q=32, block_k=64, block_q_bwd=128, block_k_bwd=64,
-         bwd_skip="block", policy="recompute"),
+         tile=16, policy="recompute"),
 ]
 
 
@@ -86,23 +86,24 @@ def test_geometry_policy_parity_fwd_and_grads(geom, causal):
                                    err_msg=f"d{name} mismatch for {geom}")
 
 
-@pytest.mark.parametrize("bwd_skip", ["block", "none"])
-def test_kv_lengths_parity_across_skip_policies(bwd_skip):
-    # the masked (right-padded) path drives the skip predicates hardest:
-    # dead K blocks must contribute exactly zero either way
+@pytest.mark.parametrize("tile", [32, 16])
+def test_kv_lengths_parity_across_tiles(tile):
+    # the masked (right-padded) path drives the walk's bounds hardest: they
+    # are run-time scalars, and dead K tiles must contribute exactly zero
+    # whether a block is one tile or several
     q, k, v = _rand_qkv(3, 2, 128, 2, 32)
     kv_lengths = jnp.array([96, 40], jnp.int32)
     ref_fn = _loss(lambda q, k, v: dot_product_attention(
         q, k, v, backend="xla", causal=True, kv_lengths=kv_lengths))
     fl_fn = _loss(lambda q, k, v: dot_product_attention(
         q, k, v, backend="flash", causal=True, kv_lengths=kv_lengths,
-        block_q=32, block_k=32, bwd_skip=bwd_skip, policy="recompute"))
+        block_q=32, block_k=32, tile=tile, policy="recompute"))
     ref_g = jax.grad(ref_fn, argnums=(0, 1, 2))(q, k, v)
     fl_g = jax.grad(fl_fn, argnums=(0, 1, 2))(q, k, v)
     for rg, fg, name in zip(ref_g, fl_g, "qkv"):
         np.testing.assert_allclose(np.asarray(fg), np.asarray(rg),
                                    atol=5e-5, rtol=5e-5,
-                                   err_msg=f"d{name} mismatch (skip={bwd_skip})")
+                                   err_msg=f"d{name} mismatch (tile={tile})")
 
 
 def test_recompute_policy_stashes_no_lse_residual():
@@ -111,11 +112,9 @@ def test_recompute_policy_stashes_no_lse_residual():
     from deepspeed_tpu.ops.pallas.flash_attention import _flash_attention_bhld_fwd
     q, k, v = _rand_qkv(4, 1, 64, 1, 32)
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
-    common = (None, 32**-0.5, True, 32, 32, 32, 32)
-    _, res_lse = _flash_attention_bhld_fwd(qt, kt, vt, *common, "block", "lse",
-                                           True, None)
-    _, res_rec = _flash_attention_bhld_fwd(qt, kt, vt, *common, "block",
-                                           "recompute", True, None)
+    common = (None, 32**-0.5, True, 32, 32, 32, 32, 32)
+    _, res_lse = _flash_attention_bhld_fwd(qt, kt, vt, *common, "lse", True, None)
+    _, res_rec = _flash_attention_bhld_fwd(qt, kt, vt, *common, "recompute", True, None)
     assert res_lse[4] is not None and res_lse[4].shape == (1, 1, 64)
     assert res_rec[4] is None
 
@@ -124,8 +123,8 @@ def test_recompute_policy_stashes_no_lse_residual():
 # spec grammar + resolution layering
 # ---------------------------------------------------------------------------
 def test_parse_spec_grammar():
-    g = parse_spec("block_q=512,block_k=1024,bwd_skip=none,policy=recompute")
-    assert (g.block_q, g.block_k, g.bwd_skip, g.policy) == (512, 1024, "none", "recompute")
+    g = parse_spec("block_q=512,block_k=1024,tile=256,policy=recompute")
+    assert (g.block_q, g.block_k, g.tile, g.policy) == (512, 1024, 256, "recompute")
     assert parse_spec("512,1024") == AttentionGeometry(block_q=512, block_k=1024)
     assert parse_spec("256") == AttentionGeometry(block_q=256, block_k=256)
     assert parse_spec("") == AttentionGeometry()
@@ -133,19 +132,44 @@ def test_parse_spec_grammar():
     with pytest.raises(ValueError):
         parse_spec("block_q=512,oops=1")
     with pytest.raises(ValueError):
-        parse_spec("bwd_skip=sometimes")
+        parse_spec("policy=sometimes")
     with pytest.raises(ValueError):
         parse_spec("block_q=-8")
 
 
 def test_default_geometry_shape_keyed():
-    short, _ = resolve_geometry(1024, 1024, 64, 16, 8, True)
-    assert (short.block_q, short.block_k) == (512, 512)  # judged-config point
+    """The v5e's winners (PERF.md section 6, PR 29), as code."""
+    for heads, batch in ((16, 8), (25, 4)):  # the two training cells' call shapes
+        cell, src = resolve_geometry(1024, 1024, 64, heads, batch, True)
+        assert src == "default"
+        assert cell == AttentionGeometry(block_q=1024, block_k=1024, block_q_bwd=1024,
+                                         block_k_bwd=1024, tile=512, policy="lse")
     lng, _ = resolve_geometry(8192, 8192, 64, 16, 1, True)
-    assert lng.block_k == 1024  # head_dim<=64 doubles the kv tile at 4k+
-    assert lng.block_q_bwd < lng.block_q  # FA-2 asymmetric backward
+    assert (lng.block_q, lng.block_k) == (2048, 2048)  # the forward streams larger blocks
+    assert (lng.block_q_bwd, lng.block_k_bwd, lng.tile) == (1024, 1024, 512)
     wide, _ = resolve_geometry(8192, 8192, 128, 16, 1, True)
-    assert wide.block_k == 512  # wide heads keep the smaller tile
+    assert (wide.block_q, wide.block_k) == (1024, 1024)  # wide heads: half the rows a block
+
+
+@pytest.mark.parametrize("length", [1000, 8192])
+def test_geometry_tuned_at_1024_clamps_elsewhere(length):
+    """A pin tuned at 1,024 positions still tiles 1,000 and 8,192: blocks
+    clamp to divisors of the sequence, the compute tile to divisors of the
+    blocks, and the kernel runs."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _tiles
+    pin = parse_spec("block_q=1024,block_k=1024,block_q_bwd=1024,block_k_bwd=1024,tile=512")
+    g, src = resolve_geometry(length, length, 64, 16, 1, True, overrides=pin)
+    assert src == "explicit"
+    for blk in (g.block_q, g.block_k, g.block_q_bwd, g.block_k_bwd):
+        assert length % blk == 0 and blk <= 1024
+    for tile in _tiles(g.block_q, g.block_k, g.tile) + _tiles(g.block_q_bwd, g.block_k_bwd, g.tile):
+        assert tile <= 512 and g.block_q % tile == 0 and g.block_k % tile == 0
+    if length == 1000:  # small enough to run here: 8 x 125, the largest chain block that divides
+        q, k, v = _rand_qkv(11, 1, length, 1, 16)
+        ref = dot_product_attention(q, k, v, backend="xla", causal=True)
+        out = dot_product_attention(q, k, v, backend="flash", causal=True,
+                                    geometry_spec=pin.spec())
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
 def test_resolution_precedence_env_config_cache_default():
@@ -185,13 +209,13 @@ def test_forward_only_override_keeps_shape_default_bwd():
     assert (g.block_q, g.block_k) == (64, 32)
     base = ag.default_geometry(256, 256, 32, True)
     assert (g.block_q_bwd, g.block_k_bwd) == (base.block_q_bwd, base.block_k_bwd)
-    assert (g.bwd_skip, g.policy) == ("block", "lse")
+    assert (g.tile, g.policy) == (base.tile, "lse")
 
 
 def test_store_and_reload_winner_roundtrip(tmp_path):
     path = str(tmp_path / "winners.json")
     sig = signature(512, 512, 64, 4, 2, False, jnp.dtype(jnp.bfloat16))
-    geom = AttentionGeometry(block_q=128, block_k=256, bwd_skip="none",
+    geom = AttentionGeometry(block_q=128, block_k=256, tile=128,
                              policy="recompute")
     store_winner(sig, geom, path=path, seconds=0.012, backend="cpu")
     with open(path) as f:

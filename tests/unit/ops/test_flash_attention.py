@@ -29,25 +29,148 @@ def test_flash_forward_matches_xla(shape, causal):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_backward_matches_xla(causal):
+# Every kind of tile (dead, interior, edge) in the forward and in both
+# backward kernels, against the XLA reference. ``blocks`` are explicit block
+# kwargs; a case without them runs the shape's default geometry, whose
+# blocks are walked in compute tiles of 256: at 1,024 positions a causal
+# head has 6 dead, 6 interior and 4 edge tiles.
+_PARITY_CASES = {
+    "causal": dict(shape=(1, 128, 2, 32), blocks=32),
+    "full": dict(shape=(1, 128, 2, 32), blocks=32, causal=False),
+    "defaults_causal": dict(shape=(1, 1024, 2, 32)),
+    "defaults_lq_lt_lk": dict(shape=(1, 512, 1, 32), lk=1024),
+    # prefixes end inside a tile (300), on a tile's edge (256), before one (200)
+    "defaults_kv_lengths": dict(shape=(4, 512, 1, 32), causal=False,
+                                kv_lengths=[200, 256, 300, 512]),
+    "defaults_causal_kv_lengths": dict(shape=(2, 768, 1, 32), kv_lengths=[300, 700]),
+    # the window's lower edge cuts tiles 0 and 1 of the last q tile, 2 is interior
+    "defaults_window": dict(shape=(1, 1024, 1, 32), window=600),
+    "defaults_bf16": dict(shape=(1, 1024, 2, 64), dtype=jnp.bfloat16, tol=3e-2),
+    "defaults_bf16_head128": dict(shape=(1, 512, 1, 128), dtype=jnp.bfloat16, tol=3e-2),
+    "small_tile_window": dict(shape=(1, 512, 1, 32), window=200, tile=64),
+}
+
+
+@pytest.mark.parametrize("case", list(_PARITY_CASES))
+def test_flash_backward_matches_xla(case):
+    spec = dict(_PARITY_CASES[case])
+    b, lq, h, d = spec.pop("shape")
+    lk = spec.pop("lk", lq)
+    dtype = spec.pop("dtype", jnp.float32)
+    tol = spec.pop("tol", None)
+    causal = spec.pop("causal", True)
+    blocks = spec.pop("blocks", None)
+    if blocks:
+        spec.update(block_q=blocks, block_k=blocks)
+    lengths = spec.pop("kv_lengths", None)
+    window = spec.pop("window", None)
     rng = np.random.default_rng(1)
-    q, k, v = _rand_qkv(rng, 1, 128, 2, 32)
+    q = jnp.asarray(rng.standard_normal((b, lq, h, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((b, lk, h, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, lk, h, d)), dtype)
+    masks = dict(causal=causal, window=window)
+    row_ok = True
+    if lengths is not None:
+        masks["kv_lengths"] = jnp.asarray(lengths, jnp.int32)
+        # only rows inside each sequence's valid prefix are meaningful
+        row_ok = (jnp.arange(lq)[None, :] < masks["kv_lengths"][:, None])[..., None, None]
 
     def loss(fn):
         def wrapped(q, k, v):
-            o = fn(q, k, v)
-            return (o * jnp.sin(jnp.arange(o.size).reshape(o.shape))).sum()
+            o = jnp.where(row_ok, fn(q, k, v), 0).astype(jnp.float32)
+            return (o * jnp.sin(jnp.arange(o.size).reshape(o.shape))).sum(), o
         return wrapped
 
-    ref_fn = loss(lambda q, k, v: dot_product_attention(q, k, v, backend="xla", causal=causal))
-    fl_fn = loss(lambda q, k, v: dot_product_attention(q, k, v, backend="flash", causal=causal,
-                                                       block_q=32, block_k=32))
-    ref_grads = jax.grad(ref_fn, argnums=(0, 1, 2))(q, k, v)
-    fl_grads = jax.grad(fl_fn, argnums=(0, 1, 2))(q, k, v)
+    ref_fn = loss(lambda q, k, v: xla_attention(q, k, v, **masks))
+    fl_fn = loss(lambda q, k, v: flash_attention(q, k, v, interpret=True, **masks, **spec))
+    ref_grads, ref_o = jax.grad(ref_fn, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    fl_grads, fl_o = jax.grad(fl_fn, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    np.testing.assert_allclose(np.asarray(fl_o), np.asarray(ref_o),
+                               atol=tol or 2e-5, rtol=tol or 2e-5)
     for rg, fg, name in zip(ref_grads, fl_grads, "qkv"):
-        np.testing.assert_allclose(np.asarray(fg), np.asarray(rg), atol=5e-5, rtol=5e-5,
-                                   err_msg=f"d{name} mismatch")
+        rg, fg = np.asarray(rg, np.float32), np.asarray(fg, np.float32)
+        if tol:  # bf16: against the gradient's own size, as one rounding is
+            np.testing.assert_allclose(fg, rg, atol=tol * np.abs(rg).max(), rtol=tol,
+                                       err_msg=f"d{name} mismatch")
+        else:
+            np.testing.assert_allclose(fg, rg, atol=5e-5, rtol=5e-5,
+                                       err_msg=f"d{name} mismatch")
+
+
+def test_tile_counts_at_trace_time():
+    """1,024 causal positions in tiles of 256: each of the four q tiles has
+    its diagonal tile as an edge, the tiles left of it interior and the
+    tiles right of it dead, 4 + 6 + 6 of 16 a head; the dq kernel walks the
+    same tiles and the dkv kernel their transpose. A window of 600 turns
+    tiles (2,0), (3,0) and (3,1) from interior to edge. Where one grid step
+    holds the whole sequence and the walk is straight-line code, the dkv
+    kernel steps in register tiles of 128: 28 dead, 28 interior, 8 edge."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _flash_bwd, _flash_fwd
+    from deepspeed_tpu.utils.trace import recorder
+
+    b, h, l, d = 2, 3, 1024, 64
+    x = jax.ShapeDtypeStruct((b, h, l, d), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((b, h, l), jnp.float32)
+
+    def counted(fn, *args):
+        before = dict(recorder().counters)
+        jax.eval_shape(fn, *args)
+        return tuple((recorder().counters.get(f"attn_tiles_{kind}", 0)
+                      - before.get(f"attn_tiles_{kind}", 0)) // (b * h)
+                     for kind in ("dead", "interior", "edge"))
+
+    def fwd(window=None, blk=1024):
+        return counted(lambda q, k, v: _flash_fwd(q, k, v, 0.125, True, blk, blk, 256, True,
+                                                  window=window), x, x, x)
+
+    def bwd(window=None, blk_q=512):
+        return counted(lambda q, k, v, o, s, g: _flash_bwd(
+            (q, k, v, o, s, None), g, 0.125, True, blk_q, 1024, 256, True, window=window),
+            x, x, x, x, lse, x)
+
+    assert fwd() == fwd(blk=512) == (6, 6, 4)  # the blocks do not change the tiles
+    assert bwd() == (12, 12, 8)
+    assert fwd(window=600) == (6, 3, 7)
+    assert bwd(window=600) == (12, 6, 14)
+    assert bwd(blk_q=1024) == (6 + 28, 6 + 28, 4 + 8)
+
+
+@pytest.mark.parametrize("walk", ["k", "q"])
+def test_tile_classifier_matches_brute_force(walk):
+    """`_k_walk` / `_q_walk` against the mask itself, tile by tile."""
+    from deepspeed_tpu.ops.pallas.flash_attention import _k_walk, _q_walk
+
+    rng = np.random.default_rng(5)
+    for _ in range(300):
+        tq, tk = (int(rng.choice([8, 16, 32])) for _ in range(2))
+        nq, extra = int(rng.integers(1, 6)), int(rng.integers(0, 4))
+        lq = nq * tq
+        lk = -(-(lq + extra * tq) // tk) * tk
+        off = lk - lq
+        causal = bool(rng.integers(0, 2))
+        window = int(rng.integers(1, lk + 8)) if causal and rng.integers(0, 2) else None
+        ahead = (np.arange(lq)[:, None] + off) - np.arange(lk)[None, :]
+        live = np.ones((lq, lk), bool)
+        if causal:
+            live &= ahead >= 0
+        if window is not None:
+            live &= ahead < window
+        tiles = live.reshape(nq, tq, lk // tk, tk).transpose(0, 2, 1, 3).reshape(nq, lk // tk, -1)
+        if walk == "q":
+            tiles = tiles.transpose(1, 0, 2)
+        for t, row in enumerate(tiles):
+            if walk == "k":
+                lo, ilo, ihi, hi = _k_walk(t * tq, tq, tk, lk // tk, off, causal, window, None)
+            else:
+                lo, ilo, ihi, hi = _q_walk(t * tk, tk, tq, nq, off, causal, window, None)
+            assert 0 <= lo <= ilo <= ihi <= hi <= len(row)
+            for u, tile in enumerate(row):
+                kind = ("dead" if not lo <= u < hi else
+                        "interior" if ilo <= u < ihi else "edge")
+                want = "dead" if not tile.any() else "interior" if tile.all() else "edge"
+                # a tile may be masked though it needs none, never the reverse
+                assert kind == want or (kind, want) == ("edge", "interior"), \
+                    (walk, tq, tk, lq, lk, causal, window, t, u, kind, want)
 
 
 def test_flash_decode_offset():
@@ -285,8 +408,13 @@ def test_mistral_preset_runs_with_window():
                 "bf16": {"enabled": True}, "steps_per_print": 10**9})
     rng = np.random.default_rng(23)
     batch = {"input_ids": rng.integers(0, cfg.vocab_size, (8, 128)).astype(np.int32)}
+    from deepspeed_tpu.utils.trace import recorder
+    before = dict(recorder().counters)
     losses = [float(engine.train_batch(batch)) for _ in range(3)]
     assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+    # tracing the step counted the tiles its attention kernels will run
+    assert recorder().counters.get("attn_tiles_edge", 0) > before.get("attn_tiles_edge", 0)
+    assert {"attn_tiles_dead", "attn_tiles_interior"} <= set(recorder().counters)
     # config table carries the real preset
     assert get_llama_config("mistral-7b").sliding_window == 4096
 

@@ -114,13 +114,18 @@ def _sq_grads(fn):
 # kernels at 350M widths
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
-def test_flash_attention_compiles(one_chip, backward):
+@pytest.mark.parametrize("batch,heads", [(B, H), (4, 25)], ids=["gpt2_medium", "gpt2_xl"])
+def test_flash_attention_compiles(one_chip, batch, heads, backward):
+    """Both training cells' call shapes (XL: the 4 sequences a micro-batch
+    that ``per_shard`` hands a chip, 25 heads) at the default geometry:
+    whole-sequence blocks walked in compute tiles, against Mosaic's tiling
+    rules and scoped VMEM."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     def fn(q, k, v):
         return flash_attention(q, k, v, causal=True)
 
-    qkv = _shape(B, L, H, D)
+    qkv = _shape(batch, L, heads, D)
     _kernel_text(_compile(_sq_grads(fn) if backward else fn, one_chip, qkv, qkv, qkv))
 
 
