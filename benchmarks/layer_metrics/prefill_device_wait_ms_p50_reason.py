@@ -1,0 +1,9 @@
+"""The prefill program of the hybrid model as the scheduler waits for it:
+p50 of the ``device_wait`` span of prefill ticks (as
+``prefill_device_wait_ms_p50``): one 128-position SSD chunk a slot."""
+
+from benchmarks.lib import program_spans
+
+
+def read(ctx):
+    return program_spans.device_wait_ms_p50("prefill")

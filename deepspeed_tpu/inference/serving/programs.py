@@ -28,7 +28,12 @@ from jax.sharding import NamedSharding, PartitionSpec
 # the cache's leaf names are the models' (``models/common.py`` DecodeCache):
 # INDEX_LEAVES hold write positions (scalar in ``generate``'s lockstep
 # cache; [slots] vectors in the serving cache), KV_LEAVES are the pools
-from deepspeed_tpu.models.common import (INDEX_LEAVES, KV_LEAVES, slot_pool,
+# a model with recurrent layers (``models/nemotron_h.py``) adds STATE_LEAVES
+# (per-slot state with no positions, ``[slots, ...]`` as the model shapes
+# it), LENGTH_LEAVES (how many of a slot's tokens this tick are real) and,
+# where a layer counts for the host, COUNTER_LEAVES
+from deepspeed_tpu.models.common import (COUNTER_LEAVES, INDEX_LEAVES, KV_LEAVES,
+                                         LENGTH_LEAVES, STATE_LEAVES, slot_pool,
                                          slot_pool_positions, slot_pool_scale)
 from deepspeed_tpu.utils import trace
 
@@ -40,6 +45,11 @@ def _leaf_name(path) -> str:
 
 def _is_index_leaf(path) -> bool:
     return _leaf_name(path) in INDEX_LEAVES
+
+
+def _leaves_named(cache, names):
+    return [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+            if _leaf_name(path) in names]
 
 
 def make_slot_cache(module, slots: int, kv_quant: bool = False):
@@ -55,12 +65,16 @@ def make_slot_cache(module, slots: int, kv_quant: bool = False):
     converts the KV pools to int8 codes and adds a
     ``<leaf>_scale [slots, H, P]`` companion per pool — the provided
     cache dtype is what statically flips the model's decode branch to
-    quantize-on-write / dequantize-on-read."""
+    quantize-on-write / dequantize-on-read.
+
+    A recurrent layer's state (``STATE_LEAVES``) stays ``[slots, ...]`` as
+    the model shapes it, zeroed and never quantised; the ``LENGTH_LEAVES``
+    beside the index leaves are [slots] vectors too, 0 for a parked slot."""
     from deepspeed_tpu.models.common import init_cache
 
     def stored(cache):
         def leaf_of(path, leaf):
-            if _is_index_leaf(path):
+            if _is_index_leaf(path) or _leaf_name(path) in LENGTH_LEAVES:
                 return jnp.zeros((slots,), jnp.int32)
             return slot_pool(leaf) if _leaf_name(path) in KV_LEAVES else leaf
 
@@ -107,17 +121,48 @@ def slot_capacity(cache) -> int:
     raise ValueError("cache has no cached_key leaves — not a decode cache")
 
 
-def with_write_positions(cache, write_pos):
+def with_write_positions(cache, write_pos, fed=1):
     """Traced: ``cache`` with every index leaf set to the tick's
     ``write_pos [slots] int32`` operand — one value used by each block's
     ``cache_index`` and the model's ``position_index``, no transfer. The
     carried leaves' own values are dead (each comes back a fresh output
-    buffer, so the donated cache still chains tick to tick)."""
+    buffer, so the donated cache still chains tick to tick).
+
+    A cache with ``LENGTH_LEAVES`` (a model with recurrent state) also gets
+    how many of each slot's tokens are real: ``fed`` (a scalar or [slots]),
+    and 0 for a parked slot, whose state must come back untouched."""
+
+    if not _leaves_named(cache, LENGTH_LEAVES):
+        return jax.tree_util.tree_map_with_path(
+            lambda path, leaf: write_pos if _is_index_leaf(path) else leaf, cache)
+    length = jnp.where(write_pos < slot_capacity(cache), fed, 0).astype(jnp.int32)
 
     def sub(path, leaf):
+        if _leaf_name(path) in LENGTH_LEAVES:
+            return length
         return write_pos if _is_index_leaf(path) else leaf
 
     return jax.tree_util.tree_map_with_path(sub, cache)
+
+
+def with_counters(cache, tok):
+    """Traced: what a tick reads back. ``tok`` [slots] int32, and behind it
+    the sum of the cache's ``COUNTER_LEAVES`` (int32 vectors a layer left for
+    the host: ``MOELayer.experts_held``'s rows) where the model has any, so
+    that the one read-back a tick makes carries them."""
+    counters = _leaves_named(cache, COUNTER_LEAVES)
+    return jnp.concatenate([tok, sum(counters)]) if counters else tok
+
+
+def has_recurrent_state(cache) -> bool:
+    """Whether a serving cache holds per-slot state with no positions."""
+    return bool(_leaves_named(cache, STATE_LEAVES))
+
+
+def state_bytes_per_slot(cache) -> int:
+    """Bytes of recurrent state (``STATE_LEAVES``) one slot holds."""
+    return sum(leaf.size * leaf.dtype.itemsize // leaf.shape[0]
+               for leaf in _leaves_named(cache, STATE_LEAVES))
 
 
 # ---------------------------------------------------------------------------
@@ -157,7 +202,9 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
     positions write beyond the committed length, are re-written by later
     tokens, and —
     because the per-slot causal mask bounds every query by its own
-    position — are never attended by real queries). The chunk that
+    position — are never attended by real queries; a recurrent layer, which
+    has no positions to overwrite, is handed ``last_idx + 1`` as the slot's
+    real length and does not advance past it). The chunk that
     completes a prompt samples the request's FIRST token from its
     last-real-position logits, so TTFT stops at prefill completion."""
     import jax.numpy as jnp
@@ -169,15 +216,17 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
 
     if do_sample:
         def prefill(params, cache, write_pos, ids, last_idx, rng):
-            logits, cache = apply_fn(params, with_write_positions(cache, write_pos), ids)
+            logits, cache = apply_fn(params, with_write_positions(cache, write_pos,
+                                                                  last_idx + 1), ids)
             tok = sample_logits(last_logits(logits, last_idx), rng, True,
                                 temperature, top_k, top_p).astype(jnp.int32)
-            return cache, tok
+            return cache, with_counters(cache, tok)
     else:
         def prefill(params, cache, write_pos, ids, last_idx):
-            logits, cache = apply_fn(params, with_write_positions(cache, write_pos), ids)
-            return cache, jnp.argmax(last_logits(logits, last_idx),
-                                     axis=-1).astype(jnp.int32)
+            logits, cache = apply_fn(params, with_write_positions(cache, write_pos,
+                                                                  last_idx + 1), ids)
+            return cache, with_counters(cache, jnp.argmax(last_logits(logits, last_idx),
+                                                          axis=-1).astype(jnp.int32))
 
     return prefill
 
@@ -195,12 +244,13 @@ def build_decode_step(apply_fn, do_sample: bool, temperature: float,
                                      tokens[:, None])
             tok = sample_logits(logits[:, -1], rng, True, temperature,
                                 top_k, top_p).astype(jnp.int32)
-            return cache, tok
+            return cache, with_counters(cache, tok)
     else:
         def decode(params, cache, write_pos, tokens):
             logits, cache = apply_fn(params, with_write_positions(cache, write_pos),
                                      tokens[:, None])
-            return cache, jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+            return cache, with_counters(cache,
+                                        jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32))
 
     return decode
 
@@ -213,7 +263,8 @@ def build_verify_step(apply_fn) -> Callable:
     first divergence (lossless under greedy decoding by construction)."""
 
     def verify(params, cache, write_pos, tokens):
-        logits, cache = apply_fn(params, with_write_positions(cache, write_pos), tokens)
+        logits, cache = apply_fn(params, with_write_positions(cache, write_pos,
+                                                              tokens.shape[1]), tokens)
         return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, K+1]
 
     return verify

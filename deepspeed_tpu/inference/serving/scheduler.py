@@ -42,8 +42,9 @@ import jax
 from deepspeed_tpu.inference.serving.blocks import BlockPool
 from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.programs import (KV_LEAVES, _leaf_name,
-                                                      make_slot_cache, serve_programs,
-                                                      slot_capacity)
+                                                      has_recurrent_state, make_slot_cache,
+                                                      serve_programs, slot_capacity,
+                                                      state_bytes_per_slot)
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
@@ -179,6 +180,23 @@ class ContinuousBatchingScheduler:
             self._placement)
         self.capacity = slot_capacity(self._cache)  # tokens per slot
         self._probe_slot_decode()
+        # a model with recurrent layers keeps per-slot state with no
+        # positions: no rows to copy, no length to leave unadvanced. What
+        # assumes rows refuses it by name until the state has snapshots
+        self._recurrent = has_recurrent_state(self._cache)
+        self._state_bytes = state_bytes_per_slot(self._cache)
+        if self._recurrent and config.prefix_cache == "on":
+            raise NotImplementedError(
+                f"prefix_cache='on' over {type(self.module).__name__}: a shared prefix is "
+                f"restored as cache rows at positions, and this model's recurrent state "
+                f"(ssm_state / conv_state) has no rows: sharing it needs a snapshot of the "
+                f"state at the prefix's end, which is not built")
+        if self._recurrent and (config.speculation.enabled or drafter is not None):
+            raise NotImplementedError(
+                f"speculative decoding over {type(self.module).__name__}: a rejected draft is "
+                f"rolled back by not advancing a length, and this model's recurrent state "
+                f"has already advanced over the drafted tokens: verification needs the "
+                f"state at the accepted position, which is not built")
 
         # admission: block-pool truthful KV accounting. A byte budget is
         # sized into tokens from the cache's ACTUAL per-token footprint
@@ -308,6 +326,37 @@ class ContinuousBatchingScheduler:
             if name in KV_LEAVES or name.endswith("_scale"):
                 total += leaf.size * leaf.dtype.itemsize
         return total / float(self.slots * self.capacity)
+
+    def _read_back(self, tok, kind: str) -> np.ndarray:
+        """The tick's blocking read-back: the tokens [slots], and behind
+        them whatever the program counted for the host
+        (``programs.with_counters``): an expert layer that holds a share of
+        its experts decides on the device which rows are its own. Rows and
+        experts touched are also kept by the kind of tick, for whoever
+        works out what a tick of that kind had to stream."""
+        tok = np.asarray(tok)
+        if len(tok) > self.slots:
+            here, computed, anywhere, touched = (int(n) for n in tok[self.slots:])
+            self._rec.count("moe_rows_routed", here)
+            self._rec.count("moe_rows_elsewhere", anywhere - here)
+            self._rec.count("moe_rows_computed", computed)
+            self._rec.count(f"moe_rows_routed_{kind}", here)
+            self._rec.count(f"moe_experts_touched_{kind}", touched)
+        return tok[:self.slots]
+
+    def _count_state(self, write_pos: np.ndarray, fed: Optional[int] = None,
+                     computed: Optional[int] = None) -> None:
+        """One target pass of a model with recurrent state: every slot's
+        state is read and written once, live or parked; a slot that writes
+        at position 0 was zeroed first (a join); a prefill tick also says
+        how many of the positions its scan ran over were real."""
+        if not self._recurrent:
+            return
+        self._rec.count("ssm_state_bytes_touched", 2 * self.slots * self._state_bytes)
+        self._rec.count("ssm_state_resets", int((write_pos == 0).sum()))
+        if computed is not None:
+            self._rec.count("ssm_positions_fed", fed)
+            self._rec.count("ssm_positions_computed", computed)
 
     def _count_moe_rows(self, fed: int, computed: int) -> None:
         """One target forward pass over ``computed`` positions, ``fed`` of
@@ -749,6 +798,7 @@ class ContinuousBatchingScheduler:
         self._rec.count("prefill_positions_computed", self.slots * C)
         self._count_moe_rows(fed, self.slots * C)
         self._count_kv_write(write_pos, C)
+        self._count_state(write_pos, fed, self.slots * C)
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32), ids, last_idx)
         with self._phase("dispatch"):
@@ -761,7 +811,7 @@ class ContinuousBatchingScheduler:
                 self._drafter_cache, _ = self.dfns["prefill"](
                     self._drafter[1], self._drafter_cache, *inputs)
         with self._phase("device_wait"):
-            tok = np.asarray(tok)
+            tok = self._read_back(tok, "prefill")
         with self._phase("commit"):
             now = self.clock()
             for i in slots:
@@ -795,6 +845,7 @@ class ContinuousBatchingScheduler:
         self._rec.count("decode_slots_computed", self.slots)
         self._count_moe_rows(len(slots), self.slots)
         self._count_kv_write(write_pos, 1)
+        self._count_state(write_pos)
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32), tokens)
         with self._phase("dispatch"):
@@ -804,7 +855,7 @@ class ContinuousBatchingScheduler:
             self._cache, tok = self.fns["decode"](self._serve_params, self._cache,
                                                   *inputs)
         with self._phase("device_wait"):
-            tok = np.asarray(tok)
+            tok = self._read_back(tok, "decode")
         with self._phase("commit"):
             now = self.clock()
             for i in slots:
@@ -959,6 +1010,14 @@ class ContinuousBatchingScheduler:
             rows.append(np.ascontiguousarray(src))
         return flat, treedef, kv_idx, rows
 
+    def _refuse_recurrent_migration(self) -> None:
+        if self._recurrent:
+            raise MigrationError(
+                f"live migration over {type(self.module).__name__}: a request's committed "
+                f"state travels as cache rows up to its length, and this model's recurrent "
+                f"state (ssm_state / conv_state) is not rows: migrating it needs the "
+                f"state's snapshot in the payload, which is not built — drain instead")
+
     def export_inflight(self, release: bool = True) -> List[dict]:
         """Serialize every in-flight request — host bookkeeping plus its
         committed per-slot KV — into migration payloads a peer's
@@ -973,6 +1032,7 @@ class ContinuousBatchingScheduler:
         ``release=True`` (the SIGTERM path) frees each exported request's
         pool blocks and parks its slot, so the drain loop sees an empty
         scheduler and exits without generating further tokens here."""
+        self._refuse_recurrent_migration()
         if self.config.do_sample:
             raise MigrationError(
                 "sampled decoding cannot migrate: the sampling rng stream is "
@@ -1053,6 +1113,7 @@ class ContinuousBatchingScheduler:
         processes count from 0 — the wire id would collide) with the
         origin id kept in ``meta["migrated_from"]`` for at-most-once
         completion accounting."""
+        self._refuse_recurrent_migration()
         for knob in ("kv_quant", "weight_dtype", "spec_k", "capacity",
                      "prefix_cache"):
             if payload.get(knob) != getattr(self, knob):

@@ -44,6 +44,16 @@ def init_cache(model: nn.Module, batch_size: int, rng=None):
 #: model with a learned position table (GPT-2) keeps at its top level
 KV_LEAVES = ("cached_key", "cached_value")
 INDEX_LEAVES = ("cache_index", "position_index")
+#: a second kind of per-slot state, with no positions (``models/nemotron_h.py``):
+#: a recurrent layer's state ``[slots, ...]``, carried from tick to tick, never
+#: quantised, zeroed when a request joins at position 0; ``LENGTH_LEAVES`` say
+#: how many of the tokens a slot is handed this call are real (a recurrence
+#: must not advance over a chunk's padding, nor a parked slot at all), and
+#: ``COUNTER_LEAVES`` are int32 counts a layer leaves for the host, which a
+#: serving program sums and returns beside its tokens
+STATE_LEAVES = ("ssm_state", "conv_state")
+LENGTH_LEAVES = ("chunk_length",)
+COUNTER_LEAVES = ("moe_rows",)
 
 
 class DecodeCache:

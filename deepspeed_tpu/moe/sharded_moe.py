@@ -38,7 +38,7 @@ Key departures from the reference, all forced by XLA's compilation model
 """
 
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -317,13 +317,44 @@ def top2routing(logits: jax.Array,
 
 
 
+def _slots_by_sort(experts, num_experts, used_token):
+    """Each copy's position inside its expert's group, and the copies an
+    expert was sent, from one stable sort of the copies' experts: O(n log n)
+    in the ``S k`` copies, where the one-hot cumulative sum of
+    :func:`_topk_decisions` is O(S k E) (92 M elements a layer at 8,192
+    tokens x 22 of 512). Inside a group the copies lie token-major, not
+    choice-major: the same groups in another order, which nothing reads
+    when no copy can be dropped. Copies of unused tokens sort past the last
+    group. Returns ``(slot [S, k], counts [E])``."""
+    num_tokens, k = experts.shape
+    flat = experts.reshape(-1)
+    if used_token is not None:
+        flat = jnp.where(jnp.repeat(used_token.astype(bool), k), flat, num_experts)
+    n = flat.shape[0]
+    at = jnp.arange(n, dtype=jnp.int32)
+    by_expert, order = jax.lax.sort((flat, at), num_keys=1, is_stable=True)
+    starts = jnp.searchsorted(by_expert, jnp.arange(num_experts + 1, dtype=flat.dtype))
+    rank = at - starts[by_expert].astype(jnp.int32)
+    # back to the copies' own order: ``order`` is a permutation
+    _, slot = jax.lax.sort((order, rank), num_keys=1)
+    return slot.reshape(num_tokens, k), jnp.diff(starts).astype(jnp.int32)
+
+
 def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, normalize,
-                    used_token=None):
+                    used_token=None, score="softmax", select_bias=None, scale=1.0,
+                    assign="cumsum"):
     """The decision core for any ``k`` <= experts (OLMoE: 8 of 64): softmax
     over the experts in fp32, the ``k`` largest probabilities
     (``jax.lax.top_k``; ties go to the lower index), and as combine weights
     the softmax values themselves, or, with ``normalize``, those values
     divided by their sum. Deterministic: no sampled choice, no RTS.
+
+    ``score="sigmoid"`` scores each expert alone (DeepSeek-V3, Nemotron-H);
+    ``select_bias`` [E] is added to the scores for the *choice* only, the
+    weights stay the unbiased scores; ``scale`` multiplies the weights after
+    the normalisation. ``assign="sort"`` takes the positions from
+    :func:`_slots_by_sort` (drop-free only; ``exp_counts`` then counts all
+    ``k`` choices).
 
     Slots are assigned choice-major, as the top-2 core does: every first
     choice queues in its expert's buffer before any second choice, so what
@@ -332,13 +363,31 @@ def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, norma
     can exceed (a token's ``k`` experts are distinct), and nothing drops."""
     logits = logits.astype(jnp.float32)
     num_tokens, num_experts = logits.shape
-    gates = jax.nn.softmax(logits, axis=1)
+    gates = jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, axis=1)
     capacity = _gate_capacity(num_tokens, num_experts, capacity_factor, min_capacity,
                               drop_tokens, k)
-    weights, experts = jax.lax.top_k(gates, k)                      # [S, k]
+    if select_bias is None:
+        weights, experts = jax.lax.top_k(gates, k)                  # [S, k]
+    else:
+        _, experts = jax.lax.top_k(gates + select_bias.astype(jnp.float32)[None, :], k)
+        weights = jnp.take_along_axis(gates, experts, axis=1)
     if normalize:
         weights = weights / jnp.maximum(weights.sum(axis=1, keepdims=True),
                                         jnp.finfo(gates.dtype).eps)
+    if scale != 1.0:
+        weights = weights * scale
+    if assign == "sort":
+        if drop_tokens:
+            raise ValueError("assign='sort' orders a group token-major: only where no copy "
+                             "is dropped (drop_tokens=False)")
+        slot, counts = _slots_by_sort(experts, num_experts, used_token)
+        keep = (jnp.ones_like(slot) if used_token is None
+                else jnp.broadcast_to(used_token[:, None].astype(jnp.int32), slot.shape))
+        l_aux = jnp.sum(jnp.mean(gates, axis=0) * counts / num_tokens) * num_experts
+        routing = SortedRouting(expert=experts.astype(jnp.int32), slot=slot, weight=weights * keep,
+                                keep=keep)
+        # every choice's copies, not the first choice's alone: no [S, E] mask is built
+        return l_aux, routing, counts, capacity
     masks = jax.nn.one_hot(experts, num_experts, dtype=jnp.int32)   # [S, k, E]
     if used_token is not None:
         masks = masks * used_token[:, None, None].astype(masks.dtype)
@@ -360,21 +409,23 @@ def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, norma
 
 def topkrouting(logits: jax.Array, k: int, capacity_factor: float, min_capacity: int,
                 drop_tokens: bool = True, normalize: bool = False,
-                used_token: Optional[jax.Array] = None) -> Tuple[jax.Array, SortedRouting, jax.Array]:
+                used_token: Optional[jax.Array] = None,
+                **scoring) -> Tuple[jax.Array, SortedRouting, jax.Array]:
     """Top-``k`` gating, compact form for the sorted route. Returns
-    ``(l_aux, SortedRouting [S,k] fields, exp_counts [E])``."""
+    ``(l_aux, SortedRouting [S,k] fields, exp_counts [E])``. ``scoring``:
+    ``score``, ``select_bias``, ``scale`` of :func:`_topk_decisions`."""
     l_aux, routing, exp_counts, _ = _topk_decisions(
-        logits, k, capacity_factor, min_capacity, drop_tokens, normalize, used_token)
+        logits, k, capacity_factor, min_capacity, drop_tokens, normalize, used_token, **scoring)
     return l_aux, routing, exp_counts
 
 
 def topkgating(logits: jax.Array, k: int, capacity_factor: float, min_capacity: int,
                drop_tokens: bool = True, normalize: bool = False,
-               used_token: Optional[jax.Array] = None):
+               used_token: Optional[jax.Array] = None, **scoring):
     """Top-``k`` gating in the dense route's form: the same decisions as
     :func:`topkrouting`, spread into ``[S,E,C]`` combine weights."""
     l_aux, routing, exp_counts, capacity = _topk_decisions(
-        logits, k, capacity_factor, min_capacity, drop_tokens, normalize, used_token)
+        logits, k, capacity_factor, min_capacity, drop_tokens, normalize, used_token, **scoring)
     expert_se = jax.nn.one_hot(routing.expert, logits.shape[1], dtype=jnp.float32)  # [S,k,E]
     slot_sc = jax.nn.one_hot(routing.slot, capacity, dtype=jnp.float32)             # [S,k,C]
     combine_weights = jnp.einsum("sk,ske,skc->sec", routing.weight, expert_se, slot_sc)
@@ -417,12 +468,26 @@ class TopKGate(nn.Module):
     # reference top-2, Mixtral) or the softmax values as they are (OLMoE's
     # ``norm_topk_prob`` false)
     norm_topk_prob: bool = True
+    # how an expert is scored: "softmax" over the experts, or "sigmoid", each
+    # alone. With ``select_bias`` the ``k`` chosen are the top of score +
+    # ``e_score_correction_bias`` [E] (a parameter of this gate) and the
+    # weights the unbiased scores; ``routed_scale`` multiplies the weights
+    # after the normalisation (DeepSeek-V3's router, Nemotron-H's)
+    score: str = "softmax"
+    select_bias: bool = False
+    routed_scale: float = 1.0
 
     @nn.compact
-    def __call__(self, tokens, used_token=None, deterministic: bool = True):
+    def __call__(self, tokens, used_token=None, deterministic: bool = True,
+                 slots_by_sort: bool = False):
+        """``slots_by_sort`` (the layer's to pass, drop-free only): a copy's
+        position in its expert's group comes from ``_slots_by_sort``, O(S k
+        log S k), not from the one-hot cumulative sum, O(S k E)."""
         if not 1 <= self.k <= self.num_experts:
             raise ValueError(f"top-k gating needs 1 <= k <= experts "
                              f"(got k={self.k}, experts={self.num_experts})")
+        if self.score not in ("softmax", "sigmoid"):
+            raise ValueError(f"gate score must be 'softmax' or 'sigmoid', got {self.score!r}")
         # the gate runs in fp32 regardless of compute dtype (reference keeps
         # wg in fp32, sharded_moe.py:373,394)
         wg = self.param("wg", nn.with_logical_partitioning(nn.initializers.normal(0.02), ("embed", None)),
@@ -452,7 +517,23 @@ class TopKGate(nn.Module):
 
         top1_fn = top1routing if self.route == "sorted" else top1gating
         top2_fn = top2routing if self.route == "sorted" else top2gating
-        if self.k == 1:
+        scoring = {}
+        if (self.score != "softmax" or self.select_bias or self.routed_scale != 1.0
+                or slots_by_sort):
+            # the top-k core alone knows these: it serves every k
+            scoring = dict(score=self.score, scale=self.routed_scale,
+                           assign="sort" if slots_by_sort else "cumsum")
+            if self.select_bias:
+                bias = self.param("e_score_correction_bias",
+                                  nn.with_logical_partitioning(nn.initializers.normal(0.02), (None,)),
+                                  (self.num_experts,), jnp.float32)
+                scoring["select_bias"] = bias.value if isinstance(bias, nn.meta.AxisMetadata) else bias
+        if scoring:
+            topk_fn = topkrouting if self.route == "sorted" else topkgating
+            gate_fn = lambda lg, r, ut: topk_fn(lg, self.k, cf, self.min_capacity,
+                                                self.drop_tokens, self.norm_topk_prob, ut,
+                                                **scoring)
+        elif self.k == 1:
             gate_fn = lambda lg, r, ut: top1_fn(lg, cf, self.min_capacity, ut,
                                                 self.noisy_gate_policy if not deterministic else None,
                                                 self.drop_tokens, self.use_rts, r)
@@ -587,6 +668,27 @@ class MOELayer(nn.Module):
     route: str = "sorted"
     route_kernel: str = "auto"
     norm_topk_prob: bool = True
+    # the gate's scoring (``TopKGate``): "softmax" | "sigmoid", a selection
+    # bias, a scale on the normalised weights
+    score: str = "softmax"
+    select_bias: bool = False
+    routed_scale: float = 1.0
+    # ``(first, count)``: the experts this device holds of ``num_experts``
+    # (its share of an expert-parallel layer). The gate routes over all of
+    # them; only copies routed to a held expert are grouped, computed and
+    # combined, so the result is this device's part of the layer's sum and
+    # ``expert`` is a bank of ``count``. None: all of them, today's layer
+    experts_held: Optional[Tuple[int, int]] = None
+    # > 0: the experts live in a latent space of this size, between two
+    # bias-free projections ``latent_down`` [model, latent] and ``latent_up``
+    # that every device holds whole; the gate still reads the model's state
+    latent_dim: int = 0
+    # a module [..., model] -> [..., model] every token passes through, added
+    # to the routed result (held whole by every device, like the projections)
+    shared_expert: Optional[nn.Module] = None
+    # of the latent projections (the gate's weight is float32, the bank's
+    # are its own)
+    param_dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, hidden_states, used_token=None, deterministic: bool = True):
@@ -609,7 +711,11 @@ class MOELayer(nn.Module):
         gate = TopKGate(self.model_dim, self.num_experts, self.k, self.capacity_factor,
                         self.eval_capacity_factor, self.min_capacity, self.noisy_gate_policy,
                         self.drop_tokens, self.use_rts, route=route,
-                        norm_topk_prob=self.norm_topk_prob, name="gate")
+                        norm_topk_prob=self.norm_topk_prob, score=self.score,
+                        select_bias=self.select_bias, routed_scale=self.routed_scale,
+                        name="gate")
+        if (self.experts_held is not None or self.latent_dim) and route != "sorted":
+            raise ValueError("experts_held and latent_dim are options of the sorted route")
 
         if route == "sorted":
             out, l_aux, exp_counts, kept_counts, routed_counts, capacity = self._sorted_route(
@@ -620,6 +726,9 @@ class MOELayer(nn.Module):
                 gate, tokens, used_token, deterministic, constrain, orig_dtype)
 
         out = out.reshape(orig_shape)
+        if self.shared_expert is not None:
+            with jax.named_scope("moe_shared"):
+                out = out + self.shared_expert.copy(name="shared_expert")(hidden_states)
         # expert-load observability (threaded to monitor/ by the engine):
         # exp_counts = first-choice routing decisions pre-drop (the reference
         # contract, and the signal the aux loss balances), kept_counts =
@@ -701,27 +810,55 @@ class MOELayer(nn.Module):
         # at a prefill tick's 16,384 rows of 2,048 it made the tick 208 ms
         # (PERF.md, PR 26)
         permute_impl = "xla" if ragged else impl
+        held = self.experts_held
+        if held is not None and not ragged:
+            raise NotImplementedError(
+                "experts_held needs the drop-free grouped layout of one device "
+                "(drop_tokens=False over an expert bank); on a mesh the layer's "
+                "exchange is not built")
 
         with jax.named_scope("moe_route"):
-            l_aux, routing, exp_counts = gate(tokens, used_token, deterministic)
+            l_aux, routing, exp_counts = gate(tokens, used_token, deterministic,
+                                              slots_by_sort=held is not None)
             capacity = gate.capacity(num_tokens, deterministic)
             k = routing.expert.shape[-1]
             # which experts each token took, [G, S, k], best first (read
             # with mutable=["intermediates"]; costs nothing otherwise)
             self.sow("intermediates", "expert_choice", routing.expert)
-            kept_counts = jnp.zeros((E,), jnp.int32).at[routing.expert.reshape(-1)].add(
-                routing.keep.reshape(-1).astype(jnp.int32))
-            if ragged:
-                # one device, so one group (``_num_groups``): ``kept_counts``
-                # are its group sizes, and group e starts where the groups
-                # before it end. The buffer holds S*k rows, every one a copy
-                starts = jnp.cumsum(kept_counts) - kept_counts
-                base = starts[routing.expert].reshape(groups, -1)
+            if held is not None:
+                # a copy routed to an expert another device holds is that
+                # device's to compute: here it is dropped, as a copy past a
+                # capacity is (sentinel row, zero in the combine). The gate
+                # counted every choice's copies (``slots_by_sort``):
+                # the held experts' are this device's group sizes, and no
+                # scatter over the S k copies is needed for them
+                first, count = held
+                here = (routing.expert >= first) & (routing.expert < first + count)
+                routing = routing._replace(keep=routing.keep * here.astype(routing.keep.dtype))
+                routed_anywhere, sizes = exp_counts.sum(), exp_counts[first:first + count]
+                kept_counts = jnp.zeros((E,), jnp.int32).at[first:first + count].set(sizes)
+                # the held experts' groups only, packed from row 0: the
+                # sizes add up to less than the buffer's rows, and the
+                # grouped matmul runs over no tile past them
+                starts = jnp.cumsum(sizes) - sizes
+                base = starts[jnp.clip(routing.expert - first, 0, count - 1)].reshape(groups, -1)
                 rows = num_tokens * k
-                capacity = -(-rows // E)   # evidence only: mean rows an expert
+                capacity = -(-rows // E)
             else:
-                base = (routing.expert * capacity).reshape(groups, -1)
-                rows = E * capacity
+                kept_counts = jnp.zeros((E,), jnp.int32).at[routing.expert.reshape(-1)].add(
+                    routing.keep.reshape(-1).astype(jnp.int32))
+                if ragged:
+                    # one device, so one group (``_num_groups``): ``kept_counts``
+                    # are its group sizes, and group e starts where the groups
+                    # before it end. The buffer holds S*k rows, every one a copy
+                    sizes = kept_counts
+                    starts = jnp.cumsum(kept_counts) - kept_counts
+                    base = starts[routing.expert].reshape(groups, -1)
+                    rows = num_tokens * k
+                    capacity = -(-rows // E)   # evidence only: mean rows an expert
+                else:
+                    base = (routing.expert * capacity).reshape(groups, -1)
+                    rows = E * capacity
             # each kept copy owns a unique row base + position (the cumsum
             # position assignment is a stable counting sort by expert);
             # dropped copies park on the sentinel → zero rows / no reads
@@ -729,20 +866,50 @@ class MOELayer(nn.Module):
                                   base + routing.slot.reshape(groups, -1),
                                   rows).astype(jnp.int32)
             flat_slot = constrain(flat_slot, (BATCH_AXES, None))
-            src = inverse_index(flat_slot, rows)  # [G, rows] — row -> token copy
+            if held is None:
+                src = inverse_index(flat_slot, rows)  # [G, rows] — row -> token copy
+            else:
+                # the kept copies' rows are 0 .. n-1 with no gap: sorted by
+                # row, the copies are the rows' sources (no scatter)
+                by_row, src = jax.lax.sort(
+                    (flat_slot, jax.lax.broadcasted_iota(jnp.int32, flat_slot.shape, 1)),
+                    dimension=1, num_keys=1)
+                src = jnp.where(by_row < rows, src, flat_slot.shape[1])
             src = constrain(src, (BATCH_AXES, None))
 
+            if held is not None and self.is_mutable_collection("cache"):
+                # for the host, beside a serving tick's tokens: rows routed
+                # to experts held here, rows of the tiles the expert
+                # matmuls run over, copies routed to any expert, held
+                # experts that got a row (whose weights the tick streams)
+                from deepspeed_tpu.ops.pallas.grouped_matmul import rows_visited
+                self.variable("cache", "moe_rows", jnp.zeros, (4,), jnp.int32).value = jnp.stack(
+                    [sizes.sum(), rows_visited(sizes, rows), routed_anywhere,
+                     (sizes > 0).sum()]).astype(jnp.int32)
+            if self.latent_dim:
+                with jax.named_scope("moe_latent_down"):
+                    tokens = nn.Dense(self.latent_dim, use_bias=False, dtype=orig_dtype,
+                                      param_dtype=self.param_dtype,
+                                      kernel_init=nn.with_logical_partitioning(
+                                          nn.initializers.normal(0.02), ("embed", None)),
+                                      name="latent_down")(tokens)
+                d_model = self.latent_dim
             # [G, S, M] -> [G, S*k, M], copy j of token s at row s*k + j (the
             # reshape order of the [S, k] routing fields)
-            tok_rep = jnp.repeat(tokens, k, axis=1) if k > 1 else tokens
-            # dispatch = pure row permutation
-            dispatched = permute_rows(tok_rep, src, flat_slot, impl=permute_impl)
+            if held is None:
+                tok_rep = jnp.repeat(tokens, k, axis=1) if k > 1 else tokens
+                # dispatch = pure row permutation
+                dispatched = permute_rows(tok_rep, src, flat_slot, impl=permute_impl)
+            else:
+                # row -> its copy's token, with no k-fold copy of the tokens
+                token_of = jnp.where(src < num_tokens * k, src // k, num_tokens)
+                dispatched = permute_rows(tokens, token_of, token_of, impl="xla")
 
         with jax.named_scope("moe_experts"):
             experts = Experts(self.expert, self.num_experts, name="experts")
             if ragged:
                 expert_out = experts(dispatched[0], deterministic,
-                                     group_sizes=kept_counts, impl=impl)[None]
+                                     group_sizes=sizes, impl=impl)[None]
             else:
                 # same constraint pair as the dense route so the expert
                 # all-to-all still moves only the capacity-bounded
@@ -764,10 +931,21 @@ class MOELayer(nn.Module):
             weights = routing.weight.astype(orig_dtype).reshape(groups, num_tokens * k, 1)
             combined = (weights * gathered).reshape(groups, num_tokens, k, d_model).sum(axis=2)
             combined = constrain(combined, (BATCH_AXES, None, None))
+        if self.latent_dim:
+            with jax.named_scope("moe_latent_up"):
+                combined = nn.Dense(self.model_dim, use_bias=False, dtype=orig_dtype,
+                                    param_dtype=self.param_dtype,
+                                    kernel_init=nn.with_logical_partitioning(
+                                        nn.initializers.normal(0.02), (None, "embed")),
+                                    name="latent_up")(combined)
 
         # all k copies pre-capacity: the compact routing names every copy's
         # expert, so the kept denominator is exact for every k (k=1: equals
         # exp_counts; k>=2: adds the later choices the dense return hides)
-        routed_counts = exp_counts if k == 1 else (
-            exp_counts + jnp.zeros((E,), jnp.int32).at[routing.expert[..., 1:].reshape(-1)].add(1))
+        if held is not None:
+            routed_counts = exp_counts           # the sorting gate counts every choice
+        else:
+            routed_counts = exp_counts if k == 1 else (
+                exp_counts
+                + jnp.zeros((E,), jnp.int32).at[routing.expert[..., 1:].reshape(-1)].add(1))
         return combined, l_aux, exp_counts, kept_counts, routed_counts, capacity

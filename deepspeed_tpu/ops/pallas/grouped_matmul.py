@@ -7,7 +7,9 @@ and every expert projection becomes
     out[start_e : start_e + size_e] = lhs[start_e : start_e + size_e] @ rhs[e]
 
 for the ``E`` groups of ``group_sizes``. Rows past ``sum(group_sizes)``
-hold no defined result.
+hold no defined result, and no tile that lies wholly past them is computed:
+a layer that holds only some of the experts (``MOELayer.experts_held``)
+hands the kernel its own groups and a buffer sized for every copy.
 
 Implementations, chosen by the same kernel choice as the row permutation
 (``MOELayer.route_kernel``):
@@ -50,6 +52,20 @@ def tiling(rows: int, k: int, n: int) -> Tuple[int, int, int]:
     cap = ROW_TILE_LARGE if rows >= LARGE_ROWS else ROW_TILE_SMALL
     return (backend.largest_block(rows, cap, 8), backend.largest_block(k, COL_TILE, 128),
             backend.largest_block(n, COL_TILE, 128))
+
+
+def rows_visited(group_sizes: jax.Array, rows: int) -> jax.Array:
+    """Rows of the row tiles the Pallas kernel runs over for ``group_sizes``
+    packed from row 0 of a ``rows``-row buffer (an int32 scalar, traced):
+    every tile a non-empty group overlaps, a tile two groups share once for
+    each. Tiles past the last group are not visited, so sizes that add up
+    to less than ``rows`` cost what they hold, rounded to tiles."""
+    import jax.numpy as jnp
+    tm = tiling(rows, 128, 128)[0]
+    ends = jnp.cumsum(group_sizes)
+    starts = ends - group_sizes
+    tiles = jnp.where(group_sizes > 0, (ends + tm - 1) // tm - starts // tm, 0)
+    return tiles.sum() * tm
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array, *,

@@ -323,6 +323,54 @@ def test_olmoe_serving_program_compiles(one_chip, program):
     _assert_pool_written_in_place(compiled, slots * 2048 * 16 * 128, 1, logits)
 
 
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, program):
+    """The hybrid model's serving programs at the tiny preset's layer
+    pattern (Mamba, experts a quarter held, attention), widths the chip's
+    tiling takes: 16 slots of recurrent state donated with the cache and
+    written back where they lie: no pass over a whole state leaf beside the
+    update itself, and the whole cache aliased."""
+    import flax.linen as nn
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step,
+                                                          make_apply_fn, make_slot_cache)
+    from deepspeed_tpu.models.nemotron_h import NemotronHForCausalLM, get_nemotron_h_config
+
+    slots, chunk = 16, 128
+    cfg = get_nemotron_h_config(
+        "nemotron-h-test", hidden_size=256, head_dim=64, mamba_num_heads=16, mamba_head_dim=64,
+        ssm_state_size=128, chunk_size=128, moe_latent_size=128, moe_intermediate_size=256,
+        moe_shared_expert_intermediate_size=512, experts_held=(4, 4), decode_cache_len=256,
+        max_position_embeddings=256, vocab_size=1024, dtype=bf16)
+    module = NemotronHForCausalLM(cfg)
+    params = jax.eval_shape(
+        lambda key: jax.tree.map(lambda p: p.astype(bf16), nn.meta.unbox(
+            module.init(key, jnp.zeros((1, 8), jnp.int32))["params"])), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots, kv_quant=True))
+    apply_fn = make_apply_fn(module)
+    if program == "prefill":
+        step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, chunk, dtype=jnp.int32),
+                    _shape(slots, dtype=jnp.int32))
+    else:
+        step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
+    compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
+    assert compiled.as_text().count("tpu_custom_call") == 4       # two expert layers x up, down
+    state = cache["layers_0"]["mixer"]["ssm_state"]
+    assert state.shape == (slots, 2, 8, 64, 128) and state.dtype == jnp.float32
+    # (a prefill tick relays its chunk's activations, as large at these widths)
+    assert not [line for line in _relayouts(compiled, state.size) if "f32[16,2,8,64,128]" in line]
+    memory = compiled.memory_analysis()
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
+    # every pool and state leaf comes back in place (the index vectors and
+    # the layers' counters, a few hundred bytes, are fresh outputs)
+    assert memory.alias_size_in_bytes >= cache_bytes - 1024
+    if program == "decode":
+        # one step: nothing the size of a state leaf is held beside the cache
+        assert memory.temp_size_in_bytes < state.size * 4
+
+
 def _train_engine(devices, zero_stage, fsdp):
     import deepspeed_tpu
     from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config

@@ -1,0 +1,116 @@
+"""A host-side model of a saturated serving cell's schedule: which seeds
+read how many tokens a second, with no chip.
+
+The continuous scheduler's policy is small (``scheduler.py::step``): free
+slots are filled from the queue in order; a prefill tick feeds one chunk to
+every slot still in its prompt, and runs when there is such a slot and
+either nothing decodes or ``prefill_interleave`` decode ticks have passed
+since the last one; every other tick decodes one token a busy slot. With
+the two tick times fixed (they are the fixed-shape programs') the tokens a
+window counts depend only on the order the traffic's requests come in,
+which is what ``--seed`` decides. For ``serve-nemotron-3-super-reason-sat``
+at 18.7 and 343.8 ms a tick the model gave the chip's six seeds at ``block``
+64 as 3,419 3,456 3,380 3,439 3,364 3,379 tokens/s where they read 3,425.9
+3,462.9 3,379.5 3,432.8 3,358.6 3,365.4 (PERF.md section 6, PR 30), and it
+is what chose ``block`` 16 for that cell. It knows nothing of a machine
+that runs slow: a spread it does not give is not the seeds'.
+
+    python3 tools/serve_schedule_model.py --decode-ms 18.7 --prefill-ms 343.8 \\
+        [--workload <cell>] [--block <n> ...] [--interleave <n>] [--sets 12]
+
+Prints, for each ``block``, the spread (quartiles over the median) of sets
+of six consecutive seeds.
+"""
+
+import argparse
+import json
+import os
+import statistics
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def tokens_per_s(lengths, slots, chunk, interleave, decode_s, prefill_s, preroll_s, window_s):
+    """``serve_total_tok_s`` of one run: ``lengths`` the (prompt, output)
+    pairs in queue order, all due at time zero."""
+    queue = iter(lengths)
+    held = [None] * slots           # [prompt tokens left, outputs made, outputs wanted]
+    now, since_prefill, progress, opened = 0.0, 0, 0, None
+    while True:
+        if opened is None and now >= preroll_s:
+            opened = (progress, now)
+        if opened is not None and now - opened[1] >= window_s:
+            return (progress - opened[0]) / (now - opened[1])
+        for i in range(slots):
+            if held[i] is None:
+                pair = next(queue, None)
+                held[i] = None if pair is None else [pair[0], 0, pair[1]]
+        prefilling = [s for s in held if s and s[0] > 0]
+        active = [s for s in held if s and s[0] == 0]
+        if prefilling and (not active or since_prefill >= interleave):
+            for s in prefilling:
+                fed = min(chunk, s[0])
+                s[0] -= fed
+                progress += fed
+                if s[0] == 0:       # the chunk that ends a prompt samples the first token
+                    s[1] += 1
+                    progress += 1
+            now, since_prefill = now + prefill_s, 0
+        else:
+            for s in active:
+                s[1] += 1
+                progress += 1
+            now, since_prefill = now + decode_s, since_prefill + 1
+        held = [None if s and s[0] == 0 and s[1] >= s[2] else s for s in held]
+
+
+def spread_pct(values):
+    first, _, third = statistics.quantiles(values, n=4)
+    return 100.0 * (third - first) / statistics.median(values)
+
+
+def run_seed(cell, seed, decode_s, prefill_s, block=None, interleave=None, window_s=51.0):
+    from benchmarks.lib.traffic import serve_schedule
+
+    traffic = dict(cell.traffic, block=block or cell.traffic["block"])
+    serve = cell.config["serve"]
+    lengths = [(len(r["prompt"]), r["max_new_tokens"])
+               for r in serve_schedule(traffic, cell.config["vocab_size"], seed, 0.0)]
+    return tokens_per_s(lengths, serve["slots"], serve["prefill_chunk"],
+                        interleave or serve["prefill_interleave"], decode_s, prefill_s,
+                        float(traffic["preroll_s"]), window_s)
+
+
+def main(argv):
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--workload", default="serve-nemotron-3-super-reason-sat")
+    parser.add_argument("--decode-ms", type=float, required=True)
+    parser.add_argument("--prefill-ms", type=float, required=True)
+    parser.add_argument("--block", type=int, nargs="+")
+    parser.add_argument("--interleave", type=int)
+    parser.add_argument("--sets", type=int, default=12)
+    parser.add_argument("--first-seed", type=int, default=2000000000)
+    args = parser.parse_args(argv)
+
+    from benchmarks.lib import harness
+
+    cell = harness.Cell(ROOT, harness.load_json(ROOT, "BENCHMARK.json"), args.workload)
+    if cell.traffic["arrivals"]["process"] != "all_at_zero":
+        raise SystemExit("the model is of a standing backlog (arrivals all_at_zero)")
+    for block in args.block or [cell.traffic["block"]]:
+        spreads, medians = [], []
+        for k in range(args.sets):
+            values = [run_seed(cell, args.first_seed + 7919 * k + j, args.decode_ms / 1e3,
+                               args.prefill_ms / 1e3, block, args.interleave) for j in range(6)]
+            spreads.append(spread_pct(values))
+            medians.append(statistics.median(values))
+        print(json.dumps({"block": block, "sets_of_six": args.sets,
+                          "spread_pct_median": round(statistics.median(spreads), 3),
+                          "spread_pct_max": round(max(spreads), 3),
+                          "tokens_per_s_medians": [round(min(medians), 1), round(max(medians), 1)]}))
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])
