@@ -333,14 +333,17 @@ class ContinuousBatchingScheduler:
         (``programs.with_counters``): an expert layer that holds a share of
         its experts decides on the device which rows are its own. Rows and
         experts touched are also kept by the kind of tick, for whoever
-        works out what a tick of that kind had to stream."""
+        works out what a tick of that kind had to stream, and so are the
+        rows of the buffers the layers sized for their routed rows."""
         tok = np.asarray(tok)
         if len(tok) > self.slots:
-            here, computed, anywhere, touched = (int(n) for n in tok[self.slots:])
+            here, computed, anywhere, touched, buffered = (int(n) for n in tok[self.slots:])
             self._rec.count("moe_rows_routed", here)
             self._rec.count("moe_rows_elsewhere", anywhere - here)
             self._rec.count("moe_rows_computed", computed)
+            self._rec.count("moe_rows_buffered", buffered)
             self._rec.count(f"moe_rows_routed_{kind}", here)
+            self._rec.count(f"moe_rows_buffered_{kind}", buffered)
             self._rec.count(f"moe_experts_touched_{kind}", touched)
         return tok[:self.slots]
 

@@ -37,6 +37,7 @@ Key departures from the reference, all forced by XLA's compilation model
   ``moe_route``, where the engine's ``"moe"`` block lands).
 """
 
+import functools
 import math
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -317,32 +318,9 @@ def top2routing(logits: jax.Array,
 
 
 
-def _slots_by_sort(experts, num_experts, used_token):
-    """Each copy's position inside its expert's group, and the copies an
-    expert was sent, from one stable sort of the copies' experts: O(n log n)
-    in the ``S k`` copies, where the one-hot cumulative sum of
-    :func:`_topk_decisions` is O(S k E) (92 M elements a layer at 8,192
-    tokens x 22 of 512). Inside a group the copies lie token-major, not
-    choice-major: the same groups in another order, which nothing reads
-    when no copy can be dropped. Copies of unused tokens sort past the last
-    group. Returns ``(slot [S, k], counts [E])``."""
-    num_tokens, k = experts.shape
-    flat = experts.reshape(-1)
-    if used_token is not None:
-        flat = jnp.where(jnp.repeat(used_token.astype(bool), k), flat, num_experts)
-    n = flat.shape[0]
-    at = jnp.arange(n, dtype=jnp.int32)
-    by_expert, order = jax.lax.sort((flat, at), num_keys=1, is_stable=True)
-    starts = jnp.searchsorted(by_expert, jnp.arange(num_experts + 1, dtype=flat.dtype))
-    rank = at - starts[by_expert].astype(jnp.int32)
-    # back to the copies' own order: ``order`` is a permutation
-    _, slot = jax.lax.sort((order, rank), num_keys=1)
-    return slot.reshape(num_tokens, k), jnp.diff(starts).astype(jnp.int32)
-
-
 def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, normalize,
                     used_token=None, score="softmax", select_bias=None, scale=1.0,
-                    assign="cumsum"):
+                    positions=True):
     """The decision core for any ``k`` <= experts (OLMoE: 8 of 64): softmax
     over the experts in fp32, the ``k`` largest probabilities
     (``jax.lax.top_k``; ties go to the lower index), and as combine weights
@@ -352,9 +330,10 @@ def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, norma
     ``score="sigmoid"`` scores each expert alone (DeepSeek-V3, Nemotron-H);
     ``select_bias`` [E] is added to the scores for the *choice* only, the
     weights stay the unbiased scores; ``scale`` multiplies the weights after
-    the normalisation. ``assign="sort"`` takes the positions from
-    :func:`_slots_by_sort` (drop-free only; ``exp_counts`` then counts all
-    ``k`` choices).
+    the normalisation. ``positions=False`` (drop-free only) assigns no copy
+    its place in its expert's buffer: the caller groups the copies itself
+    (``MOELayer._held_route``), ``slot`` is zero and ``exp_counts`` counts
+    all ``k`` choices.
 
     Slots are assigned choice-major, as the top-2 core does: every first
     choice queues in its expert's buffer before any second choice, so what
@@ -376,17 +355,21 @@ def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, norma
                                         jnp.finfo(gates.dtype).eps)
     if scale != 1.0:
         weights = weights * scale
-    if assign == "sort":
+    if not positions:
         if drop_tokens:
-            raise ValueError("assign='sort' orders a group token-major: only where no copy "
-                             "is dropped (drop_tokens=False)")
-        slot, counts = _slots_by_sort(experts, num_experts, used_token)
-        keep = (jnp.ones_like(slot) if used_token is None
-                else jnp.broadcast_to(used_token[:, None].astype(jnp.int32), slot.shape))
+            raise ValueError("positions=False leaves the copies' places to the caller: only "
+                             "where no copy is dropped (drop_tokens=False)")
+        keep = (jnp.ones(experts.shape, jnp.int32) if used_token is None
+                else jnp.broadcast_to(used_token[:, None].astype(jnp.int32), experts.shape))
+        # every choice's copies, not the first choice's alone: one compare and
+        # add a (copy, expert), which fuses into the sum; the cumulative sum
+        # below would write its [S, k, E] out (92 M elements a layer at 8,192
+        # tokens x 22 of 512)
+        counts = jnp.sum((experts[:, :, None] == jnp.arange(num_experts, dtype=experts.dtype))
+                         & (keep[:, :, None] > 0), axis=(0, 1), dtype=jnp.int32)
         l_aux = jnp.sum(jnp.mean(gates, axis=0) * counts / num_tokens) * num_experts
-        routing = SortedRouting(expert=experts.astype(jnp.int32), slot=slot, weight=weights * keep,
-                                keep=keep)
-        # every choice's copies, not the first choice's alone: no [S, E] mask is built
+        routing = SortedRouting(expert=experts.astype(jnp.int32), slot=jnp.zeros_like(keep),
+                                weight=weights * keep, keep=keep)
         return l_aux, routing, counts, capacity
     masks = jax.nn.one_hot(experts, num_experts, dtype=jnp.int32)   # [S, k, E]
     if used_token is not None:
@@ -479,10 +462,10 @@ class TopKGate(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, used_token=None, deterministic: bool = True,
-                 slots_by_sort: bool = False):
-        """``slots_by_sort`` (the layer's to pass, drop-free only): a copy's
-        position in its expert's group comes from ``_slots_by_sort``, O(S k
-        log S k), not from the one-hot cumulative sum, O(S k E)."""
+                 positions: bool = True):
+        """``positions=False`` (the layer's to pass, drop-free only): no copy
+        is given its place in its expert's buffer, O(S k E) by the one-hot
+        cumulative sum; the layer groups the copies it holds itself."""
         if not 1 <= self.k <= self.num_experts:
             raise ValueError(f"top-k gating needs 1 <= k <= experts "
                              f"(got k={self.k}, experts={self.num_experts})")
@@ -519,10 +502,9 @@ class TopKGate(nn.Module):
         top2_fn = top2routing if self.route == "sorted" else top2gating
         scoring = {}
         if (self.score != "softmax" or self.select_bias or self.routed_scale != 1.0
-                or slots_by_sort):
+                or not positions):
             # the top-k core alone knows these: it serves every k
-            scoring = dict(score=self.score, scale=self.routed_scale,
-                           assign="sort" if slots_by_sort else "cumsum")
+            scoring = dict(score=self.score, scale=self.routed_scale, positions=positions)
             if self.select_bias:
                 bias = self.param("e_score_correction_bias",
                                   nn.with_logical_partitioning(nn.initializers.normal(0.02), (None,)),
@@ -634,6 +616,21 @@ def _num_groups(num_tokens_leading: int) -> int:
     if dp > 1 and num_tokens_leading % dp == 0:
         return dp
     return 1
+
+
+#: ``MOELayer.experts_held``: the row buffer is a sixteenth or a quarter of
+#: the copies where the held rows fit one; a buffer under ``MIN_RUNG_ROWS``
+#: is not worth a branch (a decode tick's few hundred copies take none)
+RUNG_FRACTIONS, MIN_RUNG_ROWS = (16, 4), 1024
+
+
+def _row_rungs(copies: int) -> Tuple[int, ...]:
+    """The static sizes a held layer's row buffer may take for ``copies``
+    token copies, ascending, each but the last in whole row tiles of the
+    grouped matmul; the last is every copy."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import ROW_TILE_LARGE as tile
+    rungs = [-(-copies // (part * tile)) * tile for part in RUNG_FRACTIONS]
+    return tuple(r for r in rungs if MIN_RUNG_ROWS <= r < copies) + (copies,)
 
 
 class MOELayer(nn.Module):
@@ -810,55 +807,35 @@ class MOELayer(nn.Module):
         # at a prefill tick's 16,384 rows of 2,048 it made the tick 208 ms
         # (PERF.md, PR 26)
         permute_impl = "xla" if ragged else impl
-        held = self.experts_held
-        if held is not None and not ragged:
-            raise NotImplementedError(
-                "experts_held needs the drop-free grouped layout of one device "
-                "(drop_tokens=False over an expert bank); on a mesh the layer's "
-                "exchange is not built")
+        if self.experts_held is not None:
+            if not ragged:
+                raise NotImplementedError(
+                    "experts_held needs the drop-free grouped layout of one device "
+                    "(drop_tokens=False over an expert bank); on a mesh the layer's "
+                    "exchange is not built")
+            return self._held_route(gate, tokens, used_token, deterministic, impl, orig_dtype)
 
         with jax.named_scope("moe_route"):
-            l_aux, routing, exp_counts = gate(tokens, used_token, deterministic,
-                                              slots_by_sort=held is not None)
+            l_aux, routing, exp_counts = gate(tokens, used_token, deterministic)
             capacity = gate.capacity(num_tokens, deterministic)
             k = routing.expert.shape[-1]
             # which experts each token took, [G, S, k], best first (read
             # with mutable=["intermediates"]; costs nothing otherwise)
             self.sow("intermediates", "expert_choice", routing.expert)
-            if held is not None:
-                # a copy routed to an expert another device holds is that
-                # device's to compute: here it is dropped, as a copy past a
-                # capacity is (sentinel row, zero in the combine). The gate
-                # counted every choice's copies (``slots_by_sort``):
-                # the held experts' are this device's group sizes, and no
-                # scatter over the S k copies is needed for them
-                first, count = held
-                here = (routing.expert >= first) & (routing.expert < first + count)
-                routing = routing._replace(keep=routing.keep * here.astype(routing.keep.dtype))
-                routed_anywhere, sizes = exp_counts.sum(), exp_counts[first:first + count]
-                kept_counts = jnp.zeros((E,), jnp.int32).at[first:first + count].set(sizes)
-                # the held experts' groups only, packed from row 0: the
-                # sizes add up to less than the buffer's rows, and the
-                # grouped matmul runs over no tile past them
-                starts = jnp.cumsum(sizes) - sizes
-                base = starts[jnp.clip(routing.expert - first, 0, count - 1)].reshape(groups, -1)
+            kept_counts = jnp.zeros((E,), jnp.int32).at[routing.expert.reshape(-1)].add(
+                routing.keep.reshape(-1).astype(jnp.int32))
+            if ragged:
+                # one device, so one group (``_num_groups``): ``kept_counts``
+                # are its group sizes, and group e starts where the groups
+                # before it end. The buffer holds S*k rows, every one a copy
+                sizes = kept_counts
+                starts = jnp.cumsum(kept_counts) - kept_counts
+                base = starts[routing.expert].reshape(groups, -1)
                 rows = num_tokens * k
-                capacity = -(-rows // E)
+                capacity = -(-rows // E)   # evidence only: mean rows an expert
             else:
-                kept_counts = jnp.zeros((E,), jnp.int32).at[routing.expert.reshape(-1)].add(
-                    routing.keep.reshape(-1).astype(jnp.int32))
-                if ragged:
-                    # one device, so one group (``_num_groups``): ``kept_counts``
-                    # are its group sizes, and group e starts where the groups
-                    # before it end. The buffer holds S*k rows, every one a copy
-                    sizes = kept_counts
-                    starts = jnp.cumsum(kept_counts) - kept_counts
-                    base = starts[routing.expert].reshape(groups, -1)
-                    rows = num_tokens * k
-                    capacity = -(-rows // E)   # evidence only: mean rows an expert
-                else:
-                    base = (routing.expert * capacity).reshape(groups, -1)
-                    rows = E * capacity
+                base = (routing.expert * capacity).reshape(groups, -1)
+                rows = E * capacity
             # each kept copy owns a unique row base + position (the cumsum
             # position assignment is a stable counting sort by expert);
             # dropped copies park on the sentinel → zero rows / no reads
@@ -866,44 +843,16 @@ class MOELayer(nn.Module):
                                   base + routing.slot.reshape(groups, -1),
                                   rows).astype(jnp.int32)
             flat_slot = constrain(flat_slot, (BATCH_AXES, None))
-            if held is None:
-                src = inverse_index(flat_slot, rows)  # [G, rows] — row -> token copy
-            else:
-                # the kept copies' rows are 0 .. n-1 with no gap: sorted by
-                # row, the copies are the rows' sources (no scatter)
-                by_row, src = jax.lax.sort(
-                    (flat_slot, jax.lax.broadcasted_iota(jnp.int32, flat_slot.shape, 1)),
-                    dimension=1, num_keys=1)
-                src = jnp.where(by_row < rows, src, flat_slot.shape[1])
+            src = inverse_index(flat_slot, rows)  # [G, rows] — row -> token copy
             src = constrain(src, (BATCH_AXES, None))
 
-            if held is not None and self.is_mutable_collection("cache"):
-                # for the host, beside a serving tick's tokens: rows routed
-                # to experts held here, rows of the tiles the expert
-                # matmuls run over, copies routed to any expert, held
-                # experts that got a row (whose weights the tick streams)
-                from deepspeed_tpu.ops.pallas.grouped_matmul import rows_visited
-                self.variable("cache", "moe_rows", jnp.zeros, (4,), jnp.int32).value = jnp.stack(
-                    [sizes.sum(), rows_visited(sizes, rows), routed_anywhere,
-                     (sizes > 0).sum()]).astype(jnp.int32)
-            if self.latent_dim:
-                with jax.named_scope("moe_latent_down"):
-                    tokens = nn.Dense(self.latent_dim, use_bias=False, dtype=orig_dtype,
-                                      param_dtype=self.param_dtype,
-                                      kernel_init=nn.with_logical_partitioning(
-                                          nn.initializers.normal(0.02), ("embed", None)),
-                                      name="latent_down")(tokens)
-                d_model = self.latent_dim
+            tokens = self._latent("latent_down", tokens, orig_dtype)
+            d_model = tokens.shape[2]
             # [G, S, M] -> [G, S*k, M], copy j of token s at row s*k + j (the
             # reshape order of the [S, k] routing fields)
-            if held is None:
-                tok_rep = jnp.repeat(tokens, k, axis=1) if k > 1 else tokens
-                # dispatch = pure row permutation
-                dispatched = permute_rows(tok_rep, src, flat_slot, impl=permute_impl)
-            else:
-                # row -> its copy's token, with no k-fold copy of the tokens
-                token_of = jnp.where(src < num_tokens * k, src // k, num_tokens)
-                dispatched = permute_rows(tokens, token_of, token_of, impl="xla")
+            tok_rep = jnp.repeat(tokens, k, axis=1) if k > 1 else tokens
+            # dispatch = pure row permutation
+            dispatched = permute_rows(tok_rep, src, flat_slot, impl=permute_impl)
 
         with jax.named_scope("moe_experts"):
             experts = Experts(self.expert, self.num_experts, name="experts")
@@ -931,21 +880,108 @@ class MOELayer(nn.Module):
             weights = routing.weight.astype(orig_dtype).reshape(groups, num_tokens * k, 1)
             combined = (weights * gathered).reshape(groups, num_tokens, k, d_model).sum(axis=2)
             combined = constrain(combined, (BATCH_AXES, None, None))
-        if self.latent_dim:
-            with jax.named_scope("moe_latent_up"):
-                combined = nn.Dense(self.model_dim, use_bias=False, dtype=orig_dtype,
-                                    param_dtype=self.param_dtype,
-                                    kernel_init=nn.with_logical_partitioning(
-                                        nn.initializers.normal(0.02), (None, "embed")),
-                                    name="latent_up")(combined)
+        combined = self._latent("latent_up", combined, orig_dtype)
 
         # all k copies pre-capacity: the compact routing names every copy's
         # expert, so the kept denominator is exact for every k (k=1: equals
         # exp_counts; k>=2: adds the later choices the dense return hides)
-        if held is not None:
-            routed_counts = exp_counts           # the sorting gate counts every choice
-        else:
-            routed_counts = exp_counts if k == 1 else (
-                exp_counts
-                + jnp.zeros((E,), jnp.int32).at[routing.expert[..., 1:].reshape(-1)].add(1))
+        routed_counts = exp_counts if k == 1 else (
+            exp_counts
+            + jnp.zeros((E,), jnp.int32).at[routing.expert[..., 1:].reshape(-1)].add(1))
         return combined, l_aux, exp_counts, kept_counts, routed_counts, capacity
+
+    def _held_route(self, gate, tokens, used_token, deterministic, impl, orig_dtype):
+        """The sorted route where this device holds ``experts_held`` of the
+        experts (drop-free, one device, so one group): a copy routed to an
+        expert another device holds is that device's to compute, and a copy
+        of a padding position is nobody's. What moves is the rows that are
+        real and held here. Their number is known from the routing alone,
+        before any row moves, and the buffer that the gather, the expert
+        matmuls, the activation and the combine run over is sized by it, on
+        the device: the smallest of :func:`_row_rungs`' static sizes that
+        holds them, the largest every copy, so no routing can lose a row."""
+        from deepspeed_tpu.ops.pallas.grouped_matmul import rows_visited
+        from deepspeed_tpu.ops.pallas.moe_dispatch import permute_rows
+        num_tokens, E = tokens.shape[1], self.num_experts
+        first, count = self.experts_held
+
+        with jax.named_scope("moe_route"):
+            l_aux, routing, exp_counts = gate(tokens, used_token, deterministic,
+                                              positions=False)
+            k = routing.expert.shape[-1]
+            copies = num_tokens * k
+            # which experts each token took, [G, S, k], best first (read
+            # with mutable=["intermediates"]; costs nothing otherwise)
+            self.sow("intermediates", "expert_choice", routing.expert)
+            # the gate counted every choice's copies: the held experts' are
+            # this device's group sizes, packed from row 0
+            sizes = exp_counts[first:first + count]
+            held_rows = sizes.sum()
+            kept_counts = jnp.zeros((E,), jnp.int32).at[first:first + count].set(sizes)
+            # row -> copy: one stable sort puts the real copies of held
+            # experts first, by expert and inside one by token; copy j of
+            # token s is s*k + j (the reshape order of the [S, k] fields)
+            expert = routing.expert.reshape(-1) - first
+            mine = (routing.keep.reshape(-1) > 0) & (expert >= 0) & (expert < count)
+            _, copy_of = jax.lax.sort(
+                (jnp.where(mine, expert, count), jnp.arange(copies, dtype=jnp.int32)),
+                num_keys=1, is_stable=True)
+            rungs = _row_rungs(copies)
+            rung = sum((held_rows > rows).astype(jnp.int32) for rows in rungs[:-1])
+
+        if self.is_mutable_collection("cache"):
+            # for the host, beside a serving tick's tokens: rows routed to
+            # experts held here, rows of the tiles the expert matmuls run
+            # over, copies routed to any expert, held experts that got a row
+            # (whose weights the tick streams), rows of the buffer chosen
+            visited = jnp.stack([rows_visited(sizes, rows) for rows in rungs])
+            self.variable("cache", "moe_rows", jnp.zeros, (5,), jnp.int32).value = jnp.stack(
+                [held_rows, visited[rung], exp_counts.sum(), (sizes > 0).sum(),
+                 jnp.asarray(rungs, jnp.int32)[rung]]).astype(jnp.int32)
+
+        tokens = self._latent("latent_down", tokens, orig_dtype)[0]
+        weights = routing.weight.reshape(-1)
+
+        def through(rows, experts, tokens, weights, copy_of, held_rows, sizes):
+            """The held rows through a buffer of ``rows`` rows: [S, M]."""
+            copy_of = copy_of[:rows]
+            real = jnp.arange(rows, dtype=jnp.int32) < held_rows
+            with jax.named_scope("moe_route"):
+                token_of = jnp.where(real, copy_of // k, num_tokens)
+                dispatched = permute_rows(tokens[None], token_of[None], token_of[None],
+                                          impl="xla")[0]
+            with jax.named_scope("moe_experts"):
+                out = experts(dispatched, deterministic, group_sizes=sizes, impl=impl)
+            with jax.named_scope("moe_combine"):
+                # each row back to its token, weighted: a token's up to k rows
+                # add up in float32. Rows past the held ones hold no defined
+                # result (``grouped_matmul``) and add nothing
+                weighted = jnp.where(real[:, None],
+                                     weights[copy_of].astype(orig_dtype)[:, None] * out, 0)
+                return jnp.zeros(tokens.shape, jnp.float32).at[token_of].add(
+                    weighted.astype(jnp.float32), mode="drop").astype(orig_dtype)
+
+        experts = Experts(self.expert, self.num_experts, name="experts")
+        buffers = [functools.partial(through, rows) for rows in rungs]
+        operands = (tokens, weights, copy_of, held_rows, sizes)
+        if len(buffers) == 1:
+            combined = buffers[0](experts, *operands)
+        else:
+            combined = nn.switch(rung, buffers, experts, *operands)
+        combined = self._latent("latent_up", combined[None], orig_dtype)
+        # the gate counts every choice: ``exp_counts`` are the routed counts
+        return combined, l_aux, exp_counts, kept_counts, exp_counts, -(-copies // E)
+
+    def _latent(self, name, x, dtype):
+        """One of the two bias-free projections around the experts' latent
+        space (``latent_dim``), or ``x`` as it is where there is none."""
+        if not self.latent_dim:
+            return x
+        down = name == "latent_down"
+        with jax.named_scope("moe_" + name):
+            return nn.Dense(self.latent_dim if down else self.model_dim, use_bias=False,
+                            dtype=dtype, param_dtype=self.param_dtype,
+                            kernel_init=nn.with_logical_partitioning(
+                                nn.initializers.normal(0.02),
+                                ("embed", None) if down else (None, "embed")),
+                            name=name)(x)

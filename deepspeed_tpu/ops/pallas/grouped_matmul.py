@@ -9,7 +9,8 @@ and every expert projection becomes
 for the ``E`` groups of ``group_sizes``. Rows past ``sum(group_sizes)``
 hold no defined result, and no tile that lies wholly past them is computed:
 a layer that holds only some of the experts (``MOELayer.experts_held``)
-hands the kernel its own groups and a buffer sized for every copy.
+hands the kernel its own groups in the smallest of a few static buffers
+that holds them.
 
 Implementations, chosen by the same kernel choice as the row permutation
 (``MOELayer.route_kernel``):

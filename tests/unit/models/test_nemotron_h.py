@@ -21,7 +21,7 @@ from deepspeed_tpu.inference.serving.scheduler import MigrationError
 from deepspeed_tpu.models.common import init_cache
 from deepspeed_tpu.models.nemotron_h import (NemotronHBlock, NemotronHForCausalLM,
                                              get_nemotron_h_config, ssd_chunk_scan, ssm_step)
-from deepspeed_tpu.moe.sharded_moe import topkrouting
+from deepspeed_tpu.moe.sharded_moe import _row_rungs, topkrouting
 from deepspeed_tpu.utils import trace
 
 EXPERTS, QUARTER = 16, 4
@@ -236,6 +236,89 @@ def test_four_quarters_of_the_experts_add_up_to_the_uncut_layer(whole):
                                    atol=2e-5)
     np.testing.assert_allclose(np.asarray(x + sum(parts) + ref.shared(bp, h)), np.asarray(uncut),
                                atol=2e-5)
+
+
+# 32 x 128 positions x top-4 = 16,384 copies: the held layer's row buffer is
+# 1,024, 4,096 or all 16,384 rows (``sharded_moe._row_rungs``)
+RUNG_TOKENS, RUNGS = (32, 128), (1024, 4096, 16384)
+
+
+@pytest.fixture(scope="module")
+def rung_layer(whole):
+    """The first expert layer over 4,096 positions, its experts' matrices
+    sixteen times the seeded draw (as drawn the routed part is 1e-7 of the
+    residual it is added to, and a lost row would go unseen): which of each
+    token's experts a share holds, and for a share the routed part by the
+    package's layer (jitted once, ``used`` its operand) beside
+    the reference's uncompacted sum, every token through every held expert."""
+    module, params = whole
+    cfg = module.config
+    assert _row_rungs(RUNG_TOKENS[0] * RUNG_TOKENS[1] * cfg.num_experts_per_tok) == RUNGS
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf * 16 if "deepspeed_experts" in [getattr(p, "key", "") for p in path]
+        else leaf, params)
+    bp = ref.block_params(family.to_reference(params), 1)
+    x = jax.random.normal(jax.random.PRNGKey(36), RUNG_TOKENS + (cfg.hidden_size,))
+    h = ref.rms_norm(x, bp["ln"])
+    weights = ref.router(bp, h, sizes_of(cfg))
+    rest = x + ref.shared(bp, h)
+    with jax.default_matmul_precision("highest"):
+        scores = np.sort(np.asarray(jax.nn.sigmoid(h @ bp["router"]) + bp["router_bias"]), axis=-1)
+    # no token's fourth and fifth scores so close that another rounding picks the other
+    assert (scores[..., -4] - scores[..., -5]).min() > 5e-6
+    made = {}
+
+    def share(first, count):
+        if (first, count) not in made:
+            layer = NemotronHBlock(build((first, count)).config, "E")
+            mine = held_params(params, first, count)["layers_1"]
+            bank = dict(bp, w1=bp["w1"][first:first + count], w2=bp["w2"][first:first + count])
+
+            @jax.jit
+            def routed(used):
+                out, state = layer.apply({"params": mine}, x, used=used, mutable=["cache"])
+                want = ref.routed(bank, h, weights * used.reshape(RUNG_TOKENS + (1,)),
+                                  sizes_of(cfg, first))
+                return out - rest, want, state["cache"]["mixer"]["moe_rows"]
+            made[first, count] = routed
+        held = np.asarray(weights).reshape(-1, EXPERTS)[:, first:first + count] > 0
+        return held.sum(axis=1), held, made[first, count]
+
+    return share
+
+
+def tokens_that_hold(per_token, rows):
+    """A ``used_token`` mask whose tokens' held copies add up to ``rows``."""
+    used, left = np.zeros(len(per_token), bool), rows
+    for t in np.argsort(-per_token, kind="stable"):         # the fullest first, ones to finish
+        if 0 < per_token[t] <= left:
+            used[t], left = True, left - per_token[t]
+    assert left == 0
+    if rows == 0:
+        used[per_token == 0] = True                         # real tokens, every copy elsewhere
+    return used
+
+
+@pytest.mark.parametrize("held, rows, rung", [
+    ((0, 8), 0, 0), ((0, 8), 1, 0), ((0, 8), 1023, 0), ((0, 8), 1024, 0), ((0, 8), 1025, 1),
+    ((0, 8), 4095, 1), ((0, 8), 4096, 1), ((0, 8), 4097, 2), ((4, 4), 700, 0),
+    ((0, EXPERTS), 16384, 2),                               # every copy held and used
+    ((0, 8), None, 0),                                      # every token unused
+], ids=str)
+def test_the_row_buffer_follows_the_rows_held_and_loses_none(rung_layer, held, rows, rung):
+    """On every rung and on both sides of each boundary the layer with a
+    share held gives the uncompacted sum over that share, and the fifth
+    counter names the buffer it took."""
+    per_token, held_by, routed = rung_layer(*held)
+    used = (np.zeros(len(per_token), bool) if rows is None
+            else tokens_that_hold(per_token, rows))
+    got, want, counted = routed(jnp.asarray(used))
+    assert rows in (0, None) or float(jnp.abs(want).max()) > 1e-3      # not a sum of nothing
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    here, visited, anywhere, touched, buffered = np.asarray(counted)
+    assert here == (rows or 0) and anywhere == 4 * used.sum()
+    assert buffered == RUNGS[rung] and here <= visited
+    assert touched == held_by[used].any(axis=0).sum()
 
 
 def test_four_vocabulary_slices_concatenate_to_the_whole(whole):

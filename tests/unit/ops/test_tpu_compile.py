@@ -335,6 +335,7 @@ def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, pr
                                                           build_prefill_step,
                                                           make_apply_fn, make_slot_cache)
     from deepspeed_tpu.models.nemotron_h import NemotronHForCausalLM, get_nemotron_h_config
+    from deepspeed_tpu.moe.sharded_moe import _row_rungs
 
     slots, chunk = 16, 128
     cfg = get_nemotron_h_config(
@@ -356,7 +357,12 @@ def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, pr
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
         operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
-    assert compiled.as_text().count("tpu_custom_call") == 4       # two expert layers x up, down
+    # two expert layers x (up, down) x the row buffers the held layer may
+    # take (``sharded_moe._row_rungs``): a prefill tick's 16 x 128 x top-4 =
+    # 8,192 copies take 2,048 rows or all (a sixteenth, 512, is under
+    # ``MIN_RUNG_ROWS``), a decode tick's 64 copies one buffer and no branch
+    assert _row_rungs(slots * chunk * 4) == (2048, 8192) and _row_rungs(slots * 4) == (64,)
+    assert compiled.as_text().count("tpu_custom_call") == (8 if program == "prefill" else 4)
     state = cache["layers_0"]["mixer"]["ssm_state"]
     assert state.shape == (slots, 2, 8, 64, 128) and state.dtype == jnp.float32
     # (a prefill tick relays its chunk's activations, as large at these widths)
@@ -369,6 +375,10 @@ def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, pr
     if program == "decode":
         # one step: nothing the size of a state leaf is held beside the cache
         assert memory.temp_size_in_bytes < state.size * 4
+    else:
+        # the branches share their temporaries, and the largest buffer's are
+        # those of one buffer for every copy, which compiles to 31,870,976 bytes
+        assert memory.temp_size_in_bytes <= 31_870_976
 
 
 def _train_engine(devices, zero_stage, fsdp):
