@@ -1,0 +1,33 @@
+"""The absorbed decode step's kernel at its roofline: the least time the chip
+could take for every decode tick's kernels in the traced slice (each tick at
+the mean decode tick's shape, ``lib/joyai_llm_flash_ticks.py``: a layer's
+kernel reads the fed slots' live latent positions once, 1,152 B each, and
+pays heads x (2 x rank + rope) a query-position pair;
+``lib/opcounts_joyai_llm_flash.py``) over those kernels' device time
+(``pallas:mla:decode``: ``ops/pallas/latent_decode.py``'s call, which the
+family's ``op_label`` names from ``%mla_decode*``). The time is the device
+trace's; the live positions are the runner's count and the fed slots the
+program's. A program with no such kernel (the parent, or XLA's two matmuls
+over the whole pool) reads nothing.
+
+``mla_attn_time_pct_longdoc`` is the same kernels' share of device-busy time
+(``pallas:mla:*``). The prefill walk is XLA loops and fusions the trace
+cannot name (``lib/trace.py::load`` keeps no scope), so it is in neither and
+there is no ``mla_prefill_roofline_longdoc``: ``prefill_roofline_longdoc``
+carries it, and ``tools/mla_attention_time.py`` times the walk alone."""
+
+from benchmarks.lib import harness, joyai_llm_flash_ticks, program_spans, reducers
+
+
+def read(ctx):
+    kernel_s = reducers.op_seconds(ctx, "^pallas:mla:decode")
+    if not kernel_s or ctx["peaks"] is None:
+        return None
+    ticks = joyai_llm_flash_ticks.traced_ticks(ctx["trace"]["window_s"]).get("decode")
+    if not ticks:
+        return None
+    least_s = joyai_llm_flash_ticks.decode_kernels_least_s(
+        ctx["cell"].config, program_spans.ring()[1], ctx["counters"], ctx["peaks"], ticks)
+    harness.log(mla_decode_roofline={"traced_decode_ticks": ticks, "kernel_s": kernel_s,
+                                     "least_s": least_s})
+    return 100.0 * least_s / kernel_s if least_s else None

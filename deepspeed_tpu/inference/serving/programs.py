@@ -27,14 +27,18 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 # the cache's leaf names are the models' (``models/common.py`` DecodeCache):
 # INDEX_LEAVES hold write positions (scalar in ``generate``'s lockstep
-# cache; [slots] vectors in the serving cache), KV_LEAVES are the pools
+# cache; [slots] vectors in the serving cache), KV_LEAVES are the pools of
+# keys and values, POOL_LEAVES those and a latent-attention layer's one
+# ``cached_latent`` pool (``models/common.py`` LatentCache): every pool that
+# holds rows by position
 # a model with recurrent layers (``models/nemotron_h.py``) adds STATE_LEAVES
 # (per-slot state with no positions, ``[slots, ...]`` as the model shapes
 # it), LENGTH_LEAVES (how many of a slot's tokens this tick are real) and,
 # where a layer counts for the host, COUNTER_LEAVES
 from deepspeed_tpu.models.common import (COUNTER_LEAVES, INDEX_LEAVES, KV_LEAVES,
-                                         LENGTH_LEAVES, STATE_LEAVES, slot_pool,
-                                         slot_pool_positions, slot_pool_scale)
+                                         LATENT_LEAVES, LENGTH_LEAVES, POOL_LEAVES,
+                                         STATE_LEAVES, slot_pool, slot_pool_positions,
+                                         slot_pool_scale)
 from deepspeed_tpu.utils import trace
 
 
@@ -76,7 +80,7 @@ def make_slot_cache(module, slots: int, kv_quant: bool = False):
         def leaf_of(path, leaf):
             if _is_index_leaf(path) or _leaf_name(path) in LENGTH_LEAVES:
                 return jnp.zeros((slots,), jnp.int32)
-            return slot_pool(leaf) if _leaf_name(path) in KV_LEAVES else leaf
+            return slot_pool(leaf) if _leaf_name(path) in POOL_LEAVES else leaf
 
         cache = jax.tree_util.tree_map_with_path(leaf_of, cache)
         return quantize_slot_cache(cache) if kv_quant else cache
@@ -101,6 +105,11 @@ def quantize_slot_cache(cache):
         for name, leaf in tree.items():
             if isinstance(leaf, dict) or hasattr(leaf, "items"):
                 out[name] = walk(leaf)
+            elif name in LATENT_LEAVES:
+                raise NotImplementedError(
+                    f"kv_quant over a latent pool ({name}): an int8 latent is not built (every "
+                    f"head reads it through two projections, so its tolerance is its own); "
+                    f"serve this model with kv_quant=False")
             elif name in KV_LEAVES:
                 out[name] = jnp.zeros(leaf.shape, jnp.int8)
                 out[name + "_scale"] = slot_pool_scale(leaf)
@@ -116,9 +125,9 @@ def slot_capacity(cache) -> int:
     extent (also the parked-slot sentinel: a write at this position drops
     out of bounds)."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        if _leaf_name(path) in KV_LEAVES:
+        if _leaf_name(path) in POOL_LEAVES:
             return slot_pool_positions(leaf)
-    raise ValueError("cache has no cached_key leaves — not a decode cache")
+    raise ValueError("cache has no cached_key or cached_latent leaves — not a decode cache")
 
 
 def with_write_positions(cache, write_pos, fed=1):
@@ -147,11 +156,19 @@ def with_write_positions(cache, write_pos, fed=1):
 
 def with_counters(cache, tok):
     """Traced: what a tick reads back. ``tok`` [slots] int32, and behind it
-    the sum of the cache's ``COUNTER_LEAVES`` (int32 vectors a layer left for
-    the host: ``MOELayer.experts_held``'s rows) where the model has any, so
-    that the one read-back a tick makes carries them."""
-    counters = _leaves_named(cache, COUNTER_LEAVES)
-    return jnp.concatenate([tok, sum(counters)]) if counters else tok
+    the cache's ``COUNTER_LEAVES`` (int32 vectors a layer left for the host:
+    ``MOELayer.experts_held``'s rows, a latent-attention layer's reads), each
+    name's summed over the layers, in ``COUNTER_LEAVES``' order, where the
+    model has any, so that the one read-back a tick makes carries them."""
+    counters = [sum(leaves) for name in COUNTER_LEAVES
+                if (leaves := _leaves_named(cache, (name,)))]
+    return jnp.concatenate([tok, *counters]) if counters else tok
+
+
+def counter_widths(cache):
+    """``(name, int32s)`` of what :func:`with_counters` appends, in order."""
+    return [(name, leaves[0].shape[0]) for name in COUNTER_LEAVES
+            if (leaves := _leaves_named(cache, (name,)))]
 
 
 def has_recurrent_state(cache) -> bool:

@@ -41,7 +41,7 @@ import jax
 
 from deepspeed_tpu.inference.serving.blocks import BlockPool
 from deepspeed_tpu.inference.serving.config import ServingConfig
-from deepspeed_tpu.inference.serving.programs import (KV_LEAVES, _leaf_name,
+from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, _leaf_name, counter_widths,
                                                       has_recurrent_state, make_slot_cache,
                                                       serve_programs, slot_capacity,
                                                       state_bytes_per_slot)
@@ -185,6 +185,8 @@ class ContinuousBatchingScheduler:
         # assumes rows refuses it by name until the state has snapshots
         self._recurrent = has_recurrent_state(self._cache)
         self._state_bytes = state_bytes_per_slot(self._cache)
+        # what the programs count for the host behind a tick's tokens
+        self._counters = counter_widths(self._cache)
         if self._recurrent and config.prefix_cache == "on":
             raise NotImplementedError(
                 f"prefix_cache='on' over {type(self.module).__name__}: a shared prefix is "
@@ -323,28 +325,39 @@ class ContinuousBatchingScheduler:
         total = 0
         for path, leaf in jax.tree_util.tree_flatten_with_path(self._cache)[0]:
             name = _leaf_name(path)
-            if name in KV_LEAVES or name.endswith("_scale"):
+            if name in POOL_LEAVES or name.endswith("_scale"):
                 total += leaf.size * leaf.dtype.itemsize
         return total / float(self.slots * self.capacity)
 
     def _read_back(self, tok, kind: str) -> np.ndarray:
         """The tick's blocking read-back: the tokens [slots], and behind
         them whatever the program counted for the host
-        (``programs.with_counters``): an expert layer that holds a share of
-        its experts decides on the device which rows are its own. Rows and
-        experts touched are also kept by the kind of tick, for whoever
-        works out what a tick of that kind had to stream, and so are the
-        rows of the buffers the layers sized for their routed rows."""
+        (``programs.with_counters``). ``moe_rows``: an expert layer that
+        holds a share of its experts decides on the device which rows are
+        its own. Rows and experts touched are also kept by the kind of tick,
+        for whoever works out what a tick of that kind had to stream, and so
+        are the rows of the buffers the layers sized for their routed rows.
+        ``latent_reads``: the positions of its pools a latent attention's
+        loops were bounded to, against the positions that held a token, by
+        the kind of tick, and the bytes it wrote there."""
         tok = np.asarray(tok)
-        if len(tok) > self.slots:
-            here, computed, anywhere, touched, buffered = (int(n) for n in tok[self.slots:])
-            self._rec.count("moe_rows_routed", here)
-            self._rec.count("moe_rows_elsewhere", anywhere - here)
-            self._rec.count("moe_rows_computed", computed)
-            self._rec.count("moe_rows_buffered", buffered)
-            self._rec.count(f"moe_rows_routed_{kind}", here)
-            self._rec.count(f"moe_rows_buffered_{kind}", buffered)
-            self._rec.count(f"moe_experts_touched_{kind}", touched)
+        behind = tok[self.slots:]
+        for name, width in self._counters:
+            counted, behind = [int(n) for n in behind[:width]], behind[width:]
+            if name == "moe_rows":
+                here, computed, anywhere, touched, buffered = counted
+                self._rec.count("moe_rows_routed", here)
+                self._rec.count("moe_rows_elsewhere", anywhere - here)
+                self._rec.count("moe_rows_computed", computed)
+                self._rec.count("moe_rows_buffered", buffered)
+                self._rec.count(f"moe_rows_routed_{kind}", here)
+                self._rec.count(f"moe_rows_buffered_{kind}", buffered)
+                self._rec.count(f"moe_experts_touched_{kind}", touched)
+            elif name == "latent_reads":
+                read, live, written = counted
+                self._rec.count(f"latent_positions_read_{kind}", read)
+                self._rec.count(f"latent_positions_live_{kind}", live)
+                self._rec.count("latent_bytes_written", written)
         return tok[:self.slots]
 
     def _count_state(self, write_pos: np.ndarray, fed: Optional[int] = None,
@@ -476,7 +489,7 @@ class ContinuousBatchingScheduler:
         out: Dict[str, np.ndarray] = {}
         for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
             name = _leaf_name(path)
-            if name in KV_LEAVES or name.endswith("_scale"):
+            if name in POOL_LEAVES or name.endswith("_scale"):
                 host = np.asarray(jax.device_get(leaf))
                 out[jax.tree_util.keystr(path)] = slot_pool_rows(host, slot, start, stop)
         return out
@@ -951,7 +964,8 @@ class ContinuousBatchingScheduler:
     # ------------------------------------------------------------------
     def _kv_slot_leaves(self, cache, slot: int, length: int) -> Dict[str, np.ndarray]:
         """Host copies of one slot's committed KV rows — every pool leaf
-        (``KV_LEAVES``) plus its kv_quant ``*_scale`` companion, keyed by
+        (``POOL_LEAVES``: keys and values, or a latent-attention layer's one
+        latent pool) plus its kv_quant ``*_scale`` companion, keyed by
         the leaf's ``keystr`` path so target and drafter caches (same leaf
         names, different depths) stay unambiguous. Only ``[:length]`` rows
         travel: everything past the committed prefix is scratch."""
@@ -995,7 +1009,7 @@ class ContinuousBatchingScheduler:
         kv_idx, rows = [], []
         for i, (path, leaf) in enumerate(flat):
             name = _leaf_name(path)
-            if name not in KV_LEAVES and not name.endswith("_scale"):
+            if name not in POOL_LEAVES and not name.endswith("_scale"):
                 continue
             key = jax.tree_util.keystr(path)
             src = leaves.get(key)

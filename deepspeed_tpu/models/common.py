@@ -44,16 +44,26 @@ def init_cache(model: nn.Module, batch_size: int, rng=None):
 #: model with a learned position table (GPT-2) keeps at its top level
 KV_LEAVES = ("cached_key", "cached_value")
 INDEX_LEAVES = ("cache_index", "position_index")
+#: a third kind of per-position pool (:class:`LatentCache`, ``models/deepseek_v3.py``):
+#: ONE pool a layer that all the heads share, the compressed latent and the
+#: rotated rope key of a position, with no value pool beside it. In the stored
+#: form it is a pool of one "head" as wide as the latent, so whoever carries a
+#: slot's rows by position (prefix blocks, a migrated slot) carries its rows as
+#: it carries keys and values: ``POOL_LEAVES`` is what such a walker asks for
+LATENT_LEAVES = ("cached_latent",)
+POOL_LEAVES = KV_LEAVES + LATENT_LEAVES
 #: a second kind of per-slot state, with no positions (``models/nemotron_h.py``):
 #: a recurrent layer's state ``[slots, ...]``, carried from tick to tick, never
 #: quantised, zeroed when a request joins at position 0; ``LENGTH_LEAVES`` say
 #: how many of the tokens a slot is handed this call are real (a recurrence
 #: must not advance over a chunk's padding, nor a parked slot at all), and
 #: ``COUNTER_LEAVES`` are int32 counts a layer leaves for the host, which a
-#: serving program sums and returns beside its tokens
+#: serving program sums over the layers, name by name, and returns beside its
+#: tokens (``moe_rows``: an expert layer that holds a share; ``latent_reads``:
+#: a latent-attention layer, positions read, positions live, bytes written)
 STATE_LEAVES = ("ssm_state", "conv_state")
 LENGTH_LEAVES = ("chunk_length",)
-COUNTER_LEAVES = ("moe_rows",)
+COUNTER_LEAVES = ("moe_rows", "latent_reads")
 
 
 class DecodeCache:
@@ -144,6 +154,45 @@ class DecodeCache:
         for pool, new in zip(pools, slot_pool_append([p.value for p in pools], vals,
                                                      self.index.value)):
             pool.value = new
+
+
+class LatentCache:
+    """One latent-attention layer's decode cache: ONE pool ``cached_latent``
+    of ``width`` values a position (the normed latent and the rotated rope
+    key, side by side) that every head reads, and the write index. No value
+    pool: the heads' keys and values are both linear in the latent.
+
+    As :class:`DecodeCache`, the provided cache decides the branch: a scalar
+    ``cache_index`` is lockstep ``generate`` (pool [batch, positions, 1,
+    width]); a ``[slots]`` vector is the serving cache, the pool stored
+    positions minor-most [slots, 1, width, positions] and written in place by
+    :func:`slot_pool_append`. An int8 latent is not built
+    (``serving/programs.py`` ``quantize_slot_cache`` refuses it by name)."""
+
+    def __init__(self, module: nn.Module, batch: int, positions: int, width: int, dtype):
+        self.pool = module.variable("cache", "cached_latent", jnp.zeros,
+                                    (batch, positions, 1, width), dtype)
+        self.index = module.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
+
+    @property
+    def per_slot(self) -> bool:
+        return self.index.value.ndim > 0
+
+    def append(self, latent):
+        """Write ``latent`` [batch, l, width] at the index and advance it;
+        returns ``(pool, start)``: the whole pool as it is stored for serving,
+        [batch, width, positions] (the serving pool itself, no copy; a
+        lockstep pool's transpose), and each sequence's first written
+        position [batch]."""
+        b, l = latent.shape[:2]
+        idx = self.index.value
+        self.index.value = idx + l
+        new = latent[:, :, None, :].astype(self.pool.value.dtype)
+        if self.per_slot:
+            self.pool.value, = slot_pool_append([self.pool.value], [new], idx)
+            return self.pool.value[:, 0], idx
+        self.pool.value = jax.lax.dynamic_update_slice(self.pool.value, new, (0, idx, 0, 0))
+        return jnp.transpose(self.pool.value[:, :, 0], (0, 2, 1)), jnp.broadcast_to(idx, (b,))
 
 
 def slot_pool(leaf):

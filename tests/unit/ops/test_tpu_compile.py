@@ -381,6 +381,59 @@ def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, pr
         assert memory.temp_size_in_bytes <= 31_870_976
 
 
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_joyai_llm_flash_serving_program_reads_the_latent_pool_as_it_lies(one_chip, program):
+    """The long-document cell's programs at the published widths and two
+    layers (the dense one and an expert layer, 64 of 256 held): the cell's 32 slots x
+    16,384 positions of ONE bfloat16 latent pool a layer, 576 values a
+    position, donated and written in place. The decode tick is absorbed: no
+    copy, convert or transpose of anything the size of a pool, and its
+    temporaries are a fortieth of one. The prefill tick walks the keys in
+    blocks: nothing near the [slots, heads, chunk, positions] scores (25.8
+    GB) or an expanded pool (6.4 GB) is ever held."""
+    import flax.linen as nn
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step,
+                                                          make_apply_fn, make_slot_cache)
+    from deepspeed_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM, get_deepseek_v3_config
+
+    slots, chunk, positions, layers = 32, 512, 16384, 2
+    cfg = get_deepseek_v3_config("joyai-llm-flash", num_hidden_layers=layers, vocab_size=32320,
+                                 experts_held=(0, 64), decode_cache_len=positions, dtype=bf16,
+                                 param_dtype=bf16)
+    module = DeepseekV3ForCausalLM(cfg)
+    params = jax.eval_shape(
+        lambda key: nn.meta.unbox(module.init(key, jnp.zeros((1, 8), jnp.int32))["params"]),
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots))
+    pools = [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+             if path[-1].key == "cached_latent"]
+    assert [(p.shape, p.dtype) for p in pools] == [((slots, 1, 576, positions), bf16)] * layers
+    assert not [path for path, _ in jax.tree_util.tree_flatten_with_path(cache)[0]
+                if path[-1].key in ("cached_key", "cached_value")]
+    apply_fn = make_apply_fn(module)
+    if program == "prefill":
+        step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, chunk, dtype=jnp.int32),
+                    _shape(slots, dtype=jnp.int32))
+    else:
+        step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
+    compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
+    pool_bytes = pools[0].size * 2
+    assert not _relayouts(compiled, pools[0].size)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= layers * pool_bytes
+    if program == "decode":
+        # one kernel a layer reads the pool, as it lies (``latent_decode``)
+        assert compiled.as_text().count("%mla_decode") >= layers
+        assert memory.temp_size_in_bytes < pool_bytes // 40        # against 604 MB
+    else:
+        # the largest row buffer of the held route (131,072 copies of 2,048) and
+        # the dense layer's 7,168-wide activations; compiles to 1.82 GB
+        assert memory.temp_size_in_bytes < 2.0e9
+
+
 def _train_engine(devices, zero_stage, fsdp):
     import deepspeed_tpu
     from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
