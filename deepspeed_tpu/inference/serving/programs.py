@@ -13,6 +13,16 @@ writes drop out of bounds and its (garbage, finite) logits are discarded
 on the host. Rollback after a rejected speculation is therefore free —
 the next tick's operand simply doesn't advance past the accepted prefix.
 
+A prefill tick feeds few slots when few prompts are in flight, and a
+program over every slot then computes mostly parked ones. So the prefill
+program also exists over fewer sequences (:func:`prefill_rungs`; one builder,
+the size read from the operands): it is handed ``slot_ids [n] int32``, the
+slot of each sequence it runs, and ``write_pos``, ``ids`` and ``last_idx`` of
+those ``n`` alone. The model then sees a batch of ``n``; only what holds a
+row a slot is indexed (:func:`rows_of_slots`). A tick runs the smallest rung
+that holds the slots it feeds. The rung over every slot is the program above,
+with nothing indexed, and the only one over a latent pool or on a mesh.
+
 Programs are cached on the target :class:`InferenceEngine` keyed by the
 pow2 slot bucket (``engine._pow2_bucket`` — the same bucketing discipline
 as ``generate``), so schedulers and repeated deployments reuse
@@ -37,8 +47,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 # where a layer counts for the host, COUNTER_LEAVES
 from deepspeed_tpu.models.common import (COUNTER_LEAVES, INDEX_LEAVES, KV_LEAVES,
                                          LATENT_LEAVES, LENGTH_LEAVES, POOL_LEAVES,
-                                         STATE_LEAVES, slot_pool, slot_pool_positions,
-                                         slot_pool_scale)
+                                         SLOT_LEAF, STATE_LEAVES, slot_pool,
+                                         slot_pool_positions, slot_pool_scale)
 from deepspeed_tpu.utils import trace
 
 
@@ -154,8 +164,66 @@ def with_write_positions(cache, write_pos, fed=1):
     return jax.tree_util.tree_map_with_path(sub, cache)
 
 
+def prefill_rungs(slots: int, mesh_size: int = 1, cache=None) -> tuple:
+    """The sequence counts the prefill program is built at, ascending, the
+    last every slot: a quarter of the slots, and all. Two, because a rung is
+    a program: a model's trace, a lowering and an executable to load at
+    set-up (2.3-6.6 s warm in the benchmark's cells) and its code on the
+    device (94-116 MB for GPT-2 medium); the chip read a half rung as worth
+    less than that (``PERF.md`` section 6, PR 33). Only all:
+
+    * on a mesh of more than one device: the slots are sharded over ``data``
+      there, and a row picked by number would cross devices;
+    * where ``cache`` holds a latent pool: its prefill attention already
+      walks the fed slots alone, a block of a slot's pool at a time, which is
+      two thirds of that tick, so a rung has the least to spare where it
+      costs set-up the most (the long-document cell: 6.6 s of 64, no gain)."""
+    latent = cache is not None and bool(_leaves_named(cache, LATENT_LEAVES))
+    if mesh_size > 1 or slots < 4 or latent:
+        return (slots,)
+    return (slots // 4, slots)
+
+
+def rows_of_slots(cache, slot_ids):
+    """Traced: ``cache`` as a model sees it that runs the ``n`` sequences
+    ``slot_ids``: the recurrent state (``STATE_LEAVES``) of those slots,
+    gathered to ``[n, ...]``, and ``slot_ids`` laid beside every pool
+    (``SLOT_LEAF``), so that ``DecodeCache`` indexes the pools' rows where it
+    writes and reads them: no pool is gathered here.
+    Index and length leaves are ``[n]`` already (:func:`with_write_positions`)."""
+
+    def walk(tree):
+        if any(name in LATENT_LEAVES for name in tree):
+            raise NotImplementedError("a latent pool is read in place, a slot at a time: "
+                                      "the prefill program over it has one size, every slot")
+        out = {name: walk(leaf) if hasattr(leaf, "items")
+               else leaf[slot_ids] if name in STATE_LEAVES else leaf
+               for name, leaf in tree.items()}
+        if any(name in KV_LEAVES for name in tree):
+            out[SLOT_LEAF] = slot_ids
+        return out
+
+    return walk(cache)
+
+
+def rows_to_slots(cache, ran, slot_ids):
+    """Traced: the whole cache after a model ran :func:`rows_of_slots` of
+    it: what came back ``[n, ...]`` (state, index and length leaves) put into
+    rows ``slot_ids`` of ``cache``'s, the other slots' rows as they were; the
+    pools, written in place by row, and the counters as they came back."""
+    by_row = STATE_LEAVES + INDEX_LEAVES + LENGTH_LEAVES
+
+    def walk(old, new):
+        return {name: walk(leaf, new[name]) if hasattr(leaf, "items")
+                else leaf.at[slot_ids].set(new[name]) if name in by_row else new[name]
+                for name, leaf in old.items()}
+
+    return walk(cache, ran)
+
+
 def with_counters(cache, tok):
-    """Traced: what a tick reads back. ``tok`` [slots] int32, and behind it
+    """Traced: what a tick reads back. ``tok`` [slots] int32 (a rung's: one
+    a sequence it ran), and behind it
     the cache's ``COUNTER_LEAVES`` (int32 vectors a layer left for the host:
     ``MOELayer.experts_held``'s rows, a latent-attention layer's reads), each
     name's summed over the layers, in ``COUNTER_LEAVES``' order, where the
@@ -212,7 +280,7 @@ def make_apply_fn(module, mparams: Optional[Callable] = None) -> Callable:
 
 
 def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
-                       top_k: int, top_p: float) -> Callable:
+                       top_k: int, top_p: float, rung: bool = False) -> Callable:
     """One chunked-prefill tick: consume ``ids [S, C]`` at each slot's own
     write position (``write_pos [S]``). ``last_idx [S]`` names each slot's
     final REAL token in the chunk (a short final chunk is right-padded; pad
@@ -223,29 +291,39 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
     has no positions to overwrite, is handed ``last_idx + 1`` as the slot's
     real length and does not advance past it). The chunk that
     completes a prompt samples the request's FIRST token from its
-    last-real-position logits, so TTFT stops at prefill completion."""
+    last-real-position logits, so TTFT stops at prefill completion.
+
+    ``rung=True`` builds the program over fewer sequences than slots: it
+    takes ``slot_ids [n] int32`` before ``write_pos`` (distinct slots; the
+    operands behind it are ``[n]`` and ``[n, C]``; an entry that only fills
+    the rung is a slot parked at the sentinel, which writes nothing as a
+    parked slot of the whole program writes nothing) and returns ``n``
+    tokens. The cache goes in and comes back whole."""
     import jax.numpy as jnp
 
     from deepspeed_tpu.inference.engine import sample_logits
 
-    def last_logits(logits, last_idx):
-        return jnp.take_along_axis(logits, last_idx[:, None, None], axis=1)[:, 0]
+    def prefill(params, cache, write_pos, ids, last_idx, *rng, slot_ids=None):
+        fed = with_write_positions(cache, write_pos, last_idx + 1)
+        if slot_ids is None:
+            logits, cache = apply_fn(params, fed, ids)
+        else:
+            logits, ran = apply_fn(params, rows_of_slots(fed, slot_ids), ids)
+            cache = rows_to_slots(cache, ran, slot_ids)
+        logits = jnp.take_along_axis(logits, last_idx[:, None, None], axis=1)[:, 0]
+        if do_sample:
+            tok = sample_logits(logits, *rng, True, temperature, top_k, top_p)
+        else:
+            tok = jnp.argmax(logits, axis=-1)
+        return cache, with_counters(cache, tok.astype(jnp.int32))
 
-    if do_sample:
-        def prefill(params, cache, write_pos, ids, last_idx, rng):
-            logits, cache = apply_fn(params, with_write_positions(cache, write_pos,
-                                                                  last_idx + 1), ids)
-            tok = sample_logits(last_logits(logits, last_idx), rng, True,
-                                temperature, top_k, top_p).astype(jnp.int32)
-            return cache, with_counters(cache, tok)
-    else:
-        def prefill(params, cache, write_pos, ids, last_idx):
-            logits, cache = apply_fn(params, with_write_positions(cache, write_pos,
-                                                                  last_idx + 1), ids)
-            return cache, with_counters(cache, jnp.argmax(last_logits(logits, last_idx),
-                                                          axis=-1).astype(jnp.int32))
+    if not rung:
+        return prefill
 
-    return prefill
+    def prefill_rung(params, cache, slot_ids, *operands):
+        return prefill(params, cache, *operands, slot_ids=slot_ids)
+
+    return prefill_rung
 
 
 def build_decode_step(apply_fn, do_sample: bool, temperature: float,
@@ -341,6 +419,12 @@ def serve_programs(engine, slots_bucket: int, *, prefill_chunk: int,
         "decode": jax.jit(build_decode_step(apply_fn, do_sample, temperature,
                                             top_k, top_p), **jit_kwargs),
     }
+    if len(prefill_rungs(slots_bucket, engine.mesh.size)) > 1:
+        # one jitted function for whatever rungs lie below the whole: the
+        # operands' shapes are its cache's key, a program a rung
+        fns["prefill_rung"] = jax.jit(build_prefill_step(apply_fn, do_sample, temperature,
+                                                         top_k, top_p, rung=True),
+                                      **jit_kwargs)
     if spec_k > 0:
         fns["verify"] = jax.jit(build_verify_step(apply_fn), **jit_kwargs)
     engine._serve_cache[key] = fns

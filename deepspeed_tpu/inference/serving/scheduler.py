@@ -43,8 +43,8 @@ from deepspeed_tpu.inference.serving.blocks import BlockPool
 from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, _leaf_name, counter_widths,
                                                       has_recurrent_state, make_slot_cache,
-                                                      serve_programs, slot_capacity,
-                                                      state_bytes_per_slot)
+                                                      prefill_rungs, serve_programs,
+                                                      slot_capacity, state_bytes_per_slot)
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
@@ -179,7 +179,6 @@ class ContinuousBatchingScheduler:
             make_slot_cache(self.module, self.slots, kv_quant=self.kv_quant),
             self._placement)
         self.capacity = slot_capacity(self._cache)  # tokens per slot
-        self._probe_slot_decode()
         # a model with recurrent layers keeps per-slot state with no
         # positions: no rows to copy, no length to leave unadvanced. What
         # assumes rows refuses it by name until the state has snapshots
@@ -239,6 +238,7 @@ class ContinuousBatchingScheduler:
                                   spec_k=self.spec_k,
                                   weight_dtype=self.weight_dtype if quantized else None,
                                   **sampling)
+        self._probe_slot_decode()
         self._drafter = None
         if drafter is not None and self.spec_k:
             d_module, d_params = drafter
@@ -263,6 +263,12 @@ class ContinuousBatchingScheduler:
                                        spec_k=self.spec_k,
                                        weight_dtype=d_weight_dtype,
                                        **sampling)
+        # the sequence counts the prefill program exists at, ascending: a
+        # prefill tick runs the smallest that holds the slots it feeds (the
+        # drafter's program is fed the same operands: the shorter ladder)
+        caches = [self._cache] + ([self._drafter_cache] if self._drafter is not None else [])
+        self._rungs = min((prefill_rungs(self.slots, engine.mesh.size, cache)
+                           for cache in caches), key=len)
 
         # host-side authoritative slot state
         self._slot_req: List[Optional[Request]] = [None] * self.slots
@@ -300,21 +306,30 @@ class ContinuousBatchingScheduler:
         """Fail at construction — with the model family named — when the
         module's decode path cannot take a per-slot index vector (only
         families whose attention appends through ``models/common.py``'s
-        ``DecodeCache``, GPT-2 and the llama family, can serve)."""
+        ``DecodeCache`` or ``LatentCache`` can serve). The probe is the
+        decode program's own trace, over the operands ``warmup`` and every
+        decode tick hand it, so the first of those calls finds the program
+        traced: a scheduler pays for one trace of the model here, not two.
+        Where that trace fails, the model's step alone over a [slots, 1]
+        batch says whether the family refused it; a fault of anything
+        around the model (the sampler, the counters) is raised as it is."""
+        tokens = np.zeros(self.slots, np.int32)
+        rng = (jax.random.PRNGKey(0),) if self.config.do_sample else ()
         try:
-            import jax.numpy as jnp
-
+            jax.eval_shape(self.fns["decode"], self._serve_params, self._cache,
+                           tokens, tokens, *rng)
+        except Exception:
             from deepspeed_tpu.inference.serving.programs import make_apply_fn
-            ids = jnp.zeros((self.slots, 1), jnp.int32)
-            probe = make_apply_fn(self.module)
-            jax.eval_shape(lambda p, c: probe(p, c, ids),
-                           self._serve_params, self._cache)
-        except Exception as e:
-            raise NotImplementedError(
-                f"{type(self.module).__name__} does not support the per-slot "
-                f"(ragged) decode cache graft-serve schedules against — its "
-                f"decode path rejected a [slots] cache_index vector: "
-                f"{type(e).__name__}: {e}") from e
+            step, ids = make_apply_fn(self.module), tokens[:, None]
+            try:
+                jax.eval_shape(lambda p, c: step(p, c, ids), self._serve_params, self._cache)
+            except Exception as e:
+                raise NotImplementedError(
+                    f"{type(self.module).__name__} does not support the per-slot "
+                    f"(ragged) decode cache graft-serve schedules against — its "
+                    f"decode path rejected a [slots] cache_index vector: "
+                    f"{type(e).__name__}: {e}") from e
+            raise
 
     def _kv_bytes_per_token(self) -> float:
         """Measured KV bytes per cached token, straight off the slot
@@ -330,7 +345,8 @@ class ContinuousBatchingScheduler:
         return total / float(self.slots * self.capacity)
 
     def _read_back(self, tok, kind: str) -> np.ndarray:
-        """The tick's blocking read-back: the tokens [slots], and behind
+        """The tick's blocking read-back: the tokens, one a sequence the
+        program ran ([slots], or a prefill rung's fewer), and behind
         them whatever the program counted for the host
         (``programs.with_counters``). ``moe_rows``: an expert layer that
         holds a share of its experts decides on the device which rows are
@@ -341,7 +357,8 @@ class ContinuousBatchingScheduler:
         loops were bounded to, against the positions that held a token, by
         the kind of tick, and the bytes it wrote there."""
         tok = np.asarray(tok)
-        behind = tok[self.slots:]
+        ran = len(tok) - sum(width for _, width in self._counters)
+        behind = tok[ran:]
         for name, width in self._counters:
             counted, behind = [int(n) for n in behind[:width]], behind[width:]
             if name == "moe_rows":
@@ -358,17 +375,18 @@ class ContinuousBatchingScheduler:
                 self._rec.count(f"latent_positions_read_{kind}", read)
                 self._rec.count(f"latent_positions_live_{kind}", live)
                 self._rec.count("latent_bytes_written", written)
-        return tok[:self.slots]
+        return tok[:ran]
 
     def _count_state(self, write_pos: np.ndarray, fed: Optional[int] = None,
                      computed: Optional[int] = None) -> None:
-        """One target pass of a model with recurrent state: every slot's
-        state is read and written once, live or parked; a slot that writes
+        """One target pass of a model with recurrent state: the state of
+        every slot the program ran (``write_pos``: one entry each) is read and
+        written once, live or parked; a slot that writes
         at position 0 was zeroed first (a join); a prefill tick also says
         how many of the positions its scan ran over were real."""
         if not self._recurrent:
             return
-        self._rec.count("ssm_state_bytes_touched", 2 * self.slots * self._state_bytes)
+        self._rec.count("ssm_state_bytes_touched", 2 * len(write_pos) * self._state_bytes)
         self._rec.count("ssm_state_resets", int((write_pos == 0).sum()))
         if computed is not None:
             self._rec.count("ssm_positions_fed", fed)
@@ -419,9 +437,14 @@ class ContinuousBatchingScheduler:
         last_idx = np.zeros(self.slots, np.int32)
         tok = np.zeros(self.slots, np.int32)
         block = np.zeros((self.slots, self.spec_k + 1), np.int32)
+        # a prefill rung's operands are the first ``n`` of each, behind the
+        # slots it runs: a program a rung, all behind one jitted function
+        rungs = [("prefill_rung", (np.arange(n, dtype=np.int32), parked[:n], ids[:n],
+                                   last_idx[:n])) for n in self._rungs[:-1]]
         # a spec-mode scheduler never runs the target's plain decode
         # (step() always spec-ticks) — don't pay its compile
         target_calls = ([("prefill", (parked, ids, last_idx) + rng)]
+                        + [(name, args + rng) for name, args in rungs]
                         + ([("verify", (parked, block))] if self.spec_k
                            else [("decode", (parked, tok) + rng)]))
         per_role = [(self.fns, "_cache", self._serve_params, target_calls)]
@@ -430,8 +453,9 @@ class ContinuousBatchingScheduler:
             # _spec_tick); every other tick input arrives as a host array
             dtok = jax.device_put(tok, self._placement)  # graft-lint: waive R008 warmup operand placement parity w/ the draft loop, never donated
             per_role.append((self.dfns, "_drafter_cache", self._drafter[1],
-                             [("prefill", (parked, ids, last_idx) + rng),
-                              ("decode", (parked, dtok) + rng),
+                             [("prefill", (parked, ids, last_idx) + rng)]
+                             + [(name, args + rng) for name, args in rungs] +
+                             [("decode", (parked, dtok) + rng),
                               ("verify", (parked, block))]))
         for fns, cache_attr, params, calls in per_role:
             for name, args in calls:
@@ -793,38 +817,61 @@ class ContinuousBatchingScheduler:
                      "last_tick_monotonic": time.monotonic()})
 
     # -- prefill -------------------------------------------------------
+    def _rung_rows(self, slots: List[int]) -> np.ndarray:
+        """The slots a prefill tick's program runs, one a sequence: the
+        smallest rung that holds ``slots``. The whole rung is every slot in
+        its place. A smaller one is the fed slots and then, to fill it, other
+        slots, which the tick parks at the sentinel as the whole program
+        parks every slot it does not feed: distinct, so that no two rows of a
+        write-back are one slot."""
+        n = next(r for r in self._rungs if r >= len(slots))
+        if n == self.slots:
+            return np.arange(self.slots, dtype=np.int32)
+        fed = set(slots)
+        others = [i for i in range(self.slots) if i not in fed]
+        return np.asarray(list(slots) + others[:n - len(slots)], np.int32)
+
     def _prefill_tick(self, slots: List[int]) -> None:
         C = self.config.prefill_chunk
         with self._phase("build_inputs"):
-            ids = np.zeros((self.slots, C), np.int32)
-            last_idx = np.full(self.slots, C - 1, np.int32)
-            write_pos = np.full(self.slots, self.capacity, np.int64)
+            rows = self._rung_rows(slots)
+            n = len(rows)
+            row_of = {int(i): j for j, i in enumerate(rows)}
+            ids = np.zeros((n, C), np.int32)
+            last_idx = np.full(n, C - 1, np.int32)
+            write_pos = np.full(n, self.capacity, np.int64)
             rems: Dict[int, int] = {}
             for i in slots:
-                req = self._slot_req[i]
+                req, j = self._slot_req[i], row_of[i]
                 chunk = req.prompt[req.prefill_pos:req.prefill_pos + C]
                 rems[i] = rem = len(chunk)
-                ids[i, :rem] = chunk
-                last_idx[i] = rem - 1
-                write_pos[i] = self._lengths[i]
-        # the fixed-shape program computes slots x chunk positions whatever
-        # it is fed: the ratio is the prefill program's fill
+                ids[j, :rem] = chunk
+                last_idx[j] = rem - 1
+                write_pos[j] = self._lengths[i]
+        # ``_computed`` is the cell's whole shape whatever rung ran (the
+        # benchmark counts prefill ticks by it); ``_run`` is what the tick's
+        # program computed, and fed over run is the rung's fill
         fed = sum(rems.values())
         self._rec.count("prefill_positions_fed", fed)
         self._rec.count("prefill_positions_computed", self.slots * C)
-        self._count_moe_rows(fed, self.slots * C)
+        self._rec.count("prefill_positions_run", n * C)
+        self._rec.count("prefill_slots_fed", len(slots))
+        self._rec.count(f"prefill_ticks_rung_{n}")
+        self._count_moe_rows(fed, n * C)
         self._count_kv_write(write_pos, C)
-        self._count_state(write_pos, fed, self.slots * C)
+        self._count_state(write_pos, fed, n * C)
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32), ids, last_idx)
+            name = "prefill"
+            if n < self.slots:
+                name, inputs = "prefill_rung", (rows,) + inputs
         with self._phase("dispatch"):
             if self.config.do_sample:
                 self._rng, key = jax.random.split(self._rng)
                 inputs += (key,)
-            self._cache, tok = self.fns["prefill"](self._serve_params, self._cache,
-                                                   *inputs)
+            self._cache, tok = self.fns[name](self._serve_params, self._cache, *inputs)
             if self._drafter is not None:  # speculation is greedy: no rng operand
-                self._drafter_cache, _ = self.dfns["prefill"](
+                self._drafter_cache, _ = self.dfns[name](
                     self._drafter[1], self._drafter_cache, *inputs)
         with self._phase("device_wait"):
             tok = self._read_back(tok, "prefill")
@@ -842,11 +889,11 @@ class ContinuousBatchingScheduler:
                     # next same-prefix request skips their prefill entirely
                     self._publish_prefix(i, req)
                     req.state = ACTIVE
-                    req.record_token(int(tok[i]), now)
+                    req.record_token(int(tok[row_of[i]]), now)
                     if req.admit_time is not None:   # None: migrated in
                         self._rec.record("prefill_wait", req.admit_time, now,
                                          req.request_id, self._source)
-                    self._next_token[i] = tok[i]
+                    self._next_token[i] = tok[row_of[i]]
                     self._maybe_finish(i, now)
 
     # -- plain decode --------------------------------------------------

@@ -64,6 +64,21 @@ POOL_LEAVES = KV_LEAVES + LATENT_LEAVES
 STATE_LEAVES = ("ssm_state", "conv_state")
 LENGTH_LEAVES = ("chunk_length",)
 COUNTER_LEAVES = ("moe_rows", "latent_reads")
+#: a serving program that runs fewer sequences than the cache has slots (a
+#: rung of ``serving/programs.py``'s prefill ladder) says which slot each
+#: sequence is: ``cache_slots`` [n] int32, distinct, which the program lays
+#: beside every key and value pool for the length of one call.
+#: :class:`DecodeCache` then writes, and reads, row ``cache_slots[s]`` of its
+#: pools for sequence ``s``. Absent (every other caller), sequence ``s`` is
+#: slot ``s`` and nothing is indexed. (A latent pool has no such program:
+#: ``serving/programs.py`` ``prefill_rungs``.)
+SLOT_LEAF = "cache_slots"
+
+
+def _cache_slots(module: nn.Module):
+    """The ``cache_slots`` a serving program laid in ``module``'s cache, or None."""
+    return (module.get_variable("cache", SLOT_LEAF)
+            if module.has_variable("cache", SLOT_LEAF) else None)
 
 
 class DecodeCache:
@@ -106,6 +121,7 @@ class DecodeCache:
             self.value_scale = module.variable("cache", "cached_value_scale", jnp.zeros,
                                                (batch, kv_heads, positions), dtype)
         self.index = module.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
+        self.slots = _cache_slots(module)
 
     @property
     def per_slot(self) -> bool:
@@ -116,22 +132,25 @@ class DecodeCache:
         appended (what RoPE rotates by)."""
         idx = self.index.value
         start = idx[:, None] if self.per_slot else idx
-        return jnp.broadcast_to(start + jnp.arange(length)[None, :],
-                                (self.key.value.shape[0], length))
+        batch = idx.shape[0] if self.per_slot else self.key.value.shape[0]
+        return jnp.broadcast_to(start + jnp.arange(length)[None, :], (batch, length))
 
     def append(self, k, v, read_dtype):
         """Write ``k`` / ``v`` [batch, l, kv heads, head dim] at the index,
-        advance it, and return ``(keys, values, decode_lengths)``: the whole
-        pools [batch, positions, kv heads, head dim] as attention reads them
-        (``read_dtype`` values, HBM holds the codes) and each sequence's
-        live length."""
+        advance it, and return ``(keys, values, decode_lengths)``: the
+        sequences' pools [batch, positions, kv heads, head dim] as attention
+        reads them (``read_dtype`` values, HBM holds the codes; with
+        ``cache_slots`` the rows of those slots alone, so a call over a few
+        slots dequantises and attends a few) and each sequence's live
+        length."""
         b, l = k.shape[0], k.shape[1]
         idx = self.index.value
         if self.per_slot:
             self._append_per_slot(k, v)
             self.index.value = idx + l
             scales = (self.key_scale, self.value_scale) if self.quantized else (None, None)
-            keys, values = (slot_pool_read(pool.value, scale and scale.value, read_dtype)
+            keys, values = (slot_pool_read(pool.value, scale and scale.value, read_dtype,
+                                           self.slots)
                             for pool, scale in zip((self.key, self.value), scales))
             return keys, values, idx + l
         if self.quantized:
@@ -152,7 +171,7 @@ class DecodeCache:
             pools += [self.key_scale, self.value_scale]
             vals = [k, v, k_s[..., 0], v_s[..., 0]]
         for pool, new in zip(pools, slot_pool_append([p.value for p in pools], vals,
-                                                     self.index.value)):
+                                                     self.index.value, self.slots)):
             pool.value = new
 
 
@@ -205,11 +224,15 @@ def slot_pool(leaf):
     return jnp.zeros((s, h, d, p), leaf.dtype)
 
 
-def slot_pool_read(pool, scale, read_dtype):
+def slot_pool_read(pool, scale, read_dtype, rows=None):
     """A stored pool as attention's [slots, positions, kv heads, head dim]
     operand, an int8 pool dequantised by its ``scale`` (attention reads fp
     values, HBM holds the codes): a change of logical order only, which the
-    compiler folds into the consumer's layout."""
+    compiler folds into the consumer's layout. ``rows`` [n]: those slots'
+    rows alone, gathered before anything is dequantised."""
+    if rows is not None:
+        pool = pool[rows]
+        scale = None if scale is None else scale[rows]
     if scale is not None:
         pool = pool.astype(read_dtype) * scale[:, :, None, :]
     return jnp.transpose(pool, (0, 3, 1, 2))
@@ -258,12 +281,14 @@ def _append_span(length: int, positions: int) -> int:
     return w if length == 1 else min(2 * w, positions)
 
 
-def slot_pool_append(leaves, updates, pos):
+def slot_pool_append(leaves, updates, pos, rows=None):
     """Write ``updates[i]`` [slots, l, ...] (token-major, as the projections
     produce them) into the stored leaves ``leaves[i]`` [slots, ..., positions]
     at positions ``pos[s] .. pos[s] + l - 1`` of each slot ``s``; returns the
     new leaves. A position at or past the extent writes nothing (a parked
-    slot); tokens past the extent are dropped.
+    slot); tokens past the extent are dropped. ``rows`` [n] int32, distinct:
+    the updates are ``n`` sequences' and sequence ``s`` is slot ``rows[s]``;
+    the other slots' rows come back as they went in.
 
     On a TPU, :func:`_append_in_place`. Elsewhere one scatter on the minor
     dimension: the same write, and what the in-place one is tested against;
@@ -271,16 +296,16 @@ def slot_pool_append(leaves, updates, pos):
     from deepspeed_tpu.ops.pallas import backend
     pos = pos.astype(jnp.int32)
     if backend.on_tpu():
-        return _append_in_place(leaves, updates, pos)
+        return _append_in_place(leaves, updates, pos, rows)
     slots, length = updates[0].shape[:2]
     at = pos[:, None] + jnp.arange(length)[None, :]
     # advanced indices on the first and last axes: the indexed result is
     # [slots, l, ...], the updates' own shape; out of bounds drops
-    return [leaf.at[jnp.arange(slots)[:, None], ..., at].set(upd.astype(leaf.dtype))
-            for leaf, upd in zip(leaves, updates)]
+    return [leaf.at[(jnp.arange(slots) if rows is None else rows)[:, None], ..., at]
+            .set(upd.astype(leaf.dtype)) for leaf, upd in zip(leaves, updates)]
 
 
-def _append_in_place(leaves, updates, pos):
+def _append_in_place(leaves, updates, pos, rows=None):
     """The write as a read-modify-write of the aligned span that holds the
     tokens, one slot at a time: a ``fori_loop`` of scalar-indexed
     ``dynamic_slice`` / select / ``dynamic_update_slice``, which XLA updates
@@ -290,13 +315,13 @@ def _append_in_place(leaves, updates, pos):
     # a piece of at most one window's tokens touches at most two windows
     for start in range(0, length, piece):
         leaves = _append_piece(leaves, [u[:, start:start + piece] for u in updates],
-                               pos + start)
+                               pos + start, rows)
     return leaves
 
 
 # jitted, so that a model's layers share one trace of the write
 @jax.jit
-def _append_piece(leaves, updates, pos):
+def _append_piece(leaves, updates, pos, rows=None):
     positions = leaves[0].shape[-1]
     slots, length = updates[0].shape[:2]
     span = _append_span(length, positions)
@@ -319,8 +344,9 @@ def _append_piece(leaves, updates, pos):
         j = start[s] + lane - pos[s]
         written = (j >= 0) & (j < length)
         out = []
+        row = s if rows is None else rows[s]
         for leaf, win in zip(leaves, wins):
-            at = (s,) + (0,) * (leaf.ndim - 2) + (start[s],)
+            at = (row,) + (0,) * (leaf.ndim - 2) + (start[s],)
             old = jax.lax.dynamic_slice(leaf, at, (1,) + leaf.shape[1:-1] + (span,))
             new = jnp.where(written, jax.lax.dynamic_index_in_dim(win, s, 0), old)
             out.append(jax.lax.dynamic_update_slice(leaf, new, at))

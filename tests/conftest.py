@@ -39,3 +39,16 @@ def mesh8():
     from deepspeed_tpu.parallel.topology import MeshTopology
 
     return MeshTopology(fsdp=8, data=1)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _no_topology_left_behind():
+    """A test file leaves no process-wide topology to the next file on its
+    worker (``--dist loadfile`` decides which that is). Several files end
+    with an 8-device mesh set, and one that then builds a one-device server
+    in process (``tests/unit/benchmark/test_bench_nemotron_h.py``) failed by
+    the order the files happened to run in."""
+    yield
+    from deepspeed_tpu.parallel.topology import set_topology
+
+    set_topology(None)

@@ -16,6 +16,7 @@ from deepspeed_tpu.inference.serving import (ACTIVE, FINISHED, REFUSED,
                                              BlockPool,
                                              ContinuousBatchingScheduler,
                                              Request, ServingConfig)
+from deepspeed_tpu.inference.serving.programs import prefill_rungs
 from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 
@@ -217,9 +218,11 @@ def test_two_slot_buckets_compile_two_program_sets():
     assert buckets == {4, 8}, sorted(engine._serve_cache)
     assert len(engine._serve_cache) == 2
     # and each jitted program compiled exactly once across all deployments
-    for fns in engine._serve_cache.values():
+    # (``prefill_rung``: one function, a program a rung below the whole)
+    for key, fns in engine._serve_cache.items():
         for name, fn in fns.items():
-            assert fn._cache_size() == 1, (name, fn._cache_size())
+            entries = len(prefill_rungs(key[2])) - 1 if name == "prefill_rung" else 1
+            assert fn._cache_size() == entries, (name, fn._cache_size())
     # bucketing never changes results
     assert outs[3] == outs[4] and outs[6] == outs[8]
 
@@ -270,7 +273,8 @@ def test_speculative_decoding_is_lossless_greedy(engine_cfg):
                     # warmup deliberately skips its compile
                     assert fn._cache_size() == 0, (name, fn._cache_size())
                     continue
-                assert fn._cache_size() == 1, (name, fn._cache_size())
+                entries = len(sched._rungs) - 1 if name == "prefill_rung" else 1
+                assert fn._cache_size() == entries, (name, fn._cache_size())
         return reqs, sched.stats()
 
     base_reqs, base_stats = run(False)

@@ -44,8 +44,9 @@ def _clear_topology():
 def write(request, monkeypatch):
     """The write the CPU runs, and the one a TPU runs, run here."""
     if request.param == "in_place":
-        monkeypatch.setattr(common, "slot_pool_append", lambda leaves, updates, pos: _append_in_place(
-            leaves, updates, pos.astype(jnp.int32)))
+        monkeypatch.setattr(common, "slot_pool_append",
+                            lambda leaves, updates, pos, rows=None: _append_in_place(
+                                leaves, updates, pos.astype(jnp.int32), rows))
     return request.param
 
 
@@ -185,6 +186,31 @@ def test_the_write_puts_each_token_on_its_position(head_dim, heads, dtype, lengt
         assert g.dtype == leaf.dtype and g.shape == leaf.shape
         np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)),
                                       _written(leaf, upd, WRITE_POS))
+
+
+@pytest.mark.parametrize("length", [1, 16, 200], ids=["decode", "chunk16", "longer_than_a_window"])
+@pytest.mark.parametrize("append", [_append_in_place, slot_pool_append], ids=["in_place", "scatter"])
+def test_the_write_of_a_few_sequences_lands_in_their_slots_rows(append, length):
+    """``rows``: the updates are a few sequences', each a slot of its own
+    (a rung of the prefill program, ISSUE 33): each lands at its position in
+    its slot's row, a parked one nowhere, and no other row is touched."""
+    rng = np.random.default_rng(length)
+    pool = _random(rng, (SLOTS, 2, 64, POSITIONS), jnp.int8)
+    scale = _random(rng, (SLOTS, 2, POSITIONS), jnp.bfloat16)
+    rows = np.array([SLOTS - 1, 0, 2], np.int32)                 # not in order, not the first
+    pos = np.array([WRITE_POS[1], POSITIONS, 120], np.int32)     # the second is parked
+    new = _random(rng, (3, length, 2, 64), jnp.int8)
+    new_scale = _random(rng, (3, length, 2), jnp.bfloat16)
+    got = jax.jit(lambda *a: append(a[:2], a[2:4], a[4], a[5]))(
+        pool, scale, new, new_scale, jnp.asarray(pos), jnp.asarray(rows))
+    for g, leaf, upd in zip(got, (pool, scale), (new, new_scale)):
+        # the same sequences written where a whole batch would carry them
+        whole_pos = np.full(SLOTS, POSITIONS, np.int32)
+        whole = np.zeros((SLOTS,) + upd.shape[1:], np.float32)
+        whole_pos[rows], whole[rows] = pos, np.asarray(upd.astype(jnp.float32))
+        assert g.dtype == leaf.dtype and g.shape == leaf.shape
+        np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)),
+                                      _written(leaf, jnp.asarray(whole).astype(leaf.dtype), whole_pos))
 
 
 def test_a_pool_shorter_than_a_lane_row_is_one_window():

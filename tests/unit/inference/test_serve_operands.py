@@ -82,12 +82,17 @@ def _kv_leaves(cache):
 def test_ticks_put_at_most_once_and_never_retrace(engine_cfg, spec_k):
     """Staggered arrivals with short and long prompts: the set of busy
     slots, the parked slots and every position change from tick to tick,
-    yet each tick issues at most one explicit transfer (none without
-    speculation) and every program keeps the one entry warm-up gave it."""
+    and the prefill ticks hop between the rungs of the prefill program
+    (ISSUE 33: one slot, and all four), yet each tick issues at most
+    one explicit transfer (none without speculation) and every program
+    keeps the entries warm-up gave it: one, and one a rung below the whole
+    behind ``prefill_rung``."""
     engine, cfg = engine_cfg
     sched = _scheduler(engine, cfg, kv_quant=True, spec_k=spec_k)
     sched.warmup()
+    assert sched._rungs == (1, SLOTS)
     counters = trace.recorder().counters
+    rung_ticks = {n: counters.get(f"prefill_ticks_rung_{n}", 0) for n in sched._rungs}
     reqs = [Request(prompt=p, max_new_tokens=n) for p, n in
             zip(_prompts(cfg, [5, 21, 9, 30, 3, 17], seed=5), [7, 3, 9, 4, 8, 5])]
     arrivals = {0: [0], 1: [1, 2], 6: [3], 11: [4, 5]}
@@ -103,10 +108,12 @@ def test_ticks_put_at_most_once_and_never_retrace(engine_cfg, spec_k):
         tick += 1
         assert tick < 500
     assert {"prefill", "spec" if spec_k else "decode"} <= kinds
+    assert all(counters.get(f"prefill_ticks_rung_{n}", 0) > was for n, was in rung_ticks.items())
     for fns in (sched.fns,) + ((sched.dfns,) if spec_k else ()):
         for name, fn in fns.items():
             dead = bool(spec_k) and fns is sched.fns and name == "decode"
-            assert fn._cache_size() == (0 if dead else 1), (name, fn._cache_size())
+            entries = 0 if dead else len(sched._rungs) - 1 if name == "prefill_rung" else 1
+            assert fn._cache_size() == entries, (name, fn._cache_size())
     # which takes a cache that comes back placed as the fresh one was, the
     # int8 scale leaves (rank 3, as some weights are) included
     for path, leaf in jax.tree_util.tree_flatten_with_path(sched._cache)[0]:
@@ -201,3 +208,72 @@ def test_cache_is_donated_every_tick(engine_cfg):
         assert all(leaf.is_deleted() for leaf in held.values()), kinds
         assert not any(leaf.is_deleted() for leaf in _kv_leaves(sched._cache).values())
     assert {"prefill", "decode"} <= set(kinds)
+
+
+# ---------------------------------------------------------------------------
+# (e) the construction-time probe is the decode program's own trace
+# ---------------------------------------------------------------------------
+def test_the_probe_is_the_decode_programs_one_trace(engine_cfg, monkeypatch):
+    """A scheduler traces its decode program once, at construction, where it
+    finds out whether the family can serve; ``warmup`` and the decode ticks
+    after it find that trace and make no second one."""
+    from deepspeed_tpu.inference.serving import programs
+    engine, cfg = engine_cfg
+    built, traces = programs.build_decode_step, []
+
+    def counting(*args, **kwargs):
+        step = built(*args, **kwargs)
+
+        def counted(*operands):
+            traces.append(1)
+            return step(*operands)
+
+        return counted
+
+    monkeypatch.setattr(programs, "build_decode_step", counting)
+    # a temperature no other test serves at: programs of this scheduler's own
+    sched = ContinuousBatchingScheduler(engine, ServingConfig(
+        slots=SLOTS, prefill_chunk=CHUNK, temperature=0.9))
+    assert len(traces) == 1
+    sched.warmup()
+    for p in _prompts(cfg, [11, 4], seed=9):
+        sched.submit(Request(prompt=p, max_new_tokens=5))
+    sched.run_until_drained()
+    assert sched.ticks["decode"] > 0 and len(traces) == 1
+    assert sched.fns["decode"]._cache_size() == 1
+
+
+@pytest.mark.parametrize("fault", ["model", "sampler"])
+def test_the_probe_names_the_family_only_where_the_model_refused(engine_cfg, monkeypatch, fault):
+    """A decode path that cannot take a [slots] index is refused with the
+    family's name; a fault of what surrounds the model in the decode program
+    comes out as itself."""
+    from deepspeed_tpu.inference import engine as inference_engine
+    from deepspeed_tpu.inference.serving import programs
+    engine, _ = engine_cfg
+    if fault == "model":
+        made = programs.make_apply_fn
+
+        def refusing(*args, **kwargs):
+            apply_fn = made(*args, **kwargs)
+
+            def apply(params, cache, ids):
+                if ids.shape[1] == 1:
+                    raise TypeError("cache_index is one number a batch")
+                return apply_fn(params, cache, ids)
+
+            return apply
+
+        monkeypatch.setattr(programs, "make_apply_fn", refusing)
+        raised, match = NotImplementedError, "GPT2LMHeadModel does not support the per-slot"
+    else:
+        def broken(*args, **kwargs):
+            raise ValueError("no such sampler")
+
+        monkeypatch.setattr(inference_engine, "sample_logits", broken)
+        raised, match = ValueError, "no such sampler"
+    with pytest.raises(raised, match=match):
+        ContinuousBatchingScheduler(engine, ServingConfig(
+            slots=SLOTS, prefill_chunk=CHUNK, do_sample=True,
+            temperature=0.8 if fault == "model" else 0.7))
+

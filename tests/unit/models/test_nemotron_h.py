@@ -158,11 +158,15 @@ def test_serving_counts_state_and_rows(served):
     module, _, _, sched, reqs, counted = served
     fed = sum(len(r.prompt) for r in reqs)
     assert counted["ssm_positions_fed"] == counted["prefill_positions_fed"] == fed
-    assert counted["ssm_positions_computed"] == counted["prefill_positions_computed"]
+    # the scan runs over what the tick's program ran: the rung's slots x the
+    # chunk (ISSUE 33), under the cell's whole shape where a smaller rung ran
+    assert counted["ssm_positions_computed"] == counted["prefill_positions_run"]
+    assert counted["prefill_positions_run"] <= counted["prefill_positions_computed"] == (
+        sched.ticks["prefill"] * sched.slots * 8)
     assert counted["ssm_state_resets"] == len(reqs)            # one join a request
-    ticks = sched.ticks["prefill"] + sched.ticks["decode"]
+    slots_run = sched.ticks["decode"] * sched.slots + counted["prefill_positions_run"] // 8
     state = 2 * (8 * 16 * 16 * 4 + 3 * 192 * 4)                # two Mamba layers, float32 tail
-    assert counted["ssm_state_bytes_touched"] == ticks * 2 * sched.slots * state
+    assert counted["ssm_state_bytes_touched"] == slots_run * 2 * state
     # every real token takes k experts a layer, here or elsewhere; parked
     # slots and padding route nowhere
     tokens = fed + sum(len(r.output) - 1 for r in reqs)

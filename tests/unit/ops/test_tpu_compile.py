@@ -226,6 +226,29 @@ def test_block_sparse_attention_compiles(one_chip, backward):
 # ---------------------------------------------------------------------------
 # whole programs at 350M width, reduced depth
 # ---------------------------------------------------------------------------
+def _cell_family(family):
+    """``(module, slots, chunk, kv_quant)`` of a family's serving cell, at the
+    depth and widths the whole-program tests below compile it at."""
+    if family == "gpt2":
+        from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+        return GPT2LMHeadModel(get_gpt2_config("350m", n_layer=2, dtype=bf16)), 32, CHUNK, True
+    if family == "olmoe":
+        from deepspeed_tpu.models.llama import LlamaForCausalLM, get_llama_config
+        return LlamaForCausalLM(get_llama_config("olmoe-1b-7b", num_hidden_layers=1, dtype=bf16,
+                                                 decode_cache_len=2048)), 32, 64, True
+    if family == "nemotron_h":
+        from deepspeed_tpu.models.nemotron_h import NemotronHForCausalLM, get_nemotron_h_config
+        return NemotronHForCausalLM(get_nemotron_h_config(
+            "nemotron-h-test", hidden_size=256, head_dim=64, mamba_num_heads=16, mamba_head_dim=64,
+            ssm_state_size=128, chunk_size=128, moe_latent_size=128, moe_intermediate_size=256,
+            moe_shared_expert_intermediate_size=512, experts_held=(4, 4), decode_cache_len=256,
+            max_position_embeddings=256, vocab_size=1024, dtype=bf16)), 16, 128, True
+    from deepspeed_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM, get_deepseek_v3_config
+    return DeepseekV3ForCausalLM(get_deepseek_v3_config(
+        "joyai-llm-flash", num_hidden_layers=2, vocab_size=32320, experts_held=(0, 64),
+        decode_cache_len=16384, dtype=bf16, param_dtype=bf16)), 32, 512, False
+
+
 @pytest.mark.parametrize("program", ["prefill", "decode"])
 @pytest.mark.parametrize("attention", ["xla", "flash"])
 def test_serving_program_compiles(one_chip, program, attention):
@@ -334,16 +357,9 @@ def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, pr
     from deepspeed_tpu.inference.serving.programs import (build_decode_step,
                                                           build_prefill_step,
                                                           make_apply_fn, make_slot_cache)
-    from deepspeed_tpu.models.nemotron_h import NemotronHForCausalLM, get_nemotron_h_config
     from deepspeed_tpu.moe.sharded_moe import _row_rungs
 
-    slots, chunk = 16, 128
-    cfg = get_nemotron_h_config(
-        "nemotron-h-test", hidden_size=256, head_dim=64, mamba_num_heads=16, mamba_head_dim=64,
-        ssm_state_size=128, chunk_size=128, moe_latent_size=128, moe_intermediate_size=256,
-        moe_shared_expert_intermediate_size=512, experts_held=(4, 4), decode_cache_len=256,
-        max_position_embeddings=256, vocab_size=1024, dtype=bf16)
-    module = NemotronHForCausalLM(cfg)
+    module, slots, chunk, _ = _cell_family("nemotron_h")
     params = jax.eval_shape(
         lambda key: jax.tree.map(lambda p: p.astype(bf16), nn.meta.unbox(
             module.init(key, jnp.zeros((1, 8), jnp.int32))["params"])), jax.random.PRNGKey(0))
@@ -395,13 +411,9 @@ def test_joyai_llm_flash_serving_program_reads_the_latent_pool_as_it_lies(one_ch
     from deepspeed_tpu.inference.serving.programs import (build_decode_step,
                                                           build_prefill_step,
                                                           make_apply_fn, make_slot_cache)
-    from deepspeed_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM, get_deepseek_v3_config
 
-    slots, chunk, positions, layers = 32, 512, 16384, 2
-    cfg = get_deepseek_v3_config("joyai-llm-flash", num_hidden_layers=layers, vocab_size=32320,
-                                 experts_held=(0, 64), decode_cache_len=positions, dtype=bf16,
-                                 param_dtype=bf16)
-    module = DeepseekV3ForCausalLM(cfg)
+    positions, layers = 16384, 2
+    module, slots, chunk, _ = _cell_family("joyai_llm_flash")
     params = jax.eval_shape(
         lambda key: nn.meta.unbox(module.init(key, jnp.zeros((1, 8), jnp.int32))["params"]),
         jax.random.PRNGKey(0))
@@ -432,6 +444,72 @@ def test_joyai_llm_flash_serving_program_reads_the_latent_pool_as_it_lies(one_ch
         # the largest row buffer of the held route (131,072 copies of 2,048) and
         # the dense layer's 7,168-wide activations; compiles to 1.82 GB
         assert memory.temp_size_in_bytes < 2.0e9
+
+
+# ---------------------------------------------------------------------------
+# a prefill rung: the prefill program over a quarter of the slots (ISSUE 33)
+# ---------------------------------------------------------------------------
+def _write_loop_trip_counts(compiled):
+    """The bound each ``_append_piece`` loop's condition compares its
+    counter with (the compiled loop keeps it as a constant)."""
+    import re
+    counts, bound = [], None
+    for line in compiled.as_text().splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            bound = None
+        m = re.search(r"= s32\[\]\S* constant\((\d+)\)", line)
+        if m:
+            bound = int(m.group(1))
+        if "ROOT" in line and "_append_piece)/while/cond/lt" in line and "direction=LT" in line:
+            counts.append(bound)
+    return counts
+
+
+@pytest.mark.parametrize("family", ["gpt2", "olmoe", "nemotron_h"])
+def test_a_quarter_rung_prefill_program_runs_a_quarter(one_chip, family):
+    """The prefill program over a quarter of each cell's slots, handed the
+    slots it runs: it still holds no copy, convert or transpose the size of
+    a pool (the rows are picked where they are written and read), its write
+    loop runs once a sequence of the rung, the cache comes back in place and
+    its temporaries are no more than the whole program's. (A latent pool
+    has no such program: ``prefill_rungs``.)"""
+    import re
+    import flax.linen as nn
+    from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, build_prefill_step,
+                                                          make_apply_fn, make_slot_cache,
+                                                          prefill_rungs)
+
+    module, slots, chunk, kv_quant = _cell_family(family)
+    n = prefill_rungs(slots)[0]
+    assert n == slots // 4
+    params = jax.eval_shape(
+        lambda key: jax.tree.map(lambda p: p.astype(bf16), nn.meta.unbox(
+            module.init(key, jnp.zeros((1, 8), jnp.int32))["params"])), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots, kv_quant=kv_quant))
+    apply_fn = make_apply_fn(module)
+    ints = lambda *dims: _shape(*dims, dtype=jnp.int32)
+    whole = _compile(build_prefill_step(apply_fn, False, 1.0, 0, 1.0), one_chip, params, cache,
+                     ints(slots), ints(slots, chunk), ints(slots), donate_argnums=(1,))
+    rung = _compile(build_prefill_step(apply_fn, False, 1.0, 0, 1.0, rung=True), one_chip, params,
+                    cache, ints(n), ints(n), ints(n, chunk), ints(n), donate_argnums=(1,))
+    pool = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+                if path[-1].key in POOL_LEAVES)
+    # (at the hybrid's reduced widths a chunk's activations, [n, ...], are as
+    # large as its pool: there only what holds a row for every slot counts)
+    assert not [line for line in _relayouts(rung, pool.size)
+                if family != "nemotron_h" or f"[{slots}," in line]
+    assert set(_write_loop_trip_counts(whole)) == {slots}
+    assert set(_write_loop_trip_counts(rung)) == {n}
+    memory, memory_whole = rung.memory_analysis(), whole.memory_analysis()
+    assert memory.temp_size_in_bytes <= memory_whole.temp_size_in_bytes
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
+    assert memory.alias_size_in_bytes >= cache_bytes - 1024
+    # what is gathered of a leaf that holds a row a slot (a pool, the
+    # recurrent state) is the rung's rows, a quarter of it, never more
+    gathered = [int(np.prod([int(d) for d in dims.split(",")])) for dims in
+                re.findall(r"= \w+\[([\d,]+)\]\S* gather\(", rung.as_text())]
+    by_row = max(leaf.size for leaf in jax.tree.leaves(cache) if leaf.ndim > 1)
+    assert max(gathered, default=0) <= by_row // 4
 
 
 def _train_engine(devices, zero_stage, fsdp):
