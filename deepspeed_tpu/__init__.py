@@ -9,6 +9,9 @@ Public surface mirrors the reference (``deepspeed/__init__.py``):
 ``initialize`` (:64), ``init_inference`` (:269), ``comm``, ``zero``,
 ``add_config_arguments`` (:246).
 """
+import time as _time
+
+_import_t0 = _time.perf_counter()  # the package's ``import`` record starts here
 
 from deepspeed_tpu.version import __version__, __capability_parity__
 
@@ -77,10 +80,14 @@ _LAZY = {
 def __getattr__(name):
     if name in _LAZY:
         import importlib
+        import sys
 
         mod_name, attr = _LAZY[name]
+        fresh, t0 = mod_name not in sys.modules, _time.perf_counter()
         try:
             mod = importlib.import_module(mod_name)
+            if fresh:    # most of the package is imported here, at first use
+                _trace.imported(mod_name, t0)
             obj = mod if attr is None else getattr(mod, attr)
         except (ImportError, AttributeError) as e:
             # keep hasattr() semantics sane for not-yet-built components
@@ -93,3 +100,8 @@ def __getattr__(name):
 def __dir__():
     # PEP 562: keep dir()/tab-completion aware of the lazy exports
     return sorted(set(globals()) | set(_LAZY))
+
+
+from deepspeed_tpu.utils import trace as _trace  # noqa: E402
+
+_trace.imported(__name__, _import_t0)

@@ -30,6 +30,7 @@ from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.models.common import init_cache
 from deepspeed_tpu.module_inject.replace_module import replace_transformer_layer, tp_shard_params
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
+from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -131,7 +132,9 @@ class InferenceEngine:
         set_topology(topology)
 
         # -- injection policy (engine.py:413)
-        self.module = replace_transformer_layer(model, config)
+        span = trace.recorder().span     # children of ``init_inference``'s span
+        with span("kernel_inject"):
+            self.module = replace_transformer_layer(model, config)
         self.mcfg = getattr(self.module, "config", None)
 
         self._rng = jax.random.PRNGKey(seed)
@@ -140,10 +143,11 @@ class InferenceEngine:
         self._is_seq2seq = is_seq2seq_module(self.module)
         example_extra = {"decoder_input_ids": example} if self._is_seq2seq else {}
 
-        if params is None and config.checkpoint is not None:
-            params = _load_checkpoint_params(config.checkpoint, config.base_dir)
-        if params is None:
-            params = self.module.init(self._rng, example, **example_extra)["params"]
+        with span("load_weights"):
+            if params is None and config.checkpoint is not None:
+                params = _load_checkpoint_params(config.checkpoint, config.base_dir)
+            if params is None:
+                params = self.module.init(self._rng, example, **example_extra)["params"]
         # callers may hand in boxed trees straight from model.init(); the
         # TP spec derivation below needs raw arrays (boxed leaves have no
         # .shape, so every spec would silently fall back to replicated)
@@ -153,12 +157,14 @@ class InferenceEngine:
         # sharding below — a raw astype(int8) would destroy the weights
         quant_on = bool(config.quant.enabled) or config.dtype == jnp.int8
         cast_dtype = (jnp.bfloat16 if config.dtype == jnp.int8 else config.dtype)
-        if cast_dtype is not None:
-            params = jax.tree.map(
-                lambda p: p.astype(cast_dtype) if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
-        # -- TP weight placement (ReplaceWithTensorSlicing / AutoTP)
-        self.params, self.param_specs = tp_shard_params(params, self.module, topology, example,
-                                                        policy=config.injection_policy)
+        with span("place_weights"):
+            if cast_dtype is not None:
+                params = jax.tree.map(
+                    lambda p: p.astype(cast_dtype) if jnp.issubdtype(p.dtype, jnp.floating) else p,
+                    params)
+            # -- TP weight placement (ReplaceWithTensorSlicing / AutoTP)
+            self.params, self.param_specs = tp_shard_params(
+                params, self.module, topology, example, policy=config.injection_policy)
 
         # -- int8 weight quantization (reference WeightQuantization applied
         # at checkpoint load; here on the already-sharded tree, engine.py:299)
@@ -169,9 +175,10 @@ class InferenceEngine:
             # mp_size=1: JAX sharded arrays keep their GLOBAL shape, so the
             # reference's local-shard ratio recovery must not re-multiply
             wq = WeightQuantization(mp_size=1)
-            self.params, self._wq_scales = wq.model_quantize(
-                self.params, quantize_bits=config.quant.bits,
-                group_size=max(1, config.quant.group_size))
+            with span("quantize_weights"):
+                self.params, self._wq_scales = wq.model_quantize(
+                    self.params, quantize_bits=config.quant.bits,
+                    group_size=max(1, config.quant.group_size))
 
         self._forward_fn = None
         self._prefill_fn = None

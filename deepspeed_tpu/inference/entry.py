@@ -1,6 +1,7 @@
 """``init_inference`` — parity with reference ``deepspeed/__init__.py:269``."""
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.inference.engine import InferenceEngine
+from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.version import __version__
 
@@ -12,6 +13,11 @@ def init_inference(model, config=None, params=None, topology=None, **kwargs):
     (``mp_size=``, ``dtype=``, ``replace_with_kernel_inject=`` …) are folded
     in for parity with the reference's kwarg path (``__init__.py:306``).
     """
+    with trace.recorder().span("init_inference", marks=trace.TOTAL):
+        return _init_inference(model, config, params, topology, kwargs)
+
+
+def _init_inference(model, config, params, topology, kwargs):
     log_dist(f"DeepSpeed-TPU inference info: version={__version__}")
     cfg_dict = dict(config) if isinstance(config, dict) else {}
     if isinstance(config, DeepSpeedInferenceConfig):

@@ -1,5 +1,8 @@
 """graft-serve: continuous in-flight batching with chunked prefill and
 speculative decoding (ISSUE 14 / ROADMAP item 1)."""
+import time as _time
+
+_import_t0 = _time.perf_counter()  # the package's ``import`` record starts here
 
 from deepspeed_tpu.inference.serving.blocks import BlockPool
 from deepspeed_tpu.inference.serving.events import (SERVE_EVENT_SCHEMAS,
@@ -28,3 +31,7 @@ __all__ = [
     "make_slot_cache", "serve_programs", "slot_capacity",
     "validate_event",
 ]
+
+from deepspeed_tpu.utils import trace as _trace  # noqa: E402
+
+_trace.imported(__name__, _import_t0)

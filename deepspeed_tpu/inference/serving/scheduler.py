@@ -126,14 +126,6 @@ class ContinuousBatchingScheduler:
     def __init__(self, engine, config=None, drafter: Optional[Tuple] = None,
                  clock: Optional[Callable[[], float]] = None, telemetry=None,
                  seed: int = 0):
-        if config is None:
-            config = ServingConfig()
-        elif isinstance(config, dict):
-            config = ServingConfig(**config)
-        self.config = config
-        self.engine = engine
-        self.module = engine.module
-        self.clock = clock or time.monotonic
         self.telemetry = telemetry
         # spans and counters go to the process's recorder whether or not a
         # sink is attached; with one, under its source, so its window flush
@@ -142,6 +134,18 @@ class ContinuousBatchingScheduler:
         self._source = (telemetry.source if telemetry is not None
                         else trace.new_source("sched"))
         self._tick_no = 0
+        with self._rec.span("scheduler_init", source=self._source, marks=trace.TOTAL):
+            self._build(engine, config, drafter, clock, seed)
+
+    def _build(self, engine, config, drafter, clock, seed) -> None:
+        if config is None:
+            config = ServingConfig()
+        elif isinstance(config, dict):
+            config = ServingConfig(**config)
+        self.config = config
+        self.engine = engine
+        self.module = engine.module
+        self.clock = clock or time.monotonic
         # explicit host-to-device transfers issued inside ticks; a tick's
         # inputs ride its program's own dispatch, so it reads 0 without
         # speculation (shown as 0, not left out)
@@ -175,9 +179,10 @@ class ContinuousBatchingScheduler:
         # program (~0.7 s mid-serve, measured as request 0's TTFT tail)
         from jax.sharding import NamedSharding, PartitionSpec
         self._placement = NamedSharding(engine.mesh, PartitionSpec())
-        self._cache = jax.device_put(  # graft-lint: waive R008 jax-owned fresh cache zeros, never donated before first use
-            make_slot_cache(self.module, self.slots, kv_quant=self.kv_quant),
-            self._placement)
+        with self._phase("cache_alloc"):
+            self._cache = jax.device_put(  # graft-lint: waive R008 jax-owned fresh cache zeros, never donated before first use
+                make_slot_cache(self.module, self.slots, kv_quant=self.kv_quant),
+                self._placement)
         self.capacity = slot_capacity(self._cache)  # tokens per slot
         # a model with recurrent layers keeps per-slot state with no
         # positions: no rows to copy, no length to leave unadvanced. What
@@ -231,14 +236,16 @@ class ContinuousBatchingScheduler:
         sampling = dict(do_sample=config.do_sample, temperature=config.temperature,
                         top_k=config.top_k, top_p=config.top_p)
         quantized = self.weight_dtype != "fp"
-        self.fns = serve_programs(engine, self.slots,
-                                  module=self.module if quantized else None,
-                                  mparams=(lambda p: p) if quantized else None,
-                                  prefill_chunk=config.prefill_chunk,
-                                  spec_k=self.spec_k,
-                                  weight_dtype=self.weight_dtype if quantized else None,
-                                  **sampling)
-        self._probe_slot_decode()
+        with self._phase("serve_programs"):
+            self.fns = serve_programs(engine, self.slots,
+                                      module=self.module if quantized else None,
+                                      mparams=(lambda p: p) if quantized else None,
+                                      prefill_chunk=config.prefill_chunk,
+                                      spec_k=self.spec_k,
+                                      weight_dtype=self.weight_dtype if quantized else None,
+                                      **sampling)
+        with self._phase("probe"):
+            self._probe_slot_decode()
         self._drafter = None
         if drafter is not None and self.spec_k:
             d_module, d_params = drafter
@@ -250,19 +257,21 @@ class ContinuousBatchingScheduler:
                                                  config.weight_group_size)
                 d_weight_dtype = "int8"
             self._drafter = (d_module, jax.device_put(d_params))  # graft-lint: waive R008 drafter weights, never donated
-            self._drafter_cache = jax.device_put(  # graft-lint: waive R008 jax-owned fresh cache zeros, same placement contract as the target cache
-                make_slot_cache(d_module, self.slots, kv_quant=self.kv_quant),
-                self._placement)
+            with self._phase("cache_alloc"):
+                self._drafter_cache = jax.device_put(  # graft-lint: waive R008 jax-owned fresh cache zeros, same placement contract as the target cache
+                    make_slot_cache(d_module, self.slots, kv_quant=self.kv_quant),
+                    self._placement)
             if slot_capacity(self._drafter_cache) < self.capacity:
                 raise ValueError("drafter context capacity is smaller than the "
                                  "target's — it cannot draft to the end of a "
                                  "maximal request")
-            self.dfns = serve_programs(engine, self.slots, role="drafter",
-                                       module=d_module, mparams=lambda p: p,
-                                       prefill_chunk=config.prefill_chunk,
-                                       spec_k=self.spec_k,
-                                       weight_dtype=d_weight_dtype,
-                                       **sampling)
+            with self._phase("serve_programs"):
+                self.dfns = serve_programs(engine, self.slots, role="drafter",
+                                           module=d_module, mparams=lambda p: p,
+                                           prefill_chunk=config.prefill_chunk,
+                                           spec_k=self.spec_k,
+                                           weight_dtype=d_weight_dtype,
+                                           **sampling)
         # the sequence counts the prefill program exists at, ascending: a
         # prefill tick runs the smallest that holds the slots it feeds (the
         # drafter's program is fed the same operands: the shorter ladder)
@@ -416,7 +425,8 @@ class ContinuousBatchingScheduler:
                         slot_pool_positions_touched(live, length, self.capacity))
 
     def _phase(self, name: str):
-        """A host phase of the tick in progress: a child span of ``tick``."""
+        """A host phase of the tick in progress: a child span of ``tick``
+        (or, before the first tick, of ``scheduler_init`` and ``warmup``)."""
         return self._rec.span(name, self._tick_no, self._source)
 
     # ------------------------------------------------------------------
@@ -428,7 +438,13 @@ class ContinuousBatchingScheduler:
         XLA compile time — and a warm *request* cannot reliably reach the
         rare-path programs (the drafter's refeed verify only runs when
         some slot accepts all k drafts). Touches no request accounting,
-        no histograms, and not the sampling rng stream."""
+        no histograms, and not the sampling rng stream. Each call is one
+        ``program`` span: the compile records JAX's events become fall
+        under it, and what is left of it is the program's first dispatch."""
+        with self._rec.span("warmup", source=self._source, marks=trace.WARMS | trace.TOTAL):
+            self._warmup()
+
+    def _warmup(self) -> None:
         parked = np.full(self.slots, self.capacity, np.int32)
         rng = ((jax.random.PRNGKey(0),) if self.config.do_sample else ())
         # host arrays, as every tick hands them over: what a jitted call is
@@ -447,20 +463,22 @@ class ContinuousBatchingScheduler:
                         + [(name, args + rng) for name, args in rungs]
                         + ([("verify", (parked, block))] if self.spec_k
                            else [("decode", (parked, tok) + rng)]))
-        per_role = [(self.fns, "_cache", self._serve_params, target_calls)]
+        per_role = [("", self.fns, "_cache", self._serve_params, target_calls)]
         if self._drafter is not None:
             # the draft loop feeds decode a mesh-committed token (see
             # _spec_tick); every other tick input arrives as a host array
             dtok = jax.device_put(tok, self._placement)  # graft-lint: waive R008 warmup operand placement parity w/ the draft loop, never donated
-            per_role.append((self.dfns, "_drafter_cache", self._drafter[1],
+            per_role.append(("drafter_", self.dfns, "_drafter_cache", self._drafter[1],
                              [("prefill", (parked, ids, last_idx) + rng)]
                              + [(name, args + rng) for name, args in rungs] +
                              [("decode", (parked, dtok) + rng),
                               ("verify", (parked, block))]))
-        for fns, cache_attr, params, calls in per_role:
+        for role, fns, cache_attr, params, calls in per_role:
             for name, args in calls:
                 if name in fns:
-                    cache, _ = fns[name](params, getattr(self, cache_attr), *args)
+                    with self._phase("program") as program:
+                        program.kind = role + name
+                        cache, _ = fns[name](params, getattr(self, cache_attr), *args)
                     setattr(self, cache_attr, cache)
 
     # ------------------------------------------------------------------
@@ -600,7 +618,7 @@ class ContinuousBatchingScheduler:
         if self.telemetry is not None:
             self.telemetry.begin_step(step_no)
         self._tick_no = step_no
-        with self._phase("tick") as tick:
+        with self._rec.span("tick", step_no, self._source, trace.UNIT) as tick:
             with self._phase("admit"):
                 if admit:
                     self._admit()
@@ -610,19 +628,18 @@ class ContinuousBatchingScheduler:
                       if r is not None and r.state == ACTIVE]
             if prefilling and (not active or self._decode_ticks_since_prefill
                                >= self.config.prefill_interleave):
-                kind = "prefill"
+                tick.kind = kind = "prefill"     # before the work: a compile inside names it
                 self._prefill_tick(prefilling)
                 self._decode_ticks_since_prefill = 0
             elif active:
-                kind = "spec" if self.spec_k else "decode"
+                tick.kind = kind = "spec" if self.spec_k else "decode"
                 if self.spec_k:
                     self._spec_tick(active)
                 else:
                     self._decode_tick(active)
                 self._decode_ticks_since_prefill += 1
             else:
-                kind = "idle"
-            tick.kind = kind
+                tick.kind = kind = "idle"
             if kind != "idle" and self._serve_t0 is None:
                 self._serve_t0 = self.clock()
             self.ticks[kind] += 1

@@ -1,3 +1,7 @@
+import time as _time
+
+_import_t0 = _time.perf_counter()  # the package's ``import`` record starts here
+
 from deepspeed_tpu.models.gpt2 import (GPT2Config, GPT2LMHeadModel, GPT2_CONFIGS, get_gpt2_config,
                                        cross_entropy_loss)
 from deepspeed_tpu.models.llama import (LlamaConfig, LlamaForCausalLM, LLAMA_CONFIGS, get_llama_config)
@@ -18,3 +22,7 @@ from deepspeed_tpu.models.gpt_neo import (GPTNeoConfig, GPTNeoForCausalLM, GPT_N
                                           get_gpt_neo_config)
 from deepspeed_tpu.models.clip import (CLIPTextConfig, CLIPTextModel, CLIP_TEXT_CONFIGS,
                                        get_clip_text_config)
+
+from deepspeed_tpu.utils import trace as _trace  # noqa: E402
+
+_trace.imported(__name__, _import_t0)

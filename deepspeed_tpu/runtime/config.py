@@ -230,8 +230,7 @@ class TelemetryConfig(DeepSpeedConfigModel):
     JSONL event log, and static-vs-measured drift reporting.
 
     ``output_path``/``job_name``: the run directory
-    (``<output_path>/<job_name>/telemetry.jsonl``; the
-    ``DS_TRACE_STEPS`` XLA capture lands under ``xla_trace/`` next to it).
+    (``<output_path>/<job_name>/telemetry.jsonl``).
     ``flush_interval_steps``: span/drift window cadence (0 = follow
     ``steps_per_print``). ``static_price``: stamp the step program's
     static price (flops_proxy + liveness bytes) into the run header —

@@ -42,6 +42,7 @@ from deepspeed_tpu.runtime.fp16.loss_scaler import (LossScaleState, OverflowWatc
 from deepspeed_tpu.runtime.lr_schedules import get_lr_schedule
 from deepspeed_tpu.runtime.resilience.faults import fault_point
 from deepspeed_tpu.runtime.zero.planner import ZeroPlan, build_plan, resolve_topology_axes
+from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (TRAIN_BATCH_TIMER, NoopTimer, SynchronizedWallClockTimer, ThroughputTimer)
 
@@ -245,7 +246,7 @@ class DeepSpeedEngine:
         # Instrumentation is host-only by construction: the traced step
         # program must stay eqn-identical with telemetry on (rule R015,
         # scenario train_batch_telemetry) and within 2% step time (tier-1).
-        from deepspeed_tpu.runtime.telemetry import RuntimeTelemetry, parse_trace_steps
+        from deepspeed_tpu.runtime.telemetry import RuntimeTelemetry
         self.telemetry = RuntimeTelemetry(config.telemetry_config,
                                           flush_every=config.steps_per_print,
                                           rank=dist.get_rank(),
@@ -253,21 +254,6 @@ class DeepSpeedEngine:
                                           label="engine")
         if self.monitor.enabled:
             self.telemetry.subscribe(self.monitor.write_events)
-        # DS_TRACE_STEPS=<start>[:<count>]: cadenced XLA device-trace
-        # capture into the telemetry run dir (jax.profiler.start_trace
-        # via _maybe_trace_window) — the env wins over any trace_profiler
-        # config block, the A/B lever for one-off captures
-        _trace_spec = parse_trace_steps(os.environ.get("DS_TRACE_STEPS"))
-        if _trace_spec is not None:
-            from deepspeed_tpu.profiling.config import DeepSpeedTraceProfilerConfig
-            _tc = config.trace_profiler_config
-            _out = (os.path.join(self.telemetry.run_dir, "xla_trace")
-                    if self.telemetry.run_dir else _tc.output_dir)
-            config.trace_profiler_config = DeepSpeedTraceProfilerConfig(
-                enabled=True, start_step=_trace_spec[0], num_steps=_trace_spec[1],
-                output_dir=_out, host_tracer_level=_tc.host_tracer_level,
-                python_tracer=_tc.python_tracer)
-
         # -- resilience (runtime/resilience): host mirror of the compiled
         #    overflow-skip state + preemption-to-checkpoint signal handling
         _rcfg = config.resilience_config
@@ -595,7 +581,21 @@ class DeepSpeedEngine:
             set_topology(self.topology)
             return
         rng = rng if rng is not None else self._base_rng
-        init_params, _, _ = self._prepare_plan(example_batch, rng)
+        tel = self.telemetry
+        with tel.recorder.span("initialize_state", source=tel.source, marks=trace.TOTAL):
+            with tel.span("plan"):
+                init_params, _, _ = self._prepare_plan(example_batch, rng)
+            with tel.span("state_init"):
+                self._init_state(init_params, rng)
+            with tel.span("build_step"):
+                self._maybe_apply_student_init()
+                self._setup_offload_optimizer()
+                self._setup_param_offload()
+                self._build_step_fns()
+
+    def _init_state(self, init_params, rng) -> None:
+        """Params and optimizer state made where they rest, and the
+        ``TrainState`` around them."""
         param_shardings = self.state_shardings.params
         opt_shardings = self.state_shardings.opt_state
 
@@ -647,10 +647,6 @@ class DeepSpeedEngine:
                                 params=params,
                                 opt_state=opt_state,
                                 loss_scale=ls_state)
-        self._maybe_apply_student_init()
-        self._setup_offload_optimizer()
-        self._setup_param_offload()
-        self._build_step_fns()
 
     def abstract_state(self, example_batch, rng: Optional[jax.Array] = None) -> TrainState:
         """The TrainState as a ``ShapeDtypeStruct`` pytree — plan, shardings
@@ -2110,7 +2106,8 @@ class DeepSpeedEngine:
         tel = self.telemetry
         step_no = self.global_steps + 1
         tel.begin_step(step_no)
-        with tel.span("train_batch", step_no):
+        with tel.span("train_batch", step_no, trace.UNIT) as unit:
+            unit.kind = "stack"     # another program than a single step's: its first compile is no recompile
             with tel.span("timer_sync", step_no):
                 self.tput_timer.start()
             self.timers(TRAIN_BATCH_TIMER).start()
@@ -2192,7 +2189,7 @@ class DeepSpeedEngine:
         tel = self.telemetry
         step_no = self.global_steps + 1
         tel.begin_step(step_no)
-        with tel.span("train_batch", step_no):
+        with tel.span("train_batch", step_no, trace.UNIT):
             with tel.span("timer_sync", step_no):
                 self.tput_timer.start()
             self.timers(TRAIN_BATCH_TIMER).start()

@@ -12,6 +12,7 @@ from typing import Optional
 from deepspeed_tpu import comm as dist
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import log_dist
 from deepspeed_tpu.version import __version__
 
@@ -35,6 +36,17 @@ def initialize(args=None,
     ``config`` is a dict or JSON path; ``args.deepspeed_config`` is honored
     for parity. ``mpu`` is accepted but unused: the mesh topology subsumes
     it (pass ``topology=`` to override)."""
+    with trace.recorder().span("initialize", marks=trace.TOTAL) as span:
+        built = _initialize(args, model, optimizer, model_parameters, training_data,
+                            lr_scheduler, topology, dist_init_required, collate_fn, config,
+                            config_params, loss_fn)
+        # under the engine's source, so that its sink's first window takes the span
+        span.source = built[0].telemetry.source
+    return built
+
+
+def _initialize(args, model, optimizer, model_parameters, training_data, lr_scheduler, topology,
+                dist_init_required, collate_fn, config, config_params, loss_fn):
     assert model is not None, "deepspeed.initialize requires a model"
     log_dist(f"DeepSpeed-TPU info: version={__version__}")
 
@@ -66,8 +78,9 @@ def initialize(args=None,
         if bound:
             log_dist(f"bound to host cores {bound[0]}-{bound[-1]} ({len(bound)} cores)")
 
-    ds_config = DeepSpeedConfig(config,
-                                dp_world_size=topology.data_parallel_size if topology is not None else None)
+    with trace.recorder().span("config"):
+        ds_config = DeepSpeedConfig(
+            config, dp_world_size=topology.data_parallel_size if topology is not None else None)
     from deepspeed_tpu.runtime.pipe.module import PipelineModule
     if ds_config.hybrid_engine_config.enabled and not isinstance(model, PipelineModule):
         # RLHF train+serve engine (reference __init__.py:151 dispatches

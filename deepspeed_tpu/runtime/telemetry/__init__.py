@@ -13,14 +13,14 @@ Reader/report side: ``tools/trace_report.py``.
 
 from deepspeed_tpu.runtime.telemetry.core import (RuntimeTelemetry, config_signature,
                                                   drift_ratios, measured_memory,
-                                                  parse_trace_steps, TELEMETRY_FILE)
+                                                  TELEMETRY_FILE)
 from deepspeed_tpu.runtime.telemetry.metrics import DEFAULT_LATENCY_BOUNDS, Histogram
 from deepspeed_tpu.runtime.telemetry.sink import (TELEMETRY_SCHEMA_VERSION, JsonlSink,
                                                   iter_events, read_events)
 
 __all__ = [
     "RuntimeTelemetry", "config_signature", "drift_ratios", "measured_memory",
-    "parse_trace_steps", "TELEMETRY_FILE",
+    "TELEMETRY_FILE",
     "Histogram", "DEFAULT_LATENCY_BOUNDS",
     "TELEMETRY_SCHEMA_VERSION", "JsonlSink", "iter_events", "read_events",
 ]

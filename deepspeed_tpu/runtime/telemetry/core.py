@@ -16,9 +16,9 @@ Three pillars (ISSUE 13):
    its ``source`` and writes one ``spans`` event (raw timeline) and one
    ``step_window`` event (per-phase p50/p99 aggregates).
    ``tools/trace_report.py`` turns the timeline into Chrome trace-event
-   JSON. ``DS_TRACE_STEPS=<start>:<count>`` additionally opens a cadenced
-   ``jax.profiler`` device-trace window into the same run directory
-   (wired by the engine through ``jax.profiler.start_trace``).
+   JSON. The engine's ``trace_profiler`` config block opens a
+   ``jax.profiler`` device-trace window, in which the same spans lie
+   under ``ds:``.
 3. **Drift** — each window closes with a ``drift`` event: achieved
    TFLOPS (predicted ``flops_proxy`` ÷ measured median step time) and
    predicted-vs-measured memory ratios (device ``memory_stats`` peaks
@@ -44,8 +44,7 @@ from deepspeed_tpu.runtime.telemetry.sink import (TELEMETRY_SCHEMA_VERSION, Json
 from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import logger
 
-__all__ = ["RuntimeTelemetry", "config_signature", "parse_trace_steps",
-           "measured_memory", "TELEMETRY_FILE"]
+__all__ = ["RuntimeTelemetry", "config_signature", "measured_memory", "TELEMETRY_FILE"]
 
 TELEMETRY_FILE = "telemetry.jsonl"
 
@@ -57,25 +56,6 @@ def config_signature(raw_dict: Dict) -> str:
     except (TypeError, ValueError):
         blob = repr(raw_dict)
     return hashlib.sha256(blob.encode()).hexdigest()[:12]
-
-
-def parse_trace_steps(spec: Optional[str]) -> Optional[Tuple[int, int]]:
-    """``DS_TRACE_STEPS=<start>[:<count>]`` → (start, count); None when
-    unset/empty. Malformed specs raise — a mistyped capture window must
-    not silently skip the one chip run it was meant to profile."""
-    if not spec:
-        return None
-    parts = spec.split(":")
-    if len(parts) > 2:
-        raise ValueError(f"DS_TRACE_STEPS={spec!r}: expected <start>[:<count>]")
-    try:
-        start = int(parts[0])
-        count = int(parts[1]) if len(parts) == 2 and parts[1] else 1
-    except ValueError as e:
-        raise ValueError(f"DS_TRACE_STEPS={spec!r}: expected integers") from e
-    if start < 1 or count < 1:
-        raise ValueError(f"DS_TRACE_STEPS={spec!r}: start and count must be >= 1")
-    return start, count
 
 
 def measured_memory() -> Dict[str, int]:
@@ -220,8 +200,8 @@ class RuntimeTelemetry:
                          "static_price": self.static_price}, flush=True)
 
     # -- spans / steps -------------------------------------------------
-    def span(self, name: str, uid: Optional[int] = None):
-        return self.recorder.span(name, uid, self.source)
+    def span(self, name: str, uid: Optional[int] = None, marks: int = 0):
+        return self.recorder.span(name, uid, self.source, marks)
 
     @property
     def last_span(self) -> Optional[str]:
@@ -261,9 +241,12 @@ class RuntimeTelemetry:
         hists: Dict[str, Histogram] = {}
         for r in records:
             if len(events) < self.max_buffered:
-                events.append({"name": r.name, "path": "/".join(r.path),
-                               "ts": r.start + self._epoch, "dur_s": r.dur,
-                               "depth": len(r.path), "uid": r.uid})
+                event = {"name": r.name, "path": "/".join(r.path),
+                         "ts": r.start + self._epoch, "dur_s": r.dur,
+                         "depth": len(r.path), "uid": r.uid}
+                if r.kind is not None:      # a tick's kind; a compile record's function
+                    event["kind"] = r.kind
+                events.append(event)
             else:
                 dropped += 1
             hists.setdefault(r.name, Histogram()).record(r.dur)

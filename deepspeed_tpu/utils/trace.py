@@ -11,8 +11,8 @@ dispatch, the blocking read-back, the commit loop, checkpoint publish.
 A span does two things on entry and exit:
 
 * it opens ``jax.profiler.TraceAnnotation("ds:<name>")``, so that in any
-  profiler session (``DS_TRACE_STEPS``, the ``trace_profiler`` block, a
-  benchmark's ``--trace 1``) it lies on the host plane on the same clock
+  profiler session (the ``trace_profiler`` block, a benchmark's
+  ``--trace 1``) it lies on the host plane on the same clock
   as the device's ``XLA Ops`` line. With no session that is one flag test;
 * it appends one :class:`Record` to a bounded in-memory ring: two
   ``perf_counter`` reads and one append. The ring is always on: it is the
@@ -23,22 +23,59 @@ Counters are plain integers in one dict on the same object. One
 process-level instance (:func:`recorder`) is reachable without a handle on
 an engine; a record names the engine or scheduler it came from
 (``source``), so two in one process do not read each other's.
+
+The process's recorder also hears JAX's compile events
+(``jax.monitoring``): each trace, lowering, backend compile and cache
+retrieval becomes one back-dated record under the span that was open on
+the thread, and adds its microseconds to counters named by the **root**
+span it fell under (:data:`COMPILE_EVENTS`). So a start divides into the
+spans of the entry points (marked :data:`TOTAL`) and, inside each,
+the time JAX held the interpreter and the time the compiler or the cache
+took; and a compile in the middle of serving or training is a
+``recompile`` record that names the tick or step, the phase and the
+function.
 """
 
 import collections
 import itertools
 import threading
 import time
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Set, Tuple
 
 import jax
+import jax.monitoring
 
-__all__ = ["Record", "Recorder", "recorder", "new_source", "PREFIX"]
+__all__ = ["Record", "Recorder", "recorder", "new_source", "imported", "PREFIX",
+           "COMPILE_EVENTS", "UNIT", "WARMS", "TOTAL"]
 
 #: prefix of every annotation this recorder writes into a profiler trace
 PREFIX = "ds:"
 #: a 51 s serving run is ~10,000 records (some ten spans a 60 ms tick)
 RING_RECORDS = 65536
+#: JAX's compile events: the record each becomes, and the counter its
+#: microseconds go to, which ends in the name of the root span it fell
+#: under (``setup_backend_us_warmup``). The cache's retrieval lies inside
+#: the backend compile that asked for it, so it is in no sum of the others
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": ("compile_trace", "setup_trace_lower_us"),
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": ("compile_lower", "setup_trace_lower_us"),
+    "/jax/core/compile/backend_compile_duration": ("compile_backend", "setup_backend_us"),
+    "/jax/compilation_cache/cache_retrieval_time_sec": ("compile_cache_load",
+                                                        "setup_cache_load_us"),
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+#: a model's trace emits one event for every jitted function it calls
+#: (``add``, ``softmax``): thousands, each inside the trace of the program.
+#: They are counted, once, and those shorter than this stay out of the ring
+_RING_COMPILE_S = 5e-3
+#: an interval is inside another that starts this much after it: the two
+#: clocks are read some microseconds after the interval ended
+_NEST_SLACK_S = 1e-4
+#: closed intervals kept for one that may contain them. A model's trace holds
+#: some thousand until it ends; a process that retraces for ever holds no more
+_NEST_KEPT = 16384
+#: what a span's opener may say of it (``Recorder.span``)
+UNIT, WARMS, TOTAL = 1, 2, 4
 
 
 class Record(NamedTuple):
@@ -51,7 +88,7 @@ class Record(NamedTuple):
     path: Tuple[str, ...]      # names of the enclosing spans, outermost first
     uid: Optional[int]         # shared by the spans of one unit of work: tick, step, request
     source: Optional[str]      # the engine or scheduler it came from
-    kind: Optional[str]        # what the unit of work turned out to be (a tick's kind)
+    kind: Optional[str]        # a tick's kind, a compile's function, an import's package
 
     @property
     def parent(self) -> Optional[str]:
@@ -64,20 +101,25 @@ class Record(NamedTuple):
 
 
 class _Span:
-    __slots__ = ("_rec", "name", "uid", "source", "kind", "_ann", "_path", "_t0")
+    __slots__ = ("_rec", "name", "uid", "source", "kind", "marks", "_ann", "_path", "_t0")
 
-    def __init__(self, rec: "Recorder", name: str, uid, source):
+    def __init__(self, rec: "Recorder", name: str, uid, source, marks):
         self._rec = rec
         self.name = name
         self.uid = uid
         self.source = source
         self.kind = None    # set while the span is open, once the work is chosen
+        self.marks = marks  # what the opener said of it: see ``Recorder.span``
 
     def __enter__(self):
         rec = self._rec
-        stack = rec._stack()
-        self._path = tuple(stack)
-        stack.append(self.name)
+        stack = rec._local.stack
+        if stack:
+            parent = stack[-1]
+            self._path = parent._path + (parent.name,)
+        else:
+            self._path = ()
+        stack.append(self)
         rec.last_span = self.name
         self._ann = jax.profiler.TraceAnnotation(PREFIX + self.name)
         self._ann.__enter__()
@@ -88,9 +130,34 @@ class _Span:
         end = time.perf_counter()
         self._ann.__exit__(*exc)
         rec = self._rec
-        rec._stack().pop()
+        rec._local.stack.pop()
         rec._append(self.name, self._t0, end, self._path, self.uid, self.source, self.kind)
+        marks = self.marks
+        if marks:
+            if marks & TOTAL and not self._path:
+                rec.count("setup_span_us_" + self.name, int((end - self._t0) * 1e6))
+            if marks & WARMS:
+                rec._warmed.add(self.source)
         return False
+
+    @property
+    def below(self) -> Tuple[str, ...]:
+        """The path of what opens or falls inside this span."""
+        return self._path + (self.name,)
+
+
+class _Thread(threading.local):
+    """What the recorder keeps for each thread."""
+
+    def __init__(self):
+        self.stack: List[_Span] = []    # the open spans, outermost first
+        # closed compile and import intervals that a later one may turn out
+        # to contain, oldest first: (start, microseconds, counter). One stays
+        # for each compile or import that no other contained, ``_NEST_KEPT`` at most
+        self.nest: List[tuple] = []
+        self.cache_hit = False          # the cache answered the compile in progress
+        # ((unit span, its kind), is a compile under it a recompile)
+        self.unit = (None, False)
 
 
 class Recorder:
@@ -102,31 +169,122 @@ class Recorder:
         self.last_seq = 0
         self.counters: Dict[str, int] = {}
         self.last_span: Optional[str] = None  # liveness breadcrumb (heartbeat payload)
-        self._local = threading.local()       # a stack of open spans per thread
-
-    def _stack(self) -> List[str]:
-        try:
-            return self._local.stack
-        except AttributeError:
-            self._local.stack = stack = []
-            return stack
+        self._local = _Thread()
+        self._warmed: Set[Optional[str]] = set()    # sources that closed a span that ``WARMS``
 
     def _append(self, name, start, end, path, uid, source, kind) -> None:
         self.last_seq = seq = next(self._seq)
         self._ring.append(Record(seq, name, start, end, path, uid, source, kind))
 
-    def span(self, name: str, uid: Optional[int] = None,
-             source: Optional[str] = None) -> _Span:
+    def span(self, name: str, uid: Optional[int] = None, source: Optional[str] = None,
+             marks: int = 0) -> _Span:
         """Context manager around one host phase; nests under the span
-        that is open on this thread."""
-        return _Span(self, name, uid, source)
+        that is open on this thread. ``marks`` is what its opener says of
+        it, any of these or'ed together:
+
+        * :data:`UNIT`: it is one unit of steady work (a tick, a step). A
+          backend compile under it is a recompile once its source has
+          finished a unit of the same name and kind, or a span that warms;
+        * :data:`WARMS`: when it closes, its source has compiled what its
+          units run;
+        * :data:`TOTAL`: where it is a root (an entry point of the start),
+          its microseconds also go to the counter ``setup_span_us_<name>``:
+          what a start cost is still known when the ring has turned over."""
+        return _Span(self, name, uid, source, marks)
 
     def record(self, name: str, start: float, end: float, uid: Optional[int] = None,
-               source: Optional[str] = None) -> None:
+               source: Optional[str] = None, kind: Optional[str] = None) -> None:
         """An interval that is over when it becomes known (a request's wait
-        for a slot), on the caller's clock. Ring only: a ``TraceAnnotation``
+        for a slot, a compile), on the caller's clock. It is filed under
+        the span open on this thread, and takes that span's ``uid`` and
+        ``source`` where it is given none. Ring only: a ``TraceAnnotation``
         cannot be back-dated."""
-        self._append(name, start, end, (), uid, source, None)
+        stack = self._local.stack
+        if stack:
+            inner = stack[-1]
+            self._append(name, start, end, inner.below, inner.uid if uid is None else uid,
+                         inner.source if source is None else source, kind)
+        else:
+            self._append(name, start, end, (), uid, source, kind)
+
+    def _add_once(self, counter: str, start: float, end: float) -> None:
+        """Add an interval's microseconds to ``counter``, and take back
+        those of the earlier intervals of this thread that lie inside it (a
+        jitted function traced inside another's trace reports first): they
+        are its children, so no sum of these counters exceeds the span the
+        intervals fell under."""
+        nest = self._local.nest
+        while nest and nest[-1][0] >= start - _NEST_SLACK_S:
+            _, us, inside = nest.pop()
+            self.count(inside, -us)
+        if len(nest) >= _NEST_KEPT:
+            del nest[:_NEST_KEPT // 2]
+        us = int((end - start) * 1e6)
+        nest.append((start, us, counter))
+        self.count(counter, us)
+
+    def _is_recompile(self, stack: List[_Span]) -> bool:
+        """Whether a compile under the open spans falls in a unit of steady
+        work (the innermost span marked :data:`UNIT`) whose source has
+        already closed a span that :data:`WARMS`, or finished a unit of the same
+        name and kind. Read from the ring when a unit compiles, so that no
+        tick or step pays to keep it known."""
+        for unit in reversed(stack):
+            if unit.marks & UNIT:
+                break
+        else:
+            return False
+        key, known = (unit, unit.kind), self._local.unit
+        if known[0] != key:     # a tick's kind is set once its work is chosen
+            done = unit.source in self._warmed or any(
+                r.name == unit.name and r.source == unit.source and r.kind == unit.kind
+                for r in reversed(self._ring))
+            self._local.unit = known = (key, done)
+        return known[1]
+
+    def _on_compile_duration(self, event: str, duration: float, fun_name: Optional[str] = None,
+                             **_) -> None:
+        """What the process's recorder does with a duration event of
+        ``jax.monitoring``: see :data:`COMPILE_EVENTS`."""
+        found = COMPILE_EVENTS.get(event)
+        if found is None:
+            return
+        name, counter = found
+        end = time.perf_counter()
+        start = end - duration
+        local = self._local
+        stack = local.stack
+        recompile = self._is_recompile(stack)
+        if not stack:
+            where = "compile_outside_us"
+        elif recompile:
+            where = "recompile_us"
+        else:
+            where = f"{counter}_{stack[0].name}"
+        if name == "compile_cache_load":
+            if stack and not recompile:
+                self.count(where, int(duration * 1e6))
+        else:
+            self._add_once(where, start, end)
+        if name != "compile_trace" or duration >= _RING_COMPILE_S:
+            self.record(name, start, end, kind=fun_name)
+        if name != "compile_backend":
+            return
+        hit, local.cache_hit = local.cache_hit, False
+        if recompile:
+            # the operator's answer to "which step recompiled, and what":
+            # uid is the tick or step, the parent the phase, kind the function
+            self.count("recompiles_in_units")
+            self.record("recompile", start, end, kind=fun_name)
+        elif stack:
+            self.count(f"setup_programs_loaded_{stack[0].name}")
+            self.count(f"setup_cache_hits_{stack[0].name}", int(hit))
+
+    def _on_compile_event(self, event: str, **_) -> None:
+        """A hit of the persistent cache comes before the duration of the
+        backend compile it answers."""
+        if event == _CACHE_HIT_EVENT:
+            self._local.cache_hit = True
 
     def count(self, name: str, n: int = 1) -> None:
         counters = self.counters
@@ -162,6 +320,20 @@ _RECORDER = Recorder()
 _sources = itertools.count()
 
 
+def _hear_duration(event: str, duration: float, **kwargs) -> None:
+    _RECORDER._on_compile_duration(event, duration, **kwargs)
+
+
+def _hear_event(event: str, **kwargs) -> None:
+    _RECORDER._on_compile_event(event, **kwargs)
+
+
+# once a process, here: whatever recorder is the process's when JAX compiles
+# hears it, however many engines and schedulers the process builds
+jax.monitoring.register_event_duration_secs_listener(_hear_duration)
+jax.monitoring.register_event_listener(_hear_event)
+
+
 def recorder() -> Recorder:
     """The process's recorder."""
     return _RECORDER
@@ -170,3 +342,13 @@ def recorder() -> Recorder:
 def new_source(label: str) -> str:
     """A name no other engine or scheduler of this process records under."""
     return f"{label}#{next(_sources)}"
+
+
+def imported(package: str, start: float) -> None:
+    """The last line of a package's ``__init__`` calls this with the
+    ``perf_counter`` read on its first: one ``import`` record, and its
+    microseconds in ``setup_import_us`` (a package imported inside another's
+    import is counted once)."""
+    end = time.perf_counter()
+    _RECORDER._add_once("setup_import_us", start, end)
+    _RECORDER.record("import", start, end, kind=package)

@@ -124,7 +124,8 @@ def test_queue_wait_plus_prefill_wait_is_the_time_to_first_token(served):
         assert (queue.start, queue.end) == (r.arrival_time, r.admit_time)
         assert (prefill.start, prefill.end) == (r.admit_time, r.first_token_time)
         assert queue.dur + prefill.dur == r.ttft
-        assert queue.path == () and prefill.path == ()       # ring only: no enclosing span
+        # ring only, filed under the phase that wrote them, under the request's own identifier
+        assert queue.path == ("tick", "admit") and prefill.path == ("tick", "commit")
 
 
 def test_prefill_counters_are_tokens_fed_against_positions_computed(served):

@@ -11,8 +11,7 @@ import pytest
 
 from deepspeed_tpu.runtime.telemetry import (DEFAULT_LATENCY_BOUNDS, Histogram,
                                              JsonlSink, RuntimeTelemetry,
-                                             TELEMETRY_SCHEMA_VERSION,
-                                             parse_trace_steps, read_events)
+                                             TELEMETRY_SCHEMA_VERSION, read_events)
 from deepspeed_tpu.utils import trace
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..", "..", ".."))
@@ -69,15 +68,6 @@ def test_sink_rank_gating_and_corrupt_tail(tmp_path):
     assert [e["event"] for e in events] == ["a", "coerced"]
     assert events[1]["arr"] == [0, 1] and isinstance(events[1]["bad"], str)
     assert all("t" in e for e in events)
-
-
-def test_parse_trace_steps():
-    assert parse_trace_steps(None) is None and parse_trace_steps("") is None
-    assert parse_trace_steps("3:2") == (3, 2)
-    assert parse_trace_steps("5") == (5, 1)
-    for bad in ("0:1", "2:0", "a", "1:2:3"):
-        with pytest.raises(ValueError):
-            parse_trace_steps(bad)
 
 
 # ---------------------------------------------------------------------------
@@ -188,7 +178,9 @@ def test_train_batch_children_with_the_sink_off():
     steps = [r for r in records if r.name == "train_batch"]
     assert [r.uid for r in steps] == [1, 2, 3, 4]     # the fused stack is one span, from step 4
     for step in steps:
-        children = [r for r in records if r.uid == step.uid and r.path == ("train_batch",)]
+        # (what a step compiled between its phases is records of their own among them)
+        children = [r for r in records if r.uid == step.uid and r.path == ("train_batch",)
+                    and not r.name.startswith(("compile_", "recompile"))]
         assert [r.name for r in children] == ["timer_sync", "batch_stage", "dispatch",
                                               "device_wait", "post_step"]
         assert all(r.parent == "train_batch" for r in children)
@@ -252,20 +244,34 @@ def test_trace_report_round_trip_and_drift(tmp_path, capsys):
     assert summary["median_step_s"] > 0
 
 
-def test_ds_trace_steps_env_knob(tmp_path, monkeypatch):
-    """DS_TRACE_STEPS=<start>:<count> drops an XLA device trace into the
-    telemetry run dir (jax.profiler.start_trace cadence)."""
-    import glob
+def test_trace_report_shows_the_start_and_lists_every_recompile(tmp_path, capsys):
+    """The spans of the start and the compile records the recorder files
+    under them are in the exported timeline (a back-dated record carries
+    its enclosing span's source, so the window's drain takes it), and a
+    step that compiled again is named on stderr: step, phase, function."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    import trace_report
 
-    monkeypatch.setenv("DS_TRACE_STEPS", "2:1")
-    engine, run_dir = _train_run(tmp_path)
-    assert not getattr(engine, "_trace_active", False), "trace window left open"
-    found = glob.glob(os.path.join(run_dir, "xla_trace", "**", "*.xplane.pb"),
-                      recursive=True)
-    assert found, f"no xplane trace under {run_dir}/xla_trace"
-    events = read_events(os.path.join(run_dir, "telemetry.jsonl"))
-    phases = [e["phase"] for e in events if e["event"] == "xla_trace"]
-    assert phases == ["start", "stop"]
+    engine, run_dir = _train_run(tmp_path, n_steps=2)
+    shorter = {"input_ids": np.arange(8 * 16, dtype=np.int32).reshape(8, 16) % 256}
+    engine.train_batch(shorter)      # another shape: step 3 compiles the step again
+    engine.telemetry.sink.flush()
+    out = str(tmp_path / "chrome.json")
+    assert trace_report.main([run_dir, "--out", out]) == 0
+    err = capsys.readouterr().err
+    listed = [line for line in err.splitlines() if "recompile in unit" in line]
+    assert any("unit 3 (step), phase dispatch: jit(train_step)" in line for line in listed), err
+    spans = [e for e in json.load(open(out))["traceEvents"] if e["ph"] == "X"]
+    names = {e["name"] for e in spans}
+    assert {"initialize", "initialize_state", "state_init", "build_step", "compile_lower",
+            "compile_backend", "recompile"} <= names
+    first = [e for e in spans if e["name"] == "compile_backend"
+             and e["args"].get("kind") == "jit(train_step)"]
+    assert [e["args"]["path"] for e in first] == ["train_batch/dispatch"] * 2     # steps 1 and 3
+    (start,) = [e for e in spans if e["name"] == "initialize_state"]
+    inside = [e for e in spans if e["args"]["path"].startswith("initialize_state")]
+    assert inside and all(start["ts"] <= e["ts"] and e["ts"] + e["dur"] <= start["ts"]
+                          + start["dur"] + 1e3 for e in inside)
 
 
 def test_checkpoint_spans_and_event(tmp_path):

@@ -7,6 +7,8 @@ import os
 import threading
 import time
 
+import pytest
+
 from deepspeed_tpu.utils import trace
 
 
@@ -176,3 +178,334 @@ def test_spans_lie_in_a_profiler_session_under_the_prefix(tmp_path):
     assert found["ds:device_wait"][1] <= found["ds:tick"][1]
     assert found["ds:device_wait"][1] - found["ds:device_wait"][0] >= 2e6      # the sleep, in ns
     assert [r.name for r in rec.records()] == ["device_wait", "tick", "after_the_session"]
+
+
+# ---------------------------------------------------------------------------
+# JAX's compile events, filed under the span that caused them
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def hearing(monkeypatch):
+    """A recorder of this test's own as the process's: the listeners, which
+    the module registered once, reach whichever recorder that is."""
+    rec = trace.Recorder()
+    monkeypatch.setattr(trace, "_RECORDER", rec)
+    return rec
+
+
+def _fresh_jit(name):
+    """A jitted function no other test has compiled, under a name to look for."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(x):
+        time.sleep(0.006)    # runs while tracing only; a trace under 5 ms is counted, not recorded
+        return jnp.tanh(x) * 3.0 + 1.0
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def _compiles(rec, fun):
+    return [r for r in rec.records() if r.name.startswith("compile_") and fun in (r.kind or "")]
+
+
+def test_a_compile_is_filed_under_the_open_span_and_counted_under_its_root(hearing):
+    import jax.numpy as jnp
+
+    fn, x = _fresh_jit("filed_under_the_span"), jnp.ones((3, 5))
+    hearing.counters.clear()     # making ``x`` compiled too, under no span
+    with hearing.span("warmup", source="sched#7", marks=trace.TOTAL):
+        with hearing.span("program", 11, "sched#7") as program:
+            program.kind = "decode"
+            fn(x)
+    mine = _compiles(hearing, "filed_under_the_span")
+    # the trace is named by the function, the rest by JAX's ``jit(...)``
+    assert {r.name for r in mine} == {"compile_trace", "compile_lower", "compile_backend"}
+    by_name = {r.name: r for r in mine}
+    assert by_name["compile_trace"].kind == "filed_under_the_span"
+    assert by_name["compile_backend"].kind == "jit(filed_under_the_span)"
+    span = {r.name: r for r in hearing.records()}["program"]
+    for r in mine:
+        assert (r.path, r.uid, r.source) == (("warmup", "program"), 11, "sched#7")
+        assert span.start <= r.start <= r.end <= span.end + 1e-3     # back-dated into the span
+    assert (by_name["compile_trace"].end <= by_name["compile_lower"].end
+            <= by_name["compile_backend"].end)
+    c = hearing.counters
+    assert c["setup_programs_loaded_warmup"] == 1 and c["setup_cache_hits_warmup"] in (0, 1)
+    assert c["setup_trace_lower_us_warmup"] > 0 and c["setup_backend_us_warmup"] > 0
+    # no total exceeds the span it fell under, and that span is a counter too
+    assert (c["setup_trace_lower_us_warmup"] + c["setup_backend_us_warmup"]
+            <= c["setup_span_us_warmup"])
+    assert "compile_outside_us" not in c and "recompiles_in_units" not in c
+
+
+def test_the_second_call_of_a_function_records_nothing(hearing):
+    import jax.numpy as jnp
+
+    fn, x = _fresh_jit("called_twice"), jnp.ones((2, 2))
+    with hearing.span("warmup"):
+        fn(x)
+    seen, counters = hearing.last_seq, dict(hearing.counters)
+    with hearing.span("warmup"):
+        fn(x)
+    assert [r.name for r in hearing.records() if r.seq > seen] == ["warmup"]
+    assert hearing.counters == counters
+
+
+def test_a_compile_under_no_span_goes_to_compile_outside(hearing):
+    import jax.numpy as jnp
+
+    fn, x = _fresh_jit("under_no_span"), jnp.ones((4,))
+    hearing.counters.clear()
+    fn(x)
+    mine = _compiles(hearing, "under_no_span")
+    assert len(mine) == 3 and all((r.path, r.uid, r.source) == ((), None, None) for r in mine)
+    assert set(hearing.counters) == {"compile_outside_us"}
+    assert hearing.counters["compile_outside_us"] <= sum(r.dur for r in mine) * 1e6 + 3
+
+
+def test_a_function_traced_inside_another_is_counted_once(hearing):
+    """The inner ``jit``'s trace event arrives first, inside the outer's
+    interval: its microseconds are taken back when the outer's arrive."""
+    import jax
+    import jax.numpy as jnp
+
+    inner = _fresh_jit("inner_of_two")
+
+    def outer(x):
+        time.sleep(0.01)     # so that the inner's own time could not hide in rounding
+        return inner(x) + inner(x * 2.0)
+    outer.__name__ = outer.__qualname__ = "outer_of_two"
+    x = jnp.ones((6,))
+    hearing.counters.clear()
+    with hearing.span("warmup", marks=trace.TOTAL):
+        jax.jit(outer)(x)
+    traces = [r for r in hearing.records() if r.name == "compile_trace"]
+    outer_trace = [r for r in traces if r.kind == "outer_of_two"]
+    assert len(outer_trace) == 1 and outer_trace[0].dur >= 0.01
+    lowered = sum(r.dur for r in hearing.records()
+                  if r.name == "compile_lower" and r.path == ("warmup",))
+    c = hearing.counters
+    # trace + lowering is the outer's trace and the lowerings, not the inner traces again
+    assert c["setup_trace_lower_us_warmup"] == pytest.approx(
+        (outer_trace[0].dur + lowered) * 1e6, abs=200)
+    assert (c["setup_trace_lower_us_warmup"] + c["setup_backend_us_warmup"]
+            <= c["setup_span_us_warmup"])
+    assert c["setup_programs_loaded_warmup"] == 1      # one program came of it
+
+
+class _ToyScheduler:
+    """The scheduler's shape as the recorder sees it: ticks of a kind, each
+    with a ``dispatch`` phase that calls a jitted step."""
+
+    def __init__(self, rec, name):
+        self.rec, self.source, self.tick_no = rec, trace.new_source("toy"), 0
+        self.step = _fresh_jit(name)
+
+    def warmup(self, x):
+        with self.rec.span("warmup", source=self.source, marks=trace.WARMS | trace.TOTAL):
+            self.step(x)
+
+    def tick(self, x, kind="decode", admit=None):
+        self.tick_no += 1
+        with self.rec.span("tick", self.tick_no, self.source, trace.UNIT) as tick:
+            if admit is not None:       # before the tick has chosen its work
+                with self.rec.span("admit", self.tick_no, self.source):
+                    admit()
+            tick.kind = kind
+            with self.rec.span("dispatch", self.tick_no, self.source):
+                return self.step(x)
+
+
+def test_a_retrace_in_the_second_tick_is_a_recompile_that_names_it(hearing):
+    import jax.numpy as jnp
+
+    sched = _ToyScheduler(hearing, "toy_decode")
+    same, other, prompt = jnp.ones((4, 8)), jnp.ones((4, 9)), jnp.ones((2, 3))
+    sched.tick(same)            # nobody warmed it: the first tick of its kind compiles
+    assert "recompiles_in_units" not in hearing.counters
+    assert hearing.counters["setup_programs_loaded_tick"] == 1
+    sched.tick(same)
+    assert "recompiles_in_units" not in hearing.counters
+    sched.tick(other)           # another shape: the same function compiles again
+    c = hearing.counters
+    assert c["recompiles_in_units"] == 1
+    assert c["recompile_us"] > 0 and c["setup_programs_loaded_tick"] == 1
+    (r,) = [r for r in hearing.records() if r.name == "recompile"]
+    assert (r.uid, r.parent, r.kind, r.source) == (3, "dispatch", "jit(toy_decode)", sched.source)
+    assert r.path == ("tick", "dispatch")
+    tick = [t for t in hearing.records(sched.source) if t.name == "tick" and t.uid == r.uid]
+    assert tick[0].kind == "decode" and tick[0].start <= r.start <= r.end <= tick[0].end + 1e-3
+    # a tick of another kind has finished no unit yet: its first compile is set-up
+    sched.tick(prompt, kind="prefill")
+    assert c["recompiles_in_units"] == 1 and c["setup_programs_loaded_tick"] == 2
+
+
+def test_the_first_tick_of_a_warmed_scheduler_may_compile_nothing(hearing):
+    import jax.numpy as jnp
+
+    sched = _ToyScheduler(hearing, "toy_warmed")
+    warmed, missed = jnp.ones((4, 8)), jnp.ones((5, 8))
+    sched.warmup(warmed)
+    sched.tick(warmed)          # what warm-up compiled: nothing to record
+    assert "recompiles_in_units" not in hearing.counters
+    sched.tick(missed)          # what warm-up missed, in the first tick of its kind or not
+    assert hearing.counters["recompiles_in_units"] == 1
+    (r,) = [r for r in hearing.records() if r.name == "recompile"]
+    assert (r.uid, r.parent) == (2, "dispatch")
+
+
+def test_a_compile_before_a_tick_has_chosen_its_work_does_not_settle_the_tick(hearing):
+    """The verdict is kept by the unit and its kind: what ``admit`` compiles
+    in a tick of no kind yet says nothing of the ``dispatch`` that follows."""
+    import jax.numpy as jnp
+
+    sched = _ToyScheduler(hearing, "toy_admits")
+    copy = _fresh_jit("toy_admit_copy")
+    same, other, row = jnp.ones((4, 8)), jnp.ones((4, 9)), jnp.ones((3,))
+    sched.tick(same)
+    sched.tick(other, admit=lambda: copy(row))
+    c = hearing.counters
+    # nobody warmed it and no tick of no kind has finished: admit's compile is set-up
+    assert c["setup_programs_loaded_tick"] == 2 and c["recompiles_in_units"] == 1
+    (r,) = [r for r in hearing.records() if r.name == "recompile"]
+    assert (r.uid, r.parent, r.kind) == (2, "dispatch", "jit(toy_admits)")
+
+
+def test_a_unit_under_another_root_is_a_unit_and_a_name_alone_is_none(hearing):
+    """The opener says what a unit is, not the span's name: a tick inside a
+    rollout's span recompiles, a span that is only called ``tick`` does not."""
+    import jax.numpy as jnp
+
+    sched = _ToyScheduler(hearing, "toy_rollout")
+    sched.warmup(jnp.ones((4, 8)))
+    with hearing.span("rollout", 1, "rlhf#0"):
+        sched.tick(jnp.ones((4, 9)))
+    c = hearing.counters
+    assert c["recompiles_in_units"] == 1 and "setup_programs_loaded_rollout" not in c
+    (r,) = [r for r in hearing.records() if r.name == "recompile"]
+    assert (r.path, r.uid, r.source) == (("rollout", "tick", "dispatch"), 1, sched.source)
+    named = _fresh_jit("toy_named_tick")
+    for no, x in enumerate([jnp.ones((2,)), jnp.ones((3,))], start=1):
+        with hearing.span("tick", no, "other#0"):
+            named(x)
+    assert c["recompiles_in_units"] == 1 and c["setup_programs_loaded_tick"] == 2
+
+
+def test_the_intervals_kept_for_nesting_are_bounded(hearing, monkeypatch):
+    monkeypatch.setattr(trace, "_NEST_KEPT", 8)
+    for _ in range(50):     # a process that retraces for ever, none inside another
+        hearing._on_compile_duration("/jax/core/compile/jaxpr_trace_duration", 1e-5, fun_name="f")
+        time.sleep(2e-4)
+    assert len(hearing._local.nest) <= 8
+    assert 450 <= hearing.counters["compile_outside_us"] <= 500     # every one counted, once
+
+
+def test_the_first_step_of_an_engine_is_no_recompile_and_a_later_one_is(hearing):
+    import jax.numpy as jnp
+
+    step = _fresh_jit("toy_train_step")
+    batches = [jnp.ones((2, 8)), jnp.ones((2, 8)), jnp.ones((2, 16))]
+    for no, batch in enumerate(batches, start=1):
+        with hearing.span("train_batch", no, "engine#9", trace.UNIT):
+            with hearing.span("dispatch", no, "engine#9"):
+                step(batch)
+    c = hearing.counters
+    assert c["setup_programs_loaded_train_batch"] == 1 and c["recompiles_in_units"] == 1
+    (r,) = [r for r in hearing.records() if r.name == "recompile"]
+    assert (r.uid, r.parent, r.kind) == (3, "dispatch", "jit(toy_train_step)")
+
+
+def test_a_cache_hit_is_counted_for_the_compile_it_answers(hearing, tmp_path):
+    """Counted from hits: the event precedes its backend compile's duration."""
+    with hearing.span("warmup"):
+        hearing._on_compile_event("/jax/compilation_cache/cache_hits")
+        hearing._on_compile_duration("/jax/compilation_cache/cache_retrieval_time_sec", 0.004)
+        hearing._on_compile_duration("/jax/core/compile/backend_compile_duration", 0.005,
+                                     fun_name="jit(answered)")
+        hearing._on_compile_duration("/jax/core/compile/backend_compile_duration", 0.002,
+                                     fun_name="jit(compiled)")
+        hearing._on_compile_duration("/jax/some/other/event", 1.0)
+    c = hearing.counters
+    assert c["setup_programs_loaded_warmup"] == 2 and c["setup_cache_hits_warmup"] == 1
+    assert c["setup_cache_load_us_warmup"] == 4000
+    assert 6900 <= c["setup_backend_us_warmup"] <= 7100     # the retrieval is inside the first
+    assert [r.name for r in hearing.records()] == ["compile_cache_load", "compile_backend",
+                                                   "compile_backend", "warmup"]
+
+
+def test_listeners_are_registered_once_for_any_number_of_engines_and_schedulers():
+    import jax.numpy as jnp
+    from jax._src import monitoring
+
+    import deepspeed_tpu
+    from deepspeed_tpu.inference.serving import ContinuousBatchingScheduler, ServingConfig
+    from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
+
+    def mine(listeners):
+        return [fn for fn in listeners if getattr(fn, "__module__", None) == trace.__name__]
+
+    model = GPT2LMHeadModel(get_gpt2_config("test"))
+    for _ in range(2):
+        engine = deepspeed_tpu.init_inference(model, dtype=jnp.float32, max_out_tokens=32)
+        ContinuousBatchingScheduler(engine, ServingConfig(slots=2, prefill_chunk=8))
+        deepspeed_tpu.initialize(model=model, config={
+            "train_batch_size": 8, "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}}})
+    assert len(mine(monitoring.get_event_duration_listeners())) == 1
+    assert len(mine(monitoring.get_event_listeners())) == 1
+
+
+def test_a_record_takes_the_open_span_and_keeps_what_it_is_given():
+    rec = trace.Recorder()
+    with rec.span("tick", 5, "sched#0"):
+        with rec.span("admit", 5, "sched#0"):
+            rec.record("queue_wait", 1.0, 2.0, uid=77, source="sched#0")   # a request's own uid
+            rec.record("noted", 1.0, 2.0, kind="why")
+    by_name = {r.name: r for r in rec.records()}
+    assert by_name["queue_wait"].path == ("tick", "admit") and by_name["queue_wait"].uid == 77
+    assert (by_name["noted"].uid, by_name["noted"].source, by_name["noted"].kind) == \
+        (5, "sched#0", "why")
+
+
+def test_a_set_up_span_leaves_its_seconds_in_a_counter_where_it_is_a_root():
+    rec = trace.Recorder(capacity=2)
+    with rec.span("scheduler_init", source="sched#0", marks=trace.TOTAL):
+        with rec.span("warmup", marks=trace.TOTAL):      # not a root here: the span alone
+            time.sleep(0.002)
+    for i in range(4):                      # the ring turns over; the counter stays
+        with rec.span("tick", i):
+            pass
+    assert "scheduler_init" not in [r.name for r in rec.records()]
+    assert rec.counters["setup_span_us_scheduler_init"] >= 2000
+    assert "setup_span_us_warmup" not in rec.counters
+
+
+def test_an_import_inside_another_is_counted_once(hearing):
+    t0 = time.perf_counter()
+    time.sleep(0.003)
+    inner0 = time.perf_counter()
+    time.sleep(0.002)
+    trace.imported("pkg.inner", inner0)
+    trace.imported("pkg", t0)
+    records = [r for r in hearing.records() if r.name == "import"]
+    assert [r.kind for r in records] == ["pkg.inner", "pkg"]
+    assert hearing.counters["setup_import_us"] == pytest.approx(records[1].dur * 1e6, abs=2)
+
+
+def test_the_packages_record_their_own_import():
+    import deepspeed_tpu  # noqa: F401
+    import deepspeed_tpu.inference.serving  # noqa: F401
+
+    kinds = {r.kind for r in trace.recorder().records() if r.name == "import"}
+    # (the ring of a long test process may have turned over: the counter has not)
+    assert trace.recorder().counters["setup_import_us"] > 0
+    assert not kinds or kinds & {"deepspeed_tpu", "deepspeed_tpu.inference.serving"}
+
+
+def test_a_lazy_export_of_a_module_already_imported_writes_no_record(hearing):
+    import deepspeed_tpu
+    import deepspeed_tpu.runtime.config  # noqa: F401
+
+    deepspeed_tpu.__dict__.pop("DeepSpeedConfig", None)     # as before its first use
+    assert deepspeed_tpu.DeepSpeedConfig is deepspeed_tpu.runtime.config.DeepSpeedConfig
+    assert not [r for r in hearing.records() if r.name == "import"]
+    assert "setup_import_us" not in hearing.counters

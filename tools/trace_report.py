@@ -7,7 +7,12 @@ Two modes over the run log ``runtime/telemetry`` writes:
   ``{"traceEvents": [...]}`` of complete ``"ph": "X"`` events). Span
   nesting falls out of timestamp containment on one tid; ``step_window``
   aggregates ride along as counter (``"ph": "C"``) series so achieved
-  step time is visible next to the phases.
+  step time is visible next to the phases. The spans of the start
+  (``initialize``, ``scheduler_init``, ``warmup``) and JAX's compile
+  events, filed by the recorder under the span that caused them, are spans
+  like the rest; every ``recompile`` of the run, a compile under a tick or
+  a step after the first of its kind, is also listed on stderr with its
+  tick or step, the phase and the function.
 * ``--drift`` — summarize the predicted-vs-measured loop: the run
   header's static price (flops_proxy, liveness peak/transient bytes)
   against each window's measured median step time and memory peaks,
@@ -64,12 +69,14 @@ def chrome_trace(events) -> dict:
                           "args": {"name": "step spans"}})
         elif kind == "spans":
             for s in rec.get("spans", ()):
+                args = {"path": s.get("path", ""), "depth": s.get("depth", 0)}
+                if "kind" in s:     # a tick's kind; a compile record's function
+                    args["kind"] = s["kind"]
                 trace.append({"name": s.get("name", "?"), "ph": "X", "pid": pid,
                               "tid": 1,
                               "ts": float(s.get("ts", 0.0)) * 1e6,
                               "dur": float(s.get("dur_s", 0.0)) * 1e6,
-                              "args": {"path": s.get("path", ""),
-                                       "depth": s.get("depth", 0)}})
+                              "args": args})
         elif kind == "step_window":
             step_phase = (rec.get("phases") or {}).get("step") or {}
             p50 = step_phase.get("p50")
@@ -84,6 +91,17 @@ def chrome_trace(events) -> dict:
                                    if k not in ("event", "t")}})
     return {"traceEvents": trace, "displayTimeUnit": "ms",
             "otherData": {"run": run}}
+
+
+def recompiles(events) -> list:
+    """The run's ``recompile`` records, oldest first: the unit (tick or
+    step) each fell in and its kind, the phase, the function, the seconds."""
+    spans = [s for rec in events if rec.get("event") == "spans" for s in rec.get("spans", ())]
+    kinds = {s.get("uid"): s.get("kind") for s in spans if not s.get("path")}
+    return [{"unit": s.get("uid"), "kind": kinds.get(s.get("uid")),
+             "phase": (s.get("path") or "").rsplit("/", 1)[-1] or None,
+             "function": s.get("kind"), "dur_s": s.get("dur_s")}
+            for s in spans if s.get("name") == "recompile"]
 
 
 def drift_report(events) -> dict:
@@ -175,6 +193,10 @@ def main(argv=None) -> int:
             print(f"drift sidecar: {out} ({len(report['windows'])} windows)")
         return 0
 
+    for r in recompiles(events):
+        print(f"trace_report: recompile in unit {r['unit']} ({r['kind'] or 'step'}), phase "
+              f"{r['phase']}: {r['function']} took {(r['dur_s'] or 0.0) * 1e3:.1f} ms",
+              file=sys.stderr)
     trace = chrome_trace(events)
     if not trace["traceEvents"]:
         print(f"trace_report: no span events in {jsonl} (telemetry.span_events "
