@@ -584,11 +584,11 @@ def serve_decode_step() -> ProgramInfo:
         decode = build_decode_step(make_apply_fn(engine.module, engine._mparams),
                                    do_sample=False, temperature=1.0, top_k=0,
                                    top_p=1.0)
-        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(engine.params, cache, write_pos, tokens)
+        write_pos = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(engine.params, cache, write_pos)
         return ProgramInfo(
             name="serve_decode_step", jaxpr=jaxpr, kind="serve_decode",
-            lower=lambda: jax.jit(decode).lower(engine.params, cache, write_pos, tokens),
+            lower=lambda: jax.jit(decode).lower(engine.params, cache, write_pos),
             metadata={
                 "serve_slots": slots,
                 "activation_budget_bytes": int(SERVE_DECODE_BUDGET_MB * 2**20),
@@ -660,11 +660,11 @@ def serve_quant_decode_step() -> ProgramInfo:
         decode = build_decode_step(make_apply_fn(module, engine._mparams),
                                    do_sample=False, temperature=1.0, top_k=0,
                                    top_p=1.0)
-        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(params, cache, write_pos, tokens)
+        write_pos = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(params, cache, write_pos)
         return ProgramInfo(
             name="serve_quant_decode_step", jaxpr=jaxpr, kind="serve_decode",
-            lower=lambda: jax.jit(decode).lower(params, cache, write_pos, tokens),
+            lower=lambda: jax.jit(decode).lower(params, cache, write_pos),
             metadata={
                 "serve_slots": slots,
                 "serve_weight_dtype": "int8",  # what the baseline banks
@@ -720,11 +720,11 @@ def serve_prefix_decode_step() -> ProgramInfo:
         decode = build_decode_step(make_apply_fn(engine.module, engine._mparams),
                                    do_sample=False, temperature=1.0, top_k=0,
                                    top_p=1.0)
-        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(engine.params, cache, write_pos, tokens)
+        write_pos = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(engine.params, cache, write_pos)
         return ProgramInfo(
             name="serve_prefix_decode_step", jaxpr=jaxpr, kind="serve_decode",
-            lower=lambda: jax.jit(decode).lower(engine.params, cache, write_pos, tokens),
+            lower=lambda: jax.jit(decode).lower(engine.params, cache, write_pos),
             metadata={
                 "serve_slots": slots,
                 "serve_prefix_cache": "on",
@@ -805,11 +805,11 @@ def rlhf_rollout_step() -> ProgramInfo:
         decode = build_decode_step(make_apply_fn(infer.module, infer._mparams),
                                    do_sample=False, temperature=1.0, top_k=0,
                                    top_p=1.0)
-        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(infer.params, cache, write_pos, tokens)
+        write_pos = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(infer.params, cache, write_pos)
         return ProgramInfo(
             name="rlhf_rollout_step", jaxpr=jaxpr, kind="serve_decode",
-            lower=lambda: jax.jit(decode).lower(infer.params, cache, write_pos, tokens),
+            lower=lambda: jax.jit(decode).lower(infer.params, cache, write_pos),
             metadata={
                 "serve_slots": slots,
                 "rlhf_weight_sync_plan": sync_plan,

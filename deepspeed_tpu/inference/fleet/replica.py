@@ -110,7 +110,7 @@ class LocalReplica:
             return
         s = self.scheduler
         for _ in range(max_ticks):
-            if not (s.in_flight or len(s.queue)):
+            if not s.busy:
                 break
             s.step()
         self._drain_finished()

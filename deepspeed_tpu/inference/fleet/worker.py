@@ -215,7 +215,7 @@ def main() -> int:
             elif kind == "stop":
                 stopping = True
 
-        if sched.in_flight or len(sched.queue):
+        if sched.busy:
             sched.step()
             if tick_sleep:
                 time.sleep(tick_sleep)

@@ -52,6 +52,10 @@ from deepspeed_tpu.models.common import (COUNTER_LEAVES, INDEX_LEAVES, KV_LEAVES
 from deepspeed_tpu.utils import trace
 
 
+#: the cache's one leaf that is no model's: the token each slot is fed next
+TOKEN_LEAF = "next_token"
+
+
 def _leaf_name(path) -> str:
     last = path[-1]
     return getattr(last, "key", None) or str(last)
@@ -83,7 +87,8 @@ def make_slot_cache(module, slots: int, kv_quant: bool = False):
 
     A recurrent layer's state (``STATE_LEAVES``) stays ``[slots, ...]`` as
     the model shapes it, zeroed and never quantised; the ``LENGTH_LEAVES``
-    beside the index leaves are [slots] vectors too, 0 for a parked slot."""
+    beside the index leaves are [slots] vectors too, 0 for a parked slot.
+    :data:`TOKEN_LEAF` lies at the top level, zeros."""
     from deepspeed_tpu.models.common import init_cache
 
     def stored(cache):
@@ -99,9 +104,10 @@ def make_slot_cache(module, slots: int, kv_quant: bool = False):
     # lockstep pools, nor fp pools beside their int8 form
     shapes = jax.eval_shape(lambda: stored(init_cache(module, slots)))
     parked = slot_capacity(shapes)
-    return jax.tree_util.tree_map_with_path(
+    cache = jax.tree_util.tree_map_with_path(
         lambda path, leaf: jnp.full(leaf.shape, parked if _is_index_leaf(path) else 0,
                                     leaf.dtype), shapes)
+    return with_next_tokens(cache, jnp.zeros((slots,), jnp.int32))
 
 
 def quantize_slot_cache(cache):
@@ -162,6 +168,16 @@ def with_write_positions(cache, write_pos, fed=1):
         return write_pos if _is_index_leaf(path) else leaf
 
     return jax.tree_util.tree_map_with_path(sub, cache)
+
+
+def without_next_tokens(cache):
+    """``(the cache as the model knows it, next_token [slots])``."""
+    return {name: leaf for name, leaf in cache.items() if name != TOKEN_LEAF}, cache[TOKEN_LEAF]
+
+
+def with_next_tokens(cache, tokens):
+    """``cache`` (the model's) with :data:`TOKEN_LEAF` laid beside it."""
+    return {**cache, TOKEN_LEAF: tokens}
 
 
 def prefill_rungs(slots: int, mesh_size: int = 1, cache=None) -> tuple:
@@ -250,6 +266,16 @@ def state_bytes_per_slot(cache) -> int:
                for leaf in _leaves_named(cache, STATE_LEAVES))
 
 
+def _tokens_of_fed(held, tok, fed, slot_ids=None):
+    """Traced: :data:`TOKEN_LEAF` after a program sampled ``tok``, one a row
+    it ran (``slot_ids`` where it ran a rung): the token of each row it
+    ``fed``; a row parked at the sentinel leaves its slot's token as it was
+    (an active slot sits out a prefill tick so)."""
+    if slot_ids is None:
+        return jnp.where(fed, tok, held)
+    return held.at[slot_ids].set(jnp.where(fed, tok, held[slot_ids]))
+
+
 # ---------------------------------------------------------------------------
 # step builders: apply_fn(params, cache, ids) -> (logits [S, L, V], cache').
 # Every built step takes ``write_pos [slots] int32`` right after the cache.
@@ -291,7 +317,9 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
     has no positions to overwrite, is handed ``last_idx + 1`` as the slot's
     real length and does not advance past it). The chunk that
     completes a prompt samples the request's FIRST token from its
-    last-real-position logits, so TTFT stops at prefill completion.
+    last-real-position logits, so TTFT stops at prefill completion. The token
+    sampled for each fed row is also left in the cache's :data:`TOKEN_LEAF`,
+    where the slot's first decode tick finds it.
 
     ``rung=True`` builds the program over fewer sequences than slots: it
     takes ``slot_ids [n] int32`` before ``write_pos`` (distinct slots; the
@@ -304,6 +332,7 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
     from deepspeed_tpu.inference.engine import sample_logits
 
     def prefill(params, cache, write_pos, ids, last_idx, *rng, slot_ids=None):
+        cache, held = without_next_tokens(cache)
         fed = with_write_positions(cache, write_pos, last_idx + 1)
         if slot_ids is None:
             logits, cache = apply_fn(params, fed, ids)
@@ -315,7 +344,9 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
             tok = sample_logits(logits, *rng, True, temperature, top_k, top_p)
         else:
             tok = jnp.argmax(logits, axis=-1)
-        return cache, with_counters(cache, tok.astype(jnp.int32))
+        tok = tok.astype(jnp.int32)
+        held = _tokens_of_fed(held, tok, write_pos < slot_capacity(cache), slot_ids)
+        return with_next_tokens(cache, held), with_counters(cache, tok)
 
     if not rung:
         return prefill
@@ -328,24 +359,31 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
 
 def build_decode_step(apply_fn, do_sample: bool, temperature: float,
                       top_k: int, top_p: float) -> Callable:
-    """One decode tick: feed each slot's token at its write position,
-    sample the next. Greedy builds a no-rng program (``decode(params,
-    cache, write_pos, tokens)``); sampling adds an rng operand."""
+    """One decode tick: feed each slot the token the cache holds for it
+    (:data:`TOKEN_LEAF`; a parked slot is fed 0) at its write position, sample
+    the next and leave it there. Greedy builds a no-rng program
+    (``decode(params, cache, write_pos)``); sampling adds an rng operand.
+    ``tokens [slots] int32``, by keyword, is fed in the leaf's place: a draft
+    loop's first token is the host's, the last the target accepted."""
     from deepspeed_tpu.inference.engine import sample_logits
 
-    if do_sample:
-        def decode(params, cache, write_pos, tokens, rng):
-            logits, cache = apply_fn(params, with_write_positions(cache, write_pos),
-                                     tokens[:, None])
-            tok = sample_logits(logits[:, -1], rng, True, temperature,
-                                top_k, top_p).astype(jnp.int32)
-            return cache, with_counters(cache, tok)
-    else:
-        def decode(params, cache, write_pos, tokens):
-            logits, cache = apply_fn(params, with_write_positions(cache, write_pos),
-                                     tokens[:, None])
-            return cache, with_counters(cache,
-                                        jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32))
+    def decode(params, cache, write_pos, *rng, tokens=None):
+        if len(rng) != int(do_sample):
+            raise TypeError(f"decode takes {int(do_sample)} rng operand(s) behind write_pos, "
+                            f"got {len(rng)}: a slot's token is the cache's, or ``tokens=``")
+        cache, held = without_next_tokens(cache)
+        live = write_pos < slot_capacity(cache)
+        if tokens is None:
+            tokens = jnp.where(live, held, 0)
+        logits, cache = apply_fn(params, with_write_positions(cache, write_pos),
+                                 tokens[:, None])
+        if do_sample:
+            tok = sample_logits(logits[:, -1], *rng, True, temperature, top_k, top_p)
+        else:
+            tok = jnp.argmax(logits[:, -1], axis=-1)
+        tok = tok.astype(jnp.int32)
+        return (with_next_tokens(cache, _tokens_of_fed(held, tok, live)),
+                with_counters(cache, tok))
 
     return decode
 
@@ -358,9 +396,11 @@ def build_verify_step(apply_fn) -> Callable:
     first divergence (lossless under greedy decoding by construction)."""
 
     def verify(params, cache, write_pos, tokens):
+        cache, held = without_next_tokens(cache)
         logits, cache = apply_fn(params, with_write_positions(cache, write_pos,
                                                               tokens.shape[1]), tokens)
-        return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [S, K+1]
+        return (with_next_tokens(cache, held),
+                jnp.argmax(logits, axis=-1).astype(jnp.int32))  # [S, K+1]
 
     return verify
 

@@ -41,10 +41,11 @@ import jax
 
 from deepspeed_tpu.inference.serving.blocks import BlockPool
 from deepspeed_tpu.inference.serving.config import ServingConfig
-from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, _leaf_name, counter_widths,
-                                                      has_recurrent_state, make_slot_cache,
-                                                      prefill_rungs, serve_programs,
-                                                      slot_capacity, state_bytes_per_slot)
+from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, TOKEN_LEAF, _leaf_name,
+                                                      counter_widths, has_recurrent_state,
+                                                      make_slot_cache, prefill_rungs,
+                                                      serve_programs, slot_capacity,
+                                                      state_bytes_per_slot)
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
@@ -94,14 +95,16 @@ def _quant_view(module, params, weight_dtype: str, group_size: int):
     return q_module, {"params": qparams, "quant": qscales}
 
 
-def _restore_rows_jit_impl(flat_cache, rows, slot, kv_idx):
+def _restore_rows_jit_impl(flat_cache, rows, slot, token, kv_idx, token_idx):
     out = list(flat_cache)
     for j, i in enumerate(kv_idx):
         out[i] = slot_pool_set_rows(out[i], slot, rows[j])
+    out[token_idx] = out[token_idx].at[slot].set(token)
     return out
 
 
-#: One program writes every KV leaf's restored rows into a slot, with the
+#: One program writes every KV leaf's restored rows into a slot, and the
+#: token the slot is fed next, with the
 #: cache DONATED so XLA updates the pool buffers in place. ``slot`` rides
 #: as a traced scalar (no per-slot recompile); the row length keys the
 #: jit cache through the row shapes. Restores happen per prefix-cache
@@ -109,7 +112,19 @@ def _restore_rows_jit_impl(flat_cache, rows, slot, kv_idx):
 #: copying the entire pool — is a serving-throughput bug, not a style
 #: choice.
 _restore_rows_jit = jax.jit(_restore_rows_jit_impl,
-                            static_argnums=(3,), donate_argnums=(0,))
+                            static_argnums=(4, 5), donate_argnums=(0,))
+
+
+class _Program:
+    """One dispatched program until its tokens are read: ``rows`` are the
+    requests it serves, each ``(slot, request, row of ``tok``, prompt tokens
+    fed, whether the row's token is the request's next)``; ``ending`` the
+    ``(slot, request)`` whose last token, by count, this program samples."""
+
+    __slots__ = ("kind", "tok", "rows", "ending")
+
+    def __init__(self, kind: str, tok, rows: list, ending: list):
+        self.kind, self.tok, self.rows, self.ending = kind, tok, rows, ending
 
 
 class ContinuousBatchingScheduler:
@@ -150,6 +165,13 @@ class ContinuousBatchingScheduler:
         # inputs ride its program's own dispatch, so it reads 0 without
         # speculation (shown as 0, not left out)
         self._rec.count("tick_input_puts", 0)
+        # programs dispatched, those of them dispatched while another was in
+        # flight, read-backs forced before their time (also by reason:
+        # ``ticks_settled_<spec|prefix|export|swap|drain>``), and rows run for
+        # a request that had already ended on its EOS
+        for name in ("ticks_dispatched", "ticks_dispatched_ahead", "ticks_settled",
+                     "slot_ticks_discarded"):
+            self._rec.count(name, 0)
         # an expert model says what its expert matmuls owe and are given
         # (``moe_rows``); a dense model has no such counters
         self._moe_rows = getattr(engine.module, "moe_rows", None)
@@ -279,10 +301,19 @@ class ContinuousBatchingScheduler:
         self._rungs = min((prefill_rungs(self.slots, engine.mesh.size, cache)
                            for cache in caches), key=len)
 
-        # host-side authoritative slot state
+        # host-side authoritative slot state, as of what has been DISPATCHED:
+        # who holds a slot, the cache positions written, the prompt tokens fed
+        # and the output tokens whose sampling is under way or done
         self._slot_req: List[Optional[Request]] = [None] * self.slots
         self._lengths = np.full(self.slots, self.capacity, np.int64)  # parked sentinel
-        self._next_token = np.zeros(self.slots, np.int32)
+        self._fed = np.zeros(self.slots, np.int64)
+        self._sent = np.zeros(self.slots, np.int64)
+        # the program whose tokens have not been read, and why this scheduler
+        # reads every program in the step that dispatched it, if it must: a
+        # drafter's accept loop reads tokens, a prefix publish copies pool rows
+        self._inflight: Optional[_Program] = None
+        self._serial = ("spec" if self._drafter is not None
+                        else "prefix" if self.prefix_cache == "on" else None)
         self._decode_ticks_since_prefill = 10**9  # first prefill never waits
         self._rng = jax.random.PRNGKey(seed)
 
@@ -325,13 +356,14 @@ class ContinuousBatchingScheduler:
         tokens = np.zeros(self.slots, np.int32)
         rng = (jax.random.PRNGKey(0),) if self.config.do_sample else ()
         try:
-            jax.eval_shape(self.fns["decode"], self._serve_params, self._cache,
-                           tokens, tokens, *rng)
+            jax.eval_shape(self.fns["decode"], self._serve_params, self._cache, tokens, *rng)
         except Exception:
-            from deepspeed_tpu.inference.serving.programs import make_apply_fn
+            from deepspeed_tpu.inference.serving.programs import (make_apply_fn,
+                                                                  without_next_tokens)
             step, ids = make_apply_fn(self.module), tokens[:, None]
             try:
-                jax.eval_shape(lambda p, c: step(p, c, ids), self._serve_params, self._cache)
+                jax.eval_shape(lambda p, c: step(p, without_next_tokens(c)[0], ids),
+                               self._serve_params, self._cache)
             except Exception as e:
                 raise NotImplementedError(
                     f"{type(self.module).__name__} does not support the per-slot "
@@ -451,7 +483,6 @@ class ContinuousBatchingScheduler:
         # given is part of the key it caches its program under
         ids = np.zeros((self.slots, self.config.prefill_chunk), np.int32)
         last_idx = np.zeros(self.slots, np.int32)
-        tok = np.zeros(self.slots, np.int32)
         block = np.zeros((self.slots, self.spec_k + 1), np.int32)
         # a prefill rung's operands are the first ``n`` of each, behind the
         # slots it runs: a program a rung, all behind one jitted function
@@ -462,23 +493,24 @@ class ContinuousBatchingScheduler:
         target_calls = ([("prefill", (parked, ids, last_idx) + rng)]
                         + [(name, args + rng) for name, args in rungs]
                         + ([("verify", (parked, block))] if self.spec_k
-                           else [("decode", (parked, tok) + rng)]))
+                           else [("decode", (parked,) + rng)]))
         per_role = [("", self.fns, "_cache", self._serve_params, target_calls)]
         if self._drafter is not None:
             # the draft loop feeds decode a mesh-committed token (see
             # _spec_tick); every other tick input arrives as a host array
-            dtok = jax.device_put(tok, self._placement)  # graft-lint: waive R008 warmup operand placement parity w/ the draft loop, never donated
+            dtok = jax.device_put(np.zeros(self.slots, np.int32), self._placement)  # graft-lint: waive R008 warmup operand placement parity w/ the draft loop, never donated
             per_role.append(("drafter_", self.dfns, "_drafter_cache", self._drafter[1],
                              [("prefill", (parked, ids, last_idx) + rng)]
                              + [(name, args + rng) for name, args in rungs] +
-                             [("decode", (parked, dtok) + rng),
+                             [("decode", (parked,) + rng, {"tokens": dtok}),
                               ("verify", (parked, block))]))
         for role, fns, cache_attr, params, calls in per_role:
-            for name, args in calls:
+            for name, args, *by_name in calls:
                 if name in fns:
                     with self._phase("program") as program:
                         program.kind = role + name
-                        cache, _ = fns[name](params, getattr(self, cache_attr), *args)
+                        cache, _ = fns[name](params, getattr(self, cache_attr), *args,
+                                             **(by_name[0] if by_name else {}))
                     setattr(self, cache_attr, cache)
 
     # ------------------------------------------------------------------
@@ -489,7 +521,16 @@ class ContinuousBatchingScheduler:
 
     @property
     def in_flight(self) -> List[Request]:
+        """The requests that hold a slot. One whose last token is on its way
+        has handed its slot on and is not ``finished`` yet: see :attr:`busy`."""
         return [r for r in self._slot_req if r is not None]
+
+    @property
+    def busy(self) -> bool:
+        """Whether a step has anything to do: a request waits or holds a
+        slot, or a program's tokens are still to be read. What a loop around
+        :meth:`step` asks, so that it reads the last program too."""
+        return self._inflight is not None or bool(self.in_flight) or len(self.queue) > 0
 
     def _free_slots(self) -> List[int]:
         return [i for i, r in enumerate(self._slot_req) if r is None]
@@ -513,7 +554,8 @@ class ContinuousBatchingScheduler:
             if match is not None and match.cached_tokens:
                 self._restore_prefix(slot, match)
                 cached = match.cached_tokens
-            self._lengths[slot] = cached
+            self._lengths[slot] = self._fed[slot] = cached
+            self._sent[slot] = 0
             req.state = PREFILL
             req.prefill_pos = cached
             req.cached_prefix_tokens = cached
@@ -544,6 +586,7 @@ class ContinuousBatchingScheduler:
         payload is read, never written). Payload rows restore through the
         migration writer (``slot_pool_set_rows``) so the buffers stay
         XLA-owned on the existing placement."""
+        self.settle("prefix")
         roles = [("target", "_cache")]
         if self._drafter is not None:
             roles.append(("drafter", "_drafter_cache"))
@@ -612,37 +655,50 @@ class ContinuousBatchingScheduler:
     # tick
     # ------------------------------------------------------------------
     def step(self, admit: bool = True) -> str:
-        """One scheduler tick; returns the tick kind it ran
-        (``prefill`` | ``decode`` | ``spec`` | ``idle``)."""
-        step_no = sum(self.ticks.values()) + 1
+        """One scheduler tick: admit, build and dispatch the next program,
+        then read back and commit the one that was in flight. Returns the
+        kind of the program it finished (``prefill`` | ``decode`` | ``spec``);
+        of the one it dispatched where none was in flight; ``idle`` where
+        there was neither."""
+        self._tick_no = step_no = self._tick_no + 1
         if self.telemetry is not None:
             self.telemetry.begin_step(step_no)
-        self._tick_no = step_no
         with self._rec.span("tick", step_no, self._source, trace.UNIT) as tick:
             with self._phase("admit"):
                 if admit:
                     self._admit()
-            prefilling = [i for i, r in enumerate(self._slot_req)
-                          if r is not None and r.state == PREFILL]
-            active = [i for i, r in enumerate(self._slot_req)
-                      if r is not None and r.state == ACTIVE]
+            held = [i for i, r in enumerate(self._slot_req) if r is not None]
+            prefilling = [i for i in held if self._fed[i] < self._slot_req[i].prompt_len]
+            active = [i for i in held if self._fed[i] >= self._slot_req[i].prompt_len]
+            tick.kind = kind = "idle"
+            program = None
             if prefilling and (not active or self._decode_ticks_since_prefill
                                >= self.config.prefill_interleave):
                 tick.kind = kind = "prefill"     # before the work: a compile inside names it
-                self._prefill_tick(prefilling)
+                program = self._prefill_tick(prefilling)
                 self._decode_ticks_since_prefill = 0
             elif active:
                 tick.kind = kind = "spec" if self.spec_k else "decode"
                 if self.spec_k:
                     self._spec_tick(active)
                 else:
-                    self._decode_tick(active)
+                    program = self._decode_tick(active)
                 self._decode_ticks_since_prefill += 1
-            else:
-                tick.kind = kind = "idle"
+            before, self._inflight = self._inflight, program
+            if kind != "idle" or before is None:    # a program, or a step with nothing to do
+                self.ticks[kind] += 1
+            if before is not None:
+                # the tick that ends here is the program's that is read here
+                tick.kind = kind = before.kind
+                self._commit(before)
+            if program is not None:
+                if self._serial:
+                    self.settle(self._serial)
+                # the slots of the requests whose last token is on its way
+                for slot, req in program.ending:
+                    self._release(slot, req)
             if kind != "idle" and self._serve_t0 is None:
                 self._serve_t0 = self.clock()
-            self.ticks[kind] += 1
             with self._phase("heartbeat"):
                 self._touch_serving_heartbeat(step_no)
         if self.telemetry is not None:
@@ -655,6 +711,20 @@ class ContinuousBatchingScheduler:
                 self.telemetry.emit("serve_tick", flush=False,
                                     tick=step_no, kind=kind, **self.signals())
         return kind
+
+    def settle(self, reason: str = "drain") -> Optional[str]:
+        """Read back and commit the program in flight, if there is one,
+        before its time (counted, by ``reason``); returns its kind. For
+        whoever reads or replaces what a tick reads (the cache, the served
+        weights, a request straight after a step) and for the end of a loop:
+        after it the requests' fields and the scheduler's agree."""
+        program, self._inflight = self._inflight, None
+        if program is None:
+            return None
+        self._rec.count("ticks_settled")
+        self._rec.count(f"ticks_settled_{reason}")
+        self._commit(program)
+        return program.kind
 
     # ------------------------------------------------------------------
     # load signals (graft-fleet: the router/autoscaler currency)
@@ -720,7 +790,7 @@ class ContinuousBatchingScheduler:
                 args = (write_pos, jax.numpy.zeros((self.slots, self.spec_k + 1),
                                                    jax.numpy.int32))
             else:
-                args = (write_pos, write_pos)
+                args = (write_pos,)
                 if self.config.do_sample:
                     args += (jax.random.PRNGKey(0),)
             closed = jax.make_jaxpr(self.fns[name])(
@@ -752,7 +822,11 @@ class ContinuousBatchingScheduler:
         sync evidence. Under a quantized weight view (``weight_dtype !=
         "fp"``) the fp params are re-encoded through ``_quant_view`` and
         digest verification is refused (the re-encode is lossy by
-        design — the caller must not expect fp-bit identity)."""
+        design — the caller must not expect fp-bit identity).
+
+        The program in flight, if any, is read first: it was dispatched
+        under the weights it is accounted to."""
+        self.settle("swap")
         if self.weight_dtype != "fp":
             if expected_digest is not None:
                 raise ValueError(
@@ -848,7 +922,7 @@ class ContinuousBatchingScheduler:
         others = [i for i in range(self.slots) if i not in fed]
         return np.asarray(list(slots) + others[:n - len(slots)], np.int32)
 
-    def _prefill_tick(self, slots: List[int]) -> None:
+    def _prefill_tick(self, slots: List[int]) -> _Program:
         C = self.config.prefill_chunk
         with self._phase("build_inputs"):
             rows = self._rung_rows(slots)
@@ -860,7 +934,7 @@ class ContinuousBatchingScheduler:
             rems: Dict[int, int] = {}
             for i in slots:
                 req, j = self._slot_req[i], row_of[i]
-                chunk = req.prompt[req.prefill_pos:req.prefill_pos + C]
+                chunk = req.prompt[self._fed[i]:self._fed[i] + C]
                 rems[i] = rem = len(chunk)
                 ids[j, :rem] = chunk
                 last_idx[j] = rem - 1
@@ -890,61 +964,82 @@ class ContinuousBatchingScheduler:
             if self._drafter is not None:  # speculation is greedy: no rng operand
                 self._drafter_cache, _ = self.dfns[name](
                     self._drafter[1], self._drafter_cache, *inputs)
-        with self._phase("device_wait"):
-            tok = self._read_back(tok, "prefill")
-        with self._phase("commit"):
-            now = self.clock()
-            for i in slots:
-                req, rem = self._slot_req[i], rems[i]
-                req.prefill_pos += rem
-                self._lengths[i] += rem
-                self.pool.advance(req.request_id, rem)
-                if req.prefill_pos >= req.prompt_len:
-                    # prompt complete: the chunk's last-position logits sampled
-                    # the FIRST new token — TTFT stops here. The committed
-                    # prompt's full blocks enter the hash index now, so the
-                    # next same-prefix request skips their prefill entirely
-                    self._publish_prefix(i, req)
-                    req.state = ACTIVE
-                    req.record_token(int(tok[row_of[i]]), now)
-                    if req.admit_time is not None:   # None: migrated in
-                        self._rec.record("prefill_wait", req.admit_time, now,
-                                         req.request_id, self._source)
-                    self._next_token[i] = tok[row_of[i]]
-                    self._maybe_finish(i, now)
+            # the prompt that completes here samples the request's first token
+            return self._dispatched("prefill", tok, [
+                (i, self._slot_req[i], row_of[i], rems[i],
+                 self._fed[i] + rems[i] >= self._slot_req[i].prompt_len) for i in slots])
 
     # -- plain decode --------------------------------------------------
-    def _decode_tick(self, slots: List[int]) -> None:
+    def _decode_tick(self, slots: List[int]) -> _Program:
         with self._phase("build_inputs"):
             write_pos = np.full(self.slots, self.capacity, np.int64)
-            tokens = np.zeros(self.slots, np.int32)
-            for i in slots:
-                write_pos[i] = self._lengths[i]
-                tokens[i] = self._next_token[i]
+            write_pos[slots] = self._lengths[slots]
         self._rec.count("decode_slots_fed", len(slots))
         self._rec.count("decode_slots_computed", self.slots)
         self._count_moe_rows(len(slots), self.slots)
         self._count_kv_write(write_pos, 1)
         self._count_state(write_pos)
         with self._phase("stamp"):
-            inputs = (write_pos.astype(np.int32), tokens)
+            inputs = (write_pos.astype(np.int32),)
         with self._phase("dispatch"):
             if self.config.do_sample:
                 self._rng, key = jax.random.split(self._rng)
                 inputs += (key,)
+            # each slot's token is the cache's own (``programs.TOKEN_LEAF``)
             self._cache, tok = self.fns["decode"](self._serve_params, self._cache,
                                                   *inputs)
+            return self._dispatched("decode", tok,
+                                    [(i, self._slot_req[i], i, 0, True) for i in slots])
+
+    def _dispatched(self, kind: str, tok, rows: list) -> _Program:
+        """What the host knows of a program the moment it is dispatched,
+        by counting: a row advances its slot by the prompt tokens it fed (a
+        decode row by the one token it fed) and, where it samples the
+        request's next token, by one token sent; a request whose count of
+        tokens is then full ends with this program."""
+        self._rec.count("ticks_dispatched")
+        if self._inflight is not None:
+            self._rec.count("ticks_dispatched_ahead")
+        ending = []
+        for slot, req, _, fed, samples in rows:
+            wrote = fed if kind == "prefill" else 1
+            self._fed[slot] += fed
+            self._lengths[slot] += wrote
+            self.pool.advance(req.request_id, wrote)
+            if samples:
+                self._sent[slot] += 1
+                if self._sent[slot] >= req.max_new_tokens:
+                    ending.append((slot, req))
+        return _Program(kind, tok, rows, ending)
+
+    def _commit(self, program: _Program) -> None:
+        """The blocking read-back of a program's tokens, and what they tell:
+        each request's progress as it is now known, its token stamped at the
+        read-back's return, whether it ended."""
         with self._phase("device_wait"):
-            tok = self._read_back(tok, "decode")
+            tok = self._read_back(program.tok, program.kind)
         with self._phase("commit"):
             now = self.clock()
-            for i in slots:
-                req = self._slot_req[i]
-                self._lengths[i] += 1  # the fed token's KV is now committed
-                self.pool.advance(req.request_id, 1)
-                req.record_token(int(tok[i]), now)
-                self._next_token[i] = tok[i]
-                self._maybe_finish(i, now)
+            for slot, req, row, fed, samples in program.rows:
+                if req.state == FINISHED:
+                    # it ended on its EOS a program ago: this row ran for nothing
+                    self._rec.count("slot_ticks_discarded")
+                    continue
+                req.prefill_pos += fed
+                if not samples:
+                    continue
+                if req.state == PREFILL:
+                    # prompt complete: the chunk's last-position logits sampled
+                    # the FIRST new token — TTFT stops here. The committed
+                    # prompt's full blocks enter the hash index now, so the
+                    # next same-prefix request skips their prefill entirely
+                    self._publish_prefix(slot, req)
+                    req.state = ACTIVE
+                    if req.admit_time is not None:   # None: migrated in
+                        self._rec.record("prefill_wait", req.admit_time, now,
+                                         req.request_id, self._source)
+                req.record_token(int(tok[row]), now)
+                self._maybe_finish(slot, req, now)
 
     # -- speculative decode --------------------------------------------
     def _spec_tick(self, slots: List[int]) -> None:
@@ -958,9 +1053,13 @@ class ContinuousBatchingScheduler:
             write_pos = np.full(self.slots, self.capacity, np.int64)
             for i in slots:
                 write_pos[i] = self._lengths[i]
-            first = np.asarray([self._next_token[i] if self._slot_req[i] is not None
-                                and self._slot_req[i].state == ACTIVE else 0
-                                for i in range(self.slots)], np.int32)
+            # a slot's next token is the last its request was given
+            first = np.zeros(self.slots, np.int32)
+            for i in slots:
+                first[i] = self._slot_req[i].output[-1]
+        # a round is dispatched and read here, whole: the accept loop needs it
+        for name in ("ticks_dispatched", "ticks_settled", "ticks_settled_spec"):
+            self._rec.count(name)
         self._rec.count("decode_slots_fed", len(slots))
         self._rec.count("decode_slots_computed", self.slots)
         self._count_moe_rows(len(slots) * (k + 1), self.slots * (k + 1))  # the verify pass
@@ -976,14 +1075,14 @@ class ContinuousBatchingScheduler:
         for j in range(k):
             with self._phase("dispatch"):
                 self._drafter_cache, cur = self.dfns["decode"](
-                    d_params, self._drafter_cache, write_pos + j, cur)
+                    d_params, self._drafter_cache, write_pos + j, tokens=cur)
             drafts.append(cur)
         with self._phase("device_wait"):
             drafts = np.stack([np.asarray(d) for d in drafts], axis=1)  # [S, k]
         with self._phase("build_inputs"):
             block = np.zeros((self.slots, k + 1), np.int32)
+            block[:, 0] = first
             for i in slots:
-                block[i, 0] = self._next_token[i]
                 block[i, 1:] = drafts[i]
         with self._phase("dispatch"):
             self._cache, greedy = self.fns["verify"](self._serve_params, self._cache,
@@ -1015,9 +1114,9 @@ class ContinuousBatchingScheduler:
                     req.record_token(int(t), now)
                 # committed KV: the fed block prefix [last, d_1..d_{m-1}]
                 self._lengths[i] += len(emitted)
+                self._sent[i] += len(emitted)
                 self.pool.advance(req.request_id, len(emitted))
-                self._next_token[i] = emitted[-1]
-                self._maybe_finish(i, now)
+                self._maybe_finish(i, req, now)
         if refeed and any(self._slot_req[i] is not None for i in slots):
             with self._phase("dispatch"):
                 self._drafter_cache, _ = self.dfns["verify"](
@@ -1036,9 +1135,10 @@ class ContinuousBatchingScheduler:
         return self._kv_rows(cache, slot, 0, length)
 
     def _restore_slot_kv(self, cache, slot: int, leaves: Dict[str, np.ndarray],
-                         length: int):
+                         length: int, token: int = 0):
         """Write migrated KV rows back into one slot of ``cache`` on
-        device (``slot_pool_set_rows``). Refuses — ``MigrationError``
+        device (``slot_pool_set_rows``), and ``token``, the one the slot is
+        fed next (a request still in prefill has none yet). Refuses — ``MigrationError``
         — on a missing/mis-shaped/mis-typed leaf rather than serving a
         half-restored cache.
 
@@ -1059,8 +1159,9 @@ class ContinuousBatchingScheduler:
         untouched."""
         flat, treedef, kv_idx, rows = self._validate_slot_kv(cache, leaves,
                                                              length)
-        new_flat = _restore_rows_jit([leaf for _, leaf in flat], rows,
-                                     np.int32(slot), tuple(kv_idx))
+        token_idx = next(i for i, (path, _) in enumerate(flat) if _leaf_name(path) == TOKEN_LEAF)
+        new_flat = _restore_rows_jit([leaf for _, leaf in flat], rows, np.int32(slot),
+                                     np.int32(token), tuple(kv_idx), token_idx)
         return jax.tree_util.tree_unflatten(treedef, new_flat)
 
     def _validate_slot_kv(self, cache, leaves: Dict[str, np.ndarray],
@@ -1112,7 +1213,11 @@ class ContinuousBatchingScheduler:
 
         ``release=True`` (the SIGTERM path) frees each exported request's
         pool blocks and parks its slot, so the drain loop sees an empty
-        scheduler and exits without generating further tokens here."""
+        scheduler and exits without generating further tokens here.
+
+        The program in flight, if any, is read first: a payload is what a
+        request has been GIVEN, and the cache rows behind it."""
+        self.settle("export")
         self._refuse_recurrent_migration()
         if self.config.do_sample:
             raise MigrationError(
@@ -1146,7 +1251,7 @@ class ContinuousBatchingScheduler:
                 "accepted_tokens": req.accepted_tokens,
                 "meta": dict(req.meta),
                 "length": length,
-                "next_token": int(self._next_token[slot]),
+                "next_token": req.output[-1] if req.output else 0,
                 "cached_prefix_tokens": req.cached_prefix_tokens,
                 # compat envelope: the importer refuses on any mismatch.
                 # prefix_cache rides in it because the KV slices below are
@@ -1172,6 +1277,7 @@ class ContinuousBatchingScheduler:
         the post-export half of a migrate-out, split from
         :meth:`export_inflight(release=False)` so a failed bundle save
         leaves the requests still serveable here (drain fallback)."""
+        self.settle("export")
         n = 0
         for slot, req in enumerate(self._slot_req):
             if req is None:
@@ -1194,6 +1300,7 @@ class ContinuousBatchingScheduler:
         processes count from 0 — the wire id would collide) with the
         origin id kept in ``meta["migrated_from"]`` for at-most-once
         completion accounting."""
+        self.settle("export")
         self._refuse_recurrent_migration()
         for knob in ("kv_quant", "weight_dtype", "spec_k", "capacity",
                      "prefix_cache"):
@@ -1233,16 +1340,18 @@ class ContinuousBatchingScheduler:
         if self._drafter is not None:
             self._validate_slot_kv(self._drafter_cache,
                                    payload["kv"].get("drafter", {}), length)
+        token = int(payload["next_token"])
         self._cache = self._restore_slot_kv(self._cache, slot,
-                                            payload["kv"]["target"], length)
+                                            payload["kv"]["target"], length, token)
         if self._drafter is not None:
             self._drafter_cache = self._restore_slot_kv(
-                self._drafter_cache, slot, payload["kv"]["drafter"], length)
+                self._drafter_cache, slot, payload["kv"]["drafter"], length, token)
         self.pool.reserve(req.request_id, req.total_tokens)
         self.pool.advance(req.request_id, length)
         self._slot_req[slot] = req
         self._lengths[slot] = length
-        self._next_token[slot] = payload["next_token"]
+        self._fed[slot] = req.prefill_pos
+        self._sent[slot] = len(req.output)
         if self.telemetry is not None:
             self.telemetry.emit("serve_admit_migrated",
                                 request_id=req.request_id,
@@ -1251,8 +1360,10 @@ class ContinuousBatchingScheduler:
         return req
 
     # -- retire --------------------------------------------------------
-    def _maybe_finish(self, slot: int, now: float) -> None:
-        req = self._slot_req[slot]
+    def _maybe_finish(self, slot: int, req: Request, now: float) -> None:
+        """Retire ``req`` if the token just read was its last: by count (its
+        slot may have been handed on when that token's program was
+        dispatched) or its EOS (found out here)."""
         done = len(req.output) >= req.max_new_tokens
         if req.eos_token_id is not None and req.output and \
                 req.output[-1] == req.eos_token_id:
@@ -1261,13 +1372,7 @@ class ContinuousBatchingScheduler:
             return
         req.state = FINISHED
         req.finish_time = now
-        # index the full blocks over prompt + output before the free, so
-        # the freed blocks park on the cached LRU instead of zeroing —
-        # a follow-up turn (prompt = this conversation + more) re-matches
-        self._publish_prefix(slot, req)
-        self.pool.free(req.request_id)
-        self._slot_req[slot] = None
-        self._lengths[slot] = self.capacity  # park
+        self._release(slot, req)
         self.finished.append(req)
         if req.ttft is not None:
             self.ttft_hist.record(req.ttft)
@@ -1276,15 +1381,29 @@ class ContinuousBatchingScheduler:
         if self.telemetry is not None:
             self.telemetry.emit("serve_request", **req.stats())
 
+    def _release(self, slot: int, req: Request) -> None:
+        """Hand ``slot`` on, if ``req`` still holds it: its blocks back to
+        the pool, the slot parked."""
+        if self._slot_req[slot] is not req:
+            return
+        # index the full blocks over prompt + output before the free, so
+        # the freed blocks park on the cached LRU instead of zeroing —
+        # a follow-up turn (prompt = this conversation + more) re-matches
+        self._publish_prefix(slot, req)
+        self.pool.free(req.request_id)
+        self._slot_req[slot] = None
+        self._lengths[slot] = self.capacity  # park
+
     # ------------------------------------------------------------------
     # loops
     # ------------------------------------------------------------------
     def run_until_drained(self, max_ticks: int = 10**9, admit: bool = True) -> int:
         """Tick until queue + slots are empty; returns ticks run."""
         n = 0
-        while (self.in_flight or len(self.queue)) and n < max_ticks:
+        while self.busy and n < max_ticks:
             self.step(admit=admit)
             n += 1
+        self.settle()       # ``max_ticks`` may have cut the loop under a program
         return n
 
     def serve(self, requests=(), guard=None, migrate=None) -> int:
@@ -1314,7 +1433,7 @@ class ContinuousBatchingScheduler:
         try:
             for r in requests:
                 self.submit(r)
-            while self.in_flight or len(self.queue):
+            while self.busy:
                 if guard.requested and preempted is None:
                     preempted = guard.consume()
                     refused = self.queue.refuse_all(f"draining on {preempted}")
@@ -1340,6 +1459,7 @@ class ContinuousBatchingScheduler:
                             continue  # slots released — loop re-checks
                 self.step(admit=preempted is None)
         finally:
+            self.settle()
             if own_guard:
                 guard.uninstall()
         return DEFAULT_PREEMPT_EXIT_CODE if preempted else 0

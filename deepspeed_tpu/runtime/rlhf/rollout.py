@@ -172,7 +172,7 @@ class RolloutLoop:
             # uninterrupted run did — the stitched-loss-curve contract
             if self.config.overlap:
                 self._train_ready(limit=1)
-            elif not self.scheduler.in_flight and not len(self.scheduler.queue):
+            elif not self.scheduler.busy:
                 self._train_ready(limit=10**9)
             if self.learner_steps >= self.total_batches:
                 break
@@ -231,8 +231,7 @@ class RolloutLoop:
                 return
             exps = [self.experience.pop(i) for i in idxs]
             self.consumed += B
-            overlapped = bool(self.scheduler.in_flight
-                              or len(self.scheduler.queue))
+            overlapped = self.scheduler.busy
             step_no = self.learner_steps + 1
             t0 = time.perf_counter()
             if self.learner_telemetry is not None:

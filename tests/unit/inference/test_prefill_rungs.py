@@ -21,7 +21,8 @@ from deepspeed_tpu.inference.serving.programs import (INDEX_LEAVES, LENGTH_LEAVE
                                                       STATE_LEAVES, _leaf_name,
                                                       build_prefill_step, make_apply_fn,
                                                       prefill_rungs, with_counters,
-                                                      with_write_positions)
+                                                      with_next_tokens, with_write_positions,
+                                                      without_next_tokens)
 from deepspeed_tpu.models.common import COUNTER_LEAVES
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
 from deepspeed_tpu.utils import trace
@@ -90,7 +91,8 @@ def _serve(sched):
         for i in arrivals.get(tick, []):
             sched.submit(reqs[i])
         before = counters.get("prefill_positions_run", 0)
-        if sched.step() == "prefill":
+        sched.step()        # returns the kind it read back; the counter says what it dispatched
+        if counters.get("prefill_positions_run", 0) > before:
             rungs.append((counters["prefill_positions_run"] - before) // CHUNK)
         tick += 1
         assert tick < 500
@@ -294,12 +296,16 @@ def test_prefill_positions_computed_still_counts_ticks(engine):
 # (d) the whole rung is the program it was
 # ---------------------------------------------------------------------------
 def _parent_prefill_step(apply_fn):
-    """The prefill program as the parent of ISSUE 33 built it (greedy)."""
+    """The prefill program as the parent of ISSUE 33 built it (greedy), with
+    the token each fed slot is given left in the cache (ISSUE 35)."""
 
     def prefill(params, cache, write_pos, ids, last_idx):
+        cache, held = without_next_tokens(cache)
         logits, cache = apply_fn(params, with_write_positions(cache, write_pos, last_idx + 1), ids)
         last = jnp.take_along_axis(logits, last_idx[:, None, None], axis=1)[:, 0]
-        return cache, with_counters(cache, jnp.argmax(last, axis=-1).astype(jnp.int32))
+        tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        held = jnp.where(write_pos < slot_capacity(cache), tok, held)
+        return with_next_tokens(cache, held), with_counters(cache, tok)
 
     return prefill
 

@@ -623,8 +623,7 @@ def test_decode_program_identical_cache_on_vs_off(engine_cfg):
     def lowered(mode):
         sched = _mk_sched(engine, prefix_cache=mode)
         toks = np.zeros(sched.slots, np.int32)
-        return sched.fns["decode"].lower(sched._serve_params, sched._cache,
-                                         toks, toks).as_text()
+        return sched.fns["decode"].lower(sched._serve_params, sched._cache, toks).as_text()
 
     on, off = lowered("on"), lowered("off")
     assert on == off  # byte-identical: zero device-side cost when idle

@@ -140,13 +140,14 @@ def test_rows_land_at_write_pos_and_parked_slots_write_nothing(engine_cfg, progr
     if program == "decode":
         rows = 1
         step = jax.jit(build_decode_step(apply_fn, False, 1.0, 0, 1.0), donate_argnums=(1,))
-        inputs = (rng.integers(1, cfg.vocab_size, SLOTS).astype(np.int32),)
+        # the tokens by name, in the place of those the cache holds
+        inputs, fed = (), {"tokens": rng.integers(1, cfg.vocab_size, SLOTS).astype(np.int32)}
     else:
         rows = CHUNK
         step = jax.jit(build_prefill_step(apply_fn, False, 1.0, 0, 1.0), donate_argnums=(1,))
-        inputs = (rng.integers(1, cfg.vocab_size, (SLOTS, CHUNK)).astype(np.int32),
-                  np.full(SLOTS, CHUNK - 1, np.int32))
-    new_cache, tok = step(engine.params, cache, write_pos, *inputs)
+        inputs, fed = (rng.integers(1, cfg.vocab_size, (SLOTS, CHUNK)).astype(np.int32),
+                       np.full(SLOTS, CHUNK - 1, np.int32)), {}
+    new_cache, tok = step(engine.params, cache, write_pos, *inputs, **fed)
     assert tok.shape == (SLOTS,)
     after = {k: np.asarray(v) for k, v in _kv_leaves(new_cache).items()}
     assert after.keys() == before.keys() and len(after) == (8 if kv_quant else 4)

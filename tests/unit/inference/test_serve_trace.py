@@ -92,10 +92,19 @@ def test_every_working_tick_has_the_named_children_in_order(served):
     assert [t.uid for t, _ in ticks] == list(range(1, len(ticks) + 1))
     kinds = [t.kind for t, _ in ticks]
     assert kinds[0] == "idle" and {"prefill", "decode"} <= set(kinds)
-    assert {k: kinds.count(k) for k in set(kinds)} == {k: v for k, v in sched.ticks.items() if v}
+    # a tick ends with the read-back of a program and has that program's kind
+    # (ISSUE 35): one tick a program, and one more that dispatched the first
+    # program of a busy stretch into an empty device and read nothing
+    read = [t.kind for t, children in ticks if any(c.name == "device_wait" for c in children)]
+    assert {k: read.count(k) for k in set(read) | {"idle"}} == \
+        {k: v for k, v in sched.ticks.items() if v} | {"idle": 0}
+    assert kinds.count("idle") == sched.ticks["idle"] and len(kinds) == len(read) + 2
     for tick, children in ticks:
         names = [c.name for c in children]
-        assert names == (["admit", "heartbeat"] if tick.kind == "idle" else PHASES), tick
+        if tick.kind == "idle":
+            assert names == ["admit", "heartbeat"]
+        else:   # no program before it to read, or none after it to dispatch
+            assert names in (PHASES, PHASES[:4] + PHASES[6:], PHASES[:1] + PHASES[4:]), tick
         assert all(c.parent == "tick" and c.source == sched._source for c in children)
 
 
@@ -188,7 +197,7 @@ def test_an_attached_sink_flushes_its_own_schedulers_records_only(engine, tmp_pa
     events = read_events(str(tmp_path / "serve" / "telemetry.jsonl"))
     spans = [s for e in events if e["event"] == "spans" for s in e["spans"]]
     assert len(spans) == len(records)
-    assert sum(s["name"] == "tick" for s in spans) == sum(sched.ticks.values())
+    assert sum(s["name"] == "tick" for s in spans) == sum(r.name == "tick" for r in records)
     window = [e for e in events if e["event"] == "step_window"][-1]
     assert window["phases"]["device_wait"]["count"] == \
         sched.ticks["prefill"] + sched.ticks["decode"]

@@ -350,7 +350,7 @@ def test_other_slots_latent_rows_are_bit_identical_after_a_tick(programs, progra
     if program == "prefill":
         after, _ = prefill(params, cache, _int(16, parked), ids, _int(9, 15))
     elif program == "decode":
-        after, _ = decode(params, cache, _int(16, parked), _int(5, 9))
+        after, _ = decode(params, cache, _int(16, parked), tokens=_int(5, 9))
     else:
         after, _ = prefill(params, cache, _int(0, parked), ids_of(2, 16, seed=5), _int(15, 15))
     for was, now in zip(before, _rows(after, 1)):
@@ -421,6 +421,7 @@ def test_a_migrated_slot_carries_its_latent_rows(engine):
     src.submit(req)
     while len(req.output) < 3:
         src.step()
+        src.settle()        # read each program in its own step: stop at three tokens exactly
     bundle, = src.export_inflight()
     leaves = bundle["kv"]["target"]
     assert leaves and all("cached_latent" in key for key in leaves)

@@ -372,7 +372,7 @@ def test_a_parked_slots_state_is_bit_identical_after_a_tick(programs, program):
     if program == "prefill":
         after, _ = prefill(params, cache, _int(8, parked), ids, _int(7, 7))
     else:
-        after, _ = decode(params, cache, _int(8, parked), _int(5, 9))
+        after, _ = decode(params, cache, _int(8, parked), tokens=_int(5, 9))
     for was, now in zip(before, _state(after, 1)):
         assert was.tobytes() == now.tobytes()
     assert any(a.tobytes() != b.tobytes() for a, b in zip(_state(cache, 0), _state(after, 0)))

@@ -273,7 +273,7 @@ def test_serving_program_compiles(one_chip, program, attention):
                     _shape(SLOTS, dtype=jnp.int32))
     else:
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
-        operands = (_shape(SLOTS, dtype=jnp.int32), _shape(SLOTS, dtype=jnp.int32))
+        operands = (_shape(SLOTS, dtype=jnp.int32),)
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     assert ("tpu_custom_call" in compiled.as_text()) == (attention == "flash")
 
@@ -305,7 +305,7 @@ def test_gpt2_serving_program_writes_the_pool_in_place(one_chip, program):
         logits = slots * CHUNK * module.config.vocab_size * 2
     else:
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
-        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
+        operands = (_shape(slots, dtype=jnp.int32),)
         logits = slots * module.config.vocab_size * 2
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     _assert_pool_written_in_place(compiled, slots * L * H * D, layers, logits)
@@ -337,7 +337,7 @@ def test_olmoe_serving_program_compiles(one_chip, program):
                     _shape(slots, dtype=jnp.int32))
     else:
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
-        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
+        operands = (_shape(slots, dtype=jnp.int32),)
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     assert compiled.as_text().count("tpu_custom_call") == 3       # gate, up, down
     # a prefill tick's temporaries stay under a gigabyte: no [E, C, M] buffer
@@ -371,7 +371,7 @@ def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, pr
                     _shape(slots, dtype=jnp.int32))
     else:
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
-        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
+        operands = (_shape(slots, dtype=jnp.int32),)
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     # two expert layers x (up, down) x the row buffers the held layer may
     # take (``sharded_moe._row_rungs``): a prefill tick's 16 x 128 x top-4 =
@@ -430,7 +430,7 @@ def test_joyai_llm_flash_serving_program_reads_the_latent_pool_as_it_lies(one_ch
                     _shape(slots, dtype=jnp.int32))
     else:
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
-        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, dtype=jnp.int32))
+        operands = (_shape(slots, dtype=jnp.int32),)
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     pool_bytes = pools[0].size * 2
     assert not _relayouts(compiled, pools[0].size)

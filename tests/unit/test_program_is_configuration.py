@@ -91,7 +91,7 @@ def _serve_engine():
 
 def _decode_text(sched):
     toks = np.zeros(sched.slots, np.int32)
-    return sched.fns["decode"].lower(sched._serve_params, sched._cache, toks, toks).as_text()
+    return sched.fns["decode"].lower(sched._serve_params, sched._cache, toks).as_text()
 
 
 def _decode_program():

@@ -221,8 +221,8 @@ def serve_evidence(engine, slots, wq="fp", kv_quant=False):
         cache = make_slot_cache(module, slots, kv_quant=kv_quant)
         decode = build_decode_step(make_apply_fn(module, engine._mparams),
                                    False, 1.0, 0, 1.0)
-        write_pos = tokens = jnp.zeros((slots,), jnp.int32)
-        jaxpr = jax.make_jaxpr(decode)(params, cache, write_pos, tokens)
+        write_pos = jnp.zeros((slots,), jnp.int32)
+        jaxpr = jax.make_jaxpr(decode)(params, cache, write_pos)
         info = ProgramInfo(name="serve_decode", jaxpr=jaxpr, kind="serve_decode")
         findings, _ = analysis.run_program_rules(info)
         mem = estimate_memory(info)
@@ -263,7 +263,7 @@ def run_continuous(engine, cfg, trace, drafter=None, telemetry=None,
     t0 = time.monotonic()
     i = 0
     reqs = []
-    while i < len(trace) or sched.in_flight or len(sched.queue):
+    while i < len(trace) or sched.busy:
         now = time.monotonic() - t0
         while i < len(trace) and trace[i][0] <= now:
             _, prompt, new = trace[i]
@@ -272,7 +272,7 @@ def run_continuous(engine, cfg, trace, drafter=None, telemetry=None,
             sched.submit(r)
             reqs.append(r)
             i += 1
-        if sched.in_flight or len(sched.queue):
+        if sched.busy:
             sched.step()
         elif i < len(trace):
             time.sleep(min(max(trace[i][0] - now, 0.0), 0.05))

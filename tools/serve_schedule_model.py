@@ -15,8 +15,15 @@ at 18.7 and 343.8 ms a tick the model gave the chip's six seeds at ``block``
 is what chose ``block`` 16 for that cell. It knows nothing of a machine
 that runs slow: a spread it does not give is not the seeds'.
 
+Since the scheduler dispatches a tick before it reads the one before it
+(PR 35), the host's time a tick runs under the device's program: the two
+times are the PROGRAMS' (a tick's ``device_wait`` plus the host time it now
+hides), and a tick costs the longer of its program and ``--host-ms``, not
+their sum. (Those readings of PR 30 were whole ticks of the serial order,
+host included: the same model with no ``--host-ms``.)
+
     python3 tools/serve_schedule_model.py --decode-ms 18.7 --prefill-ms 343.8 \\
-        [--workload <cell>] [--block <n> ...] [--interleave <n>] [--sets 12]
+        [--host-ms 1.8] [--workload <cell>] [--block <n> ...] [--interleave <n>] [--sets 12]
 
 Prints, for each ``block``, the spread (quartiles over the median) of sets
 of six consecutive seeds.
@@ -32,9 +39,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def tokens_per_s(lengths, slots, chunk, interleave, decode_s, prefill_s, preroll_s, window_s):
+def tokens_per_s(lengths, slots, chunk, interleave, decode_s, prefill_s, preroll_s, window_s,
+                 host_s=0.0):
     """``serve_total_tok_s`` of one run: ``lengths`` the (prompt, output)
-    pairs in queue order, all due at time zero."""
+    pairs in queue order, all due at time zero; a tick takes the longer of
+    its program and the host's ``host_s``, which runs under it."""
+    decode_s, prefill_s = max(decode_s, host_s), max(prefill_s, host_s)
     queue = iter(lengths)
     held = [None] * slots           # [prompt tokens left, outputs made, outputs wanted]
     now, since_prefill, progress, opened = 0.0, 0, 0, None
@@ -71,7 +81,8 @@ def spread_pct(values):
     return 100.0 * (third - first) / statistics.median(values)
 
 
-def run_seed(cell, seed, decode_s, prefill_s, block=None, interleave=None, window_s=51.0):
+def run_seed(cell, seed, decode_s, prefill_s, block=None, interleave=None, window_s=51.0,
+             host_s=0.0):
     from benchmarks.lib.traffic import serve_schedule
 
     traffic = dict(cell.traffic, block=block or cell.traffic["block"])
@@ -80,7 +91,7 @@ def run_seed(cell, seed, decode_s, prefill_s, block=None, interleave=None, windo
                for r in serve_schedule(traffic, cell.config["vocab_size"], seed, 0.0)]
     return tokens_per_s(lengths, serve["slots"], serve["prefill_chunk"],
                         interleave or serve["prefill_interleave"], decode_s, prefill_s,
-                        float(traffic["preroll_s"]), window_s)
+                        float(traffic["preroll_s"]), window_s, host_s)
 
 
 def main(argv):
@@ -88,6 +99,7 @@ def main(argv):
     parser.add_argument("--workload", default="serve-nemotron-3-super-reason-sat")
     parser.add_argument("--decode-ms", type=float, required=True)
     parser.add_argument("--prefill-ms", type=float, required=True)
+    parser.add_argument("--host-ms", type=float, default=0.0)
     parser.add_argument("--block", type=int, nargs="+")
     parser.add_argument("--interleave", type=int)
     parser.add_argument("--sets", type=int, default=12)
@@ -103,7 +115,8 @@ def main(argv):
         spreads, medians = [], []
         for k in range(args.sets):
             values = [run_seed(cell, args.first_seed + 7919 * k + j, args.decode_ms / 1e3,
-                               args.prefill_ms / 1e3, block, args.interleave) for j in range(6)]
+                               args.prefill_ms / 1e3, block, args.interleave,
+                               host_s=args.host_ms / 1e3) for j in range(6)]
             spreads.append(spread_pct(values))
             medians.append(statistics.median(values))
         print(json.dumps({"block": block, "sets_of_six": args.sets,
