@@ -40,14 +40,18 @@ from jax.sharding import NamedSharding, PartitionSpec
 # cache; [slots] vectors in the serving cache), KV_LEAVES are the pools of
 # keys and values, POOL_LEAVES those and a latent-attention layer's one
 # ``cached_latent`` pool (``models/common.py`` LatentCache): every pool that
-# holds rows by position
+# holds rows by position (an indexed layer's ``cached_index_key`` beside its
+# latent among them). RING_LEAVES are a window layer's latent: the stored form
+# of a pool over a ring of positions, written at ``position mod ring``; it is
+# no pool (it has lost most positions' rows) and its extent is not the slots'
+# capacity
 # a model with recurrent layers (``models/nemotron_h.py``) adds STATE_LEAVES
 # (per-slot state with no positions, ``[slots, ...]`` as the model shapes
 # it), LENGTH_LEAVES (how many of a slot's tokens this tick are real) and,
 # where a layer counts for the host, COUNTER_LEAVES
-from deepspeed_tpu.models.common import (COUNTER_LEAVES, INDEX_LEAVES, KV_LEAVES,
-                                         LATENT_LEAVES, LENGTH_LEAVES, POOL_LEAVES,
-                                         SLOT_LEAF, STATE_LEAVES, slot_pool,
+from deepspeed_tpu.models.common import (COUNTER_LEAVES, INDEX_KEY_LEAVES, INDEX_LEAVES,
+                                         KV_LEAVES, LATENT_LEAVES, LENGTH_LEAVES, POOL_LEAVES,
+                                         RING_LEAVES, SLOT_LEAF, STATE_LEAVES, slot_pool,
                                          slot_pool_positions, slot_pool_scale)
 from deepspeed_tpu.utils import trace
 
@@ -88,6 +92,12 @@ def make_slot_cache(module, slots: int, kv_quant: bool = False):
     A recurrent layer's state (``STATE_LEAVES``) stays ``[slots, ...]`` as
     the model shapes it, zeroed and never quantised; the ``LENGTH_LEAVES``
     beside the index leaves are [slots] vectors too, 0 for a parked slot.
+    A window layer's ring (``RING_LEAVES``) is stored as a pool is, over its
+    own, shorter extent: the cache's pools are then sized by the kind of layer
+    (every position for a full layer's latent and index keys, a window and a
+    chunk for a window layer's), and the parked sentinel is the POOLS' extent
+    (:func:`slot_capacity`), which a ring's write is told apart from by the
+    length leaf's 0.
     :data:`TOKEN_LEAF` lies at the top level, zeros."""
     from deepspeed_tpu.models.common import init_cache
 
@@ -95,7 +105,7 @@ def make_slot_cache(module, slots: int, kv_quant: bool = False):
         def leaf_of(path, leaf):
             if _is_index_leaf(path) or _leaf_name(path) in LENGTH_LEAVES:
                 return jnp.zeros((slots,), jnp.int32)
-            return slot_pool(leaf) if _leaf_name(path) in POOL_LEAVES else leaf
+            return slot_pool(leaf) if _leaf_name(path) in POOL_LEAVES + RING_LEAVES else leaf
 
         cache = jax.tree_util.tree_map_with_path(leaf_of, cache)
         return quantize_slot_cache(cache) if kv_quant else cache
@@ -121,11 +131,16 @@ def quantize_slot_cache(cache):
         for name, leaf in tree.items():
             if isinstance(leaf, dict) or hasattr(leaf, "items"):
                 out[name] = walk(leaf)
-            elif name in LATENT_LEAVES:
+            elif name in LATENT_LEAVES + RING_LEAVES:
                 raise NotImplementedError(
                     f"kv_quant over a latent pool ({name}): an int8 latent is not built (every "
                     f"head reads it through two projections, so its tolerance is its own); "
                     f"serve this model with kv_quant=False")
+            elif name in INDEX_KEY_LEAVES:
+                raise NotImplementedError(
+                    f"kv_quant over an indexer's keys ({name}): int8 index keys are not built "
+                    f"(they decide WHICH positions are attended, so their tolerance is a set's, "
+                    f"not a logit's); serve this model with kv_quant=False")
             elif name in KV_LEAVES:
                 out[name] = jnp.zeros(leaf.shape, jnp.int8)
                 out[name + "_scale"] = slot_pool_scale(leaf)
@@ -139,7 +154,8 @@ def quantize_slot_cache(cache):
 def slot_capacity(cache) -> int:
     """Token capacity per slot of a serving cache = its KV pools' position
     extent (also the parked-slot sentinel: a write at this position drops
-    out of bounds)."""
+    out of bounds). A ring (``RING_LEAVES``) is no pool: its extent bounds
+    no slot."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         if _leaf_name(path) in POOL_LEAVES:
             return slot_pool_positions(leaf)
@@ -253,6 +269,12 @@ def counter_widths(cache):
     """``(name, int32s)`` of what :func:`with_counters` appends, in order."""
     return [(name, leaves[0].shape[0]) for name in COUNTER_LEAVES
             if (leaves := _leaves_named(cache, (name,)))]
+
+
+def has_ring(cache) -> bool:
+    """Whether a serving cache holds a window layer's ring: positions it has
+    overwritten cannot be copied out of it."""
+    return bool(_leaves_named(cache, RING_LEAVES))
 
 
 def has_recurrent_state(cache) -> bool:

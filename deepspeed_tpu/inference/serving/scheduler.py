@@ -41,15 +41,16 @@ import jax
 
 from deepspeed_tpu.inference.serving.blocks import BlockPool
 from deepspeed_tpu.inference.serving.config import ServingConfig
-from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, TOKEN_LEAF, _leaf_name,
-                                                      counter_widths, has_recurrent_state,
+from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, RING_LEAVES, TOKEN_LEAF,
+                                                      _leaf_name, counter_widths,
+                                                      has_recurrent_state, has_ring,
                                                       make_slot_cache, prefill_rungs,
                                                       serve_programs, slot_capacity,
                                                       state_bytes_per_slot)
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
-from deepspeed_tpu.models.common import (slot_pool_positions_touched,
+from deepspeed_tpu.models.common import (SPARSE_READS, slot_pool_positions_touched,
                                          slot_pool_row_shape, slot_pool_rows,
                                          slot_pool_set_rows)
 from deepspeed_tpu.runtime.telemetry.metrics import Histogram
@@ -226,6 +227,23 @@ class ContinuousBatchingScheduler:
                 f"has already advanced over the drafted tokens: verification needs the "
                 f"state at the accepted position, which is not built")
 
+        # a window layer's ring has overwritten most positions' rows: what
+        # copies rows by position refuses it by name until it carries the
+        # window (the last ``window - 1`` rows and where the ring stands)
+        self._ring = has_ring(self._cache)
+        if self._ring and config.prefix_cache == "on":
+            raise NotImplementedError(
+                f"prefix_cache='on' over {type(self.module).__name__}: a shared prefix is "
+                f"restored as cache rows at positions, and this model's window layers keep a "
+                f"ring (cached_window_latent) that has overwritten them: sharing needs the "
+                f"window's rows at the prefix's end in the block, which is not built")
+        if self._ring and (config.speculation.enabled or drafter is not None):
+            raise NotImplementedError(
+                f"speculative decoding over {type(self.module).__name__}: the verify step "
+                f"writes k + 1 positions into this model's window rings "
+                f"(cached_window_latent), which are sized for the window and a prefill chunk "
+                f"and have not been checked against a rejected draft's rewrite: not built")
+
         # admission: block-pool truthful KV accounting. A byte budget is
         # sized into tokens from the cache's ACTUAL per-token footprint
         # (int8 codes + scales under kv_quant), which is how quantized KV
@@ -381,7 +399,7 @@ class ContinuousBatchingScheduler:
         total = 0
         for path, leaf in jax.tree_util.tree_flatten_with_path(self._cache)[0]:
             name = _leaf_name(path)
-            if name in POOL_LEAVES or name.endswith("_scale"):
+            if name in POOL_LEAVES + RING_LEAVES or name.endswith("_scale"):
                 total += leaf.size * leaf.dtype.itemsize
         return total / float(self.slots * self.capacity)
 
@@ -414,9 +432,29 @@ class ContinuousBatchingScheduler:
             elif name == "latent_reads":
                 read, live, written = counted
                 self._rec.count(f"latent_positions_read_{kind}", read)
-                self._rec.count(f"latent_positions_live_{kind}", live)
+                self._count_of_tick("latent_positions_live", live, kind)
                 self._rec.count("latent_bytes_written", written)
+            elif name == "sparse_reads":
+                # ``models/common.py`` SPARSE_READS: an indexed layer's index
+                # keys read and positions chosen of positions live, a window
+                # layer's ring positions read and in a window; bytes by pool
+                for what, n in zip(SPARSE_READS, counted):
+                    if what.endswith("_written"):
+                        self._rec.count(what, n)
+                    else:
+                        self._count_of_tick(what, n, kind)
         return tok[:ran]
+
+    def _count_of_tick(self, name: str, n: int, kind: str) -> None:
+        """A count the device made of one tick's operands: summed by the
+        kind of tick, and kept tick by tick in the ring (a ``count:<name>``
+        record with the count for its ``uid`` and the tick's kind): the ticks
+        of a few seconds differ from the mean tick by a third, and whoever
+        sets a traced slice's kernel time against what its ticks were owed
+        needs THOSE ticks' counts."""
+        self._rec.count(f"{name}_{kind}", n)
+        now = time.perf_counter()
+        self._rec.record(f"count:{name}", now, now, n, self._source, kind)
 
     def _count_state(self, write_pos: np.ndarray, fed: Optional[int] = None,
                      computed: Optional[int] = None) -> None:
@@ -1193,6 +1231,13 @@ class ContinuousBatchingScheduler:
         return flat, treedef, kv_idx, rows
 
     def _refuse_recurrent_migration(self) -> None:
+        if self._ring:
+            raise MigrationError(
+                f"live migration over {type(self.module).__name__}: a request's committed "
+                f"state travels as cache rows up to its length, and this model's window "
+                f"layers keep a ring (cached_window_latent) whose rows are not at their "
+                f"positions: migrating it needs the window's rows in the payload, which is "
+                f"not built — drain instead")
         if self._recurrent:
             raise MigrationError(
                 f"live migration over {type(self.module).__name__}: a request's committed "

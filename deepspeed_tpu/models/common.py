@@ -51,7 +51,21 @@ INDEX_LEAVES = ("cache_index", "position_index")
 #: slot's rows by position (prefix blocks, a migrated slot) carries its rows as
 #: it carries keys and values: ``POOL_LEAVES`` is what such a walker asks for
 LATENT_LEAVES = ("cached_latent",)
-POOL_LEAVES = KV_LEAVES + LATENT_LEAVES
+#: beside a latent pool, where the layer picks the positions it attends
+#: (``models/deepseek_v3.py``, an indexed layer): the indexer's one key a
+#: position, a pool of one "head" in the same stored form over the same
+#: positions, so the walkers carry its rows as they carry the latent's
+INDEX_KEY_LEAVES = ("cached_index_key",)
+POOL_LEAVES = KV_LEAVES + LATENT_LEAVES + INDEX_KEY_LEAVES
+#: a window layer's latent (:class:`LatentCache` with ``ring=True``): the
+#: stored form of a pool, but its extent is a RING, the window and one chunk,
+#: written at ``position mod ring``: position ``p`` of a slot is there only
+#: while the slot's length is under ``p + ring``. It is no pool: it has no row
+#: for most positions, so whoever carries rows by position (prefix blocks,
+#: migration, a speculation's drafter) refuses a cache that has one, its
+#: extent is not the slot's capacity, and a parked slot's sentinel position
+#: must not be folded into it (``fed`` 0 is what drops a ring's write)
+RING_LEAVES = ("cached_window_latent",)
 #: a second kind of per-slot state, with no positions (``models/nemotron_h.py``):
 #: a recurrent layer's state ``[slots, ...]``, carried from tick to tick, never
 #: quantised, zeroed when a request joins at position 0; ``LENGTH_LEAVES`` say
@@ -60,10 +74,22 @@ POOL_LEAVES = KV_LEAVES + LATENT_LEAVES
 #: ``COUNTER_LEAVES`` are int32 counts a layer leaves for the host, which a
 #: serving program sums over the layers, name by name, and returns beside its
 #: tokens (``moe_rows``: an expert layer that holds a share; ``latent_reads``:
-#: a latent-attention layer, positions read, positions live, bytes written)
+#: a latent-attention layer, positions read, positions live, bytes written;
+#: ``sparse_reads``: a layer that selects or windows what it attends,
+#: :data:`SPARSE_READS` names its entries)
 STATE_LEAVES = ("ssm_state", "conv_state")
 LENGTH_LEAVES = ("chunk_length",)
-COUNTER_LEAVES = ("moe_rows", "latent_reads")
+COUNTER_LEAVES = ("moe_rows", "latent_reads", "sparse_reads")
+#: the entries of a ``sparse_reads`` leaf, in order, under the names the
+#: host counts them by (by the kind of tick, but for the bytes ``_written``).
+#: An indexed layer fills the ``dsa_`` ones (positions of the index-key pool its
+#: scores were bounded to; over its real queries, the positions each attended
+#: and the positions each could have, its own included); a window layer the
+#: ``swa_`` ones (positions of the ring its attention read; of those, the ones
+#: inside some real query's window)
+SPARSE_READS = ("dsa_index_keys_read", "dsa_positions_selected", "dsa_positions_live",
+                "swa_ring_positions_read", "swa_ring_positions_live", "dsa_latent_bytes_written",
+                "dsa_index_key_bytes_written", "swa_ring_bytes_written")
 #: a serving program that runs fewer sequences than the cache has slots (a
 #: rung of ``serving/programs.py``'s prefill ladder) says which slot each
 #: sequence is: ``cache_slots`` [n] int32, distinct, which the program lays
@@ -186,31 +212,55 @@ class LatentCache:
     width]); a ``[slots]`` vector is the serving cache, the pool stored
     positions minor-most [slots, 1, width, positions] and written in place by
     :func:`slot_pool_append`. An int8 latent is not built
-    (``serving/programs.py`` ``quantize_slot_cache`` refuses it by name)."""
+    (``serving/programs.py`` ``quantize_slot_cache`` refuses it by name).
 
-    def __init__(self, module: nn.Module, batch: int, positions: int, width: int, dtype):
-        self.pool = module.variable("cache", "cached_latent", jnp.zeros,
-                                    (batch, positions, 1, width), dtype)
-        self.index = module.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
+    ``name`` and ``index`` let a layer keep a second pool over the same
+    positions under the same index (an indexed layer's ``cached_index_key``),
+    appended with ``advance=False``. ``ring=True`` (a window layer's
+    ``cached_window_latent``) makes ``positions`` a ring: token ``p`` is
+    written at ``p mod positions``, and only where the sequence is ``live``
+    (a parked slot's sentinel position would fold into the ring)."""
+
+    def __init__(self, module: nn.Module, batch: int, positions: int, width: int, dtype,
+                 name: str = "cached_latent", index=None, ring: bool = False):
+        self.pool = module.variable("cache", name, jnp.zeros, (batch, positions, 1, width), dtype)
+        self.index = index if index is not None else module.variable(
+            "cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
+        self.ring = ring
 
     @property
     def per_slot(self) -> bool:
         return self.index.value.ndim > 0
 
-    def append(self, latent):
+    @property
+    def positions(self) -> int:
+        """The pool's extent, whichever form it is stored in."""
+        return self.pool.value.shape[-1 if self.per_slot else 1]
+
+    def append(self, latent, advance: bool = True, live=None):
         """Write ``latent`` [batch, l, width] at the index and advance it;
         returns ``(pool, start)``: the whole pool as it is stored for serving,
         [batch, width, positions] (the serving pool itself, no copy; a
         lockstep pool's transpose), and each sequence's first written
-        position [batch]."""
+        position [batch]. ``live`` [batch] bool (a ring's): the sequences
+        that write at all."""
         b, l = latent.shape[:2]
         idx = self.index.value
-        self.index.value = idx + l
+        if advance:
+            self.index.value = idx + l
         new = latent[:, :, None, :].astype(self.pool.value.dtype)
         if self.per_slot:
-            self.pool.value, = slot_pool_append([self.pool.value], [new], idx)
+            if self.ring:
+                live = jnp.ones((b,), bool) if live is None else live
+                self.pool.value, = ring_pool_append([self.pool.value], [new], idx, live)
+            else:
+                self.pool.value, = slot_pool_append([self.pool.value], [new], idx)
             return self.pool.value[:, 0], idx
-        self.pool.value = jax.lax.dynamic_update_slice(self.pool.value, new, (0, idx, 0, 0))
+        if self.ring:
+            at = (idx + jnp.arange(l)) % self.positions
+            self.pool.value = self.pool.value.at[:, at].set(new)
+        else:
+            self.pool.value = jax.lax.dynamic_update_slice(self.pool.value, new, (0, idx, 0, 0))
         return jnp.transpose(self.pool.value[:, :, 0], (0, 2, 1)), jnp.broadcast_to(idx, (b,))
 
 
@@ -303,6 +353,31 @@ def slot_pool_append(leaves, updates, pos, rows=None):
     # [slots, l, ...], the updates' own shape; out of bounds drops
     return [leaf.at[(jnp.arange(slots) if rows is None else rows)[:, None], ..., at]
             .set(upd.astype(leaf.dtype)) for leaf, upd in zip(leaves, updates)]
+
+
+def ring_pool_append(leaves, updates, pos, live):
+    """:func:`slot_pool_append` into RINGS: token ``j`` of slot ``s`` goes to
+    ``(pos[s] + j) mod ring`` of the stored leaves ``[slots, ..., ring]``, and
+    a slot that is not ``live`` [slots] writes nothing (``pos`` is then a
+    parked slot's sentinel, which must not be folded into the ring). A piece
+    that runs over the ring's end goes on at its start.
+
+    On a TPU two in-place writes: one at ``pos mod ring``, which drops what
+    runs past the end, and, for more than one token, one a ring earlier,
+    which drops all but that (a position before 0 writes nothing)."""
+    from deepspeed_tpu.ops.pallas import backend
+    ring = leaves[0].shape[-1]
+    slots, length = updates[0].shape[:2]
+    pos = pos.astype(jnp.int32)
+    if backend.on_tpu():
+        at = jnp.where(live, pos % ring, ring)
+        leaves = _append_in_place(leaves, updates, at)
+        if length > 1:
+            leaves = _append_in_place(leaves, updates, jnp.where(live, at - ring, ring))
+        return leaves
+    at = jnp.where(live[:, None], (pos[:, None] + jnp.arange(length)[None, :]) % ring, ring)
+    return [leaf.at[jnp.arange(slots)[:, None], ..., at].set(upd.astype(leaf.dtype))
+            for leaf, upd in zip(leaves, updates)]
 
 
 def _append_in_place(leaves, updates, pos, rows=None):

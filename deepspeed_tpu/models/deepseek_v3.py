@@ -34,6 +34,38 @@ walk ends at its own live length (a parked slot's is empty).
 
 The residual stream is float32 between the layers whatever they compute in.
 
+**Layers of more than one kind** (``layer_types``; dots3-note-prev: 46 layers,
+hidden 5120, 13 full and 33 window layers). Each kind has its own head count,
+ranks, key width and theta (:class:`AttentionKind`); the equations above hold
+for both, with two additions a configuration may turn on: the normed latents
+rescaled (``c_q`` by ``sqrt(hidden / q_lora_rank)``, ``c_kv`` by ``sqrt(hidden
+/ kv_lora_rank)``: ``mla_lora_rescale``), and a gate a head, ``o_h <-
+sigmoid(x W_g)_h o_h``, before ``W_o`` (``attention_gate`` ``headwise``).
+
+* a **window** layer (``sliding_attention``) attends a token's own position
+  and the ``sliding_window_size - 1`` before it. Its decode cache is a RING
+  (``models/common.py`` ``RING_LEAVES``): ``window_ring`` positions, the window
+  and the longest chunk a call writes, token ``p`` at ``p mod ring``. What a
+  ring position holds is read off the query's own position (:func:`ring_mask`),
+  so a slot that joins at 0 sees nothing of the ring's last tenant, and
+  nothing is ever zeroed. One token a sequence is absorbed over the whole ring
+  (:func:`window_step`, XLA: a ring is 2 MB a slot); a chunk walks it expanded.
+* an **indexed** layer (``full_attention`` with ``index_topk`` > 0) attends,
+  of the positions at or before a token, the ``index_topk`` with the largest
+  index score ``I(t, s) = sum_j w_j(t) relu(qI_j(t) . kI(s))`` (all of them
+  while there are fewer; equal scores: the lower position), ``qI`` from the
+  query latent, ``kI(s)`` one LayerNormed, partly rotated key a position in
+  a pool of its own beside the latent's (``INDEX_KEY_LEAVES``). The chosen
+  set is found without a sort: the ``index_topk``-th largest score of a row
+  by bisection on the scores' bit patterns (:func:`kth_largest`: 32 counts),
+  then a mask (:func:`chosen_of`). A decode tick scores the slot's live keys
+  (``ops/pallas/sparse_index.py``) and runs the absorbed kernel over the live
+  latent blocks with the unchosen columns masked; a chunk scores one slot's
+  keys for all its queries, and the expanded walk masks each block by them
+  (on the chip a kernel that keeps a step's scores in VMEM,
+  ``ops/pallas/latent_walk.py``; elsewhere :func:`expanded_walk` under
+  ``allow``). Both compute exactly the reference's set.
+
 RoPE rotates the pairs ``(2i, 2i+1)`` (``rope_interleave``) by ``theta``;
 ``rope_scaling`` other than none, and group-limited routing (``n_group`` >
 1), are not built. Neither is the multi-token-prediction module of the
@@ -41,6 +73,7 @@ published checkpoints (``num_nextn_predict_layers``), which never enters
 the language model's logits.
 """
 
+import contextlib
 import dataclasses
 from typing import Any, Optional, Tuple
 
@@ -49,8 +82,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.common import (LatentCache, config_from, dense_init as _init,
-                                         embed_lookup, rms_norm)
+from deepspeed_tpu.models.common import (INDEX_KEY_LEAVES, RING_LEAVES, SPARSE_READS, LatentCache,
+                                         config_from, dense_init as _init, embed_lookup,
+                                         rms_norm)
 from deepspeed_tpu.models.llama import ExpertKernel
 
 
@@ -91,13 +125,73 @@ class DeepseekV3Config:
     # the router keeps every output (``MOELayer.experts_held``)
     experts_held: Optional[Tuple[int, int]] = None
     moe_route_kernel: str = "auto"
+    # layers of more than one kind: a "full_attention" or "sliding_attention"
+    # a layer (None: all full). A sliding layer reads the ``swa_*`` sizes and
+    # attends ``sliding_window_size`` positions, its own included
+    layer_types: Optional[Tuple[str, ...]] = None
+    swa_num_attention_heads: int = 64
+    swa_q_lora_rank: int = 1024
+    swa_kv_lora_rank: int = 1024
+    swa_qk_nope_head_dim: int = 192
+    swa_qk_rope_head_dim: int = 64
+    swa_v_head_dim: int = 128
+    swa_rope_theta: float = 50000.0
+    sliding_window_size: int = 513
+    # positions of a sliding layer's decode cache, a ring (None: as many as
+    # a full layer's pool, which never wraps): at least the window less one
+    # and the longest chunk a call writes (:func:`window_ring_positions`)
+    window_ring: Optional[int] = None
+    # the indexer of the full layers: 0 = none, every position attended
+    index_topk: int = 0
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    mla_lora_rescale: bool = False
+    # None, or "headwise": one sigmoid gate a head from the layer's input
+    attention_gate: Optional[str] = None
+    swa_attention_gate: Optional[str] = None
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     @property
     def latent_width(self) -> int:
-        """Values the cache holds a position a layer."""
+        """Values a full layer's cache holds a position."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def kind_of(self, layer: int) -> "AttentionKind":
+        """The sizes of layer ``layer``'s attention."""
+        kind = self.layer_types[layer] if self.layer_types else "full_attention"
+        if kind == "sliding_attention":
+            return AttentionKind(
+                self.swa_num_attention_heads, self.swa_q_lora_rank, self.swa_kv_lora_rank,
+                self.swa_qk_nope_head_dim, self.swa_qk_rope_head_dim, self.swa_v_head_dim,
+                self.swa_rope_theta, self.swa_attention_gate, window=self.sliding_window_size)
+        if kind != "full_attention":
+            raise NotImplementedError(f"layer_types[{layer}] = {kind!r}: not built")
+        return AttentionKind(self.num_attention_heads, self.q_lora_rank, self.kv_lora_rank,
+                             self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
+                             self.rope_theta, self.attention_gate, top_k=self.index_topk)
+
+
+@dataclasses.dataclass(frozen=True)
+class AttentionKind:
+    """One kind of latent-attention layer: its sizes, and what it attends."""
+    heads: int
+    q_rank: int
+    rank: int
+    d_nope: int
+    d_rope: int
+    d_value: int
+    theta: float
+    gate: Optional[str] = None
+    window: int = 0         # > 0: this position and the ``window - 1`` before it
+    top_k: int = 0          # > 0: the ``top_k`` positions the indexer scores highest
+
+
+def window_ring_positions(window: int, chunk: int, page: int = 128) -> int:
+    """Positions of a window layer's ring for calls of at most ``chunk``
+    tokens: the ``window - 1`` positions the chunk's first query looks back on
+    and the chunk itself, rounded up to whole pages."""
+    return -(-(window - 1 + chunk) // page) * page
 
 
 DEEPSEEK_V3_CONFIGS = {
@@ -111,6 +205,32 @@ DEEPSEEK_V3_CONFIGS = {
         v_head_dim=16, max_position_embeddings=128, attention_key_block=16,
         intermediate_size=96, n_routed_experts=16, num_experts_per_tok=4,
         moe_intermediate_size=32),
+    # dots3-note-prev (dots-studio/dots3-note-prev): the published sizes
+    "dots3-note-prev": dict(
+        vocab_size=152064, hidden_size=5120, num_hidden_layers=46, rms_norm_eps=1e-5,
+        num_attention_heads=128, q_lora_rank=1024, rope_theta=80000000.0,
+        max_position_embeddings=524288, intermediate_size=13824, moe_intermediate_size=1536,
+        routed_scaling_factor=1.0,
+        layer_types=("full_attention",) + ("full_attention",) + 11 * (
+            3 * ("sliding_attention",) + ("full_attention",)),
+        index_topk=2048, mla_lora_rescale=True, attention_gate="headwise",
+        swa_attention_gate="headwise"),
+    # a dense indexed layer, an indexed expert layer and two window layers,
+    # at sizes where the selection and the window both bind inside 128
+    # positions and the ring wraps
+    "dots3-note-test": dict(
+        vocab_size=256, hidden_size=64, num_hidden_layers=4, rms_norm_eps=1e-5,
+        num_attention_heads=4, q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, max_position_embeddings=128, attention_key_block=16,
+        intermediate_size=96, n_routed_experts=16, num_experts_per_tok=4,
+        moe_intermediate_size=32, routed_scaling_factor=1.0,
+        layer_types=("full_attention", "full_attention", "sliding_attention",
+                     "sliding_attention"),
+        swa_num_attention_heads=2, swa_q_lora_rank=40, swa_kv_lora_rank=48,
+        swa_qk_nope_head_dim=24, swa_qk_rope_head_dim=8, swa_v_head_dim=16, swa_rope_theta=5e4,
+        sliding_window_size=17, window_ring=32, index_topk=24, index_n_heads=4,
+        index_head_dim=16, mla_lora_rescale=True, attention_gate="headwise",
+        swa_attention_gate="headwise"),
 }
 
 
@@ -165,7 +285,7 @@ def walk_blocks(start, fed, block: int, positions: int):
     return jnp.minimum(n_blocks, positions // block).astype(jnp.int32)
 
 
-def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int):
+def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int, allow=None):
     """Expanded latent attention of ``l`` queries a sequence against that
     sequence's pool, a sequence and ``block`` key positions at a time.
 
@@ -182,7 +302,13 @@ def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int):
     One block's latent is expanded to the heads' keys and values inside the
     step that attends it, with a running softmax over the blocks: the scores
     in flight are [H, l, block] and nothing the size of the pool is made.
-    Returns [b, l, H, dv] in ``q_nope``'s dtype."""
+    Returns [b, l, H, dv] in ``q_nope``'s dtype.
+
+    ``allow`` replaces the causal mask: ``allow(s, q_pos)`` is called once a
+    sequence and returns ``(state, mask)``, and ``mask(j, k_at, state)`` once a
+    block, ``k_at`` [block] the block's places in the pool, giving ``(which
+    [l, block] bool, state)``: what each query may read there (a window layer's
+    ring, an indexed layer's chosen positions)."""
     b, l, heads, dn = q_nope.shape
     rank = w_kvb.shape[0]
     dv = w_kvb.shape[-1] - dn
@@ -193,9 +319,10 @@ def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int):
 
     def one(s, qn, qr, first, n_blocks):
         q_pos = first + jnp.arange(l)
+        state, mask = ((), None) if allow is None else allow(s, q_pos)
 
         def step(j, carry):
-            m, total, acc = carry
+            m, total, acc, state = carry
             with jax.named_scope("mla_expand"):
                 piece = jax.lax.dynamic_slice(pool, (s, 0, j * block), (1, width, block))[0]
                 # keys and values come out positions minor-most, as the pool
@@ -208,21 +335,24 @@ def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int):
                           + jnp.einsum("qhd,dk->hqk", qr, piece[rank:].astype(dtype),
                                        preferred_element_type=jnp.float32)) * scale
                 k_pos = j * block + jnp.arange(block)
-                scores = jnp.where(k_pos[None, None, :] <= q_pos[None, :, None],
-                                   scores, -jnp.inf)
+                if mask is None:
+                    seen = k_pos[None, :] <= q_pos[:, None]
+                else:
+                    seen, state = mask(j, k_pos, state)
+                scores = jnp.where(seen[None], scores, -jnp.inf)
                 m_new = jnp.maximum(m, scores.max(axis=-1))
                 p = jnp.exp(scores - m_new[..., None])
                 shrink = jnp.exp(m - m_new)
                 acc = acc * shrink[..., None] + jnp.einsum(
                     "hqk,hdk->hqd", p.astype(dtype), kv[:, dn:],
                     preferred_element_type=jnp.float32)
-                return m_new, total * shrink + p.sum(axis=-1), acc
+                return m_new, total * shrink + p.sum(axis=-1), acc, state
 
         # the running maximum starts finite: a block with no key a query may
         # read (its scores all -inf) then adds exactly nothing
         init = (jnp.full((heads, l), -1e30, jnp.float32), jnp.zeros((heads, l), jnp.float32),
-                jnp.zeros((heads, l, dv), jnp.float32))
-        _, total, acc = jax.lax.fori_loop(0, n_blocks, step, init)
+                jnp.zeros((heads, l, dv), jnp.float32), state)
+        _, total, acc, _ = jax.lax.fori_loop(0, n_blocks, step, init)
         out = acc / jnp.maximum(total, jnp.finfo(jnp.float32).tiny)[..., None]
         return jnp.moveaxis(out, 0, 1).astype(dtype)                    # [l, H, dv]
 
@@ -242,7 +372,7 @@ def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int):
     return jax.lax.fori_loop(0, b, sequence, jnp.zeros((b, l, heads, dv), dtype))
 
 
-def absorbed_step(q_nope, q_rope, pool, w_kvb, lengths):
+def absorbed_step(q_nope, q_rope, pool, w_kvb, lengths, chosen=None):
     """Absorbed latent attention of ONE query a sequence over its pool:
     ``q_nope`` [b, H, dn], ``q_rope`` [b, H, dr] (rotated), ``pool`` [b, rank +
     dr, positions] read as it lies, ``lengths`` [b] the positions that hold a
@@ -250,7 +380,8 @@ def absorbed_step(q_nope, q_rope, pool, w_kvb, lengths):
     that counts). The query goes into the latent space (``W_uk``), scores and
     the weighted sum are taken against the latent itself, and the result
     comes out through ``W_uv``: no key or value of any head is ever made.
-    Returns [b, H, dv].
+    ``chosen`` [b, positions] bool (an indexed layer): the live positions the
+    softmax runs over. Returns [b, H, dv].
 
     On a TPU the middle is one kernel (``ops/pallas/latent_decode.py``) that
     reads each live block of the pool once. Elsewhere two matmuls over the
@@ -261,13 +392,13 @@ def absorbed_step(q_nope, q_rope, pool, w_kvb, lengths):
     w_kvb = w_kvb.astype(dtype)
     scale = (dn + q_rope.shape[-1]) ** -0.5
     from deepspeed_tpu.ops.pallas import backend
-    with jax.named_scope("mla_attend_decode"):
+    with jax.named_scope("mla_attend_decode" if chosen is None else "dsa_attend_decode"):
         q_lat = jnp.einsum("bhd,chd->bhc", q_nope, w_kvb[..., :dn])
         if backend.on_tpu():
             from deepspeed_tpu.ops.pallas.latent_decode import latent_decode
-            mixed = latent_decode(q_lat, q_rope, pool, lengths, scale=scale)
+            mixed = latent_decode(q_lat, q_rope, pool, lengths, scale=scale, chosen=chosen)
         else:
-            mixed = _mix_whole_pool(q_lat, q_rope, pool, lengths, scale)
+            mixed = _mix_whole_pool(q_lat, q_rope, pool, lengths, scale, chosen)
         return jnp.einsum("bhc,chd->bhd", mixed.astype(dtype), w_kvb[..., dn:])
 
 
@@ -282,7 +413,7 @@ def absorbed_positions_read(lengths, positions: int):
     return blocks.sum() * block
 
 
-def _mix_whole_pool(q_lat, q_rope, pool, lengths, scale):
+def _mix_whole_pool(q_lat, q_rope, pool, lengths, scale, chosen=None):
     """``latent_decode`` by XLA, in float32: [b, H, rank]."""
     rank = q_lat.shape[-1]
     pool = pool.astype(jnp.float32)
@@ -290,84 +421,383 @@ def _mix_whole_pool(q_lat, q_rope, pool, lengths, scale):
     q_all = jnp.concatenate([q_lat, q_rope], axis=-1).astype(jnp.float32)
     scores = jnp.einsum("bhw,bwp->bhp", q_all, pool, precision="highest") * scale
     live = jnp.arange(pool.shape[-1])[None, :] < lengths[:, None]
+    if chosen is not None:
+        live = live & chosen
     scores = jnp.where(live[:, None, :], scores, jnp.finfo(jnp.float32).min)
     probs = jnp.where(live[:, None, :], jax.nn.softmax(scores, axis=-1), 0.0)
     return jnp.einsum("bhp,bwp->bhw", probs, pool, precision="highest")[..., :rank]
 
 
+def ring_mask(q_pos, k_at, ring: int, window: int):
+    """What queries at ``q_pos`` [l] may read of a ring's places ``k_at`` [n]:
+    place ``r`` holds, for a query at ``t``, position ``t - (t - r) mod
+    ring`` (whatever was written there later lies ahead of ``t``); it is read
+    where that is one of the ``window`` positions ending at ``t`` and not
+    before 0 (never written by this sequence: a former tenant's). [l, n]."""
+    back = (q_pos[:, None] - k_at[None, :]) % ring
+    return (back < window) & (q_pos[:, None] - back >= 0)
+
+
+def window_step(q_nope, q_rope, ring, w_kvb, pos, live, window: int):
+    """Absorbed latent attention of ONE query a sequence over its RING
+    [b, rank + dr, ring]: the query at ``pos`` [b] (already written) reads
+    its window (:func:`ring_mask`); a sequence that is not ``live`` gives
+    zeros. XLA, in the compute type with float32 sums: a ring is two
+    thousandths of a pool. Returns [b, H, dv]."""
+    dn = q_nope.shape[-1]
+    rank = w_kvb.shape[0]
+    dtype = q_nope.dtype
+    w_kvb = w_kvb.astype(dtype)
+    scale = (dn + q_rope.shape[-1]) ** -0.5
+    with jax.named_scope("swa_attend_decode"):
+        q_all = jnp.concatenate(
+            [jnp.einsum("bhd,chd->bhc", q_nope, w_kvb[..., :dn]), q_rope], axis=-1)
+        held = ring.astype(dtype)
+        scores = jnp.einsum("bhw,bwp->bhp", q_all, held,
+                            preferred_element_type=jnp.float32) * scale
+        seen = jax.vmap(lambda t: ring_mask(t[None], jnp.arange(ring.shape[-1]),
+                                            ring.shape[-1], window)[0])(pos)
+        seen = seen & live[:, None]
+        scores = jnp.where(seen[:, None, :], scores, jnp.finfo(jnp.float32).min)
+        probs = jnp.where(seen[:, None, :], jax.nn.softmax(scores, axis=-1), 0.0)
+        mixed = jnp.einsum("bhp,bcp->bhc", probs.astype(dtype), held[:, :rank],
+                           preferred_element_type=jnp.float32)
+        return jnp.einsum("bhc,chd->bhd", mixed.astype(dtype), w_kvb[..., dn:])
+
+
+def index_scores(q, w, keys):
+    """``I(t, s) = sum_j w_j(t) relu(q_j(t) . k(s))`` by XLA: ``q`` [..., l, J,
+    d], ``w`` [..., l, J] float32, ``keys`` [..., d, n] -> [..., l, n] float32.
+    Products in the keys' type with float32 sums, as the kernels make them."""
+    products = jnp.einsum("...ljd,...dn->...ljn", q.astype(keys.dtype), keys,
+                          preferred_element_type=jnp.float32,
+                          precision="highest" if keys.dtype == jnp.float32 else None)
+    return jnp.einsum("...ljn,...lj->...ln", jnp.maximum(products, 0.0),
+                      w.astype(jnp.float32), precision="highest")
+
+
+def _ordered(scores):
+    """float32 scores as uint32 in the same order (-0 as +0), never 0."""
+    scores = jnp.where(scores == 0, 0.0, scores.astype(jnp.float32))
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    bits = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+    return jax.lax.bitcast_convert_type(bits, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+def kth_largest(scores, valid, k: int):
+    """Of each row of ``scores`` [..., n] float32 where ``valid``: ``(keys,
+    bar, quota)``: the scores as ordered uint32 ``keys`` (0 where not
+    valid), the ``k``-th largest valid key ``bar`` [...] (0 where fewer than
+    ``k`` are valid: every valid key is above it), and how many keys EQUAL to
+    the bar belong to the top ``k``, ``quota`` [...]. No sort: the bar is
+    built a bit at a time from the top, each bit one count of the row."""
+    keys = jnp.where(valid, _ordered(scores), jnp.uint32(0))
+
+    def bit(i, bar):
+        raised = bar | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        return jnp.where((keys >= raised[..., None]).sum(axis=-1) >= k, raised, bar)
+
+    bar = jax.lax.fori_loop(0, 32, bit, jnp.zeros(keys.shape[:-1], jnp.uint32))
+    quota = k - (keys > bar[..., None]).sum(axis=-1)
+    return keys, bar, quota.astype(jnp.int32)
+
+
+def chosen_of(keys, bar, quota, tied_before=None):
+    """The top-``k`` set as a mask, from :func:`kth_largest`'s three: every
+    key above the bar, and of those equal to it the first ``quota`` by
+    position. ``keys`` may be a run of columns of the rows ``bar`` / ``quota``
+    were found over: ``tied_before`` [...] is then how many keys equal to the
+    bar lie before the run. Returns ``(chosen, tied so far)``."""
+    tied = (keys == bar[..., None]) & (keys > 0)
+    before = 0 if tied_before is None else tied_before[..., None]
+    rank = before + jnp.cumsum(tied, axis=-1, dtype=jnp.int32)
+    chosen = (keys > bar[..., None]) | (tied & (rank <= quota[..., None]))
+    return chosen, rank[..., -1]
+
+
 class LatentAttention(nn.Module):
-    """Multi-head latent attention; see the module's docstring for the
-    equations and for which of the two forms runs."""
+    """Multi-head latent attention of one ``kind``; see the module's docstring
+    for the equations, for which of the two forms runs, and for what a window
+    layer and an indexed layer attend."""
 
     config: DeepseekV3Config
+    kind: AttentionKind
+
+    def _indexer(self, x, c_q, positions):
+        """The indexer's three: ``q`` [b, l, J, d] and the one key a position
+        ``k`` [b, l, d], the first ``d_rope`` of their ``d`` values rotated as
+        the layer rotates, and the heads' weights ``w`` [b, l, J] float32."""
+        cfg, kind = self.config, self.kind
+        heads, d = cfg.index_n_heads, cfg.index_head_dim
+
+        def rotated(t):
+            return jnp.concatenate([rotate_interleaved(t[..., :kind.d_rope], positions,
+                                                       kind.theta), t[..., kind.d_rope:]], -1)
+
+        q = nn.DenseGeneral(features=(heads, d), axis=-1, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=cfg.param_dtype,
+                            kernel_init=nn.with_logical_partitioning(_init(), (None, None, None)),
+                            name="indexer_q_proj")(c_q)
+        k = nn.LayerNorm(epsilon=cfg.rms_norm_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                         name="indexer_k_norm")(
+            _dense(cfg, d, ("embed", None), "indexer_k_proj")(x))
+        w = _dense(cfg, heads, ("embed", None), "indexer_weights_proj")(x)
+        return rotated(q), rotated(k), w.astype(jnp.float32) * (heads * d) ** -0.5
 
     @nn.compact
     def __call__(self, x, decode: bool = False, fed=None):
-        cfg = self.config
+        cfg, kind = self.config, self.kind
         b, l, _ = x.shape
-        heads, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                             cfg.qk_rope_head_dim, cfg.v_head_dim)
-        rank = cfg.kv_lora_rank
+        heads, dn, dr, dv, rank = kind.heads, kind.d_nope, kind.d_rope, kind.d_value, kind.rank
+        width = rank + dr
+        a_q = (cfg.hidden_size / kind.q_rank) ** 0.5 if cfg.mla_lora_rescale else 1.0
+        a_kv = (cfg.hidden_size / rank) ** 0.5 if cfg.mla_lora_rescale else 1.0
 
         with jax.named_scope("mla_q"):
             c_q = RMSNorm(cfg, name="q_a_layernorm")(
-                _dense(cfg, cfg.q_lora_rank, ("embed", None), "q_a_proj")(x))
+                _dense(cfg, kind.q_rank, ("embed", None), "q_a_proj")(x))
+            if a_q != 1.0:
+                c_q = (c_q * a_q).astype(c_q.dtype)
             q = nn.DenseGeneral(features=(heads, dn + dr), axis=-1, use_bias=False,
                                 dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                                 kernel_init=nn.with_logical_partitioning(
                                     _init(), (None, "heads", "kv")), name="q_b_proj")(c_q)
         with jax.named_scope("mla_latent"):
-            joint = _dense(cfg, rank + dr, ("embed", None), "kv_a_proj_with_mqa")(x)
+            joint = _dense(cfg, width, ("embed", None), "kv_a_proj_with_mqa")(x)
             c_kv = RMSNorm(cfg, name="kv_a_layernorm")(joint[..., :rank])
+            if a_kv != 1.0:
+                c_kv = (c_kv * a_kv).astype(c_kv.dtype)
         w_kvb = _unboxed(self.param(
             "kv_b_proj", nn.with_logical_partitioning(_init(), (None, "heads", "kv")),
             (rank, heads, dn + dv), cfg.param_dtype))
 
         start = None
+        extent = cfg.decode_cache_len or cfg.max_position_embeddings
         if decode:
-            cache = LatentCache(self, b, cfg.decode_cache_len or cfg.max_position_embeddings,
-                                cfg.latent_width, c_kv.dtype)
+            if kind.window:
+                cache = LatentCache(self, b, cfg.window_ring or extent, width, c_kv.dtype,
+                                    name=RING_LEAVES[0], ring=True)
+            else:
+                cache = LatentCache(self, b, extent, width, c_kv.dtype)
             first = cache.index.value
             positions = (first[:, None] if cache.per_slot else first) + jnp.arange(l)[None, :]
             positions = jnp.broadcast_to(positions, (b, l))
         else:
             positions = jnp.broadcast_to(jnp.arange(l)[None, :], (b, l))
         q_nope = q[..., :dn]
-        q_rope = rotate_interleaved(q[..., dn:], positions, cfg.rope_theta)
+        q_rope = rotate_interleaved(q[..., dn:], positions, kind.theta)
         latent = jnp.concatenate(
-            [c_kv, rotate_interleaved(joint[..., rank:], positions, cfg.rope_theta)], axis=-1)
+            [c_kv, rotate_interleaved(joint[..., rank:], positions, kind.theta)], axis=-1)
+        if kind.top_k:
+            with jax.named_scope("dsa_index"):
+                index_q, index_k, index_w = self._indexer(x, c_q, positions)
 
+        keys = None
         if decode:
-            pool, start = cache.append(latent)
             fed = jnp.full((b,), l, jnp.int32) if fed is None else fed
+            if kind.window and l > cache.positions - (kind.window - 1):
+                raise ValueError(
+                    f"a call of {l} tokens over a ring of {cache.positions} positions "
+                    f"overwrites what its first query's window of {kind.window} still reads: "
+                    f"window_ring must be window_ring_positions(window, the longest chunk)")
+            if kind.top_k:
+                keys, _ = LatentCache(self, b, extent, cfg.index_head_dim, index_k.dtype,
+                                      name=INDEX_KEY_LEAVES[0], index=cache.index).append(
+                                          index_k, advance=False)
+            pool, start = cache.append(latent, live=fed > 0)
+        counts = {}
         if decode and l == 1:
             lengths = jnp.where(fed > 0, start + 1, 0)
-            out = absorbed_step(q_nope[:, 0], q_rope[:, 0], pool, w_kvb, lengths)[:, None]
-            read = absorbed_positions_read(lengths, pool.shape[-1])
+            if kind.window:
+                out = window_step(q_nope[:, 0], q_rope[:, 0], pool, w_kvb, start, fed > 0,
+                                  kind.window)[:, None]
+                counts = {"swa_ring_positions_read": (fed > 0).sum() * pool.shape[-1],
+                          "swa_ring_positions_live": jnp.minimum(lengths, kind.window).sum()}
+            else:
+                chosen = None
+                if kind.top_k:
+                    chosen, counts = self._chosen_decode(index_q[:, 0], index_w[:, 0], keys,
+                                                         lengths)
+                out = absorbed_step(q_nope[:, 0], q_rope[:, 0], pool, w_kvb, lengths,
+                                    chosen)[:, None]
+                read = absorbed_positions_read(lengths, pool.shape[-1])
         else:
             block = cfg.attention_key_block
             if not decode:
                 # the sequence itself as a pool, padded out to whole blocks
                 block = min(block, l)
-                pool = jnp.pad(jnp.swapaxes(latent, 1, 2),
-                               [(0, 0), (0, 0), (0, -l % block)])
+                as_pool = lambda t: jnp.pad(jnp.swapaxes(t, 1, 2),  # noqa: E731
+                                            [(0, 0), (0, 0), (0, -l % block)])
+                pool = as_pool(latent)
+                keys = as_pool(index_k) if kind.top_k else None
             elif pool.shape[-1] % block:
                 block = pool.shape[-1]
-            out = expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block)
+            allow = None
+            if kind.window:
+                places, window = pool.shape[-1], kind.window
+                allow = lambda s, q_pos: ((), lambda j, k_at, state: (  # noqa: E731
+                    ring_mask(q_pos, k_at, places, window), state))
+            elif kind.top_k:
+                allow = self._chosen_chunk(index_q, index_w, keys, start, block)
+            with (jax.named_scope("swa_attend_prefill") if kind.window else
+                  jax.named_scope("dsa_attend_prefill") if kind.top_k
+                  else contextlib.nullcontext()):
+                if kind.top_k and self._walks_in_kernel(l, pool, start):
+                    out = self._walk_chosen(q_nope, q_rope, index_q, index_w, pool, keys, w_kvb,
+                                            start, fed)
+                else:
+                    out = expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block, allow)
             if decode:
                 read = walk_blocks(start, fed, block, pool.shape[-1]).sum() * block
+                ends = jnp.where(fed > 0, start + fed, 0)
+                if kind.window:
+                    counts = {"swa_ring_positions_read": read,
+                              "swa_ring_positions_live": jnp.minimum(ends, fed + kind.window - 1).sum()}
+                elif kind.top_k:
+                    from deepspeed_tpu.ops.pallas.sparse_index import chunk_blocks
+                    blocks, size = chunk_blocks(ends, keys.shape[-1])
+                    each = start[:, None] + 1 + jnp.arange(l)[None, :]          # [b, l]
+                    real = jnp.arange(l)[None, :] < fed[:, None]
+                    counts = {"dsa_index_keys_read": blocks.sum() * size,
+                              "dsa_positions_selected": jnp.where(
+                                  real, jnp.minimum(each, kind.top_k), 0).sum(),
+                              "dsa_positions_live": jnp.where(real, each, 0).sum()}
         if decode:
             # for the host, beside a serving tick's tokens: positions of the
             # pool the loops above were bounded to, positions that hold a
             # token, bytes written
-            live = jnp.where(fed > 0, jnp.minimum(start + fed, pool.shape[-1]), 0).sum()
-            written = fed.sum() * (cfg.latent_width * jnp.dtype(latent.dtype).itemsize)
-            self.variable("cache", "latent_reads", jnp.zeros, (3,), jnp.int32).value = (
-                jnp.stack([read, live, written]).astype(jnp.int32))
+            wide = jnp.dtype(latent.dtype).itemsize
+            if not kind.window:
+                live = jnp.where(fed > 0, jnp.minimum(start + fed, pool.shape[-1]), 0).sum()
+                written = fed.sum() * (width * wide)
+                self.variable("cache", "latent_reads", jnp.zeros, (3,), jnp.int32).value = (
+                    jnp.stack([read, live, written]).astype(jnp.int32))
+            if kind.window or kind.top_k:
+                counts["swa_ring_bytes_written" if kind.window else "dsa_latent_bytes_written"] = fed.sum() * width * wide
+                if kind.top_k:
+                    counts["dsa_index_key_bytes_written"] = fed.sum() * cfg.index_head_dim * wide
+                self.variable("cache", "sparse_reads", jnp.zeros, (len(SPARSE_READS),),
+                              jnp.int32).value = jnp.stack(
+                    [jnp.asarray(counts.get(name, 0), jnp.int32) for name in SPARSE_READS])
+        if kind.gate:
+            if kind.gate != "headwise":
+                raise NotImplementedError(f"attention gate {kind.gate!r}: only headwise is built")
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(_dense(cfg, heads, ("embed", "heads"), "gate_proj")(x))
+                out = out * gate[..., None].astype(out.dtype)
         return nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                                kernel_init=nn.with_logical_partitioning(
                                    _init(), ("heads", "kv", "embed")), name="o_proj")(out)
+
+    def _chosen_decode(self, q, w, keys, lengths):
+        """One query a sequence: ``(chosen [b, positions] bool, counts)``, the
+        ``top_k`` live positions its index scores put first."""
+        from deepspeed_tpu.ops.pallas import backend
+        top_k, positions = self.kind.top_k, keys.shape[-1]
+        with jax.named_scope("dsa_index"):
+            if backend.on_tpu():
+                from deepspeed_tpu.ops.pallas.latent_decode import blocks_read
+                from deepspeed_tpu.ops.pallas.sparse_index import BLOCK, index_scores_decode
+                scores = index_scores_decode(q, w, keys, lengths)
+                blocks, block = blocks_read(lengths, positions, BLOCK)
+                keys_read = blocks.sum() * block
+            else:
+                scores = index_scores(q[:, None], w[:, None], keys)[:, 0]
+                keys_read = (lengths > 0).sum() * positions
+        with jax.named_scope("dsa_select"):
+            live = jnp.arange(positions)[None, :] < lengths[:, None]
+            chosen, _ = chosen_of(*kth_largest(scores, live, top_k))
+        self.sow("intermediates", "dsa_chosen", chosen[:, None])
+        return chosen, {"dsa_index_keys_read": keys_read,
+                        "dsa_positions_selected": jnp.minimum(lengths, top_k).sum(),
+                        "dsa_positions_live": lengths.sum()}
+
+    def _walks_in_kernel(self, l: int, pool, start) -> bool:
+        """Whether a chunk of an indexed layer over a serving pool runs as
+        the chip's three kernels (scores, then the walk) a slot."""
+        from deepspeed_tpu.ops.pallas import backend, latent_walk, sparse_index
+        return bool(backend.on_tpu() and start is not None and sparse_index.chunk_tile(l)
+                    and latent_walk.takes(l, self.kind.heads, pool.shape[-1]))
+
+    def _walk_chosen(self, q_nope, q_rope, index_q, index_w, pool, keys, w_kvb, start, fed):
+        """A chunk of an indexed layer on the chip, a fed slot at a time: the
+        slot's index scores (``sparse_index.index_scores_chunk``), each query's
+        bar, the mask of the chosen over the slot's positions, and the
+        expanded walk under it (``latent_walk.selected_walk``: the scores stay
+        in VMEM). A slot that is fed nothing reads nothing and gives zeros.
+        The numbers are :func:`expanded_walk`'s under :meth:`_chosen_chunk`."""
+        from deepspeed_tpu.ops.pallas import latent_walk, sparse_index
+        kind = self.kind
+        b, l = q_nope.shape[:2]
+        positions, dtype = pool.shape[-1], q_nope.dtype
+        scale = (kind.d_nope + kind.d_rope) ** -0.5
+        heads_first = lambda t: jnp.moveaxis(t, 2, 1)  # noqa: E731  [b, H, l, d]
+        q_nope, q_rope = heads_first(q_nope), heads_first(q_rope)
+        w_k = jnp.transpose(w_kvb[..., :kind.d_nope], (1, 2, 0)).astype(dtype)     # [H, dn, rank]
+        w_v = jnp.transpose(w_kvb[..., kind.d_nope:], (1, 2, 0)).astype(dtype)
+        index_q = index_q.reshape(b, l, -1)
+        slot_of = lambda t, s: jax.lax.dynamic_index_in_dim(t, s, 0, keepdims=False)  # noqa: E731
+
+        def attend(s):
+            q_pos, live = start[s] + jnp.arange(l), start[s] + fed[s]
+            with jax.named_scope("dsa_index"):
+                blocks, _ = sparse_index.chunk_blocks(live, positions)
+                scores = sparse_index.index_scores_chunk(slot_of(index_q, s), slot_of(index_w, s),
+                                                         keys, s, blocks)
+            with jax.named_scope("dsa_select"):
+                valid = jnp.arange(positions)[None, :] <= q_pos[:, None]
+                ordered, bar, quota = kth_largest(scores, valid, kind.top_k)
+                tied = ((ordered == bar[:, None]) & (ordered > 0)).sum(axis=-1)
+                # every key equal to the bar is chosen unless more are tied
+                # than the top k has room for: only then are they ranked
+                may = jax.lax.cond(
+                    (tied > quota).any(), lambda: chosen_of(ordered, bar, quota)[0],
+                    lambda: ordered >= jnp.maximum(bar, jnp.uint32(1))[:, None])
+            blocks, _ = latent_walk.walk_blocks(live, positions)
+            out = latent_walk.selected_walk(slot_of(q_nope, s), slot_of(q_rope, s), w_k, w_v, pool,
+                                            may, s, blocks, scale=scale)
+            return jnp.moveaxis(out, 0, 1)                                   # [l, H, dv]
+
+        def sequence(s, out):
+            rows = jax.lax.cond(fed[s] > 0, attend,
+                                lambda s: jnp.zeros((l, kind.heads, kind.d_value), dtype), s)
+            return jax.lax.dynamic_update_index_in_dim(out, rows, s, 0)
+
+        return jax.lax.fori_loop(0, b, sequence,
+                                 jnp.zeros((b, l, kind.heads, kind.d_value), dtype))
+
+    def _chosen_chunk(self, q, w, keys, start, block: int):
+        """The ``allow`` of :func:`expanded_walk` for a chunk of an indexed
+        layer, by XLA (off the chip; on it :meth:`_walk_chosen`): once a
+        sequence, the index scores of its ``l`` queries over its keys (one
+        slot's, never every slot's at once) and each query's bar; once a
+        block, the mask of the chosen among the block's positions."""
+        top_k, positions = self.kind.top_k, keys.shape[-1]
+        b, l = q.shape[:2]
+        slot_of = lambda t, s: jax.lax.dynamic_index_in_dim(t, s, 0, keepdims=False)  # noqa: E731
+
+        def allow(s, q_pos):
+            with jax.named_scope("dsa_index"):
+                scores = index_scores(slot_of(q, s), slot_of(w, s), slot_of(keys, s))
+            with jax.named_scope("dsa_select"):
+                valid = jnp.arange(positions)[None, :] <= q_pos[:, None]
+                ordered, bar, quota = kth_largest(scores, valid, top_k)
+
+            def mask(j, k_at, tied):
+                run = jax.lax.dynamic_slice(ordered, (0, j * block), (l, block))
+                return chosen_of(run, bar, quota, tied)
+
+            return jnp.zeros((l,), jnp.int32), mask
+
+        if self.is_mutable_collection("intermediates"):
+            # for the tests: every query's chosen set over the whole pool
+            first = jnp.zeros((b,), jnp.int32) if start is None else start
+            valid = (jnp.arange(positions)[None, None, :]
+                     <= (first[:, None] + jnp.arange(l)[None, :])[..., None])
+            self.sow("intermediates", "dsa_chosen",
+                     chosen_of(*kth_largest(index_scores(q, w, keys), valid, top_k))[0])
+        return allow
 
 
 class SwiGLU(nn.Module):
@@ -421,11 +851,12 @@ def _expert_layer(cfg: DeepseekV3Config, name: str):
 class DeepseekV3Block(nn.Module):
     config: DeepseekV3Config
     sparse: bool
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, decode: bool = False, fed=None, used=None):
         cfg = self.config
-        x = x + LatentAttention(cfg, name="self_attn")(
+        x = x + LatentAttention(cfg, cfg.kind_of(self.layer), name="self_attn")(
             RMSNorm(cfg, name="input_layernorm")(x), decode, fed).astype(x.dtype)
         h = RMSNorm(cfg, name="post_attention_layernorm")(x)
         if self.sparse:
@@ -446,6 +877,9 @@ class DeepseekV3ForCausalLM(nn.Module):
         cfg = self.config
         if cfg.n_group != 1 or cfg.topk_group != 1:
             raise NotImplementedError("group-limited routing (n_group > 1) is not built")
+        if cfg.layer_types is not None and len(cfg.layer_types) != cfg.num_hidden_layers:
+            raise ValueError(f"layer_types names {len(cfg.layer_types)} layers of "
+                             f"{cfg.num_hidden_layers}")
         bsz, l = input_ids.shape
         wte = _unboxed(self.param("embed_tokens",
                                   nn.with_logical_partitioning(_init(), ("vocab", "embed")),
@@ -470,7 +904,7 @@ class DeepseekV3ForCausalLM(nn.Module):
                 used = (jnp.arange(l)[None, :] < fed[:, None]).reshape(-1)
             index.value = index.value + l
         for i in range(cfg.num_hidden_layers):
-            x = DeepseekV3Block(cfg, i >= cfg.first_k_dense_replace, name=f"layers_{i}")(
+            x = DeepseekV3Block(cfg, i >= cfg.first_k_dense_replace, i, name=f"layers_{i}")(
                 x, decode, fed, used)
         x = RMSNorm(cfg, name="norm")(x)
         return _dense(cfg, cfg.vocab_size, ("embed", "vocab"), "lm_head")(x)

@@ -40,8 +40,13 @@ def _dot(x, pool_rows, dims):
                                preferred_element_type=jnp.float32)
 
 
+def _selected_kernel(lens_ref, q_lat_ref, q_rope_ref, pool_ref, chosen_ref, *rest, **sizes):
+    """:func:`_kernel` over the positions ``chosen_ref`` [1, block] marks."""
+    _kernel(lens_ref, q_lat_ref, q_rope_ref, pool_ref, *rest, chosen_ref=chosen_ref, **sizes)
+
+
 def _kernel(lens_ref, q_lat_ref, q_rope_ref, pool_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale, rank, block, n_blocks):
+            scale, rank, block, n_blocks, chosen_ref=None):
     s_i, j = pl.program_id(0), pl.program_id(1)
     length = lens_ref[s_i]
 
@@ -57,6 +62,8 @@ def _kernel(lens_ref, q_lat_ref, q_rope_ref, pool_ref, o_ref, m_ref, l_ref, acc_
         scores = (_dot(q_lat_ref[...], latent, _NN)
                   + _dot(q_rope_ref[...], pool_ref[rank:, :], _NN)) * scale
         live = j * block + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) < length
+        if chosen_ref is not None:
+            live = live & (chosen_ref[...] > 0)
         scores = jnp.where(live, scores, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, scores.max(axis=-1, keepdims=True))
@@ -82,14 +89,21 @@ def blocks_read(lengths, positions: int, block: int = BLOCK):
 
 
 def latent_decode(q_lat, q_rope, pool, lengths, *, scale: float, block: int = BLOCK,
-                  interpret=None):
+                  interpret=None, chosen=None):
     """``softmax(scale x [q_lat ; q_rope] . pool[:, :length]) pool[:rank]^T``
     a sequence: ``q_lat`` [b, H, rank] (the queries already in the latent
     space) and ``q_rope`` [b, H, rope], ``pool`` [b, rank + rope, positions] as
     it is stored, ``lengths`` [b] positions that hold a token (0: read
     nothing, give zeros). Queries and probabilities meet the pool in the
     pool's type; statistics and sums are float32. Returns the heads' mixed
-    latents [b, H, rank] in float32."""
+    latents [b, H, rank] in float32.
+
+    ``chosen`` [b, positions] bool (an indexed layer's selection): the softmax
+    runs over the live positions it marks and no other. The pool's live
+    blocks are read as before, a position's column masked where it is not
+    chosen (a block comes into VMEM whole, and 2,048 chosen of 16,000 live
+    leave no 1,024-position block without one); the kernel is then named
+    ``dsa_decode``."""
     b, heads, rank = q_lat.shape
     width, positions = pool.shape[1:]
     block = min(block, positions)
@@ -105,20 +119,25 @@ def latent_decode(q_lat, q_rope, pool, lengths, *, scale: float, block: int = BL
         return (s, 0, jnp.minimum(j, (jnp.maximum(lens[s], 1) - 1) // block))
 
     query = lambda s, j, lens: (s, 0, 0)  # noqa: E731
-    kernel = functools.partial(_kernel, scale=float(scale), rank=rank, block=block,
-                               n_blocks=n_blocks)
+    kernel = functools.partial(_kernel if chosen is None else _selected_kernel,
+                               scale=float(scale), rank=rank, block=block, n_blocks=n_blocks)
+    operands = (lengths, q_lat.astype(pool.dtype), q_rope.astype(pool.dtype), pool)
+    in_specs = [pl.BlockSpec((None, heads, rank), query),
+                pl.BlockSpec((None, heads, width - rank), query),
+                pl.BlockSpec((None, width, block), pool_block)]
+    if chosen is not None:
+        operands += (chosen.astype(jnp.float32)[:, None, :],)
+        in_specs.append(pl.BlockSpec((None, 1, block), pool_block))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(b, n_blocks),
-            in_specs=[pl.BlockSpec((None, heads, rank), query),
-                      pl.BlockSpec((None, heads, width - rank), query),
-                      pl.BlockSpec((None, width, block), pool_block)],
+            in_specs=in_specs,
             out_specs=pl.BlockSpec((None, heads, rank), query),
             scratch_shapes=[pltpu.VMEM((heads, 1), jnp.float32),
                             pltpu.VMEM((heads, 1), jnp.float32),
                             pltpu.VMEM((heads, rank), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((b, heads, rank), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret, name="mla_decode",
-    )(lengths, q_lat.astype(pool.dtype), q_rope.astype(pool.dtype), pool)
+        interpret=interpret, name="mla_decode" if chosen is None else "dsa_decode",
+    )(*operands)

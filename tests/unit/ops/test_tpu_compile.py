@@ -447,6 +447,105 @@ def test_joyai_llm_flash_serving_program_reads_the_latent_pool_as_it_lies(one_ch
 
 
 # ---------------------------------------------------------------------------
+# indexed and window layers of latent attention (ISSUE 37)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kernel", ["index_decode", "index_chunk", "selected_decode",
+                                    "selected_walk"])
+def test_sparse_attention_kernels_compile(one_chip, kernel):
+    """The four kernels of an indexed layer at the long-context cell's
+    shapes: 32 slots x 32,768 positions, 64 index heads of 128, 128 heads over
+    a 576-wide latent, a chunk of 256 queries of one slot."""
+    from deepspeed_tpu.ops.pallas import latent_decode, latent_walk, sparse_index
+    slots, positions, heads, d = 32, 32768, 64, 128
+    lengths = _shape(slots, dtype=jnp.int32)
+    if kernel == "index_decode":
+        compiled = _compile(sparse_index.index_scores_decode, one_chip, _shape(slots, heads, d),
+                            _shape(slots, heads, dtype=jnp.float32), _shape(slots, d, positions),
+                            lengths)
+    elif kernel == "index_chunk":
+        compiled = _compile(
+            lambda q, w, keys, slot, n: sparse_index.index_scores_chunk(q, w, keys, slot, n),
+            one_chip, _shape(256, heads * d), _shape(256, heads, dtype=jnp.float32),
+            _shape(slots, d, positions), _shape(dtype=jnp.int32), _shape(dtype=jnp.int32))
+    elif kernel == "selected_walk":
+        compiled = _compile(
+            lambda qn, qr, wk, wv, pool, may, slot, n: latent_walk.selected_walk(
+                qn, qr, wk, wv, pool, may, slot, n, scale=0.07),
+            one_chip, _shape(128, 256, 128), _shape(128, 256, 64), _shape(128, 128, 512),
+            _shape(128, 128, 512), _shape(slots, 576, positions),
+            _shape(256, positions, dtype=jnp.float32), _shape(dtype=jnp.int32),
+            _shape(dtype=jnp.int32))
+    else:
+        compiled = _compile(
+            lambda q, r, pool, n, chosen: latent_decode.latent_decode(q, r, pool, n, scale=0.07,
+                                                                      chosen=chosen),
+            one_chip, _shape(slots, 128, 512), _shape(slots, 128, 64),
+            _shape(slots, 576, positions), lengths, _shape(slots, positions, dtype=jnp.bool_))
+    _kernel_text(compiled)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_dots3_note_serving_program_keeps_a_ring_and_scores_a_slot_at_a_time(one_chip, program):
+    """The long-context cell's programs at the published widths, its 32 slots
+    and two layers, one of each kind (the dense indexed one; a window layer
+    with 32 of 256 experts held): the full layer's latent and index keys over
+    32,768 positions, the window layer's latent over a ring of 768 (513 - 1 +
+    a chunk of 256), all donated and written in place. The decode tick scores
+    and attends in kernels and holds a hundredth of a pool in temporaries.
+    The prefill tick scores one slot's keys at a time: nothing near the
+    [slots, chunk, positions] index scores (1.07 GB a layer) is held, and
+    its temporaries are those of the held route's largest row buffer at
+    hidden 5,120."""
+    import flax.linen as nn
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step,
+                                                          make_apply_fn, make_slot_cache)
+    from deepspeed_tpu.models.deepseek_v3 import (DeepseekV3ForCausalLM, get_deepseek_v3_config,
+                                                  window_ring_positions)
+
+    slots, chunk, positions = 32, 256, 32768
+    ring = window_ring_positions(513, chunk)
+    assert ring == 768
+    module = DeepseekV3ForCausalLM(get_deepseek_v3_config(
+        "dots3-note-prev", num_hidden_layers=2,
+        layer_types=("full_attention", "sliding_attention"), vocab_size=19008,
+        experts_held=(0, 32), decode_cache_len=positions, window_ring=ring, dtype=bf16,
+        param_dtype=bf16))
+    params = jax.eval_shape(
+        lambda key: nn.meta.unbox(module.init(key, jnp.zeros((1, 8), jnp.int32))["params"]),
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots))
+    shapes = {path[-1].key: (leaf.shape, leaf.dtype)
+              for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0] if leaf.ndim == 4}
+    assert shapes == {"cached_latent": ((slots, 1, 576, positions), bf16),
+                      "cached_index_key": ((slots, 1, 128, positions), bf16),
+                      "cached_window_latent": ((slots, 1, 1088, ring), bf16)}
+    apply_fn = make_apply_fn(module)
+    if program == "prefill":
+        step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, chunk, dtype=jnp.int32),
+                    _shape(slots, dtype=jnp.int32))
+    else:
+        step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32),)
+    compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
+    pool_bytes = slots * 576 * positions * 2
+    assert not _relayouts(compiled, slots * 576 * positions)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes + slots * (128 * positions + 1088 * ring) * 2
+    text = compiled.as_text()
+    if program == "decode":
+        assert text.count("%dsa_index_decode") >= 1 and text.count("%dsa_decode") >= 1
+        assert memory.temp_size_in_bytes < pool_bytes // 30          # compiles to 37 MB
+    else:
+        assert text.count("%dsa_index_prefill") >= 1 and text.count("%dsa_prefill_walk") >= 1
+        # 65,536 copies of 5,120 in float32 twice (the held route's last rung) and
+        # the dense layer's 13,824-wide activations; compiles to 2.97 GB
+        assert memory.temp_size_in_bytes < 3.2e9
+        print("prefill temporaries", memory.temp_size_in_bytes)
+
+
+# ---------------------------------------------------------------------------
 # a prefill rung: the prefill program over a quarter of the slots (ISSUE 33)
 # ---------------------------------------------------------------------------
 def _write_loop_trip_counts(compiled):

@@ -1,0 +1,128 @@
+"""Controls for the dots3-note-prev serving cell's reference check: does the
+comparison that decides ``correct`` refuse a server computed below the
+precision the configuration states, and one that attends the WRONG positions?
+
+As ``tools/joyai_llm_flash_controls.py``: each control stands **in the
+program's place**, a server built exactly as the cell builds it
+(``benchmarks/runners/serve.py::_server``) with one thing changed, serving the
+cell's two checked requests through chunked prefill and decode, then held to
+the plain reference over the configuration's own weights by the runner's own
+``_compare_with_reference``: the ``ok`` printed is the ``correct`` the cell
+would have reported for that server.
+
+* ``program``: the server as it is.
+* ``fp8_weights``: the server's matrices rounded to float8 e4m3 (and back to
+  the served type): the nearest precision below the stated bfloat16.
+* ``last_positions``: the full layers attend the LAST ``index_topk``
+  positions at or before a query in place of the indexer's choice (both
+  ticks: the package's ``kth_largest`` is handed each position's number for
+  its score). Every other number is the program's: what this control moves is
+  which 2,048 of up to 6,128 latents a query reads, and nothing else.
+
+Each line also carries what the checked requests' ticks chose
+(``selected_pct``: positions attended over positions live on the full
+layers, the program's device-side counts).
+
+    python3 tools/dots3_note_controls.py --seed <n> [<n> ...] [--control <name> ...]
+
+Prints one JSON line a seed and control. Runs on whatever device JAX finds;
+the numbers that count are the chip's.
+"""
+
+import argparse
+import contextlib
+import gc
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "tools"))
+
+CONTROLS = ("program", "fp8_weights", "last_positions")
+WORKLOAD = "serve-dots3-note-prev-longctx-sat"
+
+
+@contextlib.contextmanager
+def last_positions_chosen():
+    """The package's selection with every position's own number for its
+    index score: the ``top_k`` largest are the last ``top_k``."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.models import deepseek_v3 as package
+
+    plain = package.kth_largest
+
+    def by_position(scores, valid, k):
+        places = jnp.arange(scores.shape[-1], dtype=jnp.float32)
+        return plain(jnp.broadcast_to(places, scores.shape), valid, k)
+
+    package.kth_largest = by_position
+    try:
+        yield
+    finally:
+        package.kth_largest = plain
+
+
+def run_control(cell, seed, control):
+    """One server, one comparison: the line's fields."""
+    import jax
+    import jax.numpy as jnp
+    from benchmarks.lib import harness
+    from deepspeed_tpu.utils import trace
+    from nemotron_h_controls import fp8_family
+
+    family, runner = cell.family, cell.runner
+    gc.collect()    # an earlier control's server: 8.2 GB of weights and 3.1 of cache do not fit twice
+    t0 = time.time()
+    env = harness.Env(seed, 0, 0, harness.Setup(t0), jax.devices()[:1], harness.Tracer(False, ""))
+    before = dict(trace.recorder().counters)
+    changed = last_positions_chosen() if control == "last_positions" else contextlib.nullcontext()
+    with changed:       # the programs are traced in warm-up, under the change
+        engine, sched = runner._server(cell, env, fp8_family(family) if control == "fp8_weights"
+                                       else family)
+        sched.warmup()
+        reqs = runner._checked_requests(cell, env, sched)
+    counted = {k: v - before.get(k, 0) for k, v in trace.recorder().counters.items()}
+    line = {"seed": seed, "control": control}
+    live = sum(counted.get(f"dsa_positions_live_{kind}", 0) for kind in ("prefill", "decode"))
+    if live:
+        line["selected_pct"] = 100.0 * sum(counted.get(f"dsa_positions_selected_{kind}", 0)
+                                           for kind in ("prefill", "decode")) / live
+    if control == "fp8_weights":
+        # show that the rounding was made (rounding again changes nothing), then
+        # let the reference read the configuration's own weights, not this server's
+        head = family.to_reference(engine.params)["head"]
+        line["weights_are_fp8_values"] = bool(
+            (head.astype(jnp.float8_e4m3fn).astype(head.dtype) == head).all())
+        del engine, sched, head
+        gc.collect()
+        engine, sched = runner._server(cell, env, family)
+    del sched
+    gc.collect()
+    line.update(runner._compare_with_reference(cell, family, engine, reqs))
+    line.update(device=jax.devices()[0].device_kind, seconds=round(time.time() - t0, 1))
+    return line
+
+
+def main(argv):
+    parser = argparse.ArgumentParser()
+    parser.add_argument("--workload", default=WORKLOAD)
+    parser.add_argument("--seed", type=int, nargs="+", required=True)
+    parser.add_argument("--control", nargs="+", default=list(CONTROLS), choices=CONTROLS)
+    parser.add_argument("--root", default=ROOT)
+    args = parser.parse_args(argv)
+
+    from benchmarks.lib import harness
+    from envutil import use_compile_cache
+
+    use_compile_cache()
+    cell = harness.Cell(args.root, harness.load_json(args.root, "BENCHMARK.json"), args.workload)
+    for seed in args.seed:
+        for control in args.control:
+            print(json.dumps(run_control(cell, seed, control)), flush=True)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:])
