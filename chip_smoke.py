@@ -419,6 +419,21 @@ def kernels_phase(batch=4, seq=1024, heads=16, head_dim=64, width=1024):
     check("sparse_bwd", _run_kernel(grads(sq_loss(sparse)), qs, ks, vs),
            _reference(grads(sq_loss(dense)), qs, ks, vs), ulps=8)
 
+    # an indexed layer's selection: the k largest of each row's scores below its
+    # bound, ties to the lower position, over the blocks that hold scores: the
+    # kernel's mask against the bisection's and the ranking's, entry for entry
+    from deepspeed_tpu.models.deepseek_v3 import chosen_of, kth_largest
+    from deepspeed_tpu.ops.pallas.sparse_select import select_top_k
+    rows, extent, written, top = 32, 8 * seq, 3 * seq, seq // 4
+    scores = jnp.round(normal(rows, extent, dtype=jnp.float32) * 64) / 64    # ties at the bar
+    bound = jnp.asarray(rng.integers(1, extent, (rows,)), jnp.int32)
+    valid = jnp.arange(extent)[None, :] < jnp.minimum(bound, written)[:, None]
+    got = _run_kernel(lambda s, b: select_top_k(s, b, jnp.full((1,), 3), top, block=seq),
+                      scores, bound)
+    want, _ = jax.jit(lambda s, v: chosen_of(*kth_largest(s, v, top)))(scores, valid)
+    check("dsa_select", jnp.where(jnp.arange(extent) < written, got, 0.0),
+          want.astype(jnp.float32), ulps=0)
+
     obs = dict(compiled=_kernels_compiled(), worst_bf16_roundings=worst)
     _emit("kernels", **obs)
     return obs

@@ -84,12 +84,14 @@ COUNTER_LEAVES = ("moe_rows", "latent_reads", "sparse_reads")
 #: host counts them by (by the kind of tick, but for the bytes ``_written``).
 #: An indexed layer fills the ``dsa_`` ones (positions of the index-key pool its
 #: scores were bounded to; over its real queries, the positions each attended
-#: and the positions each could have, its own included); a window layer the
+#: and the positions each could have, its own included; the scores its
+#: selection was bounded to, rows times positions); a window layer the
 #: ``swa_`` ones (positions of the ring its attention read; of those, the ones
 #: inside some real query's window)
 SPARSE_READS = ("dsa_index_keys_read", "dsa_positions_selected", "dsa_positions_live",
                 "swa_ring_positions_read", "swa_ring_positions_live", "dsa_latent_bytes_written",
-                "dsa_index_key_bytes_written", "swa_ring_bytes_written")
+                "dsa_index_key_bytes_written", "swa_ring_bytes_written",
+                "dsa_select_positions_read")
 #: a serving program that runs fewer sequences than the cache has slots (a
 #: rung of ``serving/programs.py``'s prefill ladder) says which slot each
 #: sequence is: ``cache_slots`` [n] int32, distinct, which the program lays

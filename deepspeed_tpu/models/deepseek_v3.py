@@ -58,7 +58,9 @@ sigmoid(x W_g)_h o_h``, before ``W_o`` (``attention_gate`` ``headwise``).
   a pool of its own beside the latent's (``INDEX_KEY_LEAVES``). The chosen
   set is found without a sort: the ``index_topk``-th largest score of a row
   by bisection on the scores' bit patterns (:func:`kth_largest`: 32 counts),
-  then a mask (:func:`chosen_of`). A decode tick scores the slot's live keys
+  then a mask (:func:`chosen_of`); on the chip one kernel that counts a tile
+  of rows in VMEM, as far as its scores were written
+  (``ops/pallas/sparse_select.py``). A decode tick scores the slot's live keys
   (``ops/pallas/sparse_index.py``) and runs the absorbed kernel over the live
   latent blocks with the unchosen columns masked; a chunk scores one slot's
   keys for all its queries, and the expanded walk masks each block by them
@@ -654,11 +656,16 @@ class LatentAttention(nn.Module):
                     counts = {"swa_ring_positions_read": read,
                               "swa_ring_positions_live": jnp.minimum(ends, fed + kind.window - 1).sum()}
                 elif kind.top_k:
+                    from deepspeed_tpu.ops.pallas import sparse_select
                     from deepspeed_tpu.ops.pallas.sparse_index import chunk_blocks
                     blocks, size = chunk_blocks(ends, keys.shape[-1])
                     each = start[:, None] + 1 + jnp.arange(l)[None, :]          # [b, l]
                     real = jnp.arange(l)[None, :] < fed[:, None]
                     counts = {"dsa_index_keys_read": blocks.sum() * size,
+                              "dsa_select_positions_read": sparse_select.tile_blocks(
+                                  fed, blocks, l).sum() * (sparse_select.row_tile(l) * size)
+                              if self._walks_in_kernel(l, pool, start)
+                              else (fed > 0).sum() * (l * keys.shape[-1]),
                               "dsa_positions_selected": jnp.where(
                                   real, jnp.minimum(each, kind.top_k), 0).sum(),
                               "dsa_positions_live": jnp.where(real, each, 0).sum()}
@@ -691,10 +698,13 @@ class LatentAttention(nn.Module):
                                    _init(), ("heads", "kv", "embed")), name="o_proj")(out)
 
     def _chosen_decode(self, q, w, keys, lengths):
-        """One query a sequence: ``(chosen [b, positions] bool, counts)``, the
-        ``top_k`` live positions its index scores put first."""
-        from deepspeed_tpu.ops.pallas import backend
-        top_k, positions = self.kind.top_k, keys.shape[-1]
+        """One query a sequence: ``(chosen [b, positions], counts)``, the
+        ``top_k`` live positions its index scores put first: bool by XLA, on
+        the chip the float32 mask of ``sparse_select.select_top_k``, written
+        as far as a tile of sequences has live blocks."""
+        from deepspeed_tpu.ops.pallas import backend, sparse_select
+        top_k, (b, positions) = self.kind.top_k, (keys.shape[0], keys.shape[-1])
+        in_kernel = backend.on_tpu() and sparse_select.takes(b, positions)
         with jax.named_scope("dsa_index"):
             if backend.on_tpu():
                 from deepspeed_tpu.ops.pallas.latent_decode import blocks_read
@@ -707,27 +717,37 @@ class LatentAttention(nn.Module):
                 keys_read = (lengths > 0).sum() * positions
         with jax.named_scope("dsa_select"):
             live = jnp.arange(positions)[None, :] < lengths[:, None]
-            chosen, _ = chosen_of(*kth_largest(scores, live, top_k))
-        self.sow("intermediates", "dsa_chosen", chosen[:, None])
+            if in_kernel:
+                tile = sparse_select.row_tile(b)
+                blocks = blocks.reshape(-1, tile).max(axis=-1)
+                chosen = sparse_select.select_top_k(scores, lengths, blocks, top_k)
+                selected_from = blocks.sum() * (tile * block)
+            else:
+                chosen, _ = chosen_of(*kth_largest(scores, live, top_k))
+                selected_from = (lengths > 0).sum() * positions
+        self.sow("intermediates", "dsa_chosen", (live & (chosen > 0))[:, None])
         return chosen, {"dsa_index_keys_read": keys_read,
+                        "dsa_select_positions_read": selected_from,
                         "dsa_positions_selected": jnp.minimum(lengths, top_k).sum(),
                         "dsa_positions_live": lengths.sum()}
 
     def _walks_in_kernel(self, l: int, pool, start) -> bool:
         """Whether a chunk of an indexed layer over a serving pool runs as
-        the chip's three kernels (scores, then the walk) a slot."""
-        from deepspeed_tpu.ops.pallas import backend, latent_walk, sparse_index
+        the chip's three kernels (scores, selection, walk) a slot."""
+        from deepspeed_tpu.ops.pallas import backend, latent_walk, sparse_index, sparse_select
         return bool(backend.on_tpu() and start is not None and sparse_index.chunk_tile(l)
+                    and sparse_select.takes(l, pool.shape[-1])
                     and latent_walk.takes(l, self.kind.heads, pool.shape[-1]))
 
     def _walk_chosen(self, q_nope, q_rope, index_q, index_w, pool, keys, w_kvb, start, fed):
         """A chunk of an indexed layer on the chip, a fed slot at a time: the
-        slot's index scores (``sparse_index.index_scores_chunk``), each query's
-        bar, the mask of the chosen over the slot's positions, and the
+        slot's index scores (``sparse_index.index_scores_chunk``), the mask of
+        each query's chosen over the slot's live blocks
+        (``sparse_select.select_top_k``: the scores are read once), and the
         expanded walk under it (``latent_walk.selected_walk``: the scores stay
         in VMEM). A slot that is fed nothing reads nothing and gives zeros.
         The numbers are :func:`expanded_walk`'s under :meth:`_chosen_chunk`."""
-        from deepspeed_tpu.ops.pallas import latent_walk, sparse_index
+        from deepspeed_tpu.ops.pallas import latent_walk, sparse_index, sparse_select
         kind = self.kind
         b, l = q_nope.shape[:2]
         positions, dtype = pool.shape[-1], q_nope.dtype
@@ -746,14 +766,9 @@ class LatentAttention(nn.Module):
                 scores = sparse_index.index_scores_chunk(slot_of(index_q, s), slot_of(index_w, s),
                                                          keys, s, blocks)
             with jax.named_scope("dsa_select"):
-                valid = jnp.arange(positions)[None, :] <= q_pos[:, None]
-                ordered, bar, quota = kth_largest(scores, valid, kind.top_k)
-                tied = ((ordered == bar[:, None]) & (ordered > 0)).sum(axis=-1)
-                # every key equal to the bar is chosen unless more are tied
-                # than the top k has room for: only then are they ranked
-                may = jax.lax.cond(
-                    (tied > quota).any(), lambda: chosen_of(ordered, bar, quota)[0],
-                    lambda: ordered >= jnp.maximum(bar, jnp.uint32(1))[:, None])
+                # the tiles of rows past the slot's real queries choose nothing
+                may = sparse_select.select_top_k(
+                    scores, q_pos + 1, sparse_select.tile_blocks(fed[s], blocks, l), kind.top_k)
             blocks, _ = latent_walk.walk_blocks(live, positions)
             out = latent_walk.selected_walk(slot_of(q_nope, s), slot_of(q_rope, s), w_k, w_v, pool,
                                             may, s, blocks, scale=scale)

@@ -204,6 +204,11 @@ def test_ragged_chunks_then_decode_match_the_reference_logits_and_sets(programs)
         cache, logits, state = observed(params, cache, _int(*write_pos), _int(*fed),
                                         jnp.asarray(batch))
         sets = chosen_sets(state)
+        # the two fed slots' selections ran over their chunk's rows and (by
+        # XLA) the whole extent; a window layer selects nothing
+        at = SPARSE_READS.index("dsa_select_positions_read")
+        assert sorted(int(leaf[at]) for leaf in leaves_named(cache, ("sparse_reads",))) == \
+            [0, 0] + [int((fed > 0).sum()) * CHUNK * POSITIONS] * 2
         for k, slot in enumerate((1, 2)):
             if fed[slot]:
                 check(logits, sets, slot, k, done[k], int(fed[slot]))
@@ -426,12 +431,95 @@ def test_the_walk_kernel_is_the_expanded_walk_under_a_mask():
     assert latent_walk.takes(256, 128, 32768) and not latent_walk.takes(12, 128, 32768)
 
 
+def _selection(case):
+    """``(scores [rows, positions], bound [rows], n_blocks [tiles], k, block)``
+    of one case of the selection kernel's test."""
+    rng = np.random.default_rng(len(case))
+    rows, positions, block, k = 16, 512, 128, 40
+    scores = rng.standard_normal((rows, positions)).astype(np.float32)
+    bound, n_blocks = 300 + np.arange(rows), [3]
+    if case == "fewer_valid_than_k":
+        bound = 3 + np.arange(rows)
+        n_blocks = [1]
+    elif case == "exactly_k":
+        bound = np.full(rows, k)
+        n_blocks = [1]
+    elif case == "more_tied_at_the_bar_than_the_quota":
+        # 30 above a bar that 25 share; a row of one value; a row where the
+        # tied are exactly the quota
+        scores = -rng.random((rows, positions))
+        scores[:, 7:300:10], scores[:, 3:253:10] = 9.0, 1.0
+        scores[1] = 0.5
+        scores[2, :] = -1.0
+        scores[2, 100:140] = 2.0
+    elif case == "both_signs_and_both_zeros":
+        scores = rng.choice(np.asarray([-2.5, -0.0, 0.0, 1e-30, -1e-30, 3.25], np.float32),
+                            (rows, positions))
+        scores[0] = -np.abs(rng.standard_normal(positions))        # all below zero
+        scores[1] = np.where(np.arange(positions) % 2, -0.0, 0.0)  # the zeros alone
+    elif case == "causal_bound_inside_a_block":
+        bound, n_blocks = 250 + np.arange(rows), [3]               # 256 falls among the rows
+    elif case == "garbage_past_the_written_blocks":
+        n_blocks, bound = [2], 200 + 8 * np.arange(rows)           # rows reach past block 2
+        scores[:, 256:] = rng.choice(np.asarray([np.nan, 3e38, -3e38, np.inf], np.float32),
+                                     (rows, 256))
+    elif case == "chunk_256_at_extent_32768":
+        rows, positions, block, k = 256, 32768, 1024, 2048
+        scores = np.round(rng.standard_normal((rows, positions)) * 64).astype(np.float32) / 64
+        scores[:, 5120:] = np.nan                                  # never written
+        bound = 4700 + np.arange(rows)
+        n_blocks = [5] * 7 + [0]                                   # the last tile: no real row
+    elif case == "a_row_a_slot_with_a_length_each":
+        rows, k = 8, 24
+        scores = np.round(scores[:rows] * 4) / 4
+        bound, n_blocks = np.asarray([0, 1, 23, 24, 25, 128, 257, 384]), [3]
+    else:
+        assert case == "a_small_case"
+        rows, positions, block, k = 8, 128, 128, 5
+        scores, bound, n_blocks = scores[:rows, :positions], 1 + np.arange(rows) * 9, [1]
+    return np.asarray(scores, np.float32), np.asarray(bound, np.int32), \
+        np.asarray(n_blocks, np.int32), k, block
+
+
+@pytest.mark.parametrize("case", [
+    "fewer_valid_than_k", "exactly_k", "more_tied_at_the_bar_than_the_quota",
+    "both_signs_and_both_zeros", "causal_bound_inside_a_block", "garbage_past_the_written_blocks",
+    "chunk_256_at_extent_32768", "a_row_a_slot_with_a_length_each", "a_small_case"])
+def test_the_selection_kernel_is_the_bar_and_the_mask(case):
+    """``sparse_select.select_top_k`` (interpreted) against ``kth_largest`` +
+    ``chosen_of``, mask for mask over the blocks it was given, whatever lies
+    in the others."""
+    from deepspeed_tpu.ops.pallas import sparse_select
+    scores, bound, n_blocks, k, block = _selection(case)
+    rows, positions = scores.shape
+    tile = sparse_select.row_tile(rows)
+    assert len(n_blocks) == rows // tile
+    got = np.asarray(sparse_select.select_top_k(jnp.asarray(scores), jnp.asarray(bound),
+                                                jnp.asarray(n_blocks), k, block=block))
+    written = np.arange(positions)[None, :] < np.repeat(n_blocks, tile)[:, None] * block
+    valid = (np.arange(positions)[None, :] < bound[:, None]) & written
+    want, _ = package.chosen_of(*package.kth_largest(
+        jnp.asarray(np.where(valid, scores, 0.0)), jnp.asarray(valid), k))
+    want = np.asarray(want)
+    assert set(np.unique(got[written])) <= {0.0, 1.0}
+    np.testing.assert_array_equal(got[written] > 0, want[written])
+    # what the reference chooses: k of a row, or every valid position
+    np.testing.assert_array_equal(want.sum(-1), np.minimum(valid.sum(-1), k))
+    if case == "more_tied_at_the_bar_than_the_quota":
+        assert want[0, 3:103:10].all() and not want[0, 103:253:10].any()     # the lower ten of 25
+        assert want[2, 100:140].all()
+        assert want[1, :k].all() and not want[1, k:].any()
+    assert sparse_select.takes(256, 32768) and sparse_select.takes(32, 32768)
+    assert not sparse_select.takes(4, 32768) and not sparse_select.takes(16, 600)
+
+
 @pytest.mark.parametrize("tick", ["prefill", "decode"])
 def test_an_indexed_layer_on_the_chips_path_is_the_layer_on_xlas(programs, monkeypatch, tick):
     """One indexed attention layer over a serving cache, traced once as the
-    chip runs it (the three kernels a fed slot in a chunk, two in a decode
-    step; interpreted here) and once as XLA's loops: the same outputs, the
-    same chosen sets, the same rows written."""
+    chip runs it (scores, selection and attention as kernels, a fed slot at a
+    time in a chunk and every slot at once in a decode step; interpreted here)
+    and once as XLA's loops: the same outputs, the same chosen sets, the same
+    rows written."""
     from deepspeed_tpu.inference.serving import programs as serving
     from deepspeed_tpu.ops.pallas import backend
     module = programs[0]
@@ -450,13 +538,14 @@ def test_an_indexed_layer_on_the_chips_path_is_the_layer_on_xlas(programs, monke
     layer = OneLayer()
     params = jax.tree.map(lambda p: p * 3.0 if p.ndim >= 2 else p, nn.meta.unbox(layer.init(
         jax.random.PRNGKey(4), jnp.zeros((1, 8), jnp.int32), decode=False)["params"]))
-    cache, _ = serving.without_next_tokens(make_slot_cache(layer, 4))
+    cache, _ = serving.without_next_tokens(make_slot_cache(layer, 8))    # a tile of the selection
     rng = np.random.default_rng(6)
     cache = jax.tree.map(lambda leaf: jnp.asarray(rng.normal(size=leaf.shape), leaf.dtype)
                          if leaf.ndim == 4 else leaf, cache)
     l = CHUNK if tick == "prefill" else 1
-    x = jnp.asarray(rng.normal(size=(4, l, cfg.hidden_size)), jnp.float32)
-    start, fed = _int(POSITIONS, 70, 0, 33), _int(0, l, min(l, 9), l)
+    x = jnp.asarray(rng.normal(size=(8, l, cfg.hidden_size)), jnp.float32)
+    start = _int(POSITIONS, 70, 0, 33, 96, POSITIONS, 64, 16)
+    fed = _int(0, l, min(l, 9), l, min(l, 3), 0, l, 1)
 
     def run():
         held = serving.with_write_positions(cache, start, fed)
@@ -476,6 +565,12 @@ def test_an_indexed_layer_on_the_chips_path_is_the_layer_on_xlas(programs, monke
         np.testing.assert_array_equal(got[1]["self_attn"][name], want[1]["self_attn"][name])
     np.testing.assert_array_equal(got[1]["self_attn"]["sparse_reads"][1:3],
                                   want[1]["self_attn"]["sparse_reads"][1:3])
+    # the selection's scores: by XLA the whole extent a real row, in the kernel
+    # the live blocks (here the one of 128) of a tile of 16 queries or 8 slots
+    at = SPARSE_READS.index("dsa_select_positions_read")
+    assert int(want[1]["self_attn"]["sparse_reads"][at]) == real.sum() * l * POSITIONS
+    assert int(got[1]["self_attn"]["sparse_reads"][at]) == (real.sum() * l if tick == "prefill"
+                                                            else 8) * POSITIONS
 
 
 # ---------------------------------------------------------------------------
@@ -553,7 +648,7 @@ def test_the_scheduler_serves_it_and_counts_what_the_layers_read(engine):
     assert counted["dsa_latent_bytes_written"] >= 2 * tokens * wide.latent_width * 4
     assert counted["dsa_index_key_bytes_written"] >= 2 * tokens * wide.index_head_dim * 4
     assert counted["swa_ring_bytes_written"] >= 2 * tokens * (48 + 8) * 4
-    assert len(SPARSE_READS) == 8
+    assert len(SPARSE_READS) == 9
 
 
 @pytest.mark.parametrize("what", ["prefix_cache", "speculation", "migration"])

@@ -43,6 +43,7 @@ def test_kernels_phase_rehearsal():
     assert obs["compiled"] is False  # interpret mode off the chip
     assert obs["worst_bf16_roundings"]["moe_permute_fwd"] == 0.0
     assert [obs["worst_bf16_roundings"][f"kv_append_{n}"] for n in (1, 16, 5)] == [0.0] * 3
+    assert obs["worst_bf16_roundings"]["dsa_select"] == 0.0
     assert {"flash_bwd", "flash_decode", "quant_matmul_int4", "sparse_bwd"} <= set(
         obs["worst_bf16_roundings"])
 

@@ -9,10 +9,14 @@ each kernel held against XLA's form of the same numbers first:
   against a GATHER of the 2,048 chosen rows and XLA's absorbed step over
   them, and the window step over the ring;
 * prefill, ``--fed`` slots each ending a chunk at ``--live`` positions: one
-  slot's index scores (kernel against XLA), the bisection over them, and a
+  slot's index scores (kernel against XLA), the selection over them (keys,
+  bar, quota, ties and the mask: XLA's passes against the kernel
+  ``ops/pallas/sparse_select.py``, at ``--live`` and at half of it), and a
   whole attention layer of each kind (projections, write, scores, selection,
   walk) by key block, the full layer's walk as the chip's kernel
-  (``ops/pallas/latent_walk.py``) or, with ``--walk xla``, as XLA's loops.
+  (``ops/pallas/latent_walk.py``) or, with ``--walk xla``, as XLA's loops;
+  with ``--scopes`` the full layer's device time by named scope
+  (``dsa_index``, ``dsa_select``, the walk, the rest) from a profiler trace.
 
     python3 tools/dsa_attention_time.py [--slots 32] [--fed 8] [--live 16000]
 
@@ -37,6 +41,43 @@ def _time(fn, *args, steps=20):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / steps * 1e3
+
+
+def _by_scope(call, text, scopes, steps=3):
+    """Device ms a call of ``call()`` by the innermost of ``scopes`` an
+    operation's ``op_name`` holds (``other``: none), and of the dozen largest
+    operation families, from a profiler trace of ``steps`` calls; ``text`` is
+    the compiled program, which names each instruction's scope where the
+    trace's events do not."""
+    import re
+    import shutil
+    import tempfile
+    import jax
+    from benchmarks.lib import trace
+    where = dict(re.findall(r"^\s*(?:ROOT )?(%[^\s=]+) = .*?op_name=\"([^\"]*)\"", text, re.M))
+    out_dir = tempfile.mkdtemp(prefix="dsa_scopes_")
+    try:
+        jax.profiler.start_trace(out_dir)
+        for _ in range(steps):
+            out = call()
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        loaded = trace.load(trace.find_xplane(out_dir))
+    finally:
+        shutil.rmtree(out_dir, ignore_errors=True)
+    if not loaded["devices"]:
+        return None                    # off the chip the trace has no device plane
+    ms, largest = dict.fromkeys((*scopes, "other"), 0.0), {}
+    for event, seconds in trace.self_times(next(iter(loaded["devices"].values()))).items():
+        named = re.split(r"[/\"\s]", event + " " + where.get(
+            "%" + trace.op_name(event).lstrip("%"), ""))
+        scope = next((s for s in scopes if s in named), "other")
+        ms[scope] += seconds * 1e3 / steps
+        family = f"{scope}:{trace.op_family(event)}"
+        largest[family] = largest.get(family, 0.0) + seconds * 1e3 / steps
+    # and the dozen largest operation families, each under its scope
+    ms["largest"] = {k: round(v, 3) for k, v in sorted(largest.items(), key=lambda kv: -kv[1])[:12]}
+    return ms
 
 
 def _layer(cfg, kind):
@@ -72,6 +113,8 @@ def main(argv):
     parser.add_argument("--blocks", type=int, nargs="+", default=[256, 512])
     parser.add_argument("--walk", choices=["kernel", "xla"], default="kernel",
                         help="a full layer's prefill walk: the chip's kernel, or XLA's loops")
+    parser.add_argument("--scopes", action="store_true",
+                        help="a full layer's device time by named scope, from a trace")
     parser.add_argument("--config", default="dots3-note-prev",
                         help="benchmarks/configs/<name>.json; dots3-note-test rehearses on a CPU")
     args = parser.parse_args(argv)
@@ -83,7 +126,7 @@ def main(argv):
     from benchmarks.lib.peaks import PEAKS
     from deepspeed_tpu.inference.serving import programs
     from deepspeed_tpu.models import deepseek_v3 as model
-    from deepspeed_tpu.ops.pallas import latent_decode, sparse_index
+    from deepspeed_tpu.ops.pallas import latent_decode, sparse_index, sparse_select
 
     if args.walk == "xla":
         model.LatentAttention._walks_in_kernel = lambda self, l, pool, start: False
@@ -98,7 +141,7 @@ def main(argv):
     device = jax.devices()[0].device_kind
 
     def say(**fields):
-        print(json.dumps(dict(device=device, slots=b, live=live, **fields)), flush=True)
+        print(json.dumps({"device": device, "slots": b, "live": live, **fields}), flush=True)
 
     def least_ms(flops, nbytes):
         return ops.roofline_ms(flops, nbytes, peaks)[0]
@@ -129,6 +172,12 @@ def main(argv):
     say(what="decode_select", form="bisection_and_mask", ms=_time(choose, scores, lengths, steps=50),
         same_set_as_top_k=same)
     say(what="decode_select", form="lax_top_k", ms=_time(by_sort, scores, lengths, steps=20))
+    tile = sparse_select.row_tile(b)
+    in_kernel = jax.jit(lambda s, n: sparse_select.select_top_k(
+        s, n, sparse_index.chunk_blocks(n, positions)[0].reshape(-1, tile).max(-1), top_k))
+    differing = int(((in_kernel(scores, lengths) > 0) & alive != chosen).sum())
+    say(what="decode_select", form="kernel", ms=_time(in_kernel, scores, lengths, steps=50),
+        differing_mask_entries=differing)
 
     pool = jax.random.normal(keys[3], (b, rank + dr, positions), bf16)
     q_lat = jax.random.normal(keys[4], (b, h, rank), bf16)
@@ -187,11 +236,33 @@ def main(argv):
                          ("xla_whole_pool", _time(plain, q_c, w_c, index_keys, steps=10))):
             say(what="prefill_index_one_slot", chunk=chunk, form=name, ms=ms, least_ms=least,
                 roofline_pct=100 * least / ms, max_abs_gap_to_xla=gap)
-        at = live - chunk + jnp.arange(chunk)
-        bar = jax.jit(lambda s: model.kth_largest(
-            s, jnp.arange(positions)[None, :] <= at[:, None], top_k)[1:])
-        say(what="prefill_select_one_slot", chunk=chunk, form="bisection",
-            ms=_time(bar, got, steps=10))
+
+        @jax.jit
+        def by_passes(s, at):
+            # keys, bar, quota, ties and the mask the walk reads: XLA's passes
+            # over the whole extent, as a chunk ran them before the kernel
+            ordered, bar, quota = model.kth_largest(
+                s, jnp.arange(positions)[None, :] <= at[:, None], top_k)
+            tied = ((ordered == bar[:, None]) & (ordered > 0)).sum(axis=-1)
+            return jax.lax.cond(
+                (tied > quota).any(), lambda: model.chosen_of(ordered, bar, quota)[0],
+                lambda: ordered >= jnp.maximum(bar, jnp.uint32(1))[:, None]).astype(jnp.float32)
+
+        @jax.jit
+        def in_kernel(s, at, n):
+            return sparse_select.select_top_k(
+                s, at + 1, jnp.full((chunk // sparse_select.row_tile(chunk),), n), top_k)
+
+        for ends in (live, live // 2):
+            at = ends - chunk + jnp.arange(chunk)
+            n, size = sparse_index.chunk_blocks(jnp.int32(ends), positions)
+            held = jnp.where(jnp.arange(positions) < n * size, got, 0.0)
+            differing = (in_kernel(got, at, n) != by_passes(held, at))[:, :int(n) * size]
+            say(what="prefill_select_one_slot", chunk=chunk, live=ends, form="bisection",
+                ms=_time(by_passes, held, at, steps=10))
+            say(what="prefill_select_one_slot", chunk=chunk, live=ends, form="kernel",
+                ms=_time(in_kernel, got, at, n, steps=20),
+                differing_mask_entries=int(differing.sum()))
 
     for chunk in args.chunk:
         for layer, kind in (("full", "F"), ("sliding", "S")):
@@ -218,6 +289,8 @@ def main(argv):
 
                 run = jax.jit(tick, donate_argnums=(1,))
                 ids = jnp.zeros((b, chunk), jnp.int32)
+                text = (run.lower(params, cache, start, fed, ids).compile().as_text()
+                        if args.scopes and kind == "F" else None)
                 cache, out = run(params, cache, start, fed, ids)
                 jax.block_until_ready(out)
                 t0 = time.perf_counter()
@@ -245,6 +318,17 @@ def main(argv):
                     walk=args.walk if kind == "F" else "xla",
                     fed_slots=args.fed, ms=ms, least_ms=least, roofline_pct=100 * least / ms,
                     finite=bool(jnp.isfinite(out.astype(jnp.float32)).all()))
+                if text is not None:
+                    held = [cache]
+
+                    def again():
+                        held[0], out = run(params, held[0], start, fed, ids)
+                        return out
+
+                    say(what="prefill_layer_by_scope", layer=layer, chunk=chunk, key_block=block,
+                        fed_slots=args.fed, ms=_by_scope(again, text, (
+                            "dsa_index", "dsa_select", "dsa_attend_prefill")))
+                    cache = held[0]
                 del cache, params
 
 
