@@ -17,7 +17,7 @@ TPU-first notes, same conventions as ``models/gpt2.py``:
 """
 
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -28,6 +28,9 @@ from deepspeed_tpu.models.common import (DecodeCache, config_from, dense_init as
 from deepspeed_tpu.ops.transformer.attention import dot_product_attention
 
 
+EXPERT_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
@@ -36,6 +39,10 @@ class LlamaConfig:
     num_hidden_layers: int = 32
     num_attention_heads: int = 32
     num_key_value_heads: int = 32  # < num_attention_heads → GQA
+    # width of one head; None = ``hidden_size // num_attention_heads`` (set in
+    # ``__post_init__``). SmallThinker publishes 128 with 28 heads over a
+    # hidden size of 2,560: heads x head_dim need not be the hidden size
+    head_dim: Optional[int] = None
     max_position_embeddings: int = 2048
     rms_norm_eps: float = 1e-6
     rope_theta: float = 10000.0
@@ -62,9 +69,17 @@ class LlamaConfig:
     decode_cache_len: Optional[int] = None
     # Mistral-style sliding-window attention: each token attends the last
     # ``sliding_window`` positions. Training/prefill only — the flash
-    # kernel skips out-of-window blocks (O(L*window)); decode attends the
-    # whole cache (window >= cache length in practice).
+    # kernel skips out-of-window blocks (O(L*window)). Decode keeps no ring
+    # and applies no window: it raises by name where the cache is longer
+    # than a window layer's window, and otherwise the whole cache lies
+    # inside it.
     sliding_window: Optional[int] = None
+    # per layer, as SmallThinker publishes them: 1 = this layer attends its
+    # window (``sliding_window``), 0 = full causal attention; 1 = RoPE on
+    # this layer's queries and keys, 0 = no positional encoding (NoPE).
+    # None: every layer alike (the window wherever one is set, RoPE always)
+    sliding_window_layout: Optional[Tuple[int, ...]] = None
+    rope_layout: Optional[Tuple[int, ...]] = None
     # >0: when called with ``labels=``, compute the loss via the chunked
     # fused LM head (models/common.py fused_lm_head_loss) — never
     # materializes [B, L, V] logits (32k-152k vocabs make that the
@@ -86,15 +101,47 @@ class LlamaConfig:
     moe_eval_capacity_factor: float = 2.0  # serving must not under-provision vs training
     moe_min_capacity: int = 4
     moe_aux_loss_coef: float = 0.01
+    # the experts' gated activation: "silu" (SwiGLU) or "relu" (SmallThinker's
+    # ReGLU, ``relu(gate) * up``); the dense MLP stays SwiGLU
+    moe_activation: str = "silu"
+    # the router reads the layer's INPUT, before the input norm and before
+    # attention (SmallThinker's pre-attention router), not the normed
+    # post-attention state the experts read
+    moe_router_before_attention: bool = False
+    # ``(first, count)``: the experts this device holds of ``moe_num_experts``
+    # (``MOELayer.experts_held``; drop-free route on one device). The router
+    # keeps every output; the bank holds ``count`` experts
+    moe_experts_held: Optional[Tuple[int, int]] = None
     # dispatch/combine route ("dense"|"sorted") and the sorted route's
     # permutation kernel ("auto"|"xla"|"pallas"); the engine's "moe" config
     # block lands here
     moe_route: str = "sorted"
     moe_route_kernel: str = "auto"
 
-    @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.hidden_size // self.num_attention_heads)
+        for name in ("moe_experts_held", "sliding_window_layout", "rope_layout"):
+            given = getattr(self, name)
+            if given is None:
+                continue
+            # a list from JSON: keep the config hashable
+            object.__setattr__(self, name, tuple(int(v) for v in given))
+            if name.endswith("_layout") and len(given) != self.num_hidden_layers:
+                raise ValueError(f"{name} names {len(given)} layers, the model has "
+                                 f"{self.num_hidden_layers}")
+        if self.moe_activation not in EXPERT_ACTIVATIONS:
+            raise ValueError(f"moe_activation must be one of {sorted(EXPERT_ACTIVATIONS)}, "
+                             f"got {self.moe_activation!r}")
+
+    def window_of(self, layer: int) -> Optional[int]:
+        """The window layer ``layer`` attends, None where it attends all."""
+        if self.sliding_window_layout is not None and not self.sliding_window_layout[layer]:
+            return None
+        return self.sliding_window
+
+    def rope_on(self, layer: int) -> bool:
+        return self.rope_layout is None or bool(self.rope_layout[layer])
 
 
 LLAMA_CONFIGS = {
@@ -134,6 +181,29 @@ LLAMA_CONFIGS = {
                        max_position_embeddings=128, rms_norm_eps=1e-5, qk_norm=True,
                        moe_num_experts=8, moe_k=2, moe_norm_topk_prob=False,
                        moe_drop_tokens=False),
+    # SmallThinker-21BA3B (PowerInfer/SmallThinker-21BA3B-Instruct): 52 layers,
+    # every fourth (l % 4 == 0) full causal attention with no positional
+    # encoding, the others RoPE and a window of 4,096; 28 query heads of 128
+    # on 4 key heads over a hidden size of 2,560; every FFN 64 ReGLU experts
+    # of width 768, top-6 renormalised, routed from the layer's input
+    "smallthinker-21b-a3b": dict(
+        vocab_size=151936, hidden_size=2560, intermediate_size=768, num_hidden_layers=52,
+        num_attention_heads=28, num_key_value_heads=4, head_dim=128,
+        max_position_embeddings=16384, rms_norm_eps=1e-6, rope_theta=1.5e6,
+        sliding_window=4096,
+        sliding_window_layout=tuple(int(i % 4 != 0) for i in range(52)),
+        rope_layout=tuple(int(i % 4 != 0) for i in range(52)),
+        moe_num_experts=64, moe_k=6, moe_norm_topk_prob=True, moe_drop_tokens=False,
+        moe_activation="relu", moe_router_before_attention=True, moe_aux_loss_coef=0.0),
+    # two periods of it at a size the CPU runs: heads x head_dim != hidden
+    "smallthinker-test": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=32, num_hidden_layers=8,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=32,
+        max_position_embeddings=32, rms_norm_eps=1e-6, rope_theta=1.5e6, sliding_window=8,
+        sliding_window_layout=tuple(int(i % 4 != 0) for i in range(8)),
+        rope_layout=tuple(int(i % 4 != 0) for i in range(8)),
+        moe_num_experts=8, moe_k=3, moe_norm_topk_prob=True, moe_drop_tokens=False,
+        moe_activation="relu", moe_router_before_attention=True, moe_aux_loss_coef=0.0),
     # Qwen2 family: llama architecture + biased q/k/v projections
     "qwen2-7b": dict(vocab_size=152064, hidden_size=3584, intermediate_size=18944,
                      num_hidden_layers=28, num_attention_heads=28, num_key_value_heads=4,
@@ -174,15 +244,21 @@ def rotary_embedding(x, positions, theta: float = 10000.0):
 
 
 class LlamaAttention(nn.Module):
-    """GQA attention with RoPE and an optional decode cache."""
+    """GQA attention with RoPE and an optional decode cache. ``layer``
+    picks this layer's window and whether it rotates (``LlamaConfig``'s two
+    layouts); every layer is alike where the configuration has none."""
 
     config: LlamaConfig
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, positions=None, *, decode: bool = False, attention_mask=None):
         cfg = self.config
         b, l, _ = x.shape
         n_rep = cfg.num_attention_heads // cfg.num_key_value_heads
+        window = cfg.window_of(self.layer)
+        rope = (lambda t, at: rotary_embedding(t, at, cfg.rope_theta)) \
+            if cfg.rope_on(self.layer) else (lambda t, at: t)
 
         def proj(heads, name):
             # q/k/v projections only (o_proj is built separately, always
@@ -213,13 +289,19 @@ class LlamaAttention(nn.Module):
         if decode:
             # static-shape KV cache, lockstep or per serving slot, fp or int8
             # (models/common.py DecodeCache; the cache handed in decides)
-            cache = DecodeCache(self, b, cfg.decode_cache_len or cfg.max_position_embeddings,
+            cache_len = cfg.decode_cache_len or cfg.max_position_embeddings
+            if window is not None and cache_len > window:
+                raise NotImplementedError(
+                    f"decode over a cache of {cache_len} positions with sliding_window "
+                    f"{window} (layer {self.layer}): the decode path keeps no ring and "
+                    f"applies no window, so it would attend positions the layer must not "
+                    f"see; set decode_cache_len <= sliding_window or serve without decode")
+            cache = DecodeCache(self, b, cache_len,
                                 cfg.num_key_value_heads, cfg.head_dim, k.dtype)
             given = positions is not None
             if not given:
                 positions = cache.positions(l)
-            q = rotary_embedding(q, positions, cfg.rope_theta)
-            k = rotary_embedding(k, positions, cfg.rope_theta)
+            q, k = rope(q, positions), rope(k, positions)
             k, v, decode_lengths = cache.append(k, v, q.dtype)
             if given:
                 # per-sequence live lengths (positions may differ per batch
@@ -232,22 +314,24 @@ class LlamaAttention(nn.Module):
         else:
             if positions is None:
                 positions = jnp.broadcast_to(jnp.arange(l)[None, :], (b, l))
-            q = rotary_embedding(q, positions, cfg.rope_theta)
-            k = rotary_embedding(k, positions, cfg.rope_theta)
+            q, k = rope(q, positions), rope(k, positions)
 
-        if n_rep > 1:  # GQA: expand kv heads to full heads
+        # GQA: the flash kernels read key head ``head // n_rep`` themselves
+        # (training and prefill); every other path gets the heads repeated
+        if n_rep > 1 and (decode or cfg.attention_backend != "flash"):
             k = jnp.repeat(k, n_rep, axis=2)
             v = jnp.repeat(v, n_rep, axis=2)
 
-        if cfg.sliding_window is not None and cfg.attention_backend not in ("flash", "xla"):
+        if window is not None and cfg.attention_backend not in ("flash", "xla"):
             # silently ignoring the window would change the model's math
             raise ValueError(f"sliding_window is supported by the flash/xla attention "
                              f"backends, not {cfg.attention_backend!r}")
         from deepspeed_tpu.models.common import attention_geometry_kwargs
-        out = dot_product_attention(q, k, v, backend=cfg.attention_backend, causal=causal,
-                                    mask=mask, decode_lengths=decode_lengths,
-                                    window=cfg.sliding_window if not decode else None,
-                                    **attention_geometry_kwargs(cfg))
+        with jax.named_scope("attn_window" if window is not None else "attn_full"):
+            out = dot_product_attention(q, k, v, backend=cfg.attention_backend, causal=causal,
+                                        mask=mask, decode_lengths=decode_lengths,
+                                        window=window if not decode else None,
+                                        **attention_geometry_kwargs(cfg))
         return nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                                kernel_init=nn.with_logical_partitioning(_init(), ("heads", "kv", "embed")),
@@ -294,7 +378,8 @@ class LlamaMLP(nn.Module):
         else:
             from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
             dot = lambda t, w: grouped_matmul(t, w, group_sizes, impl=impl)  # noqa: E731
-        return dot(jax.nn.silu(dot(x, w_gate)) * dot(x, w_up), w_down)
+        act = EXPERT_ACTIVATIONS[cfg.moe_activation]
+        return dot(act(dot(x, w_gate)) * dot(x, w_up), w_down)
 
 
 class ExpertKernel(nn.Module):
@@ -315,19 +400,25 @@ class ExpertKernel(nn.Module):
 class LlamaDecoderLayer(nn.Module):
     config: LlamaConfig
     use_moe: bool = False
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, positions=None, decode: bool = False, attention_mask=None,
                  deterministic: bool = True):
         cfg = self.config
-        x = x + LlamaAttention(cfg, name="self_attn")(
+        # the pre-attention router reads the stream as it enters the layer
+        router_input = x if self.use_moe and cfg.moe_router_before_attention else None
+        x = x + LlamaAttention(cfg, self.layer, name="self_attn")(
             RMSNorm(cfg, name="input_layernorm")(x), positions, decode=decode,
             attention_mask=attention_mask)
         h = RMSNorm(cfg, name="post_attention_layernorm")(x)
         if self.use_moe:
             from deepspeed_tpu.moe import MoE
-            # drop-free routing groups rows by expert, which takes a bank
+            # drop-free routing groups rows by expert, which takes a bank:
+            # of every expert, or of the ones this device holds
             bank = 0 if cfg.moe_drop_tokens else cfg.moe_num_experts
+            if cfg.moe_experts_held is not None:
+                bank = cfg.moe_experts_held[1]
             moe_out, l_aux, _ = MoE(hidden_size=cfg.hidden_size,
                                     expert=LlamaMLP(cfg, num_experts=bank),
                                     num_experts=cfg.moe_num_experts,
@@ -339,7 +430,9 @@ class LlamaDecoderLayer(nn.Module):
                                     norm_topk_prob=cfg.moe_norm_topk_prob,
                                     route=cfg.moe_route,
                                     route_kernel=cfg.moe_route_kernel,
-                                    name="moe")(h, deterministic=deterministic)
+                                    experts_held=cfg.moe_experts_held,
+                                    name="moe")(h, deterministic=deterministic,
+                                                router_input=router_input)
             return x + moe_out, l_aux
         return x + LlamaMLP(cfg, name="mlp")(h), jnp.zeros([], jnp.float32)
 
@@ -368,6 +461,21 @@ class LlamaForCausalLM(nn.Module):
         every = max(cfg.moe_layer_freq, 1)
         return [i for i in range(cfg.num_hidden_layers)
                 if cfg.moe_num_experts > 0 and i % every == every - 1]
+
+    def step_count_names(self):
+        """What each expert layer counts on the device in a training step
+        (``moe/sharded_moe.py`` ``HELD_COUNTS``; the engine returns them
+        beside the loss): only a layer that holds a share of its experts
+        counts, since only there the rows it computes are not the copies."""
+        from deepspeed_tpu.moe.sharded_moe import HELD_COUNTS
+        return HELD_COUNTS if self.config.moe_experts_held is not None and self.moe_layers() \
+            else ()
+
+    def step_counts(self, counted):
+        """``counted``, the ``step_counts`` collection one forward pass wrote,
+        as ``[layers, counts]`` int32: a row an expert layer, in layer order."""
+        return jnp.stack([counted[f"layers_{i}"]["moe"]["deepspeed_moe"]["moe_rows"]
+                          for i in self.moe_layers()])
 
     def moe_rows(self, positions: int):
         """``(routed, computed)``: expert-matmul rows one position owes over
@@ -409,7 +517,7 @@ class LlamaForCausalLM(nn.Module):
             use_moe = i in moe_layers
             block_cls = maybe_remat(LlamaDecoderLayer, cfg, i, static_argnums=(3, 5),
                                     enabled=cfg.remat and not decode)
-            x, l_aux = block_cls(cfg, use_moe, name=f"layers_{i}")(
+            x, l_aux = block_cls(cfg, use_moe, i, name=f"layers_{i}")(
                 x, positions, decode, attention_mask, deterministic)
             x = constrain_activation(x, "batch", "length", "embed")
             aux_total = aux_total + l_aux

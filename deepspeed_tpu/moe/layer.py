@@ -11,7 +11,7 @@ placement is the ``expert`` mesh axis (``parallel/topology.py``) and
 ``ep_size`` is validated against it rather than creating anything.
 """
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -48,6 +48,9 @@ class MoE(nn.Module):
     # k >= 2: renormalise the k chosen experts' weights (Mixtral, the
     # reference top-2) or keep the softmax values (OLMoE)
     norm_topk_prob: bool = True
+    # ``(first, count)``: the experts this device holds of ``num_experts``
+    # (``MOELayer.experts_held``); ``expert`` is then a bank of ``count``
+    experts_held: Optional[Tuple[int, int]] = None
 
     def setup(self):
         if self.noisy_gate_policy not in (None, 'None', 'Jitter', 'RSample'):
@@ -76,6 +79,7 @@ class MoE(nn.Module):
             route=self.route,
             route_kernel=self.route_kernel,
             norm_topk_prob=self.norm_topk_prob,
+            experts_held=self.experts_held,
         )
         if self.use_residual:
             # PR-MoE (reference layer.py:70-77): dense MLP alongside the MoE
@@ -83,9 +87,13 @@ class MoE(nn.Module):
             self.mlp = _ResidualExpertWrapper(expert=self.expert)
             self.coefficient = nn.Dense(2, use_bias=True, dtype=jnp.float32, name="coefficient")
 
-    def __call__(self, hidden_states, used_token=None, deterministic: bool = True):
-        """Returns ``(output, l_aux, exp_counts)`` (reference ``layer.py:98``)."""
-        output, l_aux, exp_counts = self.deepspeed_moe(hidden_states, used_token, deterministic)
+    def __call__(self, hidden_states, used_token=None, deterministic: bool = True,
+                 router_input=None):
+        """Returns ``(output, l_aux, exp_counts)`` (reference ``layer.py:98``).
+        ``router_input`` (shaped like ``hidden_states``): what the gate reads
+        where that is not what the experts read; None, the same tensor."""
+        output, l_aux, exp_counts = self.deepspeed_moe(hidden_states, used_token, deterministic,
+                                                       router_input=router_input)
         if self.use_residual:
             mlp_out = self.mlp(hidden_states, deterministic=deterministic)
             coef = self.coefficient(hidden_states.astype(jnp.float32))

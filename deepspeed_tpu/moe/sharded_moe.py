@@ -618,18 +618,33 @@ def _num_groups(num_tokens_leading: int) -> int:
     return 1
 
 
+#: the collection a held layer writes its step's counts into where the
+#: caller makes it mutable (the engine's train step, ``runtime/engine.py``),
+#: and the names of the counts, in the vector's order
+STEP_COUNTS = "step_counts"
+HELD_COUNTS = ("rows_routed", "rows_visited", "copies", "experts_touched", "rows_buffered",
+               "load_max")
+
 #: ``MOELayer.experts_held``: the row buffer is a sixteenth or a quarter of
 #: the copies where the held rows fit one; a buffer under ``MIN_RUNG_ROWS``
 #: is not worth a branch (a decode tick's few hundred copies take none)
 RUNG_FRACTIONS, MIN_RUNG_ROWS = (16, 4), 1024
 
 
-def _row_rungs(copies: int) -> Tuple[int, ...]:
+def _row_rungs(copies: int, held_share: Optional[float] = None) -> Tuple[int, ...]:
     """The static sizes a held layer's row buffer may take for ``copies``
     token copies, ascending, each but the last in whole row tiles of the
-    grouped matmul; the last is every copy."""
+    grouped matmul; the last is every copy. A serving tick's rows are mostly
+    padding or another device's and vary tick by tick: ``RUNG_FRACTIONS`` of
+    the copies. A training step (``held_share`` given: the held experts'
+    part of all) has a real token in every row and gets the share of an even
+    router, which its own gradient then pulls toward the held experts (the
+    only ones whose output it sees): one rung, at twice that share."""
     from deepspeed_tpu.ops.pallas.grouped_matmul import ROW_TILE_LARGE as tile
-    rungs = [-(-copies // (part * tile)) * tile for part in RUNG_FRACTIONS]
+    if held_share is None:
+        rungs = [-(-copies // (part * tile)) * tile for part in RUNG_FRACTIONS]
+    else:
+        rungs = [-(-int(2 * held_share * copies) // tile) * tile]
     return tuple(r for r in rungs if MIN_RUNG_ROWS <= r < copies) + (copies,)
 
 
@@ -688,7 +703,11 @@ class MOELayer(nn.Module):
     param_dtype: Any = jnp.float32
 
     @nn.compact
-    def __call__(self, hidden_states, used_token=None, deterministic: bool = True):
+    def __call__(self, hidden_states, used_token=None, deterministic: bool = True,
+                 router_input=None):
+        """``router_input``: what the gate reads, shaped like ``hidden_states``,
+        where the router does not read what the experts read (a router placed
+        before attention); None, today's layer: the gate reads the tokens."""
         orig_shape = hidden_states.shape
         orig_dtype = hidden_states.dtype
         d_model = orig_shape[-1]
@@ -704,6 +723,10 @@ class MOELayer(nn.Module):
             return _constrain_groups(x, spec, groups)
 
         tokens = constrain(tokens, (BATCH_AXES, None, None))
+        # what the gate reads; the same array where no router input is given,
+        # so that program is the one it was
+        gate_tokens = tokens if router_input is None else constrain(
+            router_input.reshape(tokens.shape), (BATCH_AXES, None, None))
 
         gate = TopKGate(self.model_dim, self.num_experts, self.k, self.capacity_factor,
                         self.eval_capacity_factor, self.min_capacity, self.noisy_gate_policy,
@@ -716,11 +739,11 @@ class MOELayer(nn.Module):
 
         if route == "sorted":
             out, l_aux, exp_counts, kept_counts, routed_counts, capacity = self._sorted_route(
-                gate, tokens, used_token, deterministic, kernel, constrain,
+                gate, gate_tokens, tokens, used_token, deterministic, kernel, constrain,
                 orig_dtype, groups)
         else:
             out, l_aux, exp_counts, kept_counts, routed_counts, capacity = self._dense_route(
-                gate, tokens, used_token, deterministic, constrain, orig_dtype)
+                gate, gate_tokens, tokens, used_token, deterministic, constrain, orig_dtype)
 
         out = out.reshape(orig_shape)
         if self.shared_expert is not None:
@@ -742,9 +765,10 @@ class MOELayer(nn.Module):
                  jnp.asarray(groups * capacity, jnp.int32))
         return out, l_aux.astype(jnp.float32), exp_counts
 
-    def _dense_route(self, gate, tokens, used_token, deterministic, constrain,
+    def _dense_route(self, gate, gate_tokens, tokens, used_token, deterministic, constrain,
                      orig_dtype):
-        l_aux, combine_weights, dispatch_mask, exp_counts = gate(tokens, used_token, deterministic)
+        l_aux, combine_weights, dispatch_mask, exp_counts = gate(gate_tokens, used_token,
+                                                                 deterministic)
 
         # dispatch: [G,S,E,C] × [G,S,M] → [G,E,C,M] (reference 'sec,sm->ecm').
         # Pin the einsum output G-sharded FIRST: both operands are G-sharded,
@@ -780,7 +804,7 @@ class MOELayer(nn.Module):
         routed_counts = exp_counts if self.k == 1 else None
         return combined, l_aux, exp_counts, kept_counts, routed_counts, combine_weights.shape[-1]
 
-    def _sorted_route(self, gate, tokens, used_token, deterministic, kernel,
+    def _sorted_route(self, gate, gate_tokens, tokens, used_token, deterministic, kernel,
                       constrain, orig_dtype, groups):
         from deepspeed_tpu.ops.pallas.moe_dispatch import (inverse_index, permute_rows,
                                                            resolve_impl)
@@ -813,10 +837,11 @@ class MOELayer(nn.Module):
                     "experts_held needs the drop-free grouped layout of one device "
                     "(drop_tokens=False over an expert bank); on a mesh the layer's "
                     "exchange is not built")
-            return self._held_route(gate, tokens, used_token, deterministic, impl, orig_dtype)
+            return self._held_route(gate, gate_tokens, tokens, used_token, deterministic, impl,
+                                    orig_dtype)
 
         with jax.named_scope("moe_route"):
-            l_aux, routing, exp_counts = gate(tokens, used_token, deterministic)
+            l_aux, routing, exp_counts = gate(gate_tokens, used_token, deterministic)
             capacity = gate.capacity(num_tokens, deterministic)
             k = routing.expert.shape[-1]
             # which experts each token took, [G, S, k], best first (read
@@ -890,7 +915,8 @@ class MOELayer(nn.Module):
             + jnp.zeros((E,), jnp.int32).at[routing.expert[..., 1:].reshape(-1)].add(1))
         return combined, l_aux, exp_counts, kept_counts, routed_counts, capacity
 
-    def _held_route(self, gate, tokens, used_token, deterministic, impl, orig_dtype):
+    def _held_route(self, gate, gate_tokens, tokens, used_token, deterministic, impl,
+                    orig_dtype):
         """The sorted route where this device holds ``experts_held`` of the
         experts (drop-free, one device, so one group): a copy routed to an
         expert another device holds is that device's to compute, and a copy
@@ -906,7 +932,7 @@ class MOELayer(nn.Module):
         first, count = self.experts_held
 
         with jax.named_scope("moe_route"):
-            l_aux, routing, exp_counts = gate(tokens, used_token, deterministic,
+            l_aux, routing, exp_counts = gate(gate_tokens, used_token, deterministic,
                                               positions=False)
             k = routing.expert.shape[-1]
             copies = num_tokens * k
@@ -926,18 +952,24 @@ class MOELayer(nn.Module):
             _, copy_of = jax.lax.sort(
                 (jnp.where(mine, expert, count), jnp.arange(copies, dtype=jnp.int32)),
                 num_keys=1, is_stable=True)
-            rungs = _row_rungs(copies)
+            rungs = _row_rungs(copies, None if deterministic else count / self.num_experts)
             rung = sum((held_rows > rows).astype(jnp.int32) for rows in rungs[:-1])
 
-        if self.is_mutable_collection("cache"):
-            # for the host, beside a serving tick's tokens: rows routed to
-            # experts held here, rows of the tiles the expert matmuls run
-            # over, copies routed to any expert, held experts that got a row
-            # (whose weights the tick streams), rows of the buffer chosen
+        for collection, with_max in (("cache", False), (STEP_COUNTS, True)):
+            if not self.is_mutable_collection(collection):
+                continue
+            extra = (sizes.max(),) if with_max else ()
+            # for the host, beside a serving tick's tokens or a training
+            # step's loss: rows routed to experts held here, rows of the
+            # tiles the expert matmuls run over, copies routed to any expert,
+            # held experts that got a row (whose weights the tick streams),
+            # rows of the buffer chosen; a step adds the fullest held
+            # expert's rows (``HELD_COUNTS`` names them in this order)
             visited = jnp.stack([rows_visited(sizes, rows) for rows in rungs])
-            self.variable("cache", "moe_rows", jnp.zeros, (5,), jnp.int32).value = jnp.stack(
+            self.variable(collection, "moe_rows", jnp.zeros, (5 + len(extra),),
+                          jnp.int32).value = jnp.stack(
                 [held_rows, visited[rung], exp_counts.sum(), (sizes > 0).sum(),
-                 jnp.asarray(rungs, jnp.int32)[rung]]).astype(jnp.int32)
+                 jnp.asarray(rungs, jnp.int32)[rung], *extra]).astype(jnp.int32)
 
         tokens = self._latent("latent_down", tokens, orig_dtype)[0]
         weights = routing.weight.reshape(-1)
@@ -955,9 +987,11 @@ class MOELayer(nn.Module):
             with jax.named_scope("moe_combine"):
                 # each row back to its token, weighted: a token's up to k rows
                 # add up in float32. Rows past the held ones hold no defined
-                # result (``grouped_matmul``) and add nothing
-                weighted = jnp.where(real[:, None],
-                                     weights[copy_of].astype(orig_dtype)[:, None] * out, 0)
+                # result (``grouped_matmul``) and add nothing: zeroed BEFORE
+                # the product, so that the weights' gradient multiplies no
+                # undefined row either
+                weighted = (weights[copy_of].astype(orig_dtype)[:, None]
+                            * jnp.where(real[:, None], out, 0))
                 return jnp.zeros(tokens.shape, jnp.float32).at[token_of].add(
                     weighted.astype(jnp.float32), mode="drop").astype(orig_dtype)
 

@@ -269,7 +269,12 @@ def _grid_index(axis, extent):
 def _count_tiles(walk, n_outer, size_outer, size_inner, n_inner, scope, **masks):
     """Trace-time: the tiles of each kind one (batch, head) of a kernel
     runs, by the call's static masks (``kv_lengths`` are run-time values:
-    a tile they would kill counts as if the sequence were full)."""
+    a tile they would kill counts as if the sequence were full). Counted
+    over every call, and apart for the calls with a window and those
+    without (``attn_tiles_window_*`` / ``attn_tiles_full_*``, beside
+    ``attn_walks_window`` / ``_full``, the (batch, head) walks counted:
+    a window layer's live tiles a walk against a full layer's say what the
+    window skipped, however often a program was traced)."""
     dead = interior = edge = 0
     for t in range(n_outer):
         lo, ilo, ihi, hi = walk(t * size_outer, size_outer, size_inner, n_inner,
@@ -277,8 +282,11 @@ def _count_tiles(walk, n_outer, size_outer, size_inner, n_inner, scope, **masks)
         interior += ihi - ilo
         edge += (hi - lo) - (ihi - ilo)
         dead += n_inner - (hi - lo)
+    where = "full" if masks.get("window") is None else "window"
+    recorder().count(f"attn_walks_{where}", scope)
     for kind, n in (("dead", dead), ("interior", interior), ("edge", edge)):
         recorder().count(f"attn_tiles_{kind}", n * scope)
+        recorder().count(f"attn_tiles_{where}_{kind}", n * scope)
 
 
 _warned_fallback = set()
@@ -317,7 +325,8 @@ def per_shard(local, q, k, v, lengths):
         return axes if axes and extent % n == 0 else None
 
     batch = dividing(BATCH_AXES, q.shape[0])
-    heads = dividing((TENSOR_AXIS,), q.shape[2])
+    # grouped-query heads split only where the key heads split with them
+    heads = dividing((TENSOR_AXIS,), math.gcd(q.shape[2], k.shape[2]))
     blhd = P(batch, None, heads, None)
     in_specs = (blhd, blhd, blhd, None if lengths is None else P(batch))
     return jax.shard_map(local, mesh=mesh, in_specs=in_specs, out_specs=blhd,
@@ -432,36 +441,53 @@ def _pad_idx(fn, masked):
 
 
 def _length_call(kernel, grid, in_specs, out_specs, out_shape, scratch,
-                 interpret, kv_lengths, args):
+                 interpret, kv_lengths, args, name):
     """One pallas_call dispatch for the optional [B]-lengths scalar-prefetch
     operand (shared by fwd and both bwd passes so the masked/unmasked
-    switch cannot drift between them)."""
+    switch cannot drift between them). ``name`` is the kernel's in the
+    compiled program and in a device trace (``flash_fwd``, ``flash_bwd_dq``,
+    ``flash_bwd_dkv``): unnamed, both backward kernels read as the jit that
+    holds them."""
     if kv_lengths is not None:
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
                 out_specs=out_specs, scratch_shapes=scratch),
-            out_shape=out_shape, interpret=interpret,
+            out_shape=out_shape, interpret=interpret, name=name,
         )(kv_lengths.astype(jnp.int32), *args)
     return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
                           out_specs=out_specs, out_shape=out_shape,
-                          scratch_shapes=scratch, interpret=interpret)(*args)
+                          scratch_shapes=scratch, interpret=interpret, name=name)(*args)
 
 
-def _kv_index_map(causal, blk_q, blk_k, off, nk, masked=False, window=None):
+def _kv_head(q_heads, kv_heads):
+    """Query head -> the key/value head it reads (grouped-query attention:
+    ``q_heads // kv_heads`` query heads share one). The identity where the
+    counts are equal, so those kernels' index maps are what they were."""
+    if q_heads == kv_heads:
+        return lambda hi: hi
+    if q_heads % kv_heads:
+        raise ValueError(f"{q_heads} query heads do not group over {kv_heads} key heads")
+    n_rep = q_heads // kv_heads
+    return lambda hi: hi // n_rep
+
+
+def _kv_index_map(causal, blk_q, blk_k, off, nk, masked=False, window=None,
+                  kv_head=lambda hi: hi):
     """K/V block index for grid step (qi, j). Dead steps — causally dead,
     beyond the sequence's valid K prefix, or outside the sliding window —
     CLAMP to a live block: the index map re-requests the already-resident
     block, Mosaic elides the DMA, and the dead step moves no HBM bytes
     (the `pl.when` in the kernel already skips its FLOPs)."""
     if not causal and not masked and window is None:
-        return lambda bi, hi, qi, j: (bi, hi, j, 0)
+        return lambda bi, hi, qi, j: (bi, kv_head(hi), j, 0)
 
     def index(bi, hi, qi, j, *lens):
         first, _, _, end = _k_walk(qi * blk_q, blk_q, blk_k, nk, off, causal, window,
                                    lens[0][bi] if masked else None)
-        return (bi, hi, jnp.clip(j, jnp.minimum(first, nk - 1), jnp.maximum(end - 1, first)), 0)
+        return (bi, kv_head(hi),
+                jnp.clip(j, jnp.minimum(first, nk - 1), jnp.maximum(end - 1, first)), 0)
 
     return index
 
@@ -505,7 +531,8 @@ def _fwd_program(q, k, v, kv_lengths, *, scale, causal, blk_q, blk_k, tile, inte
     tq, tk = _tiles(blk_q, blk_k, tile)
     off = lk - lq
     masked = kv_lengths is not None
-    kv_idx = _kv_index_map(causal, blk_q, blk_k, off, nk, masked, window)
+    kv_idx = _kv_index_map(causal, blk_q, blk_k, off, nk, masked, window,
+                           _kv_head(h, k.shape[1]))
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal, window=window,
                                masked=masked, tq=tq, tk=tk, nq=nq, nk=nk)
     qo_idx = _pad_idx(lambda bi, hi, qi, j: (bi, hi, qi, 0), masked)
@@ -530,7 +557,7 @@ def _fwd_program(q, k, v, kv_lengths, *, scale, causal, blk_q, blk_k, tile, inte
     ]
     o, lse = _length_call(kernel, (b, h, nq, nk), in_specs, out_specs,
                           out_shape, scratch_shapes, interpret, kv_lengths,
-                          (q, k, v))
+                          (q, k, v), "flash_fwd")
     return o, lse.reshape(b, h, lq)
 
 
@@ -688,16 +715,17 @@ def _bwd_program(res, g, *, scale, causal, blk_q, blk_k, tile, interpret, window
     delta = (do.astype(jnp.float32) * o.astype(jnp.float32)).sum(axis=-1)  # [B,H,Lq]
 
     off = lk - lq
-    kv_idx = _kv_index_map(causal, blk_q, blk_k, off, nk, masked, window)
+    kv_head = _kv_head(h, k.shape[1])
+    kv_idx = _kv_index_map(causal, blk_q, blk_k, off, nk, masked, window, kv_head)
     qo_idx = _pad_idx(lambda bi, hi, qi, j: (bi, hi, qi, 0), masked)
     stat_q_idx = _pad_idx(lambda bi, hi, qi, j: (bi, hi, qi, 0, 0), masked)
     kernel_args = dict(scale=scale, causal=causal, window=window, masked=masked,
                        nq=nq, nk=nk)
 
-    def _call(kernel, tq, tk, grid, in_specs, out_specs, out_shape, scratch):
+    def _call(kernel, tq, tk, grid, in_specs, out_specs, out_shape, scratch, name):
         return _length_call(functools.partial(kernel, tq=tq, tk=tk, **kernel_args), grid,
                             in_specs, out_specs, out_shape, scratch, interpret, kv_lengths,
-                            (q, k, v, do, _stat_rows(lse, tq), _stat_rows(delta, tq)))
+                            (q, k, v, do, _stat_rows(lse, tq), _stat_rows(delta, tq)), name)
 
     n_qt = blk_q // tq
     dq = _call(
@@ -712,7 +740,7 @@ def _bwd_program(res, g, *, scale, causal, blk_q, blk_k, tile, interpret, window
         ],
         pl.BlockSpec((None, None, blk_q, d), qo_idx),
         jax.ShapeDtypeStruct(q.shape, q.dtype),
-        [pltpu.VMEM((n_qt, d, tq), jnp.float32)])
+        [pltpu.VMEM((n_qt, d, tq), jnp.float32)], "flash_bwd_dq")
 
     def _q_block(bi, ki, i, lens):
         """Q block to fetch for dkv step (ki, i): dead steps (causally,
@@ -735,7 +763,7 @@ def _bwd_program(res, g, *, scale, causal, blk_q, blk_k, tile, interpret, window
         # (their zero-initialized accumulators must be written back)
         if masked:
             ki = jnp.minimum(ki, jnp.maximum((lens[0][bi] + blk_k - 1) // blk_k, 1) - 1)
-        return (bi, hi, ki, 0)
+        return (bi, kv_head(hi), ki, 0)
 
     kv_out_idx = _pad_idx(lambda bi, hi, ki, i: (bi, hi, ki, 0), masked)
     dk, dv = _call(
@@ -753,11 +781,20 @@ def _bwd_program(res, g, *, scale, causal, blk_q, blk_k, tile, interpret, window
             pl.BlockSpec((None, None, blk_k, d), kv_out_idx),
         ],
         [
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, h) + k.shape[2:], k.dtype),
+            jax.ShapeDtypeStruct((b, h) + v.shape[2:], v.dtype),
         ],
         [pltpu.VMEM((blk_k, d), jnp.float32),
-         pltpu.VMEM((blk_k, d), jnp.float32)])
+         pltpu.VMEM((blk_k, d), jnp.float32)], "flash_bwd_dkv")
+    if k.shape[1] != h:
+        # grouped-query heads: the kernel wrote one dk, dv a QUERY head; a key
+        # head's gradient is the sum over the query heads that read it (what
+        # the transpose of a ``jnp.repeat`` would add up, with no repeated
+        # k, v ever written)
+        def grouped(t, like):
+            return t.reshape(b, like.shape[1], h // like.shape[1], lk, d).astype(
+                jnp.float32).sum(axis=2).astype(like.dtype)
+        dk, dv = grouped(dk, k), grouped(dv, v)
     return dq, dk, dv, None
 
 
@@ -960,6 +997,14 @@ def flash_attention(q: jax.Array,
     never knock another shape off the kernel."""
     b, lq, h, d = q.shape
     lk = k.shape[1]
+
+    def xla_attention(q, k, v, **kwargs):
+        """The XLA backend, handed as many key heads as query heads."""
+        from deepspeed_tpu.ops.transformer.attention import xla_attention as xla
+        if k.shape[2] != h:
+            k, v = (jnp.repeat(t, h // t.shape[2], axis=2) for t in (k, v))
+        return xla(q, k, v, **kwargs)
+
     if decode_lengths is not None and kv_lengths is not None:
         raise ValueError("pass decode_lengths (cache decode) or kv_lengths "
                          "(padded prefill), not both")
@@ -974,14 +1019,12 @@ def flash_attention(q: jax.Array,
             return flash_decode(q, k, v, decode_lengths, scale=scale,
                                 block_k=block_k, interpret=interpret)
         _warn_fallback("decode with bias/mask/dropout or untileable cache")
-        from deepspeed_tpu.ops.transformer.attention import xla_attention
         return xla_attention(q, k, v, causal=False, bias=bias, mask=mask, scale=scale,
                              dropout_rate=dropout_rate, dropout_rng=dropout_rng,
                              decode_lengths=decode_lengths)
     if bias is not None or mask is not None or (dropout_rate > 0.0 and dropout_rng is not None) \
             or (causal and lq > lk):
         _warn_fallback("bias/mask/dropout or lq>lk requested")
-        from deepspeed_tpu.ops.transformer.attention import xla_attention
         return xla_attention(q, k, v, causal=causal, bias=bias, mask=mask, scale=scale,
                              dropout_rate=dropout_rate, dropout_rng=dropout_rng,
                              kv_lengths=kv_lengths, window=window)
@@ -996,7 +1039,6 @@ def flash_attention(q: jax.Array,
             or (block_q_bwd and lq % block_q_bwd) or (block_k_bwd and lk % block_k_bwd):
         _warn_fallback(f"sequence lengths ({lq}, {lk}) not tileable by "
                        f"explicit blocks")
-        from deepspeed_tpu.ops.transformer.attention import xla_attention
         return xla_attention(q, k, v, causal=causal, scale=scale,
                              kv_lengths=kv_lengths, window=window)
     overrides = AttentionGeometry(block_q=block_q, block_k=block_k,

@@ -18,6 +18,7 @@ API parity:
 * ``save_checkpoint``/``load_checkpoint`` (``engine.py:2906,2601``).
 """
 
+import functools
 import os
 import time
 from typing import Any, Callable, NamedTuple, Optional
@@ -818,11 +819,14 @@ class DeepSpeedEngine:
     # (reference stage_1_and_2 cpu_offload / stage3 + swap_tensor; SURVEY §7.3)
     # ------------------------------------------------------------------
     def _accumulate_grads(self, params, batch, rng, scale, grad_shardings, gas, clip, fp16,
-                          params_transform=None, model_extra=None):
+                          params_transform=None, model_extra=None, step_counts=None):
         """The shared fwd+bwd core: GAS microbatch scan, 1/gas averaging,
         quantized or full-precision ZeRO reduction, clipping, overflow.
         Used by the fused on-device step AND the offload grads-only step so
-        the two paths cannot drift. ``params_transform`` (compression-in-
+        the two paths cannot drift. ``step_counts`` (a dict, the fused step's
+        where the module names step counts): the forward's device-side counts
+        come back in it under ``"counts"``, [gas, layers, counts] int32.
+        ``params_transform`` (compression-in-
         forward) runs INSIDE the grad closure so masks gate gradients and
         the quantization STE applies. ``model_extra`` (traced scalars such
         as the PLD theta) merges into every microbatch dict so
@@ -832,18 +836,18 @@ class DeepSpeedEngine:
         if model_extra:
             base_loss_for_extra = loss_for
 
-            def loss_for(p, mb, key, scale, train=True):
+            def loss_for(p, mb, key, scale, train=True, **kw):
                 # raw-array batches are normalized to a dict so the extras
                 # (pld_theta) still reach the model
                 mb = dict(mb, **model_extra) if isinstance(mb, dict) \
                     else dict({"input_ids": mb}, **model_extra)
-                return base_loss_for_extra(p, mb, key, scale, train=train)
+                return base_loss_for_extra(p, mb, key, scale, train=train, **kw)
         loss_for_with_extra = loss_for
         if params_transform is not None:
             base_loss_for = loss_for
 
-            def loss_for(p, mb, key, scale, train=True):
-                return base_loss_for(params_transform(p), mb, key, scale, train=train)
+            def loss_for(p, mb, key, scale, train=True, **kw):
+                return base_loss_for(params_transform(p), mb, key, scale, train=train, **kw)
 
         if getattr(self, "_use_qcomm", False):
             # ZeRO++ real quantized collectives: the whole gather→scan→reduce
@@ -873,14 +877,20 @@ class DeepSpeedEngine:
                 grads = jax.tree.map(lambda g: g * factor, grads)
             return loss_mean, grads, gnorm, overflow
 
+        if step_counts is not None:
+            loss_for = functools.partial(loss_for, with_counts=True)
+
         def micro(acc, xs):
             mb, key = xs
-            (_, loss), grads = jax.value_and_grad(loss_for, has_aux=True)(params, mb, key, scale)
+            # the aux is the loss, or (loss, counts) where the step counts
+            (_, aux), grads = jax.value_and_grad(loss_for, has_aux=True)(params, mb, key, scale)
             grads = _cast_floating(grads, jnp.float32)
-            return jax.tree.map(jnp.add, acc, grads), loss
+            return jax.tree.map(jnp.add, acc, grads), aux
 
         zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
         grads, losses = jax.lax.scan(micro, zeros, (batch, keys))
+        if step_counts is not None:
+            losses, step_counts["counts"] = losses      # [gas, layers, counts]
         # average over microbatches and unscale (reference engine.py:1868
         # scales loss by 1/GAS; fp16 unscaling in optimizer step)
         grads = jax.tree.map(lambda g: g / (gas * scale), grads)
@@ -1394,7 +1404,32 @@ class DeepSpeedEngine:
         leaves, treedef = jax.tree.flatten(grads)
         return jax.tree.unflatten(treedef, [qdq((i, g)) for i, g in enumerate(leaves)])
 
-    def _loss_for(self, params, mb, key, scale, train: bool = True):
+    def _step_count_names(self):
+        """The names of the counts the module's layers make on the device in
+        a training step (``module.step_count_names()``; none for most), which
+        the fused step then returns beside its loss."""
+        if getattr(self, "_use_qcomm", False) or self._kd_config is not None:
+            # those steps trace a loss of their own (quantized collectives;
+            # distillation's captured forward), which returns no counts
+            return ()
+        names = getattr(self.module, "step_count_names", None)
+        return tuple(names()) if callable(names) else ()
+
+    def _record_step_counts(self, counts):
+        """One step's device-side counts into the program's recorder: each
+        name summed over the layers as a counter ``moe_<name>``, and a layer
+        at a time as ring records ``count:moe_<name>`` (the count in ``uid``,
+        ``layer_<i>`` in ``kind``). The step is over (the timer's sync has
+        returned), so the read waits for nothing."""
+        per_layer = np.asarray(jax.device_get(counts)).sum(axis=0)     # [layers, counts]
+        self._last_step_counts = per_layer      # the monitor's, at its cadence
+        rec, now = trace.recorder(), time.perf_counter()
+        for at, name in enumerate(self._step_count_names()):
+            rec.count("moe_" + name, int(per_layer[:, at].sum()))
+            for layer, n in enumerate(per_layer[:, at]):
+                rec.record("count:moe_" + name, now, now, int(n), kind=f"layer_{layer}")
+
+    def _loss_for(self, params, mb, key, scale, train: bool = True, with_counts: bool = False):
         if getattr(self, "_param_offload_enabled", False):
             # ZeRO-Infinity param streaming: non-block leaves h2d here; block
             # subtrees pass through as host references and self-stream inside
@@ -1405,10 +1440,17 @@ class DeepSpeedEngine:
             with param_streaming(cast_dtype=self.compute_dtype):
                 params = stream_tree(
                     params, skip_prefixes=getattr(self.module, "streamed_block_prefixes", ()))
-                return self._loss_for_impl(params, mb, key, scale, train, precast=True)
-        return self._loss_for_impl(params, mb, key, scale, train)
+                return self._loss_for_impl(params, mb, key, scale, train, precast=True,
+                                           with_counts=with_counts)
+        return self._loss_for_impl(params, mb, key, scale, train, with_counts=with_counts)
 
-    def _loss_for_impl(self, params, mb, key, scale, train: bool = True, precast: bool = False):
+    def _loss_for_impl(self, params, mb, key, scale, train: bool = True, precast: bool = False,
+                       with_counts: bool = False):
+        """``with_counts`` (the fused train step, a module that names step
+        counts): the forward also returns what its layers counted on the
+        device this micro-batch (``moe/sharded_moe.py`` ``STEP_COUNTS``), as
+        ``[layers, counts]`` int32 beside the loss: the aux is then
+        ``(loss, counts)``."""
         if self.config.zero_config.zero_quantized_weights and not getattr(self, "_qcomm_tracing", False):
             # QDQ numerics apply everywhere EXCEPT inside the qcomm trace,
             # where the gather itself carries the int8 payload
@@ -1447,6 +1489,11 @@ class DeepSpeedEngine:
                     {"params": cparams}, ids, deterministic=False, rngs=rngs,
                     capture_intermediates=self._kd_block_filter(), **extra)
                 caps = ivars["intermediates"]
+            elif with_counts:
+                from deepspeed_tpu.moe.sharded_moe import STEP_COUNTS
+                outputs, counted = self.module.apply({"params": cparams}, ids, deterministic=False,
+                                                     rngs=rngs, mutable=[STEP_COUNTS], **extra)
+                counts = self.module.step_counts(counted[STEP_COUNTS])     # [layers, counts]
             else:
                 outputs = self.module.apply({"params": cparams}, ids, deterministic=False,
                                             rngs=rngs, **extra)
@@ -1470,6 +1517,8 @@ class DeepSpeedEngine:
                                  "fused_head_loss_chunk never materializes them — "
                                  "disable one of the two")
             loss = self._apply_kd(loss, outputs, ids, mb, caps, extra)
+        if with_counts:
+            return (loss * scale).astype(jnp.float32), (loss, counts)
         return (loss * scale).astype(jnp.float32), loss
 
     def _maybe_apply_student_init(self):
@@ -1867,9 +1916,10 @@ class DeepSpeedEngine:
                 # the KD schedule gate reads the live step counter in-graph
                 # (same mechanism as the PLD theta — no retrace on activation)
                 extra = dict(extra or {}, _kd_step=state.step)
+            counted = {} if self._step_count_names() else None
             losses, grads, gnorm, overflow = self._accumulate_grads(
                 state.params, batch, rng, scale, grad_shardings, gas, clip, fp16,
-                params_transform=pt, model_extra=extra)
+                params_transform=pt, model_extra=extra, step_counts=counted)
             if getattr(self, "_param_offload_enabled", False):
                 # second touch of the step (reference optimizer-substep param
                 # access): stream the host-resident masters in for the update
@@ -1899,6 +1949,8 @@ class DeepSpeedEngine:
                 "overflow": overflow,
                 "loss_scale": new_ls.loss_scale,
             }
+            if counted:
+                metrics["step_counts"] = counted["counts"]
             return new_state, metrics
 
         # batch leaves keep the shardings _shard_batch placed them with (a
@@ -2471,6 +2523,8 @@ class DeepSpeedEngine:
             self._last_compressed_update_norm = metrics["compressed_update_norm"]
         if "grad_norm" in metrics:
             self._last_grad_norm = metrics["grad_norm"]
+        if "step_counts" in metrics:
+            self._record_step_counts(metrics["step_counts"])
         ov = metrics.get("overflow")
         if ov is not None:
             self._pending_overflow.append((self.global_steps, ov, metrics.get("loss_scale")))
@@ -2485,6 +2539,11 @@ class DeepSpeedEngine:
                 events, self._resilience_events = events + self._resilience_events, []
             if self._fp16_mode:
                 events.append((f"Train/loss_scale", float(metrics["loss_scale"]), self.global_samples))
+            if getattr(self, "_last_step_counts", None) is not None:
+                # the step's own device-side counts, summed over its layers
+                events += [(f"MoE/step_{name}", float(self._last_step_counts[:, at].sum()),
+                            self.global_samples)
+                           for at, name in enumerate(self._step_count_names())]
             batch = getattr(self, "_last_batch_for_stats", None)
             mcfg = getattr(self.module, "config", None)
             if batch is not None and mcfg is not None and getattr(mcfg, "moe_num_experts", 0) > 0:

@@ -447,3 +447,58 @@ def test_window_composes_with_kv_lengths():
     for a, bb, name in zip(gf, gx, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(bb), atol=3e-4,
                                    err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
+# grouped-query heads inside the kernels, with and without a window, against
+# dense attention with the mask written out (no backend of the package)
+# ---------------------------------------------------------------------------
+def _dense_masked(q, k, v, window):
+    """softmax(q k^T / sqrt(d) + mask) v over BLHD operands in float32, query
+    head ``h`` reading key head ``h // (H / Hk)``; ``window`` None = causal."""
+    b, l, h, d = q.shape
+    n_rep = h // k.shape[2]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, n_rep, axis=2)) / np.sqrt(d)
+    at = np.arange(l)
+    live = at[None, :] <= at[:, None]
+    if window is not None:
+        live &= at[None, :] > at[:, None] - window
+    probs = jax.nn.softmax(jnp.where(live, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, jnp.repeat(v, n_rep, axis=2))
+
+
+@pytest.mark.parametrize("window", [None, 96], ids=["full", "window96"])
+def test_grouped_query_heads_forward_and_gradients(window):
+    """4 query heads on 2 key heads, 256 positions in 64-position blocks: with
+    the window of 96 the key block 0 lies wholly below the window of query
+    blocks 2 and 3 (a dead tile on the lower side), block 1 straddles its
+    edge. dq, dk and dv against the dense form's, dk and dv summed over the
+    two query heads that share a key head."""
+    rng = np.random.default_rng(23)
+    b, l, h, hk, d = 2, 256, 4, 2, 32
+    q, _, _ = _rand_qkv(rng, b, l, h, d)
+    k, v, _ = _rand_qkv(rng, b, l, hk, d)
+    flash = lambda q_, k_, v_: flash_attention(  # noqa: E731
+        q_, k_, v_, causal=True, window=window, block_q=64, block_k=64, interpret=True)
+    dense = lambda q_, k_, v_: _dense_masked(q_, k_, v_, window)  # noqa: E731
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)), np.asarray(dense(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    weight = jnp.asarray(rng.standard_normal((b, l, h, d)), jnp.float32)
+    grads = lambda fn: jax.grad(  # noqa: E731
+        lambda *a: jnp.sum(fn(*a) * weight), argnums=(0, 1, 2))(q, k, v)
+    for got, want, name in zip(grads(flash), grads(dense), "qkv"):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-4,
+                                   err_msg=f"d{name}")
+
+
+def test_grouped_query_heads_fall_back_with_the_heads_repeated():
+    """A call the kernel does not cover (a mask) goes to XLA with as many key
+    heads as query heads."""
+    rng = np.random.default_rng(24)
+    q, _, _ = _rand_qkv(rng, 1, 64, 4, 32)
+    k, v, _ = _rand_qkv(rng, 1, 64, 2, 32)
+    mask = jnp.ones((1, 1, 64, 64), bool)
+    got = flash_attention(q, k, v, causal=True, mask=mask, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_dense_masked(q, k, v, None)),
+                               rtol=2e-5, atol=2e-5)

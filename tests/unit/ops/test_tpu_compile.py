@@ -667,3 +667,51 @@ def test_zero3_step_compiles_over_four_chips(one_chip, topo):
         set_topology(None)
     text = _kernel_text(compiled)
     assert "all-gather" in text and "reduce-scatter" in text
+
+
+def test_smallthinker_train_step_compiles_and_fits_one_chip(one_chip, topo):
+    """The SmallThinker cell's step as the benchmark builds it, at the cell's
+    sizes (four layers of the published widths, 16 of 64 experts held, the
+    one sequence of 16,384 a step that ``pretrain-seq16k`` gives): the held route's backward (``gmm`` / ``tgmm`` over
+    group sizes short of the rows, inside ``nn.switch`` under remat), the
+    flash kernels with a window of 4,096 and 28 query heads on 4 key heads,
+    forward, dq and dkv, in one program that fits the chip beside its fp32
+    masters and Adam moments."""
+    import json
+
+    import deepspeed_tpu
+    from benchmarks.lib import harness
+    from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
+
+    root = harness.REPO_ROOT
+    config = harness.load_json(root, "benchmarks", "configs", "smallthinker-21b-a3b.json")
+    traffic = harness.load_json(root, "benchmarks", "traffic", "pretrain-seq16k.json")
+    family = harness.load_module(root, "benchmarks", "families", "smallthinker.py")
+    dep, seqs, seq = config["train"], traffic["seqs_per_chip"], traffic["seq_len"]
+    model = family.model(config, dep, n_positions=seq, remat=dep["remat"],
+                         attention_backend=dep["attention_backend"], dtype=bf16,
+                         fused_head_loss_chunk=dep["fused_head_loss_chunk"])
+    try:
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=model, topology=MeshTopology(fsdp=1, data=1, devices=topo.devices[:1]),
+            config={"train_batch_size": seqs, "optimizer": dep["optimizer"],
+                    "bf16": {"enabled": True}, "gradient_clipping": dep["gradient_clipping"],
+                    "zero_optimization": {"stage": 0}, "steps_per_print": 10**9})
+        lowered = engine.lower_train_step({"input_ids": np.zeros((seqs, seq), np.int32)})
+        compiled = lowered.compile()
+    finally:
+        set_topology(None)
+    text = _kernel_text(compiled)
+    # megablox's kernels are named after the jit that holds them, with or
+    # without what differentiated them around the name
+    import re
+    for kernel in (r"%flash_fwd", r"%flash_bwd_dq", r"%flash_bwd_dkv", r"(?<!t)gmm", r"tgmm"):
+        assert re.search(kernel, text), kernel
+    mem = compiled.memory_analysis()
+    print(json.dumps({"argument_gib": mem.argument_size_in_bytes / 2**30,
+                      "temp_gib": mem.temp_size_in_bytes / 2**30,
+                      "output_gib": mem.output_size_in_bytes / 2**30,
+                      "alias_gib": mem.alias_size_in_bytes / 2**30}))
+    # that it compiled says it fits the chip's 15.75 GiB (the compiler refuses
+    # a program that does not); the masters and moments are most of it
+    assert mem.argument_size_in_bytes > 7.8e9
