@@ -434,6 +434,24 @@ def kernels_phase(batch=4, seq=1024, heads=16, head_dim=64, width=1024):
     check("dsa_select", jnp.where(jnp.arange(extent) < written, got, 0.0),
           want.astype(jnp.float32), ulps=0)
 
+    # a plain latent layer's chunk: the walk that keeps a step's scores in VMEM,
+    # its mask read off the positions, against XLA's loops; at the defaults the
+    # long-document cell's shapes (32 heads of 128 + 64, a chunk of 512 over
+    # pools of 16,384). A slot at 0 (a row of one key: values of O(1)), a chunk
+    # that starts inside a block, a padded one, a slot fed nothing
+    from deepspeed_tpu.models.deepseek_v3 import expanded_walk, kernel_walk
+    chunk, extent, rank, dn, dr = seq // 2, 16 * seq, width // 2, 2 * head_dim, head_dim
+    pool = normal(4, rank + dr, extent)
+    q_nope, q_rope = normal(4, chunk, 2 * heads, dn), normal(4, chunk, 2 * heads, dr)
+    w_kvb = (normal(rank, 2 * heads, 2 * dn, dtype=jnp.float32) * rank ** -0.5).astype(bf16)
+    start = jnp.asarray([0, 9 * seq - chunk // 4, extent, extent - chunk], jnp.int32)
+    fed = jnp.asarray([chunk, chunk, 0, chunk - 5], jnp.int32)
+    got = _run_kernel(kernel_walk, q_nope, q_rope, pool, w_kvb, start, fed)
+    want = _reference(lambda *a: expanded_walk(*a, seq // 2), q_nope, q_rope, pool, w_kvb, start,
+                      fed)
+    real = jnp.arange(chunk)[None, :, None, None] < fed[:, None, None, None]
+    check("mla_prefill_walk", jnp.where(real, got, 0), jnp.where(real, want, 0), ulps=2)
+
     obs = dict(compiled=_kernels_compiled(), worst_bf16_roundings=worst)
     _emit("kernels", **obs)
     return obs

@@ -27,10 +27,31 @@ Which form runs is decided by the shapes, in one module
 (a decode tick) is absorbed, and reads the pool as it lies: expanding 16k
 cached positions to 32 heads every tick would cost seventeen times the
 tick's bytes. Anything longer (a prefill chunk, a whole sequence) is
-expanded, a sequence and a block of keys at a time (:func:`expanded_walk`):
-the latent of one block is expanded inside the walk, no ``[slots, heads,
-chunk, positions]`` scores and no expanded pool ever exist, and a slot's
-walk ends at its own live length (a parked slot's is empty).
+expanded, a sequence and a block of keys at a time: the latent of one block
+is expanded inside the walk, no ``[slots, heads, chunk, positions]`` scores
+and no expanded pool ever exist, and a slot's walk ends at its own live length
+(a parked slot's is empty). The walk is XLA's loops (:func:`expanded_walk`:
+every step's float32 scores pass through HBM) or, for a chunk of a full layer
+over a cache on a TPU whose shapes the kernel takes (a chunk of a multiple of
+16, whole groups of eight heads, whole key blocks), one kernel a fed slot that
+keeps them in VMEM (:func:`kernel_walk`, ``ops/pallas/latent_walk.py``); which
+of the two follows from the platform, the layer's kind and the shapes, never
+from an option:
+
+================  =============================  ================================
+layer             chunk over a cache, on a TPU   anywhere else (``decode=False``,
+                                                 off the chip, shapes refused)
+================  =============================  ================================
+plain             the kernel, the causal mask    :func:`expanded_walk`
+                  read off the positions inside
+                  it (``mla_prefill_walk``)
+indexed           the kernel under the slot's    :func:`expanded_walk` under
+                  selection, handed as a mask    ``_chosen_chunk``'s ``allow``
+                  (``dsa_prefill_walk``)
+window            :func:`expanded_walk` under    the same
+                  :func:`ring_mask` (a ring is
+                  2 MB a slot)
+================  =============================  ================================
 
 The residual stream is float32 between the layers whatever they compute in.
 
@@ -64,8 +85,8 @@ sigmoid(x W_g)_h o_h``, before ``W_o`` (``attention_gate`` ``headwise``).
   (``ops/pallas/sparse_index.py``) and runs the absorbed kernel over the live
   latent blocks with the unchosen columns masked; a chunk scores one slot's
   keys for all its queries, and the expanded walk masks each block by them
-  (on the chip a kernel that keeps a step's scores in VMEM,
-  ``ops/pallas/latent_walk.py``; elsewhere :func:`expanded_walk` under
+  (the table above: on the chip the kernel a plain layer's chunk also walks
+  in, handed the selection as its mask; elsewhere :func:`expanded_walk` under
   ``allow``). Both compute exactly the reference's set.
 
 RoPE rotates the pairs ``(2i, 2i+1)`` (``rope_interleave``) by ``theta``;
@@ -374,6 +395,43 @@ def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int, allow=Non
     return jax.lax.fori_loop(0, b, sequence, jnp.zeros((b, l, heads, dv), dtype))
 
 
+def kernel_walk(q_nope, q_rope, pool, w_kvb, start, fed, chosen=None):
+    """:func:`expanded_walk` on the chip, a fed slot at a time through the
+    kernel that keeps a step's scores in VMEM (``ops/pallas/latent_walk.py``;
+    shapes it ``takes``): the slot's pool is read in place, blocks past
+    ``start + fed`` are neither moved nor run, and a slot that is fed nothing
+    reads nothing and gives zeros. A plain layer's queries attend every
+    position at or before their own, the mask read off the positions inside
+    the kernel; an indexed layer hands ``chosen(s)``, the [l, positions]
+    float32 mask of slot ``s`` (> 0: attended; causality included). Same
+    operands and result as :func:`expanded_walk`."""
+    from deepspeed_tpu.ops.pallas import latent_walk
+    b, l, heads, dn = q_nope.shape
+    dv = w_kvb.shape[-1] - dn
+    positions, dtype = pool.shape[-1], q_nope.dtype
+    scale = (dn + q_rope.shape[-1]) ** -0.5
+    # heads first, as the kernel blocks them: the weights once, a fed slot's
+    # queries as its turn comes (an unfed slot's are never moved)
+    w = jnp.swapaxes(w_kvb, 0, 1).astype(dtype)                          # [H, rank, dn + dv]
+    of_slot = lambda t, s: jnp.moveaxis(  # noqa: E731  [H, l, d]
+        jax.lax.dynamic_index_in_dim(t, s, 0, keepdims=False), 1, 0)
+
+    def attend(s):
+        blocks, _ = latent_walk.walk_blocks(start[s] + fed[s], positions)
+        operands = of_slot(q_nope, s), of_slot(q_rope, s), w, pool
+        if chosen is None:
+            out = latent_walk.causal_walk(*operands, start[s], s, blocks, scale=scale)
+        else:
+            out = latent_walk.selected_walk(*operands, chosen(s), s, blocks, scale=scale)
+        return jnp.moveaxis(out, 0, 1)                                   # [l, H, dv]
+
+    def sequence(s, out):
+        rows = jax.lax.cond(fed[s] > 0, attend, lambda s: jnp.zeros((l, heads, dv), dtype), s)
+        return jax.lax.dynamic_update_index_in_dim(out, rows, s, 0)
+
+    return jax.lax.fori_loop(0, b, sequence, jnp.zeros((b, l, heads, dv), dtype))
+
+
 def absorbed_step(q_nope, q_rope, pool, w_kvb, lengths, chosen=None):
     """Absorbed latent attention of ONE query a sequence over its pool:
     ``q_nope`` [b, H, dn], ``q_rope`` [b, H, dr] (rotated), ``pool`` [b, rank +
@@ -634,6 +692,10 @@ class LatentAttention(nn.Module):
                 keys = as_pool(index_k) if kind.top_k else None
             elif pool.shape[-1] % block:
                 block = pool.shape[-1]
+            in_kernel = self._walks_in_kernel(l, pool, start)
+            if in_kernel:
+                from deepspeed_tpu.ops.pallas import latent_walk
+                block = min(latent_walk.BLOCK, pool.shape[-1])
             allow = None
             if kind.window:
                 places, window = pool.shape[-1], kind.window
@@ -644,9 +706,10 @@ class LatentAttention(nn.Module):
             with (jax.named_scope("swa_attend_prefill") if kind.window else
                   jax.named_scope("dsa_attend_prefill") if kind.top_k
                   else contextlib.nullcontext()):
-                if kind.top_k and self._walks_in_kernel(l, pool, start):
-                    out = self._walk_chosen(q_nope, q_rope, index_q, index_w, pool, keys, w_kvb,
-                                            start, fed)
+                if in_kernel:
+                    out = kernel_walk(q_nope, q_rope, pool, w_kvb, start, fed,
+                                      self._chosen_in_kernel(index_q, index_w, keys, start, fed)
+                                      if kind.top_k else None)
                 else:
                     out = expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block, allow)
             if decode:
@@ -664,8 +727,7 @@ class LatentAttention(nn.Module):
                     counts = {"dsa_index_keys_read": blocks.sum() * size,
                               "dsa_select_positions_read": sparse_select.tile_blocks(
                                   fed, blocks, l).sum() * (sparse_select.row_tile(l) * size)
-                              if self._walks_in_kernel(l, pool, start)
-                              else (fed > 0).sum() * (l * keys.shape[-1]),
+                              if in_kernel else (fed > 0).sum() * (l * keys.shape[-1]),
                               "dsa_positions_selected": jnp.where(
                                   real, jnp.minimum(each, kind.top_k), 0).sum(),
                               "dsa_positions_live": jnp.where(real, each, 0).sum()}
@@ -732,55 +794,41 @@ class LatentAttention(nn.Module):
                         "dsa_positions_live": lengths.sum()}
 
     def _walks_in_kernel(self, l: int, pool, start) -> bool:
-        """Whether a chunk of an indexed layer over a serving pool runs as
-        the chip's three kernels (scores, selection, walk) a slot."""
+        """Whether a chunk of a full layer over a cache's pool walks it in the
+        chip's kernel (:func:`kernel_walk`; an indexed layer with the two
+        kernels that score and select before it, a slot at a time)."""
         from deepspeed_tpu.ops.pallas import backend, latent_walk, sparse_index, sparse_select
-        return bool(backend.on_tpu() and start is not None and sparse_index.chunk_tile(l)
-                    and sparse_select.takes(l, pool.shape[-1])
-                    and latent_walk.takes(l, self.kind.heads, pool.shape[-1]))
-
-    def _walk_chosen(self, q_nope, q_rope, index_q, index_w, pool, keys, w_kvb, start, fed):
-        """A chunk of an indexed layer on the chip, a fed slot at a time: the
-        slot's index scores (``sparse_index.index_scores_chunk``), the mask of
-        each query's chosen over the slot's live blocks
-        (``sparse_select.select_top_k``: the scores are read once), and the
-        expanded walk under it (``latent_walk.selected_walk``: the scores stay
-        in VMEM). A slot that is fed nothing reads nothing and gives zeros.
-        The numbers are :func:`expanded_walk`'s under :meth:`_chosen_chunk`."""
-        from deepspeed_tpu.ops.pallas import latent_walk, sparse_index, sparse_select
         kind = self.kind
-        b, l = q_nope.shape[:2]
-        positions, dtype = pool.shape[-1], q_nope.dtype
-        scale = (kind.d_nope + kind.d_rope) ** -0.5
-        heads_first = lambda t: jnp.moveaxis(t, 2, 1)  # noqa: E731  [b, H, l, d]
-        q_nope, q_rope = heads_first(q_nope), heads_first(q_rope)
-        w_k = jnp.transpose(w_kvb[..., :kind.d_nope], (1, 2, 0)).astype(dtype)     # [H, dn, rank]
-        w_v = jnp.transpose(w_kvb[..., kind.d_nope:], (1, 2, 0)).astype(dtype)
+        if not (backend.on_tpu() and start is not None and not kind.window
+                and latent_walk.takes(l, kind.heads, pool.shape[-1])):
+            return False
+        return bool(not kind.top_k or (sparse_index.chunk_tile(l)
+                                       and sparse_select.takes(l, pool.shape[-1])))
+
+    def _chosen_in_kernel(self, index_q, index_w, keys, start, fed):
+        """The ``chosen`` of :func:`kernel_walk` for a chunk of an indexed
+        layer on the chip: a slot's index scores
+        (``sparse_index.index_scores_chunk``) and the mask of each query's
+        chosen over the slot's live blocks (``sparse_select.select_top_k``: the
+        scores are read once). The set is :meth:`_chosen_chunk`'s."""
+        from deepspeed_tpu.ops.pallas import sparse_index, sparse_select
+        b, l = index_q.shape[:2]
+        positions, top_k = keys.shape[-1], self.kind.top_k
         index_q = index_q.reshape(b, l, -1)
         slot_of = lambda t, s: jax.lax.dynamic_index_in_dim(t, s, 0, keepdims=False)  # noqa: E731
 
-        def attend(s):
-            q_pos, live = start[s] + jnp.arange(l), start[s] + fed[s]
+        def chosen(s):
+            q_pos = start[s] + jnp.arange(l)
             with jax.named_scope("dsa_index"):
-                blocks, _ = sparse_index.chunk_blocks(live, positions)
+                blocks, _ = sparse_index.chunk_blocks(start[s] + fed[s], positions)
                 scores = sparse_index.index_scores_chunk(slot_of(index_q, s), slot_of(index_w, s),
                                                          keys, s, blocks)
             with jax.named_scope("dsa_select"):
                 # the tiles of rows past the slot's real queries choose nothing
-                may = sparse_select.select_top_k(
-                    scores, q_pos + 1, sparse_select.tile_blocks(fed[s], blocks, l), kind.top_k)
-            blocks, _ = latent_walk.walk_blocks(live, positions)
-            out = latent_walk.selected_walk(slot_of(q_nope, s), slot_of(q_rope, s), w_k, w_v, pool,
-                                            may, s, blocks, scale=scale)
-            return jnp.moveaxis(out, 0, 1)                                   # [l, H, dv]
+                return sparse_select.select_top_k(
+                    scores, q_pos + 1, sparse_select.tile_blocks(fed[s], blocks, l), top_k)
 
-        def sequence(s, out):
-            rows = jax.lax.cond(fed[s] > 0, attend,
-                                lambda s: jnp.zeros((l, kind.heads, kind.d_value), dtype), s)
-            return jax.lax.dynamic_update_index_in_dim(out, rows, s, 0)
-
-        return jax.lax.fori_loop(0, b, sequence,
-                                 jnp.zeros((b, l, kind.heads, kind.d_value), dtype))
+        return chosen
 
     def _chosen_chunk(self, q, w, keys, start, block: int):
         """The ``allow`` of :func:`expanded_walk` for a chunk of an indexed

@@ -424,9 +424,8 @@ def test_the_walk_kernel_is_the_expanded_walk_under_a_mask():
         blocks, block = latent_walk.walk_blocks(start[s] + fed[s], positions, 16)
         got = latent_walk.selected_walk(
             jnp.moveaxis(q_nope[s], 1, 0), jnp.moveaxis(q_rope[s], 1, 0),
-            jnp.transpose(w_kvb[..., :dn], (1, 2, 0)), jnp.transpose(w_kvb[..., dn:], (1, 2, 0)),
-            pool, may[s].astype(jnp.float32), s, blocks, scale=(dn + dr) ** -0.5, block=block,
-            group=2)
+            jnp.swapaxes(w_kvb, 0, 1), pool, may[s].astype(jnp.float32), s, blocks,
+            scale=(dn + dr) ** -0.5, block=block, group=2)
         np.testing.assert_allclose(jnp.moveaxis(got, 0, 1)[:real], want[s, :real], atol=2e-6)
     assert latent_walk.takes(256, 128, 32768) and not latent_walk.takes(12, 128, 32768)
 

@@ -405,8 +405,9 @@ def test_joyai_llm_flash_serving_program_reads_the_latent_pool_as_it_lies(one_ch
     position, donated and written in place. The decode tick is absorbed: no
     copy, convert or transpose of anything the size of a pool, and its
     temporaries are a fortieth of one. The prefill tick walks the keys in
-    blocks: nothing near the [slots, heads, chunk, positions] scores (25.8
-    GB) or an expanded pool (6.4 GB) is ever held."""
+    blocks, a fed slot at a time in the kernel that keeps a step's scores in
+    VMEM: nothing near the [slots, heads, chunk, positions] scores (25.8 GB)
+    or an expanded pool (6.4 GB) is ever held."""
     import flax.linen as nn
     from deepspeed_tpu.inference.serving.programs import (build_decode_step,
                                                           build_prefill_step,
@@ -441,21 +442,27 @@ def test_joyai_llm_flash_serving_program_reads_the_latent_pool_as_it_lies(one_ch
         assert compiled.as_text().count("%mla_decode") >= layers
         assert memory.temp_size_in_bytes < pool_bytes // 40        # against 604 MB
     else:
+        # a fed slot's walk is one kernel a layer, its scores in VMEM
+        # (``latent_walk.causal_walk``), and XLA's loops of the walk are gone
+        assert compiled.as_text().count("%mla_prefill_walk") >= layers
         # the largest row buffer of the held route (131,072 copies of 2,048) and
-        # the dense layer's 7,168-wide activations; compiles to 1.82 GB
-        assert memory.temp_size_in_bytes < 2.0e9
+        # the dense layer's 7,168-wide activations; compiles to 1,821,700,608
+        # bytes (1,821,313,536 with the walk as XLA's loops)
+        assert memory.temp_size_in_bytes < 1.823e9
 
 
 # ---------------------------------------------------------------------------
 # indexed and window layers of latent attention (ISSUE 37)
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("kernel", ["index_decode", "index_chunk", "selected_decode",
-                                    "selected_walk", "select_chunk", "select_decode"])
+                                    "selected_walk", "causal_walk", "select_chunk",
+                                    "select_decode"])
 def test_sparse_attention_kernels_compile(one_chip, kernel):
     """The five kernels of an indexed layer at the long-context cell's
     shapes: 32 slots x 32,768 positions, 64 index heads of 128, 128 heads over
     a 576-wide latent, a chunk of 256 queries of one slot; the selection over
-    a chunk's rows and over a row a slot."""
+    a chunk's rows and over a row a slot. The walk's causal form at the
+    long-document cell's: 32 heads, a chunk of 512, pools of 16,384."""
     from deepspeed_tpu.ops.pallas import latent_decode, latent_walk, sparse_index, sparse_select
     slots, positions, heads, d = 32, 32768, 64, 128
     lengths = _shape(slots, dtype=jnp.int32)
@@ -474,12 +481,19 @@ def test_sparse_attention_kernels_compile(one_chip, kernel):
             lambda scores, bound, n: sparse_select.select_top_k(scores, bound, n, 2048),
             one_chip, _shape(rows, positions, dtype=jnp.float32), _shape(rows, dtype=jnp.int32),
             _shape(rows // sparse_select.row_tile(rows), dtype=jnp.int32))
+    elif kernel == "causal_walk":
+        compiled = _compile(
+            lambda qn, qr, w, pool, first, slot, n: latent_walk.causal_walk(
+                qn, qr, w, pool, first, slot, n, scale=0.07),
+            one_chip, _shape(32, 512, 128), _shape(32, 512, 64), _shape(32, 512, 256),
+            _shape(slots, 576, 16384), *[_shape(dtype=jnp.int32)] * 3)
+        assert "%mla_prefill_walk" in compiled.as_text()
     elif kernel == "selected_walk":
         compiled = _compile(
-            lambda qn, qr, wk, wv, pool, may, slot, n: latent_walk.selected_walk(
-                qn, qr, wk, wv, pool, may, slot, n, scale=0.07),
-            one_chip, _shape(128, 256, 128), _shape(128, 256, 64), _shape(128, 128, 512),
-            _shape(128, 128, 512), _shape(slots, 576, positions),
+            lambda qn, qr, w, pool, may, slot, n: latent_walk.selected_walk(
+                qn, qr, w, pool, may, slot, n, scale=0.07),
+            one_chip, _shape(128, 256, 128), _shape(128, 256, 64), _shape(128, 512, 256),
+            _shape(slots, 576, positions),
             _shape(256, positions, dtype=jnp.float32), _shape(dtype=jnp.int32),
             _shape(dtype=jnp.int32))
     else:

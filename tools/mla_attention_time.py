@@ -2,10 +2,13 @@
 cell's shapes (``models/deepseek_v3.py``): the absorbed decode step over 32
 slots' pools (the kernel, ``ops/pallas/latent_decode.py``, against XLA's two
 matmuls over the whole pool) and the expanded prefill walk over the fed slots'
-live key blocks (by key block), each against the least time the chip could
+live key blocks (XLA's loops by key block; the kernel that keeps a step's
+scores in VMEM, ``ops/pallas/latent_walk.py``, with the mask read off the
+positions as a plain layer runs it, and with the same mask handed over as an
+indexed layer hands its selection), each against the least time the chip could
 take for the mathematics it was fed (``benchmarks/lib/opcounts_joyai_llm_flash``).
 
-    python3 tools/mla_attention_time.py [--slots 32] [--fed 6] [--live 9000]
+    python3 tools/mla_attention_time.py [--slots 32] [--fed 6 16] [--live 9000]
 
 Prints one JSON line a variant. The numbers that count are the chip's.
 """
@@ -33,7 +36,8 @@ def _time(fn, *args, steps=20):
 def main(argv):
     parser = argparse.ArgumentParser()
     parser.add_argument("--slots", type=int, default=32)
-    parser.add_argument("--fed", type=int, default=6, help="slots a prefill tick feeds")
+    parser.add_argument("--fed", type=int, nargs="+", default=[6, 16],
+                        help="slots a prefill tick feeds")
     parser.add_argument("--live", type=int, default=9000, help="live positions a busy slot")
     parser.add_argument("--chunk", type=int, nargs="+", default=[512])
     parser.add_argument("--blocks", type=int, nargs="+", default=[512, 1024, 2048])
@@ -73,23 +77,32 @@ def main(argv):
                           "roofline_pct": 100 * least / ms}), flush=True)
 
     # prefill: ``fed`` slots each end their chunk at ``live`` positions, the rest parked
+    def causal_may(start, chunk):
+        return lambda s: (jnp.arange(positions)[None, :]
+                          <= start[s] + jnp.arange(chunk)[:, None]).astype(jnp.float32)
+
+    forms = [(f"xla_key_block_{block}",
+              lambda *operands, block=block: model.expanded_walk(*operands, block))
+             for block in args.blocks]
+    forms += [("kernel_mask_from_positions", model.kernel_walk),
+              ("kernel_mask_handed", lambda qn, qr, p, w, s, f: model.kernel_walk(
+                  qn, qr, p, w, s, f, causal_may(s, qn.shape[1])))]
     for chunk in args.chunk:
         q_nope = jax.random.normal(keys[2], (b, chunk, heads, dn), bf16)
         q_rope_c = jax.random.normal(keys[3], (b, chunk, heads, dr), bf16)
-        fed = jnp.where(jnp.arange(b) < args.fed, chunk, 0).astype(jnp.int32)
-        start = jnp.where(fed > 0, args.live - chunk, positions).astype(jnp.int32)
-        pairs = args.fed * chunk * (args.live - (chunk - 1) / 2)
-        least = ops.attention_flops(one, args.fed * chunk, pairs,
-                                    expanded_positions=args.fed * args.live) \
-            / peaks["bf16_flops"] * 1e3
-        for block in args.blocks:
-            fn = jax.jit(lambda qn, qr, p, w, s, f, block=block: model.expanded_walk(
-                qn, qr, p, w, s, f, block))
-            ms = _time(fn, q_nope, q_rope_c, pool, w_kvb, start, fed, steps=10)
-            print(json.dumps({"device": device, "what": "prefill_walk", "chunk": chunk,
-                              "key_block": block, "fed_slots": args.fed, "live": args.live,
-                              "ms": ms, "least_ms": least, "roofline_pct": 100 * least / ms}),
-                  flush=True)
+        for n_fed in args.fed:
+            fed = jnp.where(jnp.arange(b) < n_fed, chunk, 0).astype(jnp.int32)
+            start = jnp.where(fed > 0, args.live - chunk, positions).astype(jnp.int32)
+            pairs = n_fed * chunk * (args.live - (chunk - 1) / 2)
+            least = ops.attention_flops(one, n_fed * chunk, pairs,
+                                        expanded_positions=n_fed * args.live) \
+                / peaks["bf16_flops"] * 1e3
+            for form, fn in forms:
+                ms = _time(jax.jit(fn), q_nope, q_rope_c, pool, w_kvb, start, fed, steps=10)
+                print(json.dumps({"device": device, "what": "prefill_walk", "form": form,
+                                  "chunk": chunk, "fed_slots": n_fed, "live": args.live, "ms": ms,
+                                  "least_ms": least, "roofline_pct": 100 * least / ms}),
+                      flush=True)
 
 
 if __name__ == "__main__":
