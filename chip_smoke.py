@@ -187,10 +187,70 @@ def train_phase(preset="350m", seq=1024, batch_size=8, steps=4, scan_steps=4,
 # --------------------------------------------------------------------------
 # phase: serve
 # --------------------------------------------------------------------------
+def _reference_rows(engine, req):
+    """``[new, V]`` fp32: the plain full-sequence forward's logits at the
+    positions that emitted ``req``'s output tokens, teacher-forced."""
+    import jax.numpy as jnp
+    ids = np.concatenate([req.prompt, np.asarray(req.output, np.int32)])[None, :-1]
+    logits = np.asarray(engine(jnp.asarray(ids)), np.float32)[0]
+    if not np.all(np.isfinite(logits)):
+        raise AssertionError("non-finite reference logits")
+    return logits[len(req.prompt) - 1:]
+
+
+def _decode_rung_check(engine, vocab, prompt, new, chunk, slots=32, n_requests=3):
+    """Decode ticks on the quarter rung against the whole program, at 32
+    slots (``programs.decode_rungs``: 8 and 32): the same requests through a
+    scheduler that takes the rung its fed slots fit and through one held to
+    the whole program emit the same tokens. Where the two part, it must be a
+    near tie of the reference forward at that position (the two programs
+    round a row's matmuls in tiles of their own): both tokens within
+    ``SERVE_LOGIT_TOL`` of its maximum; behind it they are fed different
+    tokens and compare no further."""
+    from deepspeed_tpu.inference.serving import (ContinuousBatchingScheduler,
+                                                 Request, ServingConfig)
+    from deepspeed_tpu.utils import trace
+
+    counters, runs = trace.recorder().counters, {}
+    for ladder in ("rungs", "whole"):
+        sched = ContinuousBatchingScheduler(engine, ServingConfig(
+            slots=slots, page_size=16, kv_quant=True, prefill_chunk=chunk))
+        if ladder == "whole":
+            sched._decode_rungs = (sched.slots,)
+        rng = np.random.default_rng(SEED + 1)
+        reqs = [Request(prompt=rng.integers(0, vocab, (prompt,)).astype(np.int32),
+                        max_new_tokens=new) for _ in range(n_requests)]
+        before = counters.get(f"decode_ticks_rung_{sched._decode_rungs[0]}", 0)
+        for r in reqs:
+            sched.submit(r)
+        sched.run_until_drained()
+        ticks = counters[f"decode_ticks_rung_{sched._decode_rungs[0]}"] - before
+        if ticks < new - 1 or any(len(r.output) != new for r in reqs):
+            raise AssertionError(f"the {ladder} run took {ticks} decode ticks on its first rung "
+                                 f"of {sched._decode_rungs} for {new} tokens a request")
+        runs[ladder] = (sched._decode_rungs, reqs)
+    (ladder, reqs), (_, reqs_whole) = runs["rungs"], runs["whole"]
+    if len(ladder) < 2:
+        raise AssertionError(f"no decode rung below the whole at {slots} slots: {ladder}")
+    parted, worst = 0, 0.0
+    for got, want in zip(reqs, reqs_whole):
+        differ = np.flatnonzero(np.asarray(got.output) != np.asarray(want.output))
+        if not differ.size:
+            continue
+        parted += 1
+        j, at = int(differ[0]), _reference_rows(engine, got)
+        gaps = [float(at[j].max() - at[j, r.output[j]]) for r in (got, want)]
+        worst = max(worst, *gaps)
+        if worst > SERVE_LOGIT_TOL:
+            raise AssertionError(f"a decode rung's token {j} parts from the whole program's "
+                                 f"with no tie behind it: {gaps} under the reference's maximum")
+    return dict(ladder=list(ladder), requests=n_requests, parted_on_a_tie=parted,
+                worst_gap_at_a_parting=round(worst, 4))
+
+
 def serve_phase(preset="350m", prompt=128, new=64, n_requests=8, slots=8, chunk=16,
                 devices=None):
     import jax
-    import jax.numpy as jnp
     import deepspeed_tpu
     from deepspeed_tpu.inference.serving import (ContinuousBatchingScheduler,
                                                  Request, ServingConfig)
@@ -233,22 +293,19 @@ def serve_phase(preset="350m", prompt=128, new=64, n_requests=8, slots=8, chunk=
     # arg-max at its position
     worst = 0.0
     for r in reqs[:2]:
-        ids = np.concatenate([r.prompt, np.asarray(r.output, np.int32)])[None, :-1]
-        logits = np.asarray(engine(jnp.asarray(ids)), np.float32)[0]
-        if not np.all(np.isfinite(logits)):
-            raise AssertionError("non-finite reference logits")
-        at = logits[prompt - 1:]                                  # [new, V]
+        at = _reference_rows(engine, r)
         gap = at.max(axis=-1) - at[np.arange(new), np.asarray(r.output)]
         worst = max(worst, float(gap.max()))
     if worst > SERVE_LOGIT_TOL:
         raise AssertionError(f"an emitted token lies {worst:.3f} below the reference "
                              f"forward's maximum (tolerance {SERVE_LOGIT_TOL})")
+    rung = _decode_rung_check(engine, cfg.vocab_size, prompt, new, chunk)
 
     ticks = dict(sched.stats()["ticks"])
     obs = dict(model=preset, requests=n_requests, prompt=prompt, new=new,
                ticks=ticks, cold_compile_s=round(compile_s, 1),
                tick_ms=round(wall / max(sum(ticks.values()), 1) * 1e3, 2),
-               worst_logit_gap=round(worst, 4),
+               worst_logit_gap=round(worst, 4), decode_rung=rung,
                peak_bytes=_peak_bytes(jax.devices()[0]))
     _emit("serve", **obs)
     return obs

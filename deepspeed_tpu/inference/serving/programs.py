@@ -13,15 +13,24 @@ writes drop out of bounds and its (garbage, finite) logits are discarded
 on the host. Rollback after a rejected speculation is therefore free —
 the next tick's operand simply doesn't advance past the accepted prefix.
 
-A prefill tick feeds few slots when few prompts are in flight, and a
-program over every slot then computes mostly parked ones. So the prefill
-program also exists over fewer sequences (:func:`prefill_rungs`; one builder,
-the size read from the operands): it is handed ``slot_ids [n] int32``, the
-slot of each sequence it runs, and ``write_pos``, ``ids`` and ``last_idx`` of
+A tick feeds few slots when few requests are in flight, and a program over
+every slot then computes, writes and reads mostly parked ones. So the prefill
+program and the plain decode program also exist over fewer sequences, each at
+a ladder of two sizes ("rungs": one builder a program, the size read from the
+operands): a quarter of the slots, and all (:func:`prefill_rungs`; for decode
+only where a quarter is at least 8 sequences, :func:`decode_rungs`). A rung
+below the whole is handed ``slot_ids [n] int32``, the slot of each sequence it
+runs, and ``write_pos`` (a prefill rung's ``ids`` and ``last_idx`` too) of
 those ``n`` alone. The model then sees a batch of ``n``; only what holds a
-row a slot is indexed (:func:`rows_of_slots`). A tick runs the smallest rung
-that holds the slots it feeds. The rung over every slot is the program above,
-with nothing indexed, and the only one over a latent pool or on a mesh.
+row a slot is indexed (:func:`rows_of_slots`), and each fed slot's next token
+is left in the cache (:data:`TOKEN_LEAF`) for whichever size runs next. One
+rule decides for both: a tick runs the smallest rung that holds the slots it
+feeds, from the tick's own fed count; there is nothing to set. What a rung
+costs is a start: a trace of the model, a lowering and an executable to load
+in ``warmup`` (about three seconds warm for GPT-2 medium, half of it the
+load) and its code on the device. The rung over every slot is the program above, with nothing indexed,
+and the only one over a latent pool or on a mesh; a speculating scheduler's
+verify and a drafter's decode have the whole shape alone.
 
 Programs are cached on the target :class:`InferenceEngine` keyed by the
 pow2 slot bucket (``engine._pow2_bucket`` — the same bucketing discipline
@@ -58,6 +67,9 @@ from deepspeed_tpu.utils import trace
 
 #: the cache's one leaf that is no model's: the token each slot is fed next
 TOKEN_LEAF = "next_token"
+
+#: the fewest sequences a decode rung below the whole runs (:func:`decode_rungs`)
+DECODE_RUNG_FLOOR = 8
 
 
 def _leaf_name(path) -> str:
@@ -214,6 +226,18 @@ def prefill_rungs(slots: int, mesh_size: int = 1, cache=None) -> tuple:
     if mesh_size > 1 or slots < 4 or latent:
         return (slots,)
     return (slots // 4, slots)
+
+
+def decode_rungs(slots: int, mesh_size: int = 1, cache=None) -> tuple:
+    """The sequence counts the plain decode program is built at, ascending,
+    the last every slot: :func:`prefill_rungs`' rule (a quarter and all; all
+    alone on a mesh and over a latent pool, for its two reasons) with a floor,
+    a quarter of **at least 8 sequences**. A decode row is one position, so
+    below 8 rows (one sublane tile of fp32) the tick's matmuls and its
+    weights' stream are what they were, and a scheduler of under 32 slots has
+    no quarter worth a program's set-up."""
+    rungs = prefill_rungs(slots, mesh_size, cache)
+    return rungs if rungs[0] >= DECODE_RUNG_FLOOR else (slots,)
 
 
 def rows_of_slots(cache, slot_ids):
@@ -380,34 +404,54 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
 
 
 def build_decode_step(apply_fn, do_sample: bool, temperature: float,
-                      top_k: int, top_p: float) -> Callable:
+                      top_k: int, top_p: float, rung: bool = False) -> Callable:
     """One decode tick: feed each slot the token the cache holds for it
     (:data:`TOKEN_LEAF`; a parked slot is fed 0) at its write position, sample
     the next and leave it there. Greedy builds a no-rng program
     (``decode(params, cache, write_pos)``); sampling adds an rng operand.
     ``tokens [slots] int32``, by keyword, is fed in the leaf's place: a draft
-    loop's first token is the host's, the last the target accepted."""
+    loop's first token is the host's, the last the target accepted.
+
+    ``rung=True`` builds the program over fewer sequences than slots, as
+    :func:`build_prefill_step` does: ``decode_rung(params, cache, slot_ids,
+    write_pos, *rng)`` with ``slot_ids [n] int32`` distinct slots and
+    ``write_pos [n]`` theirs (an entry that only fills the rung is parked at
+    the sentinel). It feeds row ``j`` the token the cache holds for slot
+    ``slot_ids[j]``, writes and reads those slots' pool rows in place, returns
+    ``n`` tokens and leaves each fed slot's in :data:`TOKEN_LEAF`, so the next
+    program, of either size, finds it there. The cache goes in and comes back
+    whole; ``tokens=`` stays the whole program's."""
     from deepspeed_tpu.inference.engine import sample_logits
 
-    def decode(params, cache, write_pos, *rng, tokens=None):
+    def decode(params, cache, write_pos, *rng, tokens=None, slot_ids=None):
         if len(rng) != int(do_sample):
             raise TypeError(f"decode takes {int(do_sample)} rng operand(s) behind write_pos, "
                             f"got {len(rng)}: a slot's token is the cache's, or ``tokens=``")
         cache, held = without_next_tokens(cache)
         live = write_pos < slot_capacity(cache)
         if tokens is None:
-            tokens = jnp.where(live, held, 0)
-        logits, cache = apply_fn(params, with_write_positions(cache, write_pos),
-                                 tokens[:, None])
+            tokens = jnp.where(live, held if slot_ids is None else held[slot_ids], 0)
+        fed = with_write_positions(cache, write_pos)
+        if slot_ids is None:
+            logits, cache = apply_fn(params, fed, tokens[:, None])
+        else:
+            logits, ran = apply_fn(params, rows_of_slots(fed, slot_ids), tokens[:, None])
+            cache = rows_to_slots(cache, ran, slot_ids)
         if do_sample:
             tok = sample_logits(logits[:, -1], *rng, True, temperature, top_k, top_p)
         else:
             tok = jnp.argmax(logits[:, -1], axis=-1)
         tok = tok.astype(jnp.int32)
-        return (with_next_tokens(cache, _tokens_of_fed(held, tok, live)),
+        return (with_next_tokens(cache, _tokens_of_fed(held, tok, live, slot_ids)),
                 with_counters(cache, tok))
 
-    return decode
+    if not rung:
+        return decode
+
+    def decode_rung(params, cache, slot_ids, write_pos, *rng):
+        return decode(params, cache, write_pos, *rng, slot_ids=slot_ids)
+
+    return decode_rung
 
 
 def build_verify_step(apply_fn) -> Callable:
@@ -487,6 +531,10 @@ def serve_programs(engine, slots_bucket: int, *, prefill_chunk: int,
         fns["prefill_rung"] = jax.jit(build_prefill_step(apply_fn, do_sample, temperature,
                                                          top_k, top_p, rung=True),
                                       **jit_kwargs)
+    if len(decode_rungs(slots_bucket, engine.mesh.size)) > 1:
+        fns["decode_rung"] = jax.jit(build_decode_step(apply_fn, do_sample, temperature,
+                                                       top_k, top_p, rung=True),
+                                     **jit_kwargs)
     if spec_k > 0:
         fns["verify"] = jax.jit(build_verify_step(apply_fn), **jit_kwargs)
     engine._serve_cache[key] = fns

@@ -42,7 +42,7 @@ import jax
 from deepspeed_tpu.inference.serving.blocks import BlockPool
 from deepspeed_tpu.inference.serving.config import ServingConfig
 from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, RING_LEAVES, TOKEN_LEAF,
-                                                      _leaf_name, counter_widths,
+                                                      _leaf_name, counter_widths, decode_rungs,
                                                       has_recurrent_state, has_ring,
                                                       make_slot_cache, prefill_rungs,
                                                       serve_programs, slot_capacity,
@@ -318,6 +318,9 @@ class ContinuousBatchingScheduler:
         caches = [self._cache] + ([self._drafter_cache] if self._drafter is not None else [])
         self._rungs = min((prefill_rungs(self.slots, engine.mesh.size, cache)
                            for cache in caches), key=len)
+        # and the plain decode program's, which a speculating scheduler never runs
+        self._decode_rungs = ((self.slots,) if self.spec_k
+                              else decode_rungs(self.slots, engine.mesh.size, self._cache))
 
         # host-side authoritative slot state, as of what has been DISPATCHED:
         # who holds a slot, the cache positions written, the prompt tokens fed
@@ -522,16 +525,19 @@ class ContinuousBatchingScheduler:
         ids = np.zeros((self.slots, self.config.prefill_chunk), np.int32)
         last_idx = np.zeros(self.slots, np.int32)
         block = np.zeros((self.slots, self.spec_k + 1), np.int32)
-        # a prefill rung's operands are the first ``n`` of each, behind the
-        # slots it runs: a program a rung, all behind one jitted function
+        # a rung's operands are the first ``n`` of each, behind the slots it
+        # runs: a program a rung, all behind one jitted function a kind
         rungs = [("prefill_rung", (np.arange(n, dtype=np.int32), parked[:n], ids[:n],
                                    last_idx[:n])) for n in self._rungs[:-1]]
+        decode_calls = [("decode", (parked,) + rng)] + [
+            ("decode_rung", (np.arange(n, dtype=np.int32), parked[:n]) + rng)
+            for n in self._decode_rungs[:-1]]
         # a spec-mode scheduler never runs the target's plain decode
         # (step() always spec-ticks) — don't pay its compile
         target_calls = ([("prefill", (parked, ids, last_idx) + rng)]
                         + [(name, args + rng) for name, args in rungs]
                         + ([("verify", (parked, block))] if self.spec_k
-                           else [("decode", (parked,) + rng)]))
+                           else decode_calls))
         per_role = [("", self.fns, "_cache", self._serve_params, target_calls)]
         if self._drafter is not None:
             # the draft loop feeds decode a mesh-committed token (see
@@ -946,14 +952,14 @@ class ContinuousBatchingScheduler:
                      "last_tick_monotonic": time.monotonic()})
 
     # -- prefill -------------------------------------------------------
-    def _rung_rows(self, slots: List[int]) -> np.ndarray:
-        """The slots a prefill tick's program runs, one a sequence: the
-        smallest rung that holds ``slots``. The whole rung is every slot in
-        its place. A smaller one is the fed slots and then, to fill it, other
-        slots, which the tick parks at the sentinel as the whole program
-        parks every slot it does not feed: distinct, so that no two rows of a
-        write-back are one slot."""
-        n = next(r for r in self._rungs if r >= len(slots))
+    def _rung_rows(self, slots: List[int], rungs: Tuple[int, ...]) -> np.ndarray:
+        """The slots a tick's program runs, one a sequence: the smallest of
+        ``rungs`` (the ladder of the tick's kind) that holds ``slots``. The
+        whole rung is every slot in its place. A smaller one is the fed slots
+        and then, to fill it, other slots, which the tick parks at the
+        sentinel as the whole program parks every slot it does not feed:
+        distinct, so that no two rows of a write-back are one slot."""
+        n = next(r for r in rungs if r >= len(slots))
         if n == self.slots:
             return np.arange(self.slots, dtype=np.int32)
         fed = set(slots)
@@ -963,7 +969,7 @@ class ContinuousBatchingScheduler:
     def _prefill_tick(self, slots: List[int]) -> _Program:
         C = self.config.prefill_chunk
         with self._phase("build_inputs"):
-            rows = self._rung_rows(slots)
+            rows = self._rung_rows(slots, self._rungs)
             n = len(rows)
             row_of = {int(i): j for j, i in enumerate(rows)}
             ids = np.zeros((n, C), np.int32)
@@ -1010,24 +1016,34 @@ class ContinuousBatchingScheduler:
     # -- plain decode --------------------------------------------------
     def _decode_tick(self, slots: List[int]) -> _Program:
         with self._phase("build_inputs"):
-            write_pos = np.full(self.slots, self.capacity, np.int64)
-            write_pos[slots] = self._lengths[slots]
+            rows = self._rung_rows(slots, self._decode_rungs)
+            n = len(rows)
+            row_of = {int(i): j for j, i in enumerate(rows)}
+            write_pos = np.full(n, self.capacity, np.int64)
+            write_pos[[row_of[i] for i in slots]] = self._lengths[slots]
+        # ``_computed`` is the cell's whole shape whatever rung ran (the
+        # benchmark counts decode ticks by it); ``_run`` is what the tick's
+        # program computed
         self._rec.count("decode_slots_fed", len(slots))
         self._rec.count("decode_slots_computed", self.slots)
-        self._count_moe_rows(len(slots), self.slots)
+        self._rec.count("decode_slots_run", n)
+        self._rec.count(f"decode_ticks_rung_{n}")
+        self._count_moe_rows(len(slots), n)
         self._count_kv_write(write_pos, 1)
         self._count_state(write_pos)
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32),)
+            name = "decode"
+            if n < self.slots:
+                name, inputs = "decode_rung", (rows,) + inputs
         with self._phase("dispatch"):
             if self.config.do_sample:
                 self._rng, key = jax.random.split(self._rng)
                 inputs += (key,)
             # each slot's token is the cache's own (``programs.TOKEN_LEAF``)
-            self._cache, tok = self.fns["decode"](self._serve_params, self._cache,
-                                                  *inputs)
-            return self._dispatched("decode", tok,
-                                    [(i, self._slot_req[i], i, 0, True) for i in slots])
+            self._cache, tok = self.fns[name](self._serve_params, self._cache, *inputs)
+            return self._dispatched("decode", tok, [(i, self._slot_req[i], row_of[i], 0, True)
+                                                    for i in slots])
 
     def _dispatched(self, kind: str, tok, rows: list) -> _Program:
         """What the host knows of a program the moment it is dispatched,

@@ -281,13 +281,26 @@ def slot_pool_read(pool, scale, read_dtype, rows=None):
     operand, an int8 pool dequantised by its ``scale`` (attention reads fp
     values, HBM holds the codes): a change of logical order only, which the
     compiler folds into the consumer's layout. ``rows`` [n]: those slots'
-    rows alone, gathered before anything is dequantised."""
+    rows alone, picked before anything is dequantised."""
     if rows is not None:
-        pool = pool[rows]
-        scale = None if scale is None else scale[rows]
+        pool = slot_rows(pool, rows)
+        scale = None if scale is None else slot_rows(scale, rows)
     if scale is not None:
         pool = pool.astype(read_dtype) * scale[:, :, None, :]
     return jnp.transpose(pool, (0, 3, 1, 2))
+
+
+# jitted, so that a model's layers share one trace of it
+@jax.jit
+def slot_rows(leaf, rows):
+    """Rows ``rows`` [n] of a leaf that holds a row a slot, ``[n, ...]``: a
+    scalar-indexed slice a row, ``n`` being a rung's few. Not ``leaf[rows]``:
+    the TPU's compiler opens a gather on the slots' axis with slices of the
+    WHOLE leaf (four passes a pool, 4.2 ms of a 10.7 ms decode tick over 8 of
+    the chat cell's 32 slots: ``PERF.md`` section 6, PR 42), where a slice by
+    a scalar reads the row it names."""
+    return jnp.concatenate([jax.lax.dynamic_slice_in_dim(leaf, rows[i], 1, axis=0)
+                            for i in range(rows.shape[0])])
 
 
 def slot_pool_scale(leaf):

@@ -258,7 +258,7 @@ def test_a_latent_pool_has_the_whole_rung_alone():
 def test_the_rung_is_the_smallest_that_holds_the_fed_slots(engine, fed, rung):
     sched = _scheduler(engine)
     slots = list(range(SLOTS))[::-1][:fed]               # 7, 6, ...: not the first rows
-    rows = sched._rung_rows(slots)
+    rows = sched._rung_rows(slots, sched._rungs)
     assert len(rows) == rung and len(set(rows.tolist())) == rung
     if rung == SLOTS:
         assert rows.tolist() == list(range(SLOTS))       # every slot in its place

@@ -572,7 +572,7 @@ def test_dots3_note_serving_program_keeps_a_ring_and_scores_a_slot_at_a_time(one
 
 
 # ---------------------------------------------------------------------------
-# a prefill rung: the prefill program over a quarter of the slots (ISSUE 33)
+# a rung: the prefill (ISSUE 33) and decode (ISSUE 42) programs over a quarter of the slots
 # ---------------------------------------------------------------------------
 def _write_loop_trip_counts(compiled):
     """The bound each ``_append_piece`` loop's condition compares its
@@ -590,22 +590,49 @@ def _write_loop_trip_counts(compiled):
     return counts
 
 
-@pytest.mark.parametrize("family", ["gpt2", "olmoe", "nemotron_h"])
-def test_a_quarter_rung_prefill_program_runs_a_quarter(one_chip, family):
-    """The prefill program over a quarter of each cell's slots, handed the
-    slots it runs: it still holds no copy, convert or transpose the size of
-    a pool (the rows are picked where they are written and read), its write
+def _whole_leaf_passes(compiled, leaf):
+    """Instructions outside fusion bodies that MAKE an array over every slot
+    of a quarter or more of ``leaf`` [slots, ...]: what a program over a few
+    slots' rows has no business making (the TPU's compiler opens a gather on
+    the slots' axis with slices of the whole pool: ``common.slot_rows``)."""
+    import re
+    dtype = {"int8": "s8", "bfloat16": "bf16", "float32": "f32"}[str(leaf.dtype)]
+    found, fused = [], False
+    for line in compiled.as_text().splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(")[0]
+            continue
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) ([\w\-]+)\(", line)
+        if fused or not m or m.group(2) not in ("fusion", "copy", "gather", "slice", "convert",
+                                                "transpose"):
+            continue
+        for dims in re.findall(dtype + r"\[([\d,]+)\]", m.group(1)):
+            dims = [int(d) for d in dims.split(",")]
+            if dims[0] == leaf.shape[0] and np.prod(dims) >= leaf.size // 4:
+                found.append(line.strip()[:160])
+                break
+    return found
+
+
+@pytest.mark.parametrize("family, program", [("gpt2", "prefill"), ("olmoe", "prefill"),
+                                             ("nemotron_h", "prefill"), ("gpt2", "decode")])
+def test_a_quarter_rung_program_runs_a_quarter(one_chip, family, program):
+    """The prefill program over a quarter of each cell's slots, and the chat
+    cell's decode program over 8 of its 32 (ISSUE 42), handed the slots they
+    run: no copy, convert or transpose the size of a pool and no pass over
+    one (the rows are picked where they are written and read), the write
     loop runs once a sequence of the rung, the cache comes back in place and
-    its temporaries are no more than the whole program's. (A latent pool
+    the temporaries are no more than the whole program's. (A latent pool
     has no such program: ``prefill_rungs``.)"""
     import re
     import flax.linen as nn
-    from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, build_prefill_step,
+    from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, build_decode_step,
+                                                          build_prefill_step, decode_rungs,
                                                           make_apply_fn, make_slot_cache,
                                                           prefill_rungs)
 
     module, slots, chunk, kv_quant = _cell_family(family)
-    n = prefill_rungs(slots)[0]
+    n = (prefill_rungs if program == "prefill" else decode_rungs)(slots)[0]
     assert n == slots // 4
     params = jax.eval_shape(
         lambda key: jax.tree.map(lambda p: p.astype(bf16), nn.meta.unbox(
@@ -613,16 +640,23 @@ def test_a_quarter_rung_prefill_program_runs_a_quarter(one_chip, family):
     cache = jax.eval_shape(lambda: make_slot_cache(module, slots, kv_quant=kv_quant))
     apply_fn = make_apply_fn(module)
     ints = lambda *dims: _shape(*dims, dtype=jnp.int32)
-    whole = _compile(build_prefill_step(apply_fn, False, 1.0, 0, 1.0), one_chip, params, cache,
-                     ints(slots), ints(slots, chunk), ints(slots), donate_argnums=(1,))
-    rung = _compile(build_prefill_step(apply_fn, False, 1.0, 0, 1.0, rung=True), one_chip, params,
-                    cache, ints(n), ints(n), ints(n, chunk), ints(n), donate_argnums=(1,))
+    if program == "prefill":
+        build = build_prefill_step
+        operands = lambda rows: (ints(rows), ints(rows, chunk), ints(rows))
+    else:
+        build, operands = build_decode_step, lambda rows: (ints(rows),)
+    whole = _compile(build(apply_fn, False, 1.0, 0, 1.0), one_chip, params, cache,
+                     *operands(slots), donate_argnums=(1,))
+    rung = _compile(build(apply_fn, False, 1.0, 0, 1.0, rung=True), one_chip, params, cache,
+                    ints(n), *operands(n), donate_argnums=(1,))
     pool = next(leaf for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
                 if path[-1].key in POOL_LEAVES)
     # (at the hybrid's reduced widths a chunk's activations, [n, ...], are as
-    # large as its pool: there only what holds a row for every slot counts)
+    # large as its pool, 512 KB, which the compiler also moves whole: there
+    # only what holds a row for every slot counts)
     assert not [line for line in _relayouts(rung, pool.size)
                 if family != "nemotron_h" or f"[{slots}," in line]
+    assert family == "nemotron_h" or not _whole_leaf_passes(rung, pool)
     assert set(_write_loop_trip_counts(whole)) == {slots}
     assert set(_write_loop_trip_counts(rung)) == {n}
     memory, memory_whole = rung.memory_analysis(), whole.memory_analysis()

@@ -23,6 +23,9 @@ def test_serve_phase_rehearsal():
     assert obs["requests"] == 8 and obs["ticks"]["decode"] >= 16
     # fp32 on the CPU: the scheduler's arg-max is the reference forward's
     assert obs["worst_logit_gap"] <= 1e-3
+    # and a decode tick on the quarter rung of 32 slots emits the whole program's tokens
+    assert obs["decode_rung"] == {"ladder": [8, 32], "requests": 3, "parted_on_a_tie": 0,
+                                  "worst_gap_at_a_parting": 0.0}
 
 
 def test_zero3_phase_rehearsal():

@@ -22,8 +22,16 @@ hides), and a tick costs the longer of its program and ``--host-ms``, not
 their sum. (Those readings of PR 30 were whole ticks of the serial order,
 host included: the same model with no ``--host-ms``.)
 
+The plain decode program has two lengths where its ladder has two rungs
+(``serving/programs.py`` ``decode_rungs``, PR 42): a decode tick that feeds
+no more slots than the quarter rung holds runs the program over that many
+rows. ``--decode-rung-ms`` prices those ticks; without it every decode tick
+costs ``--decode-ms``, which is right only where the batch never falls to a
+quarter of the slots.
+
     python3 tools/serve_schedule_model.py --decode-ms 18.7 --prefill-ms 343.8 \\
-        [--host-ms 1.8] [--workload <cell>] [--block <n> ...] [--interleave <n>] [--sets 12]
+        [--host-ms 1.8] [--decode-rung-ms 5.0] [--workload <cell>] [--block <n> ...]
+        [--interleave <n>] [--sets 12]
 
 Prints, for each ``block``, the spread (quartiles over the median) of sets
 of six consecutive seeds.
@@ -40,11 +48,15 @@ sys.path.insert(0, ROOT)
 
 
 def tokens_per_s(lengths, slots, chunk, interleave, decode_s, prefill_s, preroll_s, window_s,
-                 host_s=0.0):
+                 host_s=0.0, decode_rung=None):
     """``serve_total_tok_s`` of one run: ``lengths`` the (prompt, output)
     pairs in queue order, all due at time zero; a tick takes the longer of
-    its program and the host's ``host_s``, which runs under it."""
+    its program and the host's ``host_s``, which runs under it.
+    ``decode_rung``: ``(rows, seconds)`` of the decode program's rung below
+    the whole, which a decode tick that feeds no more than ``rows`` slots runs."""
     decode_s, prefill_s = max(decode_s, host_s), max(prefill_s, host_s)
+    rung_rows, rung_s = decode_rung or (0, decode_s)
+    rung_s = max(rung_s, host_s)
     queue = iter(lengths)
     held = [None] * slots           # [prompt tokens left, outputs made, outputs wanted]
     now, since_prefill, progress, opened = 0.0, 0, 0, None
@@ -72,7 +84,8 @@ def tokens_per_s(lengths, slots, chunk, interleave, decode_s, prefill_s, preroll
             for s in active:
                 s[1] += 1
                 progress += 1
-            now, since_prefill = now + decode_s, since_prefill + 1
+            now += rung_s if len(active) <= rung_rows else decode_s
+            since_prefill += 1
         held = [None if s and s[0] == 0 and s[1] >= s[2] else s for s in held]
 
 
@@ -82,16 +95,21 @@ def spread_pct(values):
 
 
 def run_seed(cell, seed, decode_s, prefill_s, block=None, interleave=None, window_s=51.0,
-             host_s=0.0):
+             host_s=0.0, decode_rung_s=None):
     from benchmarks.lib.traffic import serve_schedule
 
     traffic = dict(cell.traffic, block=block or cell.traffic["block"])
     serve = cell.config["serve"]
     lengths = [(len(r["prompt"]), r["max_new_tokens"])
                for r in serve_schedule(traffic, cell.config["vocab_size"], seed, 0.0)]
+    decode_rung = None
+    if decode_rung_s is not None:
+        from deepspeed_tpu.inference.serving.programs import decode_rungs
+        rungs = decode_rungs(serve["slots"])
+        decode_rung = (rungs[0], decode_rung_s) if len(rungs) > 1 else None
     return tokens_per_s(lengths, serve["slots"], serve["prefill_chunk"],
                         interleave or serve["prefill_interleave"], decode_s, prefill_s,
-                        float(traffic["preroll_s"]), window_s, host_s)
+                        float(traffic["preroll_s"]), window_s, host_s, decode_rung)
 
 
 def main(argv):
@@ -100,6 +118,8 @@ def main(argv):
     parser.add_argument("--decode-ms", type=float, required=True)
     parser.add_argument("--prefill-ms", type=float, required=True)
     parser.add_argument("--host-ms", type=float, default=0.0)
+    parser.add_argument("--decode-rung-ms", type=float,
+                        help="the decode program over the quarter rung, where the cell has one")
     parser.add_argument("--block", type=int, nargs="+")
     parser.add_argument("--interleave", type=int)
     parser.add_argument("--sets", type=int, default=12)
@@ -116,7 +136,9 @@ def main(argv):
         for k in range(args.sets):
             values = [run_seed(cell, args.first_seed + 7919 * k + j, args.decode_ms / 1e3,
                                args.prefill_ms / 1e3, block, args.interleave,
-                               host_s=args.host_ms / 1e3) for j in range(6)]
+                               host_s=args.host_ms / 1e3,
+                               decode_rung_s=None if args.decode_rung_ms is None
+                               else args.decode_rung_ms / 1e3) for j in range(6)]
             spreads.append(spread_pct(values))
             medians.append(statistics.median(values))
         print(json.dumps({"block": block, "sets_of_six": args.sets,
