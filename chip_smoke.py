@@ -91,7 +91,7 @@ def _kernels_compiled():
 
 
 def _train_setup(preset, seq, zero_stage, batch_size):
-    """Model, engine config and seeded batch, as bench.py builds them."""
+    """Model, engine config and seeded batch of the training phases."""
     import jax.numpy as jnp
     from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
 
@@ -256,7 +256,7 @@ def serve_phase(preset="350m", prompt=128, new=64, n_requests=8, slots=8, chunk=
                                                  Request, ServingConfig)
     from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
 
-    # the engine tools/serve_bench.py builds, and its scheduler defaults
+    # a bf16 engine on the scheduler's own defaults
     n_positions = max(prompt + new + 1, 128)
     cfg = get_gpt2_config(preset, n_positions=n_positions, dtype=None)
     engine = deepspeed_tpu.init_inference(GPT2LMHeadModel(cfg),

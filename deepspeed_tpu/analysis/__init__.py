@@ -26,9 +26,8 @@ from deepspeed_tpu.analysis import rules as _rules  # noqa: F401 — registers R
 from deepspeed_tpu.analysis import source_rules as _source_rules  # noqa: F401 — registers R008
 from deepspeed_tpu.analysis.memory import MemoryEstimate, estimate_memory
 from deepspeed_tpu.analysis.cost import (CostInfo, build_cost, cost_baseline_from,
-                                         cost_engine_program, load_cost_baseline,
-                                         r013_cost_ratchet, run_cost_rules,
-                                         static_price_from_jaxpr,
+                                         load_cost_baseline, r013_cost_ratchet,
+                                         run_cost_rules, static_price_from_jaxpr,
                                          static_price_from_programs)  # registers R009-R013
 from deepspeed_tpu.analysis.search import (SPACES, Candidate, SearchSpace,
                                            enumerate_candidates, flops_proxy,
@@ -53,10 +52,10 @@ __all__ = [
     "ERROR", "WARN", "INFO", "RULES", "Finding", "Rule", "Waiver",
     "apply_waivers", "load_waivers", "program_rules", "ast_rules", "cost_rules",
     "ProgramAnalyzer", "ProgramInfo", "aval_bytes", "run_program_rules",
-    "check_program", "lint_engine_program",
+    "check_program",
     "MemoryEstimate", "estimate_memory",
     "CostInfo", "build_cost", "run_cost_rules", "r013_cost_ratchet",
-    "load_cost_baseline", "cost_baseline_from", "cost_engine_program",
+    "load_cost_baseline", "cost_baseline_from",
     "static_price_from_jaxpr", "static_price_from_programs",
     "SPACES", "Candidate", "SearchSpace", "enumerate_candidates", "flops_proxy",
     "gate_space_names", "load_search_artifact", "pareto", "price_candidate",
@@ -78,37 +77,3 @@ def check_program(jaxpr=None, rules=None, metadata=None, name="adhoc",
                        metadata=metadata)
     findings, _ = run_program_rules(info, rules=rules)
     return findings
-
-
-def _repo_waivers():
-    """The repo's program-layer waivers (analysis_results/waivers.json),
-    shared between the CLI and lint_engine_program so ladder evidence rows
-    never disagree with the gate about what is acknowledged."""
-    import json
-    import os
-    path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "analysis_results", "waivers.json")
-    if not os.path.exists(path):
-        return []
-    with open(path) as fh:
-        return load_waivers(json.load(fh))
-
-
-def lint_engine_program(engine, example_batch, rules=None, programs=None):
-    """Analyze a live engine's traced step program and return the compact
-    evidence summary perf_ladder embeds in its rows: rule hit counts,
-    waiver count, error count, clean flag. Chip-window rows carry this so
-    a banked TFLOPS number provably came from a lint-clean program.
-    Applies the repo's waivers.json — the row must agree with the gate.
-    Pass ``programs`` (a prior ``engine.traced_programs`` result) to
-    share one trace with the cost evidence instead of re-tracing."""
-    programs = programs or engine.traced_programs(example_batch)
-    step = programs["train_step"]
-    info = ProgramInfo(name="engine_train_step", jaxpr=step["jaxpr"],
-                       hlo_text=step["hlo_text"], kind="train_step",
-                       metadata=step["metadata"])
-    findings, _ = run_program_rules(info, rules=rules)
-    apply_waivers(findings, _repo_waivers())
-    s = summarize(findings)
-    return {"lint_rule_hits": s["rule_hits"], "lint_waived": s["waived"],
-            "lint_errors": s["errors"], "lint_clean": s["clean"]}

@@ -51,9 +51,7 @@ telemetry the repo already accumulates instead of burning chip minutes:
 ``analysis/search.py`` cashes the artifact in: ``run_space(...,
 calibration=...)`` appends a ``predicted_seconds`` objective priced
 under the calibrated model and a ``seconds_rank`` over the frontier —
-the total order in *seconds* the proxy objectives could not give, which
-``tools/perf_ladder.py`` uses to order and stamp the ``350m_search_*``
-rungs a chip window measures.
+the total order in *seconds* the proxy objectives could not give.
 """
 
 import json
@@ -416,7 +414,7 @@ def collect_samples(paths: Iterable[str],
             f"runs may contain overlapped-learner ticks, so pooling them "
             f"with rollout-only samples would fit a meaningless cost "
             f"line; re-collect the unmarked runs with current telemetry "
-            f"(tools/rlhf_bench.py stamps the field) or drop them from "
+            f"(the run header carries the field) or drop them from "
             f"the collection")
     return {k: groups[k] for k in sorted(groups)}
 

@@ -114,8 +114,7 @@ def _backend_stats(compiled) -> Dict[str, Any]:
 def build_cost(program: ProgramInfo, analyzer: Optional[ProgramAnalyzer] = None,
                compile: bool = True) -> CostInfo:  # noqa: A002 — mirrors the CLI flag
     """Assemble the cost view of one program. ``compile=False`` keeps it
-    trace-only (perf_ladder evidence on a chip window must not pay a
-    second compile); the compiled inventory/stat layers then stay absent
+    trace-only (no second compile); the compiled inventory/stat layers then stay absent
     and signature entries against them report as unchecked."""
     analyzer = analyzer or ProgramAnalyzer(program)
     mesh_axes = program.metadata.get("mesh_axes")
@@ -521,29 +520,6 @@ def run_cost_rules(program: ProgramInfo, cost: CostInfo,
     findings.extend(r011_redundant_collectives(program, cost, analyzer))
     findings.extend(r012_host_transfer_bytes(program, cost, analyzer))
     return findings
-
-
-def cost_engine_program(engine, example_batch, compile: bool = False,  # noqa: A002
-                        programs: Optional[Dict] = None) -> Dict[str, Any]:
-    """The compact static-cost evidence perf_ladder stamps next to a
-    banked TFLOPS number: predicted peak bytes (total + transient) and
-    analytic wire bytes per inventory layer. Trace-only by default — a
-    chip window must not pay a second compile for evidence. Pass
-    ``programs`` (a prior ``engine.traced_programs`` result) to share
-    one trace with the lint evidence instead of re-tracing the step."""
-    programs = programs or engine.traced_programs(example_batch)
-    step = programs["train_step"]
-    info = ProgramInfo(name="engine_train_step", jaxpr=step["jaxpr"],
-                       hlo_text=step["hlo_text"], kind="train_step",
-                       metadata=step["metadata"], lower=step.get("lower"))
-    cost = build_cost(info, compile=compile)
-    return {
-        "cost_peak_bytes": cost.memory.peak_bytes,
-        "cost_peak_transient_bytes": cost.memory.peak_transient_bytes,
-        "cost_comms_bytes": cost.bytes_moved(),
-        "cost_collectives": {layer: cost.counts(layer) for layer in cost.inventory},
-        "cost_hlo_layers": sorted(cost.inventory),
-    }
 
 
 def static_price_from_jaxpr(closed_jaxpr, metadata: Optional[Dict] = None,

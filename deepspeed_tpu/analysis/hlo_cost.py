@@ -352,7 +352,7 @@ def _permute_axes(mesh_axes):
 # ---------------------------------------------------------------------------
 def inventory(ops: Iterable[CollectiveOp]) -> Dict[str, Dict[str, Any]]:
     """Per-layer summary: op counts per kind + total analytic wire bytes —
-    the shape R013 ratchets and perf_ladder rows embed."""
+    the shape R013 ratchets."""
     out: Dict[str, Dict[str, Any]] = {}
     for op in ops:
         layer = out.setdefault(op.layer, {"counts": {}, "bytes_moved": 0,

@@ -29,8 +29,8 @@ def matrix_signature(program_names: Iterable[str]) -> str:
 
 
 def summarize(findings: List[Finding]) -> Dict:
-    """Rule hit counts split by status — the shape perf_ladder evidence
-    rows embed (rule_hits / waived / errors / clean)."""
+    """Rule hit counts split by status (rule_hits / waived / errors /
+    clean)."""
     hits: Dict[str, int] = {}
     waived = errors = 0
     for f in findings:

@@ -195,7 +195,7 @@ def _ds_base(bf16: bool) -> dict:
 
 
 #: the registry. ``350m_judged`` mirrors the bench methodology's judged
-#: single-chip operating point (bench.py: mb8 / seq1024 / bf16 / padded
+#: single-chip operating point (mb8 / seq1024 / bf16 / padded
 #: vocab / one-hot embedding backward); attention stays on the XLA
 #: backend so pricing is backend-reproducible — flash block geometry has
 #: its own tuner (tools/attn_tune.py). ``gpt2_test_gate`` is the small

@@ -1,7 +1,7 @@
 """graft-fleet replica worker: ``python -m deepspeed_tpu.inference.fleet.worker``.
 
 One serving process in the fleet: builds an engine + continuous-batching
-scheduler (serve_bench's construction path), then loops — requests in as
+scheduler (``chip_smoke.serve_phase``'s construction path), then loops — requests in as
 line-delimited JSON on stdin, ``done``/``tick`` out on stdout
 (``protocol.py``), logs on stderr, liveness through the PR-13 heartbeat
 file the scheduler touches every tick.

@@ -23,9 +23,8 @@ Three pillars (ISSUE 13):
    TFLOPS (predicted ``flops_proxy`` ÷ measured median step time) and
    predicted-vs-measured memory ratios (device ``memory_stats`` peaks
    where the backend reports them — TPU; host peak RSS as the loose
-   CPU-backend proxy, explicitly labeled). perf_ladder stamps
-   ``drift_summary()`` next to its lint/cost evidence so a chip window
-   banks model error, not just milliseconds.
+   CPU-backend proxy, explicitly labeled). ``drift_summary()`` gives the
+   whole run's.
 
 The recorder instruments only host code around the dispatched step —
 the traced program is bit-identical with telemetry on (gated by the
@@ -305,8 +304,7 @@ class RuntimeTelemetry:
 
     # -- summaries -----------------------------------------------------
     def drift_summary(self) -> Dict[str, Any]:
-        """Cumulative (whole-run) phase medians + drift ratios — what
-        perf_ladder stamps next to a rung's lint/cost evidence."""
+        """Cumulative (whole-run) phase medians + drift ratios."""
         if self._window_steps:
             # flush the pending partial window under its real last step —
             # a step-0 label would misorder consumers keying windows by step

@@ -5,8 +5,7 @@ tuples; serving latency (ROADMAP item 1) and per-phase step spans need
 *distributions*. The histogram here is the shared latency type: fixed
 bucket boundaries chosen at construction, so two histograms from
 different processes / windows merge by adding counts — the property a
-p50/p99 under load (``tools/serve_bench.py``) or a fleet-level rollup
-needs. Everything is plain Python floats and lists: recording must cost
+p50/p99 under load or a fleet-level rollup needs. Everything is plain Python floats and lists: recording must cost
 nanoseconds-to-microseconds, never a device sync. Counts are plain
 integers on the process's recorder (``deepspeed_tpu.utils.trace``).
 """

@@ -1,5 +1,5 @@
-"""Shared helpers for the repo-root entry points (``bench.py``,
-``chip_smoke.py``, ``__graft_entry__.py``), the tools and the tests: CPU
+"""Shared helpers for the repo-root entry points (``chip_smoke.py``,
+``__graft_entry__.py``), the tools and the tests: CPU
 pinning for child processes and the one compile-cache placement rule.
 
 No jax and no deepspeed_tpu import at module level, so parent processes

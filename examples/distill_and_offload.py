@@ -11,7 +11,7 @@ What it shows, reference-call-for-call:
      memory / NVMe and stream through the chip). NB: ``offload_optimizer``
      does not combine with KD (its host-driven step never reaches the
      in-graph KD gate — init_compression rejects it); pair the two offloads
-     in non-distillation configs (see bench.py BENCH_OFFLOAD=1).
+     in non-distillation configs.
   4. The loop calls ``touch_heartbeat()``, so the whole script runs under
      the elastic restart supervisor unchanged:
          bin/ds_elastic -c examples/ds_config_zero3.json \

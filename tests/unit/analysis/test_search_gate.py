@@ -131,28 +131,6 @@ def test_committed_350m_artifact_shape():
                             for c in rematted)
 
 
-def test_ladder_rungs_generated_from_frontier():
-    """perf_ladder grows one rung per distinct static price point on the
-    committed frontier, knobs routed through the engine program block."""
-    import importlib.util as iu
-    spec = iu.spec_from_file_location(
-        "perf_ladder_search", os.path.join(REPO, "tools", "perf_ladder.py"))
-    ladder = iu.module_from_spec(spec)
-    spec.loader.exec_module(ladder)
-    tags = [t for t in ladder.RUNGS if t.startswith("350m_search_")]
-    assert tags, "no frontier rungs generated"
-    artifact = analysis.load_search_artifact(ARTIFACT)
-    space = artifact["spaces"]["350m_judged"]
-    for tag in tags:
-        rung = ladder.RUNGS[tag]
-        assert "program" in rung["ds"]
-        cid = rung["retry_evidence_extra"]["search_candidate"]
-        assert cid in space["frontier"]
-    # distinct-price collapse: fewer rungs than frontier members, ties
-    # recorded as evidence
-    assert len(tags) < len(space["frontier"])
-
-
 # ---------------------------------------------------------------------------
 # registry-generated docs (the R013-stops-here satellite)
 # ---------------------------------------------------------------------------

@@ -25,15 +25,18 @@ def test_gpt2_decode_cache_matches_full_forward():
     model = GPT2LMHeadModel(cfg)
     rng = np.random.default_rng(0)
     ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 12)), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), ids)
-    full = model.apply(variables, ids)
+    # init, the full pass and the step jitted: eagerly each is dispatched an operation at a time
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
+    full = jax.jit(model.apply)(variables, ids)
+    decode = jax.jit(lambda cache, fed: model.apply({**variables, **cache}, fed, decode=True,
+                                                    mutable=["cache"]))
 
     from deepspeed_tpu.models.common import init_cache
     cache = {"cache": init_cache(model, batch_size=2)}
-    out, cache = model.apply({**variables, **cache}, ids[:, :8], decode=True, mutable=["cache"])
+    out, cache = decode(cache, ids[:, :8])
     np.testing.assert_allclose(np.asarray(out), np.asarray(full[:, :8]), rtol=2e-4, atol=2e-4)
     for t in range(8, 12):
-        out, cache = model.apply({**variables, **cache}, ids[:, t:t + 1], decode=True, mutable=["cache"])
+        out, cache = decode(cache, ids[:, t:t + 1])
         np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(full[:, t]), rtol=2e-4, atol=2e-4)
 
 
@@ -46,13 +49,12 @@ def test_generate_greedy_matches_manual_loop():
     out = engine.generate(prompt, max_new_tokens=6)
     assert out.shape == (2, 14)
 
-    # manual greedy loop over the full forward (no cache) must agree
-    ids = jnp.asarray(prompt)
-    params = engine.params
-    for _ in range(6):
-        logits = model.apply({"params": params}, ids)
-        ids = jnp.concatenate([ids, jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)], axis=1)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(ids))
+    # the manual greedy loop over the full forward (no cache) must agree. The
+    # model is causal, so one forward over the output holds every step of
+    # that loop: token t + 1 is the argmax at t, given the tokens up to t
+    np.testing.assert_array_equal(np.asarray(out)[:, :8], prompt)
+    logits = jax.jit(lambda p, ids: model.apply({"params": p}, ids))(engine.params, out[:, :-1])
+    np.testing.assert_array_equal(np.asarray(out)[:, 8:], np.asarray(jnp.argmax(logits[:, 7:], axis=-1)))
 
 
 def test_generate_eos_early_stop():

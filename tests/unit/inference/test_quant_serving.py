@@ -200,7 +200,7 @@ def test_speculative_quantized_drafter_lossless(engine_cfg):
 # ---------------------------------------------------------------------------
 def test_kv_pool_bytes_admits_deeper_when_quantized(engine_cfg):
     """The SAME byte budget sizes strictly more KV blocks under int8 KV
-    than under fp KV — the mechanism behind the serve_bench goodput A/B."""
+    than under fp KV."""
     engine, cfg = engine_cfg
     budget = 64 * 1024
     q = ContinuousBatchingScheduler(

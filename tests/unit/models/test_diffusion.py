@@ -19,7 +19,7 @@ def _unet():
     sample = jnp.zeros((2, 16, 16, cfg.in_channels))
     t = jnp.array([1, 5])
     ctx = jnp.zeros((2, 7, cfg.cross_attention_dim))
-    params = m.init(jax.random.PRNGKey(0), sample, t, ctx)["params"]
+    params = jax.jit(m.init)(jax.random.PRNGKey(0), sample, t, ctx)["params"]
     return m, params, cfg
 
 
@@ -33,14 +33,15 @@ def test_unet_eps_prediction_contract():
     m, params, cfg = _unet()
     sample = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16, cfg.in_channels))
     ctx = jax.random.normal(jax.random.PRNGKey(2), (2, 7, cfg.cross_attention_dim))
-    eps = m.apply({"params": params}, sample, jnp.array([3, 7]), ctx)
+    apply = jax.jit(m.apply)    # one program for the three calls
+    eps = apply({"params": params}, sample, jnp.array([3, 7]), ctx)
     assert eps.shape == (2, 16, 16, cfg.out_channels)
     assert np.isfinite(np.asarray(eps)).all()
     # conditioning matters: different context, different prediction
-    eps2 = m.apply({"params": params}, sample, jnp.array([3, 7]), ctx + 1.0)
+    eps2 = apply({"params": params}, sample, jnp.array([3, 7]), ctx + 1.0)
     assert not np.allclose(np.asarray(eps), np.asarray(eps2))
     # timestep matters
-    eps3 = m.apply({"params": params}, sample, jnp.array([900, 950]), ctx)
+    eps3 = apply({"params": params}, sample, jnp.array([900, 950]), ctx)
     assert not np.allclose(np.asarray(eps), np.asarray(eps3))
 
 

@@ -183,10 +183,11 @@ def test_unet_matches_functional_torch():
     sample = rng.standard_normal((2, 16, 16, 4)).astype(np.float32)
     t = np.array([3.0, 250.0], np.float32)
     ctx = rng.standard_normal((2, 6, 16)).astype(np.float32)
-    params = _unboxed(model.init(jax.random.PRNGKey(0), jnp.asarray(sample),
-                                 jnp.asarray(t), jnp.asarray(ctx)))
-    got = np.asarray(model.apply({"params": params}, jnp.asarray(sample),
-                                 jnp.asarray(t), jnp.asarray(ctx)))
+    # init and apply jitted: eagerly each is dispatched an operation at a time
+    params = _unboxed(jax.jit(model.init)(jax.random.PRNGKey(0), jnp.asarray(sample),
+                                          jnp.asarray(t), jnp.asarray(ctx)))
+    got = np.asarray(jax.jit(model.apply)({"params": params}, jnp.asarray(sample),
+                                          jnp.asarray(t), jnp.asarray(ctx)))
     with torch.no_grad():
         want = t_unet(params, _t(sample).permute(0, 3, 1, 2), t, _t(ctx), cfg)
     want = want.permute(0, 2, 3, 1).numpy()
@@ -200,10 +201,10 @@ def test_unet_unconditional_matches_torch():
     rng = np.random.default_rng(1)
     sample = rng.standard_normal((1, 8, 8, 4)).astype(np.float32)
     t = np.array([17.0], np.float32)
-    params = _unboxed(model.init(jax.random.PRNGKey(1), jnp.asarray(sample),
-                                 jnp.asarray(t)))
-    got = np.asarray(model.apply({"params": params}, jnp.asarray(sample),
-                                 jnp.asarray(t)))
+    params = _unboxed(jax.jit(model.init)(jax.random.PRNGKey(1), jnp.asarray(sample),
+                                          jnp.asarray(t)))
+    got = np.asarray(jax.jit(model.apply)({"params": params}, jnp.asarray(sample),
+                                          jnp.asarray(t)))
     with torch.no_grad():
         want = t_unet(params, _t(sample).permute(0, 3, 1, 2), t, None, cfg)
     np.testing.assert_allclose(got, want.permute(0, 2, 3, 1).numpy(),
@@ -215,8 +216,8 @@ def test_vae_roundtrip_matches_torch():
     model = AutoencoderKL(cfg)
     rng = np.random.default_rng(2)
     x = rng.standard_normal((2, 16, 16, 3)).astype(np.float32)
-    params = _unboxed(model.init(jax.random.PRNGKey(2), jnp.asarray(x)))
-    got = np.asarray(model.apply({"params": params}, jnp.asarray(x)))
+    params = _unboxed(jax.jit(model.init)(jax.random.PRNGKey(2), jnp.asarray(x)))
+    got = np.asarray(jax.jit(model.apply)({"params": params}, jnp.asarray(x)))
     with torch.no_grad():
         want = t_vae_roundtrip(params, _t(x).permute(0, 3, 1, 2), cfg)
     np.testing.assert_allclose(got, want.permute(0, 2, 3, 1).numpy(),

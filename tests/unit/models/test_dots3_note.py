@@ -51,13 +51,21 @@ def sizes_of(cfg, first=0):
                      experts_first=first, eps=cfg.rms_norm_eps)
 
 
+def reference(params, ids, sizes, with_allowed=False):
+    """``ref.forward`` as one program: eagerly it is dispatched an operation
+    at a time, several hundred of them for every new length."""
+    return jax.jit(lambda flat: ref.forward(flat, ids, sizes, with_allowed=with_allowed))(
+        family.to_reference(params))
+
+
 @pytest.fixture(scope="module")
 def whole():
     """The uncut model and its seeded weights (float32), every matrix three
     times the plain draw: the softmaxes are then peaked, so that WHICH
     positions a query reads moves its logits."""
     module = build()
-    params = nn.meta.unbox(module.init(jax.random.PRNGKey(37), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = nn.meta.unbox(jax.jit(module.init)(jax.random.PRNGKey(37),
+                                                jnp.zeros((1, 8), jnp.int32))["params"])
     return module, jax.tree.map(lambda p: p * 3.0 if p.ndim >= 2 else p, params)
 
 
@@ -93,9 +101,10 @@ def test_full_forward_matches_the_reference_logits_and_chosen_sets(whole, held):
     if held:
         module, params = build(held), held_params(params, *held)
     ids = ids_of(2, 100)
-    want, masks = ref.forward(family.to_reference(params), ids,
-                              sizes_of(module.config, held[0] if held else 0), with_allowed=True)
-    got, state = module.apply({"params": params}, jnp.asarray(ids), mutable=["intermediates"])
+    want, masks = reference(params, ids, sizes_of(module.config, held[0] if held else 0),
+                            with_allowed=True)
+    got, state = jax.jit(lambda p: module.apply({"params": p}, jnp.asarray(ids),
+                                                mutable=["intermediates"]))(params)
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
     chosen = chosen_sets(state["intermediates"])
     assert sorted(chosen) == ["layers_0", "layers_1"]
@@ -178,8 +187,7 @@ def test_ragged_chunks_then_decode_match_the_reference_logits_and_sets(programs)
     layers' chosen sets are the reference's full forward pass's."""
     module, params, _, _, observed = programs
     ids = ids_of(2, 90, seed=3)
-    want, masks = ref.forward(family.to_reference(params), ids, sizes_of(module.config, 4),
-                              with_allowed=True)
+    want, masks = reference(params, ids, sizes_of(module.config, 4), with_allowed=True)
     want, masks = np.asarray(want), [np.asarray(m) for m in masks]
     cache = make_slot_cache(module, 4)
     parked = slot_capacity(cache)
@@ -313,9 +321,8 @@ def test_a_slot_that_joins_a_ring_reads_nothing_of_its_last_tenant(programs):
     _, after_a_tenant = serve(used, second)
     _, on_a_fresh_cache = serve(make_slot_cache(module, 4), second)
     assert after_a_tenant == on_a_fresh_cache
-    want = ref.forward(family.to_reference(params),
-                       np.concatenate([second, on_a_fresh_cache[:-1]])[None].astype(np.int32),
-                       sizes_of(module.config, 4))
+    want = reference(params, np.concatenate([second, on_a_fresh_cache[:-1]])[None].astype(np.int32),
+                     sizes_of(module.config, 4))
     assert on_a_fresh_cache == np.asarray(want)[0, 20:].argmax(axis=-1).tolist()
 
 
@@ -552,10 +559,10 @@ def test_an_indexed_layer_on_the_chips_path_is_the_layer_on_xlas(programs, monke
                                  mutable=["cache", "intermediates"])
         return out, state["cache"], state["intermediates"]["self_attn"]["dsa_chosen"][0]
 
-    want = run()
+    want = jax.jit(lambda: run())()     # a program each: the second is traced under the patch
     monkeypatch.setattr(backend, "on_tpu", lambda: True)
     monkeypatch.setattr(backend, "interpret_default", lambda: True)
-    got = run()
+    got = jax.jit(lambda: run())()
     real = np.asarray(fed) > 0
     np.testing.assert_allclose(np.asarray(got[0])[real], np.asarray(want[0])[real], atol=3e-5)
     assert not np.asarray(got[0])[0].any() or tick == "decode"      # a parked slot: zeros
@@ -628,11 +635,13 @@ def test_the_scheduler_serves_it_and_counts_what_the_layers_read(engine):
     for r in reqs:
         sched.submit(r)
     sched.run_until_drained()
-    params = engine.params
-    for r in reqs:
-        ids = np.concatenate([r.prompt, np.asarray(r.output[:-1], np.int32)])[None]
-        want = ref.forward(family.to_reference(params), ids, sizes_of(engine.module.config, 4))
-        assert list(r.output) == np.asarray(want)[0, len(r.prompt) - 1:].argmax(-1).tolist()
+    # one reference pass over the five, padded on the right to the longest:
+    # the reference is causal, so a row's logits up to its length are its own
+    fed = [np.concatenate([r.prompt, np.asarray(r.output[:-1], np.int32)]) for r in reqs]
+    ids = np.stack([np.pad(row, (0, max(map(len, fed)) - len(row))) for row in fed])
+    want = np.asarray(reference(engine.params, ids, sizes_of(engine.module.config, 4)))
+    for r, row, logits in zip(reqs, fed, want):
+        assert list(r.output) == logits[len(r.prompt) - 1:len(row)].argmax(-1).tolist()
     counted = {k: v - before.get(k, 0) for k, v in trace.recorder().counters.items()}
     tokens = sum(len(p) for p in prompts)
     # two indexed layers: a query at t attends min(t + 1, TOP_K) of t + 1

@@ -31,14 +31,16 @@ def test_gptj_decode_matches_full_forward():
     cfg = get_gptj_config("test")
     model = GPTJForCausalLM(cfg)
     ids = jnp.asarray(np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 10)), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
-    full = model.apply({"params": params}, ids)
+    # init, the full pass and the step jitted: eagerly each is dispatched an operation at a time
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
+    full = jax.jit(model.apply)({"params": params}, ids)
+    decode = jax.jit(lambda cache, token: model.apply({"params": params, "cache": cache}, token,
+                                                      decode=True, mutable=["cache"]))
     from deepspeed_tpu.models.common import init_cache
     cache = init_cache(model, batch_size=2)
     outs = []
     for t in range(ids.shape[1]):
-        step, mut = model.apply({"params": params, "cache": cache}, ids[:, t:t + 1],
-                                decode=True, mutable=["cache"])
+        step, mut = decode(cache, ids[:, t:t + 1])
         cache = mut["cache"]
         outs.append(step)
     np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, axis=1)), np.asarray(full),

@@ -51,7 +51,8 @@ def sizes_of(cfg, first=0):
 def whole():
     """The uncut model and its seeded weights (float32)."""
     module = build()
-    params = nn.meta.unbox(module.init(jax.random.PRNGKey(32), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = nn.meta.unbox(jax.jit(module.init)(jax.random.PRNGKey(32),
+                                                jnp.zeros((1, 8), jnp.int32))["params"])
     return module, params
 
 
@@ -81,8 +82,10 @@ def test_full_forward_matches_the_reference(whole, held):
     first, count = held or (0, EXPERTS)
     mine = held_params(params, first, count)
     ids = ids_of(2, 37)                         # two whole key blocks of 16 and a ragged one
-    got = build(held).apply({"params": mine}, ids)
-    want = ref.forward(family.to_reference(mine), ids, sizes_of(module.config, first))
+    # both sides jitted: eagerly each is dispatched an operation at a time
+    got = jax.jit(build(held).apply)({"params": mine}, ids)
+    sizes = sizes_of(module.config, first)
+    want = jax.jit(lambda flat: ref.forward(flat, ids, sizes))(family.to_reference(mine))
     assert got.shape == (2, 37, 256)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
@@ -90,14 +93,17 @@ def test_full_forward_matches_the_reference(whole, held):
 def test_lockstep_decode_matches_the_full_forward(whole):
     module, params = whole
     ids = ids_of(2, 21)
-    full = module.apply({"params": params}, ids)
-    cache = init_cache(module, 2)
-    out, upd = module.apply({"params": params, "cache": cache}, ids[:, :13], decode=True,
+    full = jax.jit(module.apply)({"params": params}, ids)
+
+    @jax.jit  # one program for the thirteen, one for each of the eight that follow
+    def step(cache, fed):
+        return module.apply({"params": params, "cache": cache}, fed, decode=True,
                             mutable=["cache"])
+
+    out, upd = step(init_cache(module, 2), ids[:, :13])
     outs = [out]
     for t in range(13, 21):
-        out, upd = module.apply({"params": params, "cache": upd["cache"]}, ids[:, t:t + 1],
-                                decode=True, mutable=["cache"])
+        out, upd = step(upd["cache"], ids[:, t:t + 1])
         outs.append(out)
     np.testing.assert_allclose(np.asarray(jnp.concatenate(outs, axis=1)), np.asarray(full),
                                atol=2e-5)
@@ -109,8 +115,8 @@ def served():
     that end ragged, key blocks of 16) and decode, a quarter of the experts held."""
     held = (4, 4)
     module = build(held, decode_cache_len=64)
-    whole_params = nn.meta.unbox(build().init(jax.random.PRNGKey(32),
-                                              jnp.zeros((1, 8), jnp.int32))["params"])
+    whole_params = nn.meta.unbox(jax.jit(build().init)(jax.random.PRNGKey(32),
+                                                       jnp.zeros((1, 8), jnp.int32))["params"])
     params = held_params(whole_params, *held)
     engine = deepspeed_tpu.init_inference(module, params=params, dtype=jnp.float32,
                                           max_out_tokens=64, topology=one_device())
@@ -128,16 +134,26 @@ def served():
     return module, params, held, sched, reqs, counted
 
 
+@pytest.fixture(scope="module")
+def served_reference_logits(served):
+    """The reference's logits over every request's prompt and fed-back tokens,
+    one pass over the six padded on the right to the longest: the reference
+    is causal, so a row's logits up to its length are its own."""
+    module, params, held, _, reqs, _ = served
+    fed = [np.concatenate([r.prompt, np.asarray(r.output[:-1], np.int32)]) for r in reqs]
+    ids = np.stack([np.pad(row, (0, max(map(len, fed)) - len(row))) for row in fed])
+    logits = np.asarray(ref.forward(family.to_reference(params), ids,
+                                    sizes_of(module.config, held[0])))
+    return [out[len(r.prompt) - 1:len(row)] for r, row, out in zip(reqs, fed, logits)]
+
+
 @pytest.mark.parametrize("which", range(6))
-def test_served_tokens_are_the_references_greedy_tokens(served, which):
+def test_served_tokens_are_the_references_greedy_tokens(served, served_reference_logits, which):
     """Chunked prefill (the last chunk ragged) and absorbed decode through the
     slot cache against the reference's full forward, teacher-forced."""
-    module, params, held, _, reqs, _ = served
-    r = reqs[which]
+    r = served[4][which]
     assert len(r.output) == 6
-    ids = np.concatenate([r.prompt, np.asarray(r.output[:-1], np.int32)])[None]
-    logits = np.asarray(ref.forward(family.to_reference(params), ids,
-                                    sizes_of(module.config, held[0])))[0, len(r.prompt) - 1:]
+    logits = served_reference_logits[which]
     gap = logits.max(axis=-1) - logits[np.arange(6), np.asarray(r.output)]
     assert gap.max() < 1e-4
 

@@ -60,19 +60,19 @@ def test_llama_decode_cache_matches_full_forward():
     model = LlamaForCausalLM(cfg)
     rng = np.random.default_rng(0)
     ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 12)), jnp.int32)
-    variables = model.init(jax.random.PRNGKey(0), ids)
-
-    full = model.apply(variables, ids)
+    # init, the full pass and the step jitted: eagerly each is dispatched an operation at a time
+    variables = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
+    full = jax.jit(model.apply)(variables, ids)
+    decode = jax.jit(lambda cache, fed: model.apply({**variables, **cache}, fed, decode=True,
+                                                    mutable=["cache"]))
 
     # prefill on the first 8 tokens, then decode 4 more one at a time
     from deepspeed_tpu.models.llama import init_cache
     cache = {"cache": init_cache(model, batch_size=2)}
-    out, upd = model.apply({**variables, **cache}, ids[:, :8], decode=True, mutable=["cache"])
-    cache = upd
+    out, cache = decode(cache, ids[:, :8])
     np.testing.assert_allclose(np.asarray(out), np.asarray(full[:, :8]), rtol=2e-4, atol=2e-4)
     for t in range(8, 12):
-        out, cache = model.apply({**variables, **cache}, ids[:, t:t + 1], decode=True,
-                                 mutable=["cache"])
+        out, cache = decode(cache, ids[:, t:t + 1])
         np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(full[:, t]),
                                    rtol=2e-4, atol=2e-4)
 

@@ -36,8 +36,8 @@ IDS = ["whole", "held"]
 def build(held=None, seed=0, **overrides):
     cfg = get_llama_config("smallthinker-test", moe_experts_held=held, **overrides)
     model = LlamaForCausalLM(cfg)
-    params = nn.meta.unbox(model.init(jax.random.PRNGKey(seed), jnp.zeros((1, 32), jnp.int32))
-                           ["params"])
+    params = nn.meta.unbox(jax.jit(model.init)(jax.random.PRNGKey(seed),
+                                               jnp.zeros((1, 32), jnp.int32))["params"])
     params = jax.tree.map(lambda p: p * WEIGHT_SCALE if p.ndim > 1 else p, params)
     return model, params
 
@@ -63,13 +63,16 @@ def package_loss(model, params, ids):
 def test_logits_and_loss_match_the_reference(held, backend):
     model, params = build(held, attention_backend=backend)
     ids = ids_of(1)
-    logits, aux = model.apply({"params": params}, ids)
-    want = ref.forward(family.to_reference(params), ids, spec_of(model.config))
+    spec = spec_of(model.config)
+    # both sides jitted: eagerly each is dispatched an operation at a time
+    logits, aux = jax.jit(model.apply)({"params": params}, ids)
+    flat = family.to_reference(params)
+    want = jax.jit(lambda flat: ref.forward(flat, ids, spec))(flat)
     assert float(aux) == 0.0    # moe_aux_loss_coef 0: cross-entropy alone
     np.testing.assert_allclose(np.asarray(logits), np.asarray(want), atol=TOL, rtol=TOL)
-    np.testing.assert_allclose(float(package_loss(model, params, ids)),
-                               float(ref.loss(family.to_reference(params), ids,
-                                              spec_of(model.config))), rtol=1e-6)
+    np.testing.assert_allclose(float(jax.jit(lambda p: package_loss(model, p, ids))(params)),
+                               float(jax.jit(lambda flat: ref.loss(flat, ids, spec))(flat)),
+                               rtol=1e-6)
 
 
 @pytest.mark.parametrize("held", HELD, ids=IDS)
@@ -80,8 +83,10 @@ def test_every_leaf_of_the_gradient_matches_the_reference(held):
     flows through the six weights alone on both sides."""
     model, params = build(held, attention_backend="flash")
     ids = ids_of(2)
-    got = family.to_reference(jax.grad(lambda p: package_loss(model, p, ids))(params))
-    want = jax.grad(ref.loss)(family.to_reference(params), ids, spec_of(model.config))
+    spec = spec_of(model.config)
+    # both jitted: eagerly each is traced and dispatched an operation at a time
+    got = family.to_reference(jax.jit(jax.grad(lambda p: package_loss(model, p, ids)))(params))
+    want = jax.jit(jax.grad(lambda flat: ref.loss(flat, ids, spec)))(family.to_reference(params))
     assert set(got) == set(want)
     for name in sorted(want):
         scale = float(jnp.max(jnp.abs(want[name])))
@@ -186,8 +191,9 @@ def test_it_trains_through_initialize_and_train_batch(held):
     engine.initialize_state(batch)
     flat = family.to_reference(engine.state.params)
     spec = spec_of(cfg)
-    want_loss = float(ref.loss(flat, batch["input_ids"], spec))
-    grads = jax.grad(ref.loss)(flat, batch["input_ids"], spec)
+    want_loss, grads = jax.jit(jax.value_and_grad(
+        lambda flat: ref.loss(flat, batch["input_ids"], spec)))(flat)
+    want_loss = float(want_loss)
     want_norm = float(jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads))))
     before = dict(recorder().counters)
     loss = float(engine.train_batch(batch))

@@ -26,18 +26,21 @@ def test_t5_decode_matches_full_forward():
     rng = np.random.default_rng(0)
     enc_ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 10)), jnp.int32)
     dec_ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (2, 7)), jnp.int32)
-    params = m.init(jax.random.PRNGKey(0), enc_ids, dec_ids)["params"]
-    full = m.apply({"params": params}, enc_ids, dec_ids)
+    # init, the full pass and the step jitted: eagerly each is dispatched an operation at a time
+    params = jax.jit(m.init)(jax.random.PRNGKey(0), enc_ids, dec_ids)["params"]
+    full = jax.jit(m.apply)({"params": params}, enc_ids, dec_ids)
 
     enc_out = m.apply({"params": params}, enc_ids, method=T5ForConditionalGeneration.encode)
     # incremental: one decoder token at a time against the cache
-    variables = m.init(jax.random.PRNGKey(0), enc_ids, dec_ids[:, :1], decode=True)
+    variables = jax.jit(lambda key: m.init(key, enc_ids, dec_ids[:, :1], decode=True))(
+        jax.random.PRNGKey(0))
     cache = jax.tree.map(jnp.zeros_like, variables["cache"])
+    decode = jax.jit(lambda cache, token: m.apply(
+        {"params": params, "cache": cache}, decoder_input_ids=token, encoder_outputs=enc_out,
+        decode=True, mutable=["cache"]))
     outs = []
     for t in range(dec_ids.shape[1]):
-        step, mut = m.apply({"params": params, "cache": cache},
-                            decoder_input_ids=dec_ids[:, t:t + 1],
-                            encoder_outputs=enc_out, decode=True, mutable=["cache"])
+        step, mut = decode(cache, dec_ids[:, t:t + 1])
         cache = mut["cache"]
         outs.append(step)
     decoded = jnp.concatenate(outs, axis=1)

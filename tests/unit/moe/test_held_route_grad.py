@@ -90,8 +90,9 @@ def test_held_route_gradients_match_the_uncut_route_restricted(operands, held, b
 
     mine = jax.tree.map(lambda p: p[first:first + count], bank)
     args = (params["gate"], mine, tokens, read)
-    want = jax.grad(whole_loss, argnums=(0, 1, 2, 3))(*args)
-    got, counts = jax.grad(held_loss, argnums=(0, 1, 2, 3), has_aux=True)(*args)
+    # both jitted: eagerly each backward is dispatched an operation at a time
+    want = jax.jit(jax.grad(whole_loss, argnums=(0, 1, 2, 3)))(*args)
+    got, counts = jax.jit(jax.grad(held_loss, argnums=(0, 1, 2, 3), has_aux=True))(*args)
     counts = dict(zip(HELD_COUNTS, np.asarray(counts)))
     assert counts["rows_buffered"] == buffered          # the rung this case is for
     assert counts["copies"] == TOKENS * k

@@ -133,8 +133,9 @@ def _run_layer(route, k, cf, deterministic, use_rts, kernel="auto", x=None):
             rngs=None if deterministic else {"gating": jax.random.PRNGKey(7)})
         return (out**2).sum() + l_aux, out
 
-    (lv, out), gv = jax.value_and_grad(loss, has_aux=True)(variables, x)
-    gx = jax.grad(lambda xx: loss(variables, xx)[0])(x)
+    # jitted: eagerly each backward is dispatched an operation at a time
+    (lv, out), gv = jax.jit(jax.value_and_grad(loss, has_aux=True))(variables, x)
+    gx = jax.jit(jax.grad(lambda xx: loss(variables, xx)[0]))(x)
     return lv, out, gv, gx
 
 

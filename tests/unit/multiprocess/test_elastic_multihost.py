@@ -23,7 +23,7 @@ import pytest
 from tests.unit.multiprocess.common import REPO, WORKER, free_port
 
 GANG_RUNNER = textwrap.dedent("""
-    import json, os, socket, subprocess, sys
+    import json, os, socket, subprocess, sys, time
     sys.path.insert(0, __REPO__)
     from envutil import cpu_subprocess_env
 
@@ -52,8 +52,18 @@ GANG_RUNNER = textwrap.dedent("""
         procs.append(subprocess.Popen(
             [sys.executable, __WORKER__, "elastic_train", json.dumps(kwargs)],
             env=env, cwd=__REPO__))
-    rcs = [p.wait() for p in procs]
-    sys.exit(0 if all(rc == 0 for rc in rcs) else 1)
+    # a rank that dies takes the gang with it, as under a launcher, after a
+    # grace for a checkpoint's last writes: left alone, the survivor waits out
+    # the coordination service's own timeout (about 90 s) before it fails
+    died = None
+    while any(p.poll() is None for p in procs):
+        if died is None and any(p.poll() not in (None, 0) for p in procs):
+            died = time.time()
+        if died is not None and time.time() - died > 10.0:
+            for p in procs:
+                p.kill()
+        time.sleep(0.2)
+    sys.exit(0 if all(p.returncode == 0 for p in procs) else 1)
 """)
 
 

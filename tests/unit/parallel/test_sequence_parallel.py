@@ -74,8 +74,10 @@ def test_gradients_match_xla(seq4_mesh, impl):
     def loss_ref(q, k, v):
         return jnp.sum(xla_attention(q, k, v, causal=True)**2)
 
-    g_sp = jax.grad(loss_sp, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    # jitted: eagerly, the backward of a shard_map on 8 devices is traced and
+    # dispatched an operation at a time, and the values compared are the same
+    g_sp = jax.jit(jax.grad(loss_sp, argnums=(0, 1, 2)))(q, k, v)
+    g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g_sp, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
 

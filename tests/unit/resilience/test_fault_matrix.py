@@ -154,63 +154,6 @@ def test_http500_retry_matrix(tmp_path):
     assert row["ok"], row
 
 
-def test_ladder_emits_structured_blocked_row(tmp_path, monkeypatch, capsys):
-    """A rung whose backend failure survives all retries must emit a
-    machine-readable ``blocked: backend_unavailable`` row with its retry
-    history — never a bare error string (PERF.md §PR9 contract)."""
-    import json
-
-    import perf_ladder
-    from deepspeed_tpu.runtime.resilience.faults import make_backend_unavailable
-
-    def always_500(tag, retry_evidence=None, **kw):
-        raise make_backend_unavailable()
-
-    monkeypatch.setattr(perf_ladder, "run_rung", always_500)
-    monkeypatch.setitem(perf_ladder.RUNGS, "fake", dict(model_name="test", mb=2))
-    monkeypatch.setenv("LADDER", "fake")
-    monkeypatch.setenv("LADDER_RETRIES", "2")
-    monkeypatch.setenv("LADDER_RETRY_BASE", "0.01")
-    perf_ladder.main()
-    rows = [json.loads(l) for l in capsys.readouterr().out.splitlines()
-            if l.startswith("{")]
-    assert len(rows) == 1, rows
-    row = rows[0]
-    assert row["blocked"] == "backend_unavailable"
-    assert row["retries"] == 2
-    assert len(row["retry_history"]) == 2
-    assert "libtpu" in row["retry_history"][0]["error"]
-
-
-def test_ladder_success_after_retry_carries_evidence(tmp_path, monkeypatch, capsys):
-    """A rung that succeeds on attempt 2 banks its number WITH the retry
-    history riding the row."""
-    import json
-
-    import perf_ladder
-    from deepspeed_tpu.runtime.resilience.faults import make_backend_unavailable
-
-    calls = {"n": 0}
-
-    def flaky_rung(tag, retry_evidence=None, **kw):
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise make_backend_unavailable()
-        print(json.dumps({"tag": tag, "tflops": 1.0, **(retry_evidence or {})}), flush=True)
-
-    monkeypatch.setattr(perf_ladder, "run_rung", flaky_rung)
-    monkeypatch.setitem(perf_ladder.RUNGS, "fake", dict(model_name="test", mb=2))
-    monkeypatch.setenv("LADDER", "fake")
-    monkeypatch.setenv("LADDER_RETRIES", "3")
-    monkeypatch.setenv("LADDER_RETRY_BASE", "0.01")
-    perf_ladder.main()
-    rows = [json.loads(l) for l in capsys.readouterr().out.splitlines()
-            if l.startswith("{")]
-    assert len(rows) == 1 and rows[0]["tag"] == "fake"
-    assert rows[0]["retries"] == 1
-    assert rows[0]["retry_history"][0]["error_class"] == "backend_unavailable"
-
-
 # ---------------------------------------------------------------------------
 # preemption → flag, then boundary checkpoint
 # ---------------------------------------------------------------------------

@@ -113,7 +113,8 @@ def test_pipeline_matches_dense_loss():
     set_topology(None)  # dense reference on a plain single-mesh
     dense_params = _dense_params_from_pipe(jax.device_get(engine.state.params), cfg.n_layer)
     model = GPT2LMHeadModel(cfg)
-    logits = model.apply({"params": dense_params}, jnp.asarray(batch["input_ids"]), deterministic=True)
+    logits = jax.jit(lambda p, ids: model.apply({"params": p}, ids, deterministic=True))(
+        dense_params, jnp.asarray(batch["input_ids"]))
     dense_loss = float(cross_entropy_loss(logits[:, :-1], jnp.asarray(batch["input_ids"])[:, 1:]))
 
     np.testing.assert_allclose(pipe_loss, dense_loss, rtol=2e-5)
@@ -184,7 +185,7 @@ def test_tied_embedding_receives_both_gradient_paths():
         logits = model.apply({"params": p}, ids, deterministic=True)
         return cross_entropy_loss(logits[:, :-1], ids[:, 1:])
 
-    g_dense = jax.grad(dense_loss)(dense_params)["wte"]
+    g_dense = jax.jit(jax.grad(dense_loss))(dense_params)["wte"]
     np.testing.assert_allclose(np.asarray(g_pipe, np.float32),
                                np.asarray(g_dense, np.float32), atol=2e-5)
 
@@ -282,7 +283,7 @@ def test_pipeline_trains_4stage_tied_grads():
             losses.append(cross_entropy_loss(logits[:, :-1], sub[:, 1:]))
         return jnp.mean(jnp.stack(losses))
 
-    g_dense = jax.grad(dense_loss)(dense_params)["wte"]
+    g_dense = jax.jit(jax.grad(dense_loss))(dense_params)["wte"]
     np.testing.assert_allclose(np.asarray(g_pipe, np.float32),
                                np.asarray(g_dense, np.float32), atol=2e-5)
 

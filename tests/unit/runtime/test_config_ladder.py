@@ -117,7 +117,8 @@ def test_moe_serving_tp8_generates():
                           moe_layer_freq=2, moe_k=1)
     model = GPT2LMHeadModel(cfg)
     ids = np.arange(2 * 8, dtype=np.int32).reshape(2, 8) % cfg.vocab_size
-    variables = model.init(jax.random.PRNGKey(0), jnp.asarray(ids), deterministic=True)
+    variables = jax.jit(lambda key: model.init(key, jnp.asarray(ids), deterministic=True))(
+        jax.random.PRNGKey(0))
     engine = deepspeed_tpu.init_inference(model, config={"dtype": "fp32"}, mp_size=8,
                                           params=variables["params"])
     out = engine.generate(ids, max_new_tokens=4)

@@ -66,6 +66,7 @@ def test_gpt2_layer_curvature_runs():
     ids = jnp.asarray(rng.integers(0, 250, (2, 16)), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), ids)["params"]
 
+    @jax.jit  # else every product of the iteration traces the model anew
     def loss(p):
         logits = model.apply({"params": p}, ids)
         return cross_entropy_loss(logits[:, :-1], ids[:, 1:])

@@ -1,19 +1,12 @@
-"""Pin the bench FLOP accounting (tools/bench_core.model_flops_per_token).
+"""Pin the benchmark's FLOP accounting
+(``benchmarks/lib/opcounts.model_flops_per_token``).
 
 The reference's published TFLOPS numbers use the standard parameter-matmul
-estimate; the bench adds the attention-score term that estimate omits
-(PaLM-appendix accounting) so long-context rungs report true model FLOPs
-(r4 verdict: the bare 6N model understated seq-8k MFU by ~36%).
+estimate; the benchmark adds the attention-score term that estimate omits
+(PaLM-appendix accounting) so long-context cells report true model FLOPs
+(the bare 6N model understated seq-8k MFU by ~36%).
 """
-import os
-import sys
-
-import pytest
-
-sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                "..", "..", "..", "tools"))
-
-from bench_core import flops_per_token_from_cfg, model_flops_per_token
+from benchmarks.lib.opcounts import model_flops_per_token
 
 
 def test_no_attention_term_degenerates_to_6n():
@@ -37,86 +30,3 @@ def test_bidirectional_is_twice_causal_attention():
     c = model_flops_per_token(n, L, h, s, causal=True) - 6.0 * n
     b = model_flops_per_token(n, L, h, s, causal=False) - 6.0 * n
     assert b == 2 * c
-
-
-def test_cfg_dispatch_gpt2_and_bert():
-    from deepspeed_tpu.models import get_bert_config, get_gpt2_config
-
-    g = get_gpt2_config("test")
-    got = flops_per_token_from_cfg(1000, g, 128)
-    assert got == model_flops_per_token(1000, g.n_layer, g.n_embd, 128, causal=True)
-
-    b = get_bert_config("test")
-    got = flops_per_token_from_cfg(1000, b, 128)
-    assert got == model_flops_per_token(1000, b.num_hidden_layers, b.hidden_size,
-                                        128, causal=False)
-
-
-def _inactive_expert_params(model, cfg, n_experts, k):
-    """Count the inactive expert params from the INITIALIZED param tree:
-    expert leaves live under the MoE layers' ``deepspeed_experts`` scope
-    with a leading [E] axis (moe/sharded_moe.Experts nn.vmap), so one
-    expert's share of a leaf is ``leaf.size / E`` and (E - k) shares per
-    leaf are dead FLOPs-wise. Ground truth the closed form in
-    ``active_params_from_cfg`` must reproduce."""
-    import flax.linen as nn
-    import jax
-    import jax.numpy as jnp
-    from flax.traverse_util import flatten_dict
-
-    ids = jnp.zeros((1, 16), jnp.int32)
-    params = nn.meta.unbox(model.init(jax.random.PRNGKey(0), ids)["params"])
-    inactive = 0
-    for path, leaf in flatten_dict(params).items():
-        if any("deepspeed_experts" in p for p in path):
-            assert leaf.shape[0] == n_experts, (path, leaf.shape)
-            inactive += (leaf.size // n_experts) * (n_experts - k)
-    assert inactive > 0, "no expert params found under deepspeed_experts"
-    return inactive
-
-
-def test_moe_cfg_counts_active_params_only():
-    from deepspeed_tpu.models import get_gpt2_config
-    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
-
-    g = get_gpt2_config("test", moe_num_experts=4, moe_layer_freq=2, moe_k=1)
-    inactive = _inactive_expert_params(GPT2LMHeadModel(g), g, 4, 1)
-    n_total = 10_000_000
-    got = flops_per_token_from_cfg(n_total, g, 128)
-    assert got == model_flops_per_token(n_total - inactive, g.n_layer,
-                                        g.n_embd, 128, causal=True)
-    assert got < flops_per_token_from_cfg(n_total, get_gpt2_config("test"), 128)
-
-
-def test_llama_moe_cfg_counts_active_params_only():
-    # llama-family (Mixtral-style) MoE presets must not overstate TFLOPS
-    # by the sparsity factor: active params use the SwiGLU per-expert count
-    from deepspeed_tpu.models.llama import LlamaForCausalLM, get_llama_config
-
-    cfg = get_llama_config("test", moe_num_experts=4, moe_layer_freq=1, moe_k=2)
-    inactive = _inactive_expert_params(LlamaForCausalLM(cfg), cfg, 4, 2)
-    n_total = 5_000_000
-    got = flops_per_token_from_cfg(n_total, cfg, 128)
-    # llama decoders are causal and count active params only
-    assert got == model_flops_per_token(n_total - inactive,
-                                        cfg.num_hidden_layers,
-                                        cfg.hidden_size, 128, causal=True)
-    dense = get_llama_config("test")
-    assert got < flops_per_token_from_cfg(n_total, dense, 128)
-
-
-def test_moe_layer_freq_zero_does_not_divide_by_zero():
-    from deepspeed_tpu.models import get_gpt2_config
-
-    g = get_gpt2_config("test", moe_num_experts=4, moe_layer_freq=0, moe_k=1)
-    # freq<=0 clamps to 1 (every layer MoE) instead of ZeroDivisionError
-    got = flops_per_token_from_cfg(10_000_000, g, 128)
-    every = get_gpt2_config("test", moe_num_experts=4, moe_layer_freq=1, moe_k=1)
-    assert got == flops_per_token_from_cfg(10_000_000, every, 128)
-
-
-def test_unknown_cfg_falls_back_to_6n():
-    class Odd:
-        pass
-
-    assert flops_per_token_from_cfg(500, Odd(), 4096) == 3000.0

@@ -4,9 +4,7 @@ BASELINE.md north star "bit-identical loss curves vs CPU reference").
 Without a live TPU the enforceable half is: the harness itself is exactly
 reproducible (two independent CPU processes produce bit-identical curves —
 if THIS drifts, any TPU-vs-CPU comparison is meaningless), and the
-compare() report detects drift at single-ULP resolution. bench.py runs the
-real accelerator-vs-CPU comparison on live hardware and attaches the
-report to the judged JSON.
+compare() report detects drift at single-ULP resolution.
 """
 
 import json

@@ -90,10 +90,11 @@ class TestCompressedAllreducePrimitive:
             return out, ew2[None], es2[None]
 
         sharded = jax.NamedSharding(topo.mesh, P(("data", "fsdp")))
-        fn = jax.shard_map(body, mesh=topo.mesh,
-                           in_specs=(P(("data", "fsdp")), P(("data", "fsdp")), P(("data", "fsdp"))),
-                           out_specs=(P(), P(("data", "fsdp")), P(("data", "fsdp"))),
-                           check_vma=False)
+        fn = jax.jit(jax.shard_map(
+            body, mesh=topo.mesh,
+            in_specs=(P(("data", "fsdp")), P(("data", "fsdp")), P(("data", "fsdp"))),
+            out_specs=(P(), P(("data", "fsdp")), P(("data", "fsdp"))),
+            check_vma=False))
         ew = jnp.zeros((world, n)); es = jnp.zeros((world, m_chunk))
         x_dev = jax.device_put(jnp.asarray(xs), sharded)
         # error-feedback telescoping identity (exact unbiasedness): summing T

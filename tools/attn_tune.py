@@ -1,12 +1,10 @@
 """Sweep flash-attention block geometries on the live backend and bank the
-shape-keyed winners (kernel-level analog of tune_bench.py). One process,
-which holds the chip until it exits.
+shape-keyed winners. One process, which holds the chip until it exits.
 
 Each shape's sweep writes its candidate records to ATTN_EXPS_DIR and merges
 the winner into ATTN_RESULTS_DIR/attention_blocks.json — the cache
-``flash_attention`` resolves through at call time, so a subsequent
-perf_ladder run picks the tuned geometry up automatically (the ladder
-prints which source won per rung).
+``flash_attention`` resolves through at call time, so a later run from
+the same working directory picks the tuned geometry up.
 
 Run: python tools/attn_tune.py           (background; poll stdout)
 Env: ATTN_SHAPES=1024:64:16:8,4096:64:16:2,8192:64:16:1
@@ -20,7 +18,7 @@ Env: ATTN_SHAPES=1024:64:16:8,4096:64:16:2,8192:64:16:1
          apart — forward, dq, dkv — for the winner and the defaults, or
          for every explicit candidate; chip only)
      ATTN_RESULTS_DIR=autotuning_results  ATTN_EXPS_DIR=autotuning_exps
-     (CI smoke redirects both to a tmp dir, per the tune_bench precedent)
+     (the CI smoke redirects both to a tmp dir)
 """
 import json
 import os
@@ -33,9 +31,9 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 
 def main():
-    from bench_core import enable_compile_cache
+    from envutil import use_compile_cache
 
-    enable_compile_cache()
+    use_compile_cache()
     import jax
     import jax.numpy as jnp
 

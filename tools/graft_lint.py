@@ -77,7 +77,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 #: source roots the AST rule sweeps
-AST_ROOTS = ("deepspeed_tpu", "tools", "bench.py", "envutil.py")
+AST_ROOTS = ("deepspeed_tpu", "tools", "envutil.py")
 
 
 def collect_source_files(repo=REPO, roots=AST_ROOTS):

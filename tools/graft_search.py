@@ -16,9 +16,6 @@ Default mode verifies against the committed
 candidate-set drift, winner price drift >5%, or a dominated committed
 winner); ``--update`` banks the current results instead (merge semantics
 — a single-space update never drops another space's entry).
-perf_ladder.py generates ``350m_search_*`` rungs from the committed
-frontier, so the next chip window measures exactly the statically-
-surviving set.
 
 Usage:
   python tools/graft_search.py                          # price + verify all spaces

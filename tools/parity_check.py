@@ -9,17 +9,16 @@ losses as exact bit patterns (fp32 hex), so comparison is free of
 print-precision noise.
 
 ``compare()`` reports bit-identity, max |Δ|, and max ULP distance between
-two curves. bench.py attaches this to its JSON when it measures on a live
-accelerator (the CPU reference curve computed in a scrubbed subprocess);
-``PARITY_MAX_ULP`` is the enforcement envelope — 0 (default) demands
+two curves: an accelerator's against the CPU reference curve computed in
+a scrubbed subprocess. ``PARITY_MAX_ULP`` is the enforcement envelope — 0 (default) demands
 bit-identity, a positive value pins the measured-and-documented envelope.
 
 Reference-pinning caveat (measured): XLA:CPU splits its compute threads
 per virtual device, and thread partitioning changes matmul reduction
 order — an 8-virtual-device process drifts ~1 ULP/step from a 1-device
 process on the SAME machine. The CPU reference is therefore always run at
-exactly ONE pinned CPU device (bench.py passes
-``cpu_subprocess_env(n_virtual_devices=1)``); with that pinned, curves
+exactly ONE pinned CPU device
+(``envutil.cpu_subprocess_env(n_virtual_devices=1)``); with that pinned, curves
 are bit-reproducible across processes (test_loss_parity).
 
 Run directly: ``python tools/parity_check.py`` → one JSON line
