@@ -116,9 +116,9 @@ def op_label(text, stats=None):
     expert matmuls (``%gmm``, or XLA's ``%ragged-dot``) are
     ``pallas:moe:matmul``; a custom call named after a latent-attention
     kernel is ``pallas:mla:decode`` (``mla_decode``, the absorbed step:
-    ``ops/pallas/latent_decode.py``) or would be ``pallas:mla:prefill``
-    (``mla_prefill*``: none is in the tree, the expanded walk runs as XLA
-    loops and fusions, PERF.md section 6, PR 32)."""
+    ``ops/pallas/latent_decode.py``) or ``pallas:mla:prefill``
+    (``mla_prefill_walk``, a chunk's expanded walk, one call a fed slot a
+    layer: ``ops/pallas/latent_walk.py::causal_walk``)."""
     name = trace.op_name(text).lstrip("%")
     if name.startswith(("gmm", "ragged-dot")):
         return "pallas:moe:matmul"

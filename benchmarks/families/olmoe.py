@@ -44,8 +44,9 @@ def model(config, deployment, **overrides):
 def op_label(text, stats=None):
     """Names this family's kernels in a device trace, from the instruction
     names XLA derives from the program's scopes (the events carry no other
-    metadata): the grouped expert matmuls (``%gmm``, or XLA's own
-    ``%ragged-dot`` kernels under ``DS_MOE_KERNEL=xla``) and the row
+    metadata): the grouped expert matmuls (``%gmm``; ``%ragged-dot`` is what
+    XLA's own kernels would be called, and no option of the program chooses
+    them) and the row
     permutations under the ``moe_route`` / ``moe_combine`` scopes. Any other
     Mosaic custom call of a llama-family serving program is an attention
     kernel (``use_flash_prefill``; none runs in the cell as configured)."""

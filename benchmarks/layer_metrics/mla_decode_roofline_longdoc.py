@@ -10,11 +10,13 @@ trace's; the live positions are the runner's count and the fed slots the
 program's. A program with no such kernel (the parent, or XLA's two matmuls
 over the whole pool) reads nothing.
 
-``mla_attn_time_pct_longdoc`` is the same kernels' share of device-busy time
-(``pallas:mla:*``). The prefill walk is XLA loops and fusions the trace
-cannot name (``lib/trace.py::load`` keeps no scope), so it is in neither and
-there is no ``mla_prefill_roofline_longdoc``: ``prefill_roofline_longdoc``
-carries it, and ``tools/mla_attention_time.py`` times the walk alone."""
+``mla_attn_time_pct_longdoc`` is the share of device-busy time of every
+latent-attention kernel (``pallas:mla:*``): the prefill walk is
+one too (``pallas:mla:prefill``, ``ops/pallas/latent_walk.py``'s call
+``mla_prefill_walk``) and is in that share, not in this roofline, which
+reads ``pallas:mla:decode`` alone. There is no ``mla_prefill_roofline_longdoc``
+yet: ``prefill_roofline_longdoc`` carries the walk, and
+``tools/mla_attention_time.py`` times it alone."""
 
 from benchmarks.lib import harness, joyai_llm_flash_ticks, program_spans, reducers
 

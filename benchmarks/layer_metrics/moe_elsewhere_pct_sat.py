@@ -1,6 +1,9 @@
-"""Share of the real tokens' expert copies whose expert another chip of the
-deployment holds: as ``moe_elsewhere_pct_reason``. With 64 of 256 experts
-held and an even router it reads 75; this chip computes none of them."""
+"""Share of the real tokens' expert copies whose expert another chip of
+the deployment holds: 100 x ``moe_rows_elsewhere`` / (``moe_rows_routed`` +
+``moe_rows_elsewhere``), counted on the device. With a quarter of
+the experts held (128 of 512, 64 of 256) and an even router it reads 75, with
+32 of 256 held 87.5; this chip computes none of them. One entry for the
+serving cells that hold a share of their experts."""
 
 from benchmarks.lib import harness, program_spans
 

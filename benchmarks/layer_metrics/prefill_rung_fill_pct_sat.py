@@ -1,4 +1,5 @@
-"""How full the prefill programs that ran were in the docs-sat cell: 100 x
+"""How full the prefill programs that ran were, in the saturated cells whose
+prefill program has a rung below the whole: 100 x
 ``prefill_positions_fed`` / ``prefill_positions_run``, totals of the process.
 ``_run`` is what each prefill tick's program computed: the sequences of the
 rung it ran (the smallest of the program's sizes that holds the slots the

@@ -1,4 +1,5 @@
-"""How often the scheduler ran a tick ahead in the documents cell, whose backlog keeps every slot busy:
+"""How often the scheduler ran a tick ahead in the cells whose backlog keeps
+every slot busy:
 programs dispatched while another was in flight (``ticks_dispatched_ahead``,
 counted in ``scheduler.py::_dispatched``) over all programs dispatched
 (``ticks_dispatched``), totals of the process, set-up's two checked requests

@@ -8,6 +8,7 @@ operations nested inside it). The runner's spans are ``TraceAnnotation``
 events named ``bench:<span>`` on the host plane, on the same clock.
 """
 
+import bisect
 import glob
 import os
 import re
@@ -127,7 +128,16 @@ def by_family(times, op_stats, label=op_family):
 def gaps_by_span(busy, spans, lo, hi):
     """Idle seconds inside [lo, hi] by the runner span the host was in.
     ``busy`` is a merged interval list; ``spans`` are (name, start, dur).
-    Idle time under no span goes to ``_no_benchmark_span_``."""
+    Idle time under no span goes to ``_no_benchmark_span_``.
+
+    Spans nest, and every span over a gap is given the gap (an outer one
+    as its inner ones), so the gaps are walked once and each span looks its
+    own up in them: the gaps are disjoint and in time order, a span meets a
+    run of them, cuts at most the first and the last of the run and holds
+    the ones between whole. Those it reads off the running sum of the gaps'
+    lengths; ``whole`` keeps, as a difference over the gaps, how many spans
+    hold each whole, and ``cut`` the seconds of each under spans that cut
+    it, for what is left under no span."""
     gaps, at = [], lo
     for s, e in busy:
         if s > at:
@@ -135,16 +145,36 @@ def gaps_by_span(busy, spans, lo, hi):
         at = max(at, e)
     if at < hi:
         gaps.append((at, hi))
+    starts = [g0 for g0, _ in gaps]
+    ends = [g1 for _, g1 in gaps]
+    before = [0.0] * (len(gaps) + 1)         # idle seconds in the gaps ahead of each
+    for i, (g0, g1) in enumerate(gaps):
+        before[i + 1] = before[i] + (g1 - g0)
+    cut = [0.0] * len(gaps)
+    whole = [0] * (len(gaps) + 1)
     out = {}
-    for g0, g1 in gaps:
-        left = g1 - g0
-        for name, start, dur in spans:
-            if name == WINDOW_SPAN:
-                continue
-            over = min(g1, start + dur) - max(g0, start)
-            if over > 0:
-                out[name] = out.get(name, 0.0) + over
-                left -= over
+    for name, start, dur in spans:
+        end = start + dur
+        if name == WINDOW_SPAN or end <= start:
+            continue
+        first = bisect.bisect_right(ends, start)         # the first gap that ends after the span starts
+        last = bisect.bisect_left(starts, end) - 1       # the last that starts before it ends
+        if first > last:
+            continue
+        over = 0.0
+        for i in ((first,) if first == last else (first, last)):
+            part = min(ends[i], end) - max(starts[i], start)
+            cut[i] += part
+            over += part
+        if last - first > 1:
+            over += before[last] - before[first + 1]
+            whole[first + 1] += 1
+            whole[last] -= 1
+        out[name] = out.get(name, 0.0) + over
+    held = 0
+    for i, (g0, g1) in enumerate(gaps):
+        held += whole[i]
+        left = (g1 - g0) - cut[i] - held * (g1 - g0)
         if left > 1e-12:
             out[NO_SPAN] = out.get(NO_SPAN, 0.0) + left
     return out

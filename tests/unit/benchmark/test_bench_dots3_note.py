@@ -18,8 +18,8 @@ CELL, LIKE = "t-longctx", "serve-dots3-note-prev-longctx-sat"
 SEED = 2 ** 31 + 37
 DEVICE_ONLY = {"decode_roofline_longctx", "prefill_roofline_longctx",
                "dsa_prefill_walk_roofline_longctx",
-               "moe_kernel_time_pct_longctx", "moe_kernel_roofline_longctx",
-               "device_idle_pct_longctx", "dsa_attn_time_pct_longctx",
+               "moe_kernel_time_pct_sat", "moe_kernel_roofline_longctx",
+               "device_idle_pct_sat", "dsa_attn_time_pct_longctx",
                "dsa_index_decode_roofline_longctx", "dsa_index_prefill_roofline_longctx",
                "dsa_decode_roofline_longctx"}
 
@@ -75,15 +75,15 @@ def test_the_traced_run_reads_the_program_and_leaves_device_numbers_out(lines, l
     cell = harness.Cell(longctx_copy[0], longctx_copy[1], CELL)
     assert set(metrics) == {m["name"] for m in cell.per_layer} - DEVICE_ONLY
     # a quarter of the experts is held: three copies in four are another chip's
-    assert 45 < metrics["moe_elsewhere_pct_longctx"]["value"] < 95
-    assert 0 < metrics["moe_pad_pct_longctx"]["value"] < 100
-    assert 0 < metrics["prefill_fill_pct_longctx"]["value"] <= 100
+    assert 45 < metrics["moe_elsewhere_pct_sat"]["value"] < 95
+    assert 0 < metrics["moe_pad_pct_sat"]["value"] < 100
+    assert 0 < metrics["prefill_fill_pct_sat"]["value"] <= 100
     # 24 chosen of up to 116 live: the selection binds in most ticks
     assert 20 < metrics["sparse_selected_pct_longctx"]["value"] < 90
     # 17 of a ring's 32 positions are a query's window at the most
     assert 0 < metrics["window_read_live_pct_longctx"]["value"] <= 100 * 17 / 32 + 1e-6
     assert metrics["index_read_gb_per_tick_longctx"]["value"] > 0
-    assert metrics["recompiles_in_window_longctx"]["value"] == 0
+    assert metrics["recompiles_in_window_sat"]["value"] == 0
 
 
 @pytest.mark.parametrize("control", ["program", "fp8_weights", "last_positions"])
@@ -155,26 +155,28 @@ def test_the_real_cell_is_in_the_manifest_as_the_issue_gives_it():
     assert [m["name"] for m in cell.end_to_end] == ["serve_total_tok_s", "setup_s"]
     # membership, never position: a later PR may append
     names = {m["name"] for m in cell.per_layer}
-    want = {base + "_longctx" for base in (
-        "decode_device_wait_ms_p50", "prefill_device_wait_ms_p50", "sched_host_ms_p50",
-        "device_idle_pct", "recompiles_in_window", "slot_occupancy_pct", "kv_live_pct",
-        "prefill_fill_pct", "tick_ahead_pct", "decode_roofline", "prefill_roofline",
-        "moe_pad_pct", "moe_elsewhere_pct", "moe_kernel_time_pct", "moe_kernel_roofline",
+    own = {base + "_longctx" for base in (
+        "decode_roofline", "prefill_roofline", "moe_kernel_roofline",
         "sparse_selected_pct", "index_read_gb_per_tick", "window_read_live_pct",
         "dsa_attn_time_pct", "dsa_index_decode_roofline", "dsa_index_prefill_roofline",
         "dsa_prefill_walk_roofline",
         "dsa_decode_roofline")}
+    # the readers it shares with the other saturated cells: one entry each
+    shared = {"decode_device_wait_ms_p50_sat", "prefill_device_wait_ms_p50", "sched_host_ms_p50_sat",
+              "device_idle_pct_sat", "recompiles_in_window_sat", "slot_occupancy_pct",
+              "kv_live_pct_sat", "prefill_fill_pct_sat", "tick_ahead_pct_sat", "moe_pad_pct_sat",
+              "moe_elsewhere_pct_sat", "moe_kernel_time_pct_sat"}
     # what its start is made of, under ``setup_s``: the six of ``lib/program_setup.py``
-    start = {base + "_longctx" for base in (
-        "setup_trace_lower_s", "setup_backend_load_s", "setup_cache_misses",
-        "setup_programs_loaded", "setup_engine_init_s", "setup_import_s")}
-    assert want | start <= names
+    start = {"setup_trace_lower_s", "setup_backend_load_s", "setup_cache_misses",
+             "setup_programs_loaded", "setup_engine_init_s", "setup_import_s"}
+    assert own | shared | start <= names
     for metric in cell.per_layer:
         path = os.path.join(harness.REPO_ROOT, "benchmarks", "layer_metrics", metric["name"])
         assert os.path.exists(path + ".py") or os.path.exists(path + ".json")
-        if metric["name"] in want | start:
+        if metric["name"] in own | shared | start:
             assert metric["moves"] == ("setup_s" if metric["name"] in start
                                        else "serve_total_tok_s")
+        if metric["name"] in own:
             assert metric["workloads"] == [LIKE]
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
 

@@ -16,8 +16,8 @@ from benchmarks.lib import program_spans
 CELL, LIKE = "t-longdoc", "serve-joyai-llm-flash-longdoc-sat"
 SEED = 2 ** 31 + 32
 DEVICE_ONLY = {"decode_roofline_longdoc", "prefill_roofline_longdoc",
-               "moe_kernel_time_pct_longdoc", "moe_kernel_roofline_longdoc",
-               "device_idle_pct_longdoc", "mla_attn_time_pct_longdoc",
+               "moe_kernel_time_pct_sat", "moe_kernel_roofline_longdoc",
+               "device_idle_pct_sat", "mla_attn_time_pct_longdoc",
                "mla_decode_roofline_longdoc"}
 
 
@@ -73,12 +73,12 @@ def test_the_traced_run_reads_the_program_and_leaves_device_numbers_out(lines, l
     assert set(metrics) == {m["name"] for m in cell.per_layer} - DEVICE_ONLY
     # a quarter of the experts is held: three copies in four are another chip's
     # (this seed's sixteen-expert router reads 58-66 as the window's ticks fall)
-    assert 45 < metrics["moe_elsewhere_pct_longdoc"]["value"] < 90
-    assert 0 < metrics["moe_pad_pct_longdoc"]["value"] < 100
-    assert 0 < metrics["prefill_fill_pct_longdoc"]["value"] <= 100
+    assert 45 < metrics["moe_elsewhere_pct_sat"]["value"] < 90
+    assert 0 < metrics["moe_pad_pct_sat"]["value"] < 100
+    assert 0 < metrics["prefill_fill_pct_sat"]["value"] <= 100
     # a decode tick reads whole pools, a prefill tick whole key blocks
     assert 0 < metrics["latent_read_live_pct_longdoc"]["value"] < 100
-    assert metrics["recompiles_in_window_longdoc"]["value"] == 0
+    assert metrics["recompiles_in_window_sat"]["value"] == 0
 
 
 @pytest.mark.parametrize("control", ["program", "fp8_weights"])
@@ -118,11 +118,15 @@ def test_the_real_cell_is_in_the_manifest_as_the_issue_gives_it():
             mix["trace_seconds"]) == (16384, 16, 20, 0, 4)
     assert [m["name"] for m in cell.end_to_end] == ["serve_total_tok_s", "setup_s"]
     names = [m["name"] for m in cell.per_layer]
-    assert len(names) == 17 and all(name.endswith("_longdoc") for name in names)
+    # the family's own; the rest are readers the cell shares, one entry each
+    own = [name for name in names if name.endswith("_longdoc")]
+    assert len(own) == 6
     for metric in cell.per_layer:
         path = os.path.join(harness.REPO_ROOT, "benchmarks", "layer_metrics", metric["name"])
         assert os.path.exists(path + ".py") or os.path.exists(path + ".json")
-        assert metric["moves"] == "serve_total_tok_s" and metric["workloads"] == [LIKE]
+        assert metric["moves"] in ("serve_total_tok_s", "setup_s")
+        if metric["name"] in own:
+            assert metric["moves"] == "serve_total_tok_s" and metric["workloads"] == [LIKE]
 
 
 def test_the_configuration_is_the_catalogs_but_for_what_it_lists():

@@ -2,18 +2,23 @@
 test can hold it: keys, names, units, files, and that every per-layer
 metric's ``moves`` is reported by each cell the metric is reported in."""
 
+import ast
 import json
 import os
 import re
+import shutil
 
 import pytest
 
-from benchmarks.lib.harness import REPO_ROOT, Cell
+from benchmarks.lib.harness import REPO_ROOT, Cell, load_json
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-WIDTH = re.compile(r"(hidden|intermediate|latent|state|proj|_dim$|_rank$|head_size|n_embd|expand)")
+# a key ``reduced`` may never name: a width, or the experts a token takes. ``hidden_size``, not
+# ``hidden``: ``num_hidden_layers`` is a depth, and every configuration that cuts it names it
+WIDTH = re.compile(r"(hidden_size|intermediate|latent|state|proj|_dim$|_rank$|head_size|n_embd|expand"
+                   r"|experts_per_tok)")
 
 
 def manifest():
@@ -66,6 +71,22 @@ def test_config_entry_and_file(config):
     assert any(w["config"] == config["name"] for w in M["workloads"])
     assert os.path.exists(os.path.join(REPO_ROOT, "benchmarks", "reference", body["family"] + ".py"))
     assert os.path.exists(os.path.join(REPO_ROOT, "benchmarks", "families", body["family"] + ".py"))
+
+
+@pytest.mark.parametrize("reduced, refused", [
+    (["num_hidden_layers", "n_routed_experts", "vocab_size", "layer_types"], []),      # depth and counts
+    (["hybrid_override_pattern", "rope_layout", "sliding_window_layout", "moe_num_primary_experts",
+      "attn_pdrop"], []),
+    (["num_hidden_layers", "hidden_size"], ["hidden_size"]),
+    (["moe_ffn_hidden_size", "intermediate_size", "moe_intermediate_size"],
+     ["moe_ffn_hidden_size", "intermediate_size", "moe_intermediate_size"]),
+    (["q_lora_rank", "kv_lora_rank", "head_dim", "qk_rope_head_dim", "v_head_dim"],
+     ["q_lora_rank", "kv_lora_rank", "head_dim", "qk_rope_head_dim", "v_head_dim"]),
+    (["moe_latent_size", "ssm_state_size", "n_embd", "expand", "num_experts_per_tok", "vocab_size"],
+     ["moe_latent_size", "ssm_state_size", "n_embd", "expand", "num_experts_per_tok"]),
+], ids=["depth", "layouts", "hidden", "expert-widths", "ranks-and-heads", "states-and-experts-a-token"])
+def test_a_made_up_reduced_may_cut_depth_and_no_width(reduced, refused):
+    assert [k for k in reduced if WIDTH.search(k)] == refused
 
 
 def test_configs_are_the_published_sizes():
@@ -125,11 +146,73 @@ def test_metric_names_are_distinct_and_setup_is_there():
     assert setup and "workloads" not in setup[0] and setup[0]["bound"] <= 0.1
 
 
-@pytest.mark.parametrize("metric", M["per_layer"], ids=lambda m: m["name"])
-def test_moves_is_reported_wherever_the_metric_is(metric):
+# the runner a cell's traffic names: train or serve
+KIND = {w["name"]: load_json(REPO_ROOT, "benchmarks", "traffic", w["traffic"] + ".json")["kind"]
+        for w in M["workloads"]}
+LISTED = [(m, cell) for m in M["per_layer"] for cell in m.get("workloads", CELLS)]
+
+
+@pytest.mark.parametrize("metric, cell", LISTED, ids=lambda v: v if isinstance(v, str) else v["name"])
+def test_moves_is_reported_in_each_cell_the_metric_lists(metric, cell):
+    """An entry lists the cells whose runs its reader finds something in, whichever PR
+    brought each: every one is a cell, reports the metric the entry moves, and a reader
+    of serving ticks lists no training cell (nor one of steps a serving cell). An entry
+    that lists none is read in every cell, a later PR's too."""
     moved = [m for m in M["end_to_end"] if m["name"] == metric["moves"]]
     assert moved, f"{metric['name']} moves no end-to-end metric"
-    assert set(metric.get("workloads", CELLS)) <= set(moved[0].get("workloads", CELLS))
+    assert cell in CELLS and cell in moved[0].get("workloads", CELLS)
+    listed = metric.get("workloads", CELLS)
+    if metric["moves"] != "setup_s":
+        assert {KIND[c] for c in listed} == {KIND[cell]}
+    assert listed == [c for c in CELLS if c in listed]           # once each, in the cells' order
+
+
+def reader_body(name, root=REPO_ROOT):
+    """What a per-layer entry's reader does, apart from what it is called and what it
+    says of itself: a ``.json`` by its content, a ``.py`` with the docstring set aside."""
+    base = os.path.join(root, "benchmarks", "layer_metrics", name)
+    if os.path.exists(base + ".py"):
+        with open(base + ".py") as f:
+            tree = ast.parse(f.read())
+        if ast.get_docstring(tree, clean=False) is not None:
+            tree.body = tree.body[1:]
+        return "py:" + ast.dump(tree)
+    with open(base + ".json") as f:
+        return "json:" + json.dumps(json.load(f), sort_keys=True)
+
+
+def copies(per_layer, root=REPO_ROOT):
+    """Pairs of entries that move one end-to-end metric through readers with equal bodies."""
+    first, found = {}, []
+    for metric in per_layer:
+        key = (metric["moves"], reader_body(metric["name"], root))
+        if key in first:
+            found.append((first[key], metric["name"]))
+        first.setdefault(key, metric["name"])
+    return found
+
+
+def test_no_two_entries_that_move_one_metric_share_a_readers_body():
+    """A cell that needs an accepted reader joins that entry's ``workloads``; it does not
+    bring the reader again under a name of its own."""
+    assert copies(M["per_layer"]) == [], "add the cell to the first entry's workloads instead"
+
+
+def test_a_copy_under_another_name_is_found(tmp_path):
+    folder = tmp_path / "benchmarks" / "layer_metrics"
+    shutil.copytree(os.path.join(REPO_ROOT, "benchmarks", "layer_metrics"), folder)
+    shutil.copy(folder / "device_idle_pct_sat.json", folder / "device_idle_pct_next.json")
+    source = (folder / "tick_ahead_pct_sat.py").read_text()
+    (folder / "tick_ahead_pct_next.py").write_text(
+        '"""The same reader, said otherwise."""' + source[source.index('"""', 3) + 3:])
+    entry = {m["name"]: m for m in M["per_layer"]}
+    added = [dict(entry["device_idle_pct_sat"], name="device_idle_pct_next", workloads=CELLS[-1:]),
+             dict(entry["tick_ahead_pct_sat"], name="tick_ahead_pct_next", workloads=CELLS[-1:])]
+    assert copies(M["per_layer"] + added, str(tmp_path)) == [
+        ("device_idle_pct_sat", "device_idle_pct_next"), ("tick_ahead_pct_sat", "tick_ahead_pct_next")]
+    # the same body under another end-to-end metric is no copy: an entry has one ``moves``
+    assert reader_body("device_idle_pct_chat") == reader_body("device_idle_pct_sat")
+    assert entry["device_idle_pct_chat"]["moves"] != entry["device_idle_pct_sat"]["moves"]
 
 
 def test_one_layer_name_per_layer():

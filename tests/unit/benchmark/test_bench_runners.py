@@ -120,7 +120,9 @@ def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric_as_files(benc
     untraced = harness.run_cell(root, manifest, "t-new", SEED, 0.5, 0, require_tpu=False)
     traced = harness.run_cell(root, manifest, "t-new", SEED, 0.5, 1, require_tpu=False)
     assert untraced["correct"] and set(untraced["metrics"]) == {"serve_total_tok_s", "setup_s"}
-    assert set(traced["metrics"]) == {"decode_tick_ms_p99", "ticks_total"}
+    # its own two, and the entries that list no cells, which every cell reports
+    everywhere = {m["name"] for m in manifest["per_layer"] if "workloads" not in m}
+    assert set(traced["metrics"]) == {"decode_tick_ms_p99", "ticks_total"} | everywhere
     assert traced["metrics"]["ticks_total"]["value"] > 0
     for path, content in before.items():
         if not path.endswith("BENCHMARK.json"):
