@@ -50,17 +50,18 @@ from jax.sharding import NamedSharding, PartitionSpec
 # keys and values, POOL_LEAVES those and a latent-attention layer's one
 # ``cached_latent`` pool (``models/common.py`` LatentCache): every pool that
 # holds rows by position (an indexed layer's ``cached_index_key`` beside its
-# latent among them). RING_LEAVES are a window layer's latent: the stored form
-# of a pool over a ring of positions, written at ``position mod ring``; it is
-# no pool (it has lost most positions' rows) and its extent is not the slots'
-# capacity
+# latent among them). RING_LEAVES are a window layer's latent, or its keys and
+# values (RING_KV_LEAVES, ``models/llama.py``): the stored form of a pool over a
+# ring of positions, written at ``position mod ring``; it is no pool (it has
+# lost most positions' rows) and its extent is not the slots' capacity
 # a model with recurrent layers (``models/nemotron_h.py``) adds STATE_LEAVES
 # (per-slot state with no positions, ``[slots, ...]`` as the model shapes
 # it), LENGTH_LEAVES (how many of a slot's tokens this tick are real) and,
 # where a layer counts for the host, COUNTER_LEAVES
 from deepspeed_tpu.models.common import (COUNTER_LEAVES, INDEX_KEY_LEAVES, INDEX_LEAVES,
                                          KV_LEAVES, LATENT_LEAVES, LENGTH_LEAVES, POOL_LEAVES,
-                                         RING_LEAVES, SLOT_LEAF, STATE_LEAVES, slot_pool,
+                                         RING_KV_LEAVES, RING_LEAVES, SLOT_LEAF, STATE_LEAVES,
+                                         slot_pool,
                                          slot_pool_positions, slot_pool_scale)
 from deepspeed_tpu.utils import trace
 
@@ -143,6 +144,9 @@ def quantize_slot_cache(cache):
         for name, leaf in tree.items():
             if isinstance(leaf, dict) or hasattr(leaf, "items"):
                 out[name] = walk(leaf)
+            elif name in KV_LEAVES + RING_KV_LEAVES:
+                out[name] = jnp.zeros(leaf.shape, jnp.int8)
+                out[name + "_scale"] = slot_pool_scale(leaf)
             elif name in LATENT_LEAVES + RING_LEAVES:
                 raise NotImplementedError(
                     f"kv_quant over a latent pool ({name}): an int8 latent is not built (every "
@@ -153,9 +157,6 @@ def quantize_slot_cache(cache):
                     f"kv_quant over an indexer's keys ({name}): int8 index keys are not built "
                     f"(they decide WHICH positions are attended, so their tolerance is a set's, "
                     f"not a logit's); serve this model with kv_quant=False")
-            elif name in KV_LEAVES:
-                out[name] = jnp.zeros(leaf.shape, jnp.int8)
-                out[name + "_scale"] = slot_pool_scale(leaf)
             else:
                 out[name] = leaf
         return out
@@ -255,7 +256,7 @@ def rows_of_slots(cache, slot_ids):
         out = {name: walk(leaf) if hasattr(leaf, "items")
                else leaf[slot_ids] if name in STATE_LEAVES else leaf
                for name, leaf in tree.items()}
-        if any(name in KV_LEAVES for name in tree):
+        if any(name in KV_LEAVES + RING_KV_LEAVES for name in tree):
             out[SLOT_LEAF] = slot_ids
         return out
 
@@ -281,7 +282,8 @@ def with_counters(cache, tok):
     """Traced: what a tick reads back. ``tok`` [slots] int32 (a rung's: one
     a sequence it ran), and behind it
     the cache's ``COUNTER_LEAVES`` (int32 vectors a layer left for the host:
-    ``MOELayer.experts_held``'s rows, a latent-attention layer's reads), each
+    ``MOELayer.experts_held``'s rows, a latent-attention layer's reads, a walked
+    pool's, ``kv_reads``), each
     name's summed over the layers, in ``COUNTER_LEAVES``' order, where the
     model has any, so that the one read-back a tick makes carries them."""
     counters = [sum(leaves) for name in COUNTER_LEAVES
@@ -385,7 +387,12 @@ def build_prefill_step(apply_fn, do_sample: bool, temperature: float,
         else:
             logits, ran = apply_fn(params, rows_of_slots(fed, slot_ids), ids)
             cache = rows_to_slots(cache, ran, slot_ids)
-        logits = jnp.take_along_axis(logits, last_idx[:, None, None], axis=1)[:, 0]
+        if logits.shape[1] == 1:
+            # a model that makes a chunk's logits for its last real token alone
+            # (``LlamaConfig.head_last_fed_only``), or a chunk of one token
+            logits = logits[:, 0]
+        else:
+            logits = jnp.take_along_axis(logits, last_idx[:, None, None], axis=1)[:, 0]
         if do_sample:
             tok = sample_logits(logits, *rng, True, temperature, top_k, top_p)
         else:

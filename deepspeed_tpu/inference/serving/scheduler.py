@@ -50,7 +50,7 @@ from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, RING_LEAVES, 
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
-from deepspeed_tpu.models.common import (SPARSE_READS, slot_pool_positions_touched,
+from deepspeed_tpu.models.common import (KV_READS, SPARSE_READS, slot_pool_positions_touched,
                                          slot_pool_row_shape, slot_pool_rows,
                                          slot_pool_set_rows)
 from deepspeed_tpu.runtime.telemetry.metrics import Histogram
@@ -227,21 +227,31 @@ class ContinuousBatchingScheduler:
                 f"has already advanced over the drafted tokens: verification needs the "
                 f"state at the accepted position, which is not built")
 
+        if getattr(getattr(self.module, "config", None), "head_last_fed_only", False) and (
+                config.speculation.enabled or drafter is not None):
+            raise NotImplementedError(
+                f"speculative decoding over {type(self.module).__name__} with "
+                f"head_last_fed_only: the verify step reads the target's token at every "
+                f"drafted position, and this model makes a chunk's logits for its last real "
+                f"token alone: serve it with head_last_fed_only=False to speculate")
         # a window layer's ring has overwritten most positions' rows: what
         # copies rows by position refuses it by name until it carries the
         # window (the last ``window - 1`` rows and where the ring stands)
         self._ring = has_ring(self._cache)
+        rings = self._ring_names = ", ".join(sorted(
+            {_leaf_name(path) for path, _ in jax.tree_util.tree_flatten_with_path(self._cache)[0]
+             if _leaf_name(path) in RING_LEAVES}))
         if self._ring and config.prefix_cache == "on":
             raise NotImplementedError(
                 f"prefix_cache='on' over {type(self.module).__name__}: a shared prefix is "
                 f"restored as cache rows at positions, and this model's window layers keep a "
-                f"ring (cached_window_latent) that has overwritten them: sharing needs the "
+                f"ring ({rings}) that has overwritten them: sharing needs the "
                 f"window's rows at the prefix's end in the block, which is not built")
         if self._ring and (config.speculation.enabled or drafter is not None):
             raise NotImplementedError(
                 f"speculative decoding over {type(self.module).__name__}: the verify step "
                 f"writes k + 1 positions into this model's window rings "
-                f"(cached_window_latent), which are sized for the window and a prefill chunk "
+                f"({rings}), which are sized for the window and a prefill chunk "
                 f"and have not been checked against a rejected draft's rewrite: not built")
 
         # admission: block-pool truthful KV accounting. A byte budget is
@@ -431,6 +441,7 @@ class ContinuousBatchingScheduler:
                 self._rec.count("moe_rows_buffered", buffered)
                 self._rec.count(f"moe_rows_routed_{kind}", here)
                 self._rec.count(f"moe_rows_buffered_{kind}", buffered)
+                self._rec.count("moe_experts_touched", touched)
                 self._rec.count(f"moe_experts_touched_{kind}", touched)
             elif name == "latent_reads":
                 read, live, written = counted
@@ -445,6 +456,14 @@ class ContinuousBatchingScheduler:
                     if what.endswith("_written"):
                         self._rec.count(what, n)
                     else:
+                        self._count_of_tick(what, n, kind)
+            elif name == "kv_reads":
+                # ``models/common.py`` KV_READS: positions a walked pool's and a
+                # ring's decode attention was bounded to and positions live
+                # there, by the kind of tick; bytes written into rings
+                for what, n in zip(KV_READS, counted):
+                    self._rec.count(what, n)
+                    if not what.endswith("_written"):
                         self._count_of_tick(what, n, kind)
         return tok[:ran]
 
@@ -1251,7 +1270,7 @@ class ContinuousBatchingScheduler:
             raise MigrationError(
                 f"live migration over {type(self.module).__name__}: a request's committed "
                 f"state travels as cache rows up to its length, and this model's window "
-                f"layers keep a ring (cached_window_latent) whose rows are not at their "
+                f"layers keep a ring ({self._ring_names}) whose rows are not at their "
                 f"positions: migrating it needs the window's rows in the payload, which is "
                 f"not built — drain instead")
         if self._recurrent:

@@ -1,7 +1,7 @@
 """Shared model-zoo helpers."""
 
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import numpy as np
 
@@ -65,7 +65,12 @@ POOL_LEAVES = KV_LEAVES + LATENT_LEAVES + INDEX_KEY_LEAVES
 #: migration, a speculation's drafter) refuses a cache that has one, its
 #: extent is not the slot's capacity, and a parked slot's sentinel position
 #: must not be folded into it (``fed`` 0 is what drops a ring's write)
-RING_LEAVES = ("cached_window_latent",)
+#: a grouped-query window layer (``models/llama.py``) keeps the same kind of
+#: ring for its keys and its values, ``RING_KV_LEAVES`` (:class:`DecodeCache`
+#: with ``ring=True``): these two are quantised as a pool's keys and values are
+#: (``<name>_scale`` beside each), the latent's ring is not
+RING_KV_LEAVES = ("cached_window_key", "cached_window_value")
+RING_LEAVES = ("cached_window_latent",) + RING_KV_LEAVES
 #: a second kind of per-slot state, with no positions (``models/nemotron_h.py``):
 #: a recurrent layer's state ``[slots, ...]``, carried from tick to tick, never
 #: quantised, zeroed when a request joins at position 0; ``LENGTH_LEAVES`` say
@@ -79,7 +84,7 @@ RING_LEAVES = ("cached_window_latent",)
 #: :data:`SPARSE_READS` names its entries)
 STATE_LEAVES = ("ssm_state", "conv_state")
 LENGTH_LEAVES = ("chunk_length",)
-COUNTER_LEAVES = ("moe_rows", "latent_reads", "sparse_reads")
+COUNTER_LEAVES = ("moe_rows", "latent_reads", "sparse_reads", "kv_reads")
 #: the entries of a ``sparse_reads`` leaf, in order, under the names the
 #: host counts them by (by the kind of tick, but for the bytes ``_written``).
 #: An indexed layer fills the ``dsa_`` ones (positions of the index-key pool its
@@ -92,6 +97,13 @@ SPARSE_READS = ("dsa_index_keys_read", "dsa_positions_selected", "dsa_positions_
                 "swa_ring_positions_read", "swa_ring_positions_live", "dsa_latent_bytes_written",
                 "dsa_index_key_bytes_written", "swa_ring_bytes_written",
                 "dsa_select_positions_read")
+#: the entries of a ``kv_reads`` leaf, in order (``models/llama.py``, a layer
+#: whose decode attention walks its stored pool: ``decode_key_block``): positions
+#: of a full layer's pool the walk was bounded to and, of those, the ones at or
+#: before some real query; the same of a window layer's ring (read; inside a
+#: real query's window); bytes a window layer wrote into its ring
+KV_READS = ("kv_full_positions_read", "kv_full_positions_live", "kv_ring_positions_read",
+            "kv_ring_positions_live", "kv_ring_bytes_written")
 #: a serving program that runs fewer sequences than the cache has slots (a
 #: rung of ``serving/programs.py``'s prefill ladder) says which slot each
 #: sequence is: ``cache_slots`` [n] int32, distinct, which the program lays
@@ -135,21 +147,30 @@ class DecodeCache:
       default): codes plus per-(slot, head, position) ``_scale`` leaves
       [slots, kv heads, positions], quantized on write and dequantized on
       read.
+
+    ``ring=True`` (a window layer's, ``RING_KV_LEAVES``): ``positions`` is a
+    RING, token ``p`` written at ``p mod positions`` (:func:`ring_pool_append`)
+    and only by a sequence that is ``live`` (:meth:`write`): a parked slot's
+    sentinel must not be folded into the ring. Whoever reads a ring reads the
+    stored leaves (:meth:`stored`) under a mask made from the query's own
+    position (:func:`ring_mask`).
     """
 
     def __init__(self, module: nn.Module, batch: int, positions: int, kv_heads: int,
-                 head_dim: int, dtype):
+                 head_dim: int, dtype, ring: bool = False):
         shape = (batch, positions, kv_heads, head_dim)
-        self.key = module.variable("cache", "cached_key", jnp.zeros, shape, dtype)
-        self.value = module.variable("cache", "cached_value", jnp.zeros, shape, dtype)
+        key, value = RING_KV_LEAVES if ring else KV_LEAVES
+        self.key = module.variable("cache", key, jnp.zeros, shape, dtype)
+        self.value = module.variable("cache", value, jnp.zeros, shape, dtype)
         self.quantized = self.key.value.dtype == jnp.int8
         if self.quantized:
-            self.key_scale = module.variable("cache", "cached_key_scale", jnp.zeros,
+            self.key_scale = module.variable("cache", key + "_scale", jnp.zeros,
                                              (batch, kv_heads, positions), dtype)
-            self.value_scale = module.variable("cache", "cached_value_scale", jnp.zeros,
+            self.value_scale = module.variable("cache", value + "_scale", jnp.zeros,
                                                (batch, kv_heads, positions), dtype)
         self.index = module.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
         self.slots = _cache_slots(module)
+        self.ring = ring
 
     @property
     def per_slot(self) -> bool:
@@ -172,35 +193,74 @@ class DecodeCache:
         slots dequantises and attends a few) and each sequence's live
         length."""
         b, l = k.shape[0], k.shape[1]
+        if self.ring:
+            raise NotImplementedError("a ring is read as it is stored, under ring_mask: "
+                                      "write() and stored()")
         idx = self.index.value
+        self.write(k, v)
         if self.per_slot:
-            self._append_per_slot(k, v)
-            self.index.value = idx + l
             scales = (self.key_scale, self.value_scale) if self.quantized else (None, None)
             keys, values = (slot_pool_read(pool.value, scale and scale.value, read_dtype,
                                            self.slots)
                             for pool, scale in zip((self.key, self.value), scales))
             return keys, values, idx + l
-        if self.quantized:
-            raise NotImplementedError(
-                "int8 KV pools are a per-slot serving cache "
-                "(make_slot_cache(kv_quant=True)); lockstep decode uses fp KV")
-        self.key.value = jax.lax.dynamic_update_slice(self.key.value, k, (0, idx, 0, 0))
-        self.value.value = jax.lax.dynamic_update_slice(self.value.value, v, (0, idx, 0, 0))
-        self.index.value = idx + l
         # per-sequence live lengths: the flash backend's decode kernel
         # skips dead KV blocks, the XLA backend masks by them
         return self.key.value, self.value.value, jnp.broadcast_to(idx + l, (b,))
 
-    def _append_per_slot(self, k, v):
+    def _append_per_slot(self, k, v, live=None):
         pools, vals = [self.key, self.value], [k, v]
         if self.quantized:
             (k, k_s), (v, v_s) = _kv_quantize(k), _kv_quantize(v)
             pools += [self.key_scale, self.value_scale]
             vals = [k, v, k_s[..., 0], v_s[..., 0]]
-        for pool, new in zip(pools, slot_pool_append([p.value for p in pools], vals,
-                                                     self.index.value, self.slots)):
-            pool.value = new
+        held, at = [p.value for p in pools], self.index.value
+        if self.ring:
+            live = jnp.ones(at.shape, bool) if live is None else live
+            new = ring_pool_append(held, vals, at, live, self.slots)
+        else:
+            new = slot_pool_append(held, vals, at, self.slots)
+        for pool, leaf in zip(pools, new):
+            pool.value = leaf
+
+    def write(self, k, v, live=None):
+        """Write ``k`` / ``v`` [batch, l, kv heads, head dim] at the index and
+        advance it; returns each sequence's first written position [batch].
+        ``live`` [batch] bool: the sequences that write into a ring at all (a
+        pool's parked slot writes out of bounds). What was written is read
+        through :meth:`stored`."""
+        b, l = k.shape[:2]
+        idx = self.index.value
+        if self.per_slot:
+            self._append_per_slot(k, v, live)
+            self.index.value = idx + l
+            return idx
+        self.index.value = idx + l
+        if self.quantized:
+            raise NotImplementedError(
+                "int8 KV pools are a per-slot serving cache "
+                "(make_slot_cache(kv_quant=True)); lockstep decode uses fp KV")
+        for pool, new in ((self.key, k), (self.value, v)):
+            if self.ring:
+                at = (idx + jnp.arange(l)) % pool.value.shape[1]
+                pool.value = pool.value.at[:, at].set(new.astype(pool.value.dtype))
+            else:
+                pool.value = jax.lax.dynamic_update_slice(pool.value, new.astype(pool.value.dtype),
+                                                          (0, idx, 0, 0))
+        return jnp.broadcast_to(idx, (b,))
+
+    def stored(self):
+        """``(keys, key scales, values, value scales)`` as the serving cache
+        stores them: pools [slots, kv heads, head dim, positions] (the leaves
+        themselves, no copy; a lockstep pool's transpose) and, of an int8
+        pool, scales [slots, kv heads, positions], else None. Sequence ``s``
+        of a call over ``cache_slots`` is row ``self.slots[s]``."""
+        if self.per_slot:
+            scales = (self.key_scale.value, self.value_scale.value) if self.quantized \
+                else (None, None)
+            return self.key.value, scales[0], self.value.value, scales[1]
+        return (jnp.transpose(self.key.value, (0, 2, 3, 1)), None,
+                jnp.transpose(self.value.value, (0, 2, 3, 1)), None)
 
 
 class LatentCache:
@@ -370,12 +430,13 @@ def slot_pool_append(leaves, updates, pos, rows=None):
             .set(upd.astype(leaf.dtype)) for leaf, upd in zip(leaves, updates)]
 
 
-def ring_pool_append(leaves, updates, pos, live):
+def ring_pool_append(leaves, updates, pos, live, rows=None):
     """:func:`slot_pool_append` into RINGS: token ``j`` of slot ``s`` goes to
     ``(pos[s] + j) mod ring`` of the stored leaves ``[slots, ..., ring]``, and
     a slot that is not ``live`` [slots] writes nothing (``pos`` is then a
     parked slot's sentinel, which must not be folded into the ring). A piece
-    that runs over the ring's end goes on at its start.
+    that runs over the ring's end goes on at its start. ``rows`` as
+    :func:`slot_pool_append`'s.
 
     On a TPU two in-place writes: one at ``pos mod ring``, which drops what
     runs past the end, and, for more than one token, one a ring earlier,
@@ -386,13 +447,31 @@ def ring_pool_append(leaves, updates, pos, live):
     pos = pos.astype(jnp.int32)
     if backend.on_tpu():
         at = jnp.where(live, pos % ring, ring)
-        leaves = _append_in_place(leaves, updates, at)
+        leaves = _append_in_place(leaves, updates, at, rows)
         if length > 1:
-            leaves = _append_in_place(leaves, updates, jnp.where(live, at - ring, ring))
+            leaves = _append_in_place(leaves, updates, jnp.where(live, at - ring, ring), rows)
         return leaves
     at = jnp.where(live[:, None], (pos[:, None] + jnp.arange(length)[None, :]) % ring, ring)
-    return [leaf.at[jnp.arange(slots)[:, None], ..., at].set(upd.astype(leaf.dtype))
-            for leaf, upd in zip(leaves, updates)]
+    return [leaf.at[(jnp.arange(slots) if rows is None else rows)[:, None], ..., at]
+            .set(upd.astype(leaf.dtype)) for leaf, upd in zip(leaves, updates)]
+
+
+def window_ring_positions(window: int, chunk: int, page: int = 128) -> int:
+    """Positions of a window layer's ring for calls of at most ``chunk``
+    tokens: the ``window - 1`` positions the chunk's first query looks back on
+    and the chunk itself, rounded up to whole pages."""
+    return -(-(window - 1 + chunk) // page) * page
+
+
+def ring_mask(q_pos, k_at, ring: int, window: int):
+    """What queries at ``q_pos`` [..., l] may read of a ring's places ``k_at``
+    [n]: place ``r`` holds, for a query at ``t``, position ``t - (t - r) mod
+    ring`` (whatever was written there later lies ahead of ``t``); it is read
+    where that is one of the ``window`` positions ending at ``t`` and not
+    before 0 (never written by this sequence: a former tenant's). [..., l, n].
+    A pool that never wraps is a ring of its own extent."""
+    back = (q_pos[..., None] - k_at) % ring
+    return (back < window) & (q_pos[..., None] - back >= 0)
 
 
 def _append_in_place(leaves, updates, pos, rows=None):

@@ -107,7 +107,8 @@ import numpy as np
 
 from deepspeed_tpu.models.common import (INDEX_KEY_LEAVES, RING_LEAVES, SPARSE_READS, LatentCache,
                                          config_from, dense_init as _init, embed_lookup,
-                                         rms_norm)
+                                         ring_mask, rms_norm,
+                                         window_ring_positions)  # noqa: F401  (re-export)
 from deepspeed_tpu.models.llama import ExpertKernel
 
 
@@ -208,13 +209,6 @@ class AttentionKind:
     gate: Optional[str] = None
     window: int = 0         # > 0: this position and the ``window - 1`` before it
     top_k: int = 0          # > 0: the ``top_k`` positions the indexer scores highest
-
-
-def window_ring_positions(window: int, chunk: int, page: int = 128) -> int:
-    """Positions of a window layer's ring for calls of at most ``chunk``
-    tokens: the ``window - 1`` positions the chunk's first query looks back on
-    and the chunk itself, rounded up to whole pages."""
-    return -(-(window - 1 + chunk) // page) * page
 
 
 DEEPSEEK_V3_CONFIGS = {
@@ -486,16 +480,6 @@ def _mix_whole_pool(q_lat, q_rope, pool, lengths, scale, chosen=None):
     scores = jnp.where(live[:, None, :], scores, jnp.finfo(jnp.float32).min)
     probs = jnp.where(live[:, None, :], jax.nn.softmax(scores, axis=-1), 0.0)
     return jnp.einsum("bhp,bwp->bhw", probs, pool, precision="highest")[..., :rank]
-
-
-def ring_mask(q_pos, k_at, ring: int, window: int):
-    """What queries at ``q_pos`` [l] may read of a ring's places ``k_at`` [n]:
-    place ``r`` holds, for a query at ``t``, position ``t - (t - r) mod
-    ring`` (whatever was written there later lies ahead of ``t``); it is read
-    where that is one of the ``window`` positions ending at ``t`` and not
-    before 0 (never written by this sequence: a former tenant's). [l, n]."""
-    back = (q_pos[:, None] - k_at[None, :]) % ring
-    return (back < window) & (q_pos[:, None] - back >= 0)
 
 
 def window_step(q_nope, q_rope, ring, w_kvb, pos, live, window: int):

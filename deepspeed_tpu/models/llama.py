@@ -1,5 +1,5 @@
-"""LLaMA family — RMSNorm + RoPE + SwiGLU + GQA decoder; Mixtral, Qwen2
-and OLMoE are configurations of it
+"""LLaMA family — RMSNorm + RoPE + SwiGLU + GQA decoder; Mixtral, Qwen2,
+OLMoE, SmallThinker and Laguna are configurations of it
 (judged config ladder includes LLaMA-7B ZeRO-3 + ZeRO++, BASELINE.md; the
 reference supports LLaMA through kernel injection,
 ``module_inject/containers/llama.py``).
@@ -14,6 +14,15 @@ TPU-first notes, same conventions as ``models/gpt2.py``:
   cache every family shares (``models/common.py`` ``DecodeCache``), so the
   server's per-slot int8 cache serves this family as it serves GPT-2; RoPE
   rotates by each slot's own write position.
+* layers need not be alike (``LlamaConfig``'s layouts; Laguna-XS.2 uses them
+  all): a window or full attention a layer, its own number of query heads
+  over the same key heads, its own RoPE (plain, or YaRN over part of a head:
+  :class:`RopeKind`), a sigmoid gate a head, leading dense layers before
+  sparse ones, sigmoid-routed experts with a shared one. A window layer's
+  decode cache may be a RING of the window and a chunk
+  (``window_ring``; ``DecodeCache(ring=True)``), and a decode attention may
+  walk its stored pool a block at a time, grouped-query, the int8 codes as
+  they lie (:func:`cached_attention`): a window layer's always does.
 """
 
 import dataclasses
@@ -22,13 +31,37 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from deepspeed_tpu.models.common import (DecodeCache, config_from, dense_init as _init,
-                                         normalize_padding_mask, rms_norm)
+from deepspeed_tpu.models.common import (KV_READS, DecodeCache, config_from, dense_init as _init,
+                                         normalize_padding_mask, ring_mask, rms_norm,
+                                         window_ring_positions)  # noqa: F401  (re-export)
 from deepspeed_tpu.ops.transformer.attention import dot_product_attention
 
 
 EXPERT_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeKind:
+    """How one kind of layer rotates its queries and keys: RoPE at ``theta``
+    over the first ``rotary_share`` of a head's dimensions (the rest pass
+    unrotated), plain or, with ``yarn_factor``, YaRN (Peng et al. 2023): each
+    frequency a blend of the plain one and that one over ``yarn_factor``, by
+    how many turns it makes over ``original_positions`` (``beta_fast`` turns
+    and more: plain; ``beta_slow`` and fewer: divided), and the cosine and
+    sine times ``attention_factor`` (None: ``0.1 ln(yarn_factor) + 1``)."""
+    theta: float = 10000.0
+    rotary_share: float = 1.0
+    yarn_factor: Optional[float] = None
+    original_positions: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    @property
+    def plain(self) -> bool:
+        return self.yarn_factor is None and self.rotary_share == 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,18 +101,46 @@ class LlamaConfig:
     # reserve less than the context for each slot)
     decode_cache_len: Optional[int] = None
     # Mistral-style sliding-window attention: each token attends the last
-    # ``sliding_window`` positions. Training/prefill only — the flash
-    # kernel skips out-of-window blocks (O(L*window)). Decode keeps no ring
-    # and applies no window: it raises by name where the cache is longer
-    # than a window layer's window, and otherwise the whole cache lies
-    # inside it.
+    # ``sliding_window`` positions. In training and prefill the flash kernel
+    # skips out-of-window blocks (O(L*window)). In decode a window layer walks
+    # its stored pool under the window's mask (:func:`cached_attention`): a
+    # pool of every position or, with ``window_ring``, a ring.
     sliding_window: Optional[int] = None
+    # positions of a window layer's decode cache, a RING written at ``position
+    # mod window_ring`` (``models/common.py`` ``RING_KV_LEAVES``): at least the
+    # window less one and the longest chunk a call writes
+    # (:func:`window_ring_positions`). None: as many as a full layer's pool,
+    # which never wraps
+    window_ring: Optional[int] = None
+    # key positions one step of a decode attention's walk over its stored pool
+    # takes (:func:`cached_attention`: grouped-query, the int8 codes read as
+    # they are stored, bounded by the live lengths). None: a full layer's decode
+    # attends its whole pool dequantised (``dot_product_attention``); a window
+    # layer's decode always walks, in blocks of 512
+    decode_key_block: Optional[int] = None
     # per layer, as SmallThinker publishes them: 1 = this layer attends its
     # window (``sliding_window``), 0 = full causal attention; 1 = RoPE on
     # this layer's queries and keys, 0 = no positional encoding (NoPE).
     # None: every layer alike (the window wherever one is set, RoPE always)
     sliding_window_layout: Optional[Tuple[int, ...]] = None
     rope_layout: Optional[Tuple[int, ...]] = None
+    # query heads by layer (Laguna: 48 on full layers, 64 on window layers,
+    # over the same 8 key heads): ``q_proj``, ``o_proj`` and the gate then
+    # differ in shape from layer to layer. None: ``num_attention_heads`` all
+    num_attention_heads_layout: Optional[Tuple[int, ...]] = None
+    # RoPE by the kind of layer: a full layer's and a window layer's
+    # (:class:`RopeKind`, or its fields as a dict). None: plain at ``rope_theta``
+    rope_full: Optional[RopeKind] = None
+    rope_window: Optional[RopeKind] = None
+    # None, or "headwise": one sigmoid gate a head from the layer's normed
+    # input, ``o_h <- sigmoid(x W_g)_h o_h`` before ``o_proj``
+    attention_gate: Optional[str] = None
+    # a serving chunk's logits are made for each sequence's LAST REAL token
+    # alone, [B, 1, V] (the prefill program keeps no other: over 100,352 rows a
+    # whole chunk's logits are a fifth of a prefill tick and 3.3 GB). Needs
+    # ``counts_real_tokens``; a verify step needs every position's, so the
+    # scheduler refuses speculation over such a model by name
+    head_last_fed_only: bool = False
     # >0: when called with ``labels=``, compute the loss via the chunked
     # fused LM head (models/common.py fused_lm_head_loss) — never
     # materializes [B, L, V] logits (32k-152k vocabs make that the
@@ -89,6 +150,17 @@ class LlamaConfig:
     # moe_layer_freq-th layer replaces the SwiGLU MLP with experts)
     moe_num_experts: int = 0  # 0 = dense
     moe_layer_freq: int = 1   # Mixtral: every layer
+    # leading layers that keep the dense SwiGLU MLP of ``intermediate_size``
+    # whatever ``moe_layer_freq`` says (Laguna: one)
+    moe_first_dense: int = 0
+    # width of one expert (None: ``intermediate_size``) and of the one shared
+    # SwiGLU expert every token also passes through (0: none)
+    moe_intermediate_size: Optional[int] = None
+    moe_shared_intermediate_size: int = 0
+    # the router's score ("softmax" over the experts | "sigmoid", each alone)
+    # and a scale on the chosen experts' weights (``TopKGate``)
+    moe_score: str = "softmax"
+    moe_routed_scale: float = 1.0
     moe_k: int = 2            # Mixtral: top-2; any k <= experts (OLMoE: 8 of 64)
     # renormalise the k chosen experts' weights (Mixtral) or keep their
     # softmax values (OLMoE: ``norm_topk_prob`` false)
@@ -121,7 +193,14 @@ class LlamaConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.hidden_size // self.num_attention_heads)
-        for name in ("moe_experts_held", "sliding_window_layout", "rope_layout"):
+        for name in ("rope_full", "rope_window"):
+            if isinstance(getattr(self, name), dict):
+                object.__setattr__(self, name, RopeKind(**getattr(self, name)))
+        if self.attention_gate not in (None, "headwise"):
+            raise NotImplementedError(f"attention gate {self.attention_gate!r}: only "
+                                      f"headwise is built")
+        for name in ("moe_experts_held", "sliding_window_layout", "rope_layout",
+                     "num_attention_heads_layout"):
             given = getattr(self, name)
             if given is None:
                 continue
@@ -142,6 +221,27 @@ class LlamaConfig:
 
     def rope_on(self, layer: int) -> bool:
         return self.rope_layout is None or bool(self.rope_layout[layer])
+
+    def rope_of(self, layer: int) -> Optional[RopeKind]:
+        """How layer ``layer`` rotates, None where it does not."""
+        if not self.rope_on(layer):
+            return None
+        kind = self.rope_full if self.window_of(layer) is None else self.rope_window
+        return kind or RopeKind(theta=self.rope_theta)
+
+    def heads_of(self, layer: int) -> int:
+        """Query heads of layer ``layer``."""
+        if self.num_attention_heads_layout is None:
+            return self.num_attention_heads
+        return self.num_attention_heads_layout[layer]
+
+    @property
+    def counts_real_tokens(self) -> bool:
+        """Whether a serving tick is told which of its tokens are real
+        (``chunk_length``, ``models/common.py`` ``LENGTH_LEAVES``): padding and
+        parked slots then route to no expert, write no ring and bound no walk."""
+        return self.moe_experts_held is not None or self.decode_key_block is not None \
+            or self.window_ring is not None
 
 
 LLAMA_CONFIGS = {
@@ -204,6 +304,26 @@ LLAMA_CONFIGS = {
         rope_layout=tuple(int(i % 4 != 0) for i in range(8)),
         moe_num_experts=8, moe_k=3, moe_norm_topk_prob=True, moe_drop_tokens=False,
         moe_activation="relu", moe_router_before_attention=True, moe_aux_loss_coef=0.0),
+    # Laguna-XS.2 (poolside/Laguna-XS.2) at a size the CPU runs: a leading
+    # dense layer and one period [full, window x 3], then full; 6 query heads
+    # on full layers and 8 on window layers over 2 key heads; a gate a head;
+    # YaRN on half a head on full layers, plain RoPE on window layers; 8
+    # sigmoid-routed experts, top-2 renormalised x 2.5, and a shared one, all
+    # held; a window of 8 in a ring of 16
+    "laguna-test": dict(
+        vocab_size=256, hidden_size=64, intermediate_size=128, num_hidden_layers=5,
+        num_attention_heads=6, num_key_value_heads=2, head_dim=16,
+        num_attention_heads_layout=(6, 8, 8, 8, 6), max_position_embeddings=128,
+        rms_norm_eps=1e-6, sliding_window=8, sliding_window_layout=(0, 1, 1, 1, 0),
+        window_ring=16, decode_key_block=16, attention_gate="headwise",
+        rope_full=RopeKind(theta=500000.0, rotary_share=0.5, yarn_factor=64.0,
+                           original_positions=16, beta_fast=64.0, beta_slow=1.0,
+                           attention_factor=1.4158883083359672),
+        rope_window=RopeKind(theta=10000.0),
+        moe_num_experts=8, moe_k=2, moe_first_dense=1, moe_intermediate_size=32,
+        moe_shared_intermediate_size=32, moe_score="sigmoid", moe_routed_scale=2.5,
+        moe_norm_topk_prob=True, moe_drop_tokens=False, moe_experts_held=(0, 8),
+        moe_aux_loss_coef=0.0),
     # Qwen2 family: llama architecture + biased q/k/v projections
     "qwen2-7b": dict(vocab_size=152064, hidden_size=3584, intermediate_size=18944,
                      num_hidden_layers=28, num_attention_heads=28, num_key_value_heads=4,
@@ -243,22 +363,158 @@ def rotary_embedding(x, positions, theta: float = 10000.0):
     return out.astype(x.dtype)
 
 
+def rope_frequencies(kind: RopeKind, head_dim: int):
+    """``(inverse frequencies [rotated dims / 2] float32, the factor on cosine
+    and sine)`` of ``kind`` for heads of ``head_dim``, made on the host in
+    float64. YaRN: dimension pair ``i`` turns ``original_positions theta^(-2i/D)
+    / 2 pi`` times over the original context; pairs from the one that makes
+    ``beta_fast`` turns down keep the plain frequency, pairs up to the one that
+    makes ``beta_slow`` take it over ``yarn_factor``, and those between a
+    linear blend of the two (the pairs' numbers rounded outwards)."""
+    dim = int(head_dim * kind.rotary_share)
+    plain = kind.theta ** -(np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if kind.yarn_factor is None:
+        return plain.astype(np.float32), 1.0
+
+    def pair_that_turns(turns):
+        return dim * np.log(kind.original_positions / (turns * 2 * np.pi)) / (2 * np.log(kind.theta))
+
+    low = max(np.floor(pair_that_turns(kind.beta_fast)), 0)
+    high = min(np.ceil(pair_that_turns(kind.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / max(high - low, 1e-3), 0, 1)
+    blended = plain / kind.yarn_factor * ramp + plain * (1 - ramp)
+    factor = kind.attention_factor if kind.attention_factor is not None \
+        else 0.1 * np.log(kind.yarn_factor) + 1.0
+    return blended.astype(np.float32), float(factor)
+
+
+def rotate(x, positions, kind: Optional[RopeKind]):
+    """``x`` [B, L, H, D] rotated at ``positions`` [B, L] as ``kind`` says
+    (None: not at all); half-split pairs inside the rotated dimensions."""
+    if kind is None:
+        return x
+    if kind.plain:
+        return rotary_embedding(x, positions, kind.theta)
+    with jax.named_scope("rope_yarn" if kind.yarn_factor is not None else "rope_partial"):
+        inv_freq, factor = rope_frequencies(kind, x.shape[-1])
+        dim = 2 * inv_freq.shape[0]
+        angles = positions[..., None].astype(jnp.float32) * inv_freq
+        cos = (jnp.cos(angles) * factor)[:, :, None, :]
+        sin = (jnp.sin(angles) * factor)[:, :, None, :]
+        turned, passed = x[..., :dim].astype(jnp.float32), x[..., dim:]
+        x1, x2 = jnp.split(turned, 2, axis=-1)
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return jnp.concatenate([out.astype(x.dtype), passed], axis=-1)
+
+
+def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: int,
+                     block: int, rows=None):
+    """Grouped-query softmax attention of ``q`` [b, l, H, d] (already written)
+    over the cache as it is STORED: ``keys`` / ``values`` [slots, kv heads, d,
+    P] and, of int8 pools, ``key_scale`` / ``value_scale`` [slots, kv heads, P]
+    (the codes go into the matmuls as they are and a position's scale
+    multiplies its score and its probability: nothing is dequantised whole,
+    and no key head is repeated). Query head ``h`` reads key head ``h // (H /
+    kv heads)``. ``q_pos`` [b, l] are the queries' positions and ``fed`` [b]
+    how many of each sequence's are real (0: a parked slot, which reads
+    nothing and gives zeros); sequence ``s`` is row ``rows[s]`` of the pools
+    (None: ``s``). Place ``r`` of the ``P`` is read under
+    :func:`ring_mask` (``window`` positions ending at the query, of a RING of
+    ``P``): a pool that never wraps is a ring of its own extent, and plain
+    causal attention a window of ``P``.
+
+    XLA, a block of ``block`` key positions a step with a running softmax, the
+    steps bounded by what the sequences hold: ONE query a sequence (a decode
+    tick) walks every sequence's pool together as far as the longest goes; a
+    chunk walks a sequence at a time as far as that sequence goes. Returns
+    ``(out [b, l, H, d], positions read)``."""
+    b, l, heads, d = q.shape
+    kv, places = keys.shape[1], keys.shape[-1]
+    rep, dtype = heads // kv, q.dtype
+    block = block if places % block == 0 else places
+    scale = d ** -0.5
+    lowest = jnp.finfo(jnp.float32).min
+    grouped = jnp.transpose(q.reshape(b, l, kv, rep, d), (0, 2, 1, 3, 4))   # [b, kv, l, rep, d]
+    ends = jnp.where(fed > 0, jnp.minimum(q_pos[:, 0] + fed, places), 0)    # [b]
+    steps = -(-ends // block)
+
+    def part(leaf, rows_, j):
+        """Places ``[j * block, (j + 1) * block)`` of the rows ``rows_`` (None:
+        of every row, read where it lies)."""
+        if rows_ is None:
+            return jax.lax.dynamic_slice_in_dim(leaf, j * block, block, axis=leaf.ndim - 1)
+        return jnp.concatenate([jax.lax.dynamic_slice(
+            leaf, (r,) + (0,) * (leaf.ndim - 2) + (j * block,), (1,) + leaf.shape[1:-1] + (block,))
+            for r in rows_])
+
+    def step(j, state, rows_, qs, at, real):
+        """One block of the rows ``rows_`` for the queries ``qs`` [n, kv, l,
+        rep, d] at ``at`` [n, l], of sequences that are ``real`` [n]."""
+        m, den, acc = state
+        seen = ring_mask(at, j * block + jnp.arange(block), places, window) & real[:, None, None]
+        seen = seen[:, None, :, None, :]                                    # [n, 1, l, 1, block]
+        s = jnp.einsum("nklrd,nkdp->nklrp", qs, part(keys, rows_, j).astype(dtype),
+                       preferred_element_type=jnp.float32) * scale
+        if key_scale is not None:
+            s = s * part(key_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
+        s = jnp.where(seen, s, lowest)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        den = den * alpha + p.sum(axis=-1)
+        if value_scale is not None:
+            p = p * part(value_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "nklrp,nkdp->nklrd", p.astype(dtype), part(values, rows_, j).astype(dtype),
+            preferred_element_type=jnp.float32)
+        return m_new, den, acc
+
+    def walked(n, count, rows_, qs, at, real):
+        state = (jnp.full((n, kv, l, rep), lowest, jnp.float32),
+                 jnp.zeros((n, kv, l, rep), jnp.float32),
+                 jnp.zeros((n, kv, l, rep, d), jnp.float32))
+        _, den, acc = jax.lax.fori_loop(
+            0, count, lambda j, state: step(j, state, rows_, qs, at, real), state)
+        return (acc / jnp.maximum(den, 1e-37)[..., None]).astype(dtype)
+
+    if l == 1:
+        # every sequence's pool together, as far as the longest goes
+        count = steps.max()
+        out = walked(b, count, None if rows is None else [rows[s] for s in range(b)],
+                     grouped, q_pos, fed > 0)
+        read = count * block * b
+    else:
+        def one(s, out):
+            pick = lambda t: jax.lax.dynamic_slice_in_dim(t, s, 1, axis=0)  # noqa: E731
+            got = walked(1, steps[s], [s if rows is None else rows[s]], pick(grouped),
+                         pick(q_pos), pick(fed) > 0)
+            return jax.lax.dynamic_update_slice_in_dim(out, got, s, axis=0)
+
+        out = jax.lax.fori_loop(0, b, one, jnp.zeros(grouped.shape, dtype))
+        read = (steps * block).sum()
+    out = jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(b, l, heads, d)
+    return out, read.astype(jnp.int32)
+
+
 class LlamaAttention(nn.Module):
     """GQA attention with RoPE and an optional decode cache. ``layer``
-    picks this layer's window and whether it rotates (``LlamaConfig``'s two
-    layouts); every layer is alike where the configuration has none."""
+    picks this layer's window, its query heads and how it rotates
+    (``LlamaConfig``'s layouts); every layer is alike where the configuration
+    has none."""
 
     config: LlamaConfig
     layer: int = 0
 
     @nn.compact
-    def __call__(self, x, positions=None, *, decode: bool = False, attention_mask=None):
+    def __call__(self, x, positions=None, *, decode: bool = False, attention_mask=None,
+                 fed=None):
         cfg = self.config
         b, l, _ = x.shape
-        n_rep = cfg.num_attention_heads // cfg.num_key_value_heads
+        heads = cfg.heads_of(self.layer)
+        n_rep = heads // cfg.num_key_value_heads
         window = cfg.window_of(self.layer)
-        rope = (lambda t, at: rotary_embedding(t, at, cfg.rope_theta)) \
-            if cfg.rope_on(self.layer) else (lambda t, at: t)
+        kind = cfg.rope_of(self.layer)
+        rope = lambda t, at: rotate(t, at, kind)  # noqa: E731
 
         def proj(heads, name):
             # q/k/v projections only (o_proj is built separately, always
@@ -271,7 +527,7 @@ class LlamaAttention(nn.Module):
                                                                           ("heads", "kv")),
                                    name=name)
 
-        q = proj(cfg.num_attention_heads, "q_proj")(x)
+        q = proj(heads, "q_proj")(x)
         k = proj(cfg.num_key_value_heads, "k_proj")(x)
         v = proj(cfg.num_key_value_heads, "v_proj")(x)
         if cfg.qk_norm:
@@ -286,56 +542,107 @@ class LlamaAttention(nn.Module):
         # attention_mask: [B, L] 0/1 padding mask (or a pre-broadcast boolean
         # mask). In decode mode L must span the cache (max_position_embeddings).
         mask = normalize_padding_mask(attention_mask)
-        if decode:
-            # static-shape KV cache, lockstep or per serving slot, fp or int8
-            # (models/common.py DecodeCache; the cache handed in decides)
-            cache_len = cfg.decode_cache_len or cfg.max_position_embeddings
-            if window is not None and cache_len > window:
-                raise NotImplementedError(
-                    f"decode over a cache of {cache_len} positions with sliding_window "
-                    f"{window} (layer {self.layer}): the decode path keeps no ring and "
-                    f"applies no window, so it would attend positions the layer must not "
-                    f"see; set decode_cache_len <= sliding_window or serve without decode")
-            cache = DecodeCache(self, b, cache_len,
-                                cfg.num_key_value_heads, cfg.head_dim, k.dtype)
-            given = positions is not None
-            if not given:
-                positions = cache.positions(l)
-            q, k = rope(q, positions), rope(k, positions)
-            k, v, decode_lengths = cache.append(k, v, q.dtype)
-            if given:
-                # per-sequence live lengths (positions may differ per batch
-                # row); the backend derives causal validity over cache slots
-                # from them — flash's decode kernel additionally skips dead
-                # KV blocks' DMA. Any caller padding mask rides alongside
-                # (flash falls back to XLA when both are present).
-                decode_lengths = positions[:, -1] + 1
-            causal = False
+        # a decode that walks the stored pool (:func:`cached_attention`): a
+        # window layer's always, a full layer's where the configuration says
+        walks = decode and (window is not None or cfg.decode_key_block is not None)
+        if walks:
+            out = self._walk(q, k, v, positions, rope, window, fed, mask)
         else:
-            if positions is None:
-                positions = jnp.broadcast_to(jnp.arange(l)[None, :], (b, l))
-            q, k = rope(q, positions), rope(k, positions)
+            if decode:
+                # static-shape KV cache, lockstep or per serving slot, fp or int8
+                # (models/common.py DecodeCache; the cache handed in decides)
+                cache = DecodeCache(self, b, cfg.decode_cache_len or cfg.max_position_embeddings,
+                                    cfg.num_key_value_heads, cfg.head_dim, k.dtype)
+                given = positions is not None
+                if not given:
+                    positions = cache.positions(l)
+                q, k = rope(q, positions), rope(k, positions)
+                k, v, decode_lengths = cache.append(k, v, q.dtype)
+                if given:
+                    # per-sequence live lengths (positions may differ per batch
+                    # row); the backend derives causal validity over cache slots
+                    # from them — flash's decode kernel additionally skips dead
+                    # KV blocks' DMA. Any caller padding mask rides alongside
+                    # (flash falls back to XLA when both are present).
+                    decode_lengths = positions[:, -1] + 1
+                causal = False
+            else:
+                if positions is None:
+                    positions = jnp.broadcast_to(jnp.arange(l)[None, :], (b, l))
+                q, k = rope(q, positions), rope(k, positions)
 
-        # GQA: the flash kernels read key head ``head // n_rep`` themselves
-        # (training and prefill); every other path gets the heads repeated
-        if n_rep > 1 and (decode or cfg.attention_backend != "flash"):
-            k = jnp.repeat(k, n_rep, axis=2)
-            v = jnp.repeat(v, n_rep, axis=2)
+            # GQA: the flash kernels read key head ``head // n_rep`` themselves
+            # (training and prefill); every other path gets the heads repeated
+            if n_rep > 1 and (decode or cfg.attention_backend != "flash"):
+                k = jnp.repeat(k, n_rep, axis=2)
+                v = jnp.repeat(v, n_rep, axis=2)
 
-        if window is not None and cfg.attention_backend not in ("flash", "xla"):
-            # silently ignoring the window would change the model's math
-            raise ValueError(f"sliding_window is supported by the flash/xla attention "
-                             f"backends, not {cfg.attention_backend!r}")
-        from deepspeed_tpu.models.common import attention_geometry_kwargs
-        with jax.named_scope("attn_window" if window is not None else "attn_full"):
-            out = dot_product_attention(q, k, v, backend=cfg.attention_backend, causal=causal,
-                                        mask=mask, decode_lengths=decode_lengths,
-                                        window=window if not decode else None,
-                                        **attention_geometry_kwargs(cfg))
+            if window is not None and cfg.attention_backend not in ("flash", "xla"):
+                # silently ignoring the window would change the model's math
+                raise ValueError(f"sliding_window is supported by the flash/xla attention "
+                                 f"backends, not {cfg.attention_backend!r}")
+            from deepspeed_tpu.models.common import attention_geometry_kwargs
+            with jax.named_scope("attn_window" if window is not None else "attn_full"):
+                out = dot_product_attention(q, k, v, backend=cfg.attention_backend, causal=causal,
+                                            mask=mask, decode_lengths=decode_lengths,
+                                            window=window, **attention_geometry_kwargs(cfg))
+        if cfg.attention_gate:
+            with jax.named_scope("attn_gate"):
+                gate = jax.nn.sigmoid(nn.Dense(
+                    features=heads, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                    kernel_init=nn.with_logical_partitioning(_init(), ("embed", "heads")),
+                    name="gate_proj")(x))
+                out = out * gate[..., None].astype(out.dtype)
         return nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                                kernel_init=nn.with_logical_partitioning(_init(), ("heads", "kv", "embed")),
                                name="o_proj")(out)
+
+    def _walk(self, q, k, v, positions, rope, window, fed, mask):
+        """Decode by :func:`cached_attention`: the new keys and values written
+        (a window layer's into its ring where the configuration sizes one),
+        then the stored pool walked under the window's mask. Leaves ``kv_reads``
+        (``models/common.py`` ``KV_READS``) in a serving cache."""
+        cfg = self.config
+        b, l = q.shape[:2]
+        if mask is not None:
+            raise NotImplementedError("a padding mask over a decode that walks its stored pool "
+                                      "(decode_key_block, a window layer): not built")
+        extent = cfg.decode_cache_len or cfg.max_position_embeddings
+        ring = window is not None and cfg.window_ring is not None
+        places = cfg.window_ring if ring else extent
+        if ring and l > places - (window - 1):
+            raise ValueError(
+                f"a call of {l} tokens over a ring of {places} positions overwrites what its "
+                f"first query's window of {window} still reads: window_ring must be "
+                f"window_ring_positions(window, the longest chunk)")
+        cache = DecodeCache(self, b, places, cfg.num_key_value_heads, cfg.head_dim, k.dtype,
+                            ring=ring)
+        if positions is None:
+            positions = cache.positions(l)
+        if fed is None:
+            fed = jnp.full((b,), l, jnp.int32)
+        q, k = rope(q, positions), rope(k, positions)
+        cache.write(k, v, live=fed > 0)
+        with jax.named_scope("attn_window" if window is not None else "attn_full"):
+            out, read = cached_attention(
+                q, *cache.stored(), positions, fed, window=window or places,
+                block=cfg.decode_key_block or 512, rows=cache.slots)
+        # for the host, beside a serving tick's tokens (a lockstep cache carries
+        # the leaf too: a cache's leaves are the same whoever made it)
+        ends = jnp.where(fed > 0, jnp.minimum(positions[:, 0] + fed, extent), 0)
+        if window is None:
+            counts = {"kv_full_positions_read": read, "kv_full_positions_live": ends.sum()}
+        else:
+            # of a sequence's positions, the ones inside some real query's window
+            counts = {"kv_ring_positions_read": read,
+                      "kv_ring_positions_live": jnp.minimum(ends, fed + window - 1).sum(),
+                      "kv_ring_bytes_written": fed.sum() * 2 * cfg.num_key_value_heads * (
+                          cfg.head_dim * cache.key.value.dtype.itemsize
+                          + (k.dtype.itemsize if cache.quantized else 0))}
+        self.variable("cache", "kv_reads", jnp.zeros, (len(KV_READS),), jnp.int32).value = (
+            jnp.stack([jnp.asarray(counts.get(name, 0), jnp.int32) for name in KV_READS]))
+        return out
 
 
 class LlamaMLP(nn.Module):
@@ -350,10 +657,12 @@ class LlamaMLP(nn.Module):
 
     config: LlamaConfig
     num_experts: int = 0
+    width: Optional[int] = None     # None: ``intermediate_size``
 
     @nn.compact
     def __call__(self, x, deterministic: bool = True, group_sizes=None, impl: str = "xla"):
         cfg = self.config
+        width = self.width or cfg.intermediate_size
         if not self.num_experts:
             def dense(feat, names, name):
                 return nn.Dense(features=feat, use_bias=False, dtype=cfg.dtype,
@@ -361,17 +670,17 @@ class LlamaMLP(nn.Module):
                                 kernel_init=nn.with_logical_partitioning(_init(), names),
                                 name=name)
 
-            gate = dense(cfg.intermediate_size, ("embed", "mlp"), "gate_proj")(x)
-            up = dense(cfg.intermediate_size, ("embed", "mlp"), "up_proj")(x)
+            gate = dense(width, ("embed", "mlp"), "gate_proj")(x)
+            up = dense(width, ("embed", "mlp"), "up_proj")(x)
             return dense(cfg.hidden_size, ("mlp", "embed"), "down_proj")(jax.nn.silu(gate) * up)
 
         def kernel(shape, names, name):
             return ExpertKernel((self.num_experts,) + shape, ("expert",) + names,
                                 cfg.param_dtype, name=name)().astype(cfg.dtype)
 
-        w_gate = kernel((cfg.hidden_size, cfg.intermediate_size), ("embed", "mlp"), "gate_proj")
-        w_up = kernel((cfg.hidden_size, cfg.intermediate_size), ("embed", "mlp"), "up_proj")
-        w_down = kernel((cfg.intermediate_size, cfg.hidden_size), ("mlp", "embed"), "down_proj")
+        w_gate = kernel((cfg.hidden_size, width), ("embed", "mlp"), "gate_proj")
+        w_up = kernel((cfg.hidden_size, width), ("embed", "mlp"), "up_proj")
+        w_down = kernel((width, cfg.hidden_size), ("mlp", "embed"), "down_proj")
         x = x.astype(cfg.dtype)
         if group_sizes is None:
             dot = lambda t, w: jnp.einsum("...eci,eio->...eco", t, w)  # noqa: E731
@@ -404,13 +713,15 @@ class LlamaDecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions=None, decode: bool = False, attention_mask=None,
-                 deterministic: bool = True):
+                 deterministic: bool = True, fed=None):
+        """``fed`` [batch] int32 (a serving tick of a model that
+        ``counts_real_tokens``): how many of each sequence's tokens are real."""
         cfg = self.config
         # the pre-attention router reads the stream as it enters the layer
         router_input = x if self.use_moe and cfg.moe_router_before_attention else None
         x = x + LlamaAttention(cfg, self.layer, name="self_attn")(
             RMSNorm(cfg, name="input_layernorm")(x), positions, decode=decode,
-            attention_mask=attention_mask)
+            attention_mask=attention_mask, fed=fed)
         h = RMSNorm(cfg, name="post_attention_layernorm")(x)
         if self.use_moe:
             from deepspeed_tpu.moe import MoE
@@ -419,8 +730,13 @@ class LlamaDecoderLayer(nn.Module):
             bank = 0 if cfg.moe_drop_tokens else cfg.moe_num_experts
             if cfg.moe_experts_held is not None:
                 bank = cfg.moe_experts_held[1]
+            used = None if fed is None else (
+                jnp.arange(x.shape[1])[None, :] < fed[:, None]).reshape(-1)
+            shared = (LlamaMLP(cfg, width=cfg.moe_shared_intermediate_size)
+                      if cfg.moe_shared_intermediate_size else None)
             moe_out, l_aux, _ = MoE(hidden_size=cfg.hidden_size,
-                                    expert=LlamaMLP(cfg, num_experts=bank),
+                                    expert=LlamaMLP(cfg, num_experts=bank,
+                                                    width=cfg.moe_intermediate_size),
                                     num_experts=cfg.moe_num_experts,
                                     k=cfg.moe_k,
                                     capacity_factor=cfg.moe_capacity_factor,
@@ -431,7 +747,9 @@ class LlamaDecoderLayer(nn.Module):
                                     route=cfg.moe_route,
                                     route_kernel=cfg.moe_route_kernel,
                                     experts_held=cfg.moe_experts_held,
-                                    name="moe")(h, deterministic=deterministic,
+                                    score=cfg.moe_score, routed_scale=cfg.moe_routed_scale,
+                                    shared_expert=shared,
+                                    name="moe")(h, used_token=used, deterministic=deterministic,
                                                 router_input=router_input)
             return x + moe_out, l_aux
         return x + LlamaMLP(cfg, name="mlp")(h), jnp.zeros([], jnp.float32)
@@ -459,7 +777,7 @@ class LlamaForCausalLM(nn.Module):
         """Indices of the layers whose FFN is the expert layer."""
         cfg = self.config
         every = max(cfg.moe_layer_freq, 1)
-        return [i for i in range(cfg.num_hidden_layers)
+        return [i for i in range(cfg.moe_first_dense, cfg.num_hidden_layers)
                 if cfg.moe_num_experts > 0 and i % every == every - 1]
 
     def step_count_names(self):
@@ -485,7 +803,8 @@ class LlamaForCausalLM(nn.Module):
         ``experts * capacity``. The server counts its ticks with this."""
         cfg = self.config
         layers = len(self.moe_layers())
-        if not layers:
+        if not layers or cfg.moe_experts_held is not None:
+            # a layer that holds a share counts its own rows, on the device
             return 0, 0
         if not cfg.moe_drop_tokens:
             per_layer = positions * cfg.moe_k
@@ -513,14 +832,25 @@ class LlamaForCausalLM(nn.Module):
         x = constrain_activation(x, "batch", "length", "embed")
         aux_total = jnp.zeros([], jnp.float32)
         moe_layers = self.moe_layers()
+        fed = None
+        if decode and cfg.counts_real_tokens:
+            # how many of each sequence's tokens are real: a scalar in lockstep
+            # ``generate`` (all real), a [slots] vector in the serving cache,
+            # which the serving programs fill from their operands. Padding and
+            # parked slots route to no expert, write no ring and bound no walk
+            length = self.variable("cache", "chunk_length", lambda: jnp.zeros([], jnp.int32))
+            if length.value.ndim:
+                fed = length.value
         for i in range(cfg.num_hidden_layers):
             use_moe = i in moe_layers
             block_cls = maybe_remat(LlamaDecoderLayer, cfg, i, static_argnums=(3, 5),
                                     enabled=cfg.remat and not decode)
             x, l_aux = block_cls(cfg, use_moe, i, name=f"layers_{i}")(
-                x, positions, decode, attention_mask, deterministic)
+                x, positions, decode, attention_mask, deterministic, fed)
             x = constrain_activation(x, "batch", "length", "embed")
             aux_total = aux_total + l_aux
+        if fed is not None and cfg.head_last_fed_only and x.shape[1] > 1:
+            x = jnp.take_along_axis(x, (jnp.maximum(fed, 1) - 1)[:, None, None], axis=1)
         x = RMSNorm(cfg, name="norm")(x)
         if labels is not None and cfg.fused_head_loss_chunk > 0:
             # chunked fused head on the [E, V] Dense kernel — same param

@@ -51,6 +51,12 @@ class MoE(nn.Module):
     # ``(first, count)``: the experts this device holds of ``num_experts``
     # (``MOELayer.experts_held``); ``expert`` is then a bank of ``count``
     experts_held: Optional[Tuple[int, int]] = None
+    # the gate's scoring and a scale on the chosen experts' weights
+    # (``TopKGate``), and a module every token also passes through, added to
+    # the routed result (``MOELayer.shared_expert``)
+    score: str = "softmax"
+    routed_scale: float = 1.0
+    shared_expert: Optional[nn.Module] = None
 
     def setup(self):
         if self.noisy_gate_policy not in (None, 'None', 'Jitter', 'RSample'):
@@ -80,6 +86,9 @@ class MoE(nn.Module):
             route_kernel=self.route_kernel,
             norm_topk_prob=self.norm_topk_prob,
             experts_held=self.experts_held,
+            score=self.score,
+            routed_scale=self.routed_scale,
+            shared_expert=self.shared_expert,
         )
         if self.use_residual:
             # PR-MoE (reference layer.py:70-77): dense MLP alongside the MoE

@@ -160,10 +160,14 @@ def test_the_layouts_decide_window_and_rotation_a_layer_at_a_time():
         get_llama_config("smallthinker-test", moe_activation="gelu")
 
 
-def test_decode_over_a_cache_longer_than_a_window_raises_by_name():
+def test_decode_over_a_cache_longer_than_a_window_attends_the_window():
+    """Where this raised by name ("keeps no ring") until PR 46: a window layer's
+    decode walks its pool under the window's mask
+    (``tests/unit/models/test_laguna.py`` holds it against the forward pass)."""
     model, params = build()
-    with pytest.raises(NotImplementedError, match="keeps no ring"):
-        model.apply({"params": params}, ids_of(5, 1, 4), decode=True, mutable=["cache"])
+    out, upd = model.apply({"params": params}, ids_of(5, 1, 4), decode=True, mutable=["cache"])
+    assert out[0].shape == (1, 4, 256)
+    assert upd["cache"]["layers_1"]["self_attn"]["cached_key"].shape[1] == 32     # no ring asked for
     # a cache no longer than the window lies inside it: decode runs
     short = LlamaForCausalLM(get_llama_config("smallthinker-test", decode_cache_len=8))
     out, _ = short.apply({"params": params}, ids_of(5, 1, 4), decode=True, mutable=["cache"])

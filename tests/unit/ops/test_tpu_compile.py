@@ -571,6 +571,68 @@ def test_dots3_note_serving_program_keeps_a_ring_and_scores_a_slot_at_a_time(one
         assert memory.temp_size_in_bytes <= 2_945_695_232
 
 
+@pytest.mark.parametrize("program", ["prefill", "decode_rung"])
+def test_laguna_serving_program_keeps_int8_rings_beside_int8_pools(one_chip, program):
+    """The mixed-lengths cell's programs at the published widths, its 32 slots
+    and two layers, one of each kind (the dense full layer of 48 query heads;
+    a sliding layer of 64 with all 256 experts and the shared one): the full
+    layer's int8 keys and values over 16,384 positions, the sliding layer's
+    over a ring of the window and a chunk, all donated and written in place;
+    the walk reads the stored codes a block at a time (no pass over a whole
+    pool, nothing dequantised whole, no key head repeated) and the head is made
+    for the one position a slot a prefill tick keeps."""
+    import json
+    import os
+    import flax.linen as nn
+    from benchmarks.families import laguna as family
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step,
+                                                          make_apply_fn, make_slot_cache)
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(__file__))))
+    with open(os.path.join(root, "benchmarks", "configs", "laguna-xs2.json")) as f:
+        config = json.load(f)
+    config.update(num_hidden_layers=2, layer_types=config["layer_types"][:2],
+                  mlp_layer_types=config["mlp_layer_types"][:2],
+                  num_attention_heads_per_layer=config["num_attention_heads_per_layer"][:2])
+    dep = config["serve"]
+    slots, chunk, positions = dep["slots"], dep["prefill_chunk"], dep["max_out_tokens"]
+    ring = family.window_ring(config, dep)
+    module = family.model(config, dep)
+    params = jax.eval_shape(
+        lambda key: jax.tree.map(lambda p: p.astype(bf16), nn.meta.unbox(
+            module.init(key, jnp.zeros((1, 8), jnp.int32))["params"])), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots, kv_quant=True))
+    shapes = {path[-1].key: (leaf.shape, leaf.dtype)
+              for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0] if leaf.ndim == 4}
+    assert shapes == {"cached_key": ((slots, 8, 128, positions), jnp.int8),
+                      "cached_value": ((slots, 8, 128, positions), jnp.int8),
+                      "cached_window_key": ((slots, 8, 128, ring), jnp.int8),
+                      "cached_window_value": ((slots, 8, 128, ring), jnp.int8)}
+    apply_fn = make_apply_fn(module)
+    n = slots // 4
+    if program == "prefill":
+        step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (_shape(slots, dtype=jnp.int32), _shape(slots, chunk, dtype=jnp.int32),
+                    _shape(slots, dtype=jnp.int32))
+    else:
+        step = build_decode_step(apply_fn, False, 1.0, 0, 1.0, rung=True)
+        operands = (_shape(n, dtype=jnp.int32), _shape(n, dtype=jnp.int32))
+    compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
+    pool_bytes = slots * 8 * 128 * positions
+    assert not _relayouts(compiled, pool_bytes)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * pool_bytes + 2 * slots * 8 * 128 * ring
+    # gate, up, down: once a size of the held route's row buffer (a prefill tick's three)
+    assert compiled.as_text().count("tpu_custom_call") == (9 if program == "prefill" else 3)
+    if program == "prefill":
+        # no [slots, chunk, 100,352] logits (1.6 GB at 256): the held route's
+        # last rung at hidden 2,048 and the dense layer's 8,192-wide activations
+        assert memory.temp_size_in_bytes < slots * chunk * config["vocab_size"] * 2
+    else:
+        assert memory.temp_size_in_bytes < pool_bytes // 10
+
+
 # ---------------------------------------------------------------------------
 # a rung: the prefill (ISSUE 33) and decode (ISSUE 42) programs over a quarter of the slots
 # ---------------------------------------------------------------------------

@@ -1,6 +1,9 @@
-"""Controls for the dots3-note-prev serving cell's reference check: does the
-comparison that decides ``correct`` refuse a server computed below the
-precision the configuration states, and one that attends the WRONG positions?
+"""Controls for a serving cell's reference check (``--workload``; the
+dots3-note-prev cell's by default, the Laguna cell's with
+``--workload serve-laguna-xs2-mixedlen-sat --control program fp8_weights
+full_window``): does the comparison that decides ``correct`` refuse a server
+computed below the precision the configuration states, and one that attends the
+WRONG positions?
 
 As ``tools/joyai_llm_flash_controls.py``: each control stands **in the
 program's place**, a server built exactly as the cell builds it
@@ -18,6 +21,13 @@ would have reported for that server.
   ticks: the package's ``kth_largest`` is handed each position's number for
   its score). Every other number is the program's: what this control moves is
   which 2,048 of up to 6,128 latents a query reads, and nothing else.
+* ``full_window`` (a ``models/llama.py`` family with window layers, Laguna):
+  the sliding layers attend EVERY earlier position, over pools of the full
+  layers' extent in place of their rings (the family's ``model`` is handed
+  ``sliding_window`` = the slot's capacity and no ``window_ring``; the layers
+  keep their own heads and their own RoPE). Five pools of every position do
+  not fit beside the weights at the cell's 32 slots, so this control's server
+  has 8: the two checked requests' arithmetic does not see the slot count.
 
 Each line also carries what the checked requests' ticks chose
 (``selected_pct``: positions attended over positions live on the full
@@ -41,7 +51,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "tools"))
 
-CONTROLS = ("program", "fp8_weights", "last_positions")
+CONTROLS = ("program", "fp8_weights", "last_positions", "full_window")
 WORKLOAD = "serve-dots3-note-prev-longctx-sat"
 
 
@@ -65,8 +75,21 @@ def last_positions_chosen():
         package.kth_largest = plain
 
 
+def full_window_family(family):
+    """``family`` whose model's window layers attend every earlier position."""
+    import types
+
+    def model(config, deployment):
+        return family.model(config, deployment, sliding_window=deployment["max_out_tokens"],
+                            window_ring=None)
+
+    return types.SimpleNamespace(model=model)
+
+
 def run_control(cell, seed, control):
     """One server, one comparison: the line's fields."""
+    import copy
+
     import jax
     import jax.numpy as jnp
     from benchmarks.lib import harness
@@ -74,6 +97,10 @@ def run_control(cell, seed, control):
     from nemotron_h_controls import fp8_family
 
     family, runner = cell.family, cell.runner
+    if control == "full_window":
+        cell = copy.copy(cell)
+        cell.config = copy.deepcopy(cell.config)
+        cell.config["serve"]["slots"] = min(8, cell.config["serve"]["slots"])
     gc.collect()    # an earlier control's server: 8.2 GB of weights and 3.1 of cache do not fit twice
     t0 = time.time()
     env = harness.Env(seed, 0, 0, harness.Setup(t0), jax.devices()[:1], harness.Tracer(False, ""))
@@ -81,6 +108,7 @@ def run_control(cell, seed, control):
     changed = last_positions_chosen() if control == "last_positions" else contextlib.nullcontext()
     with changed:       # the programs are traced in warm-up, under the change
         engine, sched = runner._server(cell, env, fp8_family(family) if control == "fp8_weights"
+                                       else full_window_family(family) if control == "full_window"
                                        else family)
         sched.warmup()
         reqs = runner._checked_requests(cell, env, sched)
@@ -110,7 +138,7 @@ def main(argv):
     parser = argparse.ArgumentParser()
     parser.add_argument("--workload", default=WORKLOAD)
     parser.add_argument("--seed", type=int, nargs="+", required=True)
-    parser.add_argument("--control", nargs="+", default=list(CONTROLS), choices=CONTROLS)
+    parser.add_argument("--control", nargs="+", default=list(CONTROLS[:3]), choices=CONTROLS)
     parser.add_argument("--root", default=ROOT)
     args = parser.parse_args(argv)
 
