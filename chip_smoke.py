@@ -509,6 +509,33 @@ def kernels_phase(batch=4, seq=1024, heads=16, head_dim=64, width=1024):
     real = jnp.arange(chunk)[None, :, None, None] < fed[:, None, None, None]
     check("mla_prefill_walk", jnp.where(real, got, 0), jnp.where(real, want, 0), ulps=2)
 
+    # a decode tick's read of a stored int8 pool: the kernel that reads each slot
+    # as far as that slot goes against XLA's loop over every slot together; at
+    # the defaults the mixed-lengths cell's full layers (32 slots of 16,384
+    # positions, 48 query heads over 8 key heads of 128, blocks of 1,024). A
+    # parked slot, one key, a block's edge and one past it, the whole pool
+    from unittest import mock
+    from deepspeed_tpu.models.llama import cached_attention
+    from deepspeed_tpu.ops.pallas import backend
+    from deepspeed_tpu.ops.pallas.pool_decode import pool_decode
+    slots, kv, d, extent = 2 * heads, heads // 2, 2 * head_dim, 16 * seq
+    keys, values = (jnp.asarray(rng.integers(-127, 128, (slots, kv, d, extent)), jnp.int8)
+                    for _ in range(2))
+    key_scale, value_scale = (jnp.asarray(rng.uniform(0.01, 0.02, (slots, kv, extent)), bf16)
+                              for _ in range(2))
+    held = np.minimum(np.exp(rng.normal(np.log(2 * seq), 1.0, slots)).astype(np.int64) + 1, extent)
+    held[:5] = 0, 1, seq, seq + 1, extent
+    fed, at = jnp.asarray(held > 0, jnp.int32), jnp.asarray(np.maximum(held - 1, 0), jnp.int32)
+    qd = normal(slots, 1, 6 * kv, d)
+    got, read = _run_kernel(
+        lambda q, *a: pool_decode(q[:, 0], *a, window=extent, block=seq),
+        qd, keys, key_scale, values, value_scale, at, fed)
+    with mock.patch.object(backend, "on_tpu", lambda: False):       # the loop, on any device
+        want, _ = _reference(lambda *a: cached_attention(*a, window=extent, block=seq),
+                             qd, keys, key_scale, values, value_scale, at[:, None], fed)
+    assert int(read) == int((-(-held // seq)).sum()) * seq, (int(read), held)
+    check("pool_decode", got, want[:, 0], ulps=4)
+
     obs = dict(compiled=_kernels_compiled(), worst_bf16_roundings=worst)
     _emit("kernels", **obs)
     return obs

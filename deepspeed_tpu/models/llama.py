@@ -22,7 +22,9 @@ TPU-first notes, same conventions as ``models/gpt2.py``:
   decode cache may be a RING of the window and a chunk
   (``window_ring``; ``DecodeCache(ring=True)``), and a decode attention may
   walk its stored pool a block at a time, grouped-query, the int8 codes as
-  they lie (:func:`cached_attention`): a window layer's always does.
+  they lie (:func:`cached_attention`): a window layer's always does. On a TPU
+  a decode tick's walk is one kernel that reads each slot as far as that slot
+  goes (``ops/pallas/pool_decode.py``).
 """
 
 import dataclasses
@@ -114,9 +116,11 @@ class LlamaConfig:
     window_ring: Optional[int] = None
     # key positions one step of a decode attention's walk over its stored pool
     # takes (:func:`cached_attention`: grouped-query, the int8 codes read as
-    # they are stored, bounded by the live lengths). None: a full layer's decode
-    # attends its whole pool dequantised (``dot_product_attention``); a window
-    # layer's decode always walks, in blocks of 512
+    # they are stored, bounded by the live lengths): a step of XLA's loop, and
+    # on a TPU the block a grid step of a decode tick's kernel brings into VMEM
+    # (``ops/pallas/pool_decode.py``). None: a full layer's decode attends its
+    # whole pool dequantised (``dot_product_attention``); a window layer's
+    # decode always walks, in blocks of 512
     decode_key_block: Optional[int] = None
     # per layer, as SmallThinker publishes them: 1 = this layer attends its
     # window (``sliding_window``), 0 = full causal attention; 1 = RoPE on
@@ -423,12 +427,22 @@ def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, win
     ``P``): a pool that never wraps is a ring of its own extent, and plain
     causal attention a window of ``P``.
 
-    XLA, a block of ``block`` key positions a step with a running softmax, the
-    steps bounded by what the sequences hold: ONE query a sequence (a decode
-    tick) walks every sequence's pool together as far as the longest goes; a
-    chunk walks a sequence at a time as far as that sequence goes. Returns
-    ``(out [b, l, H, d], positions read)``."""
+    A block of ``block`` key positions a step with a running softmax, the
+    steps bounded by what the sequences hold. ONE query a sequence (a decode
+    tick): on a TPU one kernel (``ops/pallas/pool_decode.py``, serving only:
+    no VJP) that reads each sequence's pool as far as that sequence goes;
+    elsewhere XLA's loop, which walks every sequence's pool together as far as
+    the longest goes: the same numbers, and what the kernel is tested
+    against. A chunk: XLA's loop, a sequence at a time as far as that sequence
+    goes. Returns ``(out [b, l, H, d], positions read)``: the positions the
+    walk that ran was bounded to."""
     b, l, heads, d = q.shape
+    from deepspeed_tpu.ops.pallas import backend
+    if l == 1 and backend.on_tpu():
+        from deepspeed_tpu.ops.pallas.pool_decode import pool_decode
+        out, read = pool_decode(q[:, 0], keys, key_scale, values, value_scale, q_pos[:, 0], fed,
+                                window=window, block=block, rows=rows)
+        return out[:, None], read
     kv, places = keys.shape[1], keys.shape[-1]
     rep, dtype = heads // kv, q.dtype
     block = block if places % block == 0 else places

@@ -1,0 +1,161 @@
+"""A decode tick's read of a stored KV pool, as one kernel: one query a
+sequence against that sequence's pool **as it is stored** (``keys`` / ``values``
+[slots, kv heads, head dim, positions], int8 codes or floating, positions
+minor-most; of int8 pools a scale a position, [slots, kv heads, positions]),
+grouped-query: a block of positions comes into VMEM once and every key head's
+codes meet the ``rep`` query heads that read them, under a running softmax in
+float32. A slot's blocks past its live end move no bytes (the index map clamps,
+so the DMA is elided) and run no FLOPs; a parked slot (``fed`` 0) stays on the
+block the slot before it left in VMEM, reads nothing and gives zeros.
+
+The arithmetic is ``models/llama.py`` ``cached_attention``'s: the codes go into
+the matmuls as they are, a position's key scale multiplies its score and its
+value scale its probability, nothing is dequantised whole and no key head is
+repeated; what a query may read is ``models/common.py`` ``ring_mask`` of its own
+position, so a full pool (a ring of its own extent under a window of it) and a
+window layer's ring are one body. XLA's form, a loop that walks every slot's
+pool together as far as the longest goes, stays: it runs off the chip, and it is
+what this kernel is tested against. Serving only, no VJP.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.pallas import backend
+
+NEG_INF = float(jnp.finfo(jnp.float32).min)
+
+_NN = (((1,), (0,)), ((), ()))
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _kernel(steps_ref, at_ref, row_ref, last_ref, q_ref, k_ref, v_ref, *rest,
+            scale, block, n_blocks, places, window, scaled):
+    if scaled:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, ks32_ref, vs32_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
+    s_i, j = pl.program_id(0), pl.program_id(1)
+    kv, rep = q_ref.shape[:2]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j < steps_ref[s_i])
+    def _block():
+        # ring_mask: place r holds position t - (t - r) mod places; t mod places
+        # on the scalar core, the rest one subtraction away (|t' - r| < places)
+        at = at_ref[s_i]
+        back = jax.lax.rem(at, places) - (
+            j * block + jax.lax.broadcasted_iota(jnp.int32, (rep, block), 1))
+        back = jnp.where(back < 0, back + places, back)
+        seen = (back < window) & (at - back >= 0)
+        if scaled:
+            # in float32, where a key head's row can be picked by a run-time index
+            ks32_ref[...] = ks_ref[...].astype(jnp.float32)          # [kv, block]
+            vs32_ref[...] = vs_ref[...].astype(jnp.float32)
+
+        # traced once and unrolled where it is lowered: eight copies of the body
+        # in Python cost every program that holds the kernel a second of set-up
+        # to trace, and a rolled loop cannot overlap one head's matmuls with the
+        # next one's conversion (0.51 for 0.44 ms a full layer: PERF.md, PR 47)
+        def head(h, _):
+            q = q_ref[h]                                             # [rep, d]
+            s = jax.lax.dot_general(q, k_ref[h].astype(q.dtype), _NN,
+                                    preferred_element_type=jnp.float32) * scale
+            if scaled:
+                s = s * ks32_ref[pl.ds(h, 1), :]
+            s = jnp.where(seen, s, NEG_INF)
+            m = m_ref[h]
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            shrink = jnp.exp(m - m_new)
+            l_ref[h] = l_ref[h] * shrink + p.sum(axis=-1, keepdims=True)
+            if scaled:
+                p = p * vs32_ref[pl.ds(h, 1), :]
+            acc_ref[h] = acc_ref[h] * shrink + jax.lax.dot_general(
+                p.astype(q.dtype), v_ref[h].astype(q.dtype), _NT,
+                preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+        jax.lax.fori_loop(0, kv, head, None, unroll=True)
+
+    @pl.when(j == n_blocks - 1)
+    def _finalize():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-37)).astype(o_ref.dtype)
+
+
+def blocks_read(q_pos, fed, places: int, block: int):
+    """``(blocks [b], block)``: the blocks of each sequence's pool that
+    :func:`pool_decode` brings in and works on, those that start before the
+    sequence's live end (none of a parked one's), and the block's positions
+    (``block``, or the whole extent where that is no multiple of it)."""
+    block = block if places % block == 0 else places
+    ends = jnp.where(fed > 0, jnp.minimum(q_pos.astype(jnp.int32) + fed, places), 0)
+    return -(-ends // block), block
+
+
+# jitted, so that a model's layers of one shape share a trace
+@functools.partial(jax.jit, static_argnames=("window", "block", "interpret"))
+def pool_decode(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: int, block: int,
+                rows=None, interpret=None):
+    """Grouped-query softmax attention of ONE query a sequence, ``q`` [b, H,
+    d] at ``q_pos`` [b], over the stored ``keys`` / ``values`` [slots, kv heads,
+    d, P] and, of int8 pools, ``key_scale`` / ``value_scale`` [slots, kv heads,
+    P] (else None). Sequence ``s`` is row ``rows[s]`` of the pools (None: ``s``)
+    and reads place ``r`` under ``ring_mask(q_pos, r, P, window)``, in blocks of
+    ``block`` places up to the one that holds ``q_pos[s]``; ``fed`` [b] 0 is a
+    parked sequence, which reads nothing and gives zeros. Queries and
+    probabilities meet the pool in ``q``'s type; statistics and sums are
+    float32. Returns ``(out [b, H, d] in q's type, places read)``: the sum over
+    sequences of their own blocks' places."""
+    b, heads, d = q.shape
+    kv, places = keys.shape[1], keys.shape[-1]
+    rep = heads // kv
+    q_pos, fed = q_pos.astype(jnp.int32), fed.astype(jnp.int32)
+    steps, block = blocks_read(q_pos, fed, places, block)
+    n_blocks = places // block
+    if interpret is None:
+        interpret = backend.interpret_default()
+    # a parked sequence stays where the last one that read left off: no DMA
+    seq = jnp.arange(b, dtype=jnp.int32)
+    reader = jnp.maximum(jax.lax.cummax(jnp.where(steps > 0, seq, -1)), 0)
+    row = (seq if rows is None else jnp.asarray(rows, jnp.int32))[reader]
+    last = jnp.maximum(steps[reader] - 1, 0)
+    scaled = key_scale is not None
+
+    # past a sequence's last live block the same block again: no new DMA
+    pool_block = lambda s, j, _steps, _at, row_, last_: (  # noqa: E731
+        row_[s], 0, 0, jnp.minimum(j, last_[s]))
+    scale_block = lambda s, j, _steps, _at, row_, last_: (  # noqa: E731
+        row_[s], 0, jnp.minimum(j, last_[s]))
+    own = lambda s, j, *_: (s, 0, 0, 0)  # noqa: E731
+    pool = pl.BlockSpec((None, kv, d, block), pool_block)
+    operands = [q.reshape(b, kv, rep, d), keys, values]
+    in_specs = [pl.BlockSpec((None, kv, rep, d), own), pool, pool]
+    if scaled:
+        operands += [key_scale, value_scale]
+        in_specs += [pl.BlockSpec((None, kv, block), scale_block)] * 2
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=float(d) ** -0.5, block=block, n_blocks=n_blocks,
+                          places=places, window=window, scaled=scaled),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4, grid=(b, n_blocks),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, kv, rep, d), own),
+            scratch_shapes=[pltpu.VMEM((kv, rep, 1), jnp.float32),
+                            pltpu.VMEM((kv, rep, 1), jnp.float32),
+                            pltpu.VMEM((kv, rep, d), jnp.float32)]
+            + [pltpu.VMEM((kv, block), jnp.float32)] * (2 if scaled else 0)),
+        out_shape=jax.ShapeDtypeStruct((b, kv, rep, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="pool_decode",
+    )(steps, q_pos, row, last, *operands)
+    return out.reshape(b, heads, d), (steps * block).sum().astype(jnp.int32)

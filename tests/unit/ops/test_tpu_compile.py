@@ -192,6 +192,34 @@ def test_kv_append_compiles(one_chip, heads, head_dim, positions, length):
     assert compiled.memory_analysis().temp_size_in_bytes < slots * heads * head_dim * 128
 
 
+@pytest.mark.parametrize("sequences", [8, 32], ids=["quarter_rung", "every_slot"])
+@pytest.mark.parametrize("heads,places,window,pool", [(48, 16384, 16384, jnp.int8),
+                                                      (64, 768, 512, jnp.int8),
+                                                      (48, 16384, 16384, bf16)],
+                         ids=["full_int8", "ring_int8", "full_bf16"])
+def test_pool_decode_compiles(one_chip, sequences, heads, places, window, pool):
+    """A decode tick's read of a stored pool at the mixed-lengths cell's shapes
+    (32 slots, 8 key heads of 128, blocks of 1,024; a full layer's 48 query
+    heads over 16,384 positions, a sliding layer's 64 over a ring of 768),
+    the rung's rows handed in: the kernel alone, no copy of a pool and no
+    temporary beside the few scalars it is steered by."""
+    from deepspeed_tpu.ops.pallas.pool_decode import pool_decode
+    slots, kv, d = 32, 8, 128
+    leaf = _shape(slots, kv, d, places, dtype=pool)
+    scale = _shape(slots, kv, places) if pool == jnp.int8 else None
+    ints = _shape(sequences, dtype=jnp.int32)
+
+    def fn(q, keys, key_scale, values, value_scale, q_pos, fed, rows):
+        return pool_decode(q, keys, key_scale, values, value_scale, q_pos, fed, window=window,
+                           block=1024, rows=rows)
+
+    compiled = _compile(fn, one_chip, _shape(sequences, heads, d), leaf, scale, leaf, scale,
+                        ints, ints, ints)
+    assert _kernel_text(compiled).count("tpu_custom_call") == 1
+    assert not _relayouts(compiled, slots * kv * d * places // 4)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4096
+
+
 @pytest.mark.parametrize("rows", [256, 16384], ids=["decode_tick", "prefill_tick"])
 def test_grouped_matmul_compiles(one_chip, rows):
     """The drop-free route's expert projection at OLMoE's published widths
@@ -579,8 +607,9 @@ def test_laguna_serving_program_keeps_int8_rings_beside_int8_pools(one_chip, pro
     layer's int8 keys and values over 16,384 positions, the sliding layer's
     over a ring of the window and a chunk, all donated and written in place;
     the walk reads the stored codes a block at a time (no pass over a whole
-    pool, nothing dequantised whole, no key head repeated) and the head is made
-    for the one position a slot a prefill tick keeps."""
+    pool, nothing dequantised whole, no key head repeated), a decode tick's as
+    ONE kernel a walking layer (ISSUE 47), and the head is made for the one
+    position a slot a prefill tick keeps."""
     import json
     import os
     import flax.linen as nn
@@ -623,14 +652,28 @@ def test_laguna_serving_program_keeps_int8_rings_beside_int8_pools(one_chip, pro
     assert not _relayouts(compiled, pool_bytes)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 2 * pool_bytes + 2 * slots * 8 * 128 * ring
-    # gate, up, down: once a size of the held route's row buffer (a prefill tick's three)
-    assert compiled.as_text().count("tpu_custom_call") == (9 if program == "prefill" else 3)
+    text = compiled.as_text()
     if program == "prefill":
+        # gate, up, down: once a size of the held route's row buffer (a prefill
+        # tick's three); a chunk's walk stays XLA's loop, a sequence at a time
+        assert text.count("tpu_custom_call") == 9 and "%pool_decode" not in text
         # no [slots, chunk, 100,352] logits (1.6 GB at 256): the held route's
         # last rung at hidden 2,048 and the dense layer's 8,192-wide activations
         assert memory.temp_size_in_bytes < slots * chunk * config["vocab_size"] * 2
     else:
-        assert memory.temp_size_in_bytes < pool_bytes // 10
+        # gate, up, down, and ONE kernel a walking layer (the full layer's pool,
+        # the sliding layer's ring: ``ops/pallas/pool_decode.py``); outside it no
+        # pass over a quarter of a pool leaf, and temporaries under one block set
+        # (a block of keys, of values and of their scales: what the kernel holds
+        # of a pool at a time) beside the head's float32 logits, which no layer
+        # owns; compiles to 5,106,176 bytes
+        walks = [line for line in text.splitlines() if line.lstrip().startswith("%pool_decode")]
+        assert text.count("tpu_custom_call") == 3 + 2 and len(walks) == 2
+        pool = next(leaf for leaf in jax.tree.leaves(cache) if leaf.shape[-1] == positions
+                    and leaf.ndim == 4)
+        assert not _whole_leaf_passes(compiled, pool)
+        block_set = 2 * 8 * dep["decode_key_block"] * (128 + 2)
+        assert memory.temp_size_in_bytes < block_set + n * config["vocab_size"] * 4
 
 
 # ---------------------------------------------------------------------------
