@@ -515,26 +515,52 @@ def kernels_phase(batch=4, seq=1024, heads=16, head_dim=64, width=1024):
     # positions, 48 query heads over 8 key heads of 128, blocks of 1,024). A
     # parked slot, one key, a block's edge and one past it, the whole pool
     from unittest import mock
-    from deepspeed_tpu.models.llama import cached_attention
+    from deepspeed_tpu.models.common import cached_attention, decode_key_block
     from deepspeed_tpu.ops.pallas import backend
     from deepspeed_tpu.ops.pallas.pool_decode import pool_decode
+    def int8_pools(slots, kv, d, extent):
+        """``(keys, key scales, values, value scales)`` as a serving cache stores them."""
+        return tuple(jnp.asarray(rng.integers(-127, 128, (slots, kv, d, extent)), jnp.int8)
+                     if codes else jnp.asarray(rng.uniform(0.01, 0.02, (slots, kv, extent)), bf16)
+                     for codes in (True, False, True, False))
+
     slots, kv, d, extent = 2 * heads, heads // 2, 2 * head_dim, 16 * seq
-    keys, values = (jnp.asarray(rng.integers(-127, 128, (slots, kv, d, extent)), jnp.int8)
-                    for _ in range(2))
-    key_scale, value_scale = (jnp.asarray(rng.uniform(0.01, 0.02, (slots, kv, extent)), bf16)
-                              for _ in range(2))
+    pools = int8_pools(slots, kv, d, extent)
     held = np.minimum(np.exp(rng.normal(np.log(2 * seq), 1.0, slots)).astype(np.int64) + 1, extent)
     held[:5] = 0, 1, seq, seq + 1, extent
     fed, at = jnp.asarray(held > 0, jnp.int32), jnp.asarray(np.maximum(held - 1, 0), jnp.int32)
     qd = normal(slots, 1, 6 * kv, d)
     got, read = _run_kernel(
         lambda q, *a: pool_decode(q[:, 0], *a, window=extent, block=seq),
-        qd, keys, key_scale, values, value_scale, at, fed)
+        qd, *pools, at, fed)
     with mock.patch.object(backend, "on_tpu", lambda: False):       # the loop, on any device
         want, _ = _reference(lambda *a: cached_attention(*a, window=extent, block=seq),
-                             qd, keys, key_scale, values, value_scale, at[:, None], fed)
+                             qd, *pools, at[:, None], fed)
     assert int(read) == int((-(-held // seq)).sum()) * seq, (int(read), held)
     check("pool_decode", got, want[:, 0], ulps=4)
+
+    # the same read with a query head a key head, every head scored in one
+    # matmul (the kernel's other body): at the defaults the chat cell's decode
+    # rung, 8 of GPT-2 medium's 32 slots out of order, 16 heads of 64 over
+    # 1,024 positions, one of the eight parked at the sentinel
+    slots, kv, d, extent = 2 * heads, heads, head_dim, seq
+    pools = int8_pools(slots, kv, d, extent)
+    rung = max(slots // 4, 4)
+    rows = jnp.asarray(rng.permutation(slots)[:rung], jnp.int32)
+    held = rng.integers(1, extent + 1, rung)
+    held[:3] = 0, 1, extent
+    fed = jnp.asarray(held > 0, jnp.int32)
+    at = jnp.asarray(np.where(held > 0, held - 1, extent), jnp.int32)
+    qd, block = normal(rung, 1, kv, d), decode_key_block(kv, d, extent)
+    got, read = _run_kernel(
+        lambda q, *a: pool_decode(q[:, 0], *a[:-1], window=extent, block=block, rows=a[-1]),
+        qd, *pools, at, fed, rows)
+    with mock.patch.object(backend, "on_tpu", lambda: False):
+        want, _ = _reference(
+            lambda *a: cached_attention(*a[:-1], window=extent, block=block, rows=a[-1]),
+            qd, *pools, at[:, None], fed, rows)
+    assert int(read) == int((-(-held // block)).sum()) * block, (int(read), held)
+    check("pool_decode_rung", got, want[:, 0], ulps=4)
 
     obs = dict(compiled=_kernels_compiled(), worst_bf16_roundings=worst)
     _emit("kernels", **obs)

@@ -417,7 +417,9 @@ def build_decode_step(apply_fn, do_sample: bool, temperature: float,
     the next and leave it there. Greedy builds a no-rng program
     (``decode(params, cache, write_pos)``); sampling adds an rng operand.
     ``tokens [slots] int32``, by keyword, is fed in the leaf's place: a draft
-    loop's first token is the host's, the last the target accepted.
+    loop's first token is the host's, the last the target accepted, and each
+    later one what the step before returned, so a step fed by keyword returns
+    its tokens alone, no counter behind them (whoever drafts reads none).
 
     ``rung=True`` builds the program over fewer sequences than slots, as
     :func:`build_prefill_step` does: ``decode_rung(params, cache, slot_ids,
@@ -436,7 +438,8 @@ def build_decode_step(apply_fn, do_sample: bool, temperature: float,
                             f"got {len(rng)}: a slot's token is the cache's, or ``tokens=``")
         cache, held = without_next_tokens(cache)
         live = write_pos < slot_capacity(cache)
-        if tokens is None:
+        drafts = tokens is not None
+        if not drafts:
             tokens = jnp.where(live, held if slot_ids is None else held[slot_ids], 0)
         fed = with_write_positions(cache, write_pos)
         if slot_ids is None:
@@ -450,7 +453,7 @@ def build_decode_step(apply_fn, do_sample: bool, temperature: float,
             tok = jnp.argmax(logits[:, -1], axis=-1)
         tok = tok.astype(jnp.int32)
         return (with_next_tokens(cache, _tokens_of_fed(held, tok, live, slot_ids)),
-                with_counters(cache, tok))
+                tok if drafts else with_counters(cache, tok))
 
     if not rung:
         return decode

@@ -97,11 +97,13 @@ SPARSE_READS = ("dsa_index_keys_read", "dsa_positions_selected", "dsa_positions_
                 "swa_ring_positions_read", "swa_ring_positions_live", "dsa_latent_bytes_written",
                 "dsa_index_key_bytes_written", "swa_ring_bytes_written",
                 "dsa_select_positions_read")
-#: the entries of a ``kv_reads`` leaf, in order (``models/llama.py``, a layer
-#: whose decode attention walks its stored pool: ``decode_key_block``): positions
-#: of a full layer's pool the walk was bounded to and, of those, the ones at or
-#: before some real query; the same of a window layer's ring (read; inside a
-#: real query's window); bytes a window layer wrote into its ring
+#: the entries of a ``kv_reads`` leaf, in order (every :class:`DecodeCache`
+#: carries one, zeros unless the call walked its stored pool: a serving decode
+#: tick's read, :meth:`DecodeCache.attend_tick`, and ``models/llama.py``'s
+#: ``_walk``): positions of a full layer's pool the walk was bounded to and, of
+#: those, the ones at or before some real query; the same of a window layer's
+#: ring (read; inside a real query's window); bytes a window layer wrote into
+#: its ring
 KV_READS = ("kv_full_positions_read", "kv_full_positions_live", "kv_ring_positions_read",
             "kv_ring_positions_live", "kv_ring_bytes_written")
 #: a serving program that runs fewer sequences than the cache has slots (a
@@ -148,6 +150,14 @@ class DecodeCache:
       [slots, kv heads, positions], quantized on write and dequantized on
       read.
 
+    Which read a call takes is decided by what it is, too. **One query a
+    sequence over a per-slot cache** (:attr:`ticks`: a serving decode tick,
+    whole or a rung) reads the pool where it lies (:meth:`attend_tick`: the
+    write, then :func:`cached_attention` over :meth:`stored`, each slot as far
+    as it goes). Every other call (a chunk, lockstep ``generate``) takes
+    :meth:`append`'s whole pools to the attention backend its model names.
+    Either leaves ``kv_reads`` (:data:`KV_READS`; zeros where nothing walked).
+
     ``ring=True`` (a window layer's, ``RING_KV_LEAVES``): ``positions`` is a
     RING, token ``p`` written at ``p mod positions`` (:func:`ring_pool_append`)
     and only by a sequence that is ``live`` (:meth:`write`): a parked slot's
@@ -171,10 +181,49 @@ class DecodeCache:
         self.index = module.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
         self.slots = _cache_slots(module)
         self.ring = ring
+        # for the host, beside a serving tick's tokens; every call says anew what
+        # it walked (a server's programs share one cache tree, and a lockstep
+        # cache carries the leaf too: a cache's leaves are the same whoever made it)
+        self.reads = module.variable("cache", "kv_reads", jnp.zeros, (len(KV_READS),), jnp.int32)
+        self.count_reads()
 
     @property
     def per_slot(self) -> bool:
         return self.index.value.ndim > 0
+
+    def ticks(self, length: int) -> bool:
+        """Whether a call of ``length`` tokens a sequence is a serving decode
+        tick, which reads its pool through :meth:`attend_tick`."""
+        return self.per_slot and length == 1
+
+    def count_reads(self, **counts):
+        """Leave ``counts`` (of :data:`KV_READS`; the rest 0) in ``kv_reads``."""
+        self.reads.value = jnp.stack([jnp.asarray(counts.get(name, 0), jnp.int32)
+                                      for name in KV_READS])
+
+    def attend_tick(self, q, k, v, block: Optional[int] = None, q_pos=None, fed=None):
+        """A serving decode tick's attention: ``k`` / ``v`` [batch, 1, kv
+        heads, head dim] written at the index, then ``q`` [batch, 1, heads,
+        head dim] against each sequence's pool as it is stored
+        (:func:`cached_attention`: grouped-query, int8 codes as they lie, a
+        slot read as far as it goes; on a TPU ``ops/pallas/pool_decode.py``),
+        under the causal mask of ``q_pos`` [batch] (None: where it wrote).
+        A sequence parked at or past the pool's extent reads nothing and gives
+        zeros, as does one whose ``fed`` [batch] is 0. ``block``: key positions
+        a step (None: :func:`decode_key_block`). Returns ``out`` like ``q``."""
+        places = self.key.value.shape[-1]
+        kv_heads, head_dim = k.shape[2:]
+        at = self.write(k, v)
+        live = at < places
+        if fed is not None:
+            live &= fed > 0
+        q_pos = at if q_pos is None else q_pos
+        out, read = cached_attention(
+            q, *self.stored(), q_pos[:, None], live.astype(jnp.int32), window=places,
+            block=block or decode_key_block(kv_heads, head_dim, places), rows=self.slots)
+        self.count_reads(kv_full_positions_read=read, kv_full_positions_live=jnp.where(
+            live, jnp.minimum(q_pos + 1, places), 0).sum())
+        return out
 
     def positions(self, length: int):
         """[batch, length] positions of the ``length`` tokens about to be
@@ -472,6 +521,120 @@ def ring_mask(q_pos, k_at, ring: int, window: int):
     A pool that never wraps is a ring of its own extent."""
     back = (q_pos[..., None] - k_at) % ring
     return (back < window) & (q_pos[..., None] - back >= 0)
+
+
+def decode_key_block(kv_heads: int, head_dim: int, places: int) -> int:
+    """Key positions a step of a decode attention's walk takes where the
+    configuration names none: half a MiB of a leaf's codes, in whole tiles of
+    128 positions and at most the pool. What the chip read as fastest, or within
+    a tenth of it, at three shapes (``PERF.md`` section 6, PR 49: 16 heads of
+    64 over 1,024 positions 512, 16 of 128 over 2,048 256, 2 of 128 over 2,048
+    the pool): a step's cost is its bytes and about a third of a microsecond,
+    and a short step's DMA is latency, not bandwidth."""
+    return min(max(2 ** 19 // (kv_heads * head_dim) // 128 * 128, 128), places)
+
+
+def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: int,
+                     block: int, rows=None):
+    """Grouped-query softmax attention of ``q`` [b, l, H, d] (already written)
+    over the cache as it is STORED: ``keys`` / ``values`` [slots, kv heads, d,
+    P] and, of int8 pools, ``key_scale`` / ``value_scale`` [slots, kv heads, P]
+    (the codes go into the matmuls as they are and a position's scale
+    multiplies its score and its probability: nothing is dequantised whole,
+    and no key head is repeated). Query head ``h`` reads key head ``h // (H /
+    kv heads)``. ``q_pos`` [b, l] are the queries' positions and ``fed`` [b]
+    how many of each sequence's are real (0: a parked slot, which reads
+    nothing and gives zeros); sequence ``s`` is row ``rows[s]`` of the pools
+    (None: ``s``). Place ``r`` of the ``P`` is read under
+    :func:`ring_mask` (``window`` positions ending at the query, of a RING of
+    ``P``): a pool that never wraps is a ring of its own extent, and plain
+    causal attention a window of ``P``.
+
+    A block of ``block`` key positions a step with a running softmax, the
+    steps bounded by what the sequences hold; ``block`` is that and nothing
+    else (whether a call walks at all is its caller's:
+    :meth:`DecodeCache.attend_tick` for every family's serving decode tick,
+    ``models/llama.py`` ``_walk`` for a window layer and a configured full
+    one). ONE query a sequence (a decode tick): on a TPU one kernel
+    (``ops/pallas/pool_decode.py``, serving only: no VJP) that reads each
+    sequence's pool as far as that sequence goes; elsewhere XLA's loop, which
+    walks every sequence's pool together as far as the longest goes: the same
+    numbers, and what the kernel is tested against. A chunk: XLA's loop, a
+    sequence at a time as far as that sequence goes. Scores are scaled by
+    ``d ** -0.5``. Returns ``(out [b, l, H, d], positions read)``: the
+    positions the walk that ran was bounded to."""
+    b, l, heads, d = q.shape
+    from deepspeed_tpu.ops.pallas import backend
+    if l == 1 and backend.on_tpu():
+        from deepspeed_tpu.ops.pallas.pool_decode import pool_decode
+        out, read = pool_decode(q[:, 0], keys, key_scale, values, value_scale, q_pos[:, 0], fed,
+                                window=window, block=block, rows=rows)
+        return out[:, None], read
+    kv, places = keys.shape[1], keys.shape[-1]
+    rep, dtype = heads // kv, q.dtype
+    block = block if places % block == 0 else places
+    scale = d ** -0.5
+    lowest = jnp.finfo(jnp.float32).min
+    grouped = jnp.transpose(q.reshape(b, l, kv, rep, d), (0, 2, 1, 3, 4))   # [b, kv, l, rep, d]
+    ends = jnp.where(fed > 0, jnp.minimum(q_pos[:, 0] + fed, places), 0)    # [b]
+    steps = -(-ends // block)
+
+    def part(leaf, rows_, j):
+        """Places ``[j * block, (j + 1) * block)`` of the rows ``rows_`` (None:
+        of every row, read where it lies)."""
+        if rows_ is None:
+            return jax.lax.dynamic_slice_in_dim(leaf, j * block, block, axis=leaf.ndim - 1)
+        return jnp.concatenate([jax.lax.dynamic_slice(
+            leaf, (r,) + (0,) * (leaf.ndim - 2) + (j * block,), (1,) + leaf.shape[1:-1] + (block,))
+            for r in rows_])
+
+    def step(j, state, rows_, qs, at, real):
+        """One block of the rows ``rows_`` for the queries ``qs`` [n, kv, l,
+        rep, d] at ``at`` [n, l], of sequences that are ``real`` [n]."""
+        m, den, acc = state
+        seen = ring_mask(at, j * block + jnp.arange(block), places, window) & real[:, None, None]
+        seen = seen[:, None, :, None, :]                                    # [n, 1, l, 1, block]
+        s = jnp.einsum("nklrd,nkdp->nklrp", qs, part(keys, rows_, j).astype(dtype),
+                       preferred_element_type=jnp.float32) * scale
+        if key_scale is not None:
+            s = s * part(key_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
+        s = jnp.where(seen, s, lowest)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        den = den * alpha + p.sum(axis=-1)
+        if value_scale is not None:
+            p = p * part(value_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "nklrp,nkdp->nklrd", p.astype(dtype), part(values, rows_, j).astype(dtype),
+            preferred_element_type=jnp.float32)
+        return m_new, den, acc
+
+    def walked(n, count, rows_, qs, at, real):
+        state = (jnp.full((n, kv, l, rep), lowest, jnp.float32),
+                 jnp.zeros((n, kv, l, rep), jnp.float32),
+                 jnp.zeros((n, kv, l, rep, d), jnp.float32))
+        _, den, acc = jax.lax.fori_loop(
+            0, count, lambda j, state: step(j, state, rows_, qs, at, real), state)
+        return (acc / jnp.maximum(den, 1e-37)[..., None]).astype(dtype)
+
+    if l == 1:
+        # every sequence's pool together, as far as the longest goes
+        count = steps.max()
+        out = walked(b, count, None if rows is None else [rows[s] for s in range(b)],
+                     grouped, q_pos, fed > 0)
+        read = count * block * b
+    else:
+        def one(s, out):
+            pick = lambda t: jax.lax.dynamic_slice_in_dim(t, s, 1, axis=0)  # noqa: E731
+            got = walked(1, steps[s], [s if rows is None else rows[s]], pick(grouped),
+                         pick(q_pos), pick(fed) > 0)
+            return jax.lax.dynamic_update_slice_in_dim(out, got, s, axis=0)
+
+        out = jax.lax.fori_loop(0, b, one, jnp.zeros(grouped.shape, dtype))
+        read = (steps * block).sum()
+    out = jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(b, l, heads, d)
+    return out, read.astype(jnp.int32)
 
 
 def _append_in_place(leaves, updates, pos, rows=None):

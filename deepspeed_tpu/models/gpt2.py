@@ -245,7 +245,7 @@ class SelfAttention(nn.Module):
         dropout_rng = None
         if not deterministic and cfg.dropout > 0.0:
             dropout_rng = self.make_rng("dropout")
-        causal, decode_lengths = True, None
+        causal, decode_lengths, cache = True, None, None
         if self.decode:
             # incremental decoding against the static-shape KV cache every
             # family shares (models/common.py DecodeCache: lockstep or
@@ -253,18 +253,27 @@ class SelfAttention(nn.Module):
             from deepspeed_tpu.models.common import DecodeCache
             cache = DecodeCache(self, x.shape[0], cfg.n_positions, cfg.n_head, cfg.head_dim,
                                 k.dtype)
-            k, v, decode_lengths = cache.append(k, v, q.dtype)
-            causal = False
-        from deepspeed_tpu.models.common import attention_geometry_kwargs
-        attn_out = dot_product_attention(q,
-                                         k,
-                                         v,
-                                         backend=cfg.attention_backend,
-                                         causal=causal,
-                                         decode_lengths=decode_lengths,
-                                         dropout_rate=0.0 if deterministic else cfg.dropout,
-                                         dropout_rng=dropout_rng,
-                                         **attention_geometry_kwargs(cfg))
+        if cache is not None and cache.ticks(x.shape[1]):
+            # a serving decode tick reads its pool where it lies
+            if dropout_rng is not None:
+                raise NotImplementedError("attention dropout over a serving decode tick, which "
+                                          "reads its stored pool (DecodeCache.attend_tick): "
+                                          "not built")
+            attn_out = cache.attend_tick(q, k, v)
+        else:
+            if cache is not None:
+                k, v, decode_lengths = cache.append(k, v, q.dtype)
+                causal = False
+            from deepspeed_tpu.models.common import attention_geometry_kwargs
+            attn_out = dot_product_attention(q,
+                                             k,
+                                             v,
+                                             backend=cfg.attention_backend,
+                                             causal=causal,
+                                             decode_lengths=decode_lengths,
+                                             dropout_rate=0.0 if deterministic else cfg.dropout,
+                                             dropout_rng=dropout_rng,
+                                             **attention_geometry_kwargs(cfg))
         out = AttnOutProj(cfg, name="c_proj")(attn_out)
         if not deterministic and cfg.dropout > 0.0:
             out = nn.Dropout(rate=cfg.dropout)(out, deterministic=False)

@@ -22,9 +22,11 @@ TPU-first notes, same conventions as ``models/gpt2.py``:
   decode cache may be a RING of the window and a chunk
   (``window_ring``; ``DecodeCache(ring=True)``), and a decode attention may
   walk its stored pool a block at a time, grouped-query, the int8 codes as
-  they lie (:func:`cached_attention`): a window layer's always does. On a TPU
-  a decode tick's walk is one kernel that reads each slot as far as that slot
-  goes (``ops/pallas/pool_decode.py``).
+  they lie (``models/common.py`` ``cached_attention``): a window layer's
+  always does, and so does every serving decode tick
+  (``DecodeCache.attend_tick``, as in every family). On a TPU a decode tick's
+  walk is one kernel that reads each slot as far as that slot goes
+  (``ops/pallas/pool_decode.py``).
 """
 
 import dataclasses
@@ -35,7 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.common import (KV_READS, DecodeCache, config_from, dense_init as _init,
+from deepspeed_tpu.models.common import (DecodeCache, cached_attention, config_from,
+                                         decode_key_block, dense_init as _init,
                                          normalize_padding_mask, ring_mask, rms_norm,
                                          window_ring_positions)  # noqa: F401  (re-export)
 from deepspeed_tpu.ops.transformer.attention import dot_product_attention
@@ -115,12 +118,15 @@ class LlamaConfig:
     # which never wraps
     window_ring: Optional[int] = None
     # key positions one step of a decode attention's walk over its stored pool
-    # takes (:func:`cached_attention`: grouped-query, the int8 codes read as
-    # they are stored, bounded by the live lengths): a step of XLA's loop, and
-    # on a TPU the block a grid step of a decode tick's kernel brings into VMEM
-    # (``ops/pallas/pool_decode.py``). None: a full layer's decode attends its
-    # whole pool dequantised (``dot_product_attention``); a window layer's
-    # decode always walks, in blocks of 512
+    # takes (``models/common.py`` ``cached_attention``: grouped-query, the int8
+    # codes read as they are stored, bounded by the live lengths): a step of
+    # XLA's loop, and on a TPU the block a grid step of a decode tick's kernel
+    # brings into VMEM (``ops/pallas/pool_decode.py``). For a serving decode
+    # tick and a window layer's decode a size and nothing else: they walk
+    # whatever it is (None: ``models/common.py`` ``decode_key_block`` of the
+    # pool). What a full layer's CHUNK and lockstep decode take still follows
+    # it, as it did (set: ``_walk``, as Laguna's; None: the whole pool to the
+    # attention backend): the chunk's read is ROADMAP S3 (b)'s other half
     decode_key_block: Optional[int] = None
     # per layer, as SmallThinker publishes them: 1 = this layer attends its
     # window (``sliding_window``), 0 = full causal attention; 1 = RoPE on
@@ -411,105 +417,6 @@ def rotate(x, positions, kind: Optional[RopeKind]):
         return jnp.concatenate([out.astype(x.dtype), passed], axis=-1)
 
 
-def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: int,
-                     block: int, rows=None):
-    """Grouped-query softmax attention of ``q`` [b, l, H, d] (already written)
-    over the cache as it is STORED: ``keys`` / ``values`` [slots, kv heads, d,
-    P] and, of int8 pools, ``key_scale`` / ``value_scale`` [slots, kv heads, P]
-    (the codes go into the matmuls as they are and a position's scale
-    multiplies its score and its probability: nothing is dequantised whole,
-    and no key head is repeated). Query head ``h`` reads key head ``h // (H /
-    kv heads)``. ``q_pos`` [b, l] are the queries' positions and ``fed`` [b]
-    how many of each sequence's are real (0: a parked slot, which reads
-    nothing and gives zeros); sequence ``s`` is row ``rows[s]`` of the pools
-    (None: ``s``). Place ``r`` of the ``P`` is read under
-    :func:`ring_mask` (``window`` positions ending at the query, of a RING of
-    ``P``): a pool that never wraps is a ring of its own extent, and plain
-    causal attention a window of ``P``.
-
-    A block of ``block`` key positions a step with a running softmax, the
-    steps bounded by what the sequences hold. ONE query a sequence (a decode
-    tick): on a TPU one kernel (``ops/pallas/pool_decode.py``, serving only:
-    no VJP) that reads each sequence's pool as far as that sequence goes;
-    elsewhere XLA's loop, which walks every sequence's pool together as far as
-    the longest goes: the same numbers, and what the kernel is tested
-    against. A chunk: XLA's loop, a sequence at a time as far as that sequence
-    goes. Returns ``(out [b, l, H, d], positions read)``: the positions the
-    walk that ran was bounded to."""
-    b, l, heads, d = q.shape
-    from deepspeed_tpu.ops.pallas import backend
-    if l == 1 and backend.on_tpu():
-        from deepspeed_tpu.ops.pallas.pool_decode import pool_decode
-        out, read = pool_decode(q[:, 0], keys, key_scale, values, value_scale, q_pos[:, 0], fed,
-                                window=window, block=block, rows=rows)
-        return out[:, None], read
-    kv, places = keys.shape[1], keys.shape[-1]
-    rep, dtype = heads // kv, q.dtype
-    block = block if places % block == 0 else places
-    scale = d ** -0.5
-    lowest = jnp.finfo(jnp.float32).min
-    grouped = jnp.transpose(q.reshape(b, l, kv, rep, d), (0, 2, 1, 3, 4))   # [b, kv, l, rep, d]
-    ends = jnp.where(fed > 0, jnp.minimum(q_pos[:, 0] + fed, places), 0)    # [b]
-    steps = -(-ends // block)
-
-    def part(leaf, rows_, j):
-        """Places ``[j * block, (j + 1) * block)`` of the rows ``rows_`` (None:
-        of every row, read where it lies)."""
-        if rows_ is None:
-            return jax.lax.dynamic_slice_in_dim(leaf, j * block, block, axis=leaf.ndim - 1)
-        return jnp.concatenate([jax.lax.dynamic_slice(
-            leaf, (r,) + (0,) * (leaf.ndim - 2) + (j * block,), (1,) + leaf.shape[1:-1] + (block,))
-            for r in rows_])
-
-    def step(j, state, rows_, qs, at, real):
-        """One block of the rows ``rows_`` for the queries ``qs`` [n, kv, l,
-        rep, d] at ``at`` [n, l], of sequences that are ``real`` [n]."""
-        m, den, acc = state
-        seen = ring_mask(at, j * block + jnp.arange(block), places, window) & real[:, None, None]
-        seen = seen[:, None, :, None, :]                                    # [n, 1, l, 1, block]
-        s = jnp.einsum("nklrd,nkdp->nklrp", qs, part(keys, rows_, j).astype(dtype),
-                       preferred_element_type=jnp.float32) * scale
-        if key_scale is not None:
-            s = s * part(key_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
-        s = jnp.where(seen, s, lowest)
-        m_new = jnp.maximum(m, s.max(axis=-1))
-        p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
-        alpha = jnp.exp(m - m_new)
-        den = den * alpha + p.sum(axis=-1)
-        if value_scale is not None:
-            p = p * part(value_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "nklrp,nkdp->nklrd", p.astype(dtype), part(values, rows_, j).astype(dtype),
-            preferred_element_type=jnp.float32)
-        return m_new, den, acc
-
-    def walked(n, count, rows_, qs, at, real):
-        state = (jnp.full((n, kv, l, rep), lowest, jnp.float32),
-                 jnp.zeros((n, kv, l, rep), jnp.float32),
-                 jnp.zeros((n, kv, l, rep, d), jnp.float32))
-        _, den, acc = jax.lax.fori_loop(
-            0, count, lambda j, state: step(j, state, rows_, qs, at, real), state)
-        return (acc / jnp.maximum(den, 1e-37)[..., None]).astype(dtype)
-
-    if l == 1:
-        # every sequence's pool together, as far as the longest goes
-        count = steps.max()
-        out = walked(b, count, None if rows is None else [rows[s] for s in range(b)],
-                     grouped, q_pos, fed > 0)
-        read = count * block * b
-    else:
-        def one(s, out):
-            pick = lambda t: jax.lax.dynamic_slice_in_dim(t, s, 1, axis=0)  # noqa: E731
-            got = walked(1, steps[s], [s if rows is None else rows[s]], pick(grouped),
-                         pick(q_pos), pick(fed) > 0)
-            return jax.lax.dynamic_update_slice_in_dim(out, got, s, axis=0)
-
-        out = jax.lax.fori_loop(0, b, one, jnp.zeros(grouped.shape, dtype))
-        read = (steps * block).sum()
-    out = jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(b, l, heads, d)
-    return out, read.astype(jnp.int32)
-
-
 class LlamaAttention(nn.Module):
     """GQA attention with RoPE and an optional decode cache. ``layer``
     picks this layer's window, its query heads and how it rotates
@@ -559,14 +466,24 @@ class LlamaAttention(nn.Module):
         # a decode that walks the stored pool (:func:`cached_attention`): a
         # window layer's always, a full layer's where the configuration says
         walks = decode and (window is not None or cfg.decode_key_block is not None)
+        # static-shape KV cache, lockstep or per serving slot, fp or int8
+        # (models/common.py DecodeCache; the cache handed in decides)
+        cache = DecodeCache(self, b, cfg.decode_cache_len or cfg.max_position_embeddings,
+                            cfg.num_key_value_heads, cfg.head_dim, k.dtype) \
+            if decode and not walks else None
         if walks:
             out = self._walk(q, k, v, positions, rope, window, fed, mask)
+        elif decode and cache.ticks(l):
+            # a serving decode tick reads its pool where it lies
+            if mask is not None:
+                raise NotImplementedError("a padding mask over a serving decode tick, which reads "
+                                          "its stored pool (DecodeCache.attend_tick): not built")
+            at = cache.positions(l) if positions is None else positions
+            with jax.named_scope("attn_full"):
+                out = cache.attend_tick(rope(q, at), rope(k, at), v, fed=fed,
+                                        q_pos=None if positions is None else positions[:, -1])
         else:
             if decode:
-                # static-shape KV cache, lockstep or per serving slot, fp or int8
-                # (models/common.py DecodeCache; the cache handed in decides)
-                cache = DecodeCache(self, b, cfg.decode_cache_len or cfg.max_position_embeddings,
-                                    cfg.num_key_value_heads, cfg.head_dim, k.dtype)
                 given = positions is not None
                 if not given:
                     positions = cache.positions(l)
@@ -641,9 +558,8 @@ class LlamaAttention(nn.Module):
         with jax.named_scope("attn_window" if window is not None else "attn_full"):
             out, read = cached_attention(
                 q, *cache.stored(), positions, fed, window=window or places,
-                block=cfg.decode_key_block or 512, rows=cache.slots)
-        # for the host, beside a serving tick's tokens (a lockstep cache carries
-        # the leaf too: a cache's leaves are the same whoever made it)
+                block=cfg.decode_key_block or decode_key_block(
+                    cfg.num_key_value_heads, cfg.head_dim, places), rows=cache.slots)
         ends = jnp.where(fed > 0, jnp.minimum(positions[:, 0] + fed, extent), 0)
         if window is None:
             counts = {"kv_full_positions_read": read, "kv_full_positions_live": ends.sum()}
@@ -654,8 +570,7 @@ class LlamaAttention(nn.Module):
                       "kv_ring_bytes_written": fed.sum() * 2 * cfg.num_key_value_heads * (
                           cfg.head_dim * cache.key.value.dtype.itemsize
                           + (k.dtype.itemsize if cache.quantized else 0))}
-        self.variable("cache", "kv_reads", jnp.zeros, (len(KV_READS),), jnp.int32).value = (
-            jnp.stack([jnp.asarray(counts.get(name, 0), jnp.int32) for name in KV_READS]))
+        cache.count_reads(**counts)
         return out
 
 

@@ -384,11 +384,15 @@ class NemotronHAttention(nn.Module):
         k = proj(cfg.num_key_value_heads, "k_proj")(x)
         v = proj(cfg.num_key_value_heads, "v_proj")(x)
         decode_lengths = None
-        if decode:
-            cache = DecodeCache(self, bsz, cfg.decode_cache_len or cfg.max_position_embeddings,
-                                cfg.num_key_value_heads, cfg.head_dim, k.dtype)
-            k, v, decode_lengths = cache.append(k, v, q.dtype)
-        out = grouped_attention(q, k, v, decode_lengths)
+        cache = DecodeCache(self, bsz, cfg.decode_cache_len or cfg.max_position_embeddings,
+                            cfg.num_key_value_heads, cfg.head_dim, k.dtype) if decode else None
+        if decode and cache.ticks(l):
+            # a serving decode tick reads its pool where it lies
+            out = cache.attend_tick(q, k, v)
+        else:
+            if decode:
+                k, v, decode_lengths = cache.append(k, v, q.dtype)
+            out = grouped_attention(q, k, v, decode_lengths)
         return nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                                kernel_init=nn.with_logical_partitioning(

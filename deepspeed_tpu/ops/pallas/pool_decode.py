@@ -8,7 +8,18 @@ float32. A slot's blocks past its live end move no bytes (the index map clamps,
 so the DMA is elided) and run no FLOPs; a parked slot (``fed`` 0) stays on the
 block the slot before it left in VMEM, reads nothing and gives zeros.
 
-The arithmetic is ``models/llama.py`` ``cached_attention``'s: the codes go into
+Two bodies, chosen by the static ``rep``. Several query heads a key head
+(Laguna's 6 and 8, Nemotron's 16): a key head at a time, its ``rep`` queries
+against its codes. ONE query head a key head (GPT-2, OLMoE): a head's products
+alone are a matrix times one row and its running softmax a row of one, sixteen
+times a block, which costs more than the block's bytes; there the key heads'
+codes are taken side by side, [kv heads x head dim, block], and every head is
+scored in one matmul against the queries laid block-diagonally, the softmax
+runs over all heads at once, and the values' matmul gives every head's sum in
+its own columns (``PERF.md`` section 6, PR 49: 0.168 -> 0.098 ms over GPT-2
+medium's 32 full slots, 705 GB/s).
+
+The arithmetic is ``models/common.py`` ``cached_attention``'s: the codes go into
 the matmuls as they are, a position's key scale multiplies its score and its
 value scale its probability, nothing is dequantised whole and no key head is
 repeated; what a query may read is ``models/common.py`` ``ring_mask`` of its own
@@ -34,13 +45,11 @@ _NT = (((1,), (1,)), ((), ()))
 
 
 def _kernel(steps_ref, at_ref, row_ref, last_ref, q_ref, k_ref, v_ref, *rest,
-            scale, block, n_blocks, places, window, scaled):
+            scale, block, n_blocks, places, window, scaled, kv, rep, d):
     if scaled:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref, ks32_ref, vs32_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        ks_ref, vs_ref, *rest = rest
+    o_ref, m_ref, l_ref, acc_ref, *scales32 = rest
     s_i, j = pl.program_id(0), pl.program_id(1)
-    kv, rep = q_ref.shape[:2]
 
     @pl.when(j == 0)
     def _init():
@@ -48,17 +57,61 @@ def _kernel(steps_ref, at_ref, row_ref, last_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(j < steps_ref[s_i])
-    def _block():
+    def seen_of(rows):
         # ring_mask: place r holds position t - (t - r) mod places; t mod places
         # on the scalar core, the rest one subtraction away (|t' - r| < places)
         at = at_ref[s_i]
         back = jax.lax.rem(at, places) - (
-            j * block + jax.lax.broadcasted_iota(jnp.int32, (rep, block), 1))
+            j * block + jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1))
         back = jnp.where(back < 0, back + places, back)
-        seen = (back < window) & (at - back >= 0)
+        return (back < window) & (at - back >= 0)
+
+    def own_head():
+        """[kv, kv * d] bool: the columns of row ``h`` that are key head
+        ``h``'s, of every key head's side by side."""
+        col = jax.lax.broadcasted_iota(jnp.int32, (kv, kv * d), 1)
+        first = jax.lax.broadcasted_iota(jnp.int32, (kv, kv * d), 0) * d
+        return (col >= first) & (col < first + d)
+
+    def softmax_step(s, seen, key_scale, value_scale, m, l):
+        """One block of the running softmax over scores ``s`` [rows, block]:
+        ``(m, l, shrink, probabilities)``, the scales [rows or 1, block] float32."""
+        if scaled:
+            s = s * key_scale
+        s = jnp.where(seen, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        shrink = jnp.exp(m - m_new)
+        l = l * shrink + p.sum(axis=-1, keepdims=True)
+        return m_new, l, shrink, p * value_scale if scaled else p
+
+    @pl.when(j < steps_ref[s_i])
+    def _block():
+        if rep == 1:
+            # a query head a key head: each head's products alone are a matrix
+            # times ONE row, and sixteen running softmaxes of one row each cost
+            # more than the block's bytes (PERF.md, PR 49). The key heads' codes
+            # lie side by side, [kv * d, block], so every head is scored in one
+            # matmul against the queries laid block-diagonally, [kv, kv * d]:
+            # row h holds query h over key head h's rows and zeros elsewhere
+            # (picked in float32: a mask lies as 32-bit rows do)
+            q = jnp.where(own_head(), jnp.broadcast_to(
+                q_ref[...].astype(jnp.float32), (kv, kv * d)), 0.0).astype(q_ref.dtype)
+            s = jax.lax.dot_general(q, k_ref[...].astype(q.dtype), _NN,
+                                    preferred_element_type=jnp.float32) * scale
+            m, l, shrink, p = softmax_step(
+                s, seen_of(kv), ks_ref[...].astype(jnp.float32) if scaled else None,
+                vs_ref[...].astype(jnp.float32) if scaled else None, m_ref[...], l_ref[...])
+            m_ref[...], l_ref[...] = m, l
+            # [kv, kv * d]: row h's own columns are head h's sum (_finalize)
+            acc_ref[...] = acc_ref[...] * shrink + jax.lax.dot_general(
+                p.astype(q.dtype), v_ref[...].astype(q.dtype), _NT,
+                preferred_element_type=jnp.float32)
+            return
+        seen = seen_of(rep)
         if scaled:
             # in float32, where a key head's row can be picked by a run-time index
+            ks32_ref, vs32_ref = scales32
             ks32_ref[...] = ks_ref[...].astype(jnp.float32)          # [kv, block]
             vs32_ref[...] = vs_ref[...].astype(jnp.float32)
 
@@ -70,26 +123,23 @@ def _kernel(steps_ref, at_ref, row_ref, last_ref, q_ref, k_ref, v_ref, *rest,
             q = q_ref[h]                                             # [rep, d]
             s = jax.lax.dot_general(q, k_ref[h].astype(q.dtype), _NN,
                                     preferred_element_type=jnp.float32) * scale
-            if scaled:
-                s = s * ks32_ref[pl.ds(h, 1), :]
-            s = jnp.where(seen, s, NEG_INF)
-            m = m_ref[h]
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-            shrink = jnp.exp(m - m_new)
-            l_ref[h] = l_ref[h] * shrink + p.sum(axis=-1, keepdims=True)
-            if scaled:
-                p = p * vs32_ref[pl.ds(h, 1), :]
+            m, l, shrink, p = softmax_step(
+                s, seen, ks32_ref[pl.ds(h, 1), :] if scaled else None,
+                vs32_ref[pl.ds(h, 1), :] if scaled else None, m_ref[h], l_ref[h])
+            m_ref[h], l_ref[h] = m, l
             acc_ref[h] = acc_ref[h] * shrink + jax.lax.dot_general(
                 p.astype(q.dtype), v_ref[h].astype(q.dtype), _NT,
                 preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
 
         jax.lax.fori_loop(0, kv, head, None, unroll=True)
 
     @pl.when(j == n_blocks - 1)
     def _finalize():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-37)).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-37)
+        if rep == 1:
+            # each head's own columns of its row, side by side: [1, kv * d]
+            out = jnp.where(own_head(), out, 0.0).sum(axis=0, keepdims=True)
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 def blocks_read(q_pos, fed, places: int, block: int):
@@ -132,29 +182,35 @@ def pool_decode(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: 
     scaled = key_scale is not None
 
     # past a sequence's last live block the same block again: no new DMA
-    pool_block = lambda s, j, _steps, _at, row_, last_: (  # noqa: E731
-        row_[s], 0, 0, jnp.minimum(j, last_[s]))
-    scale_block = lambda s, j, _steps, _at, row_, last_: (  # noqa: E731
-        row_[s], 0, jnp.minimum(j, last_[s]))
-    own = lambda s, j, *_: (s, 0, 0, 0)  # noqa: E731
-    pool = pl.BlockSpec((None, kv, d, block), pool_block)
-    operands = [q.reshape(b, kv, rep, d), keys, values]
-    in_specs = [pl.BlockSpec((None, kv, rep, d), own), pool, pool]
+    def at_block(*leading):
+        return lambda s, j, _steps, _at, row_, last_: (
+            row_[s], *leading, jnp.minimum(j, last_[s]))
+
+    if rep == 1:
+        # the key heads' rows side by side (the leaves' own bytes), the queries
+        # and what comes back one row of every head's: _kernel's one matmul
+        stacked = lambda t: t.reshape(t.shape[0], kv * d, places)  # noqa: E731
+        operands = [q.reshape(b, 1, heads * d), stacked(keys), stacked(values)]
+        ours = pl.BlockSpec((None, 1, heads * d), lambda s, j, *_: (s, 0, 0))
+        pool = pl.BlockSpec((None, kv * d, block), at_block(0))
+        sums = [(kv, 1), (kv, 1), (kv, kv * d)]
+    else:
+        operands = [q.reshape(b, kv, rep, d), keys, values]
+        ours = pl.BlockSpec((None, kv, rep, d), lambda s, j, *_: (s, 0, 0, 0))
+        pool = pl.BlockSpec((None, kv, d, block), at_block(0, 0))
+        sums = [(kv, rep, 1), (kv, rep, 1), (kv, rep, d)] + [(kv, block)] * (2 if scaled else 0)
+    in_specs = [ours, pool, pool]
     if scaled:
         operands += [key_scale, value_scale]
-        in_specs += [pl.BlockSpec((None, kv, block), scale_block)] * 2
+        in_specs += [pl.BlockSpec((None, kv, block), at_block(0))] * 2
     out = pl.pallas_call(
         functools.partial(_kernel, scale=float(d) ** -0.5, block=block, n_blocks=n_blocks,
-                          places=places, window=window, scaled=scaled),
+                          places=places, window=window, scaled=scaled, kv=kv, rep=rep, d=d),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4, grid=(b, n_blocks),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((None, kv, rep, d), own),
-            scratch_shapes=[pltpu.VMEM((kv, rep, 1), jnp.float32),
-                            pltpu.VMEM((kv, rep, 1), jnp.float32),
-                            pltpu.VMEM((kv, rep, d), jnp.float32)]
-            + [pltpu.VMEM((kv, block), jnp.float32)] * (2 if scaled else 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kv, rep, d), q.dtype),
+            in_specs=in_specs, out_specs=ours,
+            scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in sums]),
+        out_shape=jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name="pool_decode",
     )(steps, q_pos, row, last, *operands)

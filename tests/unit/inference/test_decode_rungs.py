@@ -216,6 +216,65 @@ def test_decode_slots_computed_still_counts_ticks(runs):
         assert grew["moe_rows_computed"] < whole["moe_rows_computed"]
 
 
+def _attention_layers(sched):
+    """Layers of the scheduler's cache that hold a ``DecodeCache``."""
+    return sum(1 for path, _ in jax.tree_util.tree_flatten_with_path(sched._cache)[0]
+               if _leaf_name(path) == "kv_reads")
+
+
+def test_a_decode_tick_counts_the_pool_it_read_where_it_lay(runs):
+    """Every family's decode tick reads its stored pool as far as its slots go
+    (``DecodeCache.attend_tick``) and says so on the ``program_counters`` line:
+    positions live are each fed slot's, a layer; positions read are at least
+    those and at most the rows a tick ran over a pool of 64 (off the chip the
+    loop walks the rung's rows together, parked ones too; the kernel's own
+    count is held below); a chunk hands its whole pools on and walks nothing."""
+    sched, grew, whole = runs["sched"], runs["counted"], runs["counted_whole"]
+    layers = _attention_layers(sched)
+    assert layers >= 1 and ("kv_reads", 5) in sched._counters
+    # a decode tick feeds a request's token at p, p + 1, ...: p + 1 positions live
+    live = sum(len(r.prompt) + i + 1 for r in runs["reqs"] for i in range(len(r.output) - 1))
+    assert grew["kv_full_positions_live_decode"] == layers * live
+    assert whole["kv_full_positions_live_decode"] == layers * live
+    assert layers * live <= grew["kv_full_positions_read_decode"] <= (
+        layers * sched.capacity * grew["decode_slots_run"])
+    assert grew["kv_full_positions_read_decode"] < whole["kv_full_positions_read_decode"] <= (
+        layers * sched.capacity * whole["decode_slots_computed"])
+    assert grew.get("kv_full_positions_read_prefill", 0) == 0
+    assert grew.get("kv_ring_positions_read_decode", 0) == 0
+
+
+@pytest.mark.parametrize("program", ["decode", "decode_rung"])
+def test_the_kernels_count_is_each_fed_slots_blocks_and_parked_slots_read_nothing(
+        engine, program, monkeypatch):
+    """On a TPU the read is ``ops/pallas/pool_decode.py`` (here through the
+    Pallas interpreter): ``kv_full_positions_read`` is the fed slots' own blocks,
+    live rounded up to blocks and no more, and a tick whose slots are all
+    parked reads nothing and counts nothing."""
+    from deepspeed_tpu.models import common
+    from deepspeed_tpu.ops.pallas import backend
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(backend, "interpret_default", lambda: True)
+    block = 16
+    monkeypatch.setattr(common, "decode_key_block", lambda *_: block)
+    sched = _scheduler(engine)
+    layers, parked = _attention_layers(sched), sched.capacity
+    apply_fn = make_apply_fn(engine.module, engine._mparams)
+    step = jax.jit(build_decode_step(apply_fn, False, 1.0, 0, 1.0, rung=program != "decode"))
+    rows = SLOTS if program == "decode" else RUNGS[0]
+    slot_ids = () if program == "decode" else (np.array([30, 1, 3, 4, 17, 9, 22, 31], np.int32),)
+    for held in ([10, 63, 16, 0], []):
+        write_pos = np.full(rows, parked, np.int32)
+        write_pos[:len(held)] = held
+        _, tok = step(sched._serve_params, sched._cache, *slot_ids, write_pos)
+        counted = dict(zip(common.KV_READS, np.asarray(tok)[rows:][-5:].tolist()))
+        live = sum(h + 1 for h in held)
+        assert counted["kv_full_positions_live"] == layers * live
+        assert counted["kv_full_positions_read"] == layers * sum(
+            -(-(h + 1) // block) * block for h in held) < layers * (live + block * len(held) + 1)
+        assert not (counted["kv_ring_positions_read"] or counted["kv_ring_bytes_written"])
+
+
 # ---------------------------------------------------------------------------
 # (e) warm-up compiled every program: a run that hops compiles nothing
 # ---------------------------------------------------------------------------
@@ -294,3 +353,21 @@ def test_a_sampling_run_hops_between_rungs_on_the_programs_of_warmup(engine):
         outputs.append([list(r.output) for r in reqs])
     assert outputs[0] == outputs[1]
     assert len({tuple(o) for o in outputs[0]}) > 1       # draws, not one token over and over
+
+
+# ---------------------------------------------------------------------------
+# (h) what a decode tick's read does not take is refused by name, never dropped
+# ---------------------------------------------------------------------------
+def test_a_padding_mask_over_a_serving_decode_tick_is_refused_by_name():
+    module = _module("olmoe-test")
+    params = module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    cache, _ = without_next_tokens(make_slot_cache(module, 4, kv_quant=True))
+    cache = with_write_positions(cache, jnp.asarray([3, 0, 64, 9], jnp.int32))
+    ids = jnp.zeros((4, 1), jnp.int32)
+    module.apply({"params": params, "cache": cache}, ids, decode=True, mutable=["cache"])
+    with pytest.raises(NotImplementedError, match="padding mask over a serving decode tick"):
+        module.apply({"params": params, "cache": cache}, ids, decode=True, mutable=["cache"],
+                     attention_mask=jnp.ones((4, 64), jnp.int32))
+    # a chunk over the same cache takes the mask to the backend, as it did
+    module.apply({"params": params, "cache": cache}, jnp.zeros((4, 2), jnp.int32), decode=True,
+                 mutable=["cache"], attention_mask=jnp.ones((4, 64), jnp.int32))

@@ -118,6 +118,8 @@ def test_decode_cache_writes_what_the_scatter_wrote(head_dim, quant, length, wri
     after = dict(upd["cache"])
     np.testing.assert_array_equal(np.asarray(after.pop("cache_index")), WRITE_POS + length)
     np.testing.assert_array_equal(np.asarray(lengths), WRITE_POS + length)
+    # ``append`` hands the whole pools on: nothing walked
+    assert not np.asarray(after.pop("kv_reads")).any()
     want = _scatter_reference(before, k, v, WRITE_POS, quant)
     _assert_same(after, want)
     # the parked slot's pool is untouched
@@ -150,6 +152,7 @@ def test_padded_final_chunk_is_overwritten_by_the_next_tokens(quant, write):
         cache = dict(upd["cache"])
         want = _scatter_reference(want, k, v, pos, quant)
     cache.pop("cache_index")
+    assert not np.asarray(cache.pop("kv_reads")).any()
     _assert_same(cache, want)
 
 
@@ -325,14 +328,19 @@ def test_stored_form_helpers_agree():
 # ---------------------------------------------------------------------------
 # end to end: chunked prefill + decode over the stored form equal generate
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("slots", [4, 32], ids=["whole", "rung"])
 @pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8kv"])
 def test_scheduler_tokens_equal_generate_across_a_window_boundary(engine_cfg, kv_quant, write,
-                                                                  monkeypatch):
+                                                                  slots, monkeypatch):
+    """Chunked prefill, then decode ticks that read the stored pool where it
+    lies (``DecodeCache.attend_tick``): the whole program at 4 slots, the
+    8-row rung of 32 (``programs.decode_rungs``) for the same four requests."""
     engine, cfg = engine_cfg
     # the engine keeps its serving programs: none traced under the other write
     monkeypatch.setattr(engine, "_serve_cache", {}, raising=False)
     sched = ContinuousBatchingScheduler(engine, ServingConfig(
-        slots=4, prefill_chunk=16, kv_quant=kv_quant, prefix_cache="off"))
+        slots=slots, prefill_chunk=16, kv_quant=kv_quant, prefix_cache="off"))
+    assert len(sched._decode_rungs) == (2 if slots == 32 else 1)
     rng = np.random.default_rng(5)
     # prompts end on either side of position 128; decoding carries two of
     # them over it one token at a time

@@ -17,8 +17,8 @@ from deepspeed_tpu.inference.serving import (FINISHED, ContinuousBatchingSchedul
                                              Request, ServingConfig, make_slot_cache,
                                              slot_capacity)
 from deepspeed_tpu.inference.serving.programs import (INDEX_LEAVES, KV_LEAVES, _leaf_name,
-                                                      build_decode_step,
-                                                      build_prefill_step, make_apply_fn)
+                                                      build_decode_step, build_prefill_step,
+                                                      counter_widths, make_apply_fn)
 from deepspeed_tpu.models import GPT2LMHeadModel, get_gpt2_config
 from deepspeed_tpu.models.common import slot_pool_rows
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
@@ -147,8 +147,11 @@ def test_rows_land_at_write_pos_and_parked_slots_write_nothing(engine_cfg, progr
         step = jax.jit(build_prefill_step(apply_fn, False, 1.0, 0, 1.0), donate_argnums=(1,))
         inputs, fed = (rng.integers(1, cfg.vocab_size, (SLOTS, CHUNK)).astype(np.int32),
                        np.full(SLOTS, CHUNK - 1, np.int32)), {}
+    behind = sum(n for _, n in counter_widths(cache))
     new_cache, tok = step(engine.params, cache, write_pos, *inputs, **fed)
-    assert tok.shape == (SLOTS,)
+    # a step fed its tokens by name returns tokens alone; any other, the
+    # cache's counters behind them (a ``DecodeCache`` layer's ``kv_reads``)
+    assert behind == 5 and tok.shape == (SLOTS + (0 if fed else behind),)
     after = {k: np.asarray(v) for k, v in _kv_leaves(new_cache).items()}
     assert after.keys() == before.keys() and len(after) == (8 if kv_quant else 4)
     for key, old in before.items():

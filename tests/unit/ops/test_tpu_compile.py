@@ -106,6 +106,23 @@ def _assert_pool_written_in_place(compiled, leaf_bytes, layers, logits_bytes):
     assert compiled.memory_analysis().temp_size_in_bytes < layers * leaf_bytes + logits_bytes
 
 
+def _assert_no_rows_of_a_pool_are_made(text, sequences, row):
+    """No instruction of the compiled ``text`` makes ``[sequences, *row]`` (a
+    pool's rows [kv heads, head dim, positions] of the sequences a decode
+    program runs) by a concatenation, copy, gather or transpose (the rung's
+    rows picked out) or in any type but int8 (the codes converted): the pool
+    itself, written in place, is all that has that shape."""
+    import re
+    shape = ",".join(str(d) for d in (sequences,) + tuple(row))
+    made = []
+    for line in text.splitlines():
+        m = re.search(r"= (\w+)\[" + shape + r"\]\S* ([\w\-]+)\(", line)
+        if m and (m.group(1) != "s8" or m.group(2) in ("concatenate", "copy", "gather",
+                                                       "transpose", "convert")):
+            made.append(line.strip()[:160])
+    assert not made, made
+
+
 def _sq_grads(fn):
     return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum(), argnums=(0, 1, 2))
 
@@ -303,7 +320,10 @@ def test_serving_program_compiles(one_chip, program, attention):
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
         operands = (_shape(SLOTS, dtype=jnp.int32),)
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
-    assert ("tpu_custom_call" in compiled.as_text()) == (attention == "flash")
+    # a decode tick reads its stored pool through ``pool_decode`` whatever the
+    # backend a chunk's attention takes: one kernel a layer
+    kernels = compiled.as_text().count("tpu_custom_call")
+    assert kernels == 2 if program == "decode" else (kernels > 0) == (attention == "flash")
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
@@ -337,6 +357,12 @@ def test_gpt2_serving_program_writes_the_pool_in_place(one_chip, program):
         logits = slots * module.config.vocab_size * 2
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     _assert_pool_written_in_place(compiled, slots * L * H * D, layers, logits)
+    # a decode tick reads the pool where it lies: one Mosaic kernel a layer
+    # (``ops/pallas/pool_decode.py``), no pool leaf converted outside it
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (layers if program == "decode" else 0)
+    if program == "decode":
+        _assert_no_rows_of_a_pool_are_made(text, slots, (H, D, L))
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
@@ -367,7 +393,12 @@ def test_olmoe_serving_program_compiles(one_chip, program):
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
         operands = (_shape(slots, dtype=jnp.int32),)
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
-    assert compiled.as_text().count("tpu_custom_call") == 3       # gate, up, down
+    # gate, up, down; and a decode tick's read of the stored pool
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (3 if program == "prefill" else 4)
+    if program == "decode":
+        assert text.count("%pool_decode") >= 1
+        _assert_no_rows_of_a_pool_are_made(text, slots, (16, 128, 2048))
     # a prefill tick's temporaries stay under a gigabyte: no [E, C, M] buffer
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
     logits = slots * (chunk if program == "prefill" else 1) * module.config.vocab_size * 2
@@ -406,7 +437,8 @@ def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, pr
     # 8,192 copies take 2,048 rows or all (a sixteenth, 512, is under
     # ``MIN_RUNG_ROWS``), a decode tick's 64 copies one buffer and no branch
     assert _row_rungs(slots * chunk * 4) == (2048, 8192) and _row_rungs(slots * 4) == (64,)
-    assert compiled.as_text().count("tpu_custom_call") == (8 if program == "prefill" else 4)
+    # (and the attention layer's read of its stored pool in a decode tick)
+    assert compiled.as_text().count("tpu_custom_call") == (8 if program == "prefill" else 5)
     state = cache["layers_0"]["mixer"]["ssm_state"]
     assert state.shape == (slots, 2, 8, 64, 128) and state.dtype == jnp.float32
     # (a prefill tick relays its chunk's activations, as large at these widths)
@@ -764,6 +796,13 @@ def test_a_quarter_rung_program_runs_a_quarter(one_chip, family, program):
     assert family == "nemotron_h" or not _whole_leaf_passes(rung, pool)
     assert set(_write_loop_trip_counts(whole)) == {slots}
     assert set(_write_loop_trip_counts(rung)) == {n}
+    if program == "decode":
+        # the rung's rows are read where they lie, by the kernel's index map:
+        # one kernel a layer in either program and no [n, heads, head dim,
+        # positions] made of a pool, gathered, copied or converted
+        for text, rows in ((rung.as_text(), n), (whole.as_text(), slots)):
+            assert text.count("tpu_custom_call") == 2
+            _assert_no_rows_of_a_pool_are_made(text, rows, pool.shape[1:])
     memory, memory_whole = rung.memory_analysis(), whole.memory_analysis()
     assert memory.temp_size_in_bytes <= memory_whole.temp_size_in_bytes
     cache_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
