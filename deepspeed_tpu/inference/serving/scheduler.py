@@ -50,9 +50,9 @@ from deepspeed_tpu.inference.serving.programs import (POOL_LEAVES, RING_LEAVES, 
 from deepspeed_tpu.inference.serving.queue import RequestQueue
 from deepspeed_tpu.inference.serving.request import (ACTIVE, FINISHED, PREFILL,
                                                      Request)
-from deepspeed_tpu.models.common import (KV_READS, SPARSE_READS, slot_pool_positions_touched,
-                                         slot_pool_row_shape, slot_pool_rows,
-                                         slot_pool_set_rows)
+from deepspeed_tpu.models.common import (KV_READS, PASS_READS, SPARSE_READS,
+                                         slot_pool_positions_touched, slot_pool_row_shape,
+                                         slot_pool_rows, slot_pool_set_rows)
 from deepspeed_tpu.runtime.telemetry.metrics import Histogram
 from deepspeed_tpu.utils import trace
 from deepspeed_tpu.utils.logging import log_dist
@@ -214,6 +214,11 @@ class ContinuousBatchingScheduler:
         self._state_bytes = state_bytes_per_slot(self._cache)
         # what the programs count for the host behind a tick's tokens
         self._counters = counter_widths(self._cache)
+        # a looped stack runs its layers several times a tick over one set of
+        # weights and says what a decode tick streams of them (``loop_weight_bytes``)
+        self._loop_passes = int(getattr(getattr(self.module, "config", None), "loop_passes", 1))
+        if self._loop_passes > 1:
+            self._loop_stream_bytes = self.module.loop_weight_bytes(self._serve_params)
         if self._recurrent and config.prefix_cache == "on":
             raise NotImplementedError(
                 f"prefix_cache='on' over {type(self.module).__name__}: a shared prefix is "
@@ -465,6 +470,12 @@ class ContinuousBatchingScheduler:
                     self._rec.count(what, n)
                     if not what.endswith("_written"):
                         self._count_of_tick(what, n, kind)
+            elif name == "kv_pass_reads":
+                # a looped stack's walks pass by pass (``models/common.py``
+                # PASS_READS): each pass's pair, summed over the layers
+                for i, n in enumerate(counted):
+                    self._rec.count(f"{PASS_READS[i % len(PASS_READS)]}_pass"
+                                    f"{i // len(PASS_READS)}_{kind}", n)
         return tok[:ran]
 
     def _count_of_tick(self, name: str, n: int, kind: str) -> None:
@@ -492,6 +503,16 @@ class ContinuousBatchingScheduler:
         if computed is not None:
             self._rec.count("ssm_positions_fed", fed)
             self._rec.count("ssm_positions_computed", computed)
+
+    def _count_loop(self, kind: str) -> None:
+        """One tick of a looped stack: the passes it ran, by the kind of tick,
+        and the weight bytes a decode tick streams for them (the stack once a
+        pass, the head once: the host's reckoning from the served tree)."""
+        if self._loop_passes == 1:
+            return
+        self._rec.count(f"loop_passes_run_{kind}", self._loop_passes)
+        if kind == "decode":
+            self._rec.count("loop_weight_bytes_streamed", self._loop_stream_bytes)
 
     def _count_moe_rows(self, fed: int, computed: int) -> None:
         """One target forward pass over ``computed`` positions, ``fed`` of
@@ -1014,6 +1035,7 @@ class ContinuousBatchingScheduler:
         self._count_moe_rows(fed, n * C)
         self._count_kv_write(write_pos, C)
         self._count_state(write_pos, fed, n * C)
+        self._count_loop("prefill")
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32), ids, last_idx)
             name = "prefill"
@@ -1050,6 +1072,7 @@ class ContinuousBatchingScheduler:
         self._count_moe_rows(len(slots), n)
         self._count_kv_write(write_pos, 1)
         self._count_state(write_pos)
+        self._count_loop("decode")
         with self._phase("stamp"):
             inputs = (write_pos.astype(np.int32),)
             name = "decode"

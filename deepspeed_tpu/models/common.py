@@ -84,7 +84,7 @@ RING_LEAVES = ("cached_window_latent",) + RING_KV_LEAVES
 #: :data:`SPARSE_READS` names its entries)
 STATE_LEAVES = ("ssm_state", "conv_state")
 LENGTH_LEAVES = ("chunk_length",)
-COUNTER_LEAVES = ("moe_rows", "latent_reads", "sparse_reads", "kv_reads")
+COUNTER_LEAVES = ("moe_rows", "latent_reads", "sparse_reads", "kv_reads", "kv_pass_reads")
 #: the entries of a ``sparse_reads`` leaf, in order, under the names the
 #: host counts them by (by the kind of tick, but for the bytes ``_written``).
 #: An indexed layer fills the ``dsa_`` ones (positions of the index-key pool its
@@ -106,6 +106,13 @@ SPARSE_READS = ("dsa_index_keys_read", "dsa_positions_selected", "dsa_positions_
 #: its ring
 KV_READS = ("kv_full_positions_read", "kv_full_positions_live", "kv_ring_positions_read",
             "kv_ring_positions_live", "kv_ring_bytes_written")
+#: a layer that runs several times a token over one set of weights (a looped
+#: stack, ``models/llama.py`` ``loop_passes``) keeps a cache a PASS, the passes
+#: side by side on its pools' head axis (:class:`DecodeCache` ``parts``), and
+#: leaves beside ``kv_reads``, which it sums over its passes, the same walk's
+#: pair pass by pass: ``kv_pass_reads`` [2 x passes] int32, positions read and
+#: positions live of pass 0, of pass 1, ...
+PASS_READS = ("kv_full_positions_read", "kv_full_positions_live")
 #: a serving program that runs fewer sequences than the cache has slots (a
 #: rung of ``serving/programs.py``'s prefill ladder) says which slot each
 #: sequence is: ``cache_slots`` [n] int32, distinct, which the program lays
@@ -164,23 +171,40 @@ class DecodeCache:
     sentinel must not be folded into the ring. Whoever reads a ring reads the
     stored leaves (:meth:`stored`) under a mask made from the query's own
     position (:func:`ring_mask`).
+
+    ``parts`` > 1 (a layer that a looped stack applies ``parts`` times a token):
+    ONE module, one set of leaves, and a cache a pass: the pools hold ``parts``
+    x ``kv_heads`` heads, and the call that is pass ``part`` (a traced scalar)
+    writes and reads heads ``[part * kv_heads, (part + 1) * kv_heads)`` alone,
+    where they lie: no pass sees another's keys. Every pass of a token writes
+    at the same position, so the index moves with the LAST pass only, and
+    ``kv_reads`` is the sum over the passes (``kv_pass_reads`` has each pass's
+    pair). The slot axis stays first and positions last, so whoever walks a
+    serving cache by its leaves' names sees pools of more heads and nothing new.
     """
 
     def __init__(self, module: nn.Module, batch: int, positions: int, kv_heads: int,
-                 head_dim: int, dtype, ring: bool = False):
-        shape = (batch, positions, kv_heads, head_dim)
+                 head_dim: int, dtype, ring: bool = False, parts: int = 1, part=None):
+        if parts > 1 and ring:
+            raise NotImplementedError("a ring a pass (a window layer of a looped stack): not built")
+        shape = (batch, positions, kv_heads * parts, head_dim)
         key, value = RING_KV_LEAVES if ring else KV_LEAVES
         self.key = module.variable("cache", key, jnp.zeros, shape, dtype)
         self.value = module.variable("cache", value, jnp.zeros, shape, dtype)
         self.quantized = self.key.value.dtype == jnp.int8
         if self.quantized:
             self.key_scale = module.variable("cache", key + "_scale", jnp.zeros,
-                                             (batch, kv_heads, positions), dtype)
+                                             (batch, kv_heads * parts, positions), dtype)
             self.value_scale = module.variable("cache", value + "_scale", jnp.zeros,
-                                               (batch, kv_heads, positions), dtype)
+                                               (batch, kv_heads * parts, positions), dtype)
         self.index = module.variable("cache", "cache_index", lambda: jnp.zeros([], jnp.int32))
         self.slots = _cache_slots(module)
         self.ring = ring
+        self.parts = parts
+        self.part = None if parts == 1 else jnp.asarray(0 if part is None else part, jnp.int32)
+        if parts > 1:
+            self.pass_reads = module.variable("cache", "kv_pass_reads", jnp.zeros,
+                                              (len(PASS_READS) * parts,), jnp.int32)
         # for the host, beside a serving tick's tokens; every call says anew what
         # it walked (a server's programs share one cache tree, and a lockstep
         # cache carries the leaf too: a cache's leaves are the same whoever made it)
@@ -197,9 +221,23 @@ class DecodeCache:
         return self.per_slot and length == 1
 
     def count_reads(self, **counts):
-        """Leave ``counts`` (of :data:`KV_READS`; the rest 0) in ``kv_reads``."""
-        self.reads.value = jnp.stack([jnp.asarray(counts.get(name, 0), jnp.int32)
-                                      for name in KV_READS])
+        """Leave ``counts`` (of :data:`KV_READS`; the rest 0) in ``kv_reads``:
+        of a pass after the first, added to what the passes before it left."""
+        new = jnp.stack([jnp.asarray(counts.get(name, 0), jnp.int32) for name in KV_READS])
+        if self.parts == 1:
+            self.reads.value = new
+            return
+        first = self.part == 0
+        self.reads.value = jnp.where(first, 0, self.reads.value) + new
+        pair = jnp.stack([jnp.asarray(counts.get(name, 0), jnp.int32) for name in PASS_READS])
+        self.pass_reads.value = jax.lax.dynamic_update_slice(
+            jnp.where(first, 0, self.pass_reads.value), pair, (self.part * len(PASS_READS),))
+
+    def _advance(self, length: int):
+        """Move the index over ``length`` written tokens: every pass of a
+        token writes at the same position, so only the last pass moves it."""
+        step = length if self.parts == 1 else jnp.where(self.part == self.parts - 1, length, 0)
+        self.index.value = self.index.value + step
 
     def attend_tick(self, q, k, v, block: Optional[int] = None, q_pos=None, fed=None):
         """A serving decode tick's attention: ``k`` / ``v`` [batch, 1, kv
@@ -220,10 +258,16 @@ class DecodeCache:
         q_pos = at if q_pos is None else q_pos
         out, read = cached_attention(
             q, *self.stored(), q_pos[:, None], live.astype(jnp.int32), window=places,
-            block=block or decode_key_block(kv_heads, head_dim, places), rows=self.slots)
+            block=block or decode_key_block(kv_heads, head_dim, places), rows=self.slots,
+            **self._part)
         self.count_reads(kv_full_positions_read=read, kv_full_positions_live=jnp.where(
             live, jnp.minimum(q_pos + 1, places), 0).sum())
         return out
+
+    @property
+    def _part(self) -> dict:
+        """What tells a walker of the stored leaves which pass's heads to take."""
+        return {} if self.parts == 1 else {"part": self.part, "parts": self.parts}
 
     def positions(self, length: int):
         """[batch, length] positions of the ``length`` tokens about to be
@@ -250,12 +294,15 @@ class DecodeCache:
         if self.per_slot:
             scales = (self.key_scale, self.value_scale) if self.quantized else (None, None)
             keys, values = (slot_pool_read(pool.value, scale and scale.value, read_dtype,
-                                           self.slots)
+                                           self.slots, **self._part)
                             for pool, scale in zip((self.key, self.value), scales))
             return keys, values, idx + l
         # per-sequence live lengths: the flash backend's decode kernel
         # skips dead KV blocks, the XLA backend masks by them
-        return self.key.value, self.value.value, jnp.broadcast_to(idx + l, (b,))
+        keys, values = self.key.value, self.value.value
+        if self.parts > 1:
+            keys, values = (_heads_of_part(t, 2, self.part, self.parts) for t in (keys, values))
+        return keys, values, jnp.broadcast_to(idx + l, (b,))
 
     def _append_per_slot(self, k, v, live=None):
         pools, vals = [self.key, self.value], [k, v]
@@ -268,7 +315,7 @@ class DecodeCache:
             live = jnp.ones(at.shape, bool) if live is None else live
             new = ring_pool_append(held, vals, at, live, self.slots)
         else:
-            new = slot_pool_append(held, vals, at, self.slots)
+            new = slot_pool_append(held, vals, at, self.slots, **self._part)
         for pool, leaf in zip(pools, new):
             pool.value = leaf
 
@@ -282,9 +329,9 @@ class DecodeCache:
         idx = self.index.value
         if self.per_slot:
             self._append_per_slot(k, v, live)
-            self.index.value = idx + l
+            self._advance(l)
             return idx
-        self.index.value = idx + l
+        self._advance(l)
         if self.quantized:
             raise NotImplementedError(
                 "int8 KV pools are a per-slot serving cache "
@@ -294,8 +341,9 @@ class DecodeCache:
                 at = (idx + jnp.arange(l)) % pool.value.shape[1]
                 pool.value = pool.value.at[:, at].set(new.astype(pool.value.dtype))
             else:
+                first = 0 if self.parts == 1 else self.part * k.shape[2]
                 pool.value = jax.lax.dynamic_update_slice(pool.value, new.astype(pool.value.dtype),
-                                                          (0, idx, 0, 0))
+                                                          (0, idx, first, 0))
         return jnp.broadcast_to(idx, (b,))
 
     def stored(self):
@@ -385,15 +433,26 @@ def slot_pool(leaf):
     return jnp.zeros((s, h, d, p), leaf.dtype)
 
 
-def slot_pool_read(pool, scale, read_dtype, rows=None):
+def _heads_of_part(leaf, axis: int, part, parts: int):
+    """The heads of pass ``part`` of ``parts`` on ``axis`` of a leaf that holds
+    every pass's side by side (:class:`DecodeCache` ``parts``): a copy."""
+    heads = leaf.shape[axis] // parts
+    return jax.lax.dynamic_slice_in_dim(leaf, part * heads, heads, axis=axis)
+
+
+def slot_pool_read(pool, scale, read_dtype, rows=None, part=None, parts: int = 1):
     """A stored pool as attention's [slots, positions, kv heads, head dim]
     operand, an int8 pool dequantised by its ``scale`` (attention reads fp
     values, HBM holds the codes): a change of logical order only, which the
     compiler folds into the consumer's layout. ``rows`` [n]: those slots'
-    rows alone, picked before anything is dequantised."""
+    rows alone, picked before anything is dequantised; ``part`` of ``parts``:
+    that pass's heads alone."""
     if rows is not None:
         pool = slot_rows(pool, rows)
         scale = None if scale is None else slot_rows(scale, rows)
+    if parts > 1:
+        pool = _heads_of_part(pool, 1, part, parts)
+        scale = None if scale is None else _heads_of_part(scale, 1, part, parts)
     if scale is not None:
         pool = pool.astype(read_dtype) * scale[:, :, None, :]
     return jnp.transpose(pool, (0, 3, 1, 2))
@@ -455,14 +514,16 @@ def _append_span(length: int, positions: int) -> int:
     return w if length == 1 else min(2 * w, positions)
 
 
-def slot_pool_append(leaves, updates, pos, rows=None):
+def slot_pool_append(leaves, updates, pos, rows=None, part=None, parts: int = 1):
     """Write ``updates[i]`` [slots, l, ...] (token-major, as the projections
     produce them) into the stored leaves ``leaves[i]`` [slots, ..., positions]
     at positions ``pos[s] .. pos[s] + l - 1`` of each slot ``s``; returns the
     new leaves. A position at or past the extent writes nothing (a parked
     slot); tokens past the extent are dropped. ``rows`` [n] int32, distinct:
     the updates are ``n`` sequences' and sequence ``s`` is slot ``rows[s]``;
-    the other slots' rows come back as they went in.
+    the other slots' rows come back as they went in. ``part`` of ``parts``
+    (:class:`DecodeCache` ``parts``): the leaves hold ``parts`` times the
+    updates' heads and the write goes to pass ``part``'s, the others' untouched.
 
     On a TPU, :func:`_append_in_place`. Elsewhere one scatter on the minor
     dimension: the same write, and what the in-place one is tested against;
@@ -470,13 +531,20 @@ def slot_pool_append(leaves, updates, pos, rows=None):
     from deepspeed_tpu.ops.pallas import backend
     pos = pos.astype(jnp.int32)
     if backend.on_tpu():
-        return _append_in_place(leaves, updates, pos, rows)
+        return _append_in_place(leaves, updates, pos, rows, part, parts)
     slots, length = updates[0].shape[:2]
     at = pos[:, None] + jnp.arange(length)[None, :]
+    which = (jnp.arange(slots) if rows is None else rows)[:, None]
     # advanced indices on the first and last axes: the indexed result is
     # [slots, l, ...], the updates' own shape; out of bounds drops
-    return [leaf.at[(jnp.arange(slots) if rows is None else rows)[:, None], ..., at]
-            .set(upd.astype(leaf.dtype)) for leaf, upd in zip(leaves, updates)]
+    if parts == 1:
+        return [leaf.at[which, ..., at]
+                .set(upd.astype(leaf.dtype)) for leaf, upd in zip(leaves, updates)]
+    # the passes' heads apart, [slots, parts, heads, ..., positions]: the same
+    # scatter with the pass picked beside the slot
+    by_pass = [leaf.reshape(leaf.shape[:1] + (parts, -1) + leaf.shape[2:]) for leaf in leaves]
+    return [apart.at[which, part, ..., at].set(upd.astype(leaf.dtype)).reshape(leaf.shape)
+            for leaf, apart, upd in zip(leaves, by_pass, updates)]
 
 
 def ring_pool_append(leaves, updates, pos, live, rows=None):
@@ -535,7 +603,7 @@ def decode_key_block(kv_heads: int, head_dim: int, places: int) -> int:
 
 
 def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: int,
-                     block: int, rows=None):
+                     block: int, rows=None, part=None, parts: int = 1):
     """Grouped-query softmax attention of ``q`` [b, l, H, d] (already written)
     over the cache as it is STORED: ``keys`` / ``values`` [slots, kv heads, d,
     P] and, of int8 pools, ``key_scale`` / ``value_scale`` [slots, kv heads, P]
@@ -562,15 +630,20 @@ def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, win
     numbers, and what the kernel is tested against. A chunk: XLA's loop, a
     sequence at a time as far as that sequence goes. Scores are scaled by
     ``d ** -0.5``. Returns ``(out [b, l, H, d], positions read)``: the
-    positions the walk that ran was bounded to."""
+    positions the walk that ran was bounded to.
+
+    ``part`` of ``parts`` (:class:`DecodeCache` ``parts``): the leaves hold
+    ``parts`` passes' key heads side by side and this call reads pass
+    ``part``'s alone, a block's worth at a time, where they lie."""
     b, l, heads, d = q.shape
     from deepspeed_tpu.ops.pallas import backend
+    looped = {} if parts == 1 else {"part": part, "parts": parts}
     if l == 1 and backend.on_tpu():
         from deepspeed_tpu.ops.pallas.pool_decode import pool_decode
         out, read = pool_decode(q[:, 0], keys, key_scale, values, value_scale, q_pos[:, 0], fed,
-                                window=window, block=block, rows=rows)
+                                window=window, block=block, rows=rows, **looped)
         return out[:, None], read
-    kv, places = keys.shape[1], keys.shape[-1]
+    kv, places = keys.shape[1] // parts, keys.shape[-1]
     rep, dtype = heads // kv, q.dtype
     block = block if places % block == 0 else places
     scale = d ** -0.5
@@ -579,9 +652,15 @@ def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, win
     ends = jnp.where(fed > 0, jnp.minimum(q_pos[:, 0] + fed, places), 0)    # [b]
     steps = -(-ends // block)
 
-    def part(leaf, rows_, j):
+    def piece(leaf, rows_, j):
         """Places ``[j * block, (j + 1) * block)`` of the rows ``rows_`` (None:
-        of every row, read where it lies)."""
+        of every row, read where it lies); of a looped stack's leaf, the
+        pass's heads."""
+        if parts > 1:
+            at = (part * kv,) + (0,) * (leaf.ndim - 3) + (j * block,)
+            size = (kv,) + leaf.shape[2:-1] + (block,)
+            return jnp.concatenate([jax.lax.dynamic_slice(leaf, (r,) + at, (1,) + size)
+                                    for r in (range(b) if rows_ is None else rows_)])
         if rows_ is None:
             return jax.lax.dynamic_slice_in_dim(leaf, j * block, block, axis=leaf.ndim - 1)
         return jnp.concatenate([jax.lax.dynamic_slice(
@@ -594,19 +673,19 @@ def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, win
         m, den, acc = state
         seen = ring_mask(at, j * block + jnp.arange(block), places, window) & real[:, None, None]
         seen = seen[:, None, :, None, :]                                    # [n, 1, l, 1, block]
-        s = jnp.einsum("nklrd,nkdp->nklrp", qs, part(keys, rows_, j).astype(dtype),
+        s = jnp.einsum("nklrd,nkdp->nklrp", qs, piece(keys, rows_, j).astype(dtype),
                        preferred_element_type=jnp.float32) * scale
         if key_scale is not None:
-            s = s * part(key_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
+            s = s * piece(key_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
         s = jnp.where(seen, s, lowest)
         m_new = jnp.maximum(m, s.max(axis=-1))
         p = jnp.where(seen, jnp.exp(s - m_new[..., None]), 0.0)
         alpha = jnp.exp(m - m_new)
         den = den * alpha + p.sum(axis=-1)
         if value_scale is not None:
-            p = p * part(value_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
+            p = p * piece(value_scale, rows_, j)[:, :, None, None, :].astype(jnp.float32)
         acc = acc * alpha[..., None] + jnp.einsum(
-            "nklrp,nkdp->nklrd", p.astype(dtype), part(values, rows_, j).astype(dtype),
+            "nklrp,nkdp->nklrd", p.astype(dtype), piece(values, rows_, j).astype(dtype),
             preferred_element_type=jnp.float32)
         return m_new, den, acc
 
@@ -637,17 +716,31 @@ def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, win
     return out, read.astype(jnp.int32)
 
 
-def _append_in_place(leaves, updates, pos, rows=None):
+def _append_in_place(leaves, updates, pos, rows=None, part=None, parts: int = 1):
     """The write as a read-modify-write of the aligned span that holds the
     tokens, one slot at a time: a ``fori_loop`` of scalar-indexed
     ``dynamic_slice`` / select / ``dynamic_update_slice``, which XLA updates
-    in place under donation."""
+    in place under donation. A looped stack's pass (``parts``) writes through
+    ``ops/pallas/pool_write.py``'s kernel, the same read-modify-write with
+    every sequence's windows in flight at once, whatever the piece's length:
+    the families of one pass keep the loop until a ``perf_opt`` issue has
+    measured the kernel on each of their cells (``ROADMAP.md`` S1)."""
     leaves, updates = list(leaves), list(updates)
     length, piece = updates[0].shape[1], _append_span(1, leaves[0].shape[-1])
+    write = _append_piece
+    if parts > 1:
+        # a looped stack writes 192 times a tick: each piece goes through one
+        # kernel a layer a pass (ops/pallas/pool_write.py)
+        from deepspeed_tpu.ops.pallas import pool_write
+        if not pool_write.takes(leaves, [u[:, :piece] for u in updates]):
+            raise NotImplementedError(
+                "a looped stack's write on a TPU is ops/pallas/pool_write.py's, which takes "
+                "pools of whole 128-position windows; got "
+                f"{[tuple(leaf.shape) for leaf in leaves]}")
+        write = functools.partial(pool_write.pool_write, part=part, parts=parts)
     # a piece of at most one window's tokens touches at most two windows
     for start in range(0, length, piece):
-        leaves = _append_piece(leaves, [u[:, start:start + piece] for u in updates],
-                               pos + start, rows)
+        leaves = write(leaves, [u[:, start:start + piece] for u in updates], pos + start, rows)
     return leaves
 
 

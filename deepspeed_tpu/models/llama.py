@@ -1,5 +1,5 @@
 """LLaMA family — RMSNorm + RoPE + SwiGLU + GQA decoder; Mixtral, Qwen2,
-OLMoE, SmallThinker and Laguna are configurations of it
+OLMoE, SmallThinker, Laguna and Ouro are configurations of it
 (judged config ladder includes LLaMA-7B ZeRO-3 + ZeRO++, BASELINE.md; the
 reference supports LLaMA through kernel injection,
 ``module_inject/containers/llama.py``).
@@ -27,6 +27,16 @@ TPU-first notes, same conventions as ``models/gpt2.py``:
   (``DecodeCache.attend_tick``, as in every family). On a TPU a decode tick's
   walk is one kernel that reads each slot as far as that slot goes
   (``ops/pallas/pool_decode.py``).
+* the stack may be LOOPED (``loop_passes``; Ouro-2.6B runs its 48 layers four
+  times): the layers are applied several times a token over one set of
+  weights, the final norm after every pass, two more norms a layer on the
+  sublayers' outputs (``sandwich_norm``), an exit gate whose distribution over
+  the passes is returned where asked. The passes are one loop on the device
+  (``nn.scan`` over the pass's number: parameters broadcast, the cache
+  carried), so a program holds the layers' bodies once, and a layer keeps a
+  cache a PASS under its one set of leaves (``DecodeCache(parts=...)``: the
+  passes side by side on the pools' head axis). One pass is the plain stack:
+  the same tree, the same cache, the same programs.
 """
 
 import dataclasses
@@ -199,6 +209,22 @@ class LlamaConfig:
     # block lands here
     moe_route: str = "sorted"
     moe_route_kernel: str = "auto"
+    # a LOOPED stack (Ouro, arXiv:2510.25741): the whole stack of layers is
+    # applied ``loop_passes`` times over ONE set of weights, the final norm
+    # after every pass, pass ``t``'s output the input of pass ``t + 1`` and the
+    # head on the last pass's. A pass's keys and values come from that pass's
+    # stream, so a layer keeps a cache a pass (``models/common.py``
+    # ``DecodeCache`` ``parts``: the passes side by side on the pools' head
+    # axis, under the layer's one set of leaves), and every pass of a token
+    # writes at the token's one position. An exit gate (a ``hidden -> 1`` linear
+    # with bias, a sigmoid a position a pass) gives the exit distribution over
+    # the passes, returned where ``return_exit_pdf`` asks; every position runs
+    # every pass (the published ``early_exit_threshold`` of 1). 1: a plain stack
+    loop_passes: int = 1
+    # two more RMSNorms a layer, one on each sublayer's OUTPUT before it joins
+    # the stream (``x + norm(attn(norm(x)))``, ``x + norm(mlp(norm(x)))``): what
+    # keeps a looped stack's recurrence stable
+    sandwich_norm: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -222,6 +248,10 @@ class LlamaConfig:
         if self.moe_activation not in EXPERT_ACTIVATIONS:
             raise ValueError(f"moe_activation must be one of {sorted(EXPERT_ACTIVATIONS)}, "
                              f"got {self.moe_activation!r}")
+        if self.loop_passes < 1:
+            raise ValueError(f"loop_passes must be at least 1, got {self.loop_passes}")
+        if self.loop_passes > 1 and self.window_ring is not None:
+            raise NotImplementedError("a looped stack over window rings: a ring a pass is not built")
 
     def window_of(self, layer: int) -> Optional[int]:
         """The window layer ``layer`` attends, None where it attends all."""
@@ -334,6 +364,18 @@ LLAMA_CONFIGS = {
         moe_shared_intermediate_size=32, moe_score="sigmoid", moe_routed_scale=2.5,
         moe_norm_topk_prob=True, moe_drop_tokens=False, moe_experts_held=(0, 8),
         moe_aux_loss_coef=0.0),
+    # Ouro-2.6B (ByteDance/Ouro-2.6B, arXiv:2510.25741): 48 MHA layers with
+    # sandwich norms, the whole stack run four times over one set of weights,
+    # the final norm after every pass, an exit gate, a cache a pass
+    "ouro-2.6b": dict(vocab_size=49152, hidden_size=2048, intermediate_size=5632,
+                      num_hidden_layers=48, num_attention_heads=16, num_key_value_heads=16,
+                      max_position_embeddings=65536, rms_norm_eps=1e-6, rope_theta=1e6,
+                      loop_passes=4, sandwich_norm=True),
+    # the same at a size the CPU runs: three layers three times
+    "ouro-test": dict(vocab_size=256, hidden_size=64, intermediate_size=128,
+                      num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+                      max_position_embeddings=128, rms_norm_eps=1e-6, rope_theta=1e6,
+                      loop_passes=3, sandwich_norm=True),
     # Qwen2 family: llama architecture + biased q/k/v projections
     "qwen2-7b": dict(vocab_size=152064, hidden_size=3584, intermediate_size=18944,
                      num_hidden_layers=28, num_attention_heads=28, num_key_value_heads=4,
@@ -428,7 +470,10 @@ class LlamaAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions=None, *, decode: bool = False, attention_mask=None,
-                 fed=None):
+                 fed=None, loop_pass=None):
+        """``loop_pass`` (a looped stack's, a traced scalar): which pass of
+        ``loop_passes`` this call is, so which of the layer's caches it
+        writes and reads."""
         cfg = self.config
         b, l, _ = x.shape
         heads = cfg.heads_of(self.layer)
@@ -469,10 +514,11 @@ class LlamaAttention(nn.Module):
         # static-shape KV cache, lockstep or per serving slot, fp or int8
         # (models/common.py DecodeCache; the cache handed in decides)
         cache = DecodeCache(self, b, cfg.decode_cache_len or cfg.max_position_embeddings,
-                            cfg.num_key_value_heads, cfg.head_dim, k.dtype) \
+                            cfg.num_key_value_heads, cfg.head_dim, k.dtype,
+                            **_cache_of_pass(cfg, loop_pass)) \
             if decode and not walks else None
         if walks:
-            out = self._walk(q, k, v, positions, rope, window, fed, mask)
+            out = self._walk(q, k, v, positions, rope, window, fed, mask, loop_pass)
         elif decode and cache.ticks(l):
             # a serving decode tick reads its pool where it lies
             if mask is not None:
@@ -529,7 +575,7 @@ class LlamaAttention(nn.Module):
                                kernel_init=nn.with_logical_partitioning(_init(), ("heads", "kv", "embed")),
                                name="o_proj")(out)
 
-    def _walk(self, q, k, v, positions, rope, window, fed, mask):
+    def _walk(self, q, k, v, positions, rope, window, fed, mask, loop_pass=None):
         """Decode by :func:`cached_attention`: the new keys and values written
         (a window layer's into its ring where the configuration sizes one),
         then the stored pool walked under the window's mask. Leaves ``kv_reads``
@@ -548,7 +594,7 @@ class LlamaAttention(nn.Module):
                 f"first query's window of {window} still reads: window_ring must be "
                 f"window_ring_positions(window, the longest chunk)")
         cache = DecodeCache(self, b, places, cfg.num_key_value_heads, cfg.head_dim, k.dtype,
-                            ring=ring)
+                            ring=ring, **_cache_of_pass(cfg, loop_pass))
         if positions is None:
             positions = cache.positions(l)
         if fed is None:
@@ -559,7 +605,8 @@ class LlamaAttention(nn.Module):
             out, read = cached_attention(
                 q, *cache.stored(), positions, fed, window=window or places,
                 block=cfg.decode_key_block or decode_key_block(
-                    cfg.num_key_value_heads, cfg.head_dim, places), rows=cache.slots)
+                    cfg.num_key_value_heads, cfg.head_dim, places), rows=cache.slots,
+                **cache._part)
         ends = jnp.where(fed > 0, jnp.minimum(positions[:, 0] + fed, extent), 0)
         if window is None:
             counts = {"kv_full_positions_read": read, "kv_full_positions_live": ends.sum()}
@@ -572,6 +619,12 @@ class LlamaAttention(nn.Module):
                           + (k.dtype.itemsize if cache.quantized else 0))}
         cache.count_reads(**counts)
         return out
+
+
+def _cache_of_pass(cfg: LlamaConfig, loop_pass) -> dict:
+    """What tells a layer's ``DecodeCache`` that it is one of a looped stack's:
+    a cache a pass, and which pass this call is."""
+    return {} if cfg.loop_passes == 1 else {"parts": cfg.loop_passes, "part": loop_pass}
 
 
 class LlamaMLP(nn.Module):
@@ -642,15 +695,21 @@ class LlamaDecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions=None, decode: bool = False, attention_mask=None,
-                 deterministic: bool = True, fed=None):
+                 deterministic: bool = True, fed=None, loop_pass=None):
         """``fed`` [batch] int32 (a serving tick of a model that
-        ``counts_real_tokens``): how many of each sequence's tokens are real."""
+        ``counts_real_tokens``): how many of each sequence's tokens are real.
+        ``loop_pass``: which pass of a looped stack this call is."""
         cfg = self.config
+        # a sublayer's output, normed before it joins the stream where the
+        # configuration sandwiches its sublayers
+        after = (lambda name, t: RMSNorm(cfg, name=name)(t)) if cfg.sandwich_norm \
+            else (lambda name, t: t)
         # the pre-attention router reads the stream as it enters the layer
         router_input = x if self.use_moe and cfg.moe_router_before_attention else None
-        x = x + LlamaAttention(cfg, self.layer, name="self_attn")(
+        x = x + after("input_layernorm_2", LlamaAttention(cfg, self.layer, name="self_attn")(
             RMSNorm(cfg, name="input_layernorm")(x), positions, decode=decode,
-            attention_mask=attention_mask, fed=fed)
+            attention_mask=attention_mask, fed=fed,
+            **({} if loop_pass is None else {"loop_pass": loop_pass})))
         h = RMSNorm(cfg, name="post_attention_layernorm")(x)
         if self.use_moe:
             from deepspeed_tpu.moe import MoE
@@ -680,8 +739,9 @@ class LlamaDecoderLayer(nn.Module):
                                     shared_expert=shared,
                                     name="moe")(h, used_token=used, deterministic=deterministic,
                                                 router_input=router_input)
-            return x + moe_out, l_aux
-        return x + LlamaMLP(cfg, name="mlp")(h), jnp.zeros([], jnp.float32)
+            return x + after("post_attention_layernorm_2", moe_out), l_aux
+        return (x + after("post_attention_layernorm_2", LlamaMLP(cfg, name="mlp")(h)),
+                jnp.zeros([], jnp.float32))
 
 
 from deepspeed_tpu.models.common import init_cache  # noqa: E402  (re-export)
@@ -744,9 +804,27 @@ class LlamaForCausalLM(nn.Module):
                 cfg.moe_min_capacity, True, cfg.moe_k)
         return cfg.moe_k * layers, per_layer * layers
 
+    def loop_weight_bytes(self, params) -> int:
+        """Weight bytes a decode tick of a looped stack streams, from the served
+        tree: the layers and the final norm once a pass, the head once (the
+        table is a lookup; the exit gate is not run). The server counts its
+        decode ticks with this."""
+        stack = outside = 0
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+            top = str(getattr(path[0], "key", path[0]))
+            size = leaf.size * leaf.dtype.itemsize
+            if top.startswith("layers_") or top == "norm":
+                stack += size
+            elif top == "lm_head":
+                outside += size
+        return self.config.loop_passes * stack + outside
+
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True, decode: bool = False,
-                 positions=None, attention_mask=None, labels=None):
+                 positions=None, attention_mask=None, labels=None,
+                 return_exit_pdf: bool = False):
+        """``return_exit_pdf`` (a looped stack's): also return the exit
+        distribution over the passes, [B, L, passes] float32, behind the logits."""
         cfg = self.config
         wte = self.param("embed_tokens", nn.with_logical_partitioning(_init(), ("vocab", "embed")),
                          (cfg.vocab_size, cfg.hidden_size), cfg.param_dtype)
@@ -759,7 +837,6 @@ class LlamaForCausalLM(nn.Module):
         # residual stream stays batch-parallel over fsdp-sharded weights —
         # see constrain_activation (the ZeRO-3 weak-scaling invariant)
         x = constrain_activation(x, "batch", "length", "embed")
-        aux_total = jnp.zeros([], jnp.float32)
         moe_layers = self.moe_layers()
         fed = None
         if decode and cfg.counts_real_tokens:
@@ -770,17 +847,31 @@ class LlamaForCausalLM(nn.Module):
             length = self.variable("cache", "chunk_length", lambda: jnp.zeros([], jnp.int32))
             if length.value.ndim:
                 fed = length.value
-        for i in range(cfg.num_hidden_layers):
-            use_moe = i in moe_layers
-            block_cls = maybe_remat(LlamaDecoderLayer, cfg, i, static_argnums=(3, 5),
-                                    enabled=cfg.remat and not decode)
-            x, l_aux = block_cls(cfg, use_moe, i, name=f"layers_{i}")(
-                x, positions, decode, attention_mask, deterministic, fed)
-            x = constrain_activation(x, "batch", "length", "embed")
-            aux_total = aux_total + l_aux
-        if fed is not None and cfg.head_last_fed_only and x.shape[1] > 1:
-            x = jnp.take_along_axis(x, (jnp.maximum(fed, 1) - 1)[:, None, None], axis=1)
-        x = RMSNorm(cfg, name="norm")(x)
+
+        def stack(x, *loop_pass):
+            """The layers once, each made under the module that is current:
+            this one, or its stand-in inside a looped stack's scan."""
+            aux_total = jnp.zeros([], jnp.float32)
+            for i in range(cfg.num_hidden_layers):
+                use_moe = i in moe_layers
+                block_cls = maybe_remat(LlamaDecoderLayer, cfg, i, static_argnums=(3, 5),
+                                        enabled=cfg.remat and not decode)
+                x, l_aux = block_cls(cfg, use_moe, i, name=f"layers_{i}")(
+                    x, positions, decode, attention_mask, deterministic, fed, *loop_pass)
+                x = constrain_activation(x, "batch", "length", "embed")
+                aux_total = aux_total + l_aux
+            return x, aux_total
+
+        exit_pdf = None
+        if cfg.loop_passes == 1:
+            x, aux_total = stack(x)
+            if fed is not None and cfg.head_last_fed_only and x.shape[1] > 1:
+                x = jnp.take_along_axis(x, (jnp.maximum(fed, 1) - 1)[:, None, None], axis=1)
+            x = RMSNorm(cfg, name="norm")(x)
+        else:
+            x, aux_total, exit_pdf = self._looped(stack, x, return_exit_pdf)
+            if fed is not None and cfg.head_last_fed_only and x.shape[1] > 1:
+                x = jnp.take_along_axis(x, (jnp.maximum(fed, 1) - 1)[:, None, None], axis=1)
         if labels is not None and cfg.fused_head_loss_chunk > 0:
             # chunked fused head on the [E, V] Dense kernel — same param
             # path ("lm_head"/"kernel") as the unfused branch, so
@@ -798,6 +889,51 @@ class LlamaForCausalLM(nn.Module):
                           param_dtype=cfg.param_dtype,
                           kernel_init=nn.with_logical_partitioning(_init(), ("embed", "vocab")),
                           name="lm_head")(x)
+        if return_exit_pdf:
+            if exit_pdf is None:
+                raise ValueError("return_exit_pdf: a stack of one pass has no exit gate")
+            return logits, exit_pdf
         if cfg.moe_num_experts > 0:
             return logits, aux_total * cfg.moe_aux_loss_coef
         return logits
+
+    def _looped(self, stack, x, want_pdf: bool):
+        """``x`` through ``loop_passes`` passes of ``stack`` over one set of
+        weights, the final norm after every pass: ``(h_T, the passes' summed
+        aux loss, the exit distribution [B, L, passes] or None)``. The passes
+        are ONE loop on the device (``nn.scan`` over the pass's number: the
+        parameters broadcast, the cache carried and written in place), so a
+        program holds the layers' bodies once however many passes run them;
+        the variables are made by one pass run plainly (``init``)."""
+        cfg = self.config
+
+        def one_pass(mdl, carry, t):
+            x, aux_total = carry
+            with jax.named_scope("loop_pass"):
+                x, l_aux = stack(x, t)
+            with jax.named_scope("loop_norm"):
+                x = RMSNorm(cfg, name="norm")(x)
+            gate = None
+            if want_pdf or mdl.is_initializing():
+                # lambda_t = sigmoid(w_g . h_t + b_g), one scalar a position
+                gate = jax.nn.sigmoid(nn.Dense(
+                    features=1, use_bias=True, dtype=jnp.float32, param_dtype=cfg.param_dtype,
+                    kernel_init=nn.with_logical_partitioning(_init(), ("embed", None)),
+                    name="exit_gate")(x)[..., 0])
+            return (x, aux_total + l_aux), gate
+
+        carry = (x, jnp.zeros([], jnp.float32))
+        if self.is_initializing():
+            (x, aux_total), _ = one_pass(self, carry, jnp.zeros([], jnp.int32))
+            return x, aux_total, None
+        (x, aux_total), gates = nn.scan(
+            one_pass, variable_broadcast="params", variable_carry="cache",
+            split_rngs={"params": False}, length=cfg.loop_passes,
+            check_constancy_invariants=False)(self, carry, jnp.arange(cfg.loop_passes))
+        if not want_pdf:
+            return x, aux_total, None
+        # p_t = lambda_t prod_{j<t} (1 - lambda_j), the last pass takes what is left
+        stay = jnp.cumprod(1.0 - gates, axis=0)
+        before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+        pdf = jnp.concatenate([(gates * before)[:-1], before[-1:]])
+        return x, aux_total, jnp.moveaxis(pdf, 0, -1)

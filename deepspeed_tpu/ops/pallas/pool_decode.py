@@ -44,8 +44,12 @@ _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
 
 
-def _kernel(steps_ref, at_ref, row_ref, last_ref, q_ref, k_ref, v_ref, *rest,
-            scale, block, n_blocks, places, window, scaled, kv, rep, d):
+def _kernel(steps_ref, at_ref, row_ref, last_ref, *rest,
+            scale, block, n_blocks, places, window, scaled, kv, rep, d, looped=False):
+    if looped:
+        # the pass whose heads the index maps picked: nothing to do with it here
+        rest = rest[1:]
+    q_ref, k_ref, v_ref, *rest = rest
     if scaled:
         ks_ref, vs_ref, *rest = rest
     o_ref, m_ref, l_ref, acc_ref, *scales32 = rest
@@ -153,9 +157,9 @@ def blocks_read(q_pos, fed, places: int, block: int):
 
 
 # jitted, so that a model's layers of one shape share a trace
-@functools.partial(jax.jit, static_argnames=("window", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "block", "interpret", "parts"))
 def pool_decode(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: int, block: int,
-                rows=None, interpret=None):
+                rows=None, interpret=None, part=None, parts: int = 1):
     """Grouped-query softmax attention of ONE query a sequence, ``q`` [b, H,
     d] at ``q_pos`` [b], over the stored ``keys`` / ``values`` [slots, kv heads,
     d, P] and, of int8 pools, ``key_scale`` / ``value_scale`` [slots, kv heads,
@@ -165,9 +169,15 @@ def pool_decode(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: 
     parked sequence, which reads nothing and gives zeros. Queries and
     probabilities meet the pool in ``q``'s type; statistics and sums are
     float32. Returns ``(out [b, H, d] in q's type, places read)``: the sum over
-    sequences of their own blocks' places."""
+    sequences of their own blocks' places.
+
+    ``part`` of ``parts`` (``models/common.py`` ``DecodeCache`` ``parts``: a
+    looped stack's cache a pass): the pools hold ``parts`` x kv heads and this
+    call reads pass ``part``'s, a traced scalar: one more prefetched number,
+    which the index maps take as the block along the head axis, so the pass's
+    heads come into VMEM from where they lie and no others move."""
     b, heads, d = q.shape
-    kv, places = keys.shape[1], keys.shape[-1]
+    kv, places = keys.shape[1] // parts, keys.shape[-1]
     rep = heads // kv
     q_pos, fed = q_pos.astype(jnp.int32), fed.astype(jnp.int32)
     steps, block = blocks_read(q_pos, fed, places, block)
@@ -181,15 +191,21 @@ def pool_decode(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: 
     last = jnp.maximum(steps[reader] - 1, 0)
     scaled = key_scale is not None
 
+    looped = parts > 1
+
     # past a sequence's last live block the same block again: no new DMA
     def at_block(*leading):
+        if looped:
+            # the block along the head axis is the pass
+            return lambda s, j, _steps, _at, row_, last_, part_: (
+                row_[s], part_[0], *leading[1:], jnp.minimum(j, last_[s]))
         return lambda s, j, _steps, _at, row_, last_: (
             row_[s], *leading, jnp.minimum(j, last_[s]))
 
     if rep == 1:
         # the key heads' rows side by side (the leaves' own bytes), the queries
         # and what comes back one row of every head's: _kernel's one matmul
-        stacked = lambda t: t.reshape(t.shape[0], kv * d, places)  # noqa: E731
+        stacked = lambda t: t.reshape(t.shape[0], parts * kv * d, places)  # noqa: E731
         operands = [q.reshape(b, 1, heads * d), stacked(keys), stacked(values)]
         ours = pl.BlockSpec((None, 1, heads * d), lambda s, j, *_: (s, 0, 0))
         pool = pl.BlockSpec((None, kv * d, block), at_block(0))
@@ -205,13 +221,15 @@ def pool_decode(q, keys, key_scale, values, value_scale, q_pos, fed, *, window: 
         in_specs += [pl.BlockSpec((None, kv, block), at_block(0))] * 2
     out = pl.pallas_call(
         functools.partial(_kernel, scale=float(d) ** -0.5, block=block, n_blocks=n_blocks,
-                          places=places, window=window, scaled=scaled, kv=kv, rep=rep, d=d),
+                          places=places, window=window, scaled=scaled, kv=kv, rep=rep, d=d,
+                          **({"looped": True} if looped else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4, grid=(b, n_blocks),
+            num_scalar_prefetch=5 if looped else 4, grid=(b, n_blocks),
             in_specs=in_specs, out_specs=ours,
             scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in sums]),
         out_shape=jax.ShapeDtypeStruct(operands[0].shape, q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name="pool_decode",
-    )(steps, q_pos, row, last, *operands)
+    )(steps, q_pos, row, last,
+      *([jnp.asarray(part, jnp.int32).reshape(1)] if looped else []), *operands)
     return out.reshape(b, heads, d), (steps * block).sum().astype(jnp.int32)

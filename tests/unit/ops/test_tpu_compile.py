@@ -708,6 +708,95 @@ def test_laguna_serving_program_keeps_int8_rings_beside_int8_pools(one_chip, pro
         assert memory.temp_size_in_bytes < block_set + n * config["vocab_size"] * 4
 
 
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_ouro_serving_program_loops_its_passes_over_pools_written_in_place(one_chip, program):
+    """The looped-stack cell's programs at the published widths, its 16 slots
+    and 512 positions, two of its 48 layers and all four passes: one set of
+    leaves a layer whose int8 pools hold the four passes' heads side by side,
+    donated and written in place (no pass over a whole pool leaf beside the
+    write and the walk, nothing pool-sized made: a slice of a pass's pool in
+    front of the kernel would copy it), the passes ONE loop on the device (a
+    layer's body is in the program once, not once a pass), a decode tick's walk
+    one kernel a layer whose index map picks the pass's heads, and the head
+    made for the one position a slot a prefill tick keeps."""
+    import json
+    import os
+    import re
+    import flax.linen as nn
+    from benchmarks.families import ouro as family
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step,
+                                                          make_apply_fn, make_slot_cache)
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(__file__))))
+    with open(os.path.join(root, "benchmarks", "configs", "ouro-2.6b.json")) as f:
+        config = json.load(f)
+    layers, passes = 2, config["total_ut_steps"]
+    config.update(num_hidden_layers=layers, layer_types=config["layer_types"][:layers])
+    dep = config["serve"]
+    slots, chunk, positions = dep["slots"], dep["prefill_chunk"], dep["max_out_tokens"]
+    module = family.model(config, dep)
+    assert module.config.loop_passes == passes == 4
+    params = jax.eval_shape(
+        lambda key: jax.tree.map(lambda p: p.astype(bf16), nn.meta.unbox(
+            module.init(key, jnp.zeros((1, 8), jnp.int32))["params"])), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots, kv_quant=True))
+    pools = [leaf for leaf in jax.tree.leaves(cache) if leaf.ndim == 4]
+    assert len(pools) == 2 * layers
+    assert {(leaf.shape, leaf.dtype) for leaf in pools} == {
+        ((slots, passes * 16, 128, positions), jnp.dtype(jnp.int8))}
+    apply_fn = make_apply_fn(module)
+    ints = lambda *dims: _shape(*dims, dtype=jnp.int32)  # noqa: E731
+    if program == "prefill":
+        step = build_prefill_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (ints(slots), ints(slots, chunk), ints(slots))
+    else:
+        step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
+        operands = (ints(slots),)
+    compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
+    memory, text = compiled.memory_analysis(), compiled.as_text()
+    cache_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(cache))
+    assert memory.alias_size_in_bytes >= cache_bytes - 1024
+    assert not _relayouts(compiled, pools[0].size // passes)     # nor of one pass's share
+    # (at two layers the compiler stages a pool through its fast memory on the way to a
+    # kernel, four slots at a time: ``slice-start`` / ``copy-start``, not the program's
+    # doing and 4 of 96 leaves at the cell's depth: PERF.md section 6, PR 50)
+    # (and a chunk's write lays its 128 tokens out as the leaf lies and rolls them
+    # into place, [slots, 16 heads, 128, 2 x 256 positions]: at 512 positions the size
+    # of ONE pass's share of a pool, and no read of it)
+    assert not [line for line in _whole_leaf_passes(compiled, pools[0])
+                if not re.match(r"%(slice|copy)-(start|done)|%pad", line)]
+    # temporaries: the projections' kernels relaid once for the loop (three a layer,
+    # 8.4 MB each) and a tick's activations; under one pool leaf, whatever the passes
+    assert memory.temp_size_in_bytes < pools[0].size + 2 * (slots * chunk if program == "prefill"
+                                                            else slots) * config["vocab_size"]
+    # the layer's body once in the program: its MLP's last matmul is one instruction
+    outside, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = "fused_computation" in line.split("(")[0]
+        elif not fused and "layers_0/mlp/down_proj/dot_general" in line:
+            outside.append(line)
+    assert len(outside) == 1 and "_looped/while/body" in outside[0]
+    # the write is a kernel a layer inside the pass loop, handed the pass as one more
+    # prefetched scalar (a token's 128-position window, or a chunk's two, of the pass's
+    # heads; every leaf of the layer in one call, the pools aliased through it); a decode
+    # tick's walk is another; the slots' write loop is in neither program
+    calls = {name: [line for line in text.splitlines() if "custom-call(" in line
+                    and line.lstrip().startswith("%" + name)]
+             for name in ("pool_write", "pool_decode")}
+    assert len(calls["pool_write"]) == layers
+    assert len(calls["pool_decode"]) == (layers if program == "decode" else 0)
+    assert text.count("tpu_custom_call") == (2 * layers if program == "decode" else layers)
+    assert all("_looped/while/body" in line for found in calls.values() for line in found)
+    assert "jit(_append_piece)/while" not in text and not _write_loop_trip_counts(compiled)
+    if program == "decode":
+        _assert_no_rows_of_a_pool_are_made(text, slots, (16, 128, positions))
+    else:
+        # no [slots, chunk, 49,152] logits: the head for the last fed position a slot
+        assert not re.search(rf"\\[{slots},{chunk},{config['vocab_size']}\\]", text)
+
+
 # ---------------------------------------------------------------------------
 # a rung: the prefill (ISSUE 33) and decode (ISSUE 42) programs over a quarter of the slots
 # ---------------------------------------------------------------------------

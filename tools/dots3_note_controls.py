@@ -1,7 +1,8 @@
 """Controls for a serving cell's reference check (``--workload``; the
 dots3-note-prev cell's by default, the Laguna cell's with
 ``--workload serve-laguna-xs2-mixedlen-sat --control program fp8_weights
-full_window``): does the comparison that decides ``correct`` refuse a server
+full_window``, the Ouro cell's with ``--workload serve-ouro-2.6b-mathword-sat
+--control program fp8_weights three_passes shared_pass_cache``): does the comparison that decides ``correct`` refuse a server
 computed below the precision the configuration states, and one that attends the
 WRONG positions?
 
@@ -28,6 +29,21 @@ would have reported for that server.
   keep their own heads and their own RoPE). Five pools of every position do
   not fit beside the weights at the cell's 32 slots, so this control's server
   has 8: the two checked requests' arithmetic does not see the slot count.
+* ``three_passes`` (a ``models/llama.py`` family with a looped stack, Ouro): the
+  server runs ``total_ut_steps`` - 1 passes of its stack (the family's
+  ``model`` is handed ``loop_passes`` one short; the weights and the head are
+  the configuration's): a pass left out, whatever else it did.
+* ``shared_pass_cache`` (the same families): every pass writes and reads pass
+  1's pool (the package's ``_cache_of_pass`` hands every call part 0 of the
+  layer's pools): at a position a pass finds its own keys, at every earlier one
+  what the LAST pass of that token left there: the one cache shared by the
+  passes that arXiv:2510.25741 measures as an approximation, and that this
+  configuration does not make.
+
+``fp8_weights`` applies to every family; ``last_positions`` to an indexed
+latent family (dots3-note), ``full_window`` to a llama family with window
+layers (Laguna), ``three_passes`` and ``shared_pass_cache`` to a llama family
+with a looped stack (Ouro).
 
 Each line also carries what the checked requests' ticks chose
 (``selected_pct``: positions attended over positions live on the full
@@ -51,7 +67,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "tools"))
 
-CONTROLS = ("program", "fp8_weights", "last_positions", "full_window")
+CONTROLS = ("program", "fp8_weights", "last_positions", "full_window", "three_passes",
+            "shared_pass_cache")
 WORKLOAD = "serve-dots3-note-prev-longctx-sat"
 
 
@@ -86,6 +103,36 @@ def full_window_family(family):
     return types.SimpleNamespace(model=model)
 
 
+def three_passes_family(family):
+    """``family`` whose model runs one pass fewer than the configuration's."""
+    import types
+
+    def model(config, deployment):
+        return family.model(config, deployment, loop_passes=int(config["total_ut_steps"]) - 1)
+
+    return types.SimpleNamespace(model=model)
+
+
+@contextlib.contextmanager
+def one_cache_for_every_pass():
+    """The package's looped stack with every pass handed pass 1's part of its
+    layer's pools: written by each pass in turn, read by each as it stands."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.models import llama as package
+
+    plain = package._cache_of_pass
+
+    def first_part(cfg, loop_pass):
+        given = plain(cfg, loop_pass)
+        return dict(given, part=jnp.zeros_like(given["part"])) if given else given
+
+    package._cache_of_pass = first_part
+    try:
+        yield
+    finally:
+        package._cache_of_pass = plain
+
+
 def run_control(cell, seed, control):
     """One server, one comparison: the line's fields."""
     import copy
@@ -105,15 +152,17 @@ def run_control(cell, seed, control):
     t0 = time.time()
     env = harness.Env(seed, 0, 0, harness.Setup(t0), jax.devices()[:1], harness.Tracer(False, ""))
     before = dict(trace.recorder().counters)
-    changed = last_positions_chosen() if control == "last_positions" else contextlib.nullcontext()
+    changed = {"last_positions": last_positions_chosen,
+               "shared_pass_cache": one_cache_for_every_pass}.get(control, contextlib.nullcontext)()
+    stand_in = {"fp8_weights": fp8_family, "full_window": full_window_family,
+                "three_passes": three_passes_family}.get(control, lambda family: family)
     with changed:       # the programs are traced in warm-up, under the change
-        engine, sched = runner._server(cell, env, fp8_family(family) if control == "fp8_weights"
-                                       else full_window_family(family) if control == "full_window"
-                                       else family)
+        engine, sched = runner._server(cell, env, stand_in(family))
         sched.warmup()
         reqs = runner._checked_requests(cell, env, sched)
     counted = {k: v - before.get(k, 0) for k, v in trace.recorder().counters.items()}
-    line = {"seed": seed, "control": control}
+    line = {"seed": seed, "control": control,
+            "tokens_emitted_distinct": len({int(t) for r in reqs for t in r.output})}
     live = sum(counted.get(f"dsa_positions_live_{kind}", 0) for kind in ("prefill", "decode"))
     if live:
         line["selected_pct"] = 100.0 * sum(counted.get(f"dsa_positions_selected_{kind}", 0)
