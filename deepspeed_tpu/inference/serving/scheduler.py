@@ -117,15 +117,16 @@ _restore_rows_jit = jax.jit(_restore_rows_jit_impl,
 
 
 class _Program:
-    """One dispatched program until its tokens are read: ``rows`` are the
+    """One dispatched program until its tokens are read: ``ran`` the
+    sequences it ran (its rung), ``rows`` the
     requests it serves, each ``(slot, request, row of ``tok``, prompt tokens
     fed, whether the row's token is the request's next)``; ``ending`` the
     ``(slot, request)`` whose last token, by count, this program samples."""
 
-    __slots__ = ("kind", "tok", "rows", "ending")
+    __slots__ = ("kind", "ran", "tok", "rows", "ending")
 
-    def __init__(self, kind: str, tok, rows: list, ending: list):
-        self.kind, self.tok, self.rows, self.ending = kind, tok, rows, ending
+    def __init__(self, kind: str, ran: int, tok, rows: list, ending: list):
+        self.kind, self.ran, self.tok, self.rows, self.ending = kind, ran, tok, rows, ending
 
 
 class ContinuousBatchingScheduler:
@@ -168,10 +169,11 @@ class ContinuousBatchingScheduler:
         self._rec.count("tick_input_puts", 0)
         # programs dispatched, those of them dispatched while another was in
         # flight, read-backs forced before their time (also by reason:
-        # ``ticks_settled_<spec|prefix|export|swap|drain>``), and rows run for
-        # a request that had already ended on its EOS
+        # ``ticks_settled_<spec|prefix|export|swap|drain>``), rows run for
+        # a request that had already ended on its EOS, and the programs
+        # dispatched ahead that reached a device with nothing queued (``_probe``)
         for name in ("ticks_dispatched", "ticks_dispatched_ahead", "ticks_settled",
-                     "slot_ticks_discarded"):
+                     "slot_ticks_discarded", "ticks_device_dry"):
             self._rec.count(name, 0)
         # an expert model says what its expert matmuls owe and are given
         # (``moe_rows``); a dense model has no such counters
@@ -337,6 +339,13 @@ class ContinuousBatchingScheduler:
         self._decode_rungs = ((self.slots,) if self.spec_k
                               else decode_rungs(self.slots, engine.mesh.size, self._cache))
 
+        # what every call of a target program is handed besides its tick's few
+        # host arrays: the served tree and the slot cache, leaf by leaf
+        handed = jax.tree_util.tree_leaves((self._serve_params, self._cache))
+        self._rec.gauge("program_operand_leaves", len(handed))
+        self._rec.gauge("program_operand_bytes",
+                        sum(leaf.size * leaf.dtype.itemsize for leaf in handed))
+
         # host-side authoritative slot state, as of what has been DISPATCHED:
         # who holds a slot, the cache positions written, the prompt tokens fed
         # and the output tokens whose sampling is under way or done
@@ -348,6 +357,10 @@ class ContinuousBatchingScheduler:
         # reads every program in the step that dispatched it, if it must: a
         # drafter's accept loop reads tokens, a prefix publish copies pool rows
         self._inflight: Optional[_Program] = None
+        # of the tick in progress: when the program in flight was last looked
+        # at, and (when, the look before, the phase between) of the first look
+        # that found it ended (``_probe``)
+        self._probed, self._dry = 0.0, None
         self._serial = ("spec" if self._drafter is not None
                         else "prefix" if self.prefix_cache == "on" else None)
         self._decode_ticks_since_prefill = 10**9  # first prefill never waits
@@ -537,10 +550,42 @@ class ContinuousBatchingScheduler:
         self._rec.count("kv_window_positions_touched",
                         slot_pool_positions_touched(live, length, self.capacity))
 
-    def _phase(self, name: str):
+    def _phase(self, name: str, marks: int = 0):
         """A host phase of the tick in progress: a child span of ``tick``
         (or, before the first tick, of ``scheduler_init`` and ``warmup``)."""
-        return self._rec.span(name, self._tick_no, self._source)
+        return self._rec.span(name, self._tick_no, self._source, marks)
+
+    def _launch(self, kind: str):
+        """The span around a tick's jitted call alone, inside ``dispatch``:
+        its wall time beside its CPU time, by the kind of program."""
+        launch = self._phase("launch", trace.CPU)
+        launch.kind = kind
+        return launch
+
+    def _probe(self, at: float, phase: str) -> None:
+        """One look at the program in flight (``is_ready``: no wait, no
+        transfer) at time ``at``, the close of ``phase``: the first look of a
+        tick that finds its tokens made is kept. From then on the device has
+        nothing queued until the tick's own program reaches it."""
+        flight = self._inflight
+        if flight is None:
+            return
+        if self._dry is None and flight.tok.is_ready():
+            self._dry = (at, self._probed, phase)
+        self._probed = at
+
+    def _count_dry(self) -> None:
+        """The program in flight had ended before the launch of the one
+        dispatched behind it returned (the last look): the device ran dry
+        under the host, for at least the time since the look that found it
+        ended (one ``device_dry`` record beside the tick, whose ``kind`` is the
+        phase that ended with that look), at most since the look before it."""
+        (seen, before, phase), launched = self._dry, self._probed
+        self._rec.count("ticks_device_dry")
+        self._rec.count(f"ticks_device_dry_in_{phase}")
+        self._rec.count("device_dry_us_min", int((launched - seen) * 1e6))
+        self._rec.count("device_dry_us_max", int((launched - before) * 1e6))
+        self._rec.record("device_dry", seen, launched, self._tick_no, self._source, phase)
 
     # ------------------------------------------------------------------
     def warmup(self) -> None:
@@ -596,6 +641,9 @@ class ContinuousBatchingScheduler:
                         cache, _ = fns[name](params, getattr(self, cache_attr), *args,
                                              **(by_name[0] if by_name else {}))
                     setattr(self, cache_attr, cache)
+                    # the host arrays its tick hands it, each copied over by the call
+                    self._rec.gauge(f"program_host_operands_{role}{name}",
+                                    sum(isinstance(a, np.ndarray) for a in args))
 
     # ------------------------------------------------------------------
     # submission / admission
@@ -748,13 +796,16 @@ class ContinuousBatchingScheduler:
         if self.telemetry is not None:
             self.telemetry.begin_step(step_no)
         with self._rec.span("tick", step_no, self._source, trace.UNIT) as tick:
+            # the program in flight began when the one before it was read, a
+            # tick ago: taken as not ended yet where this tick starts
+            self._probed, self._dry = tick.start, None
             with self._phase("admit"):
                 if admit:
                     self._admit()
             held = [i for i, r in enumerate(self._slot_req) if r is not None]
             prefilling = [i for i in held if self._fed[i] < self._slot_req[i].prompt_len]
             active = [i for i in held if self._fed[i] >= self._slot_req[i].prompt_len]
-            tick.kind = kind = "idle"
+            tick.kind = kind = trace.IDLE
             program = None
             if prefilling and (not active or self._decode_ticks_since_prefill
                                >= self.config.prefill_interleave):
@@ -772,8 +823,10 @@ class ContinuousBatchingScheduler:
             if kind != "idle" or before is None:    # a program, or a step with nothing to do
                 self.ticks[kind] += 1
             if before is not None:
-                # the tick that ends here is the program's that is read here
+                # the tick that ends here is the program's that is read here, and
+                # takes as long as the ticks of its kind that read as many rows
                 tick.kind = kind = before.kind
+                tick.like = before.ran
                 self._commit(before)
             if program is not None:
                 if self._serial:
@@ -785,6 +838,8 @@ class ContinuousBatchingScheduler:
                 self._serve_t0 = self.clock()
             with self._phase("heartbeat"):
                 self._touch_serving_heartbeat(step_no)
+        if self._dry is not None:
+            self._count_dry()
         if self.telemetry is not None:
             self.telemetry.end_step(step_no)
             every = self.config.tick_telemetry_every
@@ -1008,7 +1063,8 @@ class ContinuousBatchingScheduler:
 
     def _prefill_tick(self, slots: List[int]) -> _Program:
         C = self.config.prefill_chunk
-        with self._phase("build_inputs"):
+        with self._phase("build_inputs") as build:
+            self._probe(build.start, "admit")
             rows = self._rung_rows(slots, self._rungs)
             n = len(rows)
             row_of = {int(i): j for j, i in enumerate(rows)}
@@ -1041,22 +1097,27 @@ class ContinuousBatchingScheduler:
             name = "prefill"
             if n < self.slots:
                 name, inputs = "prefill_rung", (rows,) + inputs
-        with self._phase("dispatch"):
+        with self._phase("dispatch") as dispatch:
+            self._probe(dispatch.start, "build_inputs")
             if self.config.do_sample:
                 self._rng, key = jax.random.split(self._rng)
                 inputs += (key,)
-            self._cache, tok = self.fns[name](self._serve_params, self._cache, *inputs)
-            if self._drafter is not None:  # speculation is greedy: no rng operand
-                self._drafter_cache, _ = self.dfns[name](
-                    self._drafter[1], self._drafter_cache, *inputs)
+            with self._launch("prefill") as launch:
+                self._cache, tok = self.fns[name](self._serve_params, self._cache, *inputs)
+                if self._drafter is not None:  # speculation is greedy: no rng operand
+                    self._drafter_cache, _ = self.dfns[name](
+                        self._drafter[1], self._drafter_cache, *inputs)
+            self._probe(launch.end, "launch")
             # the prompt that completes here samples the request's first token
-            return self._dispatched("prefill", tok, [
-                (i, self._slot_req[i], row_of[i], rems[i],
-                 self._fed[i] + rems[i] >= self._slot_req[i].prompt_len) for i in slots])
+            with self._phase("account"):
+                return self._dispatched("prefill", n, tok, [
+                    (i, self._slot_req[i], row_of[i], rems[i],
+                     self._fed[i] + rems[i] >= self._slot_req[i].prompt_len) for i in slots])
 
     # -- plain decode --------------------------------------------------
     def _decode_tick(self, slots: List[int]) -> _Program:
-        with self._phase("build_inputs"):
+        with self._phase("build_inputs") as build:
+            self._probe(build.start, "admit")
             rows = self._rung_rows(slots, self._decode_rungs)
             n = len(rows)
             row_of = {int(i): j for j, i in enumerate(rows)}
@@ -1078,16 +1139,20 @@ class ContinuousBatchingScheduler:
             name = "decode"
             if n < self.slots:
                 name, inputs = "decode_rung", (rows,) + inputs
-        with self._phase("dispatch"):
+        with self._phase("dispatch") as dispatch:
+            self._probe(dispatch.start, "build_inputs")
             if self.config.do_sample:
                 self._rng, key = jax.random.split(self._rng)
                 inputs += (key,)
             # each slot's token is the cache's own (``programs.TOKEN_LEAF``)
-            self._cache, tok = self.fns[name](self._serve_params, self._cache, *inputs)
-            return self._dispatched("decode", tok, [(i, self._slot_req[i], row_of[i], 0, True)
-                                                    for i in slots])
+            with self._launch("decode") as launch:
+                self._cache, tok = self.fns[name](self._serve_params, self._cache, *inputs)
+            self._probe(launch.end, "launch")
+            with self._phase("account"):
+                return self._dispatched("decode", n, tok, [
+                    (i, self._slot_req[i], row_of[i], 0, True) for i in slots])
 
-    def _dispatched(self, kind: str, tok, rows: list) -> _Program:
+    def _dispatched(self, kind: str, ran: int, tok, rows: list) -> _Program:
         """What the host knows of a program the moment it is dispatched,
         by counting: a row advances its slot by the prompt tokens it fed (a
         decode row by the one token it fed) and, where it samples the
@@ -1106,7 +1171,7 @@ class ContinuousBatchingScheduler:
                 self._sent[slot] += 1
                 if self._sent[slot] >= req.max_new_tokens:
                     ending.append((slot, req))
-        return _Program(kind, tok, rows, ending)
+        return _Program(kind, ran, tok, rows, ending)
 
     def _commit(self, program: _Program) -> None:
         """The blocking read-back of a program's tokens, and what they tell:

@@ -273,6 +273,9 @@ class RuntimeTelemetry:
             window = {"event": "step_window", "step": step,
                       "phases": {name: h.snapshot() for name, h in hists.items()}}
             if self.recorder.counters:   # process totals, not this source's alone
+                # what the ring has pushed out by now: a reader of the ring (a
+                # median, a stall's children) saw no more than it still held
+                self.recorder.gauge("ring_records_dropped", self.recorder.dropped)
                 window["metrics"] = {"counters": dict(self.recorder.counters)}
             self.sink.write(window)
             step_hist = hists.get("step")

@@ -34,6 +34,15 @@ the time JAX held the interpreter and the time the compiler or the cache
 took; and a compile in the middle of serving or training is a
 ``recompile`` record that names the tick or step, the phase and the
 function.
+
+A span is wall time. Three things say what wall time cannot: a span marked
+:data:`CPU` reads the thread's CPU clock beside it, so a call that waits is
+told from one that computes; a unit of work (:data:`UNIT`: a tick, a step)
+that runs far over the typical length of its kind is counted and leaves a
+``stall`` record that names the phase it hung under, so that a hang no
+median shows is on record (:meth:`Recorder._unit_closed`); and a gauge
+(:meth:`Recorder.gauge`) is a counter that is set, for what the newest
+engine or scheduler says of itself.
 """
 
 import collections
@@ -46,12 +55,15 @@ import jax
 import jax.monitoring
 
 __all__ = ["Record", "Recorder", "recorder", "new_source", "imported", "PREFIX",
-           "COMPILE_EVENTS", "UNIT", "WARMS", "TOTAL"]
+           "COMPILE_EVENTS", "UNIT", "WARMS", "TOTAL", "CPU", "IDLE"]
 
 #: prefix of every annotation this recorder writes into a profiler trace
 PREFIX = "ds:"
-#: a 51 s serving run is ~10,000 records (some ten spans a 60 ms tick)
-RING_RECORDS = 65536
+#: what the busiest run measured writes, and a quarter: the benchmark's chat
+#: cell, 136,172 records from its start to its last reader (a working tick
+#: of 4.7 ms writes a dozen, and an idle ``step()`` three, a millisecond apart
+#: while the runner waits for an arrival); ~240 bytes a record, 42 MB full
+RING_RECORDS = 172032
 #: JAX's compile events: the record each becomes, and the counter its
 #: microseconds go to, which ends in the name of the root span it fell
 #: under (``setup_backend_us_warmup``). The cache's retrieval lies inside
@@ -74,8 +86,18 @@ _NEST_SLACK_S = 1e-4
 #: closed intervals kept for one that may contain them. A model's trace holds
 #: some thousand until it ends; a process that retraces for ever holds no more
 _NEST_KEPT = 16384
+#: a unit of work (a span marked ``UNIT``) is a stall where it runs over
+#: ``_STALL_TIMES`` the typical length of the units like it (its source, name,
+#: kind and ``like``: a tick's is the rows its program ran, since a
+#: whole-shape prefill tick is 3-5 times its rung's) AND over
+#: ``_STALL_MIN_S``. The typical length is a mean over the first
+#: ``_STALL_AFTER_UNITS`` such units, none of which can be a stall, and from
+#: then on weighted ``_STALL_WEIGHT`` to the newest unit that was no stall
+_STALL_TIMES, _STALL_MIN_S, _STALL_AFTER_UNITS, _STALL_WEIGHT = 5.0, 0.25, 8, 0.125
 #: what a span's opener may say of it (``Recorder.span``)
-UNIT, WARMS, TOTAL = 1, 2, 4
+UNIT, WARMS, TOTAL, CPU = 1, 2, 4, 8
+#: the ``kind`` of a unit that found no work to do: never a stall
+IDLE = "idle"
 
 
 class Record(NamedTuple):
@@ -101,7 +123,8 @@ class Record(NamedTuple):
 
 
 class _Span:
-    __slots__ = ("_rec", "name", "uid", "source", "kind", "marks", "_ann", "_path", "_t0")
+    __slots__ = ("_rec", "name", "uid", "source", "kind", "like", "marks", "_ann", "_path", "_t0",
+                 "end", "_cpu0", "_seq0", "compiled")
 
     def __init__(self, rec: "Recorder", name: str, uid, source, marks):
         self._rec = rec
@@ -109,7 +132,10 @@ class _Span:
         self.uid = uid
         self.source = source
         self.kind = None    # set while the span is open, once the work is chosen
+        self.like = None    # a unit's: what units of its kind must share to take as long
         self.marks = marks  # what the opener said of it: see ``Recorder.span``
+        self.end = None     # ``perf_counter`` at the close
+        self._cpu0 = None   # the thread's CPU clock at the opening, of a span marked ``CPU``
 
     def __enter__(self):
         rec = self._rec
@@ -123,11 +149,20 @@ class _Span:
         rec.last_span = self.name
         self._ann = jax.profiler.TraceAnnotation(PREFIX + self.name)
         self._ann.__enter__()
+        marks = self.marks
+        if marks & UNIT:
+            self._seq0 = rec.last_seq   # what the ring holds above it when it closes is its own
+            self.compiled = False       # set by a backend compile under it
         self._t0 = time.perf_counter()
+        if marks & CPU:     # inside the wall clock's reads: the CPU counted lies inside the wall
+            self._cpu0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
-        end = time.perf_counter()
+        cpu0 = self._cpu0
+        if cpu0 is not None:
+            cpu = time.thread_time() - cpu0
+        self.end = end = time.perf_counter()
         self._ann.__exit__(*exc)
         rec = self._rec
         rec._local.stack.pop()
@@ -138,7 +173,21 @@ class _Span:
                 rec.count("setup_span_us_" + self.name, int((end - self._t0) * 1e6))
             if marks & WARMS:
                 rec._warmed.add(self.source)
+            if cpu0 is not None:
+                wall, cpu = int((end - self._t0) * 1e6), int(cpu * 1e6)
+                names = (self.name,) if self.kind is None else (self.name,
+                                                                f"{self.name}_{self.kind}")
+                for name in names:
+                    rec.count("span_wall_us_" + name, wall)
+                    rec.count("span_cpu_us_" + name, cpu)
+            if marks & UNIT:
+                rec._unit_closed(self)
         return False
+
+    @property
+    def start(self) -> float:
+        """``perf_counter`` at the opening."""
+        return self._t0
 
     @property
     def below(self) -> Tuple[str, ...]:
@@ -171,10 +220,13 @@ class Recorder:
         self.last_span: Optional[str] = None  # liveness breadcrumb (heartbeat payload)
         self._local = _Thread()
         self._warmed: Set[Optional[str]] = set()    # sources that closed a span that ``WARMS``
+        # (source, name, kind, like) of a unit -> [units that fed it, their typical seconds]
+        self._typical: Dict[tuple, list] = {}
 
     def _append(self, name, start, end, path, uid, source, kind) -> None:
         self.last_seq = seq = next(self._seq)
-        self._ring.append(Record(seq, name, start, end, path, uid, source, kind))
+        # a NamedTuple's own constructor costs twice the tuple's, a span
+        self._ring.append(tuple.__new__(Record, (seq, name, start, end, path, uid, source, kind)))
 
     def span(self, name: str, uid: Optional[int] = None, source: Optional[str] = None,
              marks: int = 0) -> _Span:
@@ -189,7 +241,17 @@ class Recorder:
           units run;
         * :data:`TOTAL`: where it is a root (an entry point of the start),
           its microseconds also go to the counter ``setup_span_us_<name>``:
-          what a start cost is still known when the ring has turned over."""
+          what a start cost is still known when the ring has turned over;
+        * :data:`CPU`: it reads the thread's CPU clock (a system call) beside
+          the wall clock and adds both to ``span_wall_us_<name>`` and
+          ``span_cpu_us_<name>`` (and, where it has a ``kind`` when it
+          closes, to ``..._<name>_<kind>``): wall well over CPU means what
+          it called waited, equal means it computed. The sums are as fine as
+          the host's CPU clock: one that steps by a scheduler tick of 10 ms
+          tells a call that computes from one that waits, and no finer.
+
+        A span marked :data:`UNIT` that runs far over the typical length of
+        its kind is counted and recorded as a stall: :meth:`_unit_closed`."""
         return _Span(self, name, uid, source, marks)
 
     def record(self, name: str, start: float, end: float, uid: Optional[int] = None,
@@ -223,16 +285,13 @@ class Recorder:
         nest.append((start, us, counter))
         self.count(counter, us)
 
-    def _is_recompile(self, stack: List[_Span]) -> bool:
-        """Whether a compile under the open spans falls in a unit of steady
-        work (the innermost span marked :data:`UNIT`) whose source has
+    def _is_recompile(self, unit: Optional[_Span]) -> bool:
+        """Whether a compile under ``unit``, the innermost open span marked
+        :data:`UNIT`, falls in steady work: its source has
         already closed a span that :data:`WARMS`, or finished a unit of the same
         name and kind. Read from the ring when a unit compiles, so that no
         tick or step pays to keep it known."""
-        for unit in reversed(stack):
-            if unit.marks & UNIT:
-                break
-        else:
+        if unit is None:
             return False
         key, known = (unit, unit.kind), self._local.unit
         if known[0] != key:     # a tick's kind is set once its work is chosen
@@ -254,7 +313,10 @@ class Recorder:
         start = end - duration
         local = self._local
         stack = local.stack
-        recompile = self._is_recompile(stack)
+        unit = next((span for span in reversed(stack) if span.marks & UNIT), None)
+        if unit is not None:
+            unit.compiled = True    # its length is the compiler's: no stall, and not typical
+        recompile = self._is_recompile(unit)
         if not stack:
             where = "compile_outside_us"
         elif recompile:
@@ -286,9 +348,66 @@ class Recorder:
         if event == _CACHE_HIT_EVENT:
             self._local.cache_hit = True
 
+    def _unit_closed(self, unit: _Span) -> None:
+        """A unit of steady work has closed: hold it against the typical
+        length of the units like it, which it then feeds unless it
+        is a stall (``_STALL_TIMES``). An idle unit and one that compiled
+        (a first step, a recompile: named already) are neither. A steady
+        unit pays one lookup and one multiply-add."""
+        kind = unit.kind
+        if kind == IDLE or unit.compiled:
+            return
+        dur = unit.end - unit._t0
+        key = (unit.source, unit.name, kind, unit.like)
+        known = self._typical.get(key)
+        if known is None:
+            self._typical[key] = [1, dur]
+            self.count("units_stalled_" + unit.name, 0)     # shown as 0, not left out
+            return
+        n, typical = known
+        if n < _STALL_AFTER_UNITS:
+            known[0] = n + 1
+            known[1] = typical + (dur - typical) / (n + 1)
+        elif dur > _STALL_TIMES * typical and dur > _STALL_MIN_S:
+            self._stalled(unit)
+        else:
+            known[1] = typical + (dur - typical) * _STALL_WEIGHT
+
+    def _stalled(self, unit: _Span) -> None:
+        """Count a stalled unit, and write one ``stall`` record over it,
+        beside it in the ring, that says under what it hung: ``kind`` is the
+        unit's kind (its name where it has none) and its longest direct
+        child, or what that child spent most of its time in
+        (``decode:device_wait``; ``prefill:launch``, inside ``dispatch``):
+        a child's longest child, while one holds over half of its parent.
+        Read from the ring, only now."""
+        start, end = unit._t0, unit.end
+        self.count("units_stalled_" + unit.name)
+        self.count("stall_us_" + unit.name, int((end - start) * 1e6))
+        below = unit.below
+        under = [r for r in itertools.islice(reversed(self._ring), self.last_seq - unit._seq0)
+                 if r.uid == unit.uid and r.source == unit.source
+                 and r.path[:len(below)] == below]
+        inner, dur = None, end - start
+        while True:
+            inside = [r for r in under if r.path == below and start <= r.start and r.end <= end]
+            longest = max(inside, key=lambda r: r.dur, default=None)
+            if longest is None or (inner is not None and longest.dur <= dur / 2):
+                break
+            inner, below = longest.name, below + (longest.name,)
+            start, end, dur = longest.start, longest.end, longest.dur
+        kind = unit.kind or unit.name
+        self._append("stall", unit._t0, unit.end, unit._path, unit.uid, unit.source,
+                     kind if inner is None else f"{kind}:{inner}")
+
     def count(self, name: str, n: int = 1) -> None:
         counters = self.counters
         counters[name] = counters.get(name, 0) + n
+
+    def gauge(self, name: str, n: int) -> None:
+        """A counter that is set, not added to: what the newest of its
+        kind says (two schedulers in a process do not sum)."""
+        self.counters[name] = n
 
     @property
     def dropped(self) -> int:

@@ -509,3 +509,287 @@ def test_a_lazy_export_of_a_module_already_imported_writes_no_record(hearing):
     assert deepspeed_tpu.DeepSpeedConfig is deepspeed_tpu.runtime.config.DeepSpeedConfig
     assert not [r for r in hearing.records() if r.name == "import"]
     assert "setup_import_us" not in hearing.counters
+
+
+# -- the CPU mark, and the stall rule (ISSUE 53) ------------------------------------------------
+
+def _spin(seconds):
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+@pytest.mark.parametrize("work, seconds, cpu_share", [(time.sleep, 0.05, (0.0, 0.3)),
+                                                       (_spin, 0.03, (0.5, 1.0))],
+                         ids=["a-sleep-waits", "a-spin-computes"])
+def test_a_span_marked_cpu_reads_cpu_time_beside_wall_time(work, seconds, cpu_share):
+    rec = trace.Recorder()
+    with rec.span("launch", 1, "s", trace.CPU) as launch:
+        launch.kind = "decode"
+        work(seconds)
+    c = rec.counters
+    wall, cpu = c["span_wall_us_launch"], c["span_cpu_us_launch"]
+    assert wall >= seconds * 1e6 * 0.9 and 0 <= cpu <= wall
+    assert cpu_share[0] * wall <= cpu <= cpu_share[1] * wall
+    # and by the kind the span was given while open
+    assert (c["span_wall_us_launch_decode"], c["span_cpu_us_launch_decode"]) == (wall, cpu)
+    (r,) = rec.records()
+    assert (r.name, r.kind) == ("launch", "decode") and r.dur * 1e6 == pytest.approx(wall, abs=2)
+
+
+def test_a_cpu_span_without_a_kind_counts_under_its_name_alone():
+    rec = trace.Recorder()
+    for _ in range(2):
+        with rec.span("launch", marks=trace.CPU):
+            pass
+    assert sorted(rec.counters) == ["span_cpu_us_launch", "span_wall_us_launch"]
+
+
+def test_every_cpu_span_reads_both_clocks_and_counts_by_the_kind_it_closes_with(clock):
+    """No span of the mark is skipped: the two counters are sums over the same spans, all of
+    them, so their ratio is the calls' own however coarse the host's CPU clock."""
+    rec = trace.Recorder()
+    for i in range(17):
+        with rec.span("launch", i, "s", trace.CPU) as launch:
+            launch.kind = "decode" if i % 8 else "prefill"      # set while the span is open
+            clock.t += 0.001
+            clock.cpu += 0.0005
+    assert clock.cpu_reads == 2 * 17
+    assert rec.counters == {"span_wall_us_launch": 17000, "span_cpu_us_launch": 8500,
+                            "span_wall_us_launch_prefill": 3000, "span_cpu_us_launch_prefill": 1500,
+                            "span_wall_us_launch_decode": 14000, "span_cpu_us_launch_decode": 7000}
+    assert len(rec.records()) == 17
+
+
+def test_a_cpu_clock_that_reads_over_the_wall_clock_is_counted_as_it_reads(clock):
+    """A host whose thread CPU clock steps by a scheduler tick charges a 3 ms call 10 ms or
+    nothing: the counters carry what was read, and a reader sees a share over 100%."""
+    rec = trace.Recorder()
+    for i in range(4):
+        with rec.span("launch", i, "s", trace.CPU):
+            clock.t += 0.003
+            clock.cpu += 0.01 if i % 2 else 0.0
+    assert (rec.counters["span_wall_us_launch"], rec.counters["span_cpu_us_launch"]) == (12000, 20000)
+
+
+class _Clock:
+    """``time`` as the recorder reads it, moved by hand."""
+
+    def __init__(self):
+        self.t, self.cpu, self.cpu_reads = 100.0, 0.0, 0
+
+    def perf_counter(self):
+        return self.t
+
+    def thread_time(self):
+        self.cpu_reads += 1
+        return self.cpu
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    hand = _Clock()
+    monkeypatch.setattr(trace, "time", hand)
+    return hand
+
+
+def test_an_unmarked_span_reads_no_thread_time(clock):
+    rec = trace.Recorder()
+    with rec.span("tick", 1, "s", trace.UNIT):
+        with rec.span("dispatch", 1, "s"):
+            pass
+    assert clock.cpu_reads == 0
+    with rec.span("launch", 1, "s", trace.CPU):
+        pass
+    assert clock.cpu_reads == 2
+
+
+def _unit(rec, clock, uid, children, kind="decode", name="tick", source="s", inside=None,
+          like=None):
+    """One unit of work on the hand-moved clock: ``children`` are
+    ``(name, seconds)`` or ``(name, seconds, [(grandchild, seconds), ...])``."""
+    with rec.span(name, uid, source, trace.UNIT) as unit:
+        unit.kind, unit.like = kind, like
+        for child, seconds, *inner in children:
+            with rec.span(child, uid, source):
+                for grandchild, part in (inner[0] if inner else ()):
+                    with rec.span(grandchild, uid, source):
+                        clock.t += part
+                    seconds -= part
+                clock.t += seconds
+                if inside is not None:
+                    inside()
+        clock.t += 1e-4     # the unit's own time
+
+
+def _steady(rec, clock, n, seconds=0.01, kind="decode", first=1, **kw):
+    for uid in range(first, first + n):
+        _unit(rec, clock, uid, [("dispatch", seconds * 0.25), ("device_wait", seconds * 0.75)],
+              kind, **kw)
+    return first + n
+
+
+def _stalls(rec):
+    return [(r.uid, r.kind) for r in rec.records() if r.name == "stall"]
+
+
+@pytest.mark.parametrize("typical, long, stalled", [
+    (0.01, 0.2, False),      # twenty times the typical tick, and under a quarter of a second
+    (0.1, 0.45, False),      # over a quarter of a second, and under five times
+    (0.01, 0.3, True),
+    (0.1, 0.51, True),
+], ids=["times-alone", "seconds-alone", "both", "both-barely"])
+def test_a_stall_is_five_times_the_typical_unit_and_a_quarter_second(clock, typical, long, stalled):
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, 12, typical)
+    assert rec.counters["units_stalled_tick"] == 0      # shown as 0 from the first unit
+    _unit(rec, clock, uid, [("dispatch", typical * 0.25), ("device_wait", long)])
+    assert rec.counters["units_stalled_tick"] == int(stalled)
+    assert _stalls(rec) == ([(uid, "decode:device_wait")] if stalled else [])
+    if stalled:
+        assert rec.counters["stall_us_tick"] == pytest.approx((long + typical * 0.25) * 1e6, rel=0.01)
+        (stall,) = [r for r in rec.records() if r.name == "stall"]
+        (tick,) = [r for r in rec.records() if r.name == "tick" and r.uid == uid]
+        # one record over the unit, beside it: not a child, so no reader of phases sums it
+        assert (stall.start, stall.end, stall.path, stall.source) == (tick.start, tick.end, (), "s")
+    else:
+        assert "stall_us_tick" not in rec.counters
+
+
+@pytest.mark.parametrize("before", [0, 3, 7])
+def test_no_unit_is_a_stall_before_eight_of_its_kind(clock, before):
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, before)
+    _unit(rec, clock, uid, [("device_wait", 2.0)])
+    assert rec.counters["units_stalled_tick"] == 0 and _stalls(rec) == []
+    # another kind of the same scheduler, and another scheduler, start their own count
+    uid = _steady(rec, clock, 8, first=uid + 1)
+    _unit(rec, clock, uid, [("device_wait", 2.0)], kind="prefill")
+    _unit(rec, clock, uid + 1, [("device_wait", 2.0)], source="other")
+    assert rec.counters["units_stalled_tick"] == 0
+
+
+def test_a_typical_length_is_kept_by_source_name_and_kind(clock):
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, 10, 0.01, "decode")
+    uid = _steady(rec, clock, 10, 0.4, "prefill", first=uid)        # long by nature: none
+    uid = _steady(rec, clock, 10, 0.5, name="train_batch", source="engine#0", kind=None, first=uid)
+    assert rec.counters == {"units_stalled_tick": 0, "units_stalled_train_batch": 0}
+    _unit(rec, clock, uid, [("device_wait", 0.4)], "decode")
+    _unit(rec, clock, uid + 1, [("timer_sync", 3.0), ("device_wait", 0.5)], None,
+          name="train_batch", source="engine#0")
+    assert rec.counters["units_stalled_tick"] == 1 and rec.counters["units_stalled_train_batch"] == 1
+    # a unit without a kind is filed under its name
+    assert _stalls(rec) == [(uid, "decode:device_wait"), (uid + 1, "train_batch:timer_sync")]
+
+
+def test_units_of_a_kind_are_held_against_the_units_like_them(clock):
+    """reason-sat on the chip, the first run of this rule (PR 53): eight prefill ticks on
+    the 16-row rung at ~50 ms, then the pre-roll's whole-shape ticks at 251-276 ms, two of
+    them counted as stalls. A tick's ``like`` is the rows its program ran."""
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, 12, 0.05, "prefill", like=16)
+    for seconds in (0.276, 0.251, 0.228, 0.23, 0.229, 0.228, 0.231, 0.228, 0.23):
+        uid = _steady(rec, clock, 1, seconds, "prefill", first=uid, like=64)
+    assert rec.counters["units_stalled_tick"] == 0 and _stalls(rec) == []
+    _unit(rec, clock, uid, [("device_wait", 1.2)], "prefill", like=64)      # and a real one is seen
+    _unit(rec, clock, uid + 1, [("device_wait", 0.26)], "prefill", like=16)
+    assert _stalls(rec) == [(uid, "prefill:device_wait"), (uid + 1, "prefill:device_wait")]
+    # without it, the first whole-shape tick reads as the chip's did
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, 12, 0.05, "prefill")
+    _steady(rec, clock, 1, 0.276, "prefill", first=uid)
+    assert rec.counters["units_stalled_tick"] == 1
+
+
+def test_an_idle_unit_is_never_a_stall_and_feeds_nothing(clock):
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, 10, 0.001, trace.IDLE)
+    _unit(rec, clock, uid, [("admit", 5.0)], trace.IDLE)
+    assert rec.counters == {} and _stalls(rec) == [] and rec._typical == {}
+
+
+def test_a_unit_that_compiled_is_a_recompile_and_not_a_stall(clock):
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, 10)
+
+    def compile_():
+        rec._on_compile_duration("/jax/core/compile/backend_compile_duration", 1.5,
+                                 fun_name="jit(decode)")
+
+    _unit(rec, clock, uid, [("dispatch", 2.0)], inside=compile_)
+    assert rec.counters["units_stalled_tick"] == 0 and _stalls(rec) == []
+    assert rec.counters["recompiles_in_units"] == 1         # already named
+    assert [r.uid for r in rec.records() if r.name == "recompile"] == [uid]
+    # and it has not made the typical tick longer: the next long one is a stall
+    _unit(rec, clock, uid + 1, [("dispatch", 0.3)])
+    assert _stalls(rec) == [(uid + 1, "decode:dispatch")]
+
+
+@pytest.mark.parametrize("children, kind", [
+    ([("admit", 0.01), ("dispatch", 0.02), ("device_wait", 0.9), ("commit", 0.05)],
+     "decode:device_wait"),
+    ([("admit", 0.6), ("dispatch", 0.02), ("device_wait", 0.5)], "decode:admit"),
+    # what the longest child spent most of its time in is named in its place
+    ([("dispatch", 0.8, [("launch", 0.7), ("account", 0.05)]), ("device_wait", 0.1)],
+     "decode:launch"),
+    # unless no one child of it holds half of it
+    ([("dispatch", 0.8, [("launch", 0.3), ("account", 0.3)]), ("device_wait", 0.1)],
+     "decode:dispatch"),
+    ([], "decode"),
+], ids=["device-wait", "admit", "launch-inside-dispatch", "dispatch-itself", "no-children"])
+def test_a_stall_record_names_the_units_longest_child(clock, children, kind):
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, 9)
+    if not children:
+        with rec.span("tick", uid, "s", trace.UNIT) as unit:
+            unit.kind = "decode"
+            clock.t += 1.0
+    else:
+        # another scheduler's spans between them, and a back-dated record under the unit
+        with rec.span("tick", 77, "other", trace.UNIT):
+            _unit(rec, clock, uid, children,
+                  inside=lambda: rec.record("queue_wait", 0.0, 50.0, uid=uid, source="s"))
+    assert _stalls(rec)[-1] == (uid, kind)
+
+
+def test_a_steady_stream_writes_no_stall_and_follows_a_slow_drift(clock):
+    rec = trace.Recorder()
+    seconds, uid = 0.05, 1
+    for _ in range(400):                     # 1% longer every tick: sixty times as long at the end
+        uid = _steady(rec, clock, 1, seconds, first=uid)
+        seconds *= 1.01
+    assert seconds > 2.5 and rec.counters["units_stalled_tick"] == 0 and _stalls(rec) == []
+    # whole-shape prefill ticks among their rung's, 3.5 times as long: none
+    for i in range(40):
+        uid = _steady(rec, clock, 1, 0.35 if i % 4 == 0 else 0.1, "prefill", first=uid)
+    assert rec.counters["units_stalled_tick"] == 0
+
+
+def test_a_stall_does_not_feed_the_typical_length(clock):
+    rec = trace.Recorder()
+    uid = _steady(rec, clock, 10)
+    for _ in range(5):                      # were they fed, the third would pass for typical
+        _unit(rec, clock, uid, [("device_wait", 0.4)])
+        uid += 1
+    assert rec.counters["units_stalled_tick"] == 5
+    assert rec.counters["stall_us_tick"] == pytest.approx(5 * 0.4001e6, rel=0.01)
+
+
+def test_a_stall_survives_a_small_ring_through_its_counters(clock):
+    rec = trace.Recorder(capacity=4)
+    uid = _steady(rec, clock, 10)
+    _unit(rec, clock, uid, [("dispatch", 0.01), ("device_wait", 1.0)])
+    assert [r.name for r in rec.records()] == ["dispatch", "device_wait", "tick", "stall"]
+    _steady(rec, clock, 5, first=uid + 1)
+    assert _stalls(rec) == [] and rec.dropped > 30          # the ring has turned over
+    assert rec.counters["units_stalled_tick"] == 1
+    assert rec.counters["stall_us_tick"] == pytest.approx(1.0101e6, rel=0.01)
+
+
+def test_a_gauge_is_set_and_not_added():
+    rec = trace.Recorder()
+    rec.gauge("program_operand_leaves", 430)
+    rec.gauge("program_operand_leaves", 42)
+    rec.count("ticks", 2)
+    assert rec.counters == {"program_operand_leaves": 42, "ticks": 2}
