@@ -1,15 +1,15 @@
-"""The decode tick's share of its roofline: the least time the chip could
-take for the mean decode tick (``lib/dots3_note_ticks.py``,
+"""The decode tick's share of its roofline: the least time the chip could take for
+the mean decode tick (``lib/dots3_note_ticks.py``,
 ``lib/opcounts_dots3_note.py``: for the tokens it was fed, every projection,
 the index scores of every live pair and the fed slots' live index keys read
-once a full layer, the absorbed attention of the CHOSEN pairs and their
-latents alone, the window layers' attention over their windows, the held
-experts that got a row and the rows routed here, router, shared expert,
-dense layer, the head's slice) over the p50 of the decode ticks'
-``device_wait`` span. The program's kernel reads every live latent and masks
-the unchosen, and XLA's window step reads the whole ring: both are owed less
-than they do, and the share says so. Since PR 35 the span leaves the host's
-share of the tick out, so the share reads high by H/D (PERF.md section 3)."""
+once a full layer, the absorbed attention of the CHOSEN pairs and their latents
+alone, the window layers' attention over their windows, the held experts that
+got a row and the rows routed here, router, shared expert, dense layer, the
+head's slice) over the p50 of the decode ticks' whole ``tick`` span. The
+program's kernel reads every live latent and masks the unchosen, and XLA's
+window step reads the whole ring: both are owed less than they do, and the
+share says so. The span holds the host's share of the tick too, so the share
+cannot pass 100 however short a program grows under an unchanged host."""
 
 from benchmarks.lib import dots3_note_ticks
 

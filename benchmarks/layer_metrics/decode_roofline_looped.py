@@ -3,9 +3,10 @@ time the chip could take for the mean decode tick (``lib/ouro_ticks.py``,
 ``lib/opcounts_ouro.py``: the tokens it was fed through every matrix once a
 pass, the stack's weights streamed once a PASS and the head once, the fed
 slots' live int8 rows once a pass a layer) over the p50 of the decode ticks'
-``device_wait`` span. An earlier output line names the bound that applies and
-the weight bytes a tick streams. The span leaves out what the dispatch
-overlapped, so the share reads a little high."""
+whole ``tick`` span. An earlier output line names the bound that applies and
+the weight bytes a tick streams. The span holds the host's share of the tick
+too, so the share cannot pass 100 however short a program grows under an
+unchanged host."""
 
 from benchmarks.lib import ouro_ticks
 

@@ -3,7 +3,7 @@
 every real token's projections, routed rows and shared expert, each query's
 scores over the positions at or before it on full layers and inside its window
 on sliding ones, the head once a sequence) over the p50 of the prefill ticks'
-``device_wait`` span. What the fixed-shape program computes for padding and for
+whole ``tick`` span. What the fixed-shape program computes for padding and for
 sequences that only fill its rung is not owed."""
 
 from benchmarks.lib import laguna_ticks

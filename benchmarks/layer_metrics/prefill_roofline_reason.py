@@ -1,7 +1,7 @@
-"""The prefill tick's share of its roofline: as ``decode_roofline_reason``
-for the mean prefill tick (the prompt tokens it was fed, not the slots x
-chunk positions the program computes) over the p50 of the prefill ticks'
-``device_wait`` span."""
+"""The prefill tick's share of its roofline: as ``decode_roofline_reason`` for
+the mean prefill tick (the prompt tokens it was fed, not the slots x chunk
+positions the program computes) over the p50 of the prefill ticks' whole
+``tick`` span."""
 
 from benchmarks.lib import nemotron_h_ticks
 

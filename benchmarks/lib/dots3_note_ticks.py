@@ -1,6 +1,6 @@
 """What the dots3-note cell's roofline readers share: the least time the chip
 could take for the mean tick of one kind (``lib/opcounts_dots3_note.py``)
-against the p50 of that kind's ``device_wait`` span, and the least time of
+against the p50 of that kind's whole ``tick`` span, and the least time of
 each new kernel over the traced slice. A tick's shape is what it was *fed*
 (``lib/nemotron_h_ticks.py`` ``tick_shape``), not what its fixed-shape
 program computes.
@@ -19,7 +19,7 @@ shares take the same counts tick by tick from the ring
 """
 
 from benchmarks.lib import opcounts_dots3_note as ops
-from benchmarks.lib import program_spans, stats
+from benchmarks.lib import program_spans
 from benchmarks.lib.nemotron_h_ticks import tick_shape, traced_ticks  # noqa: F401
 
 
@@ -52,7 +52,9 @@ def tick_least_ms(config, shape, peaks, pairs=None):
 
 def tick_roofline_pct(ctx, kind):
     """100 x the least time of the mean ``kind`` tick over the p50 of that
-    kind's ``device_wait`` span; logs both and the bound that applies."""
+    kind's whole ``tick`` span (``program_spans.tick_ms_p50``: the host's
+    share included, so the share cannot pass 100); logs both and the bound
+    that applies."""
     from benchmarks.lib import harness
 
     if ctx["peaks"] is None:
@@ -60,10 +62,8 @@ def tick_roofline_pct(ctx, kind):
     config = ctx["cell"].config
     program = program_spans.ring()[1]
     shape = tick_shape(kind, program, ctx["counters"], config["serve"])
-    found = program_spans.serving()
-    waited = stats.percentile([t["phases"].get("device_wait", 0.0)
-                               for t in (found["ticks"] if found else []) if t["kind"] == kind], 50)
-    if shape is None or not waited:
+    tick_ms = program_spans.tick_ms_p50(kind)
+    if shape is None or not tick_ms:
         return None
     pairs = counted_pairs(config, kind, program, shape)
     ends = program.get(f"latent_positions_live_{kind}")
@@ -74,11 +74,11 @@ def tick_roofline_pct(ctx, kind):
         shape["kv_positions"] = ends / (ops.layers(config, "F") * shape["ticks"])
     least, bound, flops, nbytes = tick_least_ms(config, shape, ctx["peaks"], pairs)
     harness.log(tick_roofline={"kind": kind, "bound": bound, "least_ms": least,
-                               "device_wait_ms_p50": waited, "flops": flops, "bytes": nbytes,
+                               "tick_ms_p50": tick_ms, "flops": flops, "bytes": nbytes,
                                "shape": shape, "pairs_a_layer": pairs,
                                "touched_if_even": ops.layers(config, "E")
                                * ops.experts_touched(config, shape["tokens"])})
-    return 100.0 * least / waited
+    return 100.0 * least / tick_ms
 
 
 def moe_kernels_least_s(config, program, run, peaks, ticks):

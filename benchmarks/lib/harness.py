@@ -228,6 +228,21 @@ def read_layer_metrics(cell, ctx):
     return out
 
 
+def _no_device_operation(counters):
+    """Why a traced slice held nothing, as far as the runner's counters say:
+    a serving runner traces the END of its window, which a cell whose
+    backlog a fast server has emptied spends idle."""
+    said = "the traced window holds no device operation"
+    queued = counters.get("queue_at_close")
+    if queued is None:
+        return said
+    if queued == 0:
+        return said + (": the runner's queue was empty at the window's close, so a cell that "
+                       "is a backlog had run out of work before the traced slice (its mix "
+                       "offers this server too little)")
+    return said + f", though the runner's queue held {queued} requests at the window's close"
+
+
 def run_cell(root, manifest, workload, seed, seconds, trace, t0=None, require_tpu=True):
     """Run one cell once and return the object of the last line."""
     setup = Setup(t0 if t0 is not None else time.time())
@@ -252,7 +267,7 @@ def run_cell(root, manifest, workload, seed, seconds, trace, t0=None, require_tp
         return line
     reduced = env.tracer.reduce(getattr(cell.family, "op_label", trace_lib.op_family))
     if reduced is None and require_tpu:
-        raise BenchmarkError("the traced window holds no device operation")
+        raise BenchmarkError(_no_device_operation(result.get("counters", {})))
     ctx = {"cell": cell, "chips": cell.chips, "peaks": peaks, "trace": reduced,
            "spans": result.get("spans", {}), "counters": result.get("counters", {})}
     line["metrics"] = read_layer_metrics(cell, ctx)

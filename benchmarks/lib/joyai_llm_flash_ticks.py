@@ -1,7 +1,7 @@
 """What the JoyAI-LLM-Flash cell's roofline readers share: the least time
 the chip could take for the mean tick of one kind
 (``lib/opcounts_joyai_llm_flash.py``) against the p50 of that kind's
-``device_wait`` span. A tick's shape is what it was *fed*
+whole ``tick`` span. A tick's shape is what it was *fed*
 (``lib/nemotron_h_ticks.py`` ``tick_shape``: the program's and the runner's
 counters, the held route's device-side rows and experts touched among them;
 that file says which inputs are the program's own report), not what its
@@ -9,7 +9,7 @@ fixed-shape program computes, nor what its attention reads of dead positions.
 """
 
 from benchmarks.lib import opcounts_joyai_llm_flash as ops
-from benchmarks.lib import program_spans, stats
+from benchmarks.lib import program_spans
 from benchmarks.lib.nemotron_h_ticks import tick_shape, traced_ticks  # noqa: F401
 
 
@@ -30,24 +30,24 @@ def tick_least_ms(config, shape, peaks):
 
 def tick_roofline_pct(ctx, kind):
     """100 x the least time of the mean ``kind`` tick over the p50 of that
-    kind's ``device_wait`` span; logs both and the bound that applies."""
+    kind's whole ``tick`` span (``program_spans.tick_ms_p50``: the host's
+    share included, so the share cannot pass 100); logs both and the bound
+    that applies."""
     from benchmarks.lib import harness
 
     if ctx["peaks"] is None:
         return None
     config = ctx["cell"].config
     shape = tick_shape(kind, program_spans.ring()[1], ctx["counters"], config["serve"])
-    found = program_spans.serving()
-    waited = stats.percentile([t["phases"].get("device_wait", 0.0)
-                               for t in (found["ticks"] if found else []) if t["kind"] == kind], 50)
-    if shape is None or not waited:
+    tick_ms = program_spans.tick_ms_p50(kind)
+    if shape is None or not tick_ms:
         return None
     least, bound, flops, nbytes = tick_least_ms(config, shape, ctx["peaks"])
     harness.log(tick_roofline={"kind": kind, "bound": bound, "least_ms": least,
-                               "device_wait_ms_p50": waited, "flops": flops, "bytes": nbytes,
+                               "tick_ms_p50": tick_ms, "flops": flops, "bytes": nbytes,
                                "shape": shape, "touched_if_even": ops.layers(config, "E")
                                * ops.experts_touched(config, shape["tokens"])})
-    return 100.0 * least / waited
+    return 100.0 * least / tick_ms
 
 
 def moe_kernels_least_s(config, program, run, peaks, ticks):

@@ -10,7 +10,7 @@ runner's over the untraced window), so the shape is a mean over ticks.
 """
 
 from benchmarks.lib import opcounts_olmoe as ops
-from benchmarks.lib import program_spans, stats
+from benchmarks.lib import program_spans
 
 
 def tick_shape(kind, program, run, serve):
@@ -48,23 +48,23 @@ def tick_least_ms(config, shape, peaks):
 
 def tick_roofline_pct(ctx, kind):
     """100 x the least time of the mean ``kind`` tick over the p50 of that
-    kind's ``device_wait`` span; logs both and the bound that applies."""
+    kind's whole ``tick`` span (``program_spans.tick_ms_p50``: the host's
+    share included, so the share cannot pass 100); logs both and the bound
+    that applies."""
     from benchmarks.lib import harness
 
     if ctx["peaks"] is None:
         return None
     config = ctx["cell"].config
     shape = tick_shape(kind, program_spans.ring()[1], ctx["counters"], config["serve"])
-    found = program_spans.serving()
-    waited = stats.percentile([t["phases"].get("device_wait", 0.0)
-                               for t in (found["ticks"] if found else []) if t["kind"] == kind], 50)
-    if shape is None or not waited:
+    tick_ms = program_spans.tick_ms_p50(kind)
+    if shape is None or not tick_ms:
         return None
     least, bound, flops, nbytes = tick_least_ms(config, shape, ctx["peaks"])
     harness.log(tick_roofline={"kind": kind, "bound": bound, "least_ms": least,
-                               "device_wait_ms_p50": waited, "flops": flops, "bytes": nbytes,
+                               "tick_ms_p50": tick_ms, "flops": flops, "bytes": nbytes,
                                "shape": shape})
-    return 100.0 * least / waited
+    return 100.0 * least / tick_ms
 
 
 def traced_ticks(window_s):

@@ -141,6 +141,19 @@ def device_wait_ms_p50(kind):
     return stats.percentile(waits, 50)
 
 
+def tick_ms_p50(kind):
+    """The whole ``tick`` span of the steady ticks of one kind (its host
+    time and its ``device_wait`` together), p50; None without such ticks.
+    What a tick roofline divides by: where a program is always in flight the
+    ticks follow each other as the programs do, so a tick's length is its
+    program's, and a least time over it cannot pass 100% however short the
+    host's share of the tick grows (``device_wait`` alone shrinks with the
+    program under an unchanged host, and the share then passes 100)."""
+    found = serving()
+    return stats.percentile([t["tick_ms"] for t in (found["ticks"] if found else [])
+                             if t["kind"] == kind], 50)
+
+
 def request_wait_ms(name, p):
     """A percentile of ``queue_wait`` (arrival to admission) or
     ``prefill_wait`` (admission to first token) over the steady requests."""

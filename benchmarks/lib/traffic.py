@@ -3,17 +3,22 @@
 into the inputs of a run. The program under test receives only what is
 generated here.
 
-Arrivals are a process of the mix: ``poisson`` draws every inter-arrival
-gap independently from the exponential of the rate, so a window holds
-bursts, lulls and a count that differs from seed to seed, as an open
-service sees them; ``all_at_zero`` is a backlog of ``count`` requests.
+Arrivals are a process of the mix: ``all_at_zero`` is a backlog of
+``count`` requests; ``poisson_fixed_count`` is a Poisson process conditioned
+on its count (given how many arrivals an interval holds, a Poisson process
+puts them there uniformly and independently): every period of ``block /
+rate_rps`` seconds holds exactly ``block`` arrivals, at times the seed draws
+uniformly inside it, so a window holds bursts and lulls as an open service
+sees them and the same number of requests under every seed.
 
 Lengths are *not* drawn independently, and every mix's file and ``why``
 says so: each run of ``block`` requests holds the same ``block`` (prompt,
 output) pairs under every seed, the quantiles of the two distributions at
 (i + 0.5) / block, paired by a fixed stride, and the seed decides their
 order. Two seeds differ in which request is long and when it comes, not in
-how many tokens a block of requests asks for. Token ids are drawn freely.
+how many tokens a block of requests asks for; under ``poisson_fixed_count``
+a block of lengths is a period of arrivals, so not in how many tokens a
+period asks for either. Token ids are drawn freely.
 """
 
 import math
@@ -47,13 +52,22 @@ def length_pairs(traffic, n, block, rng):
     return prompt[order], output[order]
 
 
-def poisson_arrivals(rate, horizon_s, rng):
-    """Arrival times of a Poisson process over ``horizon_s`` seconds:
-    independent exponential gaps, the first arrival at time 0."""
-    due = np.zeros(1)
-    while due[-1] <= horizon_s:
-        due = np.concatenate([due, due[-1] + np.cumsum(rng.exponential(1.0 / rate, 64))])
-    return due[due <= horizon_s]
+def fixed_count_arrivals(rate, block, preroll_s, horizon_s, rng):
+    """Arrival times of a Poisson process conditioned on its count, as
+    ``[(times, size of the blocks their lengths come in)]``. Periods of
+    ``block / rate`` seconds begin where the window opens, at ``preroll_s``,
+    and go on past ``horizon_s``; each holds exactly ``block`` arrivals at
+    uniform times. The pre-roll before them holds its own ``round(rate *
+    preroll_s)``, one block of that size: the same pairs under every seed,
+    and the periods' blocks begin with the periods. The periods are drawn
+    first and as fractions of a period, so one seed at two rates gives the
+    same bursts and lulls, compressed."""
+    period = block / rate
+    n_periods = max(1, math.ceil((horizon_s - preroll_s) / period))
+    inside = np.sort(rng.random((n_periods, block)), axis=1)
+    periods = preroll_s + period * (np.arange(n_periods)[:, None] + inside).ravel()
+    head = preroll_s * np.sort(rng.random(int(round(rate * preroll_s))))
+    return [(head, len(head)), (periods, block)]
 
 
 def serve_schedule(traffic, vocab_size, seed, horizon_s):
@@ -65,13 +79,17 @@ def serve_schedule(traffic, vocab_size, seed, horizon_s):
     block = int(traffic["block"])
     arrivals = traffic["arrivals"]
     if arrivals["process"] == "all_at_zero":
-        due = np.zeros(int(arrivals["count"]))
-    elif arrivals["process"] == "poisson":
-        due = poisson_arrivals(float(arrivals["rate_rps"]), horizon_s, rng)
+        groups = [(np.zeros(int(arrivals["count"])), block)]
+    elif arrivals["process"] == "poisson_fixed_count":
+        groups = fixed_count_arrivals(float(arrivals["rate_rps"]), block,
+                                      float(traffic["preroll_s"]), horizon_s, rng)
     else:
         raise ValueError(f"unknown arrival process {arrivals['process']!r}")
+    groups = [(times, size) for times, size in groups if len(times)]
+    due = np.concatenate([times for times, _ in groups])
     n = len(due)
-    prompts, outputs = length_pairs(traffic, n, block, rng)
+    pairs = [length_pairs(traffic, len(times), size, rng) for times, size in groups]
+    prompts, outputs = (np.concatenate(part) for part in zip(*pairs))
     prompts, outputs = np.rint(prompts).astype(int), np.rint(outputs).astype(int)
     max_total = int(traffic["max_total"])
     prompts = np.minimum(prompts, max_total - 1)

@@ -29,6 +29,9 @@ def _server(cell, env, family):
     from deepspeed_tpu.parallel.topology import MeshTopology
 
     dep = cell.config["serve"]
+    # the queue's limit is the deployment's; a configuration without the key
+    # keeps the server's default
+    limit = {"max_queue": int(dep["max_queue"])} if "max_queue" in dep else {}
     dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[dep["dtype"]]
     model = family.model(cell.config, dep)
 
@@ -43,7 +46,7 @@ def _server(cell, env, family):
     sched = ContinuousBatchingScheduler(engine, ServingConfig(
         slots=dep["slots"], page_size=dep["page_size"], kv_quant=dep["kv_quant"],
         prefill_chunk=dep["prefill_chunk"], prefill_interleave=dep["prefill_interleave"],
-        prefix_cache=dep["prefix_cache"]),
+        prefix_cache=dep["prefix_cache"], **limit),
         clock=time.perf_counter)
     return engine, sched
 
@@ -109,6 +112,7 @@ def run(cell, env):
     preroll_s, drain_s = float(traffic["preroll_s"]), float(traffic["drain_s"])
     schedule = collections.deque(serve_schedule(
         traffic, cell.config["vocab_size"], env.seed, preroll_s + env.seconds + drain_s + 1.0))
+    scheduled = len(schedule)
     # a traced run traces the last ``trace_seconds`` of the window; its
     # host-clock numbers come from the part before, which the profiler's
     # start and stop (seconds each) do not touch
@@ -170,6 +174,7 @@ def run(cell, env):
                 queue_mid = len(sched.queue)
         t_close = now
         progress_close = progress()
+        queue_close = len(sched.queue)
         tracer.stop()       # every tick ends with its tokens read back: the device is done
     t_host = t_close if t_traced is None else t_traced   # host-clock numbers end here
     server_peak = harness.memory_peak_bytes(env.devices)   # before the reference runs
@@ -213,7 +218,7 @@ def run(cell, env):
     harness.log(window_s=window_s, ticks=dict(collections.Counter(t[0] for t in ticks)),
                 requests_due_in_window=len(waiting), completed_in_window=len(completed),
                 completed_per_s=len(completed) / window_s, offered_per_s=len(waiting) / window_s,
-                queue_at_middle=queue_mid, queue_at_close=len(sched.queue),
+                queue_at_middle=queue_mid, queue_at_close=queue_close, requests_scheduled=scheduled,
                 kv_live_pct=100.0 * sum(t[3] for t in ticks) / max(1, len(ticks)) / (
                     sched.slots * int(cell.config["serve"]["max_out_tokens"])),
                 in_flight_at_close=len(sched.in_flight),
@@ -227,6 +232,7 @@ def run(cell, env):
              "prefill_tick_ms": [t[1] * 1e3 for t in measured if t[0] == "prefill"],
              "gen_late_ms": late, "ttft_ms": ttfts_untraced}
     counters = {"recompiles_in_window": len(in_window),
+                "queue_at_close": queue_close, "requests_scheduled": scheduled,
                 "prefill_ticks": by_kind["prefill"], "decode_ticks": by_kind["decode"],
                 "slot_ticks_busy": sum(t[2] for t in working),
                 "slot_ticks": len(working) * sched.slots,

@@ -83,7 +83,8 @@ def test_the_real_cell_is_in_the_manifest_as_the_issue_gives_it():
     cell = harness.Cell(harness.REPO_ROOT, manifest, LIKE)
     assert cell.chips == 1 and cell.config["family"] == "olmoe"
     mix = cell.traffic
-    assert mix["arrivals"] == {"process": "all_at_zero", "count": 512}
+    assert mix["arrivals"] == {"process": "all_at_zero", "count": 2048}
+    assert cell.config["serve"]["max_queue"] == 2048
     assert mix["prompt_len"] == {"dist": "uniform", "min": 512, "max": 1536}
     assert mix["output_len"] == {"dist": "uniform", "min": 64, "max": 256}
     assert (mix["max_total"], mix["block"], mix["drain_s"], mix["trace_seconds"]) == (2048, 32, 0, 4)

@@ -70,9 +70,49 @@ def test_train_cells_report_steps_and_serve_cells_requests(lines):
             "recompiles_in_window_chat"} <= set(chat)
     docs = lines["t-docs", 1]["metrics"]
     assert {"slot_occupancy_pct", "prefill_tick_ms_p50", "kv_live_pct_sat",
-            "recompiles_in_window_sat"} <= set(docs)
+            "recompiles_in_window_sat", "backlog_left_pct_sat"} <= set(docs)
+    assert 0 <= docs["backlog_left_pct_sat"]["value"] < 100
     assert 0 < docs["slot_occupancy_pct"]["value"] <= 100
     assert 0 < docs["kv_live_pct_sat"]["value"] <= 100
+
+
+@pytest.mark.parametrize("given, want", [({"max_queue": 7}, 7), ({}, 1024)])
+def test_the_server_takes_the_queue_limit_of_its_deployment(bench_copy, monkeypatch, given, want):
+    """``serve.max_queue`` of the configuration reaches ``ServingConfig``; a
+    configuration without the key keeps the server's default."""
+    import jax
+
+    import deepspeed_tpu.inference.serving as serving
+
+    root, manifest = bench_copy
+    cell = harness.Cell(root, manifest, "t-docs")
+    assert "max_queue" not in cell.config["serve"]
+    cell.config = dict(cell.config, serve=dict(cell.config["serve"], **given))
+    built = {}
+
+    class Scheduler:
+        def __init__(self, engine, config, clock):
+            built["config"] = config
+
+    monkeypatch.setattr(serving, "ContinuousBatchingScheduler", Scheduler)
+    env = harness.Env(SEED, 0.5, 0, None, jax.devices()[:1], None)
+    cell.runner._server(cell, env, cell.family)
+    assert built["config"].max_queue == want
+    assert built["config"].slots == cell.config["serve"]["slots"]
+
+
+@pytest.mark.parametrize("counters, want", [
+    ({"queue_at_close": 105, "requests_scheduled": 512}, 100 * 105 / 512),
+    ({"queue_at_close": 3680, "requests_scheduled": 4096}, 100 * 3680 / 4096),
+    ({"queue_at_close": 0, "requests_scheduled": 512}, 0.0),      # ran dry: said, not left out
+    ({"requests_scheduled": 512}, None), ({}, None)])
+def test_backlog_left_reads_the_queue_at_the_close(counters, want):
+    from benchmarks.lib import reducers
+
+    spec = harness.load_json(harness.REPO_ROOT, "benchmarks", "layer_metrics",
+                             "backlog_left_pct_sat.json")
+    value = getattr(reducers, spec["reducer"])({"counters": counters}, **spec["args"])
+    assert value == (pytest.approx(want) if want is not None else None)
 
 
 def test_a_later_pr_adds_a_cell_a_mix_a_configuration_and_a_metric_as_files(bench_copy, tmp_path):
