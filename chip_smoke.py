@@ -444,8 +444,9 @@ def kernels_phase(batch=4, seq=1024, heads=16, head_dim=64, width=1024):
     
     # the serving cache's per-slot write, int8 codes and bf16 scales, one
     # token, a chunk, and a block that straddles a window: in place (what a
-    # TPU runs) against the scatter. A pure move of bytes into their lanes,
-    # so exact; one slot is parked
+    # TPU runs: ``ops/pallas/pool_write.py``'s kernel at these shapes) against
+    # the scatter. A pure move of bytes into their lanes, so exact; one slot
+    # is parked
     from deepspeed_tpu.models.common import _append_in_place
     pool = jnp.asarray(rng.integers(-127, 128, (slots, heads, head_dim, seq)), jnp.int8)
     scales = normal(slots, heads, seq)
@@ -455,7 +456,7 @@ def kernels_phase(batch=4, seq=1024, heads=16, head_dim=64, width=1024):
         new_scales = normal(slots, length, heads)
         put = jnp.arange(slots)[:, None], ..., at[:, None] + jnp.arange(length)[None, :]
         check(f"kv_append_{length}",
-              jax.jit(lambda *a: _append_in_place(a[:2], a[2:4], a[4]))(pool, scales, new, new_scales, at),
+              _run_kernel(lambda *a: _append_in_place(a[:2], a[2:4], a[4]), pool, scales, new, new_scales, at),
               [pool.at[put].set(new), scales.at[put].set(new_scales)], ulps=0)
 
     # block-sparse attention against dense attention under the layout's mask

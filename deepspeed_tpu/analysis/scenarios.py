@@ -544,8 +544,9 @@ def pipe_1f1b_step() -> ProgramInfo:
 #: took it from 8.41); committed at 10.8 MiB (~8% headroom). A write that
 #: rebuilds a pool fails R010 under it: the ``dense`` masked write that
 #: ISSUE 27 removed measured 2.1 MiB over the tick of its day. Off the TPU
-#: this program traces the scatter and XLA's loop, not the in-place write
-#: and the kernel the chip runs (``models/common.py`` ``slot_pool_append``,
+#: this program traces the scatter and XLA's loop, not the two kernels the
+#: chip runs (the write's, ``ops/pallas/pool_write.py`` under ``models/
+#: common.py`` ``slot_pool_append``, and the read's under
 #: ``cached_attention``): those ones' temporaries are held by the compile
 #: tests alone (``tests/unit/ops/test_tpu_compile.py``).
 SERVE_DECODE_BUDGET_MB = 10.8

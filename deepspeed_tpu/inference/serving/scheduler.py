@@ -541,7 +541,8 @@ class ContinuousBatchingScheduler:
     def _count_kv_write(self, write_pos: np.ndarray, length: int) -> None:
         """One target pass writes ``length`` tokens at each live slot's
         ``write_pos``: positions one pool leaf is handed against positions
-        the write rewrites there (whole windows, ``slot_pool_append``).
+        the write rewrites there (whole windows, ``slot_pool_append``: the
+        same ones whether its kernel or its loop writes them).
         The ratio is what writing in place costs; a write that relaid the
         pool would touch slots x capacity every tick."""
         live = write_pos[write_pos < self.capacity]

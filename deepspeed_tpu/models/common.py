@@ -525,9 +525,12 @@ def slot_pool_append(leaves, updates, pos, rows=None, part=None, parts: int = 1)
     (:class:`DecodeCache` ``parts``): the leaves hold ``parts`` times the
     updates' heads and the write goes to pass ``part``'s, the others' untouched.
 
-    On a TPU, :func:`_append_in_place`. Elsewhere one scatter on the minor
-    dimension: the same write, and what the in-place one is tested against;
-    the TPU would relay the whole pool around it (``PERF.md``, PR 27)."""
+    On a TPU, :func:`_append_in_place`: one kernel a piece
+    (``ops/pallas/pool_write.py``) wherever the shapes allow, which is every
+    serving family's, and the slots' loop for the rest. Elsewhere one scatter
+    on the minor dimension: the same write, and what the in-place one is
+    tested against; the TPU would relay the whole pool around it (``PERF.md``,
+    PR 27)."""
     from deepspeed_tpu.ops.pallas import backend
     pos = pos.astype(jnp.int32)
     if backend.on_tpu():
@@ -555,9 +558,11 @@ def ring_pool_append(leaves, updates, pos, live, rows=None):
     that runs over the ring's end goes on at its start. ``rows`` as
     :func:`slot_pool_append`'s.
 
-    On a TPU two in-place writes: one at ``pos mod ring``, which drops what
-    runs past the end, and, for more than one token, one a ring earlier,
-    which drops all but that (a position before 0 writes nothing)."""
+    On a TPU two in-place writes (:func:`_append_in_place`: the kernel's, or
+    the loop's where a ring is a single window): one at ``pos mod ring``,
+    which drops what runs past the end, and, for more than one token, one a
+    ring earlier, which drops all but that (a position before 0 writes
+    nothing)."""
     from deepspeed_tpu.ops.pallas import backend
     ring = leaves[0].shape[-1]
     slots, length = updates[0].shape[:2]
@@ -718,35 +723,41 @@ def cached_attention(q, keys, key_scale, values, value_scale, q_pos, fed, *, win
 
 def _append_in_place(leaves, updates, pos, rows=None, part=None, parts: int = 1):
     """The write as a read-modify-write of the aligned span that holds the
-    tokens, one slot at a time: a ``fori_loop`` of scalar-indexed
-    ``dynamic_slice`` / select / ``dynamic_update_slice``, which XLA updates
-    in place under donation. A looped stack's pass (``parts``) writes through
-    ``ops/pallas/pool_write.py``'s kernel, the same read-modify-write with
-    every sequence's windows in flight at once, whatever the piece's length:
-    the families of one pass keep the loop until a ``perf_opt`` issue has
-    measured the kernel on each of their cells (``ROADMAP.md`` S1)."""
+    tokens, a piece of at most one window's tokens at a time (a piece touches
+    at most two windows). Which code makes a piece's write is its shapes' to
+    say and nobody else's: where ``ops/pallas/pool_write.py`` ``takes`` them
+    (pools of whole 128-position windows that hold the span, rows that pack
+    into 32-bit words: every serving family's configured shapes) one kernel a
+    call, every leaf of the layer and every sequence's windows in flight at
+    once; where it does not (a pool shorter than a lane row, an odd head count
+    under bfloat16 scales, a ring of one window written by a piece)
+    :func:`_append_piece`, the same read-modify-write one slot at a time.
+    ``part`` of ``parts`` goes on to either. Counted a piece, at trace time
+    and over every program a process traces: ``kv_write_kernel_pieces``,
+    ``kv_write_loop_pieces``."""
+    from deepspeed_tpu.ops.pallas import pool_write
+    from deepspeed_tpu.utils.trace import recorder
     leaves, updates = list(leaves), list(updates)
     length, piece = updates[0].shape[1], _append_span(1, leaves[0].shape[-1])
-    write = _append_piece
-    if parts > 1:
-        # a looped stack writes 192 times a tick: each piece goes through one
-        # kernel a layer a pass (ops/pallas/pool_write.py)
-        from deepspeed_tpu.ops.pallas import pool_write
-        if not pool_write.takes(leaves, [u[:, :piece] for u in updates]):
-            raise NotImplementedError(
-                "a looped stack's write on a TPU is ops/pallas/pool_write.py's, which takes "
-                "pools of whole 128-position windows; got "
-                f"{[tuple(leaf.shape) for leaf in leaves]}")
-        write = functools.partial(pool_write.pool_write, part=part, parts=parts)
-    # a piece of at most one window's tokens touches at most two windows
+    looped = {} if parts == 1 else {"part": part, "parts": parts}
     for start in range(0, length, piece):
-        leaves = write(leaves, [u[:, start:start + piece] for u in updates], pos + start, rows)
+        new = [u[:, start:start + piece] for u in updates]
+        kernel = pool_write.takes(leaves, new)
+        # both names every piece, so that a run's counters say "loop 0" and do not leave it out
+        recorder().count("kv_write_kernel_pieces", int(kernel))
+        recorder().count("kv_write_loop_pieces", int(not kernel))
+        write = pool_write.pool_write if kernel else _append_piece
+        leaves = write(leaves, new, pos + start, rows, **looped)
     return leaves
 
 
 # jitted, so that a model's layers share one trace of the write
-@jax.jit
-def _append_piece(leaves, updates, pos, rows=None):
+@functools.partial(jax.jit, static_argnames=("parts",))
+def _append_piece(leaves, updates, pos, rows=None, part=None, parts: int = 1):
+    """The write of the shapes ``ops/pallas/pool_write.py`` refuses: a
+    ``fori_loop`` over the sequences of scalar-indexed ``dynamic_slice`` /
+    select / ``dynamic_update_slice``, which XLA updates in place under
+    donation; ``part`` of ``parts``: pass ``part``'s heads alone."""
     positions = leaves[0].shape[-1]
     slots, length = updates[0].shape[:2]
     span = _append_span(length, positions)
@@ -771,8 +782,9 @@ def _append_piece(leaves, updates, pos, rows=None):
         out = []
         row = s if rows is None else rows[s]
         for leaf, win in zip(leaves, wins):
-            at = (row,) + (0,) * (leaf.ndim - 2) + (start[s],)
-            old = jax.lax.dynamic_slice(leaf, at, (1,) + leaf.shape[1:-1] + (span,))
+            heads = leaf.shape[1] // parts
+            at = (row, 0 if parts == 1 else part * heads) + (0,) * (leaf.ndim - 3) + (start[s],)
+            old = jax.lax.dynamic_slice(leaf, at, (1, heads) + leaf.shape[2:-1] + (span,))
             new = jnp.where(written, jax.lax.dynamic_index_in_dim(win, s, 0), old)
             out.append(jax.lax.dynamic_update_slice(leaf, new, at))
         return out
@@ -783,9 +795,10 @@ def _append_piece(leaves, updates, pos, rows=None):
 def slot_pool_positions_touched(pos, length: int, positions: int) -> int:
     """Positions :func:`slot_pool_append` rewrites in one stored leaf for
     ``length`` tokens at each of ``pos`` (a host array of write positions),
-    none for a parked one: on a TPU a span for every live slot and piece,
-    elsewhere the positions written. What the write costs, beside the
-    ``length`` a slot it is handed."""
+    none for a parked one: on a TPU a span for every live slot and piece
+    (the kernel's windows and the loop's are the same positions), elsewhere
+    the positions written. What the write costs, beside the ``length`` a slot
+    it is handed."""
     from deepspeed_tpu.ops.pallas import backend
     live = np.asarray(pos)[np.asarray(pos) < positions]
     if not backend.on_tpu():

@@ -9,11 +9,16 @@ is the aligned 128-position window that holds it (two for a piece, which may
 straddle a boundary): a grid step a sequence a window brings that window of
 each leaf into VMEM, puts the tokens' values on their lanes and stores the
 window back where it lay (the leaves are aliased to the
-outputs, so what no step visits is untouched). ``models/common.py``
-``_append_piece`` does the same a slot at a time in a ``fori_loop`` of
-scalar-indexed slices, a dozen microseconds a slot a layer whatever the bytes;
-here the steps' DMAs run ahead of each other (``PERF.md`` section 6, PR 50:
-0.6 of a looped stack's decode tick was that loop, 192 of them a tick).
+outputs, so what no step visits is untouched). Every serving cache's write on
+a TPU comes here, piece by piece, wherever :func:`takes` holds
+(``models/common.py`` ``_append_in_place``, under ``slot_pool_append`` and
+``ring_pool_append``): a choice by the shapes, and by the configurations' own
+shapes that is every write of every serving family. What it refuses goes to
+``_append_piece`` there, which does the same a slot at a time in a
+``fori_loop`` of scalar-indexed slices, a dozen microseconds a slot a layer
+whatever the bytes; here the steps' DMAs run ahead of each other (``PERF.md``
+section 6: PR 50, 0.6 of a looped stack's decode tick was that loop, 192 of
+them a tick; PR 56, the other families).
 
 The select runs on 32-bit words (:func:`pltpu.bitcast` packs four int8 rows or
 two bfloat16 rows of a window into one: the lane mask is the same for all of

@@ -25,6 +25,7 @@ from deepspeed_tpu.models.common import (DecodeCache, _append_in_place, _kv_quan
                                          slot_pool_scale)
 from deepspeed_tpu.ops.pallas import backend
 from deepspeed_tpu.parallel.topology import MeshTopology, set_topology
+from deepspeed_tpu.utils.trace import recorder
 
 SLOTS, POSITIONS, HEADS = 5, 256, 2
 #: write positions: a window's last lanes (a block of 5 straddles the
@@ -240,6 +241,134 @@ def test_positions_touched_counts_what_the_write_rewrites(monkeypatch):
     assert slot_pool_positions_touched(WRITE_POS, 200, 1024) == 5 * 2 * 256
     assert slot_pool_positions_touched(np.array([POSITIONS]), 16, POSITIONS) == 0
     assert slot_pool_positions_touched(np.array([3, 40]), 8, 48) == 2 * 48
+
+
+# ---------------------------------------------------------------------------
+# which code writes on a TPU: the kernel wherever its shapes allow, over one
+# part too, and the loop for what it refuses (ISSUE 56)
+# ---------------------------------------------------------------------------
+PLACES = 512
+#: a window's last lanes (5 tokens straddle the boundary), a parked slot, the
+#: pool's start, its last positions (tokens over the extent's end are dropped),
+#: mid-window
+KERNEL_POS = np.array([126, PLACES, 0, PLACES - 2, 300], np.int32)
+KINDS = ["gpt2_int8", "bf16", "fp32", "latent", "latent_3d"]
+
+
+def _leaves(kind, rng, places, n, length, heads=16, head_dim=64):
+    """``(leaves, updates)`` of one layer's write: the stored leaves of
+    ``SLOTS`` slots and ``n`` sequences' ``length`` tokens."""
+    if kind == "gpt2_int8":     # int8 codes with bfloat16 scales, K and V
+        rows, dtypes = [(heads, head_dim)] * 2 + [(heads,)] * 2, [jnp.int8] * 2 + [jnp.bfloat16] * 2
+    elif kind in ("bf16", "fp32"):
+        rows, dtypes = [(heads, head_dim)] * 2, [jnp.bfloat16 if kind == "bf16" else jnp.float32] * 2
+    else:                       # one latent a position, as ``LatentCache`` stores it or flat
+        rows, dtypes = [(1, 192) if kind == "latent" else (192,)], [jnp.bfloat16]
+    return ([_random(rng, (SLOTS,) + row + (places,), dtype) for row, dtype in zip(rows, dtypes)],
+            [_random(rng, (n, length) + row, dtype) for row, dtype in zip(rows, dtypes)])
+
+
+def _pieces(fn):
+    """``fn()`` and the pieces it sent down each path: (the kernel, the loop)."""
+    names = ("kv_write_kernel_pieces", "kv_write_loop_pieces")
+    before = [recorder().counters.get(name, 0) for name in names]
+    out = fn()
+    return out, tuple(recorder().counters.get(name, 0) - b for name, b in zip(names, before))
+
+
+def _assert_equal(got, want, leaves):
+    assert len(got) == len(want) == len(leaves)
+    for g, w, leaf in zip(got, want, leaves):
+        assert g.dtype == leaf.dtype and g.shape == leaf.shape
+        np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)),
+                                      np.asarray(w.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("rung", [False, True], ids=["every_slot", "a_rung_out_of_order"])
+@pytest.mark.parametrize("length", [1, 5, 128, 200], ids=["a_token", "straddling", "a_window",
+                                                          "two_pieces"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_the_kernel_over_one_part_writes_what_the_scatter_writes(kind, length, rung):
+    """What a TPU runs for every serving family's shapes, run here by the
+    interpreter: every piece through ``ops/pallas/pool_write.py``, none
+    through the loop, bit for bit the scatter's pools."""
+    rng = np.random.default_rng(length)
+    rows = jnp.asarray([SLOTS - 1, 0, 2], jnp.int32) if rung else None
+    pos = KERNEL_POS[[0, 1, 3]] if rung else KERNEL_POS
+    leaves, updates = _leaves(kind, rng, PLACES, len(pos), length)
+    got, pieces = _pieces(lambda: _append_in_place(leaves, updates, jnp.asarray(pos), rows))
+    assert pieces == (-(-length // 128), 0)
+    _assert_equal(got, slot_pool_append(leaves, updates, jnp.asarray(pos), rows), leaves)
+    # the parked sequence's slot, and the slots no sequence names, as they were
+    for slot in ([1] if rows is None else [0, 1, 3]):
+        for g, leaf in zip(got, leaves):
+            np.testing.assert_array_equal(np.asarray(g[slot].astype(jnp.float32)),
+                                          np.asarray(leaf[slot].astype(jnp.float32)))
+    assert any((np.asarray(g.astype(jnp.float32)) != np.asarray(leaf.astype(jnp.float32))).any()
+               for g, leaf in zip(got, leaves))
+
+
+@pytest.mark.parametrize("rung", [False, True], ids=["every_slot", "a_rung_out_of_order"])
+@pytest.mark.parametrize("length", [1, 5, 128, 200], ids=["a_token", "straddling", "a_window",
+                                                          "two_pieces"])
+@pytest.mark.parametrize("kind", ["gpt2_int8", "latent"])
+def test_the_ring_write_down_the_tpus_path_wraps_as_the_scatter_does(kind, length, rung, monkeypatch):
+    """``ring_pool_append`` as a TPU runs it (two in-place writes, the second a
+    ring earlier) against the scatter modulo the ring: a ring of six windows
+    (Laguna's and dots3's 768 places), a piece across the ring's wrap, one
+    several turns on, and a slot that is not ``live``."""
+    rng = np.random.default_rng(length)
+    ring = 768
+    pos = np.array([ring - 3, 5, 3 * ring + 700, 2 * ring + 126, ring + 300], np.int32)
+    live = np.array([True, False, True, True, True])
+    rows = None
+    if rung:
+        rows, pos, live = jnp.asarray([SLOTS - 1, 0, 2], jnp.int32), pos[:3], live[:3]
+    leaves, updates = _leaves(kind, rng, ring, len(pos), length, heads=8, head_dim=128)
+    want = common.ring_pool_append(leaves, updates, jnp.asarray(pos), jnp.asarray(live), rows)
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.setattr(backend, "interpret_default", lambda: True)
+    got, pieces = _pieces(lambda: common.ring_pool_append(
+        leaves, updates, jnp.asarray(pos), jnp.asarray(live), rows))
+    assert pieces == ((1 if length == 1 else 2) * -(-length // 128), 0)
+    _assert_equal(got, want, leaves)
+    dead = 1 if rows is None else 0
+    for g, leaf in zip(got, leaves):
+        np.testing.assert_array_equal(np.asarray(g[dead].astype(jnp.float32)),
+                                      np.asarray(leaf[dead].astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("parts", [1, 2], ids=["one_part", "a_pass_of_two"])
+@pytest.mark.parametrize("length", [1, 5, 200], ids=["a_token", "straddling", "two_pieces"])
+@pytest.mark.parametrize("refused, places, heads", [("a_short_pool", 48, 2), ("gpt2_xl", 256, 25),
+                                                    ("odd_heads_bf16_scales", 256, 3)])
+def test_the_shapes_the_kernel_refuses_go_through_the_loop(refused, places, heads, length, parts):
+    """A pool shorter than a lane row and odd head counts under bfloat16 scales
+    (GPT-2 XL's 25): ``takes`` says no, the slots' loop writes them, a looped
+    stack's pass too, and what is written is the scatter's."""
+    from deepspeed_tpu.ops.pallas import pool_write
+    rng = np.random.default_rng(length)
+    pos = np.array([places - 2, places, 0, 7, 30], np.int32)
+    stored, updates = _leaves("gpt2_int8", rng, places, SLOTS, length, heads=heads, head_dim=16)
+    leaves = [jnp.concatenate([leaf] * parts, axis=1) for leaf in stored]
+    assert not pool_write.takes(leaves, [u[:, :128] for u in updates])
+    for part in range(parts):
+        how = {} if parts == 1 else {"part": jnp.int32(part), "parts": parts}
+        got, pieces = _pieces(lambda: _append_in_place(leaves, updates, jnp.asarray(pos), **how))
+        assert pieces == (0, -(-length // min(places, 128)))
+        _assert_equal(got, slot_pool_append(leaves, updates, jnp.asarray(pos), **how), leaves)
+
+
+def test_a_ring_of_one_window_takes_a_token_through_the_kernel_and_a_piece_through_the_loop():
+    """The choice is a piece's: 128 places hold a token's window and not a
+    piece's two."""
+    rng = np.random.default_rng(0)
+    pos = jnp.asarray([126, 128, 0, 127, 64], jnp.int32)
+    for length, pieces in ((1, (1, 0)), (5, (0, 1))):
+        leaves, updates = _leaves("gpt2_int8", rng, 128, SLOTS, length, heads=2)
+        got, took = _pieces(lambda: _append_in_place(leaves, updates, pos))
+        assert took == pieces
+        _assert_equal(got, slot_pool_append(leaves, updates, pos), leaves)
 
 
 # ---------------------------------------------------------------------------

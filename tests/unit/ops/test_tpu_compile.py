@@ -77,6 +77,13 @@ def _kernel_text(compiled):
     return text
 
 
+def _kernel_calls(text, name):
+    """The compiled ``text``'s custom calls of the Pallas kernel ``name``
+    (XLA names the instruction after the call)."""
+    return [line for line in text.splitlines()
+            if "custom-call(" in line and line.split("ROOT ")[-1].lstrip().startswith("%" + name)]
+
+
 def _relayouts(compiled, elements):
     """Instructions that exist only to move or retype an array of at least
     ``elements`` elements: ``copy`` / ``convert`` / ``transpose`` (and a
@@ -191,8 +198,11 @@ def test_quant_matmul_compiles(one_chip, bits, m, k, n):
                          ids=["gpt2_medium", "olmoe", "gpt2_xl"])
 def test_kv_append_compiles(one_chip, heads, head_dim, positions, length):
     """The serving cache's write alone: 32 slots of int8 codes and bf16
-    scales, K and V in one loop, every pool donated: no pass over a pool
-    leaf and next to no temporary."""
+    scales, K and V in one call, every pool donated: no pass over a pool
+    leaf and next to no temporary. Which code writes is the shapes' to say:
+    ``ops/pallas/pool_write.py``'s kernel, one call a piece, where the rows
+    pack into 32-bit words; the slots' loop for GPT-2 XL's 25 heads, whose
+    bfloat16 scales do not."""
     from deepspeed_tpu.models.common import _append_in_place
     slots = 32
     pool, scale = _shape(slots, heads, head_dim, positions, dtype=jnp.int8), _shape(slots, heads, positions)
@@ -203,7 +213,13 @@ def test_kv_append_compiles(one_chip, heads, head_dim, positions, length):
 
     compiled = _compile(fn, one_chip, pool, pool, scale, scale, new, new, new_scale, new_scale,
                         _shape(slots, dtype=jnp.int32), donate_argnums=(0, 1, 2, 3))
-    assert "tpu_custom_call" not in compiled.as_text()
+    text = compiled.as_text()
+    if heads % 2:
+        assert "tpu_custom_call" not in text and "jit(_append_piece)/while" in text
+        assert set(_write_loop_trip_counts(compiled)) == {slots}
+    else:
+        assert len(_kernel_calls(text, "pool_write")) == text.count("tpu_custom_call") == 1
+        assert "jit(_append_piece)/while" not in text
     assert not _relayouts(compiled, slots * heads * head_dim * positions)
     # under one pool's 128-position windows (a leaf is `positions / 128` of them)
     assert compiled.memory_analysis().temp_size_in_bytes < slots * heads * head_dim * 128
@@ -320,10 +336,13 @@ def test_serving_program_compiles(one_chip, program, attention):
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
         operands = (_shape(SLOTS, dtype=jnp.int32),)
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
-    # a decode tick reads its stored pool through ``pool_decode`` whatever the
-    # backend a chunk's attention takes: one kernel a layer
-    kernels = compiled.as_text().count("tpu_custom_call")
-    assert kernels == 2 if program == "decode" else (kernels > 0) == (attention == "flash")
+    # either tick writes its tokens through ``pool_write``, one kernel a layer,
+    # and a decode tick reads its stored pool through ``pool_decode`` whatever
+    # the backend a chunk's attention takes: one more a layer
+    text = compiled.as_text()
+    kernels, writes = text.count("tpu_custom_call"), len(_kernel_calls(text, "pool_write"))
+    assert writes == 2 and "jit(_append_piece)/while" not in text
+    assert kernels == 4 if program == "decode" else (kernels > writes) == (attention == "flash")
 
 
 @pytest.mark.parametrize("program", ["prefill", "decode"])
@@ -357,10 +376,14 @@ def test_gpt2_serving_program_writes_the_pool_in_place(one_chip, program):
         logits = slots * module.config.vocab_size * 2
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
     _assert_pool_written_in_place(compiled, slots * L * H * D, layers, logits)
-    # a decode tick reads the pool where it lies: one Mosaic kernel a layer
-    # (``ops/pallas/pool_decode.py``), no pool leaf converted outside it
+    # the write is one Mosaic kernel a layer (``ops/pallas/pool_write.py``: the
+    # slots' loop is in neither program), and a decode tick reads the pool where
+    # it lies: one more a layer (``ops/pallas/pool_decode.py``), no pool leaf
+    # converted outside it
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == (layers if program == "decode" else 0)
+    assert len(_kernel_calls(text, "pool_write")) == layers
+    assert "jit(_append_piece)/while" not in text
+    assert text.count("tpu_custom_call") == (2 * layers if program == "decode" else layers)
     if program == "decode":
         _assert_no_rows_of_a_pool_are_made(text, slots, (H, D, L))
 
@@ -393,9 +416,12 @@ def test_olmoe_serving_program_compiles(one_chip, program):
         step = build_decode_step(apply_fn, False, 1.0, 0, 1.0)
         operands = (_shape(slots, dtype=jnp.int32),)
     compiled = _compile(step, one_chip, params, cache, *operands, donate_argnums=(1,))
-    # gate, up, down; and a decode tick's read of the stored pool
+    # gate, up, down and the write (a 64-token chunk is one piece); and a decode
+    # tick's read of the stored pool
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == (3 if program == "prefill" else 4)
+    assert len(_kernel_calls(text, "pool_write")) == 1
+    assert "jit(_append_piece)/while" not in text
+    assert text.count("tpu_custom_call") == (4 if program == "prefill" else 5)
     if program == "decode":
         assert text.count("%pool_decode") >= 1
         _assert_no_rows_of_a_pool_are_made(text, slots, (16, 128, 2048))
@@ -437,8 +463,12 @@ def test_nemotron_h_serving_program_updates_the_state_pool_in_place(one_chip, pr
     # 8,192 copies take 2,048 rows or all (a sixteenth, 512, is under
     # ``MIN_RUNG_ROWS``), a decode tick's 64 copies one buffer and no branch
     assert _row_rungs(slots * chunk * 4) == (2048, 8192) and _row_rungs(slots * 4) == (64,)
-    # (and the attention layer's read of its stored pool in a decode tick)
-    assert compiled.as_text().count("tpu_custom_call") == (8 if program == "prefill" else 5)
+    # (and the attention layer's write, two key heads whose bfloat16 scales
+    # pack into one row of words, and its read of its stored pool in a decode tick)
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "pool_write")) == 1
+    assert "jit(_append_piece)/while" not in text
+    assert text.count("tpu_custom_call") == (9 if program == "prefill" else 6)
     state = cache["layers_0"]["mixer"]["ssm_state"]
     assert state.shape == (slots, 2, 8, 64, 128) and state.dtype == jnp.float32
     # (a prefill tick relays its chunk's activations, as large at these widths)
@@ -497,6 +527,12 @@ def test_joyai_llm_flash_serving_program_reads_the_latent_pool_as_it_lies(one_ch
     assert not _relayouts(compiled, pools[0].size)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= layers * pool_bytes
+    # the latent's write is ``pool_write``'s too: a call a layer a piece of at
+    # most a window's tokens (a chunk's four), and no loop over the slots
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, "pool_write")) == layers * (1 if program == "decode"
+                                                               else -(-chunk // 128))
+    assert "jit(_append_piece)/while" not in text
     if program == "decode":
         # one kernel a layer reads the pool, as it lies (``latent_decode``)
         assert compiled.as_text().count("%mla_decode") >= layers
@@ -616,6 +652,12 @@ def test_dots3_note_serving_program_keeps_a_ring_and_scores_a_slot_at_a_time(one
     assert memory.alias_size_in_bytes >= pool_bytes + slots * (128 * positions + 1088 * ring) * 2
     text = compiled.as_text()
     assert text.count("%dsa_select") >= 1
+    # the writes are ``pool_write``'s, a call a leaf's cache a piece: the full
+    # layer's latent and its index keys, and the window layer's ring twice for
+    # a chunk (the second a ring earlier), once for a token; no loop over the slots
+    pieces = 1 if program == "decode" else -(-chunk // 128)
+    assert len(_kernel_calls(text, "pool_write")) == (2 + (1 if program == "decode" else 2)) * pieces
+    assert "jit(_append_piece)/while" not in text
     if program == "decode":
         assert text.count("%dsa_index_decode") >= 1 and text.count("%dsa_decode") >= 1
         assert memory.temp_size_in_bytes < pool_bytes // 30          # compiles to 37 MB
@@ -685,10 +727,15 @@ def test_laguna_serving_program_keeps_int8_rings_beside_int8_pools(one_chip, pro
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 2 * pool_bytes + 2 * slots * 8 * 128 * ring
     text = compiled.as_text()
+    assert "jit(_append_piece)/while" not in text
     if program == "prefill":
         # gate, up, down: once a size of the held route's row buffer (a prefill
-        # tick's three); a chunk's walk stays XLA's loop, a sequence at a time
-        assert text.count("tpu_custom_call") == 9 and "%pool_decode" not in text
+        # tick's three); a chunk's walk stays XLA's loop, a sequence at a time;
+        # the writes: a piece a window's tokens, once into the full layer's pool
+        # and twice into the sliding layer's ring (the second a ring earlier)
+        pieces = -(-chunk // 128)
+        assert len(_kernel_calls(text, "pool_write")) == 3 * pieces
+        assert text.count("tpu_custom_call") == 9 + 3 * pieces and "%pool_decode" not in text
         # no [slots, chunk, 100,352] logits (1.6 GB at 256): the held route's
         # last rung at hidden 2,048 and the dense layer's 8,192-wide activations
         assert memory.temp_size_in_bytes < slots * chunk * config["vocab_size"] * 2
@@ -699,8 +746,10 @@ def test_laguna_serving_program_keeps_int8_rings_beside_int8_pools(one_chip, pro
         # (a block of keys, of values and of their scales: what the kernel holds
         # of a pool at a time) beside the head's float32 logits, which no layer
         # owns; compiles to 5,106,176 bytes
+        # and a token's write a layer (a ring takes one token in one write)
         walks = [line for line in text.splitlines() if line.lstrip().startswith("%pool_decode")]
-        assert text.count("tpu_custom_call") == 3 + 2 and len(walks) == 2
+        assert len(_kernel_calls(text, "pool_write")) == 2
+        assert text.count("tpu_custom_call") == 3 + 2 + 2 and len(walks) == 2
         pool = next(leaf for leaf in jax.tree.leaves(cache) if leaf.shape[-1] == positions
                     and leaf.ndim == 4)
         assert not _whole_leaf_passes(compiled, pool)
@@ -782,9 +831,7 @@ def test_ouro_serving_program_loops_its_passes_over_pools_written_in_place(one_c
     # prefetched scalar (a token's 128-position window, or a chunk's two, of the pass's
     # heads; every leaf of the layer in one call, the pools aliased through it); a decode
     # tick's walk is another; the slots' write loop is in neither program
-    calls = {name: [line for line in text.splitlines() if "custom-call(" in line
-                    and line.lstrip().startswith("%" + name)]
-             for name in ("pool_write", "pool_decode")}
+    calls = {name: _kernel_calls(text, name) for name in ("pool_write", "pool_decode")}
     assert len(calls["pool_write"]) == layers
     assert len(calls["pool_decode"]) == (layers if program == "decode" else 0)
     assert text.count("tpu_custom_call") == (2 * layers if program == "decode" else layers)
@@ -814,6 +861,22 @@ def _write_loop_trip_counts(compiled):
         if "ROOT" in line and "_append_piece)/while/cond/lt" in line and "direction=LT" in line:
             counts.append(bound)
     return counts
+
+
+def _write_grids(compiled):
+    """``(sequences, windows)`` of each ``pool_write`` call of a compiled
+    program, its grid: read off the operands the call is handed (the
+    prefetched rows, one a sequence, then each leaf's tokens laid out over the
+    windows a sequence may touch)."""
+    import re
+    grids = []
+    for line in _kernel_calls(compiled.as_text(), "pool_write"):
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}, output_to_operand", line).group(1)
+        shapes = [[int(d) for d in dims.split(",")] for dims in re.findall(r"\w+\[([\d,]+)\]", operands)]
+        rows, laid_out = shapes[0], next(shape for shape in shapes if len(shape) > 1)
+        assert len(rows) == 1 and laid_out[0] == rows[0] and laid_out[-1] % 128 == 0
+        grids.append((rows[0], laid_out[-1] // 128))
+    return grids
 
 
 def _whole_leaf_passes(compiled, leaf):
@@ -846,8 +909,8 @@ def test_a_quarter_rung_program_runs_a_quarter(one_chip, family, program):
     """The prefill program over a quarter of each cell's slots, and the chat
     cell's decode program over 8 of its 32 (ISSUE 42), handed the slots they
     run: no copy, convert or transpose the size of a pool and no pass over
-    one (the rows are picked where they are written and read), the write
-    loop runs once a sequence of the rung, the cache comes back in place and
+    one (the rows are picked where they are written and read), the write's
+    kernel runs a grid step a sequence of the rung, the cache comes back in place and
     the temporaries are no more than the whole program's. (A latent pool
     has no such program: ``prefill_rungs``.)"""
     import re
@@ -883,14 +946,18 @@ def test_a_quarter_rung_program_runs_a_quarter(one_chip, family, program):
     assert not [line for line in _relayouts(rung, pool.size)
                 if family != "nemotron_h" or f"[{slots}," in line]
     assert family == "nemotron_h" or not _whole_leaf_passes(rung, pool)
-    assert set(_write_loop_trip_counts(whole)) == {slots}
-    assert set(_write_loop_trip_counts(rung)) == {n}
+    # the write: a call a layer in either program, over the sequences it runs
+    # (the rung's rows are the kernel's index map's), and the slots' loop in neither
+    grids, grids_whole = _write_grids(rung), _write_grids(whole)
+    assert len(grids) == len(grids_whole) > 0
+    assert {rows for rows, _ in grids_whole} == {slots} and {rows for rows, _ in grids} == {n}
+    assert not _write_loop_trip_counts(whole) and not _write_loop_trip_counts(rung)
     if program == "decode":
         # the rung's rows are read where they lie, by the kernel's index map:
-        # one kernel a layer in either program and no [n, heads, head dim,
-        # positions] made of a pool, gathered, copied or converted
+        # one kernel a layer in either program beside the write's and no [n,
+        # heads, head dim, positions] made of a pool, gathered, copied or converted
         for text, rows in ((rung.as_text(), n), (whole.as_text(), slots)):
-            assert text.count("tpu_custom_call") == 2
+            assert text.count("tpu_custom_call") == 2 + len(grids)
             _assert_no_rows_of_a_pool_are_made(text, rows, pool.shape[1:])
     memory, memory_whole = rung.memory_analysis(), whole.memory_analysis()
     assert memory.temp_size_in_bytes <= memory_whole.temp_size_in_bytes
