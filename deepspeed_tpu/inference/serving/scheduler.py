@@ -445,7 +445,9 @@ class ContinuousBatchingScheduler:
         are the rows of the buffers the layers sized for their routed rows.
         ``latent_reads``: the positions of its pools a latent attention's
         loops were bounded to, against the positions that held a token, by
-        the kind of tick, and the bytes it wrote there."""
+        the kind of tick, and the bytes it wrote there. ``moe_group_rows``:
+        a router that limits a token to some groups of experts says how many
+        real rows could reach this device's experts at all."""
         tok = np.asarray(tok)
         ran = len(tok) - sum(width for _, width in self._counters)
         behind = tok[ran:]
@@ -489,6 +491,11 @@ class ContinuousBatchingScheduler:
                 for i, n in enumerate(counted):
                     self._rec.count(f"{PASS_READS[i % len(PASS_READS)]}_pass"
                                     f"{i // len(PASS_READS)}_{kind}", n)
+            elif name == "moe_group_rows":
+                # group-limited routing over held experts: the real rows whose
+                # kept groups reach an expert held here, of the real rows routed
+                self._rec.count(f"moe_rows_group_kept_{kind}", counted[0])
+                self._rec.count(f"moe_rows_group_routed_{kind}", counted[1])
         return tok[:ran]
 
     def _count_of_tick(self, name: str, n: int, kind: str) -> None:

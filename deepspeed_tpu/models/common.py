@@ -81,10 +81,13 @@ RING_LEAVES = ("cached_window_latent",) + RING_KV_LEAVES
 #: tokens (``moe_rows``: an expert layer that holds a share; ``latent_reads``:
 #: a latent-attention layer, positions read, positions live, bytes written;
 #: ``sparse_reads``: a layer that selects or windows what it attends,
-#: :data:`SPARSE_READS` names its entries)
+#: :data:`SPARSE_READS` names its entries; ``moe_group_rows``: a held expert
+#: layer under group-limited routing, the real rows whose kept groups reach an
+#: expert held here and the real rows routed)
 STATE_LEAVES = ("ssm_state", "conv_state")
 LENGTH_LEAVES = ("chunk_length",)
-COUNTER_LEAVES = ("moe_rows", "latent_reads", "sparse_reads", "kv_reads", "kv_pass_reads")
+COUNTER_LEAVES = ("moe_rows", "latent_reads", "sparse_reads", "kv_reads", "kv_pass_reads",
+                  "moe_group_rows")
 #: the entries of a ``sparse_reads`` leaf, in order, under the names the
 #: host counts them by (by the kind of tick, but for the bytes ``_written``).
 #: An indexed layer fills the ``dsa_`` ones (positions of the index-key pool its

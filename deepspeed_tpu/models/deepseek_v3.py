@@ -89,11 +89,27 @@ sigmoid(x W_g)_h o_h``, before ``W_o`` (``attention_gate`` ``headwise``).
   in, handed the selection as its mask; elsewhere :func:`expanded_walk` under
   ``allow``). Both compute exactly the reference's set.
 
-RoPE rotates the pairs ``(2i, 2i+1)`` (``rope_interleave``) by ``theta``;
-``rope_scaling`` other than none, and group-limited routing (``n_group`` >
-1), are not built. Neither is the multi-token-prediction module of the
-published checkpoints (``num_nextn_predict_layers``), which never enters
-the language model's logits.
+RoPE rotates the pairs ``(2i, 2i+1)`` (``rope_interleave``) by ``theta``.
+With ``rope_scaling`` (:class:`YarnScaling`; DeepSeek-V3.2: ``factor`` 40 over
+4,096 original positions) the frequencies are YaRN's blend
+(``models/llama.py`` ``rope_frequencies``, the one place that makes it),
+cosine and sine carry ``m(mscale) / m(mscale_all_dim)`` and every softmax
+scale ``m(mscale_all_dim)^2``, ``m(s) = 0.1 s ln(factor) + 1``. The indexer
+rotates the first ``d_rope`` of its ``index_head_dim`` values by the same
+frequencies, in the layer's pairing or, ``index_rope_interleave`` false
+(DeepSeek-V3.2's), half-split: pairs ``(i, i + d_rope / 2)``, the two
+rotations side by side in one layer.
+
+**Group-limited routing** (``n_group`` > 1; DeepSeek-V3.2: 8 groups of 32,
+``topk_group`` 4): the biased scores in groups of consecutive experts, a
+group's score its two largest summed, the best groups kept, the ``k`` chosen
+among their experts (``moe/sharded_moe.py`` ``group_limited``). An expert
+layer that holds a share then sees a token only when its experts' group is
+kept, which the layer counts (``moe_group_rows``).
+
+The multi-token-prediction module of the published checkpoints
+(``num_nextn_predict_layers``) is not built: it never enters the language
+model's logits.
 """
 
 import contextlib
@@ -109,7 +125,22 @@ from deepspeed_tpu.models.common import (INDEX_KEY_LEAVES, RING_LEAVES, SPARSE_R
                                          config_from, dense_init as _init, embed_lookup,
                                          ring_mask, rms_norm,
                                          window_ring_positions)  # noqa: F401  (re-export)
-from deepspeed_tpu.models.llama import ExpertKernel
+from deepspeed_tpu.models.llama import ExpertKernel, RopeKind, rope_frequencies
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """``rope_scaling`` of ``type`` ``yarn``, under the published keys."""
+    factor: float
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def m(self, by: float) -> float:
+        """``yarn_get_mscale``: 1 where nothing is stretched or ``by`` is 0."""
+        return 0.1 * by * float(np.log(self.factor)) + 1.0 if self.factor > 1 else 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,6 +174,8 @@ class DeepseekV3Config:
     n_shared_experts: int = 1
     routed_scaling_factor: float = 2.5
     norm_topk_prob: bool = True
+    # > 1: the ``k`` experts are chosen among those of a token's
+    # ``topk_group`` best groups of ``n_group`` (consecutive experts)
     n_group: int = 1
     topk_group: int = 1
     # (first, count): the experts this device holds of ``n_routed_experts``;
@@ -169,12 +202,26 @@ class DeepseekV3Config:
     index_topk: int = 0
     index_n_heads: int = 64
     index_head_dim: int = 128
+    # the indexer's rotated pairs: neighbours, as the layer's own, or
+    # half-split ``(i, i + d_rope / 2)`` (DeepSeek-V3.2's indexer)
+    index_rope_interleave: bool = True
+    # None, a :class:`YarnScaling`, or its published dict (``type`` yarn)
+    rope_scaling: Optional[YarnScaling] = None
     mla_lora_rescale: bool = False
     # None, or "headwise": one sigmoid gate a head from the layer's input
     attention_gate: Optional[str] = None
     swa_attention_gate: Optional[str] = None
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
+
+    def __post_init__(self):
+        scaling = self.rope_scaling
+        if isinstance(scaling, dict):
+            scaling = dict(scaling)
+            kind = scaling.pop("type", scaling.pop("rope_type", "yarn"))
+            if kind != "yarn":
+                raise NotImplementedError(f"rope_scaling of type {kind!r}: only yarn is built")
+            object.__setattr__(self, "rope_scaling", YarnScaling(**scaling))
 
     @property
     def latent_width(self) -> int:
@@ -188,12 +235,14 @@ class DeepseekV3Config:
             return AttentionKind(
                 self.swa_num_attention_heads, self.swa_q_lora_rank, self.swa_kv_lora_rank,
                 self.swa_qk_nope_head_dim, self.swa_qk_rope_head_dim, self.swa_v_head_dim,
-                self.swa_rope_theta, self.swa_attention_gate, window=self.sliding_window_size)
+                self.swa_rope_theta, self.swa_attention_gate, window=self.sliding_window_size,
+                yarn=self.rope_scaling)
         if kind != "full_attention":
             raise NotImplementedError(f"layer_types[{layer}] = {kind!r}: not built")
         return AttentionKind(self.num_attention_heads, self.q_lora_rank, self.kv_lora_rank,
                              self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim,
-                             self.rope_theta, self.attention_gate, top_k=self.index_topk)
+                             self.rope_theta, self.attention_gate, top_k=self.index_topk,
+                             yarn=self.rope_scaling)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -209,6 +258,25 @@ class AttentionKind:
     gate: Optional[str] = None
     window: int = 0         # > 0: this position and the ``window - 1`` before it
     top_k: int = 0          # > 0: the ``top_k`` positions the indexer scores highest
+    yarn: Optional[YarnScaling] = None
+
+    def frequencies(self):
+        """``(inverse frequencies [d_rope / 2] float32, the factor on cosine
+        and sine)``, made in float64 on the host: at position 16k a float32
+        angle resolves 1e-3 rad, and a last-place error of the frequency is as
+        much again. Plain or YaRN's, they are ``models/llama.py``'s."""
+        y = self.yarn
+        rope = RopeKind(theta=self.theta) if y is None else RopeKind(
+            theta=self.theta, yarn_factor=y.factor,
+            original_positions=y.original_max_position_embeddings, beta_fast=y.beta_fast,
+            beta_slow=y.beta_slow, attention_factor=y.m(y.mscale) / y.m(y.mscale_all_dim))
+        return rope_frequencies(rope, self.d_rope)
+
+    @property
+    def softmax_scale(self) -> float:
+        """``1 / sqrt(d_nope + d_rope)``, times YaRN's ``m(mscale_all_dim)^2``."""
+        scale = (self.d_nope + self.d_rope) ** -0.5
+        return scale if self.yarn is None else scale * self.yarn.m(self.yarn.mscale_all_dim) ** 2
 
 
 DEEPSEEK_V3_CONFIGS = {
@@ -248,6 +316,26 @@ DEEPSEEK_V3_CONFIGS = {
         sliding_window_size=17, window_ring=32, index_topk=24, index_n_heads=4,
         index_head_dim=16, mla_lora_rescale=True, attention_gate="headwise",
         swa_attention_gate="headwise"),
+    # DeepSeek-V3.2 (deepseek-ai/DeepSeek-V3.2): the published sizes
+    "deepseek-v3.2": dict(
+        hidden_size=7168, num_hidden_layers=61, num_attention_heads=128, rope_theta=10000.0,
+        max_position_embeddings=163840, first_k_dense_replace=3, intermediate_size=18432,
+        moe_intermediate_size=2048, n_group=8, topk_group=4, index_topk=2048,
+        index_rope_interleave=False,
+        rope_scaling=YarnScaling(factor=40.0, original_max_position_embeddings=4096,
+                                 beta_fast=32.0, beta_slow=1.0, mscale=1.0, mscale_all_dim=1.0)),
+    # a dense and three expert layers, every one indexed, at sizes where the
+    # selection binds inside 128 positions, YaRN's ramp lies inside the four
+    # rotated pairs and the 16 experts stand in 4 groups of which 2 are kept
+    "deepseek-v3.2-test": dict(
+        vocab_size=256, hidden_size=64, num_hidden_layers=4, num_attention_heads=4,
+        q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, rope_theta=10000.0, max_position_embeddings=128, attention_key_block=16,
+        intermediate_size=96, n_routed_experts=16, num_experts_per_tok=4,
+        moe_intermediate_size=32, n_group=4, topk_group=2, index_topk=24, index_n_heads=4,
+        index_head_dim=16, index_rope_interleave=False,
+        rope_scaling=YarnScaling(factor=8.0, original_max_position_embeddings=16,
+                                 beta_fast=2.0, beta_slow=0.25, mscale=1.0, mscale_all_dim=1.0)),
 }
 
 
@@ -277,22 +365,34 @@ def _dense(cfg, features, names, name):
                     kernel_init=nn.with_logical_partitioning(_init(), names), name=name)
 
 
-def rotate_interleaved(x, positions, theta: float):
+def rope_turn(x, positions, inv_freq, factor: float = 1.0, interleaved: bool = True):
     """RoPE over the last axis of ``x`` [..., l, (heads,) d] at ``positions``
-    [..., l], the rotated pairs being neighbours ``(2i, 2i+1)``
-    (``rope_interleave``): pair ``i`` turns by ``position * theta^(-2i/d)``.
-    ``x`` has the positions on its second axis."""
+    [..., l]: pair ``i`` turns by ``position * inv_freq[i]`` (``inv_freq``
+    [d / 2], host float32), cosine and sine times ``factor``. The pairs are
+    neighbours ``(2i, 2i+1)`` (``rope_interleave``) or, ``interleaved`` false,
+    half-split ``(i, i + d / 2)``. ``x`` has the positions on its second axis."""
     d = x.shape[-1]
-    # the frequencies in float64 on the host: at position 16k a float32 angle
-    # resolves 1e-3 rad, and a last-place error of the frequency is as much again
-    inv_freq = jnp.asarray(theta ** (-np.arange(0, d, 2) / d), jnp.float32)
-    angles = positions[..., None].astype(jnp.float32) * inv_freq       # [b, l, d/2]
+    angles = positions[..., None].astype(jnp.float32) * jnp.asarray(inv_freq, jnp.float32)
     angles = angles.reshape(angles.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    if not interleaved:
+        first, second = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        return jnp.concatenate([first * cos - second * sin, second * cos + first * sin],
+                               axis=-1).astype(x.dtype)
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
     even, odd = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([even * cos - odd * sin, odd * cos + even * sin], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def rotate_interleaved(x, positions, theta: float):
+    """:func:`rope_turn` of neighbouring pairs by ``theta`` alone: pair ``i``
+    turns by ``position * theta^(-2i/d)``, the frequencies made in float64 on
+    the host."""
+    d = x.shape[-1]
+    return rope_turn(x, positions, theta ** (-np.arange(0, d, 2) / d))
 
 
 def walk_blocks(start, fed, block: int, positions: int):
@@ -302,7 +402,8 @@ def walk_blocks(start, fed, block: int, positions: int):
     return jnp.minimum(n_blocks, positions // block).astype(jnp.int32)
 
 
-def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int, allow=None):
+def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int, allow=None,
+                  scale: Optional[float] = None):
     """Expanded latent attention of ``l`` queries a sequence against that
     sequence's pool, a sequence and ``block`` key positions at a time.
 
@@ -319,7 +420,8 @@ def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int, allow=Non
     One block's latent is expanded to the heads' keys and values inside the
     step that attends it, with a running softmax over the blocks: the scores
     in flight are [H, l, block] and nothing the size of the pool is made.
-    Returns [b, l, H, dv] in ``q_nope``'s dtype.
+    Returns [b, l, H, dv] in ``q_nope``'s dtype. ``scale`` multiplies the
+    scores (None: ``1 / sqrt(dn + dr)``; ``AttentionKind.softmax_scale``).
 
     ``allow`` replaces the causal mask: ``allow(s, q_pos)`` is called once a
     sequence and returns ``(state, mask)``, and ``mask(j, k_at, state)`` once a
@@ -330,7 +432,7 @@ def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int, allow=Non
     rank = w_kvb.shape[0]
     dv = w_kvb.shape[-1] - dn
     width, positions = pool.shape[1:]
-    scale = (dn + q_rope.shape[-1]) ** -0.5
+    scale = (dn + q_rope.shape[-1]) ** -0.5 if scale is None else scale
     dtype = q_nope.dtype
     w_kvb = w_kvb.astype(dtype)
 
@@ -389,7 +491,8 @@ def expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block: int, allow=Non
     return jax.lax.fori_loop(0, b, sequence, jnp.zeros((b, l, heads, dv), dtype))
 
 
-def kernel_walk(q_nope, q_rope, pool, w_kvb, start, fed, chosen=None):
+def kernel_walk(q_nope, q_rope, pool, w_kvb, start, fed, chosen=None,
+                scale: Optional[float] = None):
     """:func:`expanded_walk` on the chip, a fed slot at a time through the
     kernel that keeps a step's scores in VMEM (``ops/pallas/latent_walk.py``;
     shapes it ``takes``): the slot's pool is read in place, blocks past
@@ -403,7 +506,7 @@ def kernel_walk(q_nope, q_rope, pool, w_kvb, start, fed, chosen=None):
     b, l, heads, dn = q_nope.shape
     dv = w_kvb.shape[-1] - dn
     positions, dtype = pool.shape[-1], q_nope.dtype
-    scale = (dn + q_rope.shape[-1]) ** -0.5
+    scale = (dn + q_rope.shape[-1]) ** -0.5 if scale is None else scale
     # heads first, as the kernel blocks them: the weights once, a fed slot's
     # queries as its turn comes (an unfed slot's are never moved)
     w = jnp.swapaxes(w_kvb, 0, 1).astype(dtype)                          # [H, rank, dn + dv]
@@ -426,7 +529,8 @@ def kernel_walk(q_nope, q_rope, pool, w_kvb, start, fed, chosen=None):
     return jax.lax.fori_loop(0, b, sequence, jnp.zeros((b, l, heads, dv), dtype))
 
 
-def absorbed_step(q_nope, q_rope, pool, w_kvb, lengths, chosen=None):
+def absorbed_step(q_nope, q_rope, pool, w_kvb, lengths, chosen=None,
+                  scale: Optional[float] = None):
     """Absorbed latent attention of ONE query a sequence over its pool:
     ``q_nope`` [b, H, dn], ``q_rope`` [b, H, dr] (rotated), ``pool`` [b, rank +
     dr, positions] read as it lies, ``lengths`` [b] the positions that hold a
@@ -444,7 +548,7 @@ def absorbed_step(q_nope, q_rope, pool, w_kvb, lengths, chosen=None):
     rank = w_kvb.shape[0]
     dtype = q_nope.dtype
     w_kvb = w_kvb.astype(dtype)
-    scale = (dn + q_rope.shape[-1]) ** -0.5
+    scale = (dn + q_rope.shape[-1]) ** -0.5 if scale is None else scale
     from deepspeed_tpu.ops.pallas import backend
     with jax.named_scope("mla_attend_decode" if chosen is None else "dsa_attend_decode"):
         q_lat = jnp.einsum("bhd,chd->bhc", q_nope, w_kvb[..., :dn])
@@ -482,7 +586,8 @@ def _mix_whole_pool(q_lat, q_rope, pool, lengths, scale, chosen=None):
     return jnp.einsum("bhp,bwp->bhw", probs, pool, precision="highest")[..., :rank]
 
 
-def window_step(q_nope, q_rope, ring, w_kvb, pos, live, window: int):
+def window_step(q_nope, q_rope, ring, w_kvb, pos, live, window: int,
+                scale: Optional[float] = None):
     """Absorbed latent attention of ONE query a sequence over its RING
     [b, rank + dr, ring]: the query at ``pos`` [b] (already written) reads
     its window (:func:`ring_mask`); a sequence that is not ``live`` gives
@@ -492,7 +597,7 @@ def window_step(q_nope, q_rope, ring, w_kvb, pos, live, window: int):
     rank = w_kvb.shape[0]
     dtype = q_nope.dtype
     w_kvb = w_kvb.astype(dtype)
-    scale = (dn + q_rope.shape[-1]) ** -0.5
+    scale = (dn + q_rope.shape[-1]) ** -0.5 if scale is None else scale
     with jax.named_scope("swa_attend_decode"):
         q_all = jnp.concatenate(
             [jnp.einsum("bhd,chd->bhc", q_nope, w_kvb[..., :dn]), q_rope], axis=-1)
@@ -567,16 +672,18 @@ class LatentAttention(nn.Module):
     config: DeepseekV3Config
     kind: AttentionKind
 
-    def _indexer(self, x, c_q, positions):
+    def _indexer(self, x, c_q, turn):
         """The indexer's three: ``q`` [b, l, J, d] and the one key a position
-        ``k`` [b, l, d], the first ``d_rope`` of their ``d`` values rotated as
-        the layer rotates, and the heads' weights ``w`` [b, l, J] float32."""
+        ``k`` [b, l, d], the first ``d_rope`` of their ``d`` values rotated by
+        the layer's frequencies (``turn``) in the indexer's own pairing
+        (``index_rope_interleave``), and the heads' weights ``w`` [b, l, J]
+        float32."""
         cfg, kind = self.config, self.kind
         heads, d = cfg.index_n_heads, cfg.index_head_dim
 
         def rotated(t):
-            return jnp.concatenate([rotate_interleaved(t[..., :kind.d_rope], positions,
-                                                       kind.theta), t[..., kind.d_rope:]], -1)
+            return jnp.concatenate([turn(t[..., :kind.d_rope], cfg.index_rope_interleave),
+                                    t[..., kind.d_rope:]], -1)
 
         q = nn.DenseGeneral(features=(heads, d), axis=-1, use_bias=False, dtype=cfg.dtype,
                             param_dtype=cfg.param_dtype,
@@ -628,13 +735,19 @@ class LatentAttention(nn.Module):
             positions = jnp.broadcast_to(positions, (b, l))
         else:
             positions = jnp.broadcast_to(jnp.arange(l)[None, :], (b, l))
+        inv_freq, factor = kind.frequencies()
+        scale = kind.softmax_scale
+
+        def turn(t, interleaved=True):
+            with jax.named_scope("rope_yarn") if kind.yarn else contextlib.nullcontext():
+                return rope_turn(t, positions, inv_freq, factor, interleaved)
+
         q_nope = q[..., :dn]
-        q_rope = rotate_interleaved(q[..., dn:], positions, kind.theta)
-        latent = jnp.concatenate(
-            [c_kv, rotate_interleaved(joint[..., rank:], positions, kind.theta)], axis=-1)
+        q_rope = turn(q[..., dn:])
+        latent = jnp.concatenate([c_kv, turn(joint[..., rank:])], axis=-1)
         if kind.top_k:
             with jax.named_scope("dsa_index"):
-                index_q, index_k, index_w = self._indexer(x, c_q, positions)
+                index_q, index_k, index_w = self._indexer(x, c_q, turn)
 
         keys = None
         if decode:
@@ -654,7 +767,7 @@ class LatentAttention(nn.Module):
             lengths = jnp.where(fed > 0, start + 1, 0)
             if kind.window:
                 out = window_step(q_nope[:, 0], q_rope[:, 0], pool, w_kvb, start, fed > 0,
-                                  kind.window)[:, None]
+                                  kind.window, scale)[:, None]
                 counts = {"swa_ring_positions_read": (fed > 0).sum() * pool.shape[-1],
                           "swa_ring_positions_live": jnp.minimum(lengths, kind.window).sum()}
             else:
@@ -663,7 +776,7 @@ class LatentAttention(nn.Module):
                     chosen, counts = self._chosen_decode(index_q[:, 0], index_w[:, 0], keys,
                                                          lengths)
                 out = absorbed_step(q_nope[:, 0], q_rope[:, 0], pool, w_kvb, lengths,
-                                    chosen)[:, None]
+                                    chosen, scale)[:, None]
                 read = absorbed_positions_read(lengths, pool.shape[-1])
         else:
             block = cfg.attention_key_block
@@ -693,9 +806,10 @@ class LatentAttention(nn.Module):
                 if in_kernel:
                     out = kernel_walk(q_nope, q_rope, pool, w_kvb, start, fed,
                                       self._chosen_in_kernel(index_q, index_w, keys, start, fed)
-                                      if kind.top_k else None)
+                                      if kind.top_k else None, scale)
                 else:
-                    out = expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block, allow)
+                    out = expanded_walk(q_nope, q_rope, pool, w_kvb, start, fed, block, allow,
+                                        scale)
             if decode:
                 read = walk_blocks(start, fed, block, pool.shape[-1]).sum() * block
                 ends = jnp.where(fed > 0, start + fed, 0)
@@ -891,7 +1005,8 @@ def _expert_layer(cfg: DeepseekV3Config, name: str):
         model_dim=cfg.hidden_size, num_experts=cfg.n_routed_experts, k=cfg.num_experts_per_tok,
         drop_tokens=False, route="sorted", route_kernel=cfg.moe_route_kernel,
         norm_topk_prob=cfg.norm_topk_prob, score="sigmoid", select_bias=True,
-        routed_scale=cfg.routed_scaling_factor, experts_held=held, shared_expert=shared,
+        routed_scale=cfg.routed_scaling_factor, n_group=cfg.n_group,
+        topk_group=cfg.topk_group, experts_held=held, shared_expert=shared,
         param_dtype=cfg.param_dtype, name=name)
 
 
@@ -922,8 +1037,6 @@ class DeepseekV3ForCausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True, decode: bool = False):
         cfg = self.config
-        if cfg.n_group != 1 or cfg.topk_group != 1:
-            raise NotImplementedError("group-limited routing (n_group > 1) is not built")
         if cfg.layer_types is not None and len(cfg.layer_types) != cfg.num_hidden_layers:
             raise ValueError(f"layer_types names {len(cfg.layer_types)} layers of "
                              f"{cfg.num_hidden_layers}")

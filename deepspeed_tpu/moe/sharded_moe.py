@@ -128,6 +128,17 @@ class SortedRouting(NamedTuple):
     keep: jax.Array     # int32 — 1 iff the copy survived capacity
 
 
+class GroupedRouting(NamedTuple):
+    """:class:`SortedRouting` of a router that limits a token's choice to some
+    groups of experts (:func:`group_limited`), with which groups those were."""
+
+    expert: jax.Array
+    slot: jax.Array
+    weight: jax.Array
+    keep: jax.Array
+    group_kept: jax.Array   # bool [S, n_group] — the groups the choice was limited to
+
+
 def _top1_decisions(logits, capacity_factor, min_capacity, used_token,
                     noisy_gate_policy, drop_tokens, use_rts, rng):
     """The top-1 decision core shared by the dense and sorted routes —
@@ -318,9 +329,29 @@ def top2routing(logits: jax.Array,
 
 
 
+def group_limited(choice, n_group: int, topk_group: int, top: int = 2):
+    """Group-limited (node-limited) routing's first stage (DeepSeek-V3,
+    arXiv:2412.19437 section 2.1.2): the experts in ``n_group`` groups of
+    consecutive experts, a group's score the sum of its ``top`` largest
+    ``choice`` scores, the ``topk_group`` best groups kept (ties: the lower
+    group) and every other group's experts put out of the choice. ``choice``
+    [S, E] float32 -> ``(choice with -inf outside the kept groups, kept [S,
+    n_group] bool)``."""
+    tokens, experts = choice.shape
+    if experts % n_group or not 1 <= topk_group <= n_group:
+        raise ValueError(f"group-limited routing: {experts} experts in {n_group} groups, "
+                         f"{topk_group} kept")
+    grouped = choice.reshape(tokens, n_group, experts // n_group)
+    group_score = jax.lax.top_k(grouped, top)[0].sum(axis=-1)            # [S, n_group]
+    _, best = jax.lax.top_k(group_score, topk_group)
+    kept = (best[:, :, None] == jnp.arange(n_group, dtype=best.dtype)).any(axis=1)
+    limited = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(tokens, experts)
+    return limited, kept
+
+
 def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, normalize,
                     used_token=None, score="softmax", select_bias=None, scale=1.0,
-                    positions=True):
+                    positions=True, groups=None):
     """The decision core for any ``k`` <= experts (OLMoE: 8 of 64): softmax
     over the experts in fp32, the ``k`` largest probabilities
     (``jax.lax.top_k``; ties go to the lower index), and as combine weights
@@ -333,7 +364,11 @@ def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, norma
     the normalisation. ``positions=False`` (drop-free only) assigns no copy
     its place in its expert's buffer: the caller groups the copies itself
     (``MOELayer._held_route``), ``slot`` is zero and ``exp_counts`` counts
-    all ``k`` choices.
+    all ``k`` choices. ``groups`` = ``(n_group, topk_group)`` limits the choice
+    to the experts of each token's ``topk_group`` best groups
+    (:func:`group_limited`, over the biased scores; a group's score its two
+    largest summed, or its largest where there is no bias, as the release
+    has it); the routing is then a :class:`GroupedRouting`.
 
     Slots are assigned choice-major, as the top-2 core does: every first
     choice queues in its expert's buffer before any second choice, so what
@@ -345,10 +380,17 @@ def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, norma
     gates = jax.nn.sigmoid(logits) if score == "sigmoid" else jax.nn.softmax(logits, axis=1)
     capacity = _gate_capacity(num_tokens, num_experts, capacity_factor, min_capacity,
                               drop_tokens, k)
-    if select_bias is None:
+    compact = SortedRouting
+    if select_bias is None and groups is None:
         weights, experts = jax.lax.top_k(gates, k)                  # [S, k]
     else:
-        _, experts = jax.lax.top_k(gates + select_bias.astype(jnp.float32)[None, :], k)
+        choice = gates if select_bias is None else (
+            gates + select_bias.astype(jnp.float32)[None, :])
+        if groups is not None:
+            choice, group_kept = group_limited(choice, *groups,
+                                               top=1 if select_bias is None else 2)
+            compact = functools.partial(GroupedRouting, group_kept=group_kept)
+        _, experts = jax.lax.top_k(choice, k)
         weights = jnp.take_along_axis(gates, experts, axis=1)
     if normalize:
         weights = weights / jnp.maximum(weights.sum(axis=1, keepdims=True),
@@ -368,8 +410,8 @@ def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, norma
         counts = jnp.sum((experts[:, :, None] == jnp.arange(num_experts, dtype=experts.dtype))
                          & (keep[:, :, None] > 0), axis=(0, 1), dtype=jnp.int32)
         l_aux = jnp.sum(jnp.mean(gates, axis=0) * counts / num_tokens) * num_experts
-        routing = SortedRouting(expert=experts.astype(jnp.int32), slot=jnp.zeros_like(keep),
-                                weight=weights * keep, keep=keep)
+        routing = compact(expert=experts.astype(jnp.int32), slot=jnp.zeros_like(keep),
+                          weight=weights * keep, keep=keep)
         return l_aux, routing, counts, capacity
     masks = jax.nn.one_hot(experts, num_experts, dtype=jnp.int32)   # [S, k, E]
     if used_token is not None:
@@ -385,8 +427,8 @@ def _topk_decisions(logits, k, capacity_factor, min_capacity, drop_tokens, norma
     # chose it, summed over the k choices
     l_aux = jnp.sum(jnp.mean(gates, axis=0)
                     * jnp.mean(masks.sum(axis=1).astype(jnp.float32), axis=0)) * num_experts
-    routing = SortedRouting(expert=experts.astype(jnp.int32), slot=slot.astype(jnp.int32),
-                            weight=weights * keep, keep=keep)
+    routing = compact(expert=experts.astype(jnp.int32), slot=slot.astype(jnp.int32),
+                      weight=weights * keep, keep=keep)
     return l_aux, routing, exp_counts, capacity
 
 
@@ -396,7 +438,7 @@ def topkrouting(logits: jax.Array, k: int, capacity_factor: float, min_capacity:
                 **scoring) -> Tuple[jax.Array, SortedRouting, jax.Array]:
     """Top-``k`` gating, compact form for the sorted route. Returns
     ``(l_aux, SortedRouting [S,k] fields, exp_counts [E])``. ``scoring``:
-    ``score``, ``select_bias``, ``scale`` of :func:`_topk_decisions`."""
+    ``score``, ``select_bias``, ``scale``, ``groups`` of :func:`_topk_decisions`."""
     l_aux, routing, exp_counts, _ = _topk_decisions(
         logits, k, capacity_factor, min_capacity, drop_tokens, normalize, used_token, **scoring)
     return l_aux, routing, exp_counts
@@ -459,6 +501,10 @@ class TopKGate(nn.Module):
     score: str = "softmax"
     select_bias: bool = False
     routed_scale: float = 1.0
+    # > 1: group-limited routing (:func:`group_limited`): the ``k`` are chosen
+    # among the experts of a token's ``topk_group`` best groups of ``n_group``
+    n_group: int = 1
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, tokens, used_token=None, deterministic: bool = True,
@@ -502,9 +548,11 @@ class TopKGate(nn.Module):
         top2_fn = top2routing if self.route == "sorted" else top2gating
         scoring = {}
         if (self.score != "softmax" or self.select_bias or self.routed_scale != 1.0
-                or not positions):
+                or not positions or self.n_group > 1):
             # the top-k core alone knows these: it serves every k
             scoring = dict(score=self.score, scale=self.routed_scale, positions=positions)
+            if self.n_group > 1:
+                scoring["groups"] = (self.n_group, self.topk_group)
             if self.select_bias:
                 bias = self.param("e_score_correction_bias",
                                   nn.with_logical_partitioning(nn.initializers.normal(0.02), (None,)),
@@ -685,6 +733,8 @@ class MOELayer(nn.Module):
     score: str = "softmax"
     select_bias: bool = False
     routed_scale: float = 1.0
+    n_group: int = 1
+    topk_group: int = 1
     # ``(first, count)``: the experts this device holds of ``num_experts``
     # (its share of an expert-parallel layer). The gate routes over all of
     # them; only copies routed to a held expert are grouped, computed and
@@ -733,7 +783,7 @@ class MOELayer(nn.Module):
                         self.drop_tokens, self.use_rts, route=route,
                         norm_topk_prob=self.norm_topk_prob, score=self.score,
                         select_bias=self.select_bias, routed_scale=self.routed_scale,
-                        name="gate")
+                        n_group=self.n_group, topk_group=self.topk_group, name="gate")
         if (self.experts_held is not None or self.latent_dim) and route != "sorted":
             raise ValueError("experts_held and latent_dim are options of the sorted route")
 
@@ -970,6 +1020,16 @@ class MOELayer(nn.Module):
                           jnp.int32).value = jnp.stack(
                 [held_rows, visited[rung], exp_counts.sum(), (sizes > 0).sum(),
                  jnp.asarray(rungs, jnp.int32)[rung], *extra]).astype(jnp.int32)
+
+        if isinstance(routing, GroupedRouting) and self.is_mutable_collection("cache"):
+            # what group-limited routing exists to bound: the real rows whose
+            # kept groups include one with an expert held here (the rows that
+            # visit this device at all), beside the real rows routed
+            per = E // self.n_group
+            reaches = routing.group_kept[..., first // per:(first + count - 1) // per + 1].any(-1)
+            real = routing.keep[..., 0] > 0
+            self.variable("cache", "moe_group_rows", jnp.zeros, (2,), jnp.int32).value = (
+                jnp.stack([(reaches & real).sum(), real.sum()]).astype(jnp.int32))
 
         tokens = self._latent("latent_down", tokens, orig_dtype)[0]
         weights = routing.weight.reshape(-1)

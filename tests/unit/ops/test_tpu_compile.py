@@ -1063,3 +1063,85 @@ def test_smallthinker_train_step_compiles_and_fits_one_chip(one_chip, topo):
     # that it compiled says it fits the chip's 15.75 GiB (the compiler refuses
     # a program that does not); the masters and moments are most of it
     assert mem.argument_size_in_bytes > 7.8e9
+
+
+def _deepseek_v32_programs(one_chip, layers, slots=16, chunk=256, positions=32768):
+    """``{program: compiled}`` of the 32k-context cell's three programs at the
+    published widths over ``layers`` layers (the first dense), with the cache's
+    4-axis leaves' shapes."""
+    import flax.linen as nn
+    from deepspeed_tpu.inference.serving.programs import (build_decode_step,
+                                                          build_prefill_step, build_verify_step,
+                                                          make_apply_fn, make_slot_cache)
+    from deepspeed_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM, get_deepseek_v3_config
+
+    module = DeepseekV3ForCausalLM(get_deepseek_v3_config(
+        "deepseek-v3.2", num_hidden_layers=layers, first_k_dense_replace=1, vocab_size=16160,
+        experts_held=(0, 8), decode_cache_len=positions, attention_key_block=256, dtype=bf16,
+        param_dtype=bf16))
+    params = jax.eval_shape(
+        lambda key: nn.meta.unbox(module.init(key, jnp.zeros((1, 8), jnp.int32))["params"]),
+        jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: make_slot_cache(module, slots))
+    shapes = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        if leaf.ndim == 4:
+            shapes.setdefault(path[-1].key, []).append((leaf.shape, leaf.dtype))
+    apply_fn = make_apply_fn(module)
+    ints = lambda *dims: _shape(*dims, dtype=jnp.int32)  # noqa: E731
+    steps = {"prefill": (build_prefill_step(apply_fn, False, 1.0, 0, 1.0),
+                         (ints(slots), ints(slots, chunk), ints(slots))),
+             "decode": (build_decode_step(apply_fn, False, 1.0, 0, 1.0), (ints(slots),)),
+             "verify": (build_verify_step(apply_fn), (ints(slots), ints(slots, 2)))}
+    return shapes, lambda program: _compile(steps[program][0], one_chip, params, cache,
+                                            *steps[program][1], donate_argnums=(1,))
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "verify"])
+def test_deepseek_v32_serving_program_indexes_every_layer_and_fits_beside_its_pools(one_chip,
+                                                                                     program):
+    """The 32k-context cell's three programs at the published widths (hidden
+    7,168, 128 heads, ``q_lora_rank`` 1,536, a 16,384 x 7,168 ``o_proj``, a
+    dense layer 18,432 wide, 8 of 256 experts held under group-limited routing,
+    YaRN), its 16 slots of 32,768 positions and two layers, the dense one and an
+    expert layer, BOTH indexed: every layer has an index-key pool beside its
+    latent pool, all donated and written in place; each tick scores, selects and
+    attends in the kernels dots3-note's full layers run (the shapes are theirs),
+    and a prefill tick's temporaries are those ``serve.memory`` quotes."""
+    slots, chunk, positions = 16, 256, 32768
+    shapes, compile_program = _deepseek_v32_programs(one_chip, 2)
+    assert shapes == {"cached_latent": [((slots, 1, 576, positions), bf16)] * 2,
+                      "cached_index_key": [((slots, 1, 128, positions), bf16)] * 2}
+    compiled = compile_program(program)
+    pool_bytes = slots * 576 * positions * 2
+    assert not _relayouts(compiled, slots * 576 * positions)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * (pool_bytes + slots * 128 * positions * 2)
+    text = compiled.as_text()
+    assert "jit(_append_piece)/while" not in text
+    if program == "verify":
+        # two tokens a slot are no shape the walk's kernel takes: XLA's walk under
+        # the selection, which no cell runs (the runner cannot turn speculation
+        # on); held here to compiling and to fitting beside the pools
+        assert memory.temp_size_in_bytes < pool_bytes // 10
+        return
+    assert text.count("%dsa_select") >= 2
+    if program == "decode":
+        assert text.count("%dsa_index_decode") >= 2 and text.count("%dsa_decode") >= 2
+        assert len(_kernel_calls(text, "pool_write")) == 4
+        assert memory.temp_size_in_bytes < pool_bytes // 10
+    elif program == "prefill":
+        assert text.count("%dsa_index_prefill") >= 2 and text.count("%dsa_prefill_walk") >= 2
+        assert len(_kernel_calls(text, "pool_write")) == 4 * -(-chunk // 128)
+        # a slot's scores and its mask pass from kernel to kernel
+        assert not [line for line in text.splitlines()
+                    if f"[{chunk},{positions}]" in line and " custom-call(" not in line]
+        # 4,096 positions x 18,432 of the dense layer's two activations and the
+        # held route's last rung of 32,768 copies of 7,168 in float32
+        assert memory.temp_size_in_bytes <= DEEPSEEK_V32_PREFILL_TEMP_BYTES
+
+
+#: what ``benchmarks/configs/deepseek-v3.2.json`` ``serve.memory`` quotes for a
+#: prefill tick (the sandbox compile for the chip: 2,064,272,384 bytes at these
+#: two layers, 2,092,239,872 at the cell's six, whose layers share them)
+DEEPSEEK_V32_PREFILL_TEMP_BYTES = 2_100_000_000

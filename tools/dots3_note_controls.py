@@ -2,7 +2,9 @@
 dots3-note-prev cell's by default, the Laguna cell's with
 ``--workload serve-laguna-xs2-mixedlen-sat --control program fp8_weights
 full_window``, the Ouro cell's with ``--workload serve-ouro-2.6b-mathword-sat
---control program fp8_weights three_passes shared_pass_cache``): does the comparison that decides ``correct`` refuse a server
+--control program fp8_weights three_passes shared_pass_cache``, the
+DeepSeek-V3.2 cell's with ``--workload serve-deepseek-v3.2-ctx32k-sat --control
+program fp8_weights last_positions flat_top_k plain_rope``): does the comparison that decides ``correct`` refuse a server
 computed below the precision the configuration states, and one that attends the
 WRONG positions?
 
@@ -40,6 +42,15 @@ would have reported for that server.
   passes that arXiv:2510.25741 measures as an approximation, and that this
   configuration does not make.
 
+* ``flat_top_k`` (an indexed latent family whose router has groups,
+  DeepSeek-V3.2): the router takes the flat top ``k`` of all its experts in
+  place of the group-limited choice (the family's ``model`` is handed
+  ``n_group`` = ``topk_group`` = 1; weights, bias and scale are the
+  configuration's).
+* ``plain_rope`` (the same family): RoPE turns by ``theta`` alone and the
+  softmax scale is ``1 / sqrt(192)``, in place of YaRN's blended frequencies
+  and ``mscale^2`` (the family's ``model`` is handed ``rope_scaling`` None).
+
 ``fp8_weights`` applies to every family; ``last_positions`` to an indexed
 latent family (dots3-note), ``full_window`` to a llama family with window
 layers (Laguna), ``three_passes`` and ``shared_pass_cache`` to a llama family
@@ -51,6 +62,8 @@ layers, the program's device-side counts).
 
     python3 tools/dots3_note_controls.py --seed <n> [<n> ...] [--control <name> ...]
 
+``--draw embed_tokens=128 routed_down_proj=0.125`` overrides the configuration's
+seeded draw's multipliers (a sweep before one is written into the file).
 Prints one JSON line a seed and control. Runs on whatever device JAX finds;
 the numbers that count are the chip's.
 """
@@ -68,28 +81,34 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "tools"))
 
 CONTROLS = ("program", "fp8_weights", "last_positions", "full_window", "three_passes",
-            "shared_pass_cache")
+            "shared_pass_cache", "flat_top_k", "plain_rope")
 WORKLOAD = "serve-dots3-note-prev-longctx-sat"
 
 
 @contextlib.contextmanager
 def last_positions_chosen():
     """The package's selection with every position's own number for its
-    index score: the ``top_k`` largest are the last ``top_k``."""
+    index score: the ``top_k`` largest are the last ``top_k``. Both forms of
+    the selection are handed the numbers: XLA's (``kth_largest``) and, since
+    PR 38 the one a chip runs, the kernel's (``sparse_select.select_top_k``:
+    until PR 58 this control changed the first alone and read the program's
+    own gap on the chip)."""
     import jax.numpy as jnp
     from deepspeed_tpu.models import deepseek_v3 as package
+    from deepspeed_tpu.ops.pallas import sparse_select
 
-    plain = package.kth_largest
+    plain, plain_kernel = package.kth_largest, sparse_select.select_top_k
 
-    def by_position(scores, valid, k):
-        places = jnp.arange(scores.shape[-1], dtype=jnp.float32)
-        return plain(jnp.broadcast_to(places, scores.shape), valid, k)
+    def places_of(scores):
+        return jnp.broadcast_to(jnp.arange(scores.shape[-1], dtype=jnp.float32), scores.shape)
 
-    package.kth_largest = by_position
+    package.kth_largest = lambda scores, valid, k: plain(places_of(scores), valid, k)
+    sparse_select.select_top_k = lambda scores, *rest, **kw: plain_kernel(
+        places_of(scores), *rest, **kw)
     try:
         yield
     finally:
-        package.kth_largest = plain
+        package.kth_largest, sparse_select.select_top_k = plain, plain_kernel
 
 
 def full_window_family(family):
@@ -111,6 +130,20 @@ def three_passes_family(family):
         return family.model(config, deployment, loop_passes=int(config["total_ut_steps"]) - 1)
 
     return types.SimpleNamespace(model=model)
+
+
+def overridden_family(**overrides):
+    """``family -> family`` whose model is built with ``overrides`` over the
+    configuration's sizes."""
+    import types
+
+    def stand_in(family):
+        def model(config, deployment):
+            return family.model(config, deployment, **overrides)
+
+        return types.SimpleNamespace(model=model)
+
+    return stand_in
 
 
 @contextlib.contextmanager
@@ -155,13 +188,16 @@ def run_control(cell, seed, control):
     changed = {"last_positions": last_positions_chosen,
                "shared_pass_cache": one_cache_for_every_pass}.get(control, contextlib.nullcontext)()
     stand_in = {"fp8_weights": fp8_family, "full_window": full_window_family,
-                "three_passes": three_passes_family}.get(control, lambda family: family)
+                "three_passes": three_passes_family,
+                "flat_top_k": overridden_family(n_group=1, topk_group=1),
+                "plain_rope": overridden_family(rope_scaling=None)}.get(
+                    control, lambda family: family)
     with changed:       # the programs are traced in warm-up, under the change
         engine, sched = runner._server(cell, env, stand_in(family))
         sched.warmup()
         reqs = runner._checked_requests(cell, env, sched)
     counted = {k: v - before.get(k, 0) for k, v in trace.recorder().counters.items()}
-    line = {"seed": seed, "control": control,
+    line = {"seed": seed, "control": control, "draw": cell.config.get("draw"),
             "tokens_emitted_distinct": len({int(t) for r in reqs for t in r.output})}
     live = sum(counted.get(f"dsa_positions_live_{kind}", 0) for kind in ("prefill", "decode"))
     if live:
@@ -189,6 +225,8 @@ def main(argv):
     parser.add_argument("--seed", type=int, nargs="+", required=True)
     parser.add_argument("--control", nargs="+", default=list(CONTROLS[:3]), choices=CONTROLS)
     parser.add_argument("--root", default=ROOT)
+    parser.add_argument("--draw", nargs="*", default=[], metavar="LEAF=MULTIPLIER",
+                        help="the configuration's `draw` with these multipliers, for a sweep")
     args = parser.parse_args(argv)
 
     from benchmarks.lib import harness
@@ -196,6 +234,8 @@ def main(argv):
 
     use_compile_cache()
     cell = harness.Cell(args.root, harness.load_json(args.root, "BENCHMARK.json"), args.workload)
+    for leaf, by in (pair.split("=") for pair in args.draw):
+        cell.config["draw"][leaf] = float(by)
     for seed in args.seed:
         for control in args.control:
             print(json.dumps(run_control(cell, seed, control)), flush=True)
